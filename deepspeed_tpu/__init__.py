@@ -7,7 +7,6 @@ compute path is jitted SPMD over a named device mesh, not a port of the
 reference's torch/CUDA machinery.
 """
 
-from .utils import jax_compat as _jax_compat  # must precede runtime imports
 from .version import __version__
 from .runtime.activation_checkpointing import checkpointing
 from .runtime.engine import DeepSpeedEngine

@@ -62,6 +62,9 @@ def _audit_builtin_steps(stages):
     # override would veto the `3q` variant's explicit enabled=true (or
     # silently compress the plain stages)
     os.environ.pop("DSTPU_COMMS_COMPRESSION", None)
+    # a throwaway store, deliberately cold: the audit compiles each stage
+    # once and warm-starts it once (CPU-only; the chip path's caches live
+    # under compile_cache.cache_root(), a fixed directory)
     cache_dir = tempfile.mkdtemp(prefix="dstpu-audit-cc-")
     try:
         for spec in stages:
@@ -588,22 +591,24 @@ def _audit_tracing():
 def _kv_gather_eqns(closed_jaxpr, block_size, n_head, head_dim):
     """Gathered-K/V-materialization census: every ``gather`` equation
     (anywhere in the program, scan bodies included) whose output is a
-    per-slot block-list materialization — rank >= 5 with trailing dims
-    ``(block_size, n_head, head_dim)``, the exact shape
-    ``paged_kv.gather_kv``'s table gather produces.  The in-place
+    per-slot block-list materialization — rank >= 4 with trailing dims
+    ``(block_size, n_head * head_dim)``, the exact shape
+    ``paged_kv.gather_kv``'s table gather produces over the pool's
+    merged-head layout.  (The kernel path's int8 SCALE gather has a
+    narrower minor dim and is not K/V payload.)  The in-place
     kernel's decode step must contain ZERO of these; the gather
     fallback's must contain them (the detector is sanity-checked
     against the fallback so an upstream lowering change cannot silently
     blind it)."""
     from .jaxpr_audit import iter_eqns
     hits = []
-    sig = (int(block_size), int(n_head), int(head_dim))
+    sig = (int(block_size), int(n_head) * int(head_dim))
     for eqn, path in iter_eqns(closed_jaxpr.jaxpr):
         if eqn.primitive.name != "gather":
             continue
         for ov in eqn.outvars:
             shape = tuple(getattr(ov.aval, "shape", ()))
-            if len(shape) >= 5 and shape[-3:] == sig:
+            if len(shape) >= 4 and shape[-2:] == sig:
                 hits.append((path, shape))
     return hits
 

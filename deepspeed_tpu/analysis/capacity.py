@@ -538,12 +538,13 @@ def main(argv=None):
     ap.add_argument("run", nargs="?", default=None,
                     help="monitor run dir (or an events.jsonl path) "
                          "whose `mem` events to render")
-    ap.add_argument("--replay", metavar="MAXPARAMS_JSON", default=None,
-                    help="fit + replay a committed MAXPARAMS document")
+    ap.add_argument("--replay", metavar="RUNGS_JSON", default=None,
+                    help="fit + replay a recorded max-params document "
+                         "(tests/data/maxparams_rungs.json holds the "
+                         "rungs recorded so far)")
     ap.add_argument("--max-params", action="store_true",
                     help="predict the largest trainable params for "
-                         "--host-ram-gb (fit from --replay or "
-                         "./MAXPARAMS.json)")
+                         "--host-ram-gb (fit from --replay)")
     ap.add_argument("--host-ram-gb", type=float, default=None)
     ap.add_argument("--max-streams", action="store_true",
                     help="serving capacity: concurrent streams an HBM "
@@ -572,8 +573,11 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
-    if args.replay or args.max_params:
-        path = args.replay or "MAXPARAMS.json"
+    if args.max_params and not args.replay:
+        ap.error("--max-params fits from recorded rungs: pass --replay "
+                 "<rungs.json>")
+    if args.replay:
+        path = args.replay
         try:
             with open(path, encoding="utf-8") as fh:
                 doc = json.load(fh)

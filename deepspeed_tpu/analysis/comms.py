@@ -22,11 +22,18 @@ from typing import Optional
 from .findings import Finding
 
 # canonical kind names; both jaxpr primitives and HLO opcodes map here
+# (jax 0.9.0 under shard_map's vma typing spells psum / all_gather of a
+# varying operand ``psum_invariant`` / ``all_gather_invariant``, and the
+# unreduced-sharding forms ``unreduced_psum`` / ``all_gather_reduced`` /
+# ``unreduced_reduce_scatter``)
 KIND_ALIASES = {
-    "psum": "all_reduce", "psum2": "all_reduce", "pmax": "all_reduce",
+    "psum": "all_reduce", "psum_invariant": "all_reduce",
+    "unreduced_psum": "all_reduce", "pmax": "all_reduce",
     "pmin": "all_reduce", "all-reduce": "all_reduce",
-    "all_gather": "all_gather", "all-gather": "all_gather",
+    "all_gather": "all_gather", "all_gather_invariant": "all_gather",
+    "all_gather_reduced": "all_gather", "all-gather": "all_gather",
     "psum_scatter": "reduce_scatter", "reduce_scatter": "reduce_scatter",
+    "unreduced_reduce_scatter": "reduce_scatter",
     "reduce-scatter": "reduce_scatter",
     "all_to_all": "all_to_all", "all-to-all": "all_to_all",
     "ppermute": "collective_permute", "pshuffle": "collective_permute",

@@ -35,9 +35,11 @@ from .comms import CensusEntry, CommsBudget, canonical_kind, check_budget, \
 from .findings import Finding, counts_by_severity
 
 # primitives that round-trip through the host (serialize the step on the
-# dispatch path); anything name-matching *callback is caught too
+# dispatch path); anything name-matching *callback is caught too.
+# ``debug_print`` is what ``jax.debug.print`` traces to on jax 0.9.0.
 HOST_SYNC_PRIMS = {"pure_callback", "io_callback", "debug_callback",
-                   "callback", "infeed", "outfeed", "host_local_array_to_global_array"}
+                   "debug_print", "callback", "infeed", "outfeed",
+                   "host_local_array_to_global_array"}
 
 # primitives whose operand dtypes define the "compute dtype" of a path
 COMPUTE_PRIMS = {"dot_general", "conv_general_dilated"}

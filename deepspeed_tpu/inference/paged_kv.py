@@ -11,11 +11,15 @@ completion instead of reserving max_seq tokens per slot.
 
 Device layout (pure pytree — jit-carry/donation friendly):
 
-- ``pool["k"]/["v"]``: (L, num_blocks, block_size, H, hd) in the cache
-  dtype, or int8 when the pool is quantized;
+- ``pool["k"]/["v"]``: (L, num_blocks, block_size, H·hd) in the cache
+  dtype, or int8 when the pool is quantized.  Heads are MERGED into
+  the minor dim: a TPU tile is 128 lanes wide, so a (…, H, hd=64)
+  array can neither be DMA'd a block at a time by the paged-attention
+  kernel nor stored without lane padding;
 - ``pool["k_scale"]/["v_scale"]`` (int8 pools only): fp32 per-block
-  quantization scales, (L, num_blocks, block_size, H, hd//qb) — the
-  ``runtime/comm/quantized.py`` block quantizer over the head dim.
+  quantization scales, (L, num_blocks, block_size, H·hd//qb) — the
+  ``runtime/comm/quantized.py`` block quantizer over the merged dim
+  (``qb`` divides ``hd``, so a block never spans two heads).
 
 Block 0 is a reserved SCRATCH block: inactive batch slots carry
 all-zero block tables, so their (masked, discarded) decode writes land
@@ -425,11 +429,11 @@ def init_pool(n_layer: int, num_blocks: int, block_size: int, n_head: int,
     ``kv_bits=8`` stores int8 payloads + fp32 block scales over the head
     dim (``quant_block`` clipped to a divisor of ``head_dim``)."""
     assert kv_bits in (8, 16), f"kv_bits must be 8 or 16, got {kv_bits}"
-    shape = (n_layer, num_blocks, block_size, n_head, head_dim)
+    shape = (n_layer, num_blocks, block_size, n_head * head_dim)
     if kv_bits == 16:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     qb = pick_block(head_dim, quant_block)
-    sshape = shape[:-1] + (head_dim // qb,)
+    sshape = shape[:-1] + (shape[-1] // qb,)
     return {"k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
             # scale 1 ≡ the quantizer's all-zero-block convention
@@ -442,8 +446,8 @@ def is_quantized_pool(pool) -> bool:
 
 
 def pool_quant_block(pool) -> Optional[int]:
-    """The int8 pool's quantization block over the head dim (None for a
-    full-width pool)."""
+    """The int8 pool's quantization block over the merged head dim (None
+    for a full-width pool)."""
     if not is_quantized_pool(pool):
         return None
     return pool["k"].shape[-1] // pool["k_scale"].shape[-1]
@@ -456,6 +460,11 @@ def pool_bytes(pool) -> int:
 def capacity_tokens(pool) -> int:
     """Token capacity of the allocatable pool (scratch block excluded)."""
     return (pool["k"].shape[1] - 1) * pool["k"].shape[2]
+
+
+def _merge_heads(x):
+    """(…, H, hd) → (…, H·hd): the pool's minor dim."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
 
 
 def write_tokens(pool, layer, block_tables, lengths, k, v):
@@ -481,6 +490,7 @@ def write_tokens(pool, layer, block_tables, lengths, k, v):
                               jnp.minimum(idx, nb_max - 1), axis=1)
     blk = jnp.where(idx < nb_max, blk, SCRATCH_BLOCK)
     off = pos % bs
+    k, v = _merge_heads(k), _merge_heads(v)
     if not is_quantized_pool(pool):
         dt = pool["k"].dtype
         return dict(pool,
@@ -502,7 +512,7 @@ def write_token(pool, layer, block_tables, lengths, k, v):
                         k[:, None], v[:, None])
 
 
-def gather_kv(pool, layer, block_tables, dtype):
+def gather_kv(pool, layer, block_tables, dtype, n_head):
     """Per-slot gathered cache views for one layer — the legacy/fallback
     paged-attention path AND the oracle the in-place Pallas kernel
     (``ops/transformer/paged_attention.py``) is tested against.
@@ -514,17 +524,18 @@ def gather_kv(pool, layer, block_tables, dtype):
     dtype here let a caller's fp16 model silently read bf16 views.
 
     Returns ``(keys, vals)`` of shape (B, nb_max·block_size, H, hd) in
-    ``dtype`` — position p of slot b is row p of its view, so the
-    caller's causal mask over ``lengths`` is layout-independent."""
+    ``dtype`` (``n_head`` splits the pool's merged minor dim) — position
+    p of slot b is row p of its view, so the caller's causal mask over
+    ``lengths`` is layout-independent."""
     def view(name):
-        x = pool[name][layer][block_tables]     # (B, nb, bs, H, hd)
-        B, nb, bs = x.shape[0], x.shape[1], x.shape[2]
-        x = x.reshape(B, nb * bs, *x.shape[3:])
-        if not is_quantized_pool(pool):
-            return x.astype(dtype)
-        s = pool[name + "_scale"][layer][block_tables]
-        s = s.reshape(B, nb * bs, *s.shape[3:])
-        return dequantize_blockwise(x, s, bits=8, out_dtype=dtype)
+        x = pool[name][layer][block_tables]     # (B, nb, bs, H·hd)
+        B, nb, bs, HD = x.shape
+        x = x.reshape(B, nb * bs, HD)
+        if is_quantized_pool(pool):
+            s = pool[name + "_scale"][layer][block_tables]
+            x = dequantize_blockwise(x, s.reshape(B, nb * bs, -1), bits=8,
+                                     out_dtype=dtype)
+        return x.astype(dtype).reshape(B, nb * bs, n_head, HD // n_head)
     return view("k"), view("v")
 
 
@@ -544,9 +555,10 @@ def write_prefill(pool, blocks, k, v):
         "blocks fill during decode)")
 
     def put(name, x):
-        x = x.reshape(L, nb, bs, *x.shape[2:])
+        x = x.reshape(L, nb, bs, x.shape[-1])
         return pool[name].at[:, blocks].set(x)
 
+    k, v = _merge_heads(k), _merge_heads(v)
     if not is_quantized_pool(pool):
         dt = pool["k"].dtype
         return dict(pool, k=put("k", k.astype(dt)), v=put("v", v.astype(dt)))
@@ -593,9 +605,10 @@ def _block_digests(k, v, k_scale, v_scale):
 
 def export_block_image(pool, blocks, quant_block: int = 64) -> dict:
     """Serialize ``blocks`` (one sequence's block list) as an in-memory
-    int8+scales image — host numpy arrays of shape (L, nb, bs, H, hd)
-    plus (L, nb, bs, H, hd//qb) scales, per-block digests, and the
-    geometry needed to validate an import."""
+    int8+scales image — host numpy arrays of shape (L, nb, bs, H·hd)
+    plus (L, nb, bs, H·hd//qb) scales (the pool's own layout),
+    per-block digests, and the geometry needed to validate an import.
+    ``quant_block`` must divide the head dim for a full-width pool."""
     idx = jnp.asarray(np.asarray(blocks, np.int32))
     if is_quantized_pool(pool):
         qb = pool_quant_block(pool)
@@ -637,12 +650,12 @@ def import_block_image(pool, blocks, image, pad_to=None):
     specialization on ``len(blocks)`` otherwise puts a fresh trace
     (~100-650 ms) inside each first-of-its-size restore window."""
     k = image["k"]
-    L, nb, bs, H, hd = k.shape
+    L, nb = k.shape[:2]
     pshape = pool["k"].shape
-    if (L, bs, H, hd) != (pshape[0], pshape[2], pshape[3], pshape[4]):
+    if k.ndim != 4 or (L,) + k.shape[2:] != (pshape[0],) + pshape[2:]:
         raise BlockImageError(
-            f"image geometry {(L, bs, H, hd)} does not match pool "
-            f"{(pshape[0], pshape[2], pshape[3], pshape[4])}")
+            f"image geometry {k.shape} does not match pool {pshape} "
+            "(layers, block size and merged head dim must agree)")
     if len(blocks) != nb:
         raise BlockImageError(
             f"image holds {nb} blocks, import got {len(blocks)} ids")

@@ -574,6 +574,10 @@ class ServingEngine:
         assert self.num_blocks >= 2, "num_blocks must be >= 2"
 
         cache_dtype = getattr(inner, "dtype", jnp.bfloat16)
+        # block images quantize over the same blocks an int8 pool does:
+        # a divisor of the head dim, never spanning two heads
+        self._kv_quant_block = pk.pick_block(mc.head_dim,
+                                             config.kv_quant_block)
         with jax.set_mesh(engine.mesh):
             self.pool = pk.init_pool(
                 mc.n_layer, self.num_blocks, config.block_size, mc.n_head,
@@ -695,6 +699,7 @@ class ServingEngine:
         self._blockset = None     # jitted poison/scrub scatter (lazy)
         self._blockcopy = None    # jitted COW block clone (lazy)
         self._preflight_done = False
+        self._preflight = None    # what the startup gate compared, once run
 
         # ---- resilience state (docs/serving.md#resilience) ----
         self._outcomes = {k: 0 for k in OUTCOMES}
@@ -908,6 +913,8 @@ class ServingEngine:
         if pre is None:
             self._preflight_done = True
             return
+        self._preflight = {"budget_bytes": budget,
+                           "peak_bytes": pre["peak_bytes"]}
         if pre["peak_bytes"] > budget * self.config.preflight_safety:
             # pre-written post-mortem: the ledger + capacity verdict name
             # which subsystem blew the budget and which knob buys
@@ -1586,7 +1593,7 @@ class ServingEngine:
         sdir = stream_snapshot_dir(self.config.journal_dir, uid)
         with jax.set_mesh(self.engine.mesh):
             image = pk.export_block_image(
-                self.pool, s.blocks, quant_block=self.config.kv_quant_block)
+                self.pool, s.blocks, quant_block=self._kv_quant_block)
         meta = {
             # atomic.py's newest-first ordering key: the decode position
             "global_steps": ngen,
@@ -1737,7 +1744,7 @@ class ServingEngine:
         with jax.set_mesh(self.engine.mesh):
             warm = pk.export_block_image(
                 self.pool, [pk.SCRATCH_BLOCK],
-                quant_block=self.config.kv_quant_block)
+                quant_block=self._kv_quant_block)
             self.pool = pk.import_block_image(
                 self.pool, [pk.SCRATCH_BLOCK], warm, pad_to=self.nb_max)
 
@@ -1987,7 +1994,7 @@ class ServingEngine:
         gen = len(s.out_tokens)
         with jax.set_mesh(self.engine.mesh):
             image = pk.export_block_image(
-                self.pool, s.blocks, quant_block=self.config.kv_quant_block)
+                self.pool, s.blocks, quant_block=self._kv_quant_block)
         seat = self._seat_record(slot)
         pub = self._txq.publish(uid, gen, image, seat)
         self._transfers_total += 1
@@ -3000,7 +3007,10 @@ class ServingEngine:
                "outcomes": dict(self._outcomes),
                "requeued": self._requeued_total,
                "breaker_open": self._breaker_open,
-               "traces_emitted": self._traces_emitted}
+               "traces_emitted": self._traces_emitted,
+               # None = the startup memory gate had nothing to compare
+               # (disabled, no budget, or no executable analysis)
+               "preflight": self._preflight}
         if self.spec is not None:
             out["speculative"] = {
                 "k": self.spec.k,

@@ -194,7 +194,11 @@ def flash_or_jnp_attention(q, k, v, causal_mask, attn_pdrop, rng,
                 warning_once("attention_impl='flash' has no in-kernel "
                              "dropout; attn_pdrop is ignored on this path")
             from ..ops.transformer.flash_attention import flash_attention
-            return flash_attention(q, k, v, causal=True)
+            from ..parallel.mesh import BATCH_AXES, per_device
+            # (B, T, H, hd): batch over the data axes, heads over tensor
+            spec = P(BATCH_AXES, None, "tensor", None)
+            return per_device(partial(flash_attention, causal=True),
+                              (spec, spec, spec), spec)(q, k, v)
     return _attention_jnp(q, k, v, causal_mask, attn_pdrop, rng,
                           deterministic, scale=scale)
 
@@ -795,7 +799,7 @@ class GPT2:
                                        layer, scale_attn=c.scale_attn)
             else:
                 keys, vals = pk.gather_kv(pool, layer, block_tables,
-                                          self.dtype)
+                                          self.dtype, c.n_head)
                 attn = self._attend_paged(q, keys, vals, lengths)
             attn = self._mm(attn, lp["proj_w"], lp["proj_b"])
             return (self._ffn(lp, h + attn), pool, layer + 1), None

@@ -13,21 +13,14 @@ import jax
 
 @functools.lru_cache(maxsize=None)
 def backend():
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    return jax.default_backend()
 
 
-@functools.lru_cache(maxsize=None)
 def flash_attention_available():
-    """Pallas flash attention runs on TPU; elsewhere the jnp path is used."""
-    try:
-        # must match the import path the model uses at call time
-        from .transformer.flash_attention import flash_attention  # noqa: F401
-        return backend() == "tpu"
-    except Exception:
-        return False
+    """Pallas flash attention runs on TPU; elsewhere the jnp path is used.
+    Decided by the platform alone: on a TPU a kernel that cannot be
+    imported or compiled raises where it is used, it is not replaced."""
+    return backend() == "tpu"
 
 
 OP_REGISTRY = {}

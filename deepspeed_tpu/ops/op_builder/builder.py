@@ -28,6 +28,22 @@ BUILD_DIR = os.environ.get("DS_BUILD_DIR",
 _build_lock = threading.Lock()
 
 
+def _host_isa():
+    """What ``-march=native`` resolves against on this host: the machine
+    type plus the CPU feature flags the kernel reports."""
+    import platform
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.split(":")[0].strip() in ("flags", "Features"):
+                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
+                    break
+    except OSError:
+        flags = platform.processor()
+    return f"{platform.machine()} {flags}"
+
+
 class OpBuilder:
     """One native op: sources under csrc/, compiled once, loaded via ctypes."""
 
@@ -71,6 +87,10 @@ class OpBuilder:
             with open(s, "rb") as f:
                 h.update(f.read())
         h.update(" ".join(self.cflags()).encode())
+        # -march=native: the artifact is only valid on a CPU with the
+        # build host's instruction set — a build dir carried to another
+        # machine (a copied checkout) must miss, not load
+        h.update(_host_isa().encode())
         return h.hexdigest()[:16]
 
     # Library cache name: ops sharing a translation unit (cpu_adam /

@@ -12,29 +12,35 @@ HBM-bandwidth bound while b1 (gather ≈ cache size) sat at 0.94.  This
 kernel deletes the copy: per-slot **block tables and lengths enter as
 scalar-prefetch operands**, K/V blocks are DMA'd **directly from the
 pool in HBM**, int8 pools dequantize **in-kernel** from the fp32 block
-scales (reads priced at 1 byte/element), and the softmax accumulates
-over the slot's block walk — zero gathered copies, the pool untouched
-(read-only; donation of the pool through the decode step is unaffected).
+scales (payload reads priced at 1 byte/element; the scales alone are
+gathered), and the softmax accumulates over the slot's block walk — no
+gathered K/V copy, the pool untouched (read-only; donation of the pool
+through the decode step is unaffected).
 
 Two modes, one call (written the way ``flash_attention.py`` carries its
 BlockSpec-LUT and manual-DMA variants side by side):
 
 - ``online`` — the compiled TPU path: grid ``(B,)``, one program per
-  slot, the slot's **live** blocks (``ceil((length+W)/block_size)`` —
-  short slots skip their tail entirely) fetched through a triple-
-  buffered VMEM ring with explicit ``make_async_copy`` from the
-  HBM-resident pool (block j+2's fetch issues before block j's compute,
-  the ``_fwd_kernel_dma`` discipline), masked **online-softmax**
-  (fp32 running max/denominator) accumulation per block;
-- ``exact`` — the interpret-mode fallback (non-TPU backends / tests):
+  slot, the slot's **live** tokens (``length + W`` — short slots skip
+  their tail entirely) fetched in chunks of whole 128-lane score columns
+  through a triple-buffered VMEM ring with explicit ``make_async_copy``
+  from the HBM-resident pool (chunk c+2's fetch issues before chunk c's
+  compute, the ``_fwd_kernel_dma`` discipline), masked
+  **online-softmax** (fp32 running max/denominator) accumulation per
+  chunk.  Every tile keeps the pool's ``(rows, H·hd)`` shape: Mosaic
+  DMAs and slices in whole 128-lane tiles, so a 64-lane head is never
+  cut out — heads are separated by a block-diagonal query operand on
+  the MXU (``_online_kernel``);
+- ``exact`` — the interpreter-only fallback (non-TPU backends / tests):
   grid ``(B, nb_max)`` with the pallas pipeline DMA-ing blocks via
   scalar-prefetch index maps, scores accumulated into a full
   ``(H, W, S)`` row and the epilogue mirroring
   ``GPT2._masked_attend`` **op-for-op** (input-dtype score matmul →
   fp32 cast → scale → mask → softmax → probs cast to compute dtype →
-  AV) — measured **bit-exact** against the ``gather_kv`` oracle on
-  fp32/bf16/fp16 pools (tests/test_paged_attention.py), which is what
-  keeps CPU tier-1 exact when the serving decode routes through here.
+  AV) — **bit-exact** against the ``gather_kv`` oracle on bf16/fp16 and
+  int8 pools, within 4 ulp on fp32 (tests/test_paged_attention.py),
+  which is what keeps CPU tier-1 exact when the serving decode routes
+  through here.
 
 ``mode="auto"`` resolves to ``online`` on compiled TPU and ``exact``
 under the interpreter.  Queries are a ``(B, W, H, hd)`` window —
@@ -76,16 +82,6 @@ def resolve_mode(mode: str) -> str:
     return mode
 
 
-def _dequant_block(x, scale, compute_dtype):
-    """One pool block → compute dtype.  int8 payloads dequantize via the
-    fp32 block scales with EXACTLY ``paged_kv.gather_kv``'s formula
-    (``dequantize_blockwise``) so the kernel and the gather oracle read
-    identical values; 16-bit payloads just cast."""
-    if scale is None:
-        return x.astype(compute_dtype)
-    return dequantize_blockwise(x, scale, bits=8, out_dtype=compute_dtype)
-
-
 # ============================================================== exact kernel
 def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
                   scale_attn, compute_dtype, quantized):
@@ -93,7 +89,9 @@ def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
 
     Scores land in a full (H, W, S) fp32 row; the last block's visit
     runs the epilogue as the gather oracle computes it, op-for-op —
-    the bit-exactness contract (module docstring)."""
+    the exactness contract (module docstring).  Interpreter only: the
+    in-kernel ``reshape`` of a block to (bs, H, hd) is not a layout
+    Mosaic keeps."""
     if quantized:
         (tables_ref, lengths_ref, layer_ref, q_ref, k_ref, v_ref,
          ks_ref, vs_ref, o_ref, scores_ref, vrow_ref) = refs
@@ -105,10 +103,18 @@ def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
     j = pl.program_id(1)
     bs, W = block_size, n_window
 
-    k = _dequant_block(k_ref[0, 0], ks_ref[0, 0] if quantized else None,
-                       compute_dtype)
-    v = _dequant_block(v_ref[0, 0], vs_ref[0, 0] if quantized else None,
-                       compute_dtype)
+    def block(x_ref, s_ref):
+        """One pool block in the compute dtype, heads split out.  int8
+        payloads dequantize with EXACTLY ``paged_kv.gather_kv``'s
+        formula, so kernel and oracle read identical values."""
+        x = x_ref[0, 0]
+        x = (dequantize_blockwise(x, s_ref[0, 0], bits=8,
+                                  out_dtype=compute_dtype)
+             if quantized else x.astype(compute_dtype))
+        return x.reshape(bs, n_head, head_dim)
+
+    k = block(k_ref, ks_ref)
+    v = block(v_ref, vs_ref)
     q = q_ref[0]                                    # (W, H, hd)
     # per-(h, w, k) scores: same per-element hd-length contraction (and
     # operand layout) as the oracle's einsum("bqhd,bkhd->bhqk") — the
@@ -132,29 +138,28 @@ def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
         o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _exact_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
-                interpret):
+def _exact_call(q, pool, tables, lengths, layer_arr, *, scale_attn):
     B, W, H, hd = q.shape
-    bs = pool["k"].shape[2]
+    bs, HD = pool["k"].shape[2:]
     nb_max = tables.shape[1]
     S = nb_max * bs
     quantized = "k_scale" in pool
 
     def kv_idx(b, j, tbl, lens, lay):
-        return (lay[0], tbl[b, j], 0, 0, 0)
+        return (lay[0], tbl[b, j], 0, 0)
 
     def q_idx(b, j, tbl, lens, lay):
         return (b, 0, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, W, H, hd), q_idx),
-        pl.BlockSpec((1, 1, bs, H, hd), kv_idx),
-        pl.BlockSpec((1, 1, bs, H, hd), kv_idx),
+        pl.BlockSpec((1, 1, bs, HD), kv_idx),
+        pl.BlockSpec((1, 1, bs, HD), kv_idx),
     ]
     args = [q, pool["k"], pool["v"]]
     if quantized:
         nsc = pool["k_scale"].shape[-1]
-        in_specs += [pl.BlockSpec((1, 1, bs, H, nsc), kv_idx)] * 2
+        in_specs += [pl.BlockSpec((1, 1, bs, nsc), kv_idx)] * 2
         args += [pool["k_scale"], pool["v_scale"]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B, nb_max),
@@ -168,153 +173,264 @@ def _exact_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
         _exact_kernel, block_size=bs, nb_max=nb_max, n_head=H, head_dim=hd,
         n_window=W, scale_attn=scale_attn, compute_dtype=q.dtype,
         quantized=quantized)
-    cp = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, H, hd), q.dtype),
-        compiler_params=cp, interpret=interpret,
-    )(tables, lengths, layer_arr, *args)
+        interpret=True,
+    )(tables, lengths, layer_arr, *args).reshape(B, W, H * hd)
 
 
 # ============================================================= online kernel
-def _online_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
-                   scale_attn, compute_dtype, quantized):
-    """Grid (B,): ONE program per slot walks the slot's LIVE blocks
-    (``ceil((length + W) / bs)``; dead tail blocks are never fetched)
-    through a triple-buffered make_async_copy ring from the HBM pool,
-    carrying fp32 online-softmax state (m, l, acc) per (head, window
-    row).  Per-head 2-D dots keep every matmul Mosaic-lowerable (the
-    kernel is KV-bandwidth-bound; MXU utilization of the tiny
-    (W, hd)×(hd, bs) dots is not the term that matters)."""
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
+                   compute_dtype, quant_block, group, rows_per_token):
+    """Grid (B,): ONE program per slot walks the slot's LIVE tokens in
+    chunks of ``group`` blocks (``group * block_size`` key positions: a
+    whole number of 128-lane score columns) through a triple-buffered
+    make_async_copy ring from the HBM pool, carrying fp32 online-softmax
+    state (m, l, acc) per row.
+
+    Every tile keeps the pool's (rows, H*hd) shape — no per-head slice,
+    reshape or transpose of a sub-128-lane head (Mosaic keeps none of
+    them at hd=64).  Heads are separated on the MXU instead: the query
+    window is expanded to a BLOCK-DIAGONAL (R, H*hd) operand whose row
+    ``(w, h)`` holds ``q[w, h]`` in head h's columns and zeros
+    elsewhere, so ``Qbd @ K^T`` is every head's scores at once and the
+    diagonal blocks of ``P @ V`` are every head's outputs.  That is
+    H times the FLOPs the math needs, on a kernel whose time is the KV
+    DMA.
+
+    int8 pools: a row is one QUANTIZATION block (``quant_block``
+    columns; ``hd // quant_block`` rows per head).  Payloads cast to
+    the compute dtype (exact: |q| <= 127) and the fp32 scales multiply
+    the (R, chunk) score/probability tiles, never a (chunk, H*hd) one:
+    they arrive already transposed to (rows, positions)
+    (:func:`_scale_rows`).  Rows of one head sum their partial scores
+    through a 0/1 matmul before the softmax."""
+    quantized = quant_block is not None
     if quantized:
         (tables_ref, lengths_ref, layer_ref, q_ref,
          k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-         kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref, acc_ref, sem) = refs
+         kbuf, vbuf, ksbuf, vsbuf, kst, vst, m_ref, l_ref, acc_ref,
+         sem) = refs
     else:
         (tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
          kbuf, vbuf, m_ref, l_ref, acc_ref, sem) = refs
-        ksbuf = vsbuf = None
     b = pl.program_id(0)
     lay = layer_ref[0]
-    bs, W, H = block_size, n_window, n_head
+    bs, G, Rw = block_size, group, rows_per_token
+    W, HD = q_ref.shape[1:]
+    qb = quant_block if quantized else head_dim
+    per_head = head_dim // qb                 # rows per head
+    R = W * Rw
+    Tc = G * bs
     sm_scale = (1.0 / np.sqrt(head_dim)) if scale_attn else 1.0
     length = lengths_ref[b]
-    # blocks that hold any position <= length + W - 1 (the window's last
+    # chunks that hold any position <= length + W - 1 (the window's last
     # row); everything past is masked for every row — skip the DMA
-    nb_live = jnp.minimum((length + W + bs - 1) // bs, nb_max)
+    n_chunks = -(-nb_max // G)
+    n_live = jnp.minimum((length + W + Tc - 1) // Tc, n_chunks)
 
-    n_copies = 4 if quantized else 2
-
-    def fetches(j, slot):
-        ki = tables_ref[b, j]
-        out = [pltpu.make_async_copy(k_hbm.at[lay, ki], kbuf.at[slot],
-                                     sem.at[slot, 0]),
-               pltpu.make_async_copy(v_hbm.at[lay, ki], vbuf.at[slot],
-                                     sem.at[slot, 1])]
+    def fetches(c, slot):
+        out = []
+        for g in range(G):
+            # a chunk's tail past the table re-reads its last entry; the
+            # mask below drops every position >= nb_max * bs
+            ki = tables_ref[b, jnp.minimum(c * G + g, nb_max - 1)]
+            if quantized:
+                # int8 tiles are 32 sublanes: a block lands whole at
+                # [slot, g] and is cast into the chunk-shaped stage below
+                kd, vd = kbuf.at[slot, g], vbuf.at[slot, g]
+            else:
+                kd = kbuf.at[slot, pl.ds(g * bs, bs)]
+                vd = vbuf.at[slot, pl.ds(g * bs, bs)]
+            out += [pltpu.make_async_copy(k_hbm.at[lay, ki], kd,
+                                          sem.at[slot, 0]),
+                    pltpu.make_async_copy(v_hbm.at[lay, ki], vd,
+                                          sem.at[slot, 1])]
         if quantized:
-            out += [pltpu.make_async_copy(ks_hbm.at[lay, ki],
+            # a whole number of 128-lane tiles: Tc itself, or (a table
+            # shorter than one tile) the single padded chunk at 0
+            first = pl.multiple_of(c * Tc, 128) if n_chunks > 1 else 0
+            cols = pl.ds(first, ksbuf.shape[-1])
+            out += [pltpu.make_async_copy(ks_hbm.at[b, :, cols],
                                           ksbuf.at[slot], sem.at[slot, 2]),
-                    pltpu.make_async_copy(vs_hbm.at[lay, ki],
+                    pltpu.make_async_copy(vs_hbm.at[b, :, cols],
                                           vsbuf.at[slot], sem.at[slot, 3])]
         return out
 
-    def start(j):
-        for c in fetches(j, jax.lax.rem(j, _N_BUF)):
-            c.start()
+    def start(c):
+        for cp in fetches(c, jax.lax.rem(c, _N_BUF)):
+            cp.start()
+
+    # row r = (w, i): window token w, quantization block i of the merged
+    # head dim (16-bit pools: i is the head)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 1)
+    diag = col // qb == row % Rw                              # (R, HD)
+    q = q_ref[0].astype(jnp.float32)                          # (W, HD)
+    qbd = jnp.zeros((R, HD), jnp.float32)
+    for w in range(W):
+        mine = jnp.logical_and(diag, row // Rw == w)
+        qbd = jnp.where(mine, jnp.broadcast_to(q[w:w + 1], (R, HD)), qbd)
+    qbd = qbd.astype(compute_dtype)
+    if per_head > 1:
+        same_head = (
+            jax.lax.broadcasted_iota(jnp.int32, (R, R), 0) // per_head
+            == jax.lax.broadcasted_iota(jnp.int32, (R, R), 1) // per_head
+        ).astype(jnp.float32)
+
+    def per_window(x):
+        """(Rw, >= chunk) per-token rows -> (R, chunk): one copy per
+        window token (Rw is a whole number of sublane tiles)."""
+        x = x[:, :Tc]
+        return x if W == 1 else jnp.concatenate([x] * W, axis=0)
 
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
     acc_ref[:] = jnp.zeros_like(acc_ref)
     start(0)
-
-    @pl.when(nb_live > 1)
-    def _():
-        start(1)
-
-    def body(j, carry):
-        @pl.when(j + 2 < nb_live)
+    if n_chunks > 1:
+        @pl.when(n_live > 1)
         def _():
-            start(j + 2)
-        slot = jax.lax.rem(j, _N_BUF)
-        for c in fetches(j, slot):
-            c.wait()
-        k = _dequant_block(kbuf[slot], ksbuf[slot] if quantized else None,
-                           compute_dtype)
-        v = _dequant_block(vbuf[slot], vsbuf[slot] if quantized else None,
-                           compute_dtype)
-        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (W, bs), 1)
-        w_pos = jax.lax.broadcasted_iota(jnp.int32, (W, bs), 0)
-        valid = k_pos <= length + w_pos                     # (W, bs)
-        for h in range(H):
-            q_h = q_ref[0, :, h, :]                         # (W, hd)
+            start(1)
+
+    def body(c, carry):
+        if n_chunks > 2:
+            @pl.when(c + 2 < n_live)
+            def _():
+                start(c + 2)
+        slot = jax.lax.rem(c, _N_BUF)
+        for cp in fetches(c, slot):
+            cp.wait()
+        if quantized:
+            for g in range(G):
+                rows = pl.ds(g * bs, bs)
+                kst[rows, :] = kbuf[slot, g].astype(jnp.float32).astype(
+                    compute_dtype)
+                vst[rows, :] = vbuf[slot, g].astype(jnp.float32).astype(
+                    compute_dtype)
+            k, v = kst[...], vst[...]
+        else:
+            k = kbuf[slot].astype(compute_dtype)
+            v = vbuf[slot].astype(compute_dtype)
+        s = jax.lax.dot_general(
+            qbd, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (R, Tc)
+        if quantized:
+            s = s * per_window(ksbuf[slot])
+        if per_head > 1:
             s = jax.lax.dot_general(
-                q_h, k[:, h, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(valid, s, NEG_INF)
-            rows = pl.ds(h * W, W)
-            m_prev = m_ref[rows, :]                          # (W, 1)
-            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)                           # (W, bs) fp32
-            l_ref[rows, :] = l_ref[rows, :] * alpha + \
-                jnp.sum(p, -1, keepdims=True)
-            acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
-                p.astype(compute_dtype), v[:, h, :], (((1,), (0,)), ((), ())),
+                same_head, s, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
-            m_ref[rows, :] = m_new
+        s = s * sm_scale
+        k_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 1)
+        w_pos = jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 0) // Rw
+        last = jnp.minimum(length + w_pos, nb_max * bs - 1)
+        s = jnp.where(k_pos <= last, s, NEG_INF)
+        m_prev = m_ref[:]                                     # (R, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                                # (R, Tc) fp32
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
+        if quantized:
+            p = p * per_window(vsbuf[slot])
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
         return carry
 
-    jax.lax.fori_loop(0, nb_live, body, 0)
+    jax.lax.fori_loop(0, n_live, body, 0)
 
     l = l_ref[:]
     l_safe = jnp.where(l == 0.0, 1.0, l)                     # never 0: k_pos
-    out = acc_ref[:] / l_safe                                # 0 always live
-    o_ref[0] = out.reshape(H, W, head_dim).swapaxes(0, 1).astype(o_ref.dtype)
+    heads = jnp.where(diag, acc_ref[:] / l_safe, 0.0)        # 0 always live
+    out_row = jax.lax.broadcasted_iota(jnp.int32, (W, HD), 0)
+    out = jnp.zeros((W, HD), jnp.float32)
+    for w in range(W):
+        tok = jnp.sum(heads[w * Rw:(w + 1) * Rw], axis=0, keepdims=True)
+        out = jnp.where(out_row == w, jnp.broadcast_to(tok, (W, HD)), out)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _scale_rows(scale, layer, tables, n_rows, n_cols):
+    """One layer's block scales for every slot's table, transposed to
+    (B, rows, positions) and zero-padded to ``(n_rows, n_cols)`` — the
+    lane-dense form the kernel multiplies score tiles by.  The only
+    gathered copy on the kernel path: fp32 scales are ``4 / qb`` of the
+    int8 payload bytes."""
+    s = scale[layer][tables]                      # (B, nb_max, bs, HD//qb)
+    B, nb, bs, n = s.shape
+    s = s.reshape(B, nb * bs, n).transpose(0, 2, 1)
+    return jnp.pad(s, ((0, 0), (0, n_rows - n), (0, n_cols - nb * bs)))
 
 
 def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
                  interpret):
     B, W, H, hd = q.shape
-    bs = pool["k"].shape[2]
+    bs, HD = pool["k"].shape[2:]
     nb_max = tables.shape[1]
     quantized = "k_scale" in pool
+    # chunk: the fewest blocks that make whole 128-lane score columns —
+    # or the whole table, when it is shorter than that
+    G = min(int(np.lcm(bs, 128)) // bs, nb_max)
+    Tc = G * bs
+    qb = HD // pool["k_scale"].shape[-1] if quantized else hd
+    # row stride of one window token in the kernel's (R, ·) tiles: one row
+    # per quantization block, padded to whole fp32 sublane tiles and heads
+    Rw = _round_up(HD // qb, 8 * (hd // qb))
+    R = W * Rw
 
     in_specs = [
-        pl.BlockSpec((1, W, H, hd), lambda b, *s: (b, 0, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),      # k pool stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),      # v pool stays in HBM
+        pl.BlockSpec((1, W, HD), lambda b, *s: (b, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),         # k pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),         # v pool stays in HBM
     ]
-    args = [q, pool["k"], pool["v"]]
-    scratch = [
-        pltpu.VMEM((_N_BUF, bs, H, hd), pool["k"].dtype),
-        pltpu.VMEM((_N_BUF, bs, H, hd), pool["v"].dtype),
-    ]
+    args = [q.reshape(B, W, HD), pool["k"], pool["v"]]
     if quantized:
-        nsc = pool["k_scale"].shape[-1]
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        args += [pool["k_scale"], pool["v_scale"]]
-        scratch += [pltpu.VMEM((_N_BUF, bs, H, nsc), jnp.float32),
-                    pltpu.VMEM((_N_BUF, bs, H, nsc), jnp.float32)]
+        Tcs = _round_up(Tc, 128)                   # scale columns per DMA
+        n_cols = (-(-nb_max // G) - 1) * Tc + Tcs
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        args += [_scale_rows(pool[n], layer_arr[0], tables, Rw, n_cols)
+                 for n in ("k_scale", "v_scale")]
+        scratch = [
+            pltpu.VMEM((_N_BUF, G, bs, HD), jnp.int8),
+            pltpu.VMEM((_N_BUF, G, bs, HD), jnp.int8),
+            pltpu.VMEM((_N_BUF, Rw, Tcs), jnp.float32),
+            pltpu.VMEM((_N_BUF, Rw, Tcs), jnp.float32),
+            pltpu.VMEM((Tc, HD), q.dtype),         # k chunk, compute dtype
+            pltpu.VMEM((Tc, HD), q.dtype),         # v chunk, compute dtype
+        ]
+    else:
+        scratch = [
+            pltpu.VMEM((_N_BUF, Tc, HD), pool["k"].dtype),
+            pltpu.VMEM((_N_BUF, Tc, HD), pool["v"].dtype),
+        ]
     scratch += [
-        pltpu.VMEM((H * W, 1), jnp.float32),       # m (running max)
-        pltpu.VMEM((H * W, 1), jnp.float32),       # l (denominator)
-        pltpu.VMEM((H * W, hd), jnp.float32),      # acc
+        pltpu.VMEM((R, 1), jnp.float32),           # m (running max)
+        pltpu.VMEM((R, 1), jnp.float32),           # l (denominator)
+        pltpu.VMEM((R, HD), jnp.float32),          # acc
         pltpu.SemaphoreType.DMA((_N_BUF, 4 if quantized else 2)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, W, H, hd), lambda b, *s: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, W, HD), lambda b, *s: (b, 0, 0)),
         scratch_shapes=scratch)
     kernel = functools.partial(
-        _online_kernel, block_size=bs, nb_max=nb_max, n_head=H,
-        head_dim=hd, n_window=W, scale_attn=scale_attn,
-        compute_dtype=q.dtype, quantized=quantized)
+        _online_kernel, block_size=bs, nb_max=nb_max, head_dim=hd,
+        scale_attn=scale_attn, compute_dtype=q.dtype,
+        quant_block=qb if quantized else None, group=G, rows_per_token=Rw)
     cp = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, W, H, hd), q.dtype),
-        compiler_params=cp, interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((B, W, HD), q.dtype),
+        compiler_params=cp, interpret=interpret, name="paged_attention",
     )(tables, lengths, layer_arr, *args)
 
 
@@ -335,18 +451,18 @@ def paged_attention(q, pool, block_tables, lengths, layer, *,
 
     Returns (B, W, H·hd) in ``q.dtype`` — same contract as
     ``gather_kv`` + ``GPT2._masked_attend``, which remains the oracle
-    this kernel is tested against (bit-exact on 16-bit pools in exact
-    mode, tolerance-bounded online/int8)."""
+    this kernel is tested against (exact mode to the last ulp on 16-bit
+    pools, tolerance-bounded online/int8)."""
     B, W, H, hd = q.shape
-    assert pool["k"].shape[3] == H and pool["k"].shape[4] == hd, \
-        (pool["k"].shape, q.shape)
-    if interpret is None:
-        interpret = _interpret()
+    assert pool["k"].shape[3] == H * hd, (pool["k"].shape, q.shape)
     mode = resolve_mode(mode)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     tables = jnp.asarray(block_tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    call = _exact_call if mode == "exact" else _online_call
-    out = call(q, pool, tables, lengths, layer_arr,
-               scale_attn=scale_attn, interpret=interpret)
-    return out.reshape(B, W, H * hd)
+    if mode == "exact":
+        return _exact_call(q, pool, tables, lengths, layer_arr,
+                           scale_attn=scale_attn)
+    return _online_call(q, pool, tables, lengths, layer_arr,
+                        scale_attn=scale_attn,
+                        interpret=_interpret() if interpret is None
+                        else interpret)

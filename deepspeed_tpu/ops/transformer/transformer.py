@@ -223,8 +223,5 @@ class DeepSpeedTransformerLayer:
 def _flash_ok():
     """Pallas flash path: TPU backend (the kernel pads ragged seq/head
     shapes internally; see flash_attention._fwd)."""
-    try:
-        from ... import ops as _ops
-        return _ops.flash_attention_available()
-    except Exception:
-        return False
+    from ... import ops as _ops
+    return _ops.flash_attention_available()

@@ -141,6 +141,36 @@ def maybe_constrain(x, spec: P):
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def per_device(fn, specs, out_spec):
+    """``fn`` made to run once per device, on that device's shard of
+    operands laid out as ``specs`` — what a Pallas kernel needs inside
+    an SPMD-jitted step: XLA refuses to partition a Mosaic custom call
+    ("wrap the call in a shard_map"), so the call goes manual over the
+    mesh.  Identity when no mesh is active or it holds one device.  A
+    spec names the axes an operand dim MAY be sharded over; those the
+    active mesh lacks, or an enclosing ``shard_map`` already made
+    manual, are dropped."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or am.size == 1:
+        return fn
+    free = set(am.axis_names) - set(am.manual_axes)
+    if not free:
+        return fn
+
+    def present(spec):
+        dims = []
+        for entry in spec:
+            axes = (entry,) if isinstance(entry, str) else (entry or ())
+            axes = tuple(a for a in axes if a in free)
+            dims.append(axes if len(axes) > 1 else (axes[0] if axes
+                                                    else None))
+        return P(*dims)
+
+    return jax.shard_map(fn, mesh=am, axis_names=frozenset(free),
+                         in_specs=tuple(present(sp) for sp in specs),
+                         out_specs=present(out_spec), check_vma=False)
+
+
 class MeshContext:
     """Holds the mesh + derived extents; passed through engines.
 

@@ -66,16 +66,8 @@ def jaxpr_flops(jaxpr) -> dict:
     def visit(jx):
         for eqn in jx.eqns:
             name = eqn.primitive.name
-            for sub in jax.core.jaxprs_in_params(eqn.params) \
-                    if hasattr(jax.core, "jaxprs_in_params") else []:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
                 visit(sub)
-            for param in eqn.params.values():
-                if hasattr(param, "jaxpr"):
-                    visit(param.jaxpr)
-                elif isinstance(param, (tuple, list)):
-                    for item in param:
-                        if hasattr(item, "jaxpr"):
-                            visit(item.jaxpr)
             fl = _eqn_flops(eqn)
             if fl:
                 counts[name] = counts.get(name, 0) + fl

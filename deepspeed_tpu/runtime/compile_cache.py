@@ -23,7 +23,8 @@ per rewind-and-replay.  This module makes that a cached cost:
 Cache key anatomy (see docs/compile-cache.md) — everything that legally
 invalidates an executable:
 
-- jax/jaxlib versions, backend, device kind + count;
+- jax/jaxlib versions, backend, device kind and the executable's own
+  device assignment (the ids it runs on, not the host's device count);
 - the entry point's name and the engine's config slice (dtype, zero
   stage, gas, grad-accum dtype, clipping, scaler + health flags, mesh
   axes, offload devices — passed in by the caller as ``key_extra``);
@@ -37,12 +38,12 @@ invalidates an executable:
   that also captures remat policy, sharding constraints, and any model
   code change.
 
-NOTE: this is NOT jax's ``jax_compilation_cache_dir``.  That cache was
-measured returning executables whose donated-buffer aliasing mismatched
-the new trace on this container's jax 0.4.37 (see tests/conftest.py);
-``serialize_executable`` round-trips the executable object itself, so
-the alias map travels with the payload and is re-audited (DSTPU204) on
-warm-started engines.
+Placement (docs/compile-cache.md): :func:`cache_root` is the one
+directory every compile cache of this program lives under —
+``$JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else
+``<checkout>/.compile_cache``.  JAX's own persistent cache
+(:func:`use_persistent_cache`) takes the root itself; this store, when
+merely switched on (``DSTPU_COMPILE_CACHE=1``), takes ``<root>/aot``.
 """
 
 import hashlib
@@ -65,6 +66,10 @@ STATS_FILE = "last_run_stats.json"
 FORMAT_VERSION = 1
 ENV_DIR = "DSTPU_COMPILE_CACHE"
 _ENV_OFF = ("0", "off", "false", "no", "disabled")
+_ENV_ON = ("1", "on", "true", "yes", "enabled")
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 _MAX_EVENTS = 64
 
 # process-wide counters aggregated across every CompileCache instance —
@@ -80,11 +85,42 @@ def reset_global_stats():
         GLOBAL_STATS[k] = 0.0 if k.endswith("_ms") else 0
 
 
+def cache_root():
+    """The directory every compile cache of this program lives under:
+    ``$JAX_COMPILATION_CACHE_DIR`` when the environment places it (an
+    operator, CI, the chip driver), else ``<checkout>/.compile_cache``.
+    A FIXED path either way — the path is part of JAX's cache key, so a
+    directory that moves between runs (tempfile, pid, timestamp) never
+    hits."""
+    return os.environ.get(JAX_ENV_DIR) or os.path.join(_CHECKOUT,
+                                                       ".compile_cache")
+
+
+def aot_dir():
+    """This store's place under :func:`cache_root`."""
+    return os.path.join(cache_root(), "aot")
+
+
+def use_persistent_cache():
+    """Point JAX's own persistent compilation cache at :func:`cache_root`
+    (the value JAX already read from the environment, when set) and
+    return the directory.  Everything jitted afterwards — the engines'
+    steps, init, casts — is found again by the next process that
+    resolves the same root."""
+    root = cache_root()
+    jax.config.update("jax_compilation_cache_dir", root)
+    return root
+
+
 def resolve_env_dir():
-    """The env-configured cache dir, or None (incl. explicit-off values)."""
+    """The env-configured store dir, or None (incl. explicit-off
+    values).  ``DSTPU_COMPILE_CACHE=1`` switches the store on under
+    :func:`cache_root`; any other value is an explicit directory."""
     v = os.environ.get(ENV_DIR, "").strip()
     if not v or v.lower() in _ENV_OFF:
         return None
+    if v.lower() in _ENV_ON:
+        return aot_dir()
     return v
 
 
@@ -126,16 +162,11 @@ def args_signature(args, kwargs=None):
     return (treedef, tuple(map(_leaf_sig, leaves)))
 
 
-def _being_traced(args, kwargs):
+def _being_traced():
     """True while any jax trace is in progress (jax.make_jaxpr, an outer
-    jit).  One global flag read — no per-leaf scan on the hot path; a
-    tracer can only reach us while a trace is live.  Falls back to a
-    leaf scan on jax versions without ``trace_state_clean``."""
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:
-        return any(isinstance(l, jax.core.Tracer)
-                   for l in jax.tree_util.tree_leaves((args, kwargs)))
+    jit).  One thread-local read — no per-leaf scan on the hot path; a
+    tracer can only reach us while a trace is live."""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def build_key_material(name, args, lowered, key_extra=None, kwargs=None):
@@ -161,20 +192,31 @@ def build_key_material(name, args, lowered, key_extra=None, kwargs=None):
         logger.warning(f"compile cache: lowered.as_text failed ({e}); "
                        f"NOT caching {name} (program identity unavailable)")
         return None
-    devices = jax.devices()
+    devices = _execution_devices(lowered)
     material = {
         "v": FORMAT_VERSION,
         "name": name,
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
-        "devices": {"kind": devices[0].device_kind, "count": len(devices)},
+        # the executable's own device assignment: a program compiled for
+        # jax.devices()[:4] and one for all eight lower to the same text
+        "devices": {"kind": devices[0].device_kind,
+                    "ids": [d.id for d in devices]},
         "args": [list(map(str, fp)) for fp in fps],
         "dstpu205_weak_scalars": weak_scalars,
         "config": key_extra or {},
         "lowering_sha256": hashlib.sha256(low_text.encode()).hexdigest(),
     }
     return material
+
+
+def _execution_devices(lowered):
+    """The devices ``lowered`` was lowered for, in assignment order —
+    what its compiled executable must be loaded over.  (jax 0.9.0 keeps
+    the assignment on the MeshComputation; ``jax.stages.Lowered`` has no
+    public reader for it.)"""
+    return list(lowered._lowering._device_list)
 
 
 def key_from_material(material):
@@ -427,7 +469,7 @@ class CachedStep:
         return [k for _, k, _ in self._exes.values()]
 
     def __call__(self, *args, **kwargs):
-        if _being_traced(args, kwargs):
+        if _being_traced():
             # being traced (jax.make_jaxpr / an outer jit): stage the
             # underlying jit call, never the dispatch machinery
             return self._jit(*args, **kwargs)
@@ -439,8 +481,11 @@ class CachedStep:
             # one signature, so skip the per-call pytree flatten + sig
             # build.  Safe optimistically: Compiled.call validates avals
             # BEFORE executing (donated buffers are not consumed on a
-            # mismatch), so a new signature surfaces as TypeError and
-            # falls through to the full acquire below.
+            # mismatch), so a new shape/dtype surfaces as TypeError and
+            # falls through to the full acquire below.  (A SHARDING
+            # mismatch is a ValueError on jax 0.9.0 and propagates: the
+            # signature does not key on shardings, so it is the caller's
+            # error, not a new program.)
             (hit,) = self._exes.values()
             try:
                 return self._dispatch(hit, args, kwargs)
@@ -492,7 +537,8 @@ class CachedStep:
                                           self.key_extra, kwargs=kwargs)
         if material is not None:
             key = key_from_material(material)
-            exe = self._try_deserialize(cache, key)
+            exe = self._try_deserialize(cache, key,
+                                        _execution_devices(lowered))
             if exe is not None:
                 hit = (exe, key, "cache")
                 self._exes[sig] = hit
@@ -511,7 +557,7 @@ class CachedStep:
         self._exes[sig] = hit
         return hit
 
-    def _try_deserialize(self, cache, key):
+    def _try_deserialize(self, cache, key, devices):
         payload = cache.get(key)
         if payload is None:
             return None
@@ -519,7 +565,10 @@ class CachedStep:
         t0 = time.monotonic()
         try:
             ser, in_tree, out_tree = pickle.loads(payload)
-            exe = se.deserialize_and_load(ser, in_tree, out_tree)
+            # the loader binds to EVERY local device unless told which:
+            # an executable for a sub-mesh must go back onto its own
+            exe = se.deserialize_and_load(ser, in_tree, out_tree,
+                                          execution_devices=devices)
         except Exception as e:
             # unpicklable/incompatible payload (jaxlib drift the version
             # key missed, foreign-topology artifact): a miss, not a crash

@@ -190,7 +190,7 @@ class PipelineEngine(DeepSpeedEngine):
             is_last = s == S - 1
 
             def _vary_one(a):
-                if "pipe" in getattr(jax.typeof(a), "vma", frozenset()):
+                if "pipe" in jax.typeof(a).vma:
                     return a        # pcast rejects varying→varying
                 return lax.pcast(a, ("pipe",), to="varying")
             varying = lambda v: jax.tree_util.tree_map(_vary_one, v)

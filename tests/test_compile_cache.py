@@ -78,6 +78,56 @@ def test_readonly_mode_never_writes(tmp_path):
     assert os.path.isdir(os.path.join(d, key))
 
 
+def test_cache_root_is_placed_from_outside_or_fixed(monkeypatch, tmp_path):
+    """One variable places everything: with JAX_COMPILATION_CACHE_DIR set
+    the root IS that directory and the store switched on by
+    DSTPU_COMPILE_CACHE=1 sits inside it; unset, the root is the
+    checkout's .compile_cache — a fixed path, never a temporary one."""
+    import tempfile
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv(cc.JAX_ENV_DIR, raising=False)
+    assert cc.cache_root() == os.path.join(repo, ".compile_cache")
+    assert cc.cache_root() == cc.cache_root()          # no pid, no clock
+    assert not cc.cache_root().startswith(tempfile.gettempdir())
+    monkeypatch.setenv(cc.ENV_DIR, "1")
+    assert cc.resolve_env_dir() == os.path.join(repo, ".compile_cache", "aot")
+    monkeypatch.setenv(cc.JAX_ENV_DIR, str(tmp_path))
+    assert cc.cache_root() == str(tmp_path)
+    assert cc.resolve_env_dir() == cc.aot_dir() == str(tmp_path / "aot")
+    saved = jax.config.jax_compilation_cache_dir
+    try:
+        assert cc.use_persistent_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_store_round_trips_a_sub_mesh_executable(tmp_path, devices, n_dev):
+    """An executable compiled for jax.devices()[:n] must come back bound
+    to those n devices and dispatch warm — the loader's default is every
+    local device — and the two device assignments must key apart."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = make_mesh({"data": n_dev}, devices=devices[:n_dev])
+    x = jax.device_put(jnp.arange(8.0 * 3).reshape(8, 3),
+                       NamedSharding(mesh, P("data")))
+    keys = []
+    for expect in ("compile", "cache"):
+        cache = cc.CompileCache(str(tmp_path))
+        step = cc.wrap_step("double", lambda a: a * 2.0, cache=cache)
+        with jax.set_mesh(mesh):
+            out = step(x)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2)
+        assert len(out.sharding.device_set) == n_dev
+        assert [e["source"] for e in cache.events] == [expect]
+        assert cache.stats["corrupt"] == 0
+        keys += step.keys()
+    assert keys[0] == keys[1]
+    material = json.load(open(os.path.join(
+        str(tmp_path), keys[0], cc.KEY_FILE)))
+    assert material["devices"]["ids"] == [d.id for d in devices[:n_dev]]
+
+
 def test_env_kill_switch(monkeypatch, tmp_path):
     monkeypatch.setenv(cc.ENV_DIR, str(tmp_path))
     assert cc.resolve_env_dir() == str(tmp_path)

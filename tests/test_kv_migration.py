@@ -131,7 +131,7 @@ def test_block_image_digest_catches_tamper():
     src = _int8_pool()
     img = pk.export_block_image(src, [1, 3])
     img["k"] = np.array(img["k"], copy=True)
-    img["k"][0, 1, 0, 0, 0] ^= 0x7F
+    img["k"][0, 1, 0, 0] ^= 0x7F
     assert pk.verify_block_image(img) == [1]
     dst = pk.init_pool(2, 6, 8, 4, 8, jnp.float32, kv_bits=8)
     with pytest.raises(pk.BlockImageError, match="digest"):

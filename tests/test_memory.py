@@ -415,7 +415,8 @@ def test_serving_static_terms_latched():
 # ---------------------------------------------------------------------------
 
 def test_ds_mem_replay_reproduces_maxparams():
-    """``ds_mem --replay MAXPARAMS.json`` (the real CLI, a subprocess):
+    """``ds_mem --replay`` over the recorded max-params rungs
+    (``tests/data/maxparams_rungs.json``; the real CLI, a subprocess):
     the 1.3B rung's recorded 33.81 GB host-RSS HWM reproduces within
     ±10%, every recorded rung is within tolerance, and the model
     BRACKETS the measured ceiling — 2.65B fits the 125 GB host, the
@@ -423,7 +424,8 @@ def test_ds_mem_replay_reproduces_maxparams():
     between them."""
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "bin", "ds_mem"),
-         "--replay", os.path.join(REPO, "MAXPARAMS.json"), "--json"],
+         "--replay", os.path.join(REPO, "tests", "data",
+                                  "maxparams_rungs.json"), "--json"],
         capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr
     rep = json.loads(r.stdout)

@@ -6,10 +6,12 @@ The oracle is the legacy materialized path — ``paged_kv.gather_kv`` +
 exported exactly so the kernel has something to be tested against:
 
 - **exact mode** (the interpret/CPU fallback) must be BIT-exact on
-  16-bit pools (fp32/bf16/fp16) — that is what keeps CPU tier-1 exact
-  when the serving decode routes through the kernel — and is held to
-  the same bit-exactness on int8 pools (same dequant formula, same op
-  order);
+  bf16/fp16 pools — that is what keeps CPU tier-1 exact when the
+  serving decode routes through the kernel — and is held to the same
+  bit-exactness on int8 pools (same dequant formula, same op order).
+  On fp32 pools the contract is 4 ulp of the output scale: the
+  interpreter's (H, W, S) softmax and the oracle's (B, H, W, S) one
+  vectorize their fp32 row sums differently once W > 1;
 - **online mode** (the compiled-TPU online-softmax/DMA-ring variant,
   run here through the interpreter) is tolerance-bounded: it skips the
   oracle's probs→compute-dtype rounding, so agreement is to compute-
@@ -62,15 +64,16 @@ LENGTHS = np.asarray([31, 23, 8, 0, 0], np.int32)
 
 def _oracle(model, q, pool, tables, lengths, layer):
     keys, vals = pk.gather_kv(pool, layer, jnp.asarray(tables),
-                              q.dtype)
+                              q.dtype, H)
     return model._attend_paged(q, keys, vals, jnp.asarray(lengths))
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16, jnp.float32])
 @pytest.mark.parametrize("n_window", [1, 3])
 def test_exact_mode_bit_exact_16bit(dtype, n_window, devices):
-    """Exact mode == gather oracle, bit for bit, on 16-bit pools —
-    every length edge, partial last block, and the scratch slot."""
+    """Exact mode == gather oracle on full-width pools — bit for bit at
+    bf16/fp16, within 4 ulp of the output scale at fp32 — on every
+    length edge, partial last block, and the scratch slot."""
     model = _model(dtype)
     rng = np.random.default_rng(0)
     pool = _filled_pool(rng, dtype)
@@ -81,7 +84,12 @@ def test_exact_mode_bit_exact_16bit(dtype, n_window, devices):
         lambda q, p: paged_attention(q, p, TABLES, LENGTHS, 1,
                                      mode="exact"))(q, pool))
     assert out.dtype == ref.dtype
-    np.testing.assert_array_equal(out, ref)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(
+            out, ref, rtol=0,
+            atol=4 * np.finfo(np.float32).eps * np.abs(ref).max())
+    else:
+        np.testing.assert_array_equal(out, ref)
 
 
 @pytest.mark.parametrize("mode", ["exact", "online"])

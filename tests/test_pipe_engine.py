@@ -12,7 +12,6 @@ from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.runtime.pipe import PipelineModule, LayerSpec
 from deepspeed_tpu.runtime.pipe.engine import PipelineEngine
 from deepspeed_tpu.parallel.mesh import make_mesh
-from deepspeed_tpu.utils import jax_compat
 
 DIM = 16
 N_LAYERS = 8
@@ -241,11 +240,6 @@ def test_gpt2_pipeline_trains(devices):
     assert np.mean(losses[-2:]) < np.mean(losses[:2])
 
 
-@pytest.mark.skipif(
-    jax_compat.SHARD_MAP_FULL_MANUAL_FALLBACK,
-    reason="old-jax shard_map fallback replicates the data axis, so "
-           "per-device temp-memory thresholds calibrated for sharded "
-           "inputs do not apply")
 def test_pipe_1f1b_memory_bounded(devices):
     """1F1B property: live activation memory is O(S), independent of the
     micro-batch count M (reference ``schedule.py:243 num_pipe_buffers``).

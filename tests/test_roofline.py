@@ -68,8 +68,13 @@ def test_attribute_names_gather_bytes_and_scales_chips():
 def test_chip_specs_resolves_and_falls_back():
     row = chip_specs("TPU v5p chip")
     assert row["matched"] == "v5p" and row["hbm_gb_s"] == 2765.0
+    assert chip_specs("TPU v5 lite")["matched"] == "v5 lite"   # the v5e
     nominal = chip_specs("cpu")
     assert nominal["matched"] == "v5e" and nominal.get("nominal") is True
+    assert chip_specs().get("nominal") is True     # this backend: the CPU
+    # a TPU the table does not know is an error, never priced as a v5e
+    with pytest.raises(ValueError, match="no CHIP_TABLE row"):
+        chip_specs("TPU v9 mega")
     # every table row carries all three roofline denominators
     for kind, spec in CHIP_TABLE.items():
         assert {"peak_bf16_flops", "hbm_gb_s", "ici_gb_s"} <= set(spec)
