@@ -80,6 +80,7 @@ import jax.numpy as jnp
 
 from . import paged_kv as pk
 from .. import fault
+from ..monitor import spans as monspans
 from ..monitor.histogram import LogHistogram
 from ..monitor.ring import RingBuffer
 from ..runtime.health import rows_nonfinite, write_forensics
@@ -735,11 +736,15 @@ class ServingEngine:
         # bounded ring of recent terminal outcomes: the poison-rate
         # window AND the breaker's forensic payload (PR-9 RingBuffer)
         self._recent = RingBuffer(max(1, int(config.poison_window)))
-        # ---- request tracing (docs/monitoring.md#request-tracing) ----
-        # host-side only: uid -> open trace record; nothing here touches
-        # the compiled step (--audit-step tracing proves jaxpr equality
-        # armed vs disarmed).  Disarmed = one boolean check per call.
-        self._traces: Dict[int, dict] = {}
+        # ---- spans + request tracing (docs/monitoring.md) ------------
+        # host-side only; nothing here touches the compiled step
+        # (--audit-step tracing proves jaxpr equality armed vs disarmed).
+        # The process-wide span recorder records armed or not.
+        self._spans = monspans.recorder()
+        # sampled live requests -> wall-clock submit time (the `trace`
+        # event's anchor; everything else it carries is in results[uid])
+        self._traces: Dict[int, float] = {}
+        self._token_stamp = None      # the clock read of the newest step
         self._traces_emitted = 0
         self._exe_cost_emitted = False
         self.journal = None
@@ -776,9 +781,9 @@ class ServingEngine:
         for uid, rec in state["finished"].items():
             self.results[uid] = {
                 "tokens": rec.get("tokens"), "outcome": rec.get("outcome"),
-                "t_submit": None, "t_first": None,
-                "t_done": rec.get("t", 0.0), "prompt_len": None,
-                "deadline": None, "recovered": True}
+                "t_submit": None, "t_admit": None, "t_first": None,
+                "t_tokens": None, "t_done": rec.get("t", 0.0),
+                "prompt_len": None, "deadline": None, "recovered": True}
         for spec in state["pending"]:
             dl_ms = spec.get("deadline_ms")
             if dl_ms == "inf":     # journal spelling of float("inf")
@@ -804,8 +809,8 @@ class ServingEngine:
                     f"finalized as '{SHED}'")
                 self.results[req.uid] = {
                     "tokens": None, "outcome": None, "t_submit": None,
-                    "t_first": None, "t_done": None,
-                    "prompt_len": None, "deadline": None,
+                    "t_admit": None, "t_first": None, "t_tokens": None,
+                    "t_done": None, "prompt_len": None, "deadline": None,
                     "recovered": True}
                 self._finalize_unseated(
                     req, SHED, "recovery: no longer fits this "
@@ -1038,15 +1043,20 @@ class ServingEngine:
             # (retry exhausted), submit raises with nothing enqueued,
             # so the caller's view ("acceptance failed") stays true
             self.journal.submit(req, deadline_ms=dl_ms)
+        # the request's lifecycle stamps, all on time.monotonic():
+        # t_submit <= t_admit (seated or refused; the terminal time for a
+        # request never seated) <= t_first == t_tokens[0] <= ... <= t_done,
+        # one t_tokens entry per emitted token
         now = time.monotonic()
         self.results[req.uid] = {"tokens": None, "outcome": None,
-                                 "t_submit": now,
-                                 "t_first": None, "t_done": None,
+                                 "t_submit": now, "t_admit": None,
+                                 "t_first": None, "t_tokens": None,
+                                 "t_done": None,
                                  "prompt_len": int(toks.size),
                                  "deadline": (now + dl_ms / 1e3
                                               if dl_ms is not None else None)}
         if self._tracing and self._trace_sampled(req.uid):
-            self._trace_open(req.uid, int(toks.size), now)
+            self._traces[req.uid] = time.time()
         self.queue.append(req)
         return req.uid
 
@@ -1058,21 +1068,23 @@ class ServingEngine:
         rec["tokens"] = None
         rec["outcome"] = outcome
         rec["t_done"] = time.monotonic()
+        if rec["t_admit"] is None:
+            rec["t_admit"] = rec["t_done"]
         self._outcomes[outcome] += 1
         self._recent.append({"uid": req.uid, "outcome": outcome,
                              "why": why, "t": time.time()})
-        self._trace_finish(req.uid, outcome)
+        self._record_request(req.uid, rec)
         if self.journal is not None:
             self.journal.finish(req.uid, outcome, None)
 
     # ------------------------------------------------------- request tracing
-    # Host-side only (docs/monitoring.md#request-tracing): every sampled
-    # request accumulates spans relative to its submit instant — queue
-    # wait, prefill, one span per decode step — and emits ONE schema-v2
-    # `trace` event at its terminal outcome.  Nothing here is visible to
-    # jit: the compiled decode step is byte-identical armed vs disarmed
-    # (--audit-step tracing), and a disarmed engine pays one boolean
-    # check per call site.
+    # Host-side only (docs/monitoring.md#request-tracing).  Every request's
+    # lifecycle stamps live in results[uid]; at its terminal outcome they
+    # become one `serving.request` row of the span recorder, and, for a
+    # sampled request under an armed monitor, ONE schema-v2 `trace` event
+    # BUILT from the same stamps — queue wait, prefill, one decode span a
+    # token.  Nothing here is visible to jit: the compiled decode step is
+    # byte-identical armed vs disarmed (--audit-step tracing).
 
     @property
     def _tracing(self) -> bool:
@@ -1088,46 +1100,65 @@ class ServingEngine:
         return ((uid * 2654435761) & 0xFFFFFFFF) < (
             self.config.trace_sample_rate * 4294967296.0)
 
-    def _trace_open(self, uid: int, prompt_len: int, m_now: float):
-        self._traces[uid] = {"uid": uid, "t0_unix": time.time(),
-                             "m0": m_now, "prompt_len": prompt_len,
-                             "spans": []}
+    def _record_request(self, uid: int, rec: dict):
+        """The terminal outcome of a request: its whole life as one
+        ``serving.request`` row, and its ``trace`` event if sampled."""
+        if rec["t_submit"] is not None:     # else another process's life
+            self._spans.record(
+                "serving.request", rec["t_submit"], rec["t_done"],
+                step=self._steps, uid=uid,
+                attrs={"outcome": rec["outcome"],
+                       "prompt_len": rec["prompt_len"],
+                       "t_admit": rec["t_admit"], "t_first": rec["t_first"],
+                       "t_tokens": rec["t_tokens"]})
+        t0_unix = self._traces.pop(uid, None)
+        if t0_unix is not None:
+            self._emit_trace(uid, rec, t0_unix)
 
-    def _trace_span(self, uid: int, name: str, start_m: float,
-                    dur_s: float, step: Optional[int] = None):
-        tr = self._traces.get(uid)
-        if tr is None:
-            return
-        span = {"name": name, "start_ms": (start_m - tr["m0"]) * 1e3,
-                "dur_ms": dur_s * 1e3}
-        if step is not None:
-            span["step"] = step
-        tr["spans"].append(span)
+    def _emit_trace(self, uid: int, rec: dict, t0_unix: float):
+        t_submit, t_admit, t_first = (rec["t_submit"], rec["t_admit"],
+                                      rec["t_first"])
 
-    def _trace_finish(self, uid: int, outcome: str, generated: int = 0):
-        tr = self._traces.pop(uid, None)
-        if tr is None:
-            return
-        m_now = time.monotonic()
-        if not tr["spans"]:
-            # never seated (shed / deadline at admit): its whole life
-            # was queue wait
-            tr["spans"].append({"name": "queue_wait", "start_ms": 0.0,
-                                "dur_ms": (m_now - tr["m0"]) * 1e3})
-        qw = next((s for s in tr["spans"] if s["name"] == "queue_wait"),
-                  None)
-        rec = self.results.get(uid) or {}
-        ttft = None
-        if rec.get("t_first") is not None and rec.get("t_submit") is not None:
-            ttft = (rec["t_first"] - rec["t_submit"]) * 1e3
+        def span(name, start, end, **extra):
+            return {"name": name, "start_ms": (start - t_submit) * 1e3,
+                    "dur_ms": (end - start) * 1e3, **extra}
+
+        stamps = rec["t_tokens"] or []
+        spans = [span("queue_wait", t_submit, t_admit)]
+        if t_admit < rec["t_done"]:
+            # seated: prefill runs to the first token (to the end, for a
+            # request quarantined or evicted before it had one)
+            spans.append(span("prefill", t_admit, t_first if t_first
+                              is not None else rec["t_done"]))
+        steps = self._steps_of(stamps)
+        for prev, t in zip(stamps, stamps[1:]):
+            step = steps.get(t)
+            spans.append(span("decode", prev, t,
+                              **({} if step is None else {"step": step})))
         self.monitor.trace(
-            "request", step=self._steps, uid=uid, outcome=outcome,
-            t0_unix=tr["t0_unix"], prompt_len=tr["prompt_len"],
-            generated=generated,
-            queue_wait_ms=(qw["dur_ms"] if qw is not None else None),
-            ttft_ms=ttft, total_ms=(m_now - tr["m0"]) * 1e3,
-            spans=tr["spans"])
+            "request", step=self._steps, uid=uid, outcome=rec["outcome"],
+            t0_unix=t0_unix, prompt_len=rec["prompt_len"],
+            generated=len(rec["tokens"] or ()),
+            queue_wait_ms=spans[0]["dur_ms"],
+            ttft_ms=((t_first - t_submit) * 1e3 if t_first is not None
+                     else None),
+            total_ms=(rec["t_done"] - t_submit) * 1e3, spans=spans)
         self._traces_emitted += 1
+
+    def _steps_of(self, stamps) -> dict:
+        """The scheduler step that emitted each token stamp after the
+        first: every token of a step carries that step's one clock read,
+        which the step's ``serving.step`` row keeps (``t_tokens``); the
+        step still open is the current one.  A stamp whose row the ring no
+        longer holds is left out."""
+        steps = {self._token_stamp: self._steps}
+        if len(stamps) > 1:
+            for row in self._spans.newest_first():
+                if row.t_end < stamps[1]:
+                    break
+                if row.name == "serving.step" and row.attrs:
+                    steps[row.attrs["t_tokens"]] = row.step
+        return steps
 
     # ---------------------------------------------------------- jitted steps
     def _decode_args(self, toks=None):
@@ -1421,36 +1452,36 @@ class ServingEngine:
     def _start(self, slot: int, req: Request, blocks: List[int], new: int,
                share: Optional[dict] = None):
         fault.site("serving.prefill")
-        tr = self._traces.get(req.uid)
-        m_admit = time.monotonic() if tr is not None else 0.0
-        if tr is not None:
-            # queue wait ends the instant this request is seated
-            self._trace_span(req.uid, "queue_wait", tr["m0"],
-                             m_admit - tr["m0"])
-        if share is not None:
-            self._start_shared(slot, req, blocks, new, share)
-            return
         c = self.config
         T = int(len(req.tokens))
-        bucket = pk.blocks_needed(T, c.block_size) * c.block_size
-        toks = np.zeros((1, min(bucket, self.max_seq)), np.int32)
-        toks[0, :T] = req.tokens
-        nb_pre = bucket // c.block_size
-        blk = jnp.asarray(np.asarray(blocks[:nb_pre], np.int32))
-        fn = self._prefill_fn(bucket)
-        with jax.set_mesh(self.engine.mesh):
-            with self.monitor.span("prefill"):
-                first, bad, self.pool = fn(
-                    self.engine.params, jnp.asarray(toks), self.pool, blk,
-                    jnp.int32(T), jnp.int32(req.seed),
-                    jnp.float32(req.temperature), jnp.asarray(req.do_sample))
-        first = int(np.asarray(first))
-        if tr is not None:
-            # the int() above synced the prefill dispatch: this bracket
-            # is a true prefill cost, starting where queue_wait ended
-            self._trace_span(req.uid, "prefill", m_admit,
-                             time.monotonic() - m_admit)
-        if bool(np.asarray(bad)):
+        rec = self.results[req.uid]
+        with self._spans.span("serving.prefill", uid=req.uid) as prefill:
+            # queue wait ends the instant this request is seated
+            rec["t_admit"] = prefill.t0
+            if share is not None:
+                prefill.attrs = {"prompt_len": T,
+                                 "shared_blocks": share["ns"]}
+                self._start_shared(slot, req, blocks, new, share)
+                return
+            bucket = pk.blocks_needed(T, c.block_size) * c.block_size
+            prefill.attrs = {"prompt_len": T, "bucket": bucket}
+            toks = np.zeros((1, min(bucket, self.max_seq)), np.int32)
+            toks[0, :T] = req.tokens
+            nb_pre = bucket // c.block_size
+            blk = jnp.asarray(np.asarray(blocks[:nb_pre], np.int32))
+            fn = self._prefill_fn(bucket)
+            with jax.set_mesh(self.engine.mesh):
+                with self._spans.span("serving.prefill.dispatch"):
+                    first, bad, self.pool = fn(
+                        self.engine.params, jnp.asarray(toks), self.pool,
+                        blk, jnp.int32(T), jnp.int32(req.seed),
+                        jnp.float32(req.temperature),
+                        jnp.asarray(req.do_sample))
+            # the reads sync the prefill dispatch: the host waits here
+            with self._spans.span("serving.prefill.readback"):
+                first = int(np.asarray(first))
+                bad = bool(np.asarray(bad))
+        if bad:
             # quarantined AT prefill: the slot is never seated, the
             # sentinel token is never surfaced, and the blocks go back
             # scrubbed (prompt K/V of a poisoned forward may be
@@ -1484,8 +1515,8 @@ class ServingEngine:
         self._ngen[slot] = 1
         self._temps[slot] = req.temperature
         self._flags[slot] = req.do_sample
-        rec = self.results[req.uid]
         rec["t_first"] = time.monotonic()
+        rec["t_tokens"] = [rec["t_first"]]
         if new == 1 or first == c.eos_token_id:
             self._finish(slot)
         elif fault.poison_uid(req.uid):
@@ -1552,6 +1583,7 @@ class ServingEngine:
         self._toks[slot] = prompt[pos0]
         self._seeds[slot] = req.seed
         self._ngen[slot] = 0            # no token emitted yet
+        self.results[req.uid]["t_tokens"] = []
         self._temps[slot] = req.temperature
         self._flags[slot] = req.do_sample
         self._prefix_hits_total += 1
@@ -1926,8 +1958,10 @@ class ServingEngine:
         self._temps[slot] = req.temperature
         self._flags[slot] = req.do_sample
         self._snap_last[slot] = len(out_tokens)
+        # the restored tokens all arrive with the image: one stamp
         rec = self.results[req.uid]
-        rec["t_first"] = time.monotonic()
+        rec["t_admit"] = rec["t_first"] = time.monotonic()
+        rec["t_tokens"] = [rec["t_first"]] * len(out_tokens)
         if (len(out_tokens) >= new
                 or out_tokens[-1] == self.config.eos_token_id):
             # a snapshot taken exactly at the stream's end (an
@@ -2225,8 +2259,7 @@ class ServingEngine:
             if rec["t_first"] is not None:
                 self._ttft_hist.add(
                     (rec["t_first"] - rec["t_submit"]) * 1e3)
-        self._trace_finish(s.req.uid, outcome,
-                           generated=len(s.out_tokens))
+        self._record_request(s.req.uid, rec)
         if self.journal is not None:
             self.journal.finish(s.req.uid, outcome, rec["tokens"])
         if not (self.kvs is not None and self.kvs.export_on_evict
@@ -2334,22 +2367,32 @@ class ServingEngine:
         if not self._preflight_done:
             self._preflight_gate()
         fault.site("serving.step")
+        # the root span of this step (monitor/spans.py), recorded whether
+        # or not a monitor is armed; its number is the step's, if it decodes
+        root = self._spans.open("serving.step", step=self._steps + 1)
+        try:
+            return self._step(root)
+        finally:
+            self._spans.close(root)      # nothing, after an idle poll
+
+    def _step(self, root) -> bool:
+        spans = self._spans
         mon = self.monitor
-        mon.begin_step()
+        mon.begin_step(root)
         if self._txq is not None and self.role == "decode":
             # BEFORE _admit: a restore fallback re-queues its request,
             # and this same step's admission must pick it up (otherwise
             # the livelock guard below would see a queued request no
             # admission pass ever looked at)
-            with mon.span("kv_transfer"):
+            with spans.span("serving.kv_transfer"):
                 self._admit_transfers()
-        with mon.span("admit"):
+        with spans.span("serving.admit"):
             self._admit()
         published = 0
         if self._txq is not None and self.role == "prefill":
             # AFTER _admit: slots seated by this step's prefill publish
             # immediately — the handoff adds zero decode-step latency
-            with mon.span("kv_transfer"):
+            with spans.span("serving.kv_transfer"):
                 published = self._publish_transfers()
         active = [i for i, s in enumerate(self._slots) if s is not None]
         if not active:
@@ -2363,8 +2406,9 @@ class ServingEngine:
                 # with the head's block math instead
                 self._raise_stalled()
             # idle poll: nothing decoded — discard the bracket instead of
-            # emitting spans under a reused step number
+            # recording and emitting spans under a reused step number
             mon.abort_step()
+            spans.discard(root)
             if self.journal is not None:
                 self.journal.flush()
             return bool(self.queue)
@@ -2376,7 +2420,7 @@ class ServingEngine:
             # a pure host-side function of the request (module
             # docstring: determinism survives), proposed as runtime
             # operands so the compiled step never re-specializes
-            with mon.span("draft"):
+            with spans.span("serving.draft"):
                 toks_win = np.repeat(self._toks[:, None], spec.k + 1,
                                      axis=1)
                 for i in active:
@@ -2394,20 +2438,21 @@ class ServingEngine:
                     else:
                         toks_win[i, 1:] = ngram_draft(
                             s.hist[-DRAFT_WINDOW:], spec.k, spec.ngram)
-        t0 = time.perf_counter()
-        m_step = time.monotonic()      # decode-step span base (tracing)
         with jax.set_mesh(self.engine.mesh):
-            with mon.span("dispatch"):
+            # the small host arrays go up on their own bracket, so that
+            # "dispatch" is the call into the executable alone
+            with spans.span("serving.upload") as upload:
+                args = self._decode_args(toks=toks_win)
+            with spans.span("serving.dispatch"):
                 if spec is not None:
-                    out, accept_len, nonfin, self.pool = self._decode(
-                        *self._decode_args(toks=toks_win))
+                    out, accept_len, nonfin, self.pool = self._decode(*args)
                 else:
-                    nxt, poisoned, self.pool = \
-                        self._decode(*self._decode_args())
+                    nxt, poisoned, self.pool = self._decode(*args)
         if self._kv_warm_pending:
             self._kv_warm_pending = False
             self._warm_restore_path()
-        with mon.span("sample_join"):
+        # the host's wait for the device, alone on its bracket
+        with spans.span("serving.readback") as readback:
             if spec is not None:
                 out = np.asarray(out)                   # (B, k+1)
                 accept_len = np.asarray(accept_len)     # (B,)
@@ -2418,30 +2463,29 @@ class ServingEngine:
                 out = np.asarray(nxt)[:, None]
                 nonfin = np.asarray(poisoned)[:, None]
                 accept_len = np.ones((out.shape[0],), np.int64)
-            # the value read above synced the dispatch: this wall time is
-            # a true decode-step cost, the predictive-deadline EMA's input
-            dt = time.perf_counter() - t0
-            self._step_wall_hist.add(dt * 1e3)
-            self._step_last_s = dt
-            if self._step_ema_s is None:
-                self._step_ema_s = dt
-            elif dt < self._step_ema_s:
-                # adapt DOWN fast: one compile-heavy outlier step decays
-                # in a few iterations instead of poisoning the
-                # predictive-deadline gate for a long tail
-                self._step_ema_s = 0.5 * self._step_ema_s + 0.5 * dt
-            else:
-                self._step_ema_s = 0.7 * self._step_ema_s + 0.3 * dt
-            self._steps += 1
+        # the value read above synced the dispatch: from the upload's start
+        # to the read's end is a true decode-step cost, the predictive-
+        # deadline EMA's input (the spans' own clock reads, no others)
+        dt = readback.t1 - upload.t0
+        self._step_wall_hist.add(dt * 1e3)
+        self._step_last_s = dt
+        if self._step_ema_s is None:
+            self._step_ema_s = dt
+        elif dt < self._step_ema_s:
+            # adapt DOWN fast: one compile-heavy outlier step decays
+            # in a few iterations instead of poisoning the
+            # predictive-deadline gate for a long tail
+            self._step_ema_s = 0.5 * self._step_ema_s + 0.5 * dt
+        else:
+            self._step_ema_s = 0.7 * self._step_ema_s + 0.3 * dt
+        self._steps += 1
+        with spans.span("serving.bookkeeping"):
             c = self.config
-            now = time.monotonic()
+            # one clock read stamps every token this step emitted
+            now = self._token_stamp = time.monotonic()
             emitted_step = 0
             for i in active:
                 s = self._slots[i]
-                if self._traces:
-                    # one span per decode step this request was live in
-                    self._trace_span(s.req.uid, "decode", m_step, dt,
-                                     step=self._steps)
                 if s.pending:
                     # prompt ingestion (prefix sharing): the committed
                     # columns wrote prompt K/V — their samples are
@@ -2508,12 +2552,13 @@ class ServingEngine:
                     self._spec_accepted_total += used
                 s.out_tokens.extend(plan)
                 s.hist.extend(plan)
+                rec = self.results[s.req.uid]
+                rec["t_tokens"].extend([now] * emitted)
                 if was_ingest and plan:
                     # first token of a prefix-HIT request: TTFT stamps
                     # here (the plain path stamps it at prefill) — by
                     # construction one decode step after the suffix
                     # finished ingesting, i.e. the new-suffix cost
-                    rec = self.results[s.req.uid]
                     if rec["t_first"] is None:
                         rec["t_first"] = now
                 if poisoned_here:
@@ -2525,7 +2570,7 @@ class ServingEngine:
                 self._lengths[i] += emitted
                 self._ngen[i] += emitted
                 self._toks[i] = s.out_tokens[-1]
-                dl = self.results[s.req.uid]["deadline"]
+                dl = rec["deadline"]
                 if dl is not None and now >= dl:
                     # mid-decode deadline: evict with the partial tokens
                     # — the slot goes back to work that can still meet
@@ -2539,7 +2584,7 @@ class ServingEngine:
                     # cadence (docs/serving.md#kv-migration) — host-side
                     # export + atomic commit; the compiled step above
                     # never changes
-                    with mon.span("kv_snapshot"):
+                    with spans.span("serving.kv_snapshot"):
                         self._snapshot_slot_safe(i)
             if spec is not None and active:
                 # tokens-per-step EMA: the predictive deadline gate's
@@ -2549,11 +2594,14 @@ class ServingEngine:
                     rate if self._spec_rate_ema is None
                     else 0.7 * self._spec_rate_ema + 0.3 * rate)
         if self.journal is not None:
-            with mon.span("journal"):
+            with spans.span("serving.journal"):
                 # ONE buffered append per scheduler step (admits +
                 # finishes); submits flushed eagerly at submit()
                 self.journal.flush()
-        self._monitor_finish(len(active), tokens=emitted_step)
+        with spans.span("serving.telemetry"):
+            self._monitor_finish(len(active), tokens=emitted_step)
+        root.attrs = {"n_active": len(active), "emitted": emitted_step,
+                      "t_tokens": now}
         return True
 
     def _raise_stalled(self):

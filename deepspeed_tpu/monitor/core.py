@@ -12,8 +12,8 @@ Hot-path discipline (the <2% overhead guarantee, docs/monitoring.md):
   read host state.  ``--audit-step monitor`` asserts zero DSTPU201 host
   callbacks and the jaxpr-equality test pins monitor-on == monitor-off.
 - **Interval thinning.**  ``monitor.interval`` emits every Nth step;
-  off-interval steps pay only the span bracket cost (two clock reads per
-  span).
+  off-interval steps pay only what every step pays, armed or not: the
+  span brackets of the process-wide recorder (``monitor/spans.py``).
 
 Disabled monitoring is a :class:`NullMonitor` — shared no-op context
 managers, no bus, nothing allocated per step.
@@ -27,7 +27,7 @@ from ..utils.logging import logger
 from .bus import MonitorBus
 from .events import _scalar
 from .sinks import RingBufferSink, SinkUnavailable, make_sink
-from .spans import SpanRecorder
+from . import spans as _spans
 from .trace import TraceWindow
 
 DEFAULT_RUN_DIR = "ds_monitor"
@@ -83,7 +83,7 @@ class NullMonitor:
     def standalone_span(self, name):
         return _NULL_CTX
 
-    def begin_step(self):
+    def begin_step(self, root=None):
         pass
 
     def abort_step(self):
@@ -176,7 +176,7 @@ class Monitor:
         # "use the consumer's role default", 0 disables the ledger
         self.memory_interval = (None if memory_interval is None
                                 else int(memory_interval))
-        self.spans = SpanRecorder()
+        self.spans = _spans.recorder()   # the process-wide one
         self.ring = None
         built = []
         rank0 = _is_rank0()
@@ -216,7 +216,8 @@ class Monitor:
                 os.path.join(run_dir or DEFAULT_RUN_DIR, "traces"),
                 start, stop)
         self._rates = {}              # tokens_per_step/flops_per_step/peak
-        self._root = None
+        self._root = None             # the open step's root span
+        self._owns_root = False       # opened here, not by an engine
         self._pending = []            # lagged step-event queue
         self._tail = None             # newest interval-thinned step (the
         #                               flush-at-close fix: a 7-step run
@@ -235,35 +236,36 @@ class Monitor:
 
     @contextmanager
     def standalone_span(self, name):
-        """Span outside any step (checkpoint commit, eval): timed here,
-        emitted immediately."""
-        t0 = time.perf_counter()
+        """Span outside any step (checkpoint commit, eval): recorded like
+        any other, emitted immediately."""
+        rec = self.spans.open(name, step=self._last_step)
         try:
             yield
         finally:
-            self.bus.span(name, time.perf_counter() - t0,
-                          step=self._last_step)
+            self.bus.span(name, self.spans.close(rec), step=self._last_step)
 
     # ---------------------------------------------------------------- steps
-    def begin_step(self):
-        if self._root is not None:
+    def begin_step(self, root=None):
+        """Start a step.  An engine hands in the root span it has opened
+        in the recorder (it records with or without a monitor, and closes
+        the root itself); without one the monitor opens and owns a root
+        called ``step``."""
+        if self._root is not None and self._owns_root:
             # a step aborted mid-flight (exception between begin and
-            # end): drop its partial spans instead of folding its clock
-            # into this step
-            self.spans.reset()
-            self._root = None
-        self.spans.drain()            # drop strays from aborted steps
-        self._root = self.spans.open("step")
+            # end): its partial spans must not fold into this step
+            self.spans.discard(self._root)
+        self._owns_root = root is None
+        self._root = self.spans.open("step") if root is None else root
 
     def abort_step(self):
-        """Close an open root span and DISCARD its spans — for idle or
-        aborted iterations that must not emit (a serving scheduler poll
-        with no active slots would otherwise overwrite the last real
-        step's breakdown under a reused step number)."""
-        if self._root is not None:
-            self.spans.close(self._root)
-            self._root = None
-            self.spans.drain()
+        """Forget the open step WITHOUT emitting — for idle or aborted
+        iterations (a serving scheduler poll with no active slots would
+        otherwise overwrite the last real step's breakdown under a reused
+        step number).  A root the monitor opened is discarded with its
+        spans; an engine discards its own."""
+        if self._root is not None and self._owns_root:
+            self.spans.discard(self._root)
+        self._root = None
 
     def should_emit(self, step_no) -> bool:
         """True when this step's events would actually land somewhere:
@@ -284,14 +286,24 @@ class Monitor:
 
     def end_step(self, step_no, scalars=None, gauges=None, counters=None,
                  name="train_step"):
-        """Close the step's root span and emit (span events + rate gauges
-        now; the scalar ``step`` event one step late).  Returns the
-        step's completed spans (the ``wall_clock_breakdown`` feed)."""
-        if self._root is None:
+        """End the step and emit (span events + rate gauges now; the
+        scalar ``step`` event one step late).  The step's wall time runs
+        from its root's start to this call: a root the monitor opened is
+        closed here, an engine's stays open for the engine to close.
+        Returns the step's completed spans as ``(name, parent, dur_s)``,
+        root last, names without the root's layer prefix (the
+        ``wall_clock_breakdown`` feed and the ``span`` events)."""
+        root = self._root
+        if root is None:
             return []
-        wall = self.spans.close(self._root)
         self._root = None
-        done = self.spans.drain()
+        rows = self.spans.since(root)
+        wall = (self.spans.close(root) if self._owns_root
+                else self.spans.now() - root.t0)
+        done = [(_spans.leaf(r.name, root.name),
+                 _spans.leaf(r.parent, root.name), r.t_end - r.t_start)
+                for r in rows if r.parent is not None]
+        done.append((_spans.leaf(root.name, root.name), None, wall))
         self._last_step = step_no
         self.steps_seen += 1
         if not self.should_emit(step_no):
@@ -305,9 +317,8 @@ class Monitor:
                 self._trace_after(step_no)
             return done
         self._tail = None
-        for s in done:
-            self.bus.span(s["name"], s["dur_s"], step=step_no,
-                          parent=s["parent"])
+        for sname, parent, dur_s in done:
+            self.bus.span(sname, dur_s, step=step_no, parent=parent)
         self._emit_rate_gauges(step_no, wall)
         for gname, gval in (gauges or {}).items():
             self.bus.gauge(gname, gval, step=step_no)

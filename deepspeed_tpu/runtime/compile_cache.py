@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from ..checkpoint import atomic
+from ..monitor import spans
 from ..utils.logging import logger, log_dist
 
 PAYLOAD_FILE = "payload.bin"
@@ -526,9 +527,13 @@ class CachedStep:
 
     # ----------------------------------------------------------- internals
     def _acquire(self, args, kwargs, sig):
-        t0 = time.monotonic()
-        lowered = self._jit.lower(*args, **kwargs)
-        lower_ms = (time.monotonic() - t0) * 1000
+        # the compile.* spans nest under whatever step called for the
+        # executable, which is how an in-window recompile names its step;
+        # lower_ms / compile_ms / deserialize_ms are their durations
+        rec = spans.recorder()
+        with rec.span("compile.lower", attrs={"fn": self.name}) as span:
+            lowered = self._jit.lower(*args, **kwargs)
+        lower_ms = (span.t1 - span.t0) * 1000
         cache = self.cache
         material = None
         if cache is not None:
@@ -545,40 +550,45 @@ class CachedStep:
                 return hit
         else:
             key = "<uncached>"
-        t1 = time.monotonic()
-        compiled = lowered.compile()
-        compile_ms = (time.monotonic() - t1) * 1000
+        with rec.span("compile.build",
+                      attrs={"fn": self.name, "source": "compile"}) as span:
+            compiled = lowered.compile()
+            if material is not None:
+                self._try_serialize(cache, key, compiled, material)
+        compile_ms = (span.t1 - span.t0) * 1000      # compile + serialise
         if material is not None:
             cache._count("misses")
             cache._count("compile_ms", compile_ms)
-            self._try_serialize(cache, key, compiled, material)
             cache.record_event(self.name, key, "compile", compile_ms)
         hit = (compiled, key, "compile")
         self._exes[sig] = hit
         return hit
 
     def _try_deserialize(self, cache, key, devices):
-        payload = cache.get(key)
-        if payload is None:
-            return None
-        from jax.experimental import serialize_executable as se
-        t0 = time.monotonic()
-        try:
-            ser, in_tree, out_tree = pickle.loads(payload)
-            # the loader binds to EVERY local device unless told which:
-            # an executable for a sub-mesh must go back onto its own
-            exe = se.deserialize_and_load(ser, in_tree, out_tree,
-                                          execution_devices=devices)
-        except Exception as e:
-            # unpicklable/incompatible payload (jaxlib drift the version
-            # key missed, foreign-topology artifact): a miss, not a crash
-            cache._count("corrupt")
-            cache.invalidate(key)
-            logger.warning(f"compile cache: could not deserialize entry "
-                           f"{key[:16]} ({type(e).__name__}: {e}); "
-                           "falling back to a fresh compile")
-            return None
-        ms = (time.monotonic() - t0) * 1000
+        rec = spans.recorder()
+        with rec.span("compile.load",
+                      attrs={"fn": self.name, "source": "cache"}) as span:
+            payload = cache.get(key)
+            if payload is None:
+                rec.discard(span)    # nothing stored: a lookup, not a load
+                return None
+            from jax.experimental import serialize_executable as se
+            try:
+                ser, in_tree, out_tree = pickle.loads(payload)
+                # the loader binds to EVERY local device unless told which:
+                # an executable for a sub-mesh must go back onto its own
+                exe = se.deserialize_and_load(ser, in_tree, out_tree,
+                                              execution_devices=devices)
+            except Exception as e:
+                # unpicklable/incompatible payload (jaxlib drift the version
+                # key missed, foreign-topology artifact): a miss, not a crash
+                cache._count("corrupt")
+                cache.invalidate(key)
+                logger.warning(f"compile cache: could not deserialize entry "
+                               f"{key[:16]} ({type(e).__name__}: {e}); "
+                               "falling back to a fresh compile")
+                return None
+        ms = (span.t1 - span.t0) * 1000              # read + deserialise
         cache._count("hits")
         cache._count("deserialize_ms", ms)
         cache.record_event(self.name, key, "cache", ms, len(payload))

@@ -26,6 +26,7 @@ gradient-accumulation boundary.
 import json
 import os
 import time
+from contextlib import contextmanager
 from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -160,6 +161,8 @@ class DeepSpeedEngine:
             # bus-less monitor (no sinks, nothing written) so the span
             # recorder feeds the named-timer breakdown log
             self.monitor = moncore.Monitor(run_dir=None, sinks=())
+        from ..monitor import spans as monspans
+        self._spans = monspans.recorder()  # records armed or not
         self._mon_tokens_per_step = None   # lazy: first stacked batch
         self._mon_step_stats = None        # lazy: per-program flops/wire
         self._mon_example = None           # (batch, rng) for one-time pricing
@@ -1410,7 +1413,26 @@ class DeepSpeedEngine:
         from .. import fault
         fault.site("engine.step")    # host-side only; never traced
         self._install_moe_wire()
-        self.monitor.begin_step()    # root "step" span (host wall-clock)
+        with self._step_root():
+            return self._train_batch(data_iter)
+
+    @contextmanager
+    def _step_root(self):
+        """The ``train.step`` root span of one optimizer step (recorded
+        whether or not a monitor is armed; an armed monitor reads its
+        ``span`` events from it) and the profiler's step marker, so XProf's
+        step view works on a training capture."""
+        step_no = self._global_steps_host + 1
+        root = self._spans.open("train.step", step=step_no)
+        self.monitor.begin_step(root)
+        try:
+            with jax.profiler.StepTraceAnnotation("train", step_num=step_no):
+                yield
+        finally:
+            self._spans.close(root)
+
+    def _train_batch(self, data_iter):
+        from .. import fault
         it = data_iter if data_iter is not None else self._data_iterator
         assert it is not None, "train_batch needs training_data or a data_iter"
         if it is not self._data_iterator:
@@ -1419,7 +1441,7 @@ class DeepSpeedEngine:
             # not "fast-forward" it (the warning path in rewind())
             self._stream_pos_known = False
         gas = self.gradient_accumulation_steps()
-        with self.monitor.span("data_fetch"):
+        with self._spans.span("train.data_fetch"):
             micro_batches = [next(it) for _ in range(gas)]
         # data-stream position of THIS step (monotonic; checkpointed with
         # the data-pipeline state, advanced by rewind's fast-forward) —
@@ -1465,7 +1487,7 @@ class DeepSpeedEngine:
     def _stack_microbatches(self, micro_batches):
         # spanned as one phase: host collation + the H2D placement (the
         # device_put dispatch; the DMA itself overlaps the step)
-        with self.monitor.span("h2d_upload"):
+        with self._spans.span("train.h2d_upload"):
             batch = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
                                            *micro_batches)
             if self.monitor.armed and self._mon_tokens_per_step is None:
@@ -1492,7 +1514,7 @@ class DeepSpeedEngine:
         self.monitor.trace_before_step(self._global_steps_host + 1)
         with jax.set_mesh(self.mesh):
             if self._offload is not None:
-                with self.monitor.span("dispatch"):
+                with self._spans.span("train.dispatch"):
                     grads, metrics, new_scale, new_health, new_ef = \
                         self._jit_grad_step(self.state, batch, rng)
                 # loss scale + health EMA + qgZ error feedback advance
@@ -1509,7 +1531,7 @@ class DeepSpeedEngine:
                 # original flat array's buffer is then freed as soon as
                 # the chunk slices are computed, instead of being pinned
                 # through the DPU delay window.
-                with self.monitor.span("grad_d2h"):
+                with self._spans.span("train.grad_d2h"):
                     grads = self._offload.start_d2h(grads)
                 if self._dpu and self._global_steps_host >= self._dpu_warmup:
                     # DPU steady state: while the device computes THIS
@@ -1518,14 +1540,14 @@ class DeepSpeedEngine:
                     # the reference's overlap-centric design,
                     # docs/_posts/2021-03-08-zero3-offload.md:72)
                     if self._pending_offload is not None:
-                        with self.monitor.span("host_adam"):
+                        with self._spans.span("train.host_adam"):
                             self._host_offload_update(*self._pending_offload)
                     self._pending_offload = (grads, metrics)
                 else:
-                    with self.monitor.span("host_adam"):
+                    with self._spans.span("train.host_adam"):
                         self._host_offload_update(grads, metrics)
             else:
-                with self.monitor.span("dispatch"):
+                with self._spans.span("train.dispatch"):
                     self.state, metrics = self._jit_train_step(
                         self.state, batch, rng)
         return self._finish_step(metrics)
@@ -1545,7 +1567,7 @@ class DeepSpeedEngine:
             # the runner's layer loop (streamed gathers, NVMe swaps, host
             # Adam) runs inside this bracket; its own phase timings land
             # as child spans in _monitor_finish when it reports them
-            with self.monitor.span("dispatch"):
+            with self._spans.span("train.dispatch"):
                 metrics = self._param_stream.train_step(
                     micro_batches, rng, lr=lr,
                     step_no=int(self.state.optimizer_steps) + 1)
@@ -1634,11 +1656,11 @@ class DeepSpeedEngine:
             mled.attribute_engine(self).emit(mon, step=step_no,
                                              phases=self._rss_phases)
         if self.config.wall_clock_breakdown and spans:
-            for s in spans:
-                self.timers.record_span(s["name"], s["dur_s"])
+            for name, _, dur_s in spans:
+                self.timers.record_span(name, dur_s)
             if reporting:
                 self.timers.log(
-                    sorted({s["name"] for s in spans}),
+                    sorted({name for name, _, _ in spans}),
                     memory_breakdown=self.config.memory_breakdown)
 
     def _monitor_gauges_counters(self):
@@ -1894,7 +1916,7 @@ class DeepSpeedEngine:
                 self._tree_stage_idx = 1 - idx
                 tree = stages[idx]
             return jax.device_put(tree, self._param_sh)
-        with self.monitor.span("param_h2d"):
+        with self._spans.span("train.param_h2d"):
             payload = self._offload.payload_flat()
             chunks = self._h2d.upload_flat(payload, stage=self._dpu)
         if self._jit_scatter_params is None or \
@@ -1992,12 +2014,13 @@ class DeepSpeedEngine:
         # a retrace here must see THIS engine's expert-wire policy, not
         # whichever engine dispatched last (same rule as train_batch)
         self._install_moe_wire()
-        self.monitor.begin_step()
         micro_batches, self._pending_microbatches = \
             self._pending_microbatches, []
-        if self._param_stream is not None:
-            return self._run_stream_step(micro_batches)
-        return self._run_fused_step(self._stack_microbatches(micro_batches))
+        with self._step_root():
+            if self._param_stream is not None:
+                return self._run_stream_step(micro_batches)
+            return self._run_fused_step(
+                self._stack_microbatches(micro_batches))
 
     # ------------------------------------------------------------ data/loader
     def deepspeed_io(self, dataset, batch_size=None, route=None, data_sampler=None,

@@ -175,6 +175,43 @@ def test_warm_start_bit_identical(tmp_path, devices, stage):
                            cold_params, warm_params)
 
 
+def test_acquisition_spans_cold_and_warm(tmp_path, devices):
+    """A ``CachedStep`` miss leaves ``compile.lower`` + ``compile.build`` in
+    the span recorder, a warm start ``compile.lower`` + ``compile.load``;
+    the report's timings are those spans' durations."""
+    from deepspeed_tpu.monitor import spans as monspans
+    rec = monspans.recorder()
+
+    def acquire():
+        mark = rec.open("test")
+        cache = cc.CompileCache(str(tmp_path / "cc"))
+        step = cc.wrap_step("double", lambda x: x * 2, cache=cache)
+        out = step(jnp.arange(4.0))
+        rows = [r for r in rec.since(mark) if r.name.startswith("compile.")]
+        rec.discard(mark)
+        return out, rows, cache.report()
+
+    out, rows, report = acquire()
+    np.testing.assert_array_equal(out, np.arange(4.0) * 2)
+    assert [r.name for r in rows] == ["compile.lower", "compile.build"]
+    assert all(r.attrs["fn"] == "double" and r.t_start <= r.t_end
+               and r.parent == "test" for r in rows)
+    assert rows[1].attrs["source"] == "compile"
+    assert report["misses"] == 1 and report["hits"] == 0
+    assert report["lower_ms"] == pytest.approx(
+        (rows[0].t_end - rows[0].t_start) * 1e3, abs=0.06)
+    assert report["compile_ms"] == pytest.approx(
+        (rows[1].t_end - rows[1].t_start) * 1e3, abs=0.06)
+
+    out, rows, report = acquire()
+    np.testing.assert_array_equal(out, np.arange(4.0) * 2)
+    assert [r.name for r in rows] == ["compile.lower", "compile.load"]
+    assert rows[1].attrs == {"fn": "double", "source": "cache"}
+    assert report["hits"] == 1 and report["misses"] == 0
+    assert report["deserialize_ms"] == pytest.approx(
+        (rows[1].t_end - rows[1].t_start) * 1e3, abs=0.06)
+
+
 def test_warm_start_bit_identical_offload(tmp_path, devices):
     """The offload route (`_grad_only_step` device half + host Adam):
     cold vs warm must match exactly, including the host master."""

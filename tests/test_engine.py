@@ -30,6 +30,37 @@ def test_loss_decreases(mesh8):
     assert losses[-1] < losses[0] * 0.5, f"loss did not decrease: {losses}"
 
 
+def test_train_step_spans_recorded_without_a_monitor(mesh8):
+    """``train_batch`` records its ``train.step`` root and the dispatch
+    path's brackets in the process-wide recorder with no monitor armed:
+    children carry the step's number and lie inside the root, in order."""
+    from deepspeed_tpu.monitor import spans as monspans
+    rec = monspans.recorder()
+    mark = rec.open("test")
+    engine, _ = _train(base_config(), mesh8, steps=3)
+    assert not engine.monitor.armed
+    rows = rec.since(mark)
+    rec.discard(mark)
+    engine.close()
+    roots = [r for r in rows if r.name == "train.step"]
+    assert [r.step for r in roots] == [1, 2, 3]
+    for root in roots:
+        kids = [r for r in rows if r.parent == "train.step"
+                and r.step == root.step]
+        assert [k.name for k in kids] == ["train.data_fetch",
+                                          "train.h2d_upload",
+                                          "train.dispatch"]
+        assert root.t_start <= kids[0].t_start
+        for a, b in zip(kids, kids[1:]):
+            assert a.t_start <= a.t_end <= b.t_start
+        assert kids[-1].t_end <= root.t_end
+    # the first step acquired its executable inside its dispatch
+    lower = [r for r in rows if r.name == "compile.lower"]
+    assert lower and lower[0].parent == "train.dispatch"
+    assert lower[0].step == 1 and lower[0].attrs["fn"]
+    assert rec.depth == 0
+
+
 def test_bf16_training(mesh8):
     cfg = base_config(**{"bf16": {"enabled": True}})
     engine, losses = _train(cfg, mesh8, steps=15)

@@ -174,19 +174,153 @@ def test_jsonl_and_csv_sinks(tmp_path):
 
 
 def test_span_recorder_nesting():
+    """Rows carry start and end on one clock, the enclosing span's name,
+    and the step and uid of the enclosing span unless they give their own."""
     rec = SpanRecorder()
-    root = rec.open("step")
+    root = rec.open("step", step=7)
     with rec.span("data_fetch"):
         pass
-    with rec.span("dispatch"):
+    with rec.span("dispatch", uid=3, attrs={"bucket": 64}):
         with rec.span("inner"):
             pass
     rec.close(root)
-    done = {d["name"]: d for d in rec.drain()}
-    assert done["data_fetch"]["parent"] == "step"
-    assert done["inner"]["parent"] == "dispatch"
-    assert done["step"]["parent"] is None
-    assert done["step"]["dur_s"] >= done["dispatch"]["dur_s"]
+    rows = rec.rows()
+    assert [r.name for r in rows] == ["data_fetch", "inner", "dispatch",
+                                      "step"]             # completion order
+    done = {r.name: r for r in rows}
+    assert done["data_fetch"].parent == "step"
+    assert done["inner"].parent == "dispatch"
+    assert done["step"].parent is None
+    for r in rows:
+        assert r.t_start <= r.t_end
+        assert r.step == 7                                # inherited
+        if r.parent is not None:                          # enclosed
+            up = done[r.parent]
+            assert up.t_start <= r.t_start and r.t_end <= up.t_end
+    assert done["inner"].uid == done["dispatch"].uid == 3
+    assert done["step"].uid is done["data_fetch"].uid is None
+    assert done["dispatch"].attrs == {"bucket": 64}
+    assert rec.depth == 0
+    assert rec.close(root) >= 0 and len(rec.rows()) == 4  # closed once
+    assert rec.since(root) == rows
+
+
+def test_span_recorder_close_after_skipped_inner_closes():
+    rec = SpanRecorder()
+    root = rec.open("step")
+    rec.open("left_open")           # an exception skipped its close
+    rec.close(root)
+    assert [r.name for r in rec.rows()] == ["left_open", "step"]
+    assert rec.depth == 0
+
+
+def test_span_ring_drops_oldest_and_counts():
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+    rec = SpanRecorder(capacity=4, clock=clock)
+    assert rec.dropped == 0 and rec.dropped_until is None
+    for i in range(6):
+        with rec.span(f"s{i}"):
+            pass
+    assert [r.name for r in rec.rows()] == ["s2", "s3", "s4", "s5"]
+    assert rec.dropped == 2
+    # the newest dropped row is s1: a reader whose range starts after its
+    # end lost nothing
+    s2 = rec.rows()[0]
+    assert rec.dropped_until == s2.t_start - 1.0 < s2.t_start
+    rec.reset()
+    assert rec.rows() == [] and rec.dropped == 0
+
+
+def test_span_discard_drops_the_bracket_and_keeps_recorded_events():
+    rec = SpanRecorder()
+    with rec.span("kept"):
+        pass
+    root = rec.open("poll", step=1)
+    with rec.span("admit"):
+        # a request refused during an idle poll is an event of its own
+        rec.record("request", 0.25, 0.5, uid=9, attrs={"outcome": "shed"})
+    rec.discard(root)
+    assert [r.name for r in rec.rows()] == ["kept", "request"]
+    assert rec.rows("request")[0].uid == 9
+    assert rec.depth == 0 and rec.dropped == 0
+    rec.close(root)                                       # nothing more
+    assert len(rec.rows()) == 2
+
+
+def test_spans_are_trace_annotations_on_the_profilers_clock(tmp_path):
+    """Every span is a ``ds.<name>`` TraceAnnotation: a profiler capture
+    holds it on a host line, with step and uid as metadata, nested as
+    recorded; never under the benchmark's ``bench.`` prefix."""
+    from jax.profiler import ProfileData
+    from deepspeed_tpu.monitor.trace import newest_trace_artifact
+    rec = SpanRecorder()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with rec.span("serving.step", step=3):
+            with rec.span("serving.admit", uid=9):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    events = {}
+    for plane in ProfileData.from_file(
+            newest_trace_artifact(str(tmp_path))).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                assert not ev.name.startswith("bench.")
+                if ev.name.startswith("ds."):
+                    assert plane.name.startswith("/host:")
+                    events[ev.name] = (ev.start_ns,
+                                       ev.start_ns + ev.duration_ns,
+                                       dict(ev.stats))
+    assert set(events) == {"ds.serving.step", "ds.serving.admit"}
+    s0, s1, smeta = events["ds.serving.step"]
+    a0, a1, ameta = events["ds.serving.admit"]
+    assert s0 <= a0 <= a1 <= s1
+    assert smeta["step"] == 3 and ameta == {"step": 3, "uid": 9}
+
+
+def test_monitor_step_is_a_view_of_the_recorder():
+    """``begin_step`` / ``end_step`` read the process-wide recorder: an
+    engine's root is adopted and left for the engine to close, and the
+    ``span`` events keep their short names."""
+    from deepspeed_tpu.monitor import spans as monspans
+    mon = Monitor(run_dir=None, sinks=("ring",))
+    rec = monspans.recorder()
+    assert mon.spans is rec
+    root = rec.open("serving.step", step=5)
+    mon.begin_step(root)
+    with rec.span("serving.admit"):
+        with rec.span("serving.prefill", uid=1):
+            with rec.span("serving.prefill.dispatch"):
+                pass
+    rec.record("serving.request", 0.0, 1.0, uid=1)    # not a span event
+    done = mon.end_step(5, name="serving_step")
+    assert [(n, p) for n, p, _ in done] == [
+        ("prefill.dispatch", "prefill"), ("prefill", "admit"),
+        ("admit", "step"), ("step", None)]
+    assert not root.closed                             # the engine's
+    rec.close(root)
+    evs = [e for e in mon.ring.to_list() if e.kind == "span"]
+    assert [e.name for e in evs] == ["prefill.dispatch", "prefill", "admit",
+                                     "step"]
+    assert all(e.step == 5 and e.dur_s >= 0 for e in evs)
+    # a root of the monitor's own is opened and closed by it
+    mon.begin_step()
+    assert [n for n, _, _ in mon.end_step(6)] == ["step"]
+    assert rec.rows()[-1].name == "step" and rec.depth == 0
+    # an aborted own step is discarded, not recorded
+    n = len(rec.rows())
+    mon.begin_step()
+    mon.abort_step()
+    assert len(rec.rows()) == n and rec.depth == 0
+    mon.close()
 
 
 # ---------------------------------------------------------------------------

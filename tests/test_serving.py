@@ -523,6 +523,118 @@ def test_exact_percentiles_vs_truncated_deque_window(tiny, devices):
     srv.close()
 
 
+def _served_rows(tiny, reqs, **cfg):
+    """Serve ``reqs`` with no monitor armed; the rows the process-wide
+    recorder gained, and the results."""
+    from deepspeed_tpu.monitor import spans as monspans
+    model, params = tiny
+    rec = monspans.recorder()
+    mark = rec.open("test")           # everything recorded while serving
+    srv = ServingEngine(model=model, params=params,
+                        config=ServingConfig(batch_slots=2, block_size=8,
+                                             **cfg))
+    assert not srv.monitor.armed
+    res = srv.run(reqs)
+    srv.close()
+    rows = rec.since(mark)
+    rec.discard(mark)
+    return rows, res
+
+
+def test_request_lifecycle_stamps_and_request_row(tiny, devices):
+    """With a NullMonitor a served request leaves exactly one
+    ``serving.request`` row, and its stamps are ordered: submit <= admit <=
+    first token = the first token stamp <= ... <= done, a stamp a token."""
+    reqs = [Request(tokens=np.arange(5), max_new_tokens=4, seed=0),
+            Request(tokens=np.arange(9), max_new_tokens=3, seed=1,
+                    do_sample=True),
+            Request(tokens=np.arange(4), max_new_tokens=1, seed=2),
+            Request(tokens=np.arange(6), max_new_tokens=5, seed=3)]
+    rows, res = _served_rows(tiny, reqs)
+    by_uid = {}
+    for r in rows:
+        if r.name == "serving.request":
+            assert r.uid not in by_uid                  # exactly one
+            by_uid[r.uid] = r
+    assert set(by_uid) == {r.uid for r in reqs}
+    for q in reqs:
+        rec, row = res[q.uid], by_uid[q.uid]
+        stamps = rec["t_tokens"]
+        assert len(stamps) == len(rec["tokens"]) == q.max_new_tokens
+        assert rec["t_submit"] <= rec["t_admit"] <= rec["t_first"]
+        assert rec["t_first"] == stamps[0]
+        assert all(a <= b for a, b in zip(stamps, stamps[1:]))
+        assert stamps[-1] <= rec["t_done"]
+        assert (row.t_start, row.t_end) == (rec["t_submit"], rec["t_done"])
+        assert row.parent is None and row.attrs["outcome"] == OK
+        assert row.attrs["prompt_len"] == len(q.tokens)
+        assert row.attrs["t_admit"] == rec["t_admit"]
+        assert row.attrs["t_first"] == rec["t_first"]
+        assert row.attrs["t_tokens"] == stamps
+    # four requests over two slots: the later ones waited for a seat
+    assert res[reqs[3].uid]["t_admit"] > res[reqs[0].uid]["t_first"]
+
+
+def test_unseated_request_row_has_its_terminal_time_as_admission(tiny,
+                                                                 devices):
+    from deepspeed_tpu.inference.serving import DEADLINE
+    reqs = [Request(tokens=np.arange(5), max_new_tokens=2, seed=0,
+                    deadline_ms=0.0)]
+    rows, res = _served_rows(tiny, reqs)
+    rec = res[reqs[0].uid]
+    assert rec["outcome"] == DEADLINE and rec["tokens"] is None
+    assert rec["t_admit"] == rec["t_done"] and rec["t_tokens"] is None
+    (row,) = [r for r in rows if r.name == "serving.request"]
+    assert row.attrs["outcome"] == DEADLINE and row.attrs["t_first"] is None
+    # the poll that refused it decoded nothing: no step bracket is kept
+    assert not [r for r in rows if r.name == "serving.step"]
+
+
+def test_serving_step_anatomy(tiny, devices):
+    """The spans of a decode step, recorded with no monitor: the direct
+    children of a ``serving.step`` carry its number, do not overlap, and
+    cover it to within its self time; a prefill hangs under ``admit`` with
+    its request's uid and its dispatch and read-back as children."""
+    reqs = [Request(tokens=np.arange(5), max_new_tokens=4, seed=0),
+            Request(tokens=np.arange(9), max_new_tokens=3, seed=1)]
+    rows, res = _served_rows(tiny, reqs)
+    steps = [r for r in rows if r.name == "serving.step"]
+    assert [r.step for r in steps] == list(range(1, len(steps) + 1))
+    assert all(r.parent == "test" for r in steps)
+    every = {"serving.admit", "serving.upload", "serving.dispatch",
+             "serving.readback", "serving.bookkeeping", "serving.telemetry"}
+    for st in steps:
+        kids = [r for r in rows if r.parent == "serving.step"
+                and r.step == st.step and st.t_start <= r.t_start
+                and r.t_end <= st.t_end]
+        assert {k.name for k in kids} == every
+        kids.sort(key=lambda r: r.t_start)
+        assert [k.name for k in kids] == [
+            "serving.admit", "serving.upload", "serving.dispatch",
+            "serving.readback", "serving.bookkeeping", "serving.telemetry"]
+        for a, b in zip(kids, kids[1:]):
+            assert a.t_end <= b.t_start                   # no overlap
+        covered = sum(k.t_end - k.t_start for k in kids)
+        assert 0 <= (st.t_end - st.t_start) - covered     # self time >= 0
+        assert st.attrs["n_active"] >= 1
+    assert sum(st.attrs["emitted"] for st in steps) == sum(
+        len(r["tokens"]) - 1 for r in res.values())
+    prefills = [r for r in rows if r.name == "serving.prefill"]
+    assert sorted(r.uid for r in prefills) == sorted(q.uid for q in reqs)
+    for pf in prefills:
+        assert pf.parent == "serving.admit" and pf.step == 1
+        assert pf.attrs["prompt_len"] == len(
+            next(q for q in reqs if q.uid == pf.uid).tokens)
+        assert pf.attrs["bucket"] % 8 == 0
+        assert res[pf.uid]["t_admit"] == pf.t_start
+        kids = [r for r in rows if r.parent == "serving.prefill"
+                and r.uid == pf.uid]
+        assert [k.name for k in kids] == ["serving.prefill.dispatch",
+                                          "serving.prefill.readback"]
+        assert pf.t_start <= kids[0].t_start
+        assert kids[0].t_end <= kids[1].t_start and kids[1].t_end <= pf.t_end
+
+
 def test_tracing_emits_spans_and_chrome_export(tiny, devices, tmp_path):
     """trace_sample_rate=1.0 + armed monitor: every request emits a
     schema-v2 `trace` event with monotone non-overlapping queue_wait /
