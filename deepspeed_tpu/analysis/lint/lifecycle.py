@@ -127,11 +127,10 @@ PROTECTED_ATTRS = {
     "_buf": ("RequestJournal",),         # journal append buffer
     "assigned": ("_ReplicaState", "_place", "_record_result", "_handoff",
                  "_seat_transfer"),
-    # slot block tables: _restore_stream is the migration-era second
-    # admission path (seats a restored slot) and _start_shared the
-    # prefix-cache-hit seat — peers of _start
-    "_tables": ("__init__", "_start", "_start_shared", "_finish",
-                "_restore_stream"),
+    # slot block tables: every seat (_start, _start_shared, the restore)
+    # and the clear in _finish go through _set_slot, which also marks the
+    # device-resident copy of the slot state stale
+    "_tables": ("__init__", "_set_slot"),
     "blocks": ("__init__",),             # per-sequence block list (_Slot)
 }
 

@@ -18,6 +18,11 @@ MII/externals.  This module is that missing layer, built TPU-first:
   decode (``GPT2.decode_step_paged``): ONE executable per step for all
   slots, not 4·L separately scheduled small matmuls (the measured b=8
   scheduling-gap term, DECODE_PROFILE.json);
+- **one round trip a step** — the step's slot state (tables, lengths,
+  tokens, seeds, indices, temperatures, flags) stays on the device and
+  is advanced in-graph; the host sends it up, as one packed buffer, only
+  after a slot was seated, cleared or ingested, and reads one packed
+  buffer back (docs/serving.md#step-anatomy);
 - **admission control** — capacity math (blocks needed vs free) gates
   the queue, and the decode executable's ``memory_analysis()`` is
   preflighted against the HBM budget BEFORE any step executes (the same
@@ -468,6 +473,15 @@ class Request:
     deadline_ms: Optional[float] = None
 
 
+def _pack_read(*parts):
+    """In-graph: everything the host reads after a decode dispatch — (B,)
+    or (B, W) parts — as ONE (B, columns) int32 array: one device-to-host
+    copy, not one per value.  The poison flag rides beside the token: the
+    sentinel token is a valid id."""
+    return jnp.concatenate(
+        [p.astype(jnp.int32).reshape(p.shape[0], -1) for p in parts], axis=1)
+
+
 def _mem_analysis(exe) -> Optional[dict]:
     """Shared executable-memory reading (``runtime/compile_cache.py``)
     — one implementation for every preflight gate."""
@@ -678,6 +692,16 @@ class ServingEngine:
         self._ngen = np.zeros((S,), np.int32)
         self._temps = np.ones((S,), np.float32)
         self._flags = np.zeros((S,), bool)
+        # the seven mirrors above are the truth the scheduler reasons
+        # with; the decode step runs on a device-resident copy of them
+        # (operand order), advanced in-graph and re-sent — as ONE packed
+        # buffer — only after a write other than the plain advance
+        # (docs/serving.md#step-anatomy)
+        self._resident = None
+        self._state_dirty = True
+        self._unpack = None           # packed (S, nb_max + 6) → the seven
+        self._reused_steps = 0        # decode steps that sent no state up
+        self._state_uploads = 0
 
         self.queue: deque = deque()
         # uid → record; completed records stay until the caller
@@ -1160,23 +1184,60 @@ class ServingEngine:
                     steps[row.attrs["t_tokens"]] = row.step
         return steps
 
-    # ---------------------------------------------------------- jitted steps
+    # ------------------------------ slot state: mirrors and the device's copy
+    def _set_slot(self, slot: int, blocks=(), length=0, tok=0, seed=0,
+                  ngen=0, temp=1.0, flag=False):
+        """Seat a stream in ``slot`` — or, with the defaults, clear the
+        row — in all seven mirrors.  Every write of slot state other than
+        the decode step's plain advance comes through here or marks
+        ``_state_dirty`` itself, so the next dispatch re-sends the state."""
+        self._tables[slot] = pk.SCRATCH_BLOCK
+        self._tables[slot, :len(blocks)] = blocks
+        self._lengths[slot] = length
+        self._toks[slot] = tok
+        self._seeds[slot] = seed
+        self._ngen[slot] = ngen
+        self._temps[slot] = temp
+        self._flags[slot] = flag
+        self._state_dirty = True
+
+    def _sync_state(self):
+        """Make the device-resident slot state equal the mirrors: nothing
+        to do while no slot changed since the last upload (the compiled
+        step advances its own copy exactly as ``bookkeeping`` advances the
+        mirrors); otherwise ONE packed host buffer goes up and the unpack
+        program splits it on the device."""
+        if not self._state_dirty:
+            return
+        self._build_decode()
+        # (S, nb_max + 6) int32, the temperatures as their bits
+        buf = np.column_stack((
+            self._tables, self._lengths, self._toks, self._seeds,
+            self._ngen, self._temps.view(np.int32), self._flags))
+        with jax.set_mesh(self.engine.mesh):
+            self._resident = list(self._unpack(jnp.asarray(buf)))
+        self._state_dirty = False
+        self._state_uploads += 1
+
     def _decode_args(self, toks=None):
-        """Operands of the armed decode step.  With speculation armed the
-        token operand is the (B, k+1) window [current, draft_1..draft_k];
-        ``toks=None`` (preflight/audit/pricing callers) sends a window
-        whose draft columns repeat the current token — same shapes, same
-        program."""
-        if toks is None:
-            toks = self._toks
-            if self.spec is not None:
+        """The nine live operands of the armed decode step: params, pool
+        and the resident slot state (synced first if a slot changed;
+        calling this between steps consumes nothing).  With speculation
+        armed the token operand is the host-made (B, k+1) window
+        [current, draft_1..draft_k], sent up every step; ``toks=None``
+        (preflight/audit/pricing callers) sends a window whose draft
+        columns repeat the current token — same shapes, same program."""
+        self._sync_state()
+        tables, lengths, cur, seeds, ngen, temps, flags = self._resident
+        if self.spec is not None:
+            if toks is None:
                 toks = np.repeat(self._toks[:, None], self.spec.k + 1,
                                  axis=1)
-        return (self.engine.params, self.pool, jnp.asarray(self._tables),
-                jnp.asarray(self._lengths), jnp.asarray(toks),
-                jnp.asarray(self._seeds), jnp.asarray(self._ngen),
-                jnp.asarray(self._temps), jnp.asarray(self._flags))
+            cur = jnp.asarray(toks)
+        return (self.engine.params, self.pool, tables, lengths, cur, seeds,
+                ngen, temps, flags)
 
+    # ---------------------------------------------------------- jitted steps
     def _sample_tokens(self, logits, seeds, ngen, temps, flags):
         """(B, V) fp32 → (B,) int32: per-slot greedy/sampled select with
         the request-deterministic key stream (module docstring)."""
@@ -1209,7 +1270,13 @@ class ServingEngine:
             poisoned = rows_nonfinite(logits)
             nxt = self._sample_tokens(logits, seeds, ngen, temps, flags)
             nxt = jnp.where(poisoned, jnp.int32(POISON_SENTINEL_TOKEN), nxt)
-            return nxt, poisoned, pool
+            # the state of the NEXT step, advanced as `bookkeeping`
+            # advances the mirrors (a row the host holds inactive points
+            # at the scratch block and stays as it is); whatever else the
+            # host does to a row marks the state dirty and overwrites this
+            n = (tables[:, 0] != pk.SCRATCH_BLOCK).astype(jnp.int32)
+            return (_pack_read(nxt, poisoned), pool, lengths + n,
+                    jnp.where(n > 0, nxt, toks), ngen + n)
 
         def spec_step(params, pool, tables, lengths, toks_win, seeds, ngen,
                       temps, flags):
@@ -1238,9 +1305,21 @@ class ServingEngine:
             out = jnp.stack(outs, axis=1)                      # (B, W)
             match = (toks_win[:, 1:] == out[:, :-1]).astype(jnp.int32)
             accept_len = 1 + jnp.sum(jnp.cumprod(match, axis=1), axis=1)
-            return out, accept_len, nonfin, pool
+            n = jnp.where(tables[:, 0] != pk.SCRATCH_BLOCK, accept_len, 0)
+            return (_pack_read(out, nonfin, accept_len), pool,
+                    lengths + n, ngen + n)
+
+        nb = self.nb_max
+
+        def unpack(buf):
+            return (buf[:, :nb], buf[:, nb], buf[:, nb + 1], buf[:, nb + 2],
+                    buf[:, nb + 3],
+                    jax.lax.bitcast_convert_type(buf[:, nb + 4], jnp.float32),
+                    buf[:, nb + 5] != 0)
 
         c = self.config
+        self._unpack = self.engine._wrap_step(
+            f"serving.unpack[{c.batch_slots}x{nb}]", unpack)
         spec_tag = f",spec{self.spec.k}" if self.spec is not None else ""
         self._decode = self.engine._wrap_step(
             f"serving.decode[{c.batch_slots}x{self.nb_max}"
@@ -1294,7 +1373,8 @@ class ServingEngine:
                 jnp.zeros((1,), jnp.int32), temp[None], flag[None])
             first = jnp.where(bad, jnp.int32(POISON_SENTINEL_TOKEN),
                               first[0])
-            return first, bad, pool
+            # [first token, poison flag]: one read for the host, not two
+            return jnp.stack([first, bad.astype(jnp.int32)]), pool
 
         fn = self.engine._wrap_step(
             f"serving.prefill[{bucket},kv{self.config.kv_bits}]", prefill,
@@ -1472,15 +1552,15 @@ class ServingEngine:
             fn = self._prefill_fn(bucket)
             with jax.set_mesh(self.engine.mesh):
                 with self._spans.span("serving.prefill.dispatch"):
-                    first, bad, self.pool = fn(
+                    read, self.pool = fn(
                         self.engine.params, jnp.asarray(toks), self.pool,
                         blk, jnp.int32(T), jnp.int32(req.seed),
                         jnp.float32(req.temperature),
                         jnp.asarray(req.do_sample))
-            # the reads sync the prefill dispatch: the host waits here
+                    read.copy_to_host_async()
+            # the read syncs the prefill dispatch: the host waits here
             with self._spans.span("serving.prefill.readback"):
-                first = int(np.asarray(first))
-                bad = bool(np.asarray(bad))
+                first, bad = (int(x) for x in np.asarray(read))
         if bad:
             # quarantined AT prefill: the slot is never seated, the
             # sentinel token is never surfaced, and the blocks go back
@@ -1505,16 +1585,10 @@ class ServingEngine:
         s.out_tokens.append(first)
         s.hist.append(first)
         self._slots[slot] = s
-        self._tables[slot] = 0
-        self._tables[slot, :len(blocks)] = blocks
+        self._set_slot(slot, blocks, length=T, tok=first, seed=req.seed,
+                       ngen=1, temp=req.temperature, flag=req.do_sample)
         if self._sanitizer is not None:
             self._sanitizer.on_attach(req.uid, blocks)
-        self._lengths[slot] = T
-        self._toks[slot] = first
-        self._seeds[slot] = req.seed
-        self._ngen[slot] = 1
-        self._temps[slot] = req.temperature
-        self._flags[slot] = req.do_sample
         rec["t_first"] = time.monotonic()
         rec["t_tokens"] = [rec["t_first"]]
         if new == 1 or first == c.eos_token_id:
@@ -1575,17 +1649,13 @@ class ServingEngine:
         s.shared_blocks = ns
         s.shared_keys = list(share["keys"])
         self._slots[slot] = s
-        self._tables[slot] = 0
-        self._tables[slot, :len(blocks)] = blocks
+        # ngen 0: no token emitted yet
+        self._set_slot(slot, blocks, length=pos0, tok=prompt[pos0],
+                       seed=req.seed, ngen=0, temp=req.temperature,
+                       flag=req.do_sample)
         if self._sanitizer is not None:
             self._sanitizer.on_attach(req.uid, blocks)
-        self._lengths[slot] = pos0
-        self._toks[slot] = prompt[pos0]
-        self._seeds[slot] = req.seed
-        self._ngen[slot] = 0            # no token emitted yet
         self.results[req.uid]["t_tokens"] = []
-        self._temps[slot] = req.temperature
-        self._flags[slot] = req.do_sample
         self._prefix_hits_total += 1
         self._prefix_shared_blocks_total += ns
         if fault.poison_uid(req.uid):
@@ -1916,8 +1986,16 @@ class ServingEngine:
             # never publishes into the prefix cache at finish
             s.wire_kv = True
             self._slots[slot] = s
-            self._tables[slot] = 0
-            self._tables[slot, :len(blocks)] = blocks
+            # decode resumes where the snapshot stopped: lengths trails
+            # out_tokens by the one token whose KV the NEXT step writes
+            # (_start's invariant), and sampling continues at
+            # fold_in(seed, ngen) — token-identical to the dead replica's
+            # stream by the determinism contract
+            self._set_slot(
+                slot, blocks,
+                length=int(prompt.size) + len(out_tokens) - 1,
+                tok=out_tokens[-1], seed=req.seed, ngen=len(out_tokens),
+                temp=req.temperature, flag=req.do_sample)
             if self._sanitizer is not None:
                 self._sanitizer.on_attach(req.uid, blocks)
                 # DSTPU317 (satellite fix): a restore that imports a
@@ -1946,17 +2024,6 @@ class ServingEngine:
                 if self._sanitizer is not None:
                     self._sanitizer.on_free(released, uid=req.uid)
             raise
-        # decode resumes where the snapshot stopped: lengths trails
-        # out_tokens by the one token whose KV the NEXT step writes
-        # (_start's invariant), and sampling continues at
-        # fold_in(seed, ngen) — token-identical to the dead replica's
-        # stream by the determinism contract
-        self._lengths[slot] = int(prompt.size) + len(out_tokens) - 1
-        self._toks[slot] = out_tokens[-1]
-        self._seeds[slot] = req.seed
-        self._ngen[slot] = len(out_tokens)
-        self._temps[slot] = req.temperature
-        self._flags[slot] = req.do_sample
         self._snap_last[slot] = len(out_tokens)
         # the restored tokens all arrive with the image: one stamp
         rec = self.results[req.uid]
@@ -2270,13 +2337,7 @@ class ServingEngine:
             self._delete_stream_snapshots(s.req.uid)
         self._slots[slot] = None
         self._snap_last[slot] = 0
-        self._tables[slot] = 0
-        self._lengths[slot] = 0
-        self._toks[slot] = 0
-        self._seeds[slot] = 0
-        self._ngen[slot] = 0
-        self._temps[slot] = 1.0
-        self._flags[slot] = False
+        self._set_slot(slot)             # the cleared row
 
     def _prefix_insert(self, s: _Slot):
         """Publish one finishing request's fully-written KV blocks into
@@ -2372,6 +2433,12 @@ class ServingEngine:
         root = self._spans.open("serving.step", step=self._steps + 1)
         try:
             return self._step(root)
+        except BaseException:
+            # a step that dies between its dispatch and its bookkeeping
+            # leaves the device's copy of the slot state a step ahead of
+            # the mirrors: the mirrors are the truth, send them again
+            self._state_dirty = True
+            raise
         finally:
             self._spans.close(root)      # nothing, after an idle poll
 
@@ -2439,30 +2506,35 @@ class ServingEngine:
                         toks_win[i, 1:] = ngram_draft(
                             s.hist[-DRAFT_WINDOW:], spec.k, spec.ngram)
         with jax.set_mesh(self.engine.mesh):
-            # the small host arrays go up on their own bracket, so that
-            # "dispatch" is the call into the executable alone
+            # the slot state goes up (only if a slot changed) on its own
+            # bracket, so that "dispatch" is the call into the executable
+            # alone
             with spans.span("serving.upload") as upload:
+                upload.attrs = {"uploaded": self._state_dirty}
                 args = self._decode_args(toks=toks_win)
+            self._reused_steps += not upload.attrs["uploaded"]
+            res = self._resident    # operand order: [1] lengths, [2] toks,
+            #                         [4] ngen come back advanced
             with spans.span("serving.dispatch"):
                 if spec is not None:
-                    out, accept_len, nonfin, self.pool = self._decode(*args)
+                    read, self.pool, res[1], res[4] = self._decode(*args)
                 else:
-                    nxt, poisoned, self.pool = self._decode(*args)
+                    read, self.pool, res[1], res[2], res[4] = \
+                        self._decode(*args)
+                # the one buffer the host needs starts down at once
+                read.copy_to_host_async()
         if self._kv_warm_pending:
             self._kv_warm_pending = False
             self._warm_restore_path()
         # the host's wait for the device, alone on its bracket
         with spans.span("serving.readback") as readback:
-            if spec is not None:
-                out = np.asarray(out)                   # (B, k+1)
-                accept_len = np.asarray(accept_len)     # (B,)
-                nonfin = np.asarray(nonfin)             # (B, k+1)
-            else:
-                # plain decode is the W=1 window: one token, always
-                # "accepted"
-                out = np.asarray(nxt)[:, None]
-                nonfin = np.asarray(poisoned)[:, None]
-                accept_len = np.ones((out.shape[0],), np.int64)
+            read = np.asarray(read)
+            # (B, W) tokens | (B, W) non-finite flags | accept length;
+            # plain decode is the W=1 window: one token, always "accepted"
+            W = read.shape[1] // 2
+            out, nonfin = read[:, :W], read[:, W:2 * W] != 0
+            accept_len = (read[:, 2 * W] if spec is not None
+                          else np.ones((read.shape[0],), np.int64))
         # the value read above synced the dispatch: from the upload's start
         # to the read's end is a true decode-step cost, the predictive-
         # deadline EMA's input (the spans' own clock reads, no others)
@@ -2502,8 +2574,11 @@ class ServingEngine:
                     if nonfin[i, :adv].any():
                         self._evict_poisoned(i)
                         continue
+                    # not the in-graph advance (no sample is kept, ngen
+                    # stands): the next dispatch re-sends the state
                     self._lengths[i] += adv
                     self._toks[i] = s.pending[adv - 1]
+                    self._state_dirty = True
                     del s.pending[:adv]
                     dl = self.results[s.req.uid]["deadline"]
                     if dl is not None and now >= dl:
@@ -3016,6 +3091,8 @@ class ServingEngine:
         self._completed_total = 0
         self._generated_total = 0
         self._steps = 0
+        self._reused_steps = 0
+        self._state_uploads = 0
         self._outcomes = {k: 0 for k in OUTCOMES}
         self._requeued_total = 0
         self._err_window_last = (0, 0)
@@ -3051,6 +3128,10 @@ class ServingEngine:
                "pending": len(self.queue) + sum(
                    s is not None for s in self._slots),
                "decode_steps": self._steps,
+               # of those, the steps that sent no slot state up; and how
+               # often the packed state went up (docs/serving.md#step-anatomy)
+               "state_reused_steps": self._reused_steps,
+               "state_uploads": self._state_uploads,
                "generated_tokens": self._generated_total,
                "outcomes": dict(self._outcomes),
                "requeued": self._requeued_total,
@@ -3163,10 +3244,11 @@ class ServingEngine:
             except OSError as e:
                 logger.warning(f"serving: journal close failed ({e}); "
                                "continuing teardown")
-            for fn in [self._decode] + list(self._prefills.values()):
+            for fn in [self._decode, self._unpack] + list(
+                    self._prefills.values()):
                 if fn is not None and hasattr(fn, "clear"):
                     fn.clear()
-            self._decode = None
+            self._decode = self._unpack = self._resident = None
             self._prefills.clear()
             self._blockset = None
             self._blockcopy = None
