@@ -1,7 +1,7 @@
 """What every cell of the benchmark shares: finding a cell's data files by
-the names in ``BENCHMARK.json``, building the model from a configuration
-file, weights from the seed, compile-cache counting, host spans, the device
-block of the result line.
+the names in ``BENCHMARK.json``, finding the configuration's model family
+and plain reference by its ``model_type``, weights from the seed,
+compile-cache counting, host spans, the device block of the result line.
 
 Nothing here imports JAX at module level: ``run.py`` must be able to refuse
 a machine without a TPU before anything heavy is loaded, and the tests import
@@ -16,12 +16,6 @@ import time
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
-
-# HF ``config.json`` key -> ``GPT2Config`` field.  A configuration file
-# keeps the published key names; this is the only place they are translated.
-_HF_TO_GPT2 = {"n_embd": "n_embd", "n_layer": "n_layer", "n_head": "n_head",
-               "n_positions": "max_seq", "vocab_size": "vocab_size",
-               "layer_norm_epsilon": "layer_norm_eps"}
 
 
 def read_json(*parts):
@@ -56,11 +50,13 @@ def load_traffic(name):
 
 
 def load_plugin(folder, name):
-    """``benchmark/<folder>/<name>.py`` as a module: runners and readers are
-    found by the name a data file gives, never listed in code."""
+    """``benchmark/<folder>/<name>.py`` as a module: runners, readers,
+    model families and their references are found by the name a data file
+    gives, never listed in code."""
     path = os.path.join(BENCH_DIR, folder, f"{name}.py")
     if not os.path.isfile(path):
-        raise SystemExit(f"no {folder[:-1]} {name!r}: {path} is missing")
+        raise SystemExit(f"no {name!r} among the benchmark's {folder}: "
+                         f"{path} is missing")
     spec = importlib.util.spec_from_file_location(
         f"benchmark.{folder}.{name}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -76,25 +72,24 @@ def cell_metrics(bench, group, cell_name):
 
 
 # ------------------------------------------------------------------ the model
-def model_overrides(cfg):
-    """``GPT2`` keyword overrides from a configuration file's published
-    keys.  The repo's block is fixed at a 4x MLP and the tanh GELU: a file
-    that states anything else is refused, not silently run differently."""
-    inner = cfg.get("n_inner")
-    if inner not in (None, 4 * cfg["n_embd"]):
-        raise ValueError(f"n_inner {inner} is not 4 x n_embd: models/gpt2.py "
-                         "cannot run it")
-    if cfg.get("activation_function", "gelu_new") != "gelu_new":
-        raise ValueError("models/gpt2.py computes gelu_new (tanh); the file "
-                         f"states {cfg['activation_function']!r}")
-    return {ours: cfg[theirs] for theirs, ours in _HF_TO_GPT2.items()}
+def family(cfg):
+    """The configuration's model family,
+    ``benchmark/families/<model_type>.py``: ``build``, ``dims``, ``matmul_params_per_token`` and, optionally,
+    ``costs`` (PERF.md, "Adding to the benchmark")."""
+    return load_plugin("families", cfg["model_type"])
+
+
+def reference(cfg):
+    """The family's plain reference, ``benchmark/reference/<model_type>.py``:
+    ``logits_at(cfg, params, tokens, positions)`` and ``loss(cfg, params,
+    batch)``, which decide ``correct``."""
+    return load_plugin("reference", cfg["model_type"])
 
 
 def build_model(cfg, dtype, **extra):
-    """The model through the normal path: ``models.build`` with overrides
-    (no preset is added to the program for a benchmark configuration)."""
-    from deepspeed_tpu.models import build
-    return build("gpt2-125m", dtype=dtype, **{**model_overrides(cfg), **extra})
+    """The model through the program's normal path, as the family builds
+    it; a file the program cannot run is refused there."""
+    return family(cfg).build(cfg, dtype, **extra)
 
 
 def key_seed(seed):
@@ -214,14 +209,16 @@ class TraceWindow:
 
     def stop(self):
         """Stop the capture if one is running; ``path`` is then the
-        ``.xplane.pb`` file.  Calling it again does nothing."""
+        ``.xplane.pb`` file.  Calling it again does nothing.  Returns the
+        seconds the host stood still while the trace was written."""
         if self.t_start is None or self.t_stop is not None:
-            return
+            return 0.0
         import jax
         from deepspeed_tpu.monitor.trace import newest_trace_artifact
         self.t_stop = time.monotonic()
         jax.profiler.stop_trace()
         self.path = newest_trace_artifact(self.dir)
+        return time.monotonic() - self.t_stop
 
 
 # --------------------------------------------------------------------- device
@@ -261,6 +258,8 @@ class RunContext:
         # the tests hand in a tiny configuration and mix of their own
         self.config = config or load_config(bench, cell["config"])
         self.traffic = traffic or load_traffic(cell["traffic"])
+        self.family = family(self.config)
+        self.dims = self.family.dims(self.config)
         self.seed = int(seed)
         self.seconds = float(seconds)
         self.trace = bool(trace)

@@ -53,6 +53,7 @@ def run_cell(bench, cell, *, seed, seconds, trace, t_process_start=None,
         breakdown = summary["breakdown"]
         view = {"trace": summary, "spans": ctx.spans, "counters":
                 out["counters"], "facts": out["facts"], "config": ctx.config,
+                "family": ctx.family,
                 "peaks": harness.peaks_for(device["kind"])
                 if device["platform"] == "tpu" else None,
                 "trace_span": out["trace_span"]}

@@ -12,7 +12,7 @@ from benchmark import serving, traffic_gen
 def run(ctx):
     model, eng, srv = serving.build(ctx)
     pool = traffic_gen.backlog(ctx.traffic, ctx.seed,
-                               ctx.config["vocab_size"])
+                               ctx.dims["vocab_size"])
     n_buckets = serving.warm_up(srv, pool)
     ctx.log(f"warmed {n_buckets} prefill buckets and the decode step; a "
             f"backlog of {len(pool)} requests, round and round")
@@ -24,11 +24,10 @@ def run(ctx):
     ok, check = serving.check(ctx, model, eng, srv, pool)
     eng.close()
 
-    in_time = [r for r in rows if r["done_s"] <= ctx.seconds]
-    tokens = sum(r["prompt"] + r["generated"] for r in in_time)
-    out["facts"].update(check=check, completed_in_window=len(in_time))
+    rate, completed = serving.tokens_per_s(rows, ctx.seconds)
+    out["facts"].update(check=check, completed_in_window=completed)
     return {**out, "setup_s": setup_s,
-            "end_to_end": {"serve_tokens_per_s": tokens / ctx.seconds},
+            "end_to_end": {"serve_tokens_per_s": rate},
             "attempted": len(rows),
             "failed": sum(not r["ok"] for r in rows),
             "correct": bool(ok and out["in_window_compiles"] == 0)}

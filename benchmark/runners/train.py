@@ -25,14 +25,13 @@ from deepspeed_tpu.parallel import mesh as M
 from deepspeed_tpu.runtime import compile_cache
 
 from benchmark import harness, traffic_gen
-from benchmark.reference import gpt2 as reference
 
 
 def build(ctx, devices):
     t = ctx.traffic
     mesh = M.make_mesh(dict(t["mesh"]), devices=devices)
     model = harness.build_model(ctx.config, jnp.bfloat16,
-                                **{**t["model"], "max_seq": t["seq"]})
+                                max_positions=t["seq"], **t["model"])
     config = {
         "train_micro_batch_size_per_gpu": t["micro_batch"],
         "gradient_accumulation_steps": 1,
@@ -65,7 +64,7 @@ def run(ctx):
     spans, log, t = ctx.spans, ctx.log, ctx.traffic
     devices = jax.devices()[:ctx.cell["chips"]]
     model, engine, mesh, global_batch = build(ctx, devices)
-    pool = traffic_gen.token_batches(t, ctx.seed, ctx.config["vocab_size"],
+    pool = traffic_gen.token_batches(t, ctx.seed, ctx.dims["vocab_size"],
                                      global_batch)
     n_batch = [0]
 
@@ -138,7 +137,6 @@ def run(ctx):
           and (n_mosaic > 0 or not on_tpu))
     log(f"check: {check} -> {'ok' if ok else 'FAILED'}")
 
-    c = ctx.config
     return {
         "setup_s": setup_s,
         "end_to_end": {"train_tokens_per_s":
@@ -151,9 +149,8 @@ def run(ctx):
         "facts": {"check": check, "window": (t0, t_end),
                   "tokens_per_step": tokens_per_step, "seq": t["seq"],
                   "global_batch": global_batch, "chips": mesh.size,
-                  "n_layer": c["n_layer"], "n_head": c["n_head"],
-                  "n_embd": c["n_embd"], "head_dim": c["n_embd"] // c["n_head"],
-                  "vocab_size": c["vocab_size"],
+                  **ctx.dims, "matmul_params_per_token":
+                  ctx.family.matmul_params_per_token(ctx.config),
                   "tokens_per_s": steps * tokens_per_step / (t_end - t0)},
         "device": device, "trace_path": trace.path,
         "trace_span": (trace.t_start, trace.t_stop),
@@ -165,6 +162,7 @@ def reference_loss(ctx, model, batch):
     engine started from (the same seed through the same ``model.init``,
     rounded to bfloat16 as the engine's compute copy is), a row at a time."""
     params = harness.seeded_weights(model, ctx.seed, jnp.bfloat16)
+    reference = harness.reference(ctx.config)
     fn = jax.jit(lambda p, row: reference.loss(ctx.config, p, row))
     return float(np.mean([float(fn(params, jnp.asarray(row[None])))
                           for row in batch]))

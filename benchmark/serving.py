@@ -8,6 +8,7 @@ the yardstick.
 """
 
 import dataclasses
+import gc
 import time
 
 import jax
@@ -20,7 +21,6 @@ from deepspeed_tpu.inference.serving import OK
 from deepspeed_tpu.runtime import compile_cache
 
 from benchmark import harness
-from benchmark.reference import gpt2 as reference
 
 
 def build(ctx):
@@ -57,7 +57,22 @@ def warm_up(srv, items):
     # a sampled and a greedy request share one decode executable (the flag
     # is an operand), so nothing else is left to compile
     srv.reset_stats()
+    settle_heap()
     return len(seen)
+
+
+def settle_heap():
+    """Collect what set-up left behind and move the survivors out of the
+    collector's reach (``gc.freeze``), as a long-running server does once
+    it is warm.  The collector stays on: it goes on collecting what the
+    window allocates.  Without this, the one full collection that falls
+    into a 40 s window walks the 415,000 objects of imports and traced
+    programs and stops the host for 141 to 144 ms, once, at a moment that
+    differs from run to run: the 16 requests in flight carry it into
+    ``tpot_ms_p95`` (10.01 to 10.27 ms with it, 9.63 to 9.70 without, three
+    runs each, alternating; my chip runs, PR 27)."""
+    gc.collect()
+    gc.freeze()
 
 
 class OpenLoopFeeder:
@@ -120,7 +135,9 @@ def run_window(ctx, srv, eng, feeder):
         now = time.monotonic() - t0
         trace.poll(now)
         if now >= ctx.seconds:
-            trace.stop()                 # the stall falls after the window
+            # the stall of writing the trace falls after the window, and is
+            # the benchmark's own: the backlog gets its whole time to drain
+            limit += trace.stop()
         batch = feeder.take(now, srv)
         if batch:
             with spans.span("submit"):
@@ -149,9 +166,10 @@ def run_window(ctx, srv, eng, feeder):
     trace.stop()
     in_window = ctx.compile_count(eng.compile_report()) - compiled_before
     late = np.asarray(lateness or [0.0])
+    drain_s = t_end - t0 - ctx.seconds
     log(f"generator lateness: median {np.median(late) * 1e3:.2f} ms, max "
-        f"{late.max() * 1e3:.2f} ms; drained {t_end - t0 - ctx.seconds:.2f} s"
-        f" after the window; {in_window} compilation(s) inside it")
+        f"{late.max() * 1e3:.2f} ms; drained {drain_s:.2f} s after the "
+        f"window; {in_window} compilation(s) inside it")
     rows = request_rows(
         srv, issued + [(None, it) for it in feeder.unsent()], t0, t_end)
     return {
@@ -161,7 +179,7 @@ def run_window(ctx, srv, eng, feeder):
                      **harness.cache_counters(ctx.compiles,
                                               eng.compile_report())},
         "facts": {**shape_facts(ctx, srv), "live_tokens": live_tokens,
-                  "window": (t0, t0 + ctx.seconds),
+                  "window": (t0, t0 + ctx.seconds), "drain_s": drain_s,
                   "lateness_ms": {"median": float(np.median(late) * 1e3),
                                   "max": float(late.max() * 1e3)}},
         "trace_path": trace.path,
@@ -169,10 +187,15 @@ def run_window(ctx, srv, eng, feeder):
     }
 
 
+COUNTERS = ("completed", "decode_steps", "generated_tokens",
+            "state_reused_steps", "state_uploads")
+
+
 def counters(srv):
+    """The engine's own counts since the warm-up's ``reset_stats()``; one a
+    program does not keep is left out, and its metric with it."""
     st = srv.stats()
-    return {"completed": st["completed"], "decode_steps": st["decode_steps"],
-            "generated_tokens": st["generated_tokens"]}
+    return {name: st[name] for name in COUNTERS if name in st}
 
 
 def request_rows(srv, issued, t0, t_end):
@@ -203,6 +226,42 @@ def request_rows(srv, issued, t0, t_end):
     return rows
 
 
+def tokens_per_s(rows, seconds):
+    """``(rate, requests)``: prompt tokens prefilled plus tokens generated
+    of the requests COMPLETED inside the window, per second of the window,
+    and how many those were."""
+    in_time = [r for r in rows if r["done_s"] <= seconds]
+    return (sum(r["prompt"] + r["generated"] for r in in_time) / seconds,
+            len(in_time))
+
+
+def check_picks(items, n):
+    """The ``n`` requests whose prompts the check seats: spread over the
+    schedule's prompt lengths from the 10th to the 95th percentile."""
+    by_len = sorted(items, key=lambda it: len(it.prompt))
+    return [by_len[int(q * (len(by_len) - 1))]
+            for q in np.linspace(0.1, 0.95, n)]
+
+
+def padded_rows(rows):
+    """Token rows of unequal length as one ``(n, longest)`` int32 array,
+    padded on the right, and each row's last position."""
+    padded = np.zeros((len(rows), max(len(r) for r in rows)), np.int32)
+    for i, r in enumerate(rows):
+        padded[i, :len(r)] = r
+    return padded, np.array([len(r) - 1 for r in rows], np.int32)
+
+
+def logit_errors(got, ref):
+    """The two numbers the check compares: the largest difference over the
+    largest reference logit, and the root mean square of the differences
+    over that of the reference logits (every row and every vocabulary
+    entry: far steadier from seed to seed than a maximum)."""
+    got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
+    return (float(np.abs(got - ref).max() / np.abs(ref).max()),
+            float(np.sqrt(np.mean((got - ref) ** 2) / np.mean(ref ** 2))))
+
+
 def check(ctx, model, eng, srv, items):
     """Correctness, after the window.  A live decode step's logits through
     the paged kernel against the plain float32 reference's full forward over
@@ -212,12 +271,9 @@ def check(ctx, model, eng, srv, items):
     back, so the two never share the chip's memory."""
     spec = ctx.traffic["check"]
     log = ctx.log
-    by_len = sorted(items, key=lambda it: len(it.prompt))
-    picks = [by_len[int(q * (len(by_len) - 1))]
-             for q in np.linspace(0.1, 0.95, spec["slots"])]
     uids = [srv.submit(to_request(dataclasses.replace(
         it, new_tokens=spec["steps"] + 4, do_sample=False)))
-        for it in picks]
+        for it in check_picks(items, spec["slots"])]
     for _ in range(spec["steps"]):
         srv.step()
 
@@ -245,31 +301,29 @@ def check(ctx, model, eng, srv, items):
     srv.close()
 
     # the reference: float32, full forward, rows padded on the right
-    width = max(len(h) for h in histories)
-    padded = np.zeros((len(histories), width), np.int32)
-    for r, h in enumerate(histories):
-        padded[r, :len(h)] = h
-    last = np.array([len(h) - 1 for h in histories], np.int32)
+    padded, last = padded_rows(histories)
+    reference = harness.reference(ctx.config)
     ref = np.asarray(jax.jit(
         lambda p, t, pos: reference.logits_at(ctx.config, p, t, pos))(
         eng.params, jnp.asarray(padded), jnp.asarray(last)), np.float32)
     got = kernel[live]
-    err = float(np.abs(got - ref).max() / np.abs(ref).max())
+    err, rms = logit_errors(got, ref)
     agree = int((got.argmax(-1) == ref.argmax(-1)).sum())
     on_tpu = jax.default_backend() == "tpu"
     facts = {"logit_err": err, "logit_tol": spec["logit_tol"],
+             "logit_rms_err": rms, "logit_rms_tol": spec.get("logit_rms_tol"),
              "argmax_equal": f"{agree}/{len(live)}", "served": served,
              "blocks_recycled": recycled, "mosaic_calls": n_mosaic,
              "paged_impl": impl, "reference_rows": [len(h) for h in histories]}
-    ok = (np.isfinite(got).all() and err <= spec["logit_tol"] and served
+    ok = (np.isfinite(got).all() and err <= spec["logit_tol"]
+          and rms <= spec.get("logit_rms_tol", float("inf")) and served
           and recycled and impl == "kernel" and (n_mosaic > 0 or not on_tpu))
     log(f"check: {facts} -> {'ok' if ok else 'FAILED'}")
     return bool(ok), facts
 
 
 def shape_facts(ctx, srv):
-    """Sizes the per-layer readers price kernels with."""
-    c = ctx.config
-    return {"n_layer": c["n_layer"], "n_head": c["n_head"],
-            "head_dim": c["n_embd"] // c["n_head"], "n_embd": c["n_embd"],
+    """Sizes the per-layer readers price kernels with: the family's
+    ``dims`` and the pool's element size."""
+    return {**ctx.dims,
             "kv_bytes_per_element": 2 if srv.config.kv_bits == 16 else 1}

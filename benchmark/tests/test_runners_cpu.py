@@ -5,6 +5,7 @@ flow, the counts, the checks against the reference, and the result's shape.
 """
 
 import copy
+import gc
 import json
 
 import pytest
@@ -54,14 +55,47 @@ def run_tiny(name, seconds=2.0, **traffic_overrides):
     return result
 
 
-def test_serve_open_loop():
-    r = run_tiny("serve_chat")
+@pytest.mark.parametrize("cell, bound_to", [
+    ("serve_chat", "tpot_ms_p95"), ("serve_chat_sat", "serve_tokens_per_s")])
+def test_serve_open_loop(cell, bound_to):
+    """The open-loop runner prints the metric ``BENCHMARK.json`` binds the
+    cell to and no other; both are among its facts."""
+    r = run_tiny(cell)
+    assert set(r["metrics"]) == {bound_to, "setup_s"}
+    assert r["metrics"][bound_to]["value"] == r["details"]["facts"][bound_to]
+    assert {"tpot_ms_p95", "serve_tokens_per_s", "drain_s"} \
+        <= set(r["details"]["facts"])
     assert r["correct"] and r["failed"] == 0
     assert r["attempted"] == 12                  # 6 req/s for 2 s
     c = r["details"]["counters"]
     assert c["completed"] == 12 and c["in_window_compiles"] == 0
     check = r["details"]["facts"]["check"]
     assert check["logit_err"] < 1e-2 and check["blocks_recycled"]
+
+
+def test_the_rms_limit_decides_correct_where_the_file_states_it():
+    """``logit_rms_tol`` is compared only in a cell whose traffic file has
+    it; both numbers are printed beside their limits either way."""
+    spec = {"slots": 4, "steps": 3, "logit_tol": 0.04}
+    sound = run_tiny("serve_chat", check={**spec, "logit_rms_tol": 0.5})
+    assert sound["correct"]
+    check = sound["details"]["facts"]["check"]
+    assert 0 < check["logit_rms_err"] < 0.5 == check["logit_rms_tol"]
+    tight = run_tiny("serve_chat", check={**spec, "logit_rms_tol": 1e-9})
+    assert not tight["correct"] and tight["failed"] == 0
+    assert run_tiny("serve_chat", check=spec)["details"]["facts"]["check"][
+        "logit_rms_tol"] is None
+
+
+def test_the_heap_is_settled_and_the_collector_stays_on():
+    from benchmark import serving
+    gc.unfreeze()
+    try:
+        serving.settle_heap()
+        assert gc.get_freeze_count() > 10_000     # what set-up left
+        assert gc.isenabled()
+    finally:
+        gc.unfreeze()
 
 
 def test_serve_offline():
