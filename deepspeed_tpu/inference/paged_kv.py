@@ -423,13 +423,21 @@ class PrefixIndex:
 # ------------------------------------------------------------- device pool
 def init_pool(n_layer: int, num_blocks: int, block_size: int, n_head: int,
               head_dim: int, dtype=jnp.bfloat16, kv_bits: int = 16,
-              quant_block: int = 64):
+              quant_block: int = 64, n_kv_head: Optional[int] = None):
     """Zeroed pool pytree (see module docstring for the layout).
 
     ``kv_bits=8`` stores int8 payloads + fp32 block scales over the head
-    dim (``quant_block`` clipped to a divisor of ``head_dim``)."""
+    dim (``quant_block`` clipped to a divisor of ``head_dim``).
+
+    ``n_kv_head`` (default ``n_head``: multi-head) is the number of K/V
+    heads a token keeps; the pool's minor dim is ``n_kv_head * head_dim``.
+    Grouped and multi-query models keep fewer K/V heads than they have
+    query heads, and ``n_layer`` counts their ATTENTION layers only."""
     assert kv_bits in (8, 16), f"kv_bits must be 8 or 16, got {kv_bits}"
-    shape = (n_layer, num_blocks, block_size, n_head * head_dim)
+    if n_kv_head is None:
+        n_kv_head = n_head
+    assert n_head % n_kv_head == 0, (n_head, n_kv_head)
+    shape = (n_layer, num_blocks, block_size, n_kv_head * head_dim)
     if kv_bits == 16:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     qb = pick_block(head_dim, quant_block)
@@ -473,8 +481,8 @@ def write_tokens(pool, layer, block_tables, lengths, k, v):
     ``layer``: scalar (traced inside the layer scan); ``block_tables``:
     (B, nb_max) int32; ``lengths``: (B,) int32 — the FIRST window
     token's position (window token i lands at ``lengths + i``);
-    ``k``/``v``: (B, W, H, hd) in compute dtype (W=1 is plain decode;
-    W=k+1 is the speculative scoring window).  Slots whose tables are
+    ``k``/``v``: (B, W, H, hd) in compute dtype, ``H`` the pool's K/V
+    heads (W=1 is plain decode; W=k+1 is the speculative scoring window).  Slots whose tables are
     all-scratch write into block 0 (discarded), and a window position
     that overflows the table (a speculative draft running past the
     slot's allocation) is REDIRECTED to the scratch block instead of
@@ -524,7 +532,9 @@ def gather_kv(pool, layer, block_tables, dtype, n_head):
     dtype here let a caller's fp16 model silently read bf16 views.
 
     Returns ``(keys, vals)`` of shape (B, nb_max·block_size, H, hd) in
-    ``dtype`` (``n_head`` splits the pool's merged minor dim) — position
+    ``dtype`` (``n_head`` splits the pool's merged minor dim: the pool's
+    K/V heads, which a grouped or multi-query caller repeats to its query
+    heads) — position
     p of slot b is row p of its view, so the caller's causal mask over
     ``lengths`` is layout-independent."""
     def view(name):
@@ -542,7 +552,8 @@ def gather_kv(pool, layer, block_tables, dtype, n_head):
 def write_prefill(pool, blocks, k, v):
     """Scatter a prefilled sequence's K/V into its assigned blocks.
 
-    ``blocks``: (nb,) int32 block ids; ``k``/``v``: (L, T, H, hd) with
+    ``blocks``: (nb,) int32 block ids; ``k``/``v``: (L, T, H, hd), ``L``
+    and ``H`` the pool's layers and K/V heads, with
     ``T == nb · block_size`` (the prompt padded up to a block multiple —
     pad rows are masked by the slot's length at attention time)."""
     L, T, H, hd = k.shape

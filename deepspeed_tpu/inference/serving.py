@@ -593,11 +593,22 @@ class ServingEngine:
         # a divisor of the head dim, never spanning two heads
         self._kv_quant_block = pk.pick_block(mc.head_dim,
                                              config.kv_quant_block)
+        # the model owns its serving state: K/V blocks for every family,
+        # and for one with recurrent layers the per-slot rows beside them
+        # (docs/serving.md#recurrent-state) — ONE pytree, donated whole
+        # through every prefill and decode dispatch
+        self._recurrent = bool(getattr(inner, "has_recurrent_state", False))
+        if self._recurrent:
+            self._refuse_for_recurrent_state(config)
         with jax.set_mesh(engine.mesh):
-            self.pool = pk.init_pool(
-                mc.n_layer, self.num_blocks, config.block_size, mc.n_head,
-                mc.head_dim, cache_dtype, kv_bits=config.kv_bits,
-                quant_block=config.kv_quant_block)
+            self.pool = inner.init_serving_state(
+                config.batch_slots, self.num_blocks, config.block_size,
+                kv_bits=config.kv_bits, quant_block=config.kv_quant_block,
+                dtype=cache_dtype)
+        self._recurrent_bytes = (inner.recurrent_state_bytes(self.pool)
+                                 if self._recurrent else 0)
+        self._kv_pool_bytes = pk.pool_bytes(self.pool) - self._recurrent_bytes
+        self._state_seats = 0
         self.allocator = pk.BlockAllocator(self.num_blocks)
         # shadow lifecycle sanitizer (docs/static-analysis.md#sanitizer):
         # OFF by default; config pin wins, else env DSTPU_SANITIZE /
@@ -783,6 +794,36 @@ class ServingEngine:
             f"kv_bits={config.kv_bits} "
             f"pool={pk.pool_bytes(self.pool) / 1e6:.1f} MB", ranks=[0])
 
+    @staticmethod
+    def _refuse_for_recurrent_state(config):
+        """A model with recurrent layers keeps, per stream, state that is
+        not in its K/V blocks.  Every feature below moves, shares or rolls
+        back a stream BY its blocks, so each would serve a silently wrong
+        stream: refused here, by name, until it learns the recurrent rows
+        (ROADMAP, Queue 2)."""
+        ships_images = ("the transfer queue ships block images: the decode "
+                        "side would seat K/V without the recurrent rows")
+        why = {
+            "prefix_cache": "a shared prefix's blocks carry no recurrent "
+                            "state: the borrower's scan would start from "
+                            "zeros, not from the prefix's end",
+            "kv_snapshot": "a block image holds K/V only: a restored "
+                           "stream would resume with another stream's "
+                           "recurrent rows",
+            "speculative": "its rollback is not advancing `lengths`; a "
+                           "recurrence has consumed the rejected drafts "
+                           "and cannot un-consume them",
+            "transfer": ships_images,
+            "role": ships_images,
+        }
+        for name, reason in why.items():
+            value = getattr(config, name)
+            if value not in (None, False, "mixed"):
+                raise ValueError(
+                    f"serving.{name}={value!r} cannot serve a model with "
+                    f"recurrent state: {reason} "
+                    "(docs/serving.md#recurrent-state)")
+
     # ------------------------------------------------------------- recovery
     def _recover(self, state):
         """Fold a replayed journal into this engine: finished records are
@@ -905,9 +946,8 @@ class ServingEngine:
         blocks = jnp.zeros((bucket // c.block_size,), jnp.int32)
         with jax.set_mesh(self.engine.mesh):
             dec_exe = self._decode.executable(*self._decode_args())
-            pre_exe = pf.executable(
-                self.engine.params, toks, self.pool, blocks, jnp.int32(1),
-                jnp.int32(0), jnp.float32(1.0), jnp.asarray(False))
+            pre_exe = pf.executable(*self._prefill_args(
+                toks, blocks, 0, 1, 0, 1.0, False))
         dec = _mem_analysis(dec_exe)
         if dec is None:
             return None
@@ -1348,21 +1388,11 @@ class ServingEngine:
             return fn
         deq = self._deq
         model = self.model
-        fwd_len = min(bucket, self.max_seq)
 
-        def prefill(params, toks, pool, blocks, t_real, seed, temp, flag):
-            cache = model.init_cache(1, fwd_len)
-            logits, cache = model.apply_with_cache(deq(params), toks, cache)
-            # both cache layouts expose (L, T, H, hd) at B=1
-            if cache["k"].shape[1] == 1:          # legacy (L, B, S, H, hd)
-                k, v = cache["k"][:, 0], cache["v"][:, 0]
-            else:                                  # seq-major (L, S, B, ...)
-                k, v = cache["k"][:, :, 0], cache["v"][:, :, 0]
-            if fwd_len < bucket:
-                pad = ((0, 0), (0, bucket - fwd_len), (0, 0), (0, 0))
-                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-            pool = pk.write_prefill(pool, blocks, k, v)
-            row = logits[0, t_real - 1][None]
+        def prefill(params, toks, pool, blocks, t_real, seed, temp, flag,
+                    slot=None):
+            row, pool = model.prefill_paged(deq(params), toks, pool, blocks,
+                                            slot, t_real)
             # prefill half of the quarantine sentinel: without it, a
             # request whose PREFILL logits are already non-finite would
             # sample a garbage first token — and at max_new_tokens == 1
@@ -1381,6 +1411,15 @@ class ServingEngine:
             donate_argnums=(2,))
         self._prefills[bucket] = fn
         return fn
+
+    def _prefill_args(self, toks, blocks, slot, t_real, seed, temp, flag):
+        """The prefill executable's operands.  The slot rides along only
+        for a model that keeps state per slot: every other family's
+        prefill keeps the eight operands it always had."""
+        args = (self.engine.params, jnp.asarray(toks), self.pool,
+                jnp.asarray(blocks), jnp.int32(t_real), jnp.int32(seed),
+                jnp.float32(temp), jnp.asarray(flag))
+        return args + (jnp.int32(slot),) if self._recurrent else args
 
     # ------------------------------------------------------------- scheduler
     def _admit(self):
@@ -1545,6 +1584,11 @@ class ServingEngine:
                 return
             bucket = pk.blocks_needed(T, c.block_size) * c.block_size
             prefill.attrs = {"prompt_len": T, "bucket": bucket}
+            if self._recurrent:
+                # what the recurrence walks and what it must not take in;
+                # the dispatch below writes the slot's recurrent rows whole
+                prefill.attrs.update(scan_tokens=T, pad_tokens=bucket - T)
+                self._state_seats += 1
             toks = np.zeros((1, min(bucket, self.max_seq)), np.int32)
             toks[0, :T] = req.tokens
             nb_pre = bucket // c.block_size
@@ -1552,11 +1596,9 @@ class ServingEngine:
             fn = self._prefill_fn(bucket)
             with jax.set_mesh(self.engine.mesh):
                 with self._spans.span("serving.prefill.dispatch"):
-                    read, self.pool = fn(
-                        self.engine.params, jnp.asarray(toks), self.pool,
-                        blk, jnp.int32(T), jnp.int32(req.seed),
-                        jnp.float32(req.temperature),
-                        jnp.asarray(req.do_sample))
+                    read, self.pool = fn(*self._prefill_args(
+                        toks, blk, slot, T, req.seed, req.temperature,
+                        req.do_sample))
                     read.copy_to_host_async()
             # the read syncs the prefill dispatch: the host waits here
             with self._spans.span("serving.prefill.readback"):
@@ -3093,6 +3135,7 @@ class ServingEngine:
         self._steps = 0
         self._reused_steps = 0
         self._state_uploads = 0
+        self._state_seats = 0
         self._outcomes = {k: 0 for k in OUTCOMES}
         self._requeued_total = 0
         self._err_window_last = (0, 0)
@@ -3133,6 +3176,12 @@ class ServingEngine:
                "state_reused_steps": self._reused_steps,
                "state_uploads": self._state_uploads,
                "generated_tokens": self._generated_total,
+               # what the donated pytree holds: K/V blocks, and for a
+               # model with recurrent layers its per-slot rows and how
+               # often a slot's rows were written whole
+               "kv_pool_bytes": self._kv_pool_bytes,
+               "recurrent_state_bytes": self._recurrent_bytes,
+               "state_seats": self._state_seats,
                "outcomes": dict(self._outcomes),
                "requeued": self._requeued_total,
                "breaker_open": self._breaker_open,

@@ -1,4 +1,5 @@
-"""Model zoo: GPT-2 family (flagship), BERT encoder, MoE GPT, GPT-J/NeoX."""
+"""Model zoo: GPT-2 family (flagship), BERT encoder, MoE GPT, GPT-J/NeoX,
+Jamba (Mamba + attention hybrid)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -20,6 +21,9 @@ def build(name, **overrides):
         if name.startswith("gptneox"):
             from .gptj import GPTNeoX
             return GPTNeoX(preset=name, **overrides)
+        if name.startswith("jamba"):
+            from .jamba import Jamba
+            return Jamba(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

@@ -731,6 +731,44 @@ class GPT2:
             f"{impl!r}")
         return impl
 
+    def init_serving_state(self, batch_slots, num_blocks, block_size,
+                           kv_bits=16, quant_block=64, dtype=None):
+        """The pytree the serving engine donates through its steps.  A
+        model owns its serving state; this family's is its K/V blocks and
+        nothing else (``batch_slots`` sizes nothing here)."""
+        from ..inference import paged_kv as pk
+        c = self.config
+        return pk.init_pool(c.n_layer, num_blocks, block_size, c.n_head,
+                            c.head_dim, dtype or self.dtype, kv_bits=kv_bits,
+                            quant_block=quant_block)
+
+    def prefill_paged(self, params, toks, pool, blocks, slot, t_real):
+        """One prompt, padded to its bucket, into the pool: the contiguous
+        cached forward on ONE sequence, its K/V scattered into ``blocks``.
+        ``toks``: (1, min(bucket, max_seq)) — a bucket rounded past
+        ``max_seq`` (max_seq not a block multiple) would trip
+        ``init_cache``'s position-table guard, so the extracted K/V rows
+        zero-pad up to the bucket for the block scatter (pad rows sit
+        beyond the slot's length: masked, then overwritten by decode
+        writes).  ``slot`` names the stream's batch slot for families whose
+        state lives per slot; unused here.  Returns ``(logits (1, V) at
+        token t_real - 1, pool)``."""
+        from ..inference import paged_kv as pk
+        fwd_len = toks.shape[1]
+        bucket = blocks.shape[0] * pool["k"].shape[2]
+        cache = self.init_cache(1, fwd_len)
+        logits, cache = self.apply_with_cache(params, toks, cache)
+        # both cache layouts expose (L, T, H, hd) at B=1
+        if cache["k"].shape[1] == 1:          # legacy (L, B, S, H, hd)
+            k, v = cache["k"][:, 0], cache["v"][:, 0]
+        else:                                  # seq-major (L, S, B, ...)
+            k, v = cache["k"][:, :, 0], cache["v"][:, :, 0]
+        if fwd_len < bucket:
+            pad = ((0, 0), (0, bucket - fwd_len), (0, 0), (0, 0))
+            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+        pool = pk.write_prefill(pool, blocks, k, v)
+        return logits[0, t_real - 1][None], pool
+
     def _attend_paged(self, q, keys, vals, lengths):
         """Per-slot masked attention of a W-token query window over
         gathered pool blocks — builds the paged mask and defers to the

@@ -84,7 +84,7 @@ def resolve_mode(mode: str) -> str:
 
 # ============================================================== exact kernel
 def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
-                  scale_attn, compute_dtype, quantized):
+                  scale_attn, compute_dtype, quantized, n_kv_head):
     """Grid (B, nb_max), block walk innermost (revisits scratch).
 
     Scores land in a full (H, W, S) fp32 row; the last block's visit
@@ -111,7 +111,11 @@ def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
         x = (dequantize_blockwise(x, s_ref[0, 0], bits=8,
                                   out_dtype=compute_dtype)
              if quantized else x.astype(compute_dtype))
-        return x.reshape(bs, n_head, head_dim)
+        if n_kv_head == n_head:
+            return x.reshape(bs, n_head, head_dim)
+        # grouped / multi-query: query head h reads KV head h // group
+        return jnp.repeat(x.reshape(bs, n_kv_head, head_dim),
+                          n_head // n_kv_head, axis=1)
 
     k = block(k_ref, ks_ref)
     v = block(v_ref, vs_ref)
@@ -172,7 +176,7 @@ def _exact_call(q, pool, tables, lengths, layer_arr, *, scale_attn):
     kernel = functools.partial(
         _exact_kernel, block_size=bs, nb_max=nb_max, n_head=H, head_dim=hd,
         n_window=W, scale_attn=scale_attn, compute_dtype=q.dtype,
-        quantized=quantized)
+        quantized=quantized, n_kv_head=HD // hd)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, H, hd), q.dtype),
@@ -186,7 +190,8 @@ def _round_up(x, m):
 
 
 def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
-                   compute_dtype, quant_block, group, rows_per_token):
+                   compute_dtype, quant_block, group, rows_per_token,
+                   q_per_kv=1):
     """Grid (B,): ONE program per slot walks the slot's LIVE tokens in
     chunks of ``group`` blocks (``group * block_size`` key positions: a
     whole number of 128-lane score columns) through a triple-buffered
@@ -209,7 +214,14 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     the (R, chunk) score/probability tiles, never a (chunk, H*hd) one:
     they arrive already transposed to (rows, positions)
     (:func:`_scale_rows`).  Rows of one head sum their partial scores
-    through a 0/1 matmul before the softmax."""
+    through a 0/1 matmul before the softmax.
+
+    Grouped / multi-query pools (``q_per_kv`` > 1 query heads to a KV
+    head; the pool's width is the KV heads alone): the ``q_per_kv`` query
+    heads of one KV head ride the WINDOW axis — the caller hands in
+    ``W * q_per_kv`` query rows of the pool's width — and share window
+    token ``w``'s causal limit.  ``q_per_kv == 1`` emits the program it
+    always did."""
     quantized = quant_block is not None
     if quantized:
         (tables_ref, lengths_ref, layer_ref, q_ref,
@@ -232,7 +244,7 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     # chunks that hold any position <= length + W - 1 (the window's last
     # row); everything past is masked for every row — skip the DMA
     n_chunks = -(-nb_max // G)
-    n_live = jnp.minimum((length + W + Tc - 1) // Tc, n_chunks)
+    n_live = jnp.minimum((length + W // q_per_kv + Tc - 1) // Tc, n_chunks)
 
     def fetches(c, slot):
         out = []
@@ -329,7 +341,8 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
                 preferred_element_type=jnp.float32)
         s = s * sm_scale
         k_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 1)
-        w_pos = jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 0) // Rw
+        w_pos = jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 0) // (
+            Rw * q_per_kv)
         last = jnp.minimum(length + w_pos, nb_max * bs - 1)
         s = jnp.where(k_pos <= last, s, NEG_INF)
         m_prev = m_ref[:]                                     # (R, 1)
@@ -371,9 +384,20 @@ def _scale_rows(scale, layer, tables, n_rows, n_cols):
 
 
 def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
-                 interpret):
+                 interpret, q_per_kv=1):
     B, W, H, hd = q.shape
     bs, HD = pool["k"].shape[2:]
+    if HD != H * hd:
+        # grouped / multi-query: (B, W, Hkv, G, hd) -> (B, W * G, Hkv * hd),
+        # the G query heads of a KV head as extra window rows (the kernel
+        # masks them by their token, not their row)
+        n_kv, G_q = HD // hd, H * hd // HD
+        qg = q.reshape(B, W, n_kv, G_q, hd).transpose(0, 1, 3, 2, 4)
+        out = _online_call(qg.reshape(B, W * G_q, n_kv, hd), pool, tables,
+                           lengths, layer_arr, scale_attn=scale_attn,
+                           interpret=interpret, q_per_kv=G_q)
+        out = out.reshape(B, W, G_q, n_kv, hd).transpose(0, 1, 3, 2, 4)
+        return out.reshape(B, W, H * hd)
     nb_max = tables.shape[1]
     quantized = "k_scale" in pool
     # chunk: the fewest blocks that make whole 128-lane score columns —
@@ -425,7 +449,8 @@ def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
     kernel = functools.partial(
         _online_kernel, block_size=bs, nb_max=nb_max, head_dim=hd,
         scale_attn=scale_attn, compute_dtype=q.dtype,
-        quant_block=qb if quantized else None, group=G, rows_per_token=Rw)
+        quant_block=qb if quantized else None, group=G, rows_per_token=Rw,
+        q_per_kv=q_per_kv)
     cp = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
@@ -442,7 +467,9 @@ def paged_attention(q, pool, block_tables, lengths, layer, *,
 
     - ``q``: (B, W, H, hd) in the attention compute dtype (W=1: plain
       decode; W=k+1: the speculative scoring window);
-    - ``pool``: the ``paged_kv`` pool pytree (16-bit or int8+scales);
+    - ``pool``: the ``paged_kv`` pool pytree (16-bit or int8+scales); its
+      width is ``n_kv_head * hd``, with ``n_kv_head`` a divisor of H
+      (H itself: multi-head; 1: multi-query);
     - ``block_tables``: (B, nb_max) int32 pool block ids (scratch-0
       padded); ``lengths``: (B,) int32 — position of the FIRST window
       token (its K/V already written, so ``k_pos <= lengths + w`` is
@@ -454,7 +481,10 @@ def paged_attention(q, pool, block_tables, lengths, layer, *,
     this kernel is tested against (exact mode to the last ulp on 16-bit
     pools, tolerance-bounded online/int8)."""
     B, W, H, hd = q.shape
-    assert pool["k"].shape[3] == H * hd, (pool["k"].shape, q.shape)
+    HD = pool["k"].shape[3]
+    # the pool holds the KV heads: all H of them, or (grouped / multi-query
+    # attention) a divisor of H, each shared by H // n_kv query heads
+    assert HD % hd == 0 and (H * hd) % HD == 0, (pool["k"].shape, q.shape)
     mode = resolve_mode(mode)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     tables = jnp.asarray(block_tables, jnp.int32)
