@@ -1,11 +1,11 @@
 """``ds_bench_diff``: the perf-regression gate over bench artifacts.
 
-Compares two bench JSON documents — live ``bench.py`` headlines
-(``parse_headline_tail`` output), committed ``BENCH_*.json`` /
-``SERVING_BENCH.json`` / ``INFERENCE_BENCH.json`` artifacts, or any mix
-— metric by metric, with per-metric **noise bands**, and exits non-zero
-on a regression beyond the band.  This is the gate the bench trajectory
-lacked: the artifacts were compared by eye across PRs.
+Compares two JSON documents of metrics — a ``benchmark/run.py`` result
+line, a stdout capture whose last line is one, or a recorded document
+(the tests' ``tests/data/bench_diff_fixture_*.json``) — metric by metric, with per-metric **noise bands**, and exits non-zero
+on a regression beyond the band.  The record of speed is
+``PERF_LEDGER.jsonl``, which the driver writes; this tool compares two
+documents by hand (ROADMAP D1b).
 
 Metric classification (by key name, innermost key of the JSON path):
 
@@ -68,7 +68,7 @@ LOWER_BETTER_SLO = ("burn_rate", "slo_breaches")
 # robustness regression
 LOWER_BETTER_ROUTER = ("lost_requests", "duplicate_answers",
                        "handoff_requeue_ms")
-# sanitizer family (docs/static-analysis.md#sanitizer): a clean rung
+# sanitizer family (docs/static-analysis.md#sanitizer): a clean run
 # must report zero lifecycle findings — any growth is a serving bug,
 # not noise
 LOWER_BETTER_SANITIZE = ("sanitizer_findings",)
@@ -85,7 +85,7 @@ LOWER_BETTER_PREFIX = ("unique_block_frac",)
 # disaggregation family (docs/serving.md#disaggregation): the per-stream
 # handoff cost (publish + seat + restore) and the decode-side
 # inter-token p99 the role split exists to flatten — both explicit here
-# even though the _ms suffix rule would catch them: the rung's headline
+# even though the _ms suffix rule would catch them: the headline
 # metrics must never silently drop to informational under a rename
 LOWER_BETTER_DISAGG = ("handoff_ms", "decode_cadence_p99_ms")
 # exact count contracts where ZERO is the baseline by design: any

@@ -1,7 +1,7 @@
-"""Shared probe models for the audit stages and bench rungs.
+"""Shared probe models for the audit stages.
 
-One source of truth for the tiny engines that ``--audit-step`` and the
-bench wire probes build: keeping a single parameterized fixture (instead
+One source of truth for the tiny engines that ``--audit-step`` builds:
+keeping a single parameterized fixture (instead
 of per-caller near-twins) means a change to the MoE constructor
 signature or the ``partition_specs`` contract lands everywhere at once.
 Imports stay inside methods — the analysis CLI must not pull jax in for
@@ -20,11 +20,11 @@ class MoEProbeModel:
       ``MoEProbeModel(dim, n_experts)`` — square, big enough that the
       expert exchange dominates the budget floors so the tightness
       check has margin.
-    - the ``moe_wire_compression_cpu8`` bench rung (``bench.py``) uses
-      ``io`` well under ``dim`` so the dense-grad all-reduce is noise
-      next to the dispatch/combine payload: on the pure ``expert=8``
-      mesh the expert params are EP-sharded (their grads never cross
-      the wire), and the exchange IS the wire being measured.
+    - a wire probe passes ``io`` well under ``dim`` so the dense-grad
+      all-reduce is noise next to the dispatch/combine payload: on a
+      pure ``expert=8`` mesh the expert params are EP-sharded (their
+      grads never cross the wire), and the exchange IS the wire being
+      measured.
     """
 
     def __init__(self, dim=16, num_experts=8, io=None, expert_mult=4):

@@ -18,12 +18,10 @@ Rule catalog (see ``docs/static-analysis.md``):
   DSTPU104  ad-hoc metric emission (``print``/direct ``json.dump``)
             in runtime/inference code — metrics go through the
             monitor bus (one schema) or the logger; deliberate
-            contractual outputs (the bench headline stdout line)
-            carry per-site suppressions                             (error)
+            contractual outputs carry per-site suppressions         (error)
 """
 
 import ast
-import os
 
 from . import Rule, register
 
@@ -226,17 +224,14 @@ class AdhocMetricEmission(Rule):
                    "ds_top and the schema tests cannot see")
 
     # scope: the runtime + inference trees (where the monitor bus is the
-    # one sanctioned metric path) and the bench driver (whose contractual
-    # stdout headline carries explicit per-site suppressions)
+    # one sanctioned metric path)
     SCOPE_DIRS = ("runtime/", "inference/")
-    SCOPE_FILES = ("bench.py",)
 
     def _in_scope(self, relpath):
         norm = relpath.replace("\\", "/")
         if "/monitor/" in norm or norm.startswith("monitor/"):
             return False              # the bus itself (and ds_top's table)
-        return any(d in norm for d in self.SCOPE_DIRS) or \
-            os.path.basename(norm) in self.SCOPE_FILES
+        return any(d in norm for d in self.SCOPE_DIRS)
 
     def check(self, tree, src, relpath):
         if not self._in_scope(relpath):

@@ -27,10 +27,10 @@ Inputs, all already on the bus (docs/monitoring.md#ds_explain):
 - the shared :data:`monitor.gauges.CHIP_TABLE` (peak FLOPS + HBM +
   ICI bandwidth per generation; ``--chip``/``--hbm-gb-s``/... override).
 
-This makes ROADMAP item 1's hand-argued "b8 decode at 0.48 of the HBM
-bound" (INFERENCE_BENCH.json) a regenerable report: the acceptance test
-replays that bench's numbers through this module and reproduces the
-fraction (tests/test_roofline.py).
+This makes a hand-argued "decode at such a fraction of the HBM bound" a
+regenerable report: the acceptance test replays a recorded step (cost
+analysis + wall time, ``tests/data/bench_diff_fixture_inference.json``)
+through this module and reproduces the fraction (tests/test_roofline.py).
 """
 
 import argparse

@@ -104,7 +104,7 @@ SITES = (
     "serving.prefill",         # before a request's prefill dispatch
     # replica-worker loop boundaries (inference/router.py): one visit per
     # worker iteration, so `@N` kills/hangs a REPLICA mid-traffic — the
-    # router chaos rung's deterministic replacement for ad-hoc SIGKILL
+    # router chaos tests' deterministic replacement for ad-hoc SIGKILL
     "serving.replica_crash_step",   # worker dies here (no clean shutdown)
     "serving.replica_hang_step",    # worker stalls here, then continues
     # between computing a request's answer and journaling its finish:

@@ -1198,8 +1198,7 @@ def replica_worker(spec: dict):
     throttle_s = spec.get("throttle_ms", 0) / 1e3
     try:
         if spec.get("warm", True):
-            # compile outside the traffic window (same policy as the
-            # bench rungs): the router must observe scheduling cadence,
+            # compile outside the traffic window: the router must observe scheduling cadence,
             # not a one-off XLA compile pretending to be a straggler.
             # The warmup uid is far outside router space; the router
             # counts its journal record as `unknown_results`.

@@ -16,8 +16,8 @@ MII/externals.  This module is that missing layer, built TPU-first:
   ``runtime/comm/quantized.py``) halving the KV byte term;
 - **fused decode** — the token step is the models' stacked-scan paged
   decode (``GPT2.decode_step_paged``): ONE executable per step for all
-  slots, not 4·L separately scheduled small matmuls (the measured b=8
-  scheduling-gap term, DECODE_PROFILE.json);
+  slots, not 4·L separately scheduled small matmuls with scheduling
+  gaps between them;
 - **one round trip a step** — the step's slot state (tables, lengths,
   tokens, seeds, indices, temperatures, flags) stays on the device and
   is advanced in-graph; the host sends it up, as one packed buffer, only
@@ -388,7 +388,7 @@ class ServingConfig:
     eos_token_id: Optional[int] = None
     preflight: bool = True          # memory-gate startup (see preflight())
     hbm_budget_bytes: Optional[int] = None   # None → backend memory_stats
-    preflight_safety: float = 0.92  # allocator headroom (bench.py's margin)
+    preflight_safety: float = 0.92  # allocator headroom
     max_queue: int = 4096
     # ---- resilience block (docs/serving.md#resilience) ----
     deadline_ms: Optional[float] = None   # per-request default; None = none

@@ -1,7 +1,7 @@
 """Small CNN for CIFAR-10 — the reference's introductory training example.
 
-Role parity: DeepSpeedExamples' `cifar10_deepspeed.py` (the tutorial model
-behind BASELINE graded config 1: "CIFAR-10 ZeRO-0 single-process").  Convs
+Role parity: DeepSpeedExamples' `cifar10_deepspeed.py` (the tutorial
+model: CIFAR-10, ZeRO-0, single process).  Convs
 run through ``lax.conv_general_dilated`` in NHWC — XLA maps them onto the
 MXU like matmuls.
 """

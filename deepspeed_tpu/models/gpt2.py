@@ -2,7 +2,7 @@
 
 Role parity: the reference validates against Megatron GPT-2 checkouts
 (``tests/model/Megatron_GPT2``, vendored mini-GPT2 in
-``tests/unit/megatron_model.py``); BASELINE's graded configs are GPT-2
+``tests/unit/megatron_model.py``); the presets are GPT-2
 125M → 1.3B.  This is a from-scratch JAX implementation designed for the
 hardware, not a port:
 
@@ -65,25 +65,6 @@ class GPT2Config:
     unroll_layers: bool = False
     # attention implementation: "auto" picks pallas flash on TPU, jnp elsewhere
     attention_impl: str = "auto"
-    # KV-cache decode path:
-    #   "fused"       — ONE lax.scan over the stacked layer weights per
-    #                   forward, seq-major (L, S, B, H, hd) cache carried
-    #                   in place.  The token step is a single executable
-    #                   (2 dispatches per generate(): prefill + token
-    #                   scan) instead of 4·L+1 separately scheduled small
-    #                   matmuls — the b=8 scheduling-gap term
-    #                   DECODE_PROFILE.json attributed (49 matmuls at
-    #                   0.68 of the weight-byte bound).  int8 weight
-    #                   payloads slice per layer INSIDE the scan, so
-    #                   quantized decode is also one fused launch.
-    #   "unroll"      — static per-layer loop over the same seq-major
-    #                   stacked cache (the pre-fusion fast path; kept for
-    #                   A/B measurement)
-    #   "legacy_scan" — per-layer batch-major (L, B, S, H, hd) cache
-    #                   restacked each call (the original scan path; a
-    #                   full cache copy per decoded token)
-    #   "auto"        — "fused"
-    decode_impl: str = "auto"
     # paged-attention implementation for the serving decode path
     # (decode_step_paged):
     #   "kernel"      — the in-place Pallas kernel
@@ -112,7 +93,7 @@ class GPT2Config:
         return self.n_embd // self.n_head
 
 
-# Named presets (BASELINE graded configs: 125M → 1.3B)
+# Named presets (125M → 1.3B)
 PRESETS = {
     "gpt2-125m": dict(n_embd=768, n_layer=12, n_head=12),
     "gpt2-350m": dict(n_embd=1024, n_layer=24, n_head=16),
@@ -470,16 +451,6 @@ class GPT2:
         return logits
 
     # ------------------------------------------------------- KV-cache decode
-    def decode_impl(self) -> str:
-        """Resolve ``config.decode_impl`` ("auto" → "fused")."""
-        impl = self.config.decode_impl
-        if impl == "auto":
-            impl = "fused"
-        assert impl in ("fused", "unroll", "legacy_scan"), (
-            f"decode_impl must be auto|fused|unroll|legacy_scan, got "
-            f"{impl!r}")
-        return impl
-
     def init_cache(self, batch_size: int, max_len: Optional[int] = None,
                    dtype=None):
         """Empty KV cache pytree: k/v stacked over layers
@@ -493,15 +464,10 @@ class GPT2:
             f"init_cache max_len={max_len} exceeds config.max_seq="
             f"{c.max_seq}; raise max_seq when building the model")
         dtype = dtype or self.dtype
-        if self.decode_impl() in ("fused", "unroll"):
-            # SEQ-MAJOR stacked cache (L, S, B, H, hd): the per-token
-            # update writes ONE contiguous (B, H, hd) block per layer —
-            # batch-major (L, B, S, ...) scatters B strided 1.5 KB rows
-            # per write, measured +0.078 ms/token at b=8 (~18% of the
-            # decode step; the r4 batch-gap's largest attributed term)
-            shape = (c.n_layer, max_len, batch_size, c.n_head, c.head_dim)
-        else:
-            shape = (c.n_layer, batch_size, max_len, c.n_head, c.head_dim)
+        # SEQ-MAJOR stacked cache (L, S, B, H, hd): the per-token update
+        # writes ONE contiguous (B, H, hd) block per layer, where
+        # batch-major (L, B, S, ...) scatters B strided rows per write
+        shape = (c.n_layer, max_len, batch_size, c.n_head, c.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
                 "index": jnp.zeros((), jnp.int32)}
 
@@ -577,7 +543,7 @@ class GPT2:
         return x + self._mm(h, p["fc_proj_w"], p["fc_proj_b"])
 
     def _cached_attention(self, p, h, cache_k, cache_v, index, is_local=None):
-        """Per-layer-cache variant (scan decode path; also GPT2MoE).
+        """Per-layer batch-major cache variant (GPT2MoE's decode).
 
         ``h``: normalized block input (B, T, D).  Returns
         (attn_out (B, T, D), new_cache_k, new_cache_v)."""
@@ -592,16 +558,14 @@ class GPT2:
 
     def _block_with_cache_stacked(self, x, layer_params, ck_all, cv_all,
                                   layer, index, is_local=None):
-        """One decode block updating the FULL stacked (L, B, S, H, hd)
-        cache IN PLACE via dynamic_update_slice at (layer, 0, index, 0, 0).
+        """One decode block updating the FULL stacked (L, S, B, H, hd)
+        cache IN PLACE via dynamic_update_slice at (layer, index, 0, 0, 0).
 
-        The unrolled decode loop threads the whole cache through every
-        layer so XLA aliases one buffer end-to-end (donated at the jit
-        boundary).  The per-layer variant below instead gathers
-        ``cache[i]`` copies and re-stacks them after the loop — a full
-        cache copy per decoded token, which is what broke batched decode
-        throughput (B-proportional copy traffic on top of the
-        B-independent weight streaming)."""
+        The decode scan threads the whole cache through every layer so
+        XLA aliases one buffer end-to-end (donated at the jit boundary);
+        gathering ``cache[i]`` copies and re-stacking them after the loop
+        would be a full cache copy per decoded token (B-proportional copy
+        traffic on top of the B-independent weight streaming)."""
         c = self.config
         p = layer_params
         h = _layer_norm(x, p["ln1_scale"], p["ln1_bias"], c.layer_norm_eps)
@@ -618,20 +582,6 @@ class GPT2:
                                    is_local, seq_major=True)
         attn = self._mm(attn, p["proj_w"], p["proj_b"])
         return self._ffn(p, x + attn), ck_all, cv_all
-
-    def _block_with_cache(self, x, layer_params, cache_k, cache_v, index,
-                          is_local=None):
-        """One block over ``x: (B, T, D)`` attending to cache[:index] + x.
-
-        Returns (y, new_cache_k, new_cache_v).  Static cache length; key
-        positions ≥ index+T are masked.
-        """
-        c = self.config
-        p = layer_params
-        h = _layer_norm(x, p["ln1_scale"], p["ln1_bias"], c.layer_norm_eps)
-        attn, cache_k, cache_v = self._cached_attention(
-            p, h, cache_k, cache_v, index, is_local)
-        return self._ffn(p, x + attn), cache_k, cache_v
 
     def apply_with_cache(self, params, tokens, cache):
         """Forward ``tokens: (B, T)`` starting at ``cache['index']``.
@@ -651,53 +601,27 @@ class GPT2:
             q_gather(params["wpe"], pos, dtype)
 
         local_flags = jnp.arange(c.n_layer) % 2 == 1
-        impl = self.decode_impl()
 
-        if impl == "fused":
-            # ONE lax.scan over the stacked layer weights: the whole
-            # layer stack is a single fused executable (an XLA while
-            # loop) — no scheduling gaps between 4·L separately
-            # dispatched small matmuls, the b=8 decode term
-            # DECODE_PROFILE.json isolated.  The seq-major stacked cache
-            # rides the carry (donated at the jit boundary → in-place);
-            # weights are scan xs, so each iteration dynamic-slices ONE
-            # layer's stack — including int8 {"q","scale"} payloads,
-            # whose per-layer slices stream int8 through q_matmul inside
-            # the same launch (the fix for the 49-pallas_call-per-token
-            # int8 route, ops/transformer/int8_matmul.py).
-            def fused_body(carry, xs):
-                h, ck, cv, layer = carry
-                lp, is_local = xs
-                h, ck, cv = self._block_with_cache_stacked(
-                    h, lp, ck, cv, layer, index, is_local)
-                return (h, ck, cv, layer + 1), None
+        # ONE lax.scan over the stacked layer weights: the whole layer
+        # stack is a single executable a step (an XLA while loop), not
+        # 4·L separately dispatched small matmuls with scheduling gaps
+        # between them.  The seq-major stacked cache rides the carry
+        # (donated at the jit boundary → in-place); weights are scan xs,
+        # so each iteration dynamic-slices ONE layer's stack — including
+        # int8 {"q","scale"} payloads, whose per-layer slices stream int8
+        # through q_matmul inside the same launch
+        # (ops/transformer/int8_matmul.py).
+        def fused_body(carry, xs):
+            h, ck, cv, layer = carry
+            lp, is_local = xs
+            h, ck, cv = self._block_with_cache_stacked(
+                h, lp, ck, cv, layer, index, is_local)
+            return (h, ck, cv, layer + 1), None
 
-            (x, new_k, new_v, _), _ = jax.lax.scan(
-                fused_body,
-                (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
-                (params["blocks"], local_flags))
-        elif impl == "unroll":
-            # static layer indices AND an in-place threaded cache: the
-            # stacked (L,B,S,H,hd) arrays flow through every layer's
-            # dynamic_update_slice, so a donated cache updates in place —
-            # no per-token full-cache re-stack (see
-            # _block_with_cache_stacked)
-            new_k, new_v = cache["k"], cache["v"]
-            for i in range(c.n_layer):
-                lp = layer_slice(params["blocks"], i)
-                x, new_k, new_v = self._block_with_cache_stacked(
-                    x, lp, new_k, new_v, i, index, local_flags[i])
-        else:
-            def scan_body(carry, xs):
-                h = carry
-                layer_params, ck, cv, is_local = xs
-                h, ck, cv = self._block_with_cache(h, layer_params, ck, cv,
-                                                   index, is_local)
-                return h, (ck, cv)
-
-            x, (new_k, new_v) = jax.lax.scan(
-                scan_body, x, (params["blocks"], cache["k"], cache["v"],
-                               local_flags))
+        (x, new_k, new_v, _), _ = jax.lax.scan(
+            fused_body,
+            (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
+            (params["blocks"], local_flags))
 
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"], c.layer_norm_eps)
         # bf16 operands + fp32 accumulation: a pure-fp32 head matmul runs
@@ -758,8 +682,10 @@ class GPT2:
         bucket = blocks.shape[0] * pool["k"].shape[2]
         cache = self.init_cache(1, fwd_len)
         logits, cache = self.apply_with_cache(params, toks, cache)
-        # both cache layouts expose (L, T, H, hd) at B=1
-        if cache["k"].shape[1] == 1:          # legacy (L, B, S, H, hd)
+        # (L, T, H, hd) at B=1.  init_cache is seq-major only since the
+        # batch-major decode went; the first branch is kept unchanged
+        # until a PR that may move this function's frame (ROADMAP D13)
+        if cache["k"].shape[1] == 1:          # batch-major, or T == 1
             k, v = cache["k"][:, 0], cache["v"][:, 0]
         else:                                  # seq-major (L, S, B, ...)
             k, v = cache["k"][:, :, 0], cache["v"][:, :, 0]
@@ -798,7 +724,7 @@ class GPT2:
         scratch block 0).  Returns ``(logits, new_pool)`` with logits
         (B, V) fp32 for 1-D ``toks`` and (B, W, V) for a window.
 
-        Same fused shape as ``decode_impl="fused"``: one ``lax.scan``
+        Same fused shape as :meth:`apply_with_cache`: one ``lax.scan``
         over the stacked layer weights, the pool carried in place, int8
         weight payloads sliced per layer inside the scan.  The
         attention core is the in-place Pallas kernel by default

@@ -1,7 +1,7 @@
 """GPT-2 with Mixture-of-Experts FFN layers.
 
 Role parity: the reference's MoE usage pattern (``deepspeed/moe/layer.py``
-applied inside Megatron GPT, and BASELINE's graded "GPT-MoE 350M×16e"
+applied inside Megatron GPT, e.g. a "GPT-MoE 350M×16e"
 config): every other transformer block replaces its dense FFN with a
 top-k-gated expert layer; the gate's aux loss is added to the LM loss with
 a configurable coefficient.

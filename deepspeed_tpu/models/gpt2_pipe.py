@@ -1,8 +1,8 @@
-"""Pipelined GPT-2: the PP×DP graded configuration.
+"""Pipelined GPT-2: the PP×DP configuration.
 
 Role parity: the reference's Megatron-GPT2-over-PipelineModule setup
-(BASELINE graded config "GPT-2 PP×DP"; reference `PipelineModule` wraps the
-transformer stack in `LayerSpec`s).  The embedding runs as the pipeline
+(reference `PipelineModule` wraps the transformer stack in
+`LayerSpec`s).  The embedding runs as the pipeline
 prologue, the final-LN + untied head as the epilogue, and the body is one
 `LayerSpec` per transformer block over the `pipe` axis.
 """

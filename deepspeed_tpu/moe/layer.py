@@ -47,7 +47,7 @@ class MOELayer:
       scattered buffer makes XLA emit the all-to-all.
     - ``"einsum"``: the GShard one-hot formulation — an S×(E·C) matmul each
       way, O(S²·M·cf) FLOPs.  MXU-friendly but quadratic in tokens; kept as
-      the numerics oracle and for comparison (examples/bench_moe.py).
+      the numerics oracle (``scatter``'s test reference).
     """
 
     # per-instance wire site ids: distinct layers (even same-shaped ones

@@ -233,8 +233,8 @@ class FleetView:
 
     # ---------------------------------------------------------- verdict
     def verdict(self) -> dict:
-        """The full machine-readable fleet verdict (``ds_fleet --json``
-        / the bench rung's merge check)."""
+        """The full machine-readable fleet verdict (``ds_fleet
+        --json``)."""
         hists = self.merged_hists()
         out = {
             "replicas": [

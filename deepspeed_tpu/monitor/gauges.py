@@ -2,9 +2,7 @@
 
 These helpers read ALREADY-AVAILABLE host state (backend memory stats,
 compiled-executable analyses, host batch shapes) — never device values,
-never anything that forces a sync.  ``bench.py`` imports
-:func:`peak_flops_per_chip` so the MFU gauge and the bench headline price
-compute against the same peak table.
+never anything that forces a sync.
 """
 
 import os
@@ -13,9 +11,9 @@ import numpy as np
 
 # Per-chip-generation nominal capability table (public datasheet
 # numbers): bf16 peak FLOPS, HBM bandwidth, and aggregate one-direction
-# ICI bandwidth per chip.  ONE table for the MFU gauge, bench.py's
-# headline pricing, AND the roofline attribution (`analysis/roofline.py`
-# / `ds_explain`) — the tool and the hand math cannot drift.  Keyed by a
+# ICI bandwidth per chip.  ONE table for the MFU gauge AND the roofline
+# attribution (`analysis/roofline.py` / `ds_explain`) — the tool and the
+# hand math cannot drift.  Keyed by a
 # lowercased `device_kind` substring; matched top-down.
 CHIP_TABLE = {
     "v5 lite":    {"peak_bf16_flops": 197e12, "hbm_gb_s": 819.0,

@@ -306,9 +306,9 @@ def _slot_walk_unroll(num_k_blocks):
 def _fwd_kernel_dma(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
                     seq_len, n_heads=1, use_merge=False):
     """LUT forward with MANUAL double-buffered K/V DMA (splash-attention
-    style).  The BlockSpec LUT path pays ~1.5×/slot vs static index maps
-    (SPARSE_BENCH limits analysis: all-ones LUT 0.457 ms vs dense 0.307 ms
-    at identical visited slots) because scalar-prefetch-dependent index
+    style).  The BlockSpec LUT path pays more per visited slot than static
+    index maps (no ledger cell measures it: ROADMAP D4) because
+    scalar-prefetch-dependent index
     maps serialize the pipeline's DMA issue with the index computation.
     Here K/V stay in HBM (``pltpu.ANY``); the kernel fetches block
     ``kmap[h, qi, j]`` into a 3-deep VMEM ring with explicit

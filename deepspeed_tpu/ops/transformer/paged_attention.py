@@ -6,9 +6,9 @@ Role parity: the reference's fused inference attention
 (``inference/paged_kv.py``).  The gather-based paged decode
 (``paged_kv.gather_kv``) materializes each slot's dense
 ``(B, nb_max·block_size, H, hd)`` K/V view per layer per step — written
-once and read once, 4× the slot's KV bytes of HBM traffic — which is
-exactly why INFERENCE_BENCH.json's b8 decode sat at 0.48 of the
-HBM-bandwidth bound while b1 (gather ≈ cache size) sat at 0.94.  This
+once and read once, 4× the slot's KV bytes of HBM traffic, growing with
+the batch (``analysis/roofline.py``: ``gather_materialization_bytes``;
+the ledger's ``kernels.paged_attention_roofline`` is this kernel's).  This
 kernel deletes the copy: per-slot **block tables and lengths enter as
 scalar-prefetch operands**, K/V blocks are DMA'd **directly from the
 pool in HBM**, int8 pools dequantize **in-kernel** from the fp32 block

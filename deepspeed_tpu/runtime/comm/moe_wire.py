@@ -172,7 +172,7 @@ class MoEWire:
         """Per-kind int8-wire byte expectation summed over every traced
         exchange (both directions, forward AND backward).  Empty until
         the first cold trace — a compile-cache warm start skips tracing,
-        so budget-driven flows (``--audit-step moe``, the bench rung)
+        so budget-driven flows (``--audit-step moe``)
         run one cold step first.  A (tag, site) pair recorded at several
         SHAPES is the same exchange re-specialized (an eval twin at a
         different batch shape, a warm re-specialization) — one compiled

@@ -3,7 +3,7 @@
 The reference DeepSpeed amortizes kernel build cost once per install
 (``op_builder/`` JIT compiles + prebuilt wheels); this XLA port instead
 paid full tracing+compilation on EVERY process start — ~50s of
-engine-ready time per bench rung, per CI test worker, per auto-resume and
+engine-ready time per benchmark run, per CI test worker, per auto-resume and
 per rewind-and-replay.  This module makes that a cached cost:
 
 - every jitted entry point (the fused ``_train_step``, the offload

@@ -197,7 +197,7 @@ class DeepSpeedEngine:
 
         # ---- persistent compiled-step cache (runtime/compile_cache.py) ----
         # AOT warm-start: every jitted entry point below dispatches through
-        # a CachedStep, so a process restart (bench rung, CI worker,
+        # a CachedStep, so a process restart (benchmark run, CI worker,
         # auto-resume, rewind-and-replay) deserializes yesterday's
         # executable instead of re-paying ~50s of XLA compilation.
         from . import compile_cache as ccache
@@ -733,7 +733,7 @@ class DeepSpeedEngine:
 
     def compile_report(self):
         """Compile-cache status + per-entry hit/miss/compile-ms events
-        for this engine's cache (surfaced by bench.py and ds_report)."""
+        for this engine's cache (surfaced by ds_report)."""
         from . import compile_cache as ccache
         return ccache.report(self.compile_cache)
 
@@ -829,8 +829,8 @@ class DeepSpeedEngine:
 
     def close(self):
         """Release device state, live compiled executables and staging
-        buffers.  ``del engine`` alone does NOT free these (the r5 bench
-        ladder leaked them across rungs until later configs died
+        buffers.  ``del engine`` alone does NOT free these (engines built
+        one after another in a process leak them until a later one dies
         RESOURCE_EXHAUSTED); call ``close()`` between engine lifetimes
         sharing one process.  A pending delayed-param update is dropped,
         not applied — close is teardown, not a checkpoint boundary."""

@@ -622,8 +622,8 @@ class ParamStreamRunner:
         """Engine shutdown: drop the jitted per-layer programs (and their
         live executables), the device nonblock tree, parked H2D staging
         buffers, and the NVMe swapper's pinned buffer pool.  ``del
-        engine`` frees none of these — the r5 bench ladder's cross-rung
-        leak class (VERDICT r5 weak #1)."""
+        engine`` frees none of these, so engines built one after another
+        in a process would leak them."""
         for entry in self._jit_cache.values():
             fns = entry.values() if isinstance(entry, dict) else (entry,)
             for fn in fns:

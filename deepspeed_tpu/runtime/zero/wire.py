@@ -286,9 +286,9 @@ class H2DUploader:
 
     def close(self):
         """Engine shutdown: drop every staging buffer and tracked pair.
-        The r5 bench ladder leaked these across configs (`del engine`
-        does not free buffers still referenced here) until later rungs
-        died RESOURCE_EXHAUSTED."""
+        `del engine` does not free buffers still referenced here:
+        engines built one after another in a process leaked them until a
+        later one died RESOURCE_EXHAUSTED."""
         self._fresh = []
         self._settled = []
         self._staging = []

@@ -47,8 +47,8 @@ logger = _LoggerFactory.create_logger(
 def route_logs_to_stderr():
     """Point the package logger's stream handlers at stderr.
 
-    For machine-readable stdout protocols — ``bench.py``'s final JSON
-    headline line and ``python -m deepspeed_tpu.analysis --json`` — the
+    For machine-readable stdout protocols — ``benchmark/run.py``'s final
+    JSON line and ``python -m deepspeed_tpu.analysis --json`` — the
     engine's INFO chatter must never interleave with (or trail) the
     payload the driver parses off stdout.
     """

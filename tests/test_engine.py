@@ -255,3 +255,52 @@ def test_grad_accum_dtype_validation():
         DeepSpeedConfig({"train_micro_batch_size_per_gpu": 1,
                          "data_types": {"grad_accum_dtype": "fp8"}},
                         world_size=1)
+
+
+# the benchmark's two training recipes (benchmark/traffic/train_z3_x4.json,
+# train_z1.json) at tiny size: mesh, ZeRO stage, micro batch
+@pytest.mark.parametrize("axes,stage,micro", [
+    ({"data": 1, "fsdp": 4}, 3, 1),
+    ({"data": 1}, 1, 4),
+], ids=["z3_fsdp4", "z1_one_device"])
+def test_cell_recipe_trains_at_tiny_size(devices, axes, stage, micro):
+    """What a short run of a training cell checks on the chip: bf16
+    compute with fp32 master and AdamW through ``ds.initialize`` /
+    ``train_batch``, remat of ``attn_out`` and ``mlp_fc``, the chunked
+    loss and ``attention_impl`` auto — three steps on one repeated batch
+    give a finite, falling loss; under ZeRO-3 each device of the mesh
+    holds about a quarter of params + master + moments, read from the
+    arrays' shards and not from their specs."""
+    import itertools
+    from benchmark.runners.train import state_share_by_device
+    from deepspeed_tpu.models import build
+    n = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=devices[:n])
+    model = build("gpt2-tiny", dtype=jnp.bfloat16, n_layer=2, max_seq=32,
+                  attention_impl="auto", remat=True,
+                  remat_policy="names:attn_out,mlp_fc", loss_chunk=64,
+                  embd_pdrop=0.0, attn_pdrop=0.0, resid_pdrop=0.0)
+    config = {
+        "train_micro_batch_size_per_gpu": micro,
+        "gradient_accumulation_steps": 1,
+        "steps_per_print": 10 ** 9,
+        "gradient_clipping": 1.0,
+        "bf16": {"enabled": True},
+        "optimizer": {"type": "AdamW",
+                      "params": {"lr": 1e-4, "weight_decay": 0.1}},
+        "zero_optimization": {"stage": stage},
+    }
+    engine, _, _, _ = ds.initialize(config=config, model=model, mesh=mesh,
+                                    rng_seed=0)
+    assert micro * n == 4              # both cells: a global batch of four
+    batch = np.random.default_rng(0).integers(
+        0, model.config.vocab_size, size=(4, 33)).astype(np.int32)
+    data = itertools.repeat(batch)
+    losses = [float(engine.train_batch(data)) for _ in range(3)]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0], losses
+
+    shares, _ = state_share_by_device(engine.state)
+    assert sorted(shares) == [d.id for d in devices[:n]]
+    if stage == 3:
+        assert all(0.25 <= s <= 0.30 for s in shares.values()), shares
+    engine.close()

@@ -512,32 +512,6 @@ def test_serving_preflight_failure_dumps_forensics(tmp_path):
         srv.close()
 
 
-def test_bench_backoff_dumps_forensics(tmp_path):
-    """A preflight micro-backoff leaves the probe trail + verdict dump
-    (bench.plan_micro_backoff's forensic hook)."""
-    sys.path.insert(0, REPO)
-    try:
-        from bench import plan_micro_backoff
-    finally:
-        sys.path.pop(0)
-    peaks = {8: 100, 4: 50, 2: 20}
-    micro, attempts = plan_micro_backoff(
-        8, lambda m: peaks.get(m), budget=30, safety=1.0,
-        forensic_dir=str(tmp_path),
-        ledger_fn=lambda: {"hbm": {"params": 100}},
-        context={"rung": "test"})
-    assert micro == 2 and len(attempts) == 3
-    dumps = [f for f in os.listdir(tmp_path) if f.startswith("bench_")]
-    assert len(dumps) == 1
-    doc = json.loads((tmp_path / dumps[0]).read_text())
-    assert doc["attempts"] == attempts
-    assert doc["verdict"]["over_budget_subsystem"] == "params"
-    # no backoff -> no dump
-    plan_micro_backoff(8, lambda m: 10, budget=30, safety=1.0,
-                       forensic_dir=str(tmp_path / "none"))
-    assert not os.path.isdir(tmp_path / "none")
-
-
 def test_verdict_space_selection():
     snap = {"hbm": {"params": 100, "paged_kv_pool": 500},
             "host": {"host_master_fp32": 50},
@@ -611,14 +585,15 @@ def test_bench_diff_gates_memory_family():
 
 
 def test_cli_smoke_bench_diff_and_ds_mem(tmp_path):
-    """Tier-1 smoke over the REAL CLIs: ds_bench_diff gates the
-    committed SERVING_BENCH.json against itself (clean exit), and
+    """Tier-1 smoke over the REAL CLIs: ds_bench_diff gates a
+    committed fixture against itself (clean exit), and
     ds_mem renders a synthetic mem-event stream — both executables are
     exercised on every run."""
+    fixture = os.path.join(REPO, "tests", "data",
+                           "bench_diff_fixture_serving.json")
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "bin", "ds_bench_diff"),
-         os.path.join(REPO, "SERVING_BENCH.json"),
-         os.path.join(REPO, "SERVING_BENCH.json")],
+         fixture, fixture],
         capture_output=True, text=True, timeout=60)
     assert r.returncode == 0, r.stderr
     assert "no regression" in r.stdout

@@ -627,8 +627,7 @@ def test_moe_wire_engine_loss_tracks_full(devices):
     data×expert mesh through the ENGINE (the moe route of
     comms_compression) — plus the wire census: int8 on the all_to_all,
     replica groups > 1 (two-level phase).  The >=3x reduction acceptance
-    runs at a payload-dominated scale in bench.py's
-    ``moe_wire_compression_cpu8`` rung and ``--audit-step moe``."""
+    runs at a payload-dominated scale in ``--audit-step moe``."""
     from deepspeed_tpu.analysis.jaxpr_audit import audit_engine
     from deepspeed_tpu.analysis.comms import wire_report
 

@@ -737,10 +737,11 @@ def test_prefix_interleave_reports_seeded_violation():
 
 def test_cli_smoke_bench_diff_gates_prefix_bench(tmp_path):
     """Tier-1 smoke over the REAL CLI: ds_bench_diff gates the
-    committed PREFIX_BENCH.json against itself (clean exit), and a
+    committed prefix-sharing fixture against itself (clean exit), and a
     degraded twin — hit rate halved, unique-block fraction doubled —
     regresses on exactly the prefix-sharing metrics."""
-    artifact = os.path.join(REPO, "PREFIX_BENCH.json")
+    artifact = os.path.join(REPO, "tests", "data",
+                            "bench_diff_fixture_prefix.json")
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "bin", "ds_bench_diff"),
          artifact, artifact],

@@ -1,11 +1,11 @@
 """Roofline attribution (``analysis/roofline.py`` / ``ds_explain``) and
 the ``ds_bench_diff`` perf-regression gate (docs/monitoring.md).
 
-The flagship test replays the hand-computed b8 paged-decode point from
-the committed INFERENCE_BENCH.json through a synthetic monitor stream
-and asserts ``ds_explain`` reproduces the achieved-fraction-of-HBM-bound
-figure within 10% — ROADMAP item 1's "0.48 of roofline" as a regenerable
-report, with the gather-materialization bytes named in the gap."""
+The flagship test replays a recorded b8 paged-decode step (weight and KV
+bytes, tokens/s: ``tests/data/bench_diff_fixture_inference.json``)
+through a synthetic monitor stream and asserts ``ds_explain`` reproduces
+the hand-computed achieved-fraction-of-HBM-bound within 10%, with the
+gather-materialization bytes named in the gap."""
 
 import json
 import os
@@ -19,6 +19,10 @@ from deepspeed_tpu.monitor.gauges import CHIP_TABLE, chip_specs
 from deepspeed_tpu.monitor.histogram import LogHistogram
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+INFERENCE_FIXTURE = os.path.join(REPO, "tests", "data",
+                                 "bench_diff_fixture_inference.json")
+SERVING_FIXTURE = os.path.join(REPO, "tests", "data",
+                               "bench_diff_fixture_serving.json")
 
 V5E = dict(CHIP_TABLE["v5e"], device_kind="TPU v5e", matched="v5e")
 
@@ -81,7 +85,7 @@ def test_chip_specs_resolves_and_falls_back():
 
 
 # ---------------------------------------------------------------------------
-# the flagship acceptance: reproduce INFERENCE_BENCH's hand-computed b8
+# the flagship acceptance: reproduce the fixture's hand-computed b8
 # ---------------------------------------------------------------------------
 
 def _synthetic_stream(tmp_path, bench_point):
@@ -112,11 +116,11 @@ def _synthetic_stream(tmp_path, bench_point):
 
 def test_ds_explain_reproduces_b8_hbm_fraction(tmp_path, capsys):
     """ds_explain over a monitor stream carrying the b8 paged-decode
-    bench's measured numbers must land within 10% of the hand-computed
-    INFERENCE_BENCH fraction_of_bound, call it HBM-bound, and name the
+    fixture's numbers must land within 10% of its hand-computed
+    fraction_of_bound, call it HBM-bound, and name the
     gather-materialization bytes in the gap decomposition."""
-    with open(os.path.join(REPO, "INFERENCE_BENCH.json")) as fh:
-        bench = json.load(fh)["gpt2_125m_b8_unroll"]
+    with open(INFERENCE_FIXTURE) as fh:
+        bench = json.load(fh)["b8_gather_step"]
     run = _synthetic_stream(tmp_path, bench)
     rc = rl.main([run, "--json"])
     assert rc == 0
@@ -143,8 +147,8 @@ def test_ds_explain_empty_and_missing_stream(tmp_path, capsys):
 
 
 def test_ds_explain_chip_override(tmp_path, capsys):
-    with open(os.path.join(REPO, "INFERENCE_BENCH.json")) as fh:
-        bench = json.load(fh)["gpt2_125m_b8_unroll"]
+    with open(INFERENCE_FIXTURE) as fh:
+        bench = json.load(fh)["b8_gather_step"]
     run = _synthetic_stream(tmp_path, bench)
     # price the same stream against v5p: 2765/819 ≈ 3.38x more headroom
     rc = rl.main([run, "--chip", "v5p", "--json"])
@@ -229,15 +233,13 @@ def test_bench_diff_zero_baseline_never_gates():
 
 
 def test_bench_diff_against_committed_artifact():
-    """The gate runs directly over the committed bench artifacts (the
-    advertised workflow: headline vs SERVING_BENCH.json)."""
-    path = os.path.join(REPO, "SERVING_BENCH.json")
-    with open(path) as fh:
+    """The gate runs directly over a committed JSON document."""
+    with open(SERVING_FIXTURE) as fh:
         doc = json.load(fh)
     r = bd.compare(doc, doc)
     assert not r["rows"] and not r["regressions"]
     worse = json.loads(json.dumps(doc))
-    worse["serving_125m_b8_cpu"]["tokens_per_sec"] *= 0.5
+    worse["serving_fixture"]["tokens_per_sec"] *= 0.5
     assert len(bd.compare(doc, worse)["regressions"]) == 1
 
 
@@ -330,13 +332,12 @@ def test_live_serving_exe_cost_is_impl_aware(devices):
 
 
 def test_ds_explain_kernel_b8_projection_meets_bound(tmp_path, capsys):
-    """ISSUE 14 acceptance: replaying the refreshed b8 KERNEL entry
-    (INFERENCE_BENCH.json gpt2_125m_b8_paged_kernel — the TPU-priced
-    projection) through the real ds_explain CLI must show
+    """ISSUE 14 acceptance: replaying the fixture's b8 KERNEL entry (a
+    projection, named as one) through the real ds_explain CLI must show
     gather_materialization_bytes == 0 for the kernel decode executable
     and an achieved HBM fraction >= 0.8."""
-    with open(os.path.join(REPO, "INFERENCE_BENCH.json")) as fh:
-        bench = json.load(fh)["gpt2_125m_b8_paged_kernel"]
+    with open(INFERENCE_FIXTURE) as fh:
+        bench = json.load(fh)["b8_kernel_step"]
     batch = bench["batch"]
     wall_ms = batch / bench["decode_tokens_per_sec_modeled"] * 1e3
     hbm_bytes = (bench["roofline"]["weight_bytes_mb"]
@@ -364,6 +365,6 @@ def test_ds_explain_kernel_b8_projection_meets_bound(tmp_path, capsys):
     assert v["paged_attention_impl"] == "kernel"
     assert v["gap"]["gather_materialization_bytes"] == 0
     assert v["achieved_frac"] >= 0.8
-    # and within 5% of the committed projection's own fraction
+    # and within 5% of the fixture's own fraction
     committed = bench["roofline"]["fraction_of_bound"]
     assert abs(v["achieved_frac"] - committed) <= 0.05

@@ -926,3 +926,69 @@ def test_ngram_draft_is_pure_and_matches_continuations(devices):
     # continuation runs off the end: pads with ITS last token
     np.testing.assert_array_equal(ngram_draft([8, 1, 8], 3, 1), [1, 8, 8])
     np.testing.assert_array_equal(ngram_draft([5, 5], 3, 1), [5, 5, 5])
+
+
+# ------------------------------------- a serving cell's checks, tiny size
+@pytest.mark.parametrize("kv_bits", [16, 8])
+def test_served_streams_and_decode_logits_match_oracles(kv_bits, devices):
+    """What a short run of a serving cell checks on the chip, held here
+    at tiny size through the public API: a bfloat16 model behind
+    ``ds.init_inference`` + ``ServingEngine(engine=...)`` with more
+    requests than slots finishes every request and recycles every block;
+    on a 16-bit pool its greedy streams equal sequential ``generate``
+    token for token.  Then a decode step over blocks that
+    ``prefill_paged`` wrote (prompt lengths off the block grid): the
+    kernel's logits equal the ``gather_kv`` + ``_masked_attend`` oracle's
+    on the same pool bit for bit, 16-bit and int8 (the interpreted kernel
+    is exact; the int8 pool itself is lossy, so its streams are held to
+    completion, not to ``generate``)."""
+    import dataclasses
+    import deepspeed_tpu as ds
+    model = _tiny_model(jnp.bfloat16)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    eng = ds.init_inference(model, params=params, dtype=jnp.bfloat16)
+    assert eng.mesh.size == 1          # one device, not the whole host
+    rng = np.random.default_rng(11)
+    bs, new = 8, 4
+    reqs = [Request(tokens=rng.integers(0, 128, (n,)).astype(np.int32),
+                    max_new_tokens=new, seed=i)
+            for i, n in enumerate((5, 9, 13, 7))]
+    srv = ServingEngine(engine=eng, config={
+        "batch_slots": 2, "block_size": bs, "kv_bits": kv_bits})
+    res = srv.run(reqs)
+    assert srv.stats()["completed"] == len(reqs)
+    assert all(r["outcome"] == OK and len(r["tokens"]) == new
+               for r in res.values())
+    assert srv.allocator.free_blocks == srv.num_blocks - 1
+    if kv_bits == 16:
+        for r in reqs:
+            full = np.asarray(eng.generate(np.asarray(r.tokens)[None],
+                                           max_new_tokens=new))
+            assert res[r.uid]["tokens"] == full[0, len(r.tokens):].tolist()
+    srv.close()
+
+    # ---- one decode step, kernel vs oracle, over prefilled blocks
+    c = model.config
+    oracle = GPT2(dataclasses.replace(c, paged_attention_impl="gather"),
+                  dtype=jnp.bfloat16)
+    assert model.paged_attention_impl() == "kernel"
+    nb_max = c.max_seq // bs
+    pool = model.init_serving_state(len(reqs), 1 + len(reqs) * 2, bs,
+                                    kv_bits=kv_bits, quant_block=8)
+    tables = np.zeros((len(reqs), nb_max), np.int32)
+    cur = []
+    prefill = jax.jit(model.prefill_paged)
+    for b, r in enumerate(reqs):
+        blocks = np.asarray([1 + 2 * b, 2 + 2 * b], np.int32)
+        tables[b, :2] = blocks
+        toks = np.zeros((1, 2 * bs), np.int32)
+        toks[0, :len(r.tokens)] = r.tokens
+        row, pool = prefill(params, jnp.asarray(toks), pool,
+                            jnp.asarray(blocks), b, len(r.tokens))
+        cur.append(int(np.argmax(np.asarray(row)[0])))
+    args = (params, jnp.asarray(cur, jnp.int32), pool, jnp.asarray(tables),
+            jnp.asarray([len(r.tokens) for r in reqs], jnp.int32))
+    got = np.asarray(jax.jit(model.decode_step_paged)(*args)[0])
+    want = np.asarray(jax.jit(oracle.decode_step_paged)(*args)[0])
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)

@@ -271,8 +271,8 @@ def test_lut_compresses_grid():
 def test_sparse_beats_dense_flash_on_tpu():
     """The LUT grid's time scales with the LIVE block count: at T=16384 a
     window+global Longformer layout must clearly beat dense flash
-    (measured 2.92x — SPARSE_BENCH.json; the reference claims 6.3x at
-    higher sparsity, README.md:39).  Timed with in-graph iterations: the
+    (the reference claims 6.3x at higher sparsity; no ledger cell
+    measures this kernel).  Timed with in-graph iterations: the
     remote-attach dispatch jitter otherwise swamps single calls."""
     import time
     from jax import lax
