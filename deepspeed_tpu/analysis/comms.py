@@ -65,6 +65,9 @@ class CensusEntry:
     dtypes: tuple = ()        # payload dtype names (classification)
     groups: int = 0           # replica-group count (HLO; 0 = unknown).
     #                           >1 marks a sub-axis ("two-level") phase
+    shapes: tuple = ()        # the payload's arrays, (dims, bytes) each (HLO)
+    loop: Optional[str] = None  # the while body the op runs in (HLO level)
+    trips: int = 1            # times a step it runs: enclosing trip counts
 
     @property
     def quantized(self) -> bool:
@@ -92,6 +95,48 @@ def summarize(census) -> dict:
         if e.quantized:
             rec["quantized_count"] += 1
             rec["quantized_bytes"] += e.bytes
+    return out
+
+
+def _param_shaped(dims, param_shapes) -> bool:
+    """Is an array of ``dims`` a parameter, one layer of a stacked one,
+    or a vector?  Unit dims do not count, and a dim may be up to a
+    twentieth larger than the parameter's (the TPU compiler pads what
+    it reduce-scatters)."""
+    dims = tuple(d for d in dims if d != 1)
+    if len(dims) < 2:
+        return True
+    for shape in param_shapes:
+        for cand in (shape, shape[1:]):
+            cand = tuple(d for d in cand if d != 1)
+            if len(cand) == len(dims) and all(
+                    c <= d <= c + c // 20 for c, d in zip(cand, dims)):
+                return True
+    return False
+
+
+def step_collectives(census, param_shapes=()) -> dict:
+    """What one step of a compiled program moves, each entry weighted by
+    the trip counts of the loops it sits in: payload bytes of the
+    all-gathers, of the reductions (reduce-scatter + all-reduce), of
+    everything else, and ``non_param_bytes``: of arrays that have no
+    parameter's shape (nor a layer's slice of one, nor a vector's).  A
+    ZeRO-3 step that gathers weights reads about 0 there and 1x / 0.5x
+    the 16-bit parameter bytes a pass in the first two
+    (arXiv:1910.02054 §7); one that moves activations, logits or token
+    ids instead grows there with batch x T."""
+    out = {"all_gather_bytes": 0, "reduce_bytes": 0, "other_bytes": 0,
+           "non_param_bytes": 0, "collectives": 0}
+    for e in census:
+        n = e.trips
+        key = {"all_gather": "all_gather_bytes",
+               "reduce_scatter": "reduce_bytes",
+               "all_reduce": "reduce_bytes"}.get(e.kind, "other_bytes")
+        out[key] += e.bytes * n
+        out["collectives"] += n
+        out["non_param_bytes"] += n * sum(
+            nbytes for dims, nbytes in e.shapes
+            if not _param_shaped(dims, param_shapes))
     return out
 
 

@@ -266,9 +266,16 @@ def _alias_param_numbers(hlo_text):
 # missing those would under-count exactly the dominant traffic)
 _HLO_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 _HLO_COLLECTIVE_RE = re.compile(
-    r"=\s*(\([^)]*\)|[a-z0-9]+\[[\d,]*\][^=(]*?)\s*"
-    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start|-scatter)?\(")
+    r"\s(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(-start)?\(")
+_HLO_COMPUTATION_RE = re.compile(
+    r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_HLO_CALLEE_RE = re.compile(
+    r"(body|condition|calls|to_apply)=(%?[\w.\-]+)"
+    r"|(branch_computations)=\{([^}]*)\}")
+_HLO_TRIPS_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
+_HLO_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
+_HLO_S32_CONSTANT_RE = re.compile(r"=\s*s32\[\][^ ]*\s+constant\((\d+)\)")
 
 _HLO_DTYPE_NP = {"bf16": "uint16", "f16": "float16", "f32": "float32",
                  "f64": "float64", "s32": "int32", "s8": "int8",
@@ -278,47 +285,155 @@ _HLO_DTYPE_NP = {"bf16": "uint16", "f16": "float16", "f32": "float32",
 
 
 # replica_groups={{0,4},{1,5}} (explicit) or =[2,4]<=[8] (iota: 2 groups of 4)
-_REPLICA_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),\d+\]")
+_REPLICA_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _REPLICA_GROUPS_EXPL_RE = re.compile(r"replica_groups=\{(\{[^=]*?\})\}")
 
 
-def _group_count(line: str) -> int:
+def _groups(line: str):
+    """(group count, devices a group) of a collective's line; 0 unknown."""
     m = _REPLICA_GROUPS_IOTA_RE.search(line)
     if m:
-        return int(m.group(1))
+        return int(m.group(1)), int(m.group(2))
     m = _REPLICA_GROUPS_EXPL_RE.search(line)
     if m:
-        return m.group(1).count("{")
-    return 0
+        n = m.group(1).count("{")
+        return n, (m.group(1).count(",") + 1) // max(n, 1)
+    return 0, 0
+
+
+def _tuple_elements(text: str):
+    """Top-level elements of ``(a, (b, c), d)``; ``[text]`` if no tuple."""
+    text = text.strip()
+    if not text.startswith("("):
+        return [text]
+    out, depth, last = [], 0, 1
+    for i, ch in enumerate(text):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                out.append(text[last:i])
+                break
+        elif ch == "," and depth == 1:
+            out.append(text[last:i])
+            last = i + 1
+    return out
+
+
+def _hlo_loops(lines):
+    """``{computation: (innermost while body or None, trips)}``: for each
+    computation of the module the loop body it runs in (through fusions
+    and calls) and the product of the enclosing loops' known trip counts
+    (an unknown count is taken as 1)."""
+    parent, comp = {}, None      # callee -> (caller, is_body, trips)
+    limits, whiles = {}, []      # computation -> its s32 constants
+    for line in lines:
+        m = _HLO_COMPUTATION_RE.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        if comp is None or "=" not in line:
+            continue
+        m = _HLO_S32_CONSTANT_RE.search(line)
+        if m:
+            limits.setdefault(comp, set()).add(int(m.group(1)))
+        called = {}
+        for role, name, _, names in _HLO_CALLEE_RE.findall(line):
+            for name in (name or names).split(","):
+                name = name.strip().lstrip("%")
+                called[role] = name
+                parent.setdefault(name, (comp, role == "body", 1))
+        if "body" in called:
+            trips = _HLO_TRIPS_RE.search(line)
+            whiles.append((called["body"], called.get("condition"),
+                           int(trips.group(1)) if trips else None))
+    for body, cond, trips in whiles:
+        if trips is None:
+            # the TPU's final text drops known_trip_count: a counted
+            # loop's condition compares against its one s32 constant
+            bound = limits.get(cond, ())
+            trips = next(iter(bound)) if len(bound) == 1 else 1
+        parent[body] = (parent[body][0], True, trips)
+    out = {}
+
+    def resolve(name, seen=()):
+        if name in out:
+            return out[name]
+        if name not in parent or name in seen:
+            return None, 1
+        caller, is_body, n = parent[name]
+        loop, trips = resolve(caller, seen + (name,))
+        out[name] = (name if is_body else loop, trips * n)
+        return out[name]
+
+    for name in list(parent):
+        resolve(name)
+    return out
 
 
 def census_from_hlo_text(hlo_text):
     """Collective census entries from an HLO module's text (parses both
     array-result and variadic tuple-result collectives).  Entries carry
     the payload dtype names (int8/int4 wire = a QUANTIZED collective,
-    ``comms.QUANT_DTYPE_NAMES``) and the replica-group count (>1 marks a
-    sub-axis phase of a two-level decomposition)."""
-    out = []
-    for m in _HLO_COLLECTIVE_RE.finditer(hlo_text):
-        result, op = m.group(1), m.group(2)
-        payload = 0
-        dtypes = []
+    ``comms.QUANT_DTYPE_NAMES``), the payload's arrays as ``(dims,
+    bytes)``, the replica-group count (>1 marks a sub-axis phase of a
+    two-level decomposition), the ``while`` body the op runs in and how
+    often (``loop``, ``trips``).  One entry a channel and payload: the
+    TPU compiler repeats an asynchronous collective in its start, fused
+    and done computations.  A reduce-scatter is priced at the tensor
+    REDUCED (its result times the group), as the all-reduce + slice the
+    TPU lowers it to is."""
+    lines = hlo_text.splitlines()
+    loops = _hlo_loops(lines)
+    out, comp, seen = [], None, set()
+    for line in lines:
+        m = _HLO_COMPUTATION_RE.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _HLO_COLLECTIVE_RE.search(line)
+        if not m or "=" not in line[:m.start()]:
+            continue
+        op, started = m.group(1), bool(m.group(2))
+        result = line[line.index("=") + 1:m.start()]
+        if started and op in ("all-gather", "collective-permute"):
+            # (operand, result, ...): the payload is the result
+            result = (_tuple_elements(result) + [""])[1]
+        channel = _HLO_CHANNEL_RE.search(line)
+        if channel is not None:
+            # the same channel AND payload: a repeat (the CPU backend
+            # gives distinct collectives one channel id)
+            key = (op, channel.group(1),
+                   tuple(_HLO_SHAPE_RE.findall(result)))
+            if key in seen:
+                continue
+            seen.add(key)
+        groups, group_size = _groups(line)
+        kind = canonical_kind(op) or op
+        scale = 1
+        if op == "reduce-scatter":
+            scale = max(group_size, 1)
+        elif op == "all-reduce" and (comp or "").startswith(
+                "all-reduce-scatter"):
+            kind = "reduce_scatter"      # the TPU's fused all-reduce + slice
+        payload, dtypes, shapes = 0, [], []
         for dtype_name, dims in _HLO_SHAPE_RE.findall(result):
             try:
                 itemsize = np.dtype(
                     _HLO_DTYPE_NP.get(dtype_name, dtype_name)).itemsize
             except TypeError:
                 continue
-            numel = int(np.prod([int(d) for d in dims.split(",") if d]
-                                or [1]))
-            payload += numel * itemsize
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            nbytes = int(np.prod(dims or (1,))) * itemsize * scale
+            payload += nbytes
             dtypes.append(dtype_name)
-        line_end = hlo_text.find("\n", m.end())
-        line = hlo_text[m.start():line_end if line_end > 0 else len(hlo_text)]
+            shapes.append((dims, nbytes))
+        loop, trips = loops.get(comp, (None, 1))
         out.append(CensusEntry(
-            kind=canonical_kind(op) or op, op=op, axes=(),
-            bytes=payload, eqn_path=None, level="hlo",
-            dtypes=tuple(dtypes), groups=_group_count(line)))
+            kind=kind, op=op, axes=(), bytes=payload, eqn_path=None,
+            level="hlo", dtypes=tuple(dtypes), groups=groups,
+            shapes=tuple(shapes), loop=loop, trips=trips))
     return out
 
 

@@ -9,8 +9,8 @@ hardware, not a port:
 - **scan over layers**: block params are stacked along a leading layer axis and
   the forward is one ``lax.scan`` — O(1) compile time in depth, and under
   ZeRO-3 the per-iteration all-gather of one layer's params IS the reference's
-  prefetch/release coordinator (``partitioned_param_coordinator.py``), done by
-  XLA.
+  prefetch/release coordinator (``partitioned_param_coordinator.py``): the
+  block states it (``zero/partition.gather_layer``), XLA schedules it.
 - **remat**: ``jax.checkpoint`` over the scanned block replaces the reference's
   activation-checkpointing subsystem for this model; the policy saves only
   block boundaries (+ optionally attention outputs).
@@ -233,33 +233,38 @@ def gpt2_block_forward(c, p, x, rng, deterministic, causal_mask, attend,
 def _chunked_head_nll(c, wte, x, labels):
     """Tied-head + cross-entropy over token chunks, each under
     ``jax.checkpoint``: per-chunk logits live only inside the chunk
-    (fwd AND bwd) — the (B·T, V) fp32 array never exists.  The token
-    axis pads up to a chunk multiple with masked rows (a divisor
-    search could degenerate to per-token chunks on prime counts).
+    (fwd AND bwd) — the (B·T, V) fp32 array never exists.  A chunk is
+    the same ``loss_chunk // B`` positions of EVERY row, so a
+    batch-sharded stream gives each device its rows of each chunk (a
+    chunk of whole rows would sit on one device and the others would
+    repeat its matmul).  The position axis pads up to a chunk multiple
+    with masked positions (a divisor search could degenerate to
+    per-token chunks on prime counts).
 
     ``x``: post-final-LN hidden states (B, T, D)."""
     B, T, D = x.shape
-    BT = B * T
-    chunk = min(int(c.loss_chunk), BT)
-    n = -(-BT // chunk)
-    pad = n * chunk - BT
-    xf = jnp.pad(x.reshape(BT, D), ((0, pad), (0, 0)))
-    lf = jnp.pad(labels.reshape(BT).astype(jnp.int32), (0, pad))
-    valid = jnp.pad(jnp.ones((BT,), jnp.float32), (0, pad))
-    xf = xf.reshape(n, chunk, D)
-    lf = lf.reshape(n, chunk)
-    valid = valid.reshape(n, chunk)
+    ct = min(max(int(c.loss_chunk) // B, 1), T)
+    n = -(-T // ct)
+    pad = ((0, 0), (0, n * ct - T))
+
+    def chunks(a):
+        """(B, T, ...) -> (n, B, ct, ...)"""
+        a = jnp.pad(a, pad + ((0, 0),) * (a.ndim - 2))
+        return jnp.swapaxes(a.reshape((B, n, ct) + a.shape[2:]), 0, 1)
 
     @jax.checkpoint
     def chunk_nll(xc, lc, vc):
+        xc, lc, vc = xc.reshape(B * ct, D), lc.reshape(-1), vc.reshape(-1)
         logits = jnp.einsum("td,vd->tv", xc, wte.astype(xc.dtype),
                             preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         lab = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
         return jnp.sum((lse - lab) * vc)
 
-    total = jax.lax.map(lambda args: chunk_nll(*args), (xf, lf, valid))
-    return jnp.sum(total) / BT
+    total = jax.lax.map(lambda args: chunk_nll(*args),
+                        (chunks(x), chunks(labels.astype(jnp.int32)),
+                         chunks(jnp.ones((B, T), jnp.float32))))
+    return jnp.sum(total) / (B * T)
 
 
 class GPT2:
@@ -365,9 +370,29 @@ class GPT2:
     # --------------------------------------------------------------- forward
     def _block(self, x, layer_params, rng, deterministic, causal_mask,
                is_local=None):
-        return gpt2_block_forward(self.config, layer_params, x, rng,
-                                  deterministic, causal_mask, self._attend,
-                                  is_local=is_local)
+        # ZeRO-3's fetch, inside what jax.checkpoint wraps: the layer's
+        # weights become whole here (forward and rematerialised backward,
+        # never a residual of the scan), the stream stays batch-sharded
+        from ..runtime.zero.partition import gather_layer, shard_stream
+        layer_params = gather_layer(layer_params, self._layer_specs())
+        return gpt2_block_forward(self.config, layer_params, shard_stream(x),
+                                  rng, deterministic, causal_mask,
+                                  self._attend, is_local=is_local)
+
+    def _whole(self, params, *names):
+        """The named unstacked leaves, whole over ``fsdp`` (the tied
+        embedding where the lookup and the head use it)."""
+        from ..runtime.zero.partition import gather_layer
+        specs = self.partition_specs()
+        whole = gather_layer({n: params[n] for n in names},
+                             {n: specs[n] for n in names})
+        return [whole[n] for n in names]
+
+    def _layer_specs(self):
+        """``partition_specs()`` of one layer: the stacked dim dropped."""
+        return jax.tree_util.tree_map(
+            lambda sp: P(*tuple(sp)[1:]), self.partition_specs()["blocks"],
+            is_leaf=lambda sp: isinstance(sp, P))
 
     def _attend(self, q, k, v, causal_mask, rng, deterministic):
         c = self.config
@@ -403,12 +428,14 @@ class GPT2:
         rng = rng if rng is not None else jax.random.PRNGKey(0)
         dtype = self.dtype
 
+        from ..runtime.zero.partition import shard_stream
+        wte, wpe = self._whole(params, "wte", "wpe")
         with jax.named_scope("embedding"):
             pos = jnp.arange(T)
-            x = (params["wte"].astype(dtype)[tokens]
-                 + params["wpe"].astype(dtype)[pos])
-            x = _dropout(x, c.embd_pdrop, jax.random.fold_in(rng, 17),
-                         deterministic)
+            x = wte.astype(dtype)[tokens] + wpe.astype(dtype)[pos]
+            x = shard_stream(_dropout(x, c.embd_pdrop,
+                                      jax.random.fold_in(rng, 17),
+                                      deterministic))
         causal_mask = jnp.tril(jnp.ones((T, T), bool))[None, None, :, :]
 
         block = self._block
@@ -445,8 +472,7 @@ class GPT2:
             # tied output head: bf16 operands, fp32 accumulation — full MXU
             # rate (a pure-fp32 matmul here runs at half rate and is ~25% of
             # 125M FLOPs)
-            logits = jnp.einsum("btd,vd->btv", x,
-                                params["wte"].astype(x.dtype),
+            logits = jnp.einsum("btd,vd->btv", x, wte.astype(x.dtype),
                                 preferred_element_type=jnp.float32)
         return logits
 
@@ -799,7 +825,8 @@ class GPT2:
         :func:`_chunked_head_nll`)."""
         x = self.apply(params, tokens, rng=rng, deterministic=False,
                        return_hidden=True)
-        return _chunked_head_nll(self.config, params["wte"], x, labels)
+        (wte,) = self._whole(params, "wte")
+        return _chunked_head_nll(self.config, wte, x, labels)
 
     # ------------------------------------------------- param-offload streaming
     def stream_fns(self):

@@ -135,6 +135,8 @@ def maybe_constrain(x, spec: P):
         return x
     names = set(am.axis_names)
     for entry in spec:
+        if entry is P.UNCONSTRAINED:
+            continue
         for ax in ((entry,) if isinstance(entry, str) else (entry or ())):
             if ax not in names:
                 return x

@@ -432,13 +432,18 @@ class CachedStep:
     """
 
     def __init__(self, name, jit_fn, cache=None, key_extra=None,
-                 donate_argnums=()):
+                 donate_argnums=(), describe=None):
         self.name = name
         self._jit = jit_fn
         self.cache = cache
         self.key_extra = key_extra or {}
         self.donate_argnums = tuple(donate_argnums)
         self._exes = {}        # args_signature -> (Compiled, key, source)
+        # ``describe(compiled, args) -> dict``: read once from each
+        # acquired executable, kept as ``described`` (the newest) and
+        # as attributes of its compile.build / compile.load span
+        self._describe = describe
+        self.described = None
 
     # jax.jit API surface used elsewhere in the repo
     def lower(self, *args, **kwargs):
@@ -543,7 +548,7 @@ class CachedStep:
         if material is not None:
             key = key_from_material(material)
             exe = self._try_deserialize(cache, key,
-                                        _execution_devices(lowered))
+                                        _execution_devices(lowered), args)
             if exe is not None:
                 hit = (exe, key, "cache")
                 self._exes[sig] = hit
@@ -555,6 +560,7 @@ class CachedStep:
             compiled = lowered.compile()
             if material is not None:
                 self._try_serialize(cache, key, compiled, material)
+            self._note(span, compiled, args)
         compile_ms = (span.t1 - span.t0) * 1000      # compile + serialise
         if material is not None:
             cache._count("misses")
@@ -564,7 +570,17 @@ class CachedStep:
         self._exes[sig] = hit
         return hit
 
-    def _try_deserialize(self, cache, key, devices):
+    def _note(self, span, exe, args):
+        if self._describe is None:
+            return
+        try:
+            self.described = self._describe(exe, args)
+        except Exception as e:       # a reading, never a reason to fail
+            logger.warning(f"{self.name}: executable not described ({e})")
+            return
+        span.attrs = {**span.attrs, **self.described}
+
+    def _try_deserialize(self, cache, key, devices, args=()):
         rec = spans.recorder()
         with rec.span("compile.load",
                       attrs={"fn": self.name, "source": "cache"}) as span:
@@ -588,6 +604,7 @@ class CachedStep:
                                f"{key[:16]} ({type(e).__name__}: {e}); "
                                "falling back to a fresh compile")
                 return None
+            self._note(span, exe, args)
         ms = (span.t1 - span.t0) * 1000              # read + deserialise
         cache._count("hits")
         cache._count("deserialize_ms", ms)
@@ -643,12 +660,13 @@ def executable_memory_analysis(exe):
     return out
 
 
-def wrap_step(name, fn, cache=None, key_extra=None, donate_argnums=()):
+def wrap_step(name, fn, cache=None, key_extra=None, donate_argnums=(),
+              describe=None):
     """jit + CachedStep in one place — the factory every engine's
     ``_wrap_step`` delegates to, so dispatch-policy changes land once."""
     return CachedStep(name, jax.jit(fn, donate_argnums=donate_argnums),
                       cache=cache, key_extra=key_extra,
-                      donate_argnums=donate_argnums)
+                      donate_argnums=donate_argnums, describe=describe)
 
 
 def report(cache):

@@ -686,12 +686,16 @@ class DeepSpeedConfig:
                               "reduce-scatter at stage>=2",
             "reduce_bucket_size": "XLA schedules its own collective "
                                   "bucketing",
-            "allgather_partitions": "stage-3 gathers come from the SPMD "
-                                    "partitioner",
+            "allgather_partitions": "a stage-3 model states its gathers "
+                                    "(zero/partition.gather_layer inside "
+                                    "the layer scan); the rest are the "
+                                    "SPMD partitioner's",
             "allgather_bucket_size": "XLA schedules its own collective "
                                      "bucketing",
-            "overlap_comm": "XLA's latency-hiding scheduler overlaps "
-                            "collectives with compute",
+            "overlap_comm": "XLA schedules a layer's gathers as "
+                            "asynchronous fusions under the layer's "
+                            "compute (3.5 % of a four-chip ZeRO-3 step "
+                            "left exposed, PERF.md PR 31)",
             "load_from_fp32_weights": "checkpoints always carry the fp32 "
                                       "master; loads restore it directly",
             "elastic_checkpoint": "checkpoints are always reshardable on "
@@ -701,14 +705,19 @@ class DeepSpeedConfig:
             "round_robin_gradients": "gradient placement is the fsdp "
                                      "sharding, not rank round-robin",
             "legacy_stage1": "single stage-1 implementation",
-            "stage3_prefetch_bucket_size": "the scanned layer loop + XLA "
-                                           "latency hiding do the prefetch",
-            "prefetch_bucket_size": "the scanned layer loop + XLA latency "
-                                    "hiding do the prefetch",
-            "stage3_max_live_parameters": "XLA frees gathered params after "
-                                          "last use inside the step",
-            "max_live_parameters": "XLA frees gathered params after last "
-                                   "use inside the step",
+            "stage3_prefetch_bucket_size": "no prefetch across layers: "
+                                           "each scan iteration gathers "
+                                           "its own layer, asynchronously",
+            "prefetch_bucket_size": "no prefetch across layers: each scan "
+                                    "iteration gathers its own layer, "
+                                    "asynchronously",
+            "stage3_max_live_parameters": "a gathered layer lives inside "
+                                          "its rematerialised block only: "
+                                          "one layer (and the tied "
+                                          "embedding) whole at a time",
+            "max_live_parameters": "a gathered layer lives inside its "
+                                   "rematerialised block only: one layer "
+                                   "(and the tied embedding) whole at a time",
             "stage3_max_reuse_distance": "XLA's scheduler owns re-gather "
                                          "decisions",
             "max_reuse_distance": "XLA's scheduler owns re-gather decisions",

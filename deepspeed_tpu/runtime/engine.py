@@ -448,11 +448,12 @@ class DeepSpeedEngine:
         # CachedStep wrappers: call-compatible with the jitted functions
         # (donation, .lower for the auditor/profiler) but warm-startable
         # from the persistent compile cache
-        self._jit_train_step = self._wrap_step("train_step",
-                                               self._train_step,
-                                               donate_argnums=(0,))
-        self._jit_grad_step = self._wrap_step("grad_only_step",
-                                              self._grad_only_step)
+        self._jit_train_step = self._wrap_step(
+            "train_step", self._train_step, donate_argnums=(0,),
+            describe=self._step_collectives)
+        self._jit_grad_step = self._wrap_step(
+            "grad_only_step", self._grad_only_step,
+            describe=self._step_collectives)
         self._jit_eval = None
 
         # ---- curriculum learning / PLD ------------------------------------
@@ -722,20 +723,39 @@ class DeepSpeedEngine:
             "comms_compression": cfg.comms_compression.describe(),
         }
 
-    def _wrap_step(self, name, fn, donate_argnums=()):
+    def _wrap_step(self, name, fn, donate_argnums=(), describe=None):
         """jit + CachedStep: the engine's dispatch path for a compiled
         entry point (AOT warm-start when the compile cache is on)."""
         from . import compile_cache as ccache
         return ccache.wrap_step(
             f"{type(self).__name__}.{name}", fn,
             cache=self.compile_cache, key_extra=self._cc_key_slice,
-            donate_argnums=donate_argnums)
+            donate_argnums=donate_argnums, describe=describe)
+
+    def _step_collectives(self, exe, args):
+        """What one call of a compiled step moves between devices: the
+        HLO collective census (``analysis/comms.step_collectives``),
+        priced by the loops' trip counts, with the bytes of collectives
+        whose payload has no parameter's shape, the sign that the
+        partitioner moves activations where ZeRO-3 should move weights."""
+        from ..analysis.comms import step_collectives
+        from ..analysis.jaxpr_audit import census_from_hlo_text
+        state = args[0]
+        shapes = {np.shape(leaf) for leaf in jax.tree_util.tree_leaves(
+            state.master if state.master is not None else state.params)}
+        return step_collectives(census_from_hlo_text(exe.as_text()), shapes)
 
     def compile_report(self):
         """Compile-cache status + per-entry hit/miss/compile-ms events
-        for this engine's cache (surfaced by ds_report)."""
+        for this engine's cache (surfaced by ds_report), and under
+        ``collectives`` what each acquired step executable moves."""
         from . import compile_cache as ccache
-        return ccache.report(self.compile_cache)
+        report = ccache.report(self.compile_cache)
+        report["collectives"] = {
+            w.name: w.described
+            for w in (self._jit_train_step, self._jit_grad_step)
+            if w.described}
+        return report
 
     def _install_moe_wire(self):
         """Make THIS engine's quantized expert wire (or its absence) the
@@ -1017,7 +1037,8 @@ class DeepSpeedEngine:
 
         def one_micro(mb, r):
             rs = jax.random.split(r, D)
-            (sl, aux), pg = vgrad(base, split_dp(mb), rs)
+            with zpart.one_rank_rows():
+                (sl, aux), pg = vgrad(base, split_dp(mb), rs)
             pg = jax.tree_util.tree_map(
                 lambda g: jax.lax.with_sharding_constraint(g, lead), pg)
             # per-slice aux -> microbatch aux (counts sum, ratios average)

@@ -14,21 +14,33 @@ TPU-native re-design of the reference's ZeRO machinery (SURVEY.md §7
 - reference stage 3 (``zero/stage3.py:228`` + ``partition_parameters.py:555``
   ``zero.Init`` param interception + ``partitioned_param_coordinator.py``
   fetch/prefetch/release state machine) → parameters themselves sharded on
-  ``fsdp`` everywhere; XLA all-gathers them per-use inside the step and frees
-  the gathered copies after use (prefetch/release ≈ XLA latency hiding +
-  scan-over-layers; ``param_persistence_threshold`` keeps small params
-  replicated exactly like the reference's persistence threshold).
+  ``fsdp`` everywhere, and the MODEL says where a layer's weights become
+  whole: :func:`gather_layer` inside the rematerialised block of its layer
+  scan (the fetch; the release is the end of the block: the gathered copy
+  is no residual of the scan, and the backward gathers again), with the
+  hidden stream held to the batch axes (:func:`shard_stream`).  Left to
+  itself the SPMD partitioner is free to keep the weights sharded and
+  move ACTIVATIONS instead (tensor parallelism over ``fsdp``): the CPU's
+  does so in every layer, and the TPU's did so for the tied head (a
+  411 MB logit all-reduce in each loss chunk, PERF.md §6, PR 31), while
+  gathering the block matrices by its own choice.  The constraint's
+  transpose hands each layer's weight gradient back reduce-scattered.
+  ``param_persistence_threshold`` keeps small params replicated exactly
+  like the reference's persistence threshold.
 
 The sharding rule for a single array: shard the LARGEST axis divisible by the
 fsdp extent (falls back to replicated if none divides), composing with any
 tensor-parallel spec the model declares.
 """
 
+import contextlib
 from typing import Optional
 
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ...parallel import mesh as M
 
 
 def shardable_axis(shape, extent: int, taken_axes=()) -> Optional[int]:
@@ -123,12 +135,13 @@ def grad_specs(params, stage: int, fsdp_size: int, *, tp_specs=None):
     return param_specs(params, min(stage, 2), fsdp_size, tp_specs=tp_specs)
 
 
+def _entry_axes(entry) -> tuple:
+    """The mesh axes one PartitionSpec entry names."""
+    return (entry,) if isinstance(entry, str) else tuple(entry or ())
+
+
 def _has_fsdp(spec: P) -> bool:
-    for entry in spec:
-        axes = (entry,) if isinstance(entry, str) else (entry or ())
-        if "fsdp" in axes:
-            return True
-    return False
+    return any("fsdp" in _entry_axes(entry) for entry in spec)
 
 
 def relayout_report(params, stage: int, old_fsdp: int, new_fsdp: int, *,
@@ -188,3 +201,59 @@ def constrain(tree, specs, mesh: Optional[Mesh] = None):
         bind = lambda sp: sp
     return jax.tree_util.tree_map(
         lambda x, sp: jax.lax.with_sharding_constraint(x, bind(sp)), tree, specs)
+
+
+def _less_fsdp(spec: P) -> P:
+    dims = []
+    for entry in spec:
+        axes = tuple(a for a in _entry_axes(entry) if a != "fsdp")
+        dims.append(axes if len(axes) > 1 else (axes[0] if axes else None))
+    return P(*dims)
+
+
+def gather_layer(layer_params, layer_specs):
+    """One layer's weights made whole over ``fsdp`` at the point of use.
+
+    ``layer_specs`` state the target: the model's own tensor-parallel
+    spec for each leaf (a traced leaf does not show its sharding), any
+    ``fsdp`` entry taken out.  Called INSIDE the rematerialised block of
+    a layer scan this is ZeRO-3's fetch: the partitioner all-gathers the
+    layer's slice of the sharded stack there, forward and again in the
+    rematerialised backward, the gathered copy is no residual of the
+    scan, and the constraint's transpose hands the layer's weight
+    gradient back sharded as the stack is (a reduce-scatter).  A leaf
+    that never was ``fsdp``-sharded (stages 0-2, under the persistence
+    threshold, already gathered by the qwZ route) has the sharding it is
+    constrained to: nothing is emitted.  Identity with no mesh."""
+    return jax.tree_util.tree_map(
+        lambda x, sp: M.maybe_constrain(x, _less_fsdp(sp)),
+        layer_params, layer_specs)
+
+
+_rank_rows = False
+
+
+@contextlib.contextmanager
+def one_rank_rows():
+    """While the engine traces ONE data-parallel rank's rows (the qgZ
+    partials, vmapped over ranks) a stream's batch dim is local to a
+    device: :func:`shard_stream` is the identity."""
+    global _rank_rows
+    before, _rank_rows = _rank_rows, True
+    try:
+        yield
+    finally:
+        _rank_rows = before
+
+
+def shard_stream(x):
+    """The hidden stream ``(batch, ...)`` held to the batch axes on its
+    batch dim, every other dim left as it is (a ``seq``- or
+    ``tensor``-sharded stream keeps what it has).  With whole weights
+    this is what makes the partitioner move weights, not activations.
+    Identity with no mesh, or where the batch does not divide."""
+    am = jax.sharding.get_abstract_mesh()
+    if _rank_rows or am.empty or x.shape[0] % M.dp_world_size(am):
+        return x
+    return M.maybe_constrain(
+        x, P(M.BATCH_AXES, *[P.UNCONSTRAINED] * (x.ndim - 1)))
