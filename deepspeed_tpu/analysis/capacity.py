@@ -322,12 +322,18 @@ def request_unique_blocks(*, prompt_tokens, max_new_tokens, block_size,
 def serving_plan(*, n_layer, n_head, head_dim, max_seq, block_size=16,
                  kv_bits=16, quant_block=64, batch_slots=8, num_blocks=0,
                  max_new_tokens=64, weight_bytes=0, prompt_tokens=None,
-                 shared_prefix_tokens=0) -> dict:
+                 shared_prefix_tokens=0, kv_layers=None) -> dict:
     """Closed-form serving memory plan mirroring ``paged_kv.init_pool``'s
     arithmetic exactly (tested equal to ``pool_bytes`` of a real pool):
     per-block bytes, total pool bytes for the configuration's block
     count, and the per-request block cost at the default generation
-    length (the ``ServingEngine.capacity()`` admission math)."""
+    length (the ``ServingEngine.capacity()`` admission math).
+
+    ``kv_layers`` is the model config's: the layer-applications that keep
+    K/V for a token, where that is not ``n_layer`` (a hybrid's attention
+    layers alone; a looped model's loops x layers)."""
+    if kv_layers is None:
+        kv_layers = n_layer
     nb_max = _ceil_div(max_seq, block_size)
     if not num_blocks:
         num_blocks = 1 + batch_slots * nb_max
@@ -344,7 +350,7 @@ def serving_plan(*, n_layer, n_head, head_dim, max_seq, block_size=16,
         per_tok = 2 * (cell * 1 + (cell // qb) * FP32_BYTES)   # k+v, +scales
     else:
         per_tok = 2 * cell * BF16_BYTES
-    per_block = n_layer * block_size * per_tok
+    per_block = kv_layers * block_size * per_tok
     # the unified per-request math (request_unique_blocks): the default
     # prompt (one block) reproduces the classic
     # ceil(min(max_seq, block_size + max_new) / block_size) exactly
@@ -553,6 +559,10 @@ def main(argv=None):
                     help="HBM budget for --max-streams (default 16, "
                          "v5e-class)")
     ap.add_argument("--layers", type=int, default=12)
+    ap.add_argument("--kv-layers", type=int, default=None,
+                    help="layer-applications that keep K/V for a token, "
+                         "where not --layers (the model config's "
+                         "kv_layers: a looped model's loops x layers)")
     ap.add_argument("--heads", type=int, default=12)
     ap.add_argument("--head-dim", type=int, default=64)
     ap.add_argument("--max-seq", type=int, default=1024)
@@ -596,7 +606,8 @@ def main(argv=None):
 
     if args.max_streams:
         plan = serving_plan(
-            n_layer=args.layers, n_head=args.heads, head_dim=args.head_dim,
+            n_layer=args.layers, kv_layers=args.kv_layers,
+            n_head=args.heads, head_dim=args.head_dim,
             max_seq=args.max_seq, block_size=args.block_size,
             kv_bits=args.kv_bits, max_new_tokens=args.max_new,
             weight_bytes=int(args.weight_gb * GIB),

@@ -549,14 +549,18 @@ def gather_kv(pool, layer, block_tables, dtype, n_head):
     return view("k"), view("v")
 
 
-def write_prefill(pool, blocks, k, v):
+def write_prefill(pool, blocks, k, v, layer=None):
     """Scatter a prefilled sequence's K/V into its assigned blocks.
 
     ``blocks``: (nb,) int32 block ids; ``k``/``v``: (L, T, H, hd), ``L``
     and ``H`` the pool's layers and K/V heads, with
     ``T == nb · block_size`` (the prompt padded up to a block multiple —
-    pad rows are masked by the slot's length at attention time)."""
-    L, T, H, hd = k.shape
+    pad rows are masked by the slot's length at attention time).
+
+    With ``layer`` (a scalar, traced inside a layer scan) ``k``/``v`` are
+    ONE layer's, (T, H, hd): a model whose pool has more layers than it can
+    hold a prompt's K/V for at once writes each as it is computed."""
+    T = k.shape[-3]
     bs = pool["k"].shape[2]
     nb = T // bs
     assert nb * bs == T, f"prefill length {T} is not a multiple of {bs}"
@@ -564,10 +568,12 @@ def write_prefill(pool, blocks, k, v):
         f"write_prefill needs exactly T//block_size={nb} block ids, got "
         f"{blocks.shape} (pass the sequence's FIRST nb blocks; later "
         "blocks fill during decode)")
+    assert k.ndim == (4 if layer is None else 3), (k.shape, layer)
 
     def put(name, x):
-        x = x.reshape(L, nb, bs, x.shape[-1])
-        return pool[name].at[:, blocks].set(x)
+        x = x.reshape(x.shape[:-2] + (nb, bs, x.shape[-1]))
+        at = pool[name].at
+        return (at[:, blocks] if layer is None else at[layer, blocks]).set(x)
 
     k, v = _merge_heads(k), _merge_heads(v)
     if not is_quantized_pool(pool):

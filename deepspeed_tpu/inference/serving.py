@@ -621,6 +621,12 @@ class ServingEngine:
         self._recurrent_bytes = (inner.recurrent_state_bytes(self.pool)
                                  if self._recurrent else 0)
         self._kv_pool_bytes = pk.pool_bytes(self.pool) - self._recurrent_bytes
+        # what a token costs the pool: the model says how many layer-
+        # applications keep K/V for it (every layer; a hybrid's attention
+        # layers; a looped model's loops x layers)
+        self._loop_attrs = {"kv_layers": int(mc.kv_layers),
+                            "loop_steps": int(getattr(mc, "loop_steps", 1))}
+        self._pool_attrs = {}
         self._state_seats = 0
         self.allocator = pk.BlockAllocator(self.num_blocks)
         # shadow lifecycle sanitizer (docs/static-analysis.md#sanitizer):
@@ -1637,7 +1643,8 @@ class ServingEngine:
                 self._start_shared(slot, req, blocks, new, share)
                 return
             bucket = pk.blocks_needed(T, c.block_size) * c.block_size
-            prefill.attrs = {"prompt_len": T, "bucket": bucket}
+            prefill.attrs = {"prompt_len": T, "bucket": bucket,
+                             **self._loop_attrs}
             if self._recurrent:
                 # what the recurrence walks and what it must not take in;
                 # the dispatch below writes the slot's recurrent rows whole
@@ -2653,6 +2660,7 @@ class ServingEngine:
             if self.journal is not None:
                 self.journal.flush()
             return bool(self.queue)
+        self._pool_attrs = self._pool_state(active)
         if active:
             new = self._dispatch(active, ahead)
             if ahead:
@@ -2672,14 +2680,28 @@ class ServingEngine:
             # number of the step this call did not book
             mon.abort_step()
             root.attrs = {"n_active": len(active), "emitted": 0,
-                          "t_tokens": None}
+                          "t_tokens": None, **self._pool_attrs}
             return True
         n_active, emitted_step, now = booked
         with spans.span("serving.telemetry"):
             self._monitor_finish(n_active, tokens=emitted_step)
         root.attrs = {"n_active": n_active, "emitted": emitted_step,
-                      "t_tokens": now}
+                      "t_tokens": now, **self._pool_attrs}
         return True
+
+    def _pool_state(self, active) -> dict:
+        """The pool as this call's dispatch finds it, for the step's span:
+        blocks checked out and free (together the allocatable ones), tokens
+        cached for the seated streams (the mirrors' count: one step behind
+        while a step is unread), and whether the queue's head waits for
+        BLOCKS: admission has just run or was not due, so a head still
+        queued beside a free slot lacks only them."""
+        return {"blocks_in_use": self.allocator.used_blocks,
+                "blocks_free": self.allocator.free_blocks,
+                "kv_tokens": int(self._lengths.sum()),
+                "waits_for_blocks": bool(
+                    self.queue and not self._draining
+                    and len(active) < self.config.batch_slots)}
 
     def _dispatch(self, active, ahead: bool) -> _Unread:
         """Call the decode executable for ``active`` and start its one
@@ -3101,7 +3123,7 @@ class ServingEngine:
         # fallback keeps the modeled term.  ds_explain names the impl.
         impl = self.model.paged_attention_impl()
         gather = gather_materialization_bytes(
-            n_layer=mc.n_layer, batch_slots=c.batch_slots,
+            n_layer=mc.kv_layers, batch_slots=c.batch_slots,
             nb_max=self.nb_max, block_size=c.block_size,
             n_head=mc.n_head, head_dim=mc.head_dim,
             itemsize=(1 if c.kv_bits == 8 else jnp.dtype(
@@ -3369,6 +3391,11 @@ class ServingEngine:
                # model with recurrent layers its per-slot rows and how
                # often a slot's rows were written whole
                "kv_pool_bytes": self._kv_pool_bytes,
+               # what one token costs it, over how many layer-applications,
+               # of how many loops over the layers
+               "kv_bytes_per_token": self._kv_pool_bytes // (
+                   self.num_blocks * self.config.block_size),
+               **self._loop_attrs,
                "recurrent_state_bytes": self._recurrent_bytes,
                "state_seats": self._state_seats,
                "outcomes": dict(self._outcomes),

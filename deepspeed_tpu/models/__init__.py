@@ -1,5 +1,5 @@
 """Model zoo: GPT-2 family (flagship), BERT encoder, MoE GPT, GPT-J/NeoX,
-Jamba (Mamba + attention hybrid)."""
+Jamba (Mamba + attention hybrid), Ouro (one stack of layers looped)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -24,6 +24,9 @@ def build(name, **overrides):
         if name.startswith("jamba"):
             from .jamba import Jamba
             return Jamba(preset=name, **overrides)
+        if name.startswith("ouro"):
+            from .ouro import Ouro
+            return Ouro(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

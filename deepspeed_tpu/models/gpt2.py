@@ -92,6 +92,12 @@ class GPT2Config:
         assert self.n_embd % self.n_head == 0
         return self.n_embd // self.n_head
 
+    @property
+    def kv_layers(self):
+        """Layers that keep K/V for a token (what the serving layer and the
+        capacity math multiply by): every layer."""
+        return self.n_layer
+
 
 # Named presets (125M → 1.3B)
 PRESETS = {

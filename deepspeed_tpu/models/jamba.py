@@ -106,6 +106,11 @@ class JambaConfig:
         return len(self.attn_layers)
 
     @property
+    def kv_layers(self):
+        """As ``GPT2Config.kv_layers``: the attention layers alone."""
+        return self.n_attn_layer
+
+    @property
     def n_mamba_layer(self):
         return self.num_hidden_layers - self.n_attn_layer
 
@@ -150,6 +155,13 @@ def _rms(x, w, eps):
 
 def _mm(x, w):
     return x @ w.astype(x.dtype)
+
+
+def swiglu(p, u):
+    """The SwiGLU MLP's three matmuls over the normed input ``u``: no norm
+    and no residual (``models/ouro.py`` norms the output too)."""
+    return _mm(jax.nn.silu(_mm(u, p["gate_w"])) * _mm(u, p["up_w"]),
+               p["down_w"])
 
 
 def grouped_attention(q, k, v, valid):
@@ -260,8 +272,7 @@ class Jamba:
     def _mlp(self, p, h):
         c = self.config
         u = _rms(h, p["ln_ff"], c.rms_norm_eps)
-        return h + _mm(jax.nn.silu(_mm(u, p["gate_w"])) * _mm(u, p["up_w"]),
-                       p["down_w"])
+        return h + swiglu(p, u)
 
     def _qkv(self, p, h):
         c = self.config
