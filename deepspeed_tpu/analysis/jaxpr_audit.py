@@ -437,6 +437,51 @@ def census_from_hlo_text(hlo_text):
     return out
 
 
+_HLO_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# op_name components that say how a kernel was reached, not what it is
+_HLO_WRAPPER_SCOPES = frozenset({
+    "pallas_call", "shard_map", "checkpoint", "rematted_computation",
+    "while", "body", "cond", "custom_vjp_call", "custom_jvp_call",
+    "closed_call"})
+
+
+def _kernel_scope(op_name: str) -> str:
+    """The innermost user scope of a kernel's ``op_name``: a Mosaic
+    custom call carries no kernel name in its HLO text, so it is named
+    by the ``jax.named_scope`` it sits in (``attention`` for the flash
+    kernels of a GPT-2 block), passing over the components JAX's own
+    transformations add (``jit(f)``, ``transpose(jvp(...))``,
+    ``shard_map``, ``checkpoint``, loop bodies)."""
+    parts = [c for c in op_name.split("/")
+             if c and "(" not in c and c not in _HLO_WRAPPER_SCOPES
+             and not c.startswith("branch_")]
+    return parts[-1] if parts else "pallas_call"
+
+
+def custom_calls_from_hlo_text(hlo_text) -> dict:
+    """``{scope: calls a step}``: the Mosaic (Pallas TPU) custom calls of
+    an HLO module's text by :func:`_kernel_scope`, each times the trip
+    counts of the loops it sits in, as the collective census counts.
+    What the compiler kept, not what the program traced: a kernel whose
+    results nothing reads is gone.  Empty where kernels are interpreted
+    (any backend but a TPU)."""
+    lines = hlo_text.splitlines()
+    loops = _hlo_loops(lines)
+    out, comp = {}, None
+    for line in lines:
+        m = _HLO_COMPUTATION_RE.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        if _HLO_MOSAIC_CALL not in line:
+            continue
+        m = _HLO_OP_NAME_RE.search(line)
+        scope = _kernel_scope(m.group(1) if m else "")
+        out[scope] = out.get(scope, 0) + loops.get(comp, (None, 1))[1]
+    return out
+
+
 def _flat_args_info(lowered):
     """Flattened (donated, aval) per lowered argument, or None."""
     try:

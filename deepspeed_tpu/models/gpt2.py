@@ -51,7 +51,12 @@ class GPT2Config:
     #   "dots"        — jax dots_with_no_batch_dims_saveable: matmul outputs
     #                   are saved, only cheap elementwise work recomputes
     #   "names:a,b"   — save only the named tensors (checkpoint_name marks
-    #                   "attn_out" and "mlp_fc" in the block)
+    #                   "attn_out" and "mlp_fc" in the block).  "attn_out"
+    #                   is what the attention hands its backward: on the
+    #                   flash path the kernel's output and log-sum-exp
+    #                   (2·D + 4·H bytes a token a layer in bf16), so the
+    #                   backward runs no second forward kernel; on every
+    #                   other path the attention's output (2·D bytes)
     remat_policy: Optional[str] = None
     # loss_chunk > 0: compute the tied-head logits + cross-entropy in
     # token chunks of ~this size under jax.checkpoint — the (B·T, V) fp32
@@ -126,14 +131,16 @@ def _dropout(x, rate, rng, deterministic):
 
 def _attention_jnp(q, k, v, causal_mask, attn_drop, rng, deterministic,
                    scale=None):
-    """Reference jnp attention: fp32 softmax, bf16 matmuls (XLA fuses)."""
+    """Reference jnp attention: fp32 softmax, bf16 matmuls (XLA fuses).
+    Its output is the selective-remat save point ``attn_out``."""
     head_dim = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     scores = scores * (1.0 / np.sqrt(head_dim) if scale is None else scale)
     scores = jnp.where(causal_mask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)
     probs = _dropout(probs, attn_drop, rng, deterministic).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return checkpoint_name(jnp.einsum("bhqk,bkhd->bqhd", probs, v),
+                           "attn_out")
 
 
 def layer_slice(blocks, i):
@@ -163,7 +170,10 @@ def flash_or_jnp_attention(q, k, v, causal_mask, attn_pdrop, rng,
     """Shared standard-causal attention dispatch: resolve 'auto', warn for
     unsupported flash combinations, run the Pallas kernel or the jnp oracle.
     Used by every rotary/dense decoder family so the selection logic cannot
-    drift between models."""
+    drift between models.  Either path marks the remat save point
+    ``attn_out``: the kernel the two residuals its backward reads (a policy
+    that lists the name then spares the backward a second forward kernel),
+    the oracle its output."""
     wants_dropout = attn_pdrop > 0.0 and not deterministic
     if impl == "auto":
         from ..ops import flash_attention_available
@@ -184,7 +194,8 @@ def flash_or_jnp_attention(q, k, v, causal_mask, attn_pdrop, rng,
             from ..parallel.mesh import BATCH_AXES, per_device
             # (B, T, H, hd): batch over the data axes, heads over tensor
             spec = P(BATCH_AXES, None, "tensor", None)
-            return per_device(partial(flash_attention, causal=True),
+            return per_device(partial(flash_attention, causal=True,
+                                      residual_name="attn_out"),
                               (spec, spec, spec), spec)(q, k, v)
     return _attention_jnp(q, k, v, causal_mask, attn_pdrop, rng,
                           deterministic, scale=scale)
@@ -218,9 +229,9 @@ def gpt2_block_forward(c, p, x, rng, deterministic, causal_mask, attend,
             local = (pos[None, :] > pos[:, None] - c.local_attn_window)
             local_mask = causal_mask & local[None, None]
             mask = jnp.where(is_local, local_mask, causal_mask)
+        # ``attend`` names its own selective-remat save point "attn_out"
         attn = attend(q, k, v, mask, r1, deterministic)
         attn = attn.reshape(B, T, D)
-        attn = checkpoint_name(attn, "attn_out")
         attn = attn @ p["proj_w"].astype(h.dtype) + p["proj_b"].astype(h.dtype)
         x = x + _dropout(attn, c.resid_pdrop, r2, deterministic)
 
@@ -418,7 +429,8 @@ class GPT2:
             fn = {"ring": sp.ring_attention,
                   "ring_flash": sp.ring_flash_attention,
                   "ulysses": sp.ulysses_attention}[impl]
-            return fn(q, k, v, causal=True, batch_spec=batch_spec())
+            return checkpoint_name(
+                fn(q, k, v, causal=True, batch_spec=batch_spec()), "attn_out")
         return flash_or_jnp_attention(
             q, k, v, causal_mask, c.attn_pdrop, rng, deterministic, impl,
             scale=None if c.scale_attn else 1.0, nonstandard=nonstandard)

@@ -180,8 +180,7 @@ class GPT2MoE:
             q, k_, v = jnp.split(qkv, 3, axis=-1)
             f = lambda t: t.reshape(B, T, H, hd)
             attn = self._attend(f(q), f(k_), f(v), causal, r1, deterministic)
-            attn = attn.reshape(B, T, D)
-            attn = checkpoint_name(attn, "attn_out")
+            attn = attn.reshape(B, T, D)     # _attend named it "attn_out"
             attn = attn @ p["proj_w"].astype(h.dtype) + p["proj_b"].astype(h.dtype)
             x = x + _dropout(attn, c.resid_pdrop, r2, deterministic)
 

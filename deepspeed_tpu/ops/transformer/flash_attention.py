@@ -23,6 +23,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -892,18 +893,43 @@ def _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
 
 
 # ================================================================== public API
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhtd(q, k, v, sm_scale, causal, block_q, block_k):
+def _named_residuals(out, lse, residual):
+    """``out`` and ``lse`` under a name a ``save_only_these_names`` remat
+    policy can list: the backward kernels read exactly these two, so a
+    policy that saves them leaves the rematerialised backward no forward
+    kernel to run again.  ``residual``: ``(name, n_heads)`` or ``None``.
+    ``out`` is named in the model's layout ``(B, T, H·d)``, which the
+    caller's next matmul reads anyway, and handed on as a transpose of
+    that: a stacked ``(B·H, T, d)`` with ``d`` under 128 is padded to 128
+    lanes, twice its bytes, which on ``train_z1`` made XLA rematerialise a
+    matmul to fit and took back most of the gain (PERF.md §6, PR 33)."""
+    if residual is None:
+        return out, lse
+    name, H = residual
+    BH, T, d = out.shape
+    B = BH // H
+    btd = checkpoint_name(
+        out.reshape(B, H, T, d).transpose(0, 2, 1, 3).reshape(B, T, H * d),
+        name)
+    out = btd.reshape(B, T, H, d).transpose(0, 2, 1, 3).reshape(BH, T, d)
+    return out, checkpoint_name(lse, name)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bhtd(q, k, v, sm_scale, causal, block_q, block_k,
+                residual=None):
     out, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k)
     return out
 
 
-def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k)
+def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k, residual):
+    out, lse = _named_residuals(
+        *_fwd(q, k, v, sm_scale, causal, block_q, block_k), residual)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(sm_scale, causal, block_q, block_k, residuals, dout):
+def _flash_bwd_rule(sm_scale, causal, block_q, block_k, residual,
+                    residuals, dout):
     return _bwd(sm_scale, causal, block_q, block_k, residuals, dout)
 
 
@@ -912,13 +938,24 @@ _flash_bhtd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    key_padding_bias=None, attn_bias=None):
+                    key_padding_bias=None, attn_bias=None,
+                    residual_name=None):
     """Flash attention over (B, T, H, d) tensors (the model layout).
 
     Returns (B, T, H, d).  fp32 softmax statistics, input-dtype matmuls.
     ``key_padding_bias`` (B, T) and ``attn_bias`` (T, T) are ADDITIVE score
     biases applied in-kernel (use ``NEG_INF`` entries to mask) — the
     reference's masked softmax kernels (``softmax_kernels.cu``).
+
+    ``residual_name``: the ``checkpoint_name`` under which the two
+    residuals only the forward kernel can make, its output (named as
+    ``(B, T, H·d)``) and the log-sum-exp ``(B·H, T)`` float32, are handed
+    to the backward.  Under ``jax.checkpoint`` with a
+    ``save_only_these_names`` policy that lists it they are saved
+    (``2·H·d + 4·H`` bytes a token in bfloat16) and the rematerialised
+    backward runs two kernels a call, dK/dV and dQ, and no second forward;
+    with any other policy, or ``None`` here, the name changes nothing.
+    Not carried by the biased variants.
     """
     if key_padding_bias is not None or attn_bias is not None:
         return _biased_call(q, k, v, None, key_padding_bias, attn_bias,
@@ -930,21 +967,26 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None,
     # (B, T, H, d) → (B*H, T, d)
     to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, T, d)
     out = _flash_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v),
-                      float(sm_scale), bool(causal), int(block_q), int(block_k))
+                      float(sm_scale), bool(causal), int(block_q), int(block_k),
+                      residual_name and (residual_name, H))
     return out.reshape(B, H, T, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse_bhtd(q, k, v, sm_scale, causal, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse_bhtd(q, k, v, sm_scale, causal, block_q, block_k,
+                    residual=None):
     return _fwd(q, k, v, sm_scale, causal, block_q, block_k)
 
 
-def _flash_lse_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k)
+def _flash_lse_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
+                        residual):
+    out, lse = _named_residuals(
+        *_fwd(q, k, v, sm_scale, causal, block_q, block_k), residual)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd_rule(sm_scale, causal, block_q, block_k, residuals, cts):
+def _flash_lse_bwd_rule(sm_scale, causal, block_q, block_k, residual,
+                        residuals, cts):
     dout, dlse = cts
     return _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
                 dlse=dlse)
@@ -954,10 +996,12 @@ _flash_lse_bhtd.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
 
 
 def flash_attention_with_lse(q, k, v, *, causal=True, sm_scale=None,
-                             block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+                             block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                             residual_name=None):
     """Flash attention returning ``(out (B,T,H,d), lse (B,H,T))`` with BOTH
     outputs differentiable — the building block for ring attention, where
-    per-device partial results merge via their logsumexp statistics."""
+    per-device partial results merge via their logsumexp statistics.
+    ``residual_name``: as in :func:`flash_attention`."""
     B, T, H, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(d)
@@ -965,7 +1009,8 @@ def flash_attention_with_lse(q, k, v, *, causal=True, sm_scale=None,
     to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, T, d)
     out, lse = _flash_lse_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v),
                                float(sm_scale), bool(causal), int(block_q),
-                               int(block_k))
+                               int(block_k),
+                               residual_name and (residual_name, H))
     return (out.reshape(B, H, T, d).transpose(0, 2, 1, 3),
             lse.reshape(B, H, T))
 

@@ -450,10 +450,10 @@ class DeepSpeedEngine:
         # from the persistent compile cache
         self._jit_train_step = self._wrap_step(
             "train_step", self._train_step, donate_argnums=(0,),
-            describe=self._step_collectives)
+            describe=self._describe_step)
         self._jit_grad_step = self._wrap_step(
             "grad_only_step", self._grad_only_step,
-            describe=self._step_collectives)
+            describe=self._describe_step)
         self._jit_eval = None
 
         # ---- curriculum learning / PLD ------------------------------------
@@ -732,29 +732,40 @@ class DeepSpeedEngine:
             cache=self.compile_cache, key_extra=self._cc_key_slice,
             donate_argnums=donate_argnums, describe=describe)
 
-    def _step_collectives(self, exe, args):
-        """What one call of a compiled step moves between devices: the
-        HLO collective census (``analysis/comms.step_collectives``),
-        priced by the loops' trip counts, with the bytes of collectives
-        whose payload has no parameter's shape, the sign that the
-        partitioner moves activations where ZeRO-3 should move weights."""
+    def _describe_step(self, exe, args):
+        """Two censuses of a compiled step's HLO, each entry times the
+        trip counts of the loops it sits in.  What one call moves between
+        devices (``analysis/comms.step_collectives``), with the bytes of
+        collectives whose payload has no parameter's shape, the sign that
+        the partitioner moves activations where ZeRO-3 should move
+        weights; and ``custom_calls``, the Pallas kernels one call runs
+        by the scope they sit in (a flash block under a remat policy that
+        saves ``attn_out`` reads 3 x layers under ``attention``, 4 x
+        under any other)."""
         from ..analysis.comms import step_collectives
-        from ..analysis.jaxpr_audit import census_from_hlo_text
+        from ..analysis.jaxpr_audit import census_from_hlo_text, \
+            custom_calls_from_hlo_text
         state = args[0]
         shapes = {np.shape(leaf) for leaf in jax.tree_util.tree_leaves(
             state.master if state.master is not None else state.params)}
-        return step_collectives(census_from_hlo_text(exe.as_text()), shapes)
+        text = exe.as_text()
+        return {**step_collectives(census_from_hlo_text(text), shapes),
+                "custom_calls": custom_calls_from_hlo_text(text)}
 
     def compile_report(self):
         """Compile-cache status + per-entry hit/miss/compile-ms events
-        for this engine's cache (surfaced by ds_report), and under
-        ``collectives`` what each acquired step executable moves."""
+        for this engine's cache (surfaced by ds_report), and of each
+        acquired step executable what it moves (``collectives``) and the
+        kernels it runs (``custom_calls``)."""
         from . import compile_cache as ccache
         report = ccache.report(self.compile_cache)
+        steps = [w for w in (self._jit_train_step, self._jit_grad_step)
+                 if w.described]
         report["collectives"] = {
-            w.name: w.described
-            for w in (self._jit_train_step, self._jit_grad_step)
-            if w.described}
+            w.name: {k: v for k, v in w.described.items()
+                     if k != "custom_calls"} for w in steps}
+        report["custom_calls"] = {
+            w.name: w.described["custom_calls"] for w in steps}
         return report
 
     def _install_moe_wire(self):

@@ -103,3 +103,212 @@ def test_dma_slot_walk_unroll_bounded():
     # the bounded unroll must divide into the ring without aliasing a
     # live slot: ring depth itself is the safe group size
     assert _N_KV_BUF >= 2
+
+
+# ------------------------------------------------- residuals under remat
+# A rematerialised backward reruns whatever made a residual it was not
+# handed.  ``residual_name`` puts the two residuals only the forward kernel
+# can make under a name a ``names:`` policy can save (PERF.md §6, PR 33).
+B_, T_, H_, D_HEAD = 4, 128, 2, 32
+
+
+def _block(x, w_qkv, w_proj, residual_name, with_lse=False):
+    """A transformer block's attention half and an ``mlp_fc``-named matmul,
+    as ``models/gpt2.gpt2_block_forward`` lays them out."""
+    from jax.ad_checkpoint import checkpoint_name
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        flash_attention_with_lse)
+    from deepspeed_tpu.parallel.mesh import BATCH_AXES, per_device
+    from jax.sharding import PartitionSpec as P
+    q, k, v = (t.reshape(B_, T_, H_, D_HEAD)
+               for t in jnp.split(x @ w_qkv, 3, axis=-1))
+    spec = P(BATCH_AXES, None, "tensor", None)
+    if with_lse:
+        attn, lse = flash_attention_with_lse(
+            q, k, v, block_q=64, block_k=64, residual_name=residual_name)
+        attn = attn * jnp.tanh(lse).transpose(0, 2, 1)[..., None]
+    else:
+        attn = per_device(
+            lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=64,
+                                            residual_name=residual_name),
+            (spec, spec, spec), spec)(q, k, v)
+    h = checkpoint_name(attn.reshape(B_, T_, H_ * D_HEAD) @ w_proj, "mlp_fc")
+    return jnp.sum(jnp.square(h))
+
+
+def _block_args(seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    d = H_ * D_HEAD
+    return (jax.random.normal(ks[0], (B_, T_, d), jnp.float32),
+            jax.random.normal(ks[1], (d, 3 * d), jnp.float32) * 0.1,
+            jax.random.normal(ks[2], (d, d), jnp.float32) * 0.1)
+
+
+def _remat_grad(policy, residual_name, with_lse=False):
+    from deepspeed_tpu.models.gpt2 import resolve_remat_policy
+    block = jax.checkpoint(
+        lambda x, a, b: _block(x, a, b, residual_name, with_lse),
+        policy=resolve_remat_policy(policy))
+    return jax.grad(block, argnums=(0, 1, 2))
+
+
+def _pallas_calls(fn, *args):
+    from deepspeed_tpu.analysis.jaxpr_audit import iter_eqns
+    return sum(eqn.primitive.name == "pallas_call"
+               for eqn, _ in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_device", "per_device_x4"])
+@pytest.mark.parametrize("policy,residual_name,calls", [
+    ("names:attn_out,mlp_fc", "attn_out", 3),
+    ("names:attn_out,mlp_fc", None, 4),
+    (None, "attn_out", 4),
+    ("dots", "attn_out", 4),
+], ids=["named_and_saved", "unnamed", "remat_all", "dots"])
+def test_remat_backward_kernel_calls(policy, residual_name, calls, sharded):
+    """Forward, dK/dV, dQ: three kernels where the policy saves the named
+    residuals; a fourth, the forward again, wherever it does not.  The same
+    with the kernel inside a four-device ``shard_map``, as a ZeRO-3 step
+    runs it."""
+    import contextlib
+    from deepspeed_tpu.parallel import mesh as M
+    ctx = contextlib.nullcontext()
+    if sharded:
+        ctx = jax.set_mesh(M.make_mesh({"data": 1, "fsdp": 4},
+                                       devices=jax.devices()[:4]))
+    with ctx:
+        n = _pallas_calls(_remat_grad(policy, residual_name), *_block_args())
+    assert n == calls
+
+
+@pytest.mark.parametrize("with_lse", [False, True],
+                         ids=["flash_attention", "flash_attention_with_lse"])
+def test_saved_residuals_give_the_same_gradient_to_the_bit(with_lse):
+    args = _block_args(seed=1)
+    policy = "names:attn_out,mlp_fc"
+    saved = jax.jit(_remat_grad(policy, "attn_out", with_lse))(*args)
+    rerun = jax.jit(_remat_grad(policy, None, with_lse))(*args)
+    assert _pallas_calls(_remat_grad(policy, "attn_out", with_lse), *args) \
+        == _pallas_calls(_remat_grad(policy, None, with_lse), *args) - 1
+    for a, b in zip(saved, rerun):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(_train_step)/jvp(blocks)/while/body/closed_call/attention/"
+     "pallas_call", "attention"),
+    ("jit(_train_step)/transpose(jvp(blocks))/while/body/closed_call/"
+     "checkpoint/rematted_computation/attention/shard_map/pallas_call",
+     "attention"),
+    ("jit(step)/jit(main)/ssm.scan/pallas_call", "ssm.scan"),
+    ("jit(f)/jvp()/pallas_call", "pallas_call"),
+])
+def test_kernel_scope_of_an_op_name(op_name, scope):
+    from deepspeed_tpu.analysis.jaxpr_audit import _kernel_scope
+    assert _kernel_scope(op_name) == scope
+
+
+def test_custom_call_census_counts_loop_trips():
+    from deepspeed_tpu.analysis.jaxpr_audit import custom_calls_from_hlo_text
+    call = ('  %k.{i} = bf16[8,128]{{1,0}} custom-call(%p), '
+            'custom_call_target="tpu_custom_call", '
+            'metadata={{op_name="jit(f)/{scope}/pallas_call"}}')
+    text = "\n".join([
+        "%body (p: bf16[8,128]) -> bf16[8,128] {",
+        call.format(i=1, scope="while/body/attention"),
+        call.format(i=2, scope="while/body/checkpoint/attention"),
+        "}",
+        "%cond (p: bf16[8,128]) -> pred[] {",
+        "  %c = s32[] constant(5)",
+        "}",
+        "ENTRY %main (p: bf16[8,128]) -> bf16[8,128] {",
+        "  %w = bf16[8,128]{1,0} while(%p), condition=%cond, body=%body",
+        call.format(i=3, scope="head"),
+        '  %o = f32[4]{0} custom-call(%p), custom_call_target="Sharding"',
+        "}"])
+    assert custom_calls_from_hlo_text(text) == {"attention": 10, "head": 1}
+
+
+# -------------------------------------------- the step the TPU compiles
+@pytest.fixture(scope="module")
+def v5e():
+    """A described v5e:2x2 (no chip needed); built here and never at
+    import, so every xdist worker collects the same tests."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+N_LAYER = 3
+
+
+@pytest.mark.parametrize("policy,axes,stage,per_layer", [
+    ("names:attn_out,mlp_fc", {"data": 1}, 1, 3),
+    ("names:mlp_fc", {"data": 1}, 1, 4),
+    ("names:attn_out,mlp_fc", {"data": 1, "fsdp": 4}, 3, 3),
+], ids=["z1_saved", "z1_not_saved", "z3_x4_saved"])
+def test_compile_report_counts_the_flash_calls_of_a_step(
+        v5e, monkeypatch, tmp_path, policy, axes, stage, per_layer):
+    """``compile_report()["custom_calls"]`` of a tiny GPT-2 engine whose
+    train step is acquired, through the engine's own wrapper, for the
+    described TPU (the CPU interprets kernels and its executable holds no
+    custom call): the Mosaic calls the compiler KEPT, times the layer
+    scan's trips.  The recipe is the verify skill's: the state's shapes
+    on a mesh of described devices, the engine's meshes swapped for it."""
+    import importlib
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import deepspeed_tpu as ds
+    import deepspeed_tpu.ops as ops
+    from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
+    from deepspeed_tpu.parallel import mesh as M
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.flash_attention")
+    n_dev = int(np.prod(list(axes.values())))
+    mesh = M.make_mesh(axes, devices=jax.devices()[:n_dev])
+    model = GPT2(config=GPT2Config(
+        vocab_size=256, max_seq=256, n_embd=128, n_layer=N_LAYER, n_head=2,
+        embd_pdrop=0.0, attn_pdrop=0.0, resid_pdrop=0.0, remat=True,
+        remat_policy=policy, attention_impl="auto", loss_chunk=128),
+        dtype=jnp.bfloat16)
+    engine, _, _, _ = ds.initialize(
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": 1,
+                "steps_per_print": 10 ** 9, "bf16": {"enabled": True},
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-4}},
+                "zero_optimization": {"stage": stage},
+                "compile_cache": {"dir": str(tmp_path)}},
+        model=model, mesh=mesh, rng_seed=1)
+    tpu_mesh = Mesh(np.array(v5e.devices[:n_dev]).reshape(mesh.devices.shape),
+                    mesh.axis_names)
+
+    def on_tpu(x):
+        sh = getattr(x, "sharding", None)
+        spec = sh.spec if isinstance(sh, NamedSharding) else P()
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(tpu_mesh, spec))
+    state = jax.tree_util.tree_map(on_tpu, engine.state)
+    batch = jax.ShapeDtypeStruct(
+        (1, 2 * M.dp_world_size(mesh), 257), jnp.int32,
+        sharding=NamedSharding(tpu_mesh, P(None, M.BATCH_AXES)))
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(tpu_mesh, P()))
+    engine.mesh = engine._router.mesh = tpu_mesh
+    engine.mesh_ctx = M.MeshContext(tpu_mesh)
+    monkeypatch.setattr(ops, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    with jax.set_mesh(tpu_mesh):
+        engine._jit_train_step.executable(state, batch, rng)
+    report = engine.compile_report()
+    assert report["custom_calls"] == {
+        "DeepSpeedEngine.train_step": {"attention": per_layer * N_LAYER}}
+    assert "custom_calls" not in \
+        report["collectives"]["DeepSpeedEngine.train_step"]
+    built = [row for row in engine._spans.rows("compile.build")
+             if row.attrs.get("fn") == "DeepSpeedEngine.train_step"]
+    assert built[-1].attrs["custom_calls"] == {
+        "attention": per_layer * N_LAYER}
