@@ -322,7 +322,8 @@ def request_unique_blocks(*, prompt_tokens, max_new_tokens, block_size,
 def serving_plan(*, n_layer, n_head, head_dim, max_seq, block_size=16,
                  kv_bits=16, quant_block=64, batch_slots=8, num_blocks=0,
                  max_new_tokens=64, weight_bytes=0, prompt_tokens=None,
-                 shared_prefix_tokens=0, kv_layers=None) -> dict:
+                 shared_prefix_tokens=0, kv_layers=None,
+                 kv_row_bytes=None) -> dict:
     """Closed-form serving memory plan mirroring ``paged_kv.init_pool``'s
     arithmetic exactly (tested equal to ``pool_bytes`` of a real pool):
     per-block bytes, total pool bytes for the configuration's block
@@ -331,14 +332,20 @@ def serving_plan(*, n_layer, n_head, head_dim, max_seq, block_size=16,
 
     ``kv_layers`` is the model config's: the layer-applications that keep
     K/V for a token, where that is not ``n_layer`` (a hybrid's attention
-    layers alone; a looped model's loops x layers)."""
+    layers alone; a looped model's loops x layers).  ``kv_row_bytes`` is the
+    bytes a token keeps in ONE of them as the pool stores it, where that is
+    not K and V of ``n_head x head_dim`` cells (a latent row,
+    ``paged_kv.latent_row_bytes``: 1,280 for 512 + 64 values in whole
+    128-lane tiles)."""
     if kv_layers is None:
         kv_layers = n_layer
     nb_max = _ceil_div(max_seq, block_size)
     if not num_blocks:
         num_blocks = 1 + batch_slots * nb_max
     cell = n_head * head_dim
-    if kv_bits == 8:
+    if kv_row_bytes is not None:
+        per_tok = int(kv_row_bytes)
+    elif kv_bits == 8:
         # the quantizer's pick_block rule (runtime/comm/quantized.py):
         # LARGEST DIVISOR of head_dim <= quant_block — re-stated here
         # (not a halving loop: head_dim=96, qb=64 picks 48, not 32) so

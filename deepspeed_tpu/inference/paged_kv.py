@@ -467,12 +467,25 @@ def pool_bytes(pool) -> int:
 
 def capacity_tokens(pool) -> int:
     """Token capacity of the allocatable pool (scratch block excluded)."""
-    return (pool["k"].shape[1] - 1) * pool["k"].shape[2]
+    _, num_blocks, block_size, _ = pool[payload_names(pool)[0]].shape
+    return (num_blocks - 1) * block_size
 
 
 def _merge_heads(x):
     """(…, H, hd) → (…, H·hd): the pool's minor dim."""
     return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
+def _window_slots(block_tables, lengths, W, bs):
+    """``(block ids, offsets)``, each (B, W): where window token i of slot b
+    (position ``lengths[b] + i``) lands.  A position past the table goes to
+    the scratch block (:func:`write_tokens` says why)."""
+    nb_max = block_tables.shape[1]
+    pos = lengths[:, None] + jnp.arange(W, dtype=lengths.dtype)[None, :]
+    idx = pos // bs                                        # (B, W)
+    blk = jnp.take_along_axis(block_tables,
+                              jnp.minimum(idx, nb_max - 1), axis=1)
+    return jnp.where(idx < nb_max, blk, SCRATCH_BLOCK), pos % bs
 
 
 def write_tokens(pool, layer, block_tables, lengths, k, v):
@@ -489,15 +502,8 @@ def write_tokens(pool, layer, block_tables, lengths, k, v):
     letting the gather clamp silently overwrite the table's last real
     block — any token whose logits depend on such a position is beyond
     ``max_new`` and truncated by the scheduler anyway."""
-    bs = pool["k"].shape[2]
-    nb_max = block_tables.shape[1]
-    W = k.shape[1]
-    pos = lengths[:, None] + jnp.arange(W, dtype=lengths.dtype)[None, :]
-    idx = pos // bs                                        # (B, W)
-    blk = jnp.take_along_axis(block_tables,
-                              jnp.minimum(idx, nb_max - 1), axis=1)
-    blk = jnp.where(idx < nb_max, blk, SCRATCH_BLOCK)
-    off = pos % bs
+    blk, off = _window_slots(block_tables, lengths, k.shape[1],
+                             pool["k"].shape[2])
     k, v = _merge_heads(k), _merge_heads(v)
     if not is_quantized_pool(pool):
         dt = pool["k"].dtype
@@ -783,3 +789,88 @@ def load_block_image(ckpt_dir: str, verify: str = "full"):
         raise BlockImageError(f"block digest mismatch at image block(s) "
                               f"{bad} in {ckpt_dir}")
     return image, manifest.get("meta", {})
+
+
+# ------------------------------------------------------------- latent pool
+# A model with latent attention (MLA: ``models/deepseek_v2.py``) caches, a
+# token and a layer, ONE row shared by every query head: the compressed
+# ``c_kv`` (``kv_lora_rank`` values, after its norm) and the rotated
+# ``k_pe`` (``rope_dim`` values).  The row is K whole and V in its first
+# ``kv_lora_rank`` columns: the same bytes, read once.
+#
+# THE LAYOUT, stated here once: ONE leaf ``pool["latent"]`` of shape
+# ``(L, num_blocks, block_size, row)``, ``row`` the row's values rounded up
+# to whole 128-lane tiles (512 + 64 -> 640: columns 0..511 ``c_kv``, 512..575
+# ``k_pe``, 576..639 zero), because Mosaic DMAs and slices whole tiles
+# (ROADMAP D11).  The zero columns meet zero columns of the query, so the
+# scores are exact; they cost 64 of 640 values of every read.  16-bit only.
+LATENT = "latent"
+
+
+def payload_names(pool) -> tuple:
+    """The pool's K/V payload leaves: ``("k", "v")``, or the one latent
+    leaf."""
+    return (LATENT,) if LATENT in pool else ("k", "v")
+
+
+def is_latent_pool(pool) -> bool:
+    return LATENT in pool
+
+
+def latent_row_width(kv_lora_rank: int, rope_dim: int) -> int:
+    """Values a stored latent row has: whole 128-lane tiles."""
+    return -(-(kv_lora_rank + rope_dim) // 128) * 128
+
+
+def init_latent_pool(n_layer: int, num_blocks: int, block_size: int,
+                     kv_lora_rank: int, rope_dim: int, dtype=jnp.bfloat16):
+    """Zeroed latent pool (layout above)."""
+    return {LATENT: jnp.zeros(
+        (n_layer, num_blocks, block_size,
+         latent_row_width(kv_lora_rank, rope_dim)), dtype)}
+
+
+def latent_row_bytes(pool) -> int:
+    """Bytes one token keeps in ONE layer, as stored."""
+    x = pool[LATENT]
+    return int(x.shape[-1]) * x.dtype.itemsize
+
+
+def latent_rows(c_kv, k_pe, width):
+    """``[c_kv | k_pe | 0]``: (..., width) rows as the pool stores them (and
+    an absorbed query, ``[q_lat | q_pe | 0]``, that meets them)."""
+    pad = width - c_kv.shape[-1] - k_pe.shape[-1]
+    return jnp.concatenate(
+        [c_kv, k_pe, jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)], -1)
+
+
+def write_latent_tokens(pool, layer, block_tables, lengths, rows):
+    """:func:`write_tokens` for a latent pool: ``rows`` (B, W, row), window
+    token i of slot b at position ``lengths[b] + i``; an empty slot's row
+    lands in the scratch block, as does a position past the table."""
+    x = pool[LATENT]
+    blk, off = _window_slots(block_tables, lengths, rows.shape[1],
+                             x.shape[2])
+    return dict(pool, **{LATENT: x.at[layer, blk, off].set(
+        rows.astype(x.dtype))})
+
+
+def write_latent_prefill(pool, blocks, rows, layer):
+    """:func:`write_prefill` for a latent pool, one layer at a time:
+    ``rows`` (T, row) with ``T == len(blocks) * block_size``."""
+    x = pool[LATENT]
+    bs = x.shape[2]
+    nb = rows.shape[0] // bs
+    assert nb * bs == rows.shape[0] and blocks.shape == (nb,), (
+        rows.shape, blocks.shape, bs)
+    return dict(pool, **{LATENT: x.at[layer, blocks].set(
+        rows.reshape(nb, bs, rows.shape[-1]).astype(x.dtype))})
+
+
+def gather_latent(pool, layer, block_tables, dtype):
+    """Per-slot gathered rows of one layer, (B, nb_max * block_size, row):
+    the fallback path and the oracle of the latent kernel
+    (``ops/transformer/paged_latent_attention.py``)."""
+    x = pool[LATENT][layer][block_tables]          # (B, nb, bs, row)
+    B, nb, bs, row = x.shape
+    return x.reshape(B, nb * bs, row).astype(dtype)

@@ -620,7 +620,15 @@ class ServingEngine:
                 dtype=cache_dtype)
         self._recurrent_bytes = (inner.recurrent_state_bytes(self.pool)
                                  if self._recurrent else 0)
-        self._kv_pool_bytes = pk.pool_bytes(self.pool) - self._recurrent_bytes
+        if pk.is_latent_pool(self.pool):
+            self._refuse_for_latent_pool(config)
+        # what the model's layers count in a dispatch (an expert layer's
+        # routed pairs): a small leaf of its serving state, read back with
+        # the step's tokens and written into the step's span
+        self._counter_names = tuple(getattr(inner, "step_counters", ()))
+        self._route_attrs = {}
+        self._kv_pool_bytes = pk.pool_bytes(self.pool) - self._recurrent_bytes \
+            - (self.pool["counters"].nbytes if self._counter_names else 0)
         # what a token costs the pool: the model says how many layer-
         # applications keep K/V for it (every layer; a hybrid's attention
         # layers; a looped model's loops x layers)
@@ -841,13 +849,36 @@ class ServingEngine:
             "transfer": ships_images,
             "role": ships_images,
         }
+        ServingEngine._refuse_armed(config, why, "recurrent state",
+                                    "recurrent-state")
+
+    @staticmethod
+    def _refuse_armed(config, why, what, anchor):
+        """Raise, by name, for the first feature of ``why`` (name ->
+        reason) that ``config`` arms."""
         for name, reason in why.items():
             value = getattr(config, name)
             if value not in (None, False, "mixed"):
                 raise ValueError(
                     f"serving.{name}={value!r} cannot serve a model with "
-                    f"recurrent state: {reason} "
-                    "(docs/serving.md#recurrent-state)")
+                    f"{what}: {reason} (docs/serving.md#{anchor})")
+
+    @staticmethod
+    def _refuse_for_latent_pool(config):
+        """A latent pool (``paged_kv.init_latent_pool``) has one leaf where
+        every other pool has ``k`` and ``v``, and its kernel attends one
+        token a slot.  What moves, shares or re-reads a stream BY its K/V
+        leaves has not learned the row yet: refused by name (ROADMAP, Queue
+        2)."""
+        image = "a block image is int8 K and V with scales a head"
+        why = {"prefix_cache": "the radix cache's copy-on-write and the "
+                               "prompt tail's window steps are written for "
+                               "k and v leaves",
+               "kv_snapshot": image, "transfer": image, "role": image,
+               "speculative": "the latent kernel attends one query token a "
+                              "slot, not a draft window"}
+        ServingEngine._refuse_armed(config, why, "a latent KV pool",
+                                    "latent-pool")
 
     # ------------------------------------------------------------- recovery
     def _recover(self, state):
@@ -1350,7 +1381,12 @@ class ServingEngine:
             # at the scratch block and stays as it is); whatever else the
             # host does to a row marks the state dirty and overwrites this
             n = (tables[:, 0] != pk.SCRATCH_BLOCK).astype(jnp.int32)
-            return (_pack_read(nxt, poisoned), pool, lengths + n,
+            read = (nxt, poisoned)
+            if self._counter_names:
+                # the same (B, columns) buffer, the counters on every row
+                read += (jnp.broadcast_to(pool["counters"],
+                                          nxt.shape + pool["counters"].shape),)
+            return (_pack_read(*read), pool, lengths + n,
                     jnp.where(n > 0, nxt, toks), ngen + n)
 
         def spec_step(params, pool, tables, lengths, toks_win, seeds, ngen,
@@ -1439,7 +1475,10 @@ class ServingEngine:
             first = jnp.where(bad, jnp.int32(POISON_SENTINEL_TOKEN),
                               first[0])
             # [first token, poison flag]: one read for the host, not two
-            return jnp.stack([first, bad.astype(jnp.int32)]), pool
+            read = jnp.stack([first, bad.astype(jnp.int32)])
+            if self._counter_names:
+                read = jnp.concatenate([read, pool["counters"]])
+            return read, pool
 
         fn = self.engine._wrap_step(
             f"serving.prefill[{bucket},kv{self.config.kv_bits}]", prefill,
@@ -1663,7 +1702,7 @@ class ServingEngine:
                     read.copy_to_host_async()
             # the read syncs the prefill dispatch: the host waits here
             with self._spans.span("serving.prefill.readback"):
-                first, bad = (int(x) for x in np.asarray(read))
+                first, bad = self._read_prefill(read, prefill)
         if bad:
             # quarantined AT prefill: the slot is never seated, the
             # sentinel token is never surfaced, and the blocks go back
@@ -1714,6 +1753,15 @@ class ServingEngine:
             # — and requests admitted in this SAME wave (co-batched)
             # can already share them, not just successive traffic
             self._prefix_insert(s)
+
+    def _read_prefill(self, read, prefill):
+        """The prefill's one buffer: ``(first token, poison flag)``; what
+        the model's layers counted rides behind them and goes into the
+        prefill's span (``routed_pairs`` of an expert model)."""
+        read = np.asarray(read)
+        prefill.attrs.update(zip(self._counter_names,
+                                 (int(x) for x in read[2:])))
+        return int(read[0]), int(read[1])
 
     def _start_shared(self, slot: int, req: Request, blocks: List[int],
                       new: int, share: dict):
@@ -2356,9 +2404,10 @@ class ServingEngine:
                     return dict(pool,
                                 k_scale=pool["k_scale"].at[:, blk].set(val),
                                 v_scale=pool["v_scale"].at[:, blk].set(val))
-                v = val.astype(pool["k"].dtype)
-                return dict(pool, k=pool["k"].at[:, blk].set(v),
-                            v=pool["v"].at[:, blk].set(v))
+                return dict(pool, **{
+                    name: pool[name].at[:, blk].set(
+                        val.astype(pool[name].dtype))
+                    for name in pk.payload_names(pool)})
 
             # cpu backend: donation would only warn (PR-4's copy-on-
             # donate note); device backends get the in-place update
@@ -2686,7 +2735,8 @@ class ServingEngine:
         with spans.span("serving.telemetry"):
             self._monitor_finish(n_active, tokens=emitted_step)
         root.attrs = {"n_active": n_active, "emitted": emitted_step,
-                      "t_tokens": now, **self._pool_attrs}
+                      "t_tokens": now, **self._pool_attrs,
+                      **self._route_attrs}
         return True
 
     def _pool_state(self, active) -> dict:
@@ -2773,6 +2823,13 @@ class ServingEngine:
         # the host's wait for the device, alone on its bracket
         with spans.span("serving.readback") as readback:
             read = np.asarray(step.read)
+            if self._counter_names:
+                # what the model's layers counted in this step, behind the
+                # columns every model sends
+                n_cols = read.shape[1] - len(self._counter_names)
+                self._route_attrs = dict(zip(
+                    self._counter_names, (int(x) for x in read[0, n_cols:])))
+                read = read[:, :n_cols]
             # (B, W) tokens | (B, W) non-finite flags | accept length;
             # plain decode is the W=1 window: one token, always "accepted"
             W = read.shape[1] // 2
@@ -3396,6 +3453,10 @@ class ServingEngine:
                "kv_bytes_per_token": self._kv_pool_bytes // (
                    self.num_blocks * self.config.block_size),
                **self._loop_attrs,
+               # and what the model says of its own state (an expert
+               # model's held experts, a latent pool's row)
+               **(self.model.serving_stats(self.pool)
+                  if hasattr(self.model, "serving_stats") else {}),
                "recurrent_state_bytes": self._recurrent_bytes,
                "state_seats": self._state_seats,
                "outcomes": dict(self._outcomes),

@@ -1,5 +1,6 @@
 """Model zoo: GPT-2 family (flagship), BERT encoder, MoE GPT, GPT-J/NeoX,
-Jamba (Mamba + attention hybrid), Ouro (one stack of layers looped)."""
+Jamba (Mamba + attention hybrid), Ouro (one stack of layers looped),
+DeepSeek-V2 (latent attention, routed and shared experts)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -27,6 +28,9 @@ def build(name, **overrides):
         if name.startswith("ouro"):
             from .ouro import Ouro
             return Ouro(preset=name, **overrides)
+        if name.startswith("deepseek-v2"):
+            from .deepseek_v2 import DeepseekV2
+            return DeepseekV2(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

@@ -16,12 +16,46 @@ import numpy as np
 import jax.numpy as jnp
 
 
-def rotary_freqs(rotary_dim, max_seq, base=10000.0, dtype=jnp.float32):
-    """(max_seq, rotary_dim/2) angle table."""
-    inv = 1.0 / (base ** (np.arange(0, rotary_dim, 2) / rotary_dim))
+def rotary_freqs(rotary_dim, max_seq, base=10000.0, dtype=jnp.float32,
+                 inv_freq=None):
+    """(max_seq, rotary_dim/2) angle table; ``inv_freq`` replaces the plain
+    ``base^(-2i/d)`` (:func:`yarn_inv_freq`)."""
+    inv = (1.0 / (base ** (np.arange(0, rotary_dim, 2) / rotary_dim))
+           if inv_freq is None else np.asarray(inv_freq))
     t = np.arange(max_seq)
     ang = np.einsum("t,f->tf", t, inv)
     return jnp.asarray(np.cos(ang), dtype), jnp.asarray(np.sin(ang), dtype)
+
+
+def yarn_inv_freq(rotary_dim, base, factor, original_max_position_embeddings,
+                  beta_fast=32, beta_slow=1):
+    """YaRN's per-pair inverse frequencies (arXiv:2309.00071, as HF
+    ``DeepseekV2YarnRotaryEmbedding`` computes them): pair ``i`` blends
+    ``base^(-2i/d)`` (kept, above the ``beta_fast`` correction dim: the fast
+    pairs) and the same over ``factor`` (interpolated, below ``beta_slow``'s)
+    by a linear ramp between the two correction dims at the ORIGINAL context
+    length.  Nothing here depends on the served limit."""
+    extra = base ** (-np.arange(0, rotary_dim, 2, dtype=np.float64)
+                     / rotary_dim)
+    inter = extra / factor
+
+    def correction_dim(n_rot):
+        return (rotary_dim * np.log(original_max_position_embeddings
+                                    / (n_rot * 2 * np.pi))) / (2 * np.log(base))
+    low = max(np.floor(correction_dim(beta_fast)), 0)
+    high = min(np.ceil(correction_dim(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001                         # as published: no singularity
+    ramp = np.clip((np.arange(rotary_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp                         # 1: the pair is extrapolated
+    return inter * (1 - keep) + extra * keep
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's attention-magnitude factor ``0.1 * mscale * ln(factor) + 1``
+    (1 for ``factor`` <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * np.log(factor) + 1.0
 
 
 def apply_rotary_pos_emb(x, cos, sin, positions, neox_style=True):

@@ -760,3 +760,61 @@ def test_compile_cache_key_covers_moe_policy(devices):
     assert keys[1]["enabled"] and keys[1]["moe"] == {"bits": 8,
                                                      "block_size": 8}
     assert keys[2]["moe"]["block_size"] == 4
+
+
+# --------------------------------------- the dropless route beside the old gate
+# moe/dropless.py (PR 34) routes top-k with no capacity; TopKGate keeps its
+# capacity path for gpt2_moe, refuses any other k, and the two agree on WHICH
+# experts a token picks.
+from deepspeed_tpu.moe import dropless  # noqa: E402
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_dropless_route_picks_what_the_capacity_gate_picks(k):
+    S, E, M = 24, 8, 16
+    gate = TopKGate(M, E, k=k, capacity_factor=float(S), min_capacity=S,
+                    use_rts=False)
+    params = gate.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (S, M))
+    _, combine, dispatch, counts = gate.apply(
+        params, x, rng=jax.random.PRNGKey(4), train=False)
+    # ample capacity: nothing dropped, k picks a token
+    assert int(dispatch.sum()) == k * S     # (exp_counts counts first picks)
+    logits = x @ params["wg"]
+    experts, weights = dropless.route(logits, k, norm_topk_prob=(k == 2))
+    picked = np.asarray(dispatch.any(axis=-1))            # (S, E)
+    first = np.asarray(experts)[:, 0]
+    assert picked[np.arange(S), first].all()
+    np.testing.assert_array_equal(first, np.asarray(logits).argmax(1))
+    if k == 1:
+        # one pick: the same expert, weighted by its softmax score
+        assert picked.sum() == S
+        np.testing.assert_allclose(
+            np.asarray(combine.sum(axis=-1))[np.arange(S), first],
+            np.asarray(weights)[:, 0], rtol=1e-5)
+    else:
+        # the old gate SAMPLES its second expert (Gumbel); the dropless
+        # route takes the second largest, and renormalises the two
+        second = np.asarray(experts)[:, 1]
+        masked = np.asarray(logits).copy()
+        masked[np.arange(S), first] = -np.inf
+        np.testing.assert_array_equal(second, masked.argmax(1))
+        np.testing.assert_allclose(np.asarray(weights).sum(1), 1.0, rtol=1e-5)
+
+
+def test_the_capacity_gate_still_refuses_other_k_and_still_drops():
+    with pytest.raises(ValueError, match="top-1 and top-2"):
+        TopKGate(16, 8, k=6)
+    # 24 tokens to one expert at capacity 4: the old gate drops 20, the
+    # dropless layer computes all 24
+    logits = jnp.zeros((24, 8)).at[:, 3].set(9.0)
+    _, _, dispatch, _ = top1gating(logits, 1.0, 4, use_rts=False)
+    assert int(dispatch.sum()) == 4
+    experts, weights = dropless.route(logits, 1)
+    assert (np.asarray(experts) == 3).all()
+    x = jax.random.normal(jax.random.PRNGKey(2), (24, 16))
+    w = jax.random.normal(jax.random.PRNGKey(3), (8, 16, 16)) * 0.2
+    out = dropless.held_experts(x, experts, weights, w, w,
+                                jnp.swapaxes(w, 1, 2), 0)
+    assert (np.abs(np.asarray(out)).max(axis=1) > 0).all()
+    assert dropless.route_counters(experts, 0, 8).tolist() == [24, 0, 1, 7, 0]
