@@ -24,10 +24,18 @@ BlockSpec-LUT and manual-DMA variants side by side):
   slot, the slot's **live** tokens (``length + W`` — short slots skip
   their tail entirely) fetched in chunks of whole 128-lane score columns
   through a triple-buffered VMEM ring with explicit ``make_async_copy``
-  from the HBM-resident pool (chunk c+2's fetch issues before chunk c's
-  compute, the ``_fwd_kernel_dma`` discipline), masked
-  **online-softmax** (fp32 running max/denominator) accumulation per
-  chunk.  Every tile keeps the pool's ``(rows, H·hd)`` shape: Mosaic
+  from the HBM-resident pool, masked **online-softmax** (fp32 running
+  max/denominator) accumulation per chunk.  The walk does live work
+  only: a **dead row** (an empty slot: ``tables[b, 0]`` is the scratch
+  block) issues no DMA, runs no matmul and writes zeros; the **ring is
+  carried** across programs (the grid is sequential and scratch
+  persists: program 0 writes a plan into SMEM — each row's next live
+  row and the ring position of its first chunk — and position p's
+  fetch issues before position p - 2 computes, whichever row owns it,
+  the ``_fwd_kernel_dma`` discipline stretched over the whole call);
+  and the **tail is trimmed**: a chunk fetches only the blocks that
+  hold a live position, the value tile's dead rows zeroed before
+  ``P @ V``.  Every tile keeps the pool's ``(rows, H·hd)`` shape: Mosaic
   DMAs and slices in whole 128-lane tiles, so a 64-lane head is never
   cut out — heads are separated by a block-diagonal query operand on
   the MXU (``_online_kernel``);
@@ -67,6 +75,11 @@ NEG_INF = float(np.finfo(np.float32).min)
 _N_BUF = 3    # DMA ring depth (flash_attention._N_KV_BUF): slot (j+2)%3
 #               held block j-1 (consumed one grid step ago), so the j+2
 #               fetch can start BEFORE block j's compute with no hazard
+
+# inference/paged_kv.SCRATCH_BLOCK (not imported: inference imports the
+# models, which import this package): the block a table is padded with and
+# the one an empty slot's whole table names
+SCRATCH_BLOCK = 0
 
 
 def _interpret():
@@ -196,7 +209,19 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     chunks of ``group`` blocks (``group * block_size`` key positions: a
     whole number of 128-lane score columns) through a triple-buffered
     make_async_copy ring from the HBM pool, carrying fp32 online-softmax
-    state (m, l, acc) per row.
+    state (m, l, acc) per row.  A dead row (its table names the scratch
+    block: the fact ``inference/serving.py``'s own step reads) fetches
+    nothing, computes nothing and writes zeros.
+
+    The ring is ONE ring over the whole call.  Program 0 writes the plan
+    into SMEM scratch, which like the VMEM buffers and the DMA semaphores
+    persists across the sequential grid: for every row the next live row
+    and the ring position of its first chunk (a live row has
+    ``ceil((length + n_tok) / chunk)`` chunks, a dead row none).  The
+    chunk at ring position p lands in buffer ``p % 3`` and its fetch
+    starts before position p - 2 computes, whichever row that is — so a
+    row's last chunks compute while the next live row's first two are in
+    flight.  A chunk fetches only the blocks that hold a live position.
 
     Every tile keeps the pool's (rows, H*hd) shape — no per-head slice,
     reshape or transpose of a sub-128-lane head (Mosaic keeps none of
@@ -213,45 +238,77 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     the compute dtype (exact: |q| <= 127) and the fp32 scales multiply
     the (R, chunk) score/probability tiles, never a (chunk, H*hd) one:
     they arrive already transposed to (rows, positions)
-    (:func:`_scale_rows`).  Rows of one head sum their partial scores
+    (:func:`_scale_rows`), one tile a chunk, started and awaited with the
+    chunk's payload.  Rows of one head sum their partial scores
     through a 0/1 matmul before the softmax.
 
     Grouped / multi-query pools (``q_per_kv`` > 1 query heads to a KV
     head; the pool's width is the KV heads alone): the ``q_per_kv`` query
     heads of one KV head ride the WINDOW axis — the caller hands in
     ``W * q_per_kv`` query rows of the pool's width — and share window
-    token ``w``'s causal limit.  ``q_per_kv == 1`` emits the program it
-    always did."""
+    token ``w``'s causal limit."""
     quantized = quant_block is not None
     if quantized:
         (tables_ref, lengths_ref, layer_ref, q_ref,
          k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
          kbuf, vbuf, ksbuf, vsbuf, kst, vst, m_ref, l_ref, acc_ref,
-         sem) = refs
+         sem, next_ref, first_ref) = refs
     else:
         (tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-         kbuf, vbuf, m_ref, l_ref, acc_ref, sem) = refs
+         kbuf, vbuf, m_ref, l_ref, acc_ref, sem, next_ref, first_ref) = refs
     b = pl.program_id(0)
+    B = tables_ref.shape[0]
     lay = layer_ref[0]
     bs, G, Rw = block_size, group, rows_per_token
     W, HD = q_ref.shape[1:]
+    n_tok = W // q_per_kv                     # window tokens
     qb = quant_block if quantized else head_dim
     per_head = head_dim // qb                 # rows per head
     R = W * Rw
     Tc = G * bs
     sm_scale = (1.0 / np.sqrt(head_dim)) if scale_attn else 1.0
-    length = lengths_ref[b]
-    # chunks that hold any position <= length + W - 1 (the window's last
-    # row); everything past is masked for every row — skip the DMA
     n_chunks = -(-nb_max // G)
-    n_live = jnp.minimum((length + W // q_per_kv + Tc - 1) // Tc, n_chunks)
 
-    def fetches(c, slot):
+    def live_units(row, unit, most):
+        """Chunks (or blocks) of ``row`` that hold a position <= length +
+        n_tok - 1, the window's last row; everything past is masked for
+        every row.  0 for a dead row."""
+        n = jnp.minimum((lengths_ref[row] + n_tok + unit - 1) // unit, most)
+        return jnp.where(tables_ref[row, 0] == SCRATCH_BLOCK, 0, n)
+
+    @pl.when(b == 0)
+    def _():
+        # the plan: next_ref[r] the first live row after r (B: none),
+        # first_ref[r] the chunks of the rows before r = the ring position
+        # of row r's first chunk; first_ref[B] the call's chunks
+        def back(k, nxt):
+            r = B - 1 - k
+            next_ref[r] = nxt
+            return jnp.where(tables_ref[r, 0] == SCRATCH_BLOCK, nxt, r)
+
+        def forth(r, pos):
+            first_ref[r] = pos
+            return pos + live_units(r, Tc, n_chunks)
+
+        next_ref[B] = B
+        jax.lax.fori_loop(0, B, back, B)
+        first_ref[B] = jax.lax.fori_loop(0, B, forth, 0)
+
+    n_live = live_units(b, Tc, n_chunks)      # this row's chunks; 0: dead
+    base = first_ref[b]                       # ring position of chunk 0
+    total = first_ref[B]
+
+    def fetches(row, c, slot):
+        """Chunk c of ``row`` into buffer ``slot``: (wanted, copies) pairs,
+        one per block of the chunk — wanted if the block holds a live
+        position, which a live chunk's first always does — and one for an
+        int8 pool's scale tiles, which go whole with their chunk."""
+        live_blocks = live_units(row, bs, nb_max)
         out = []
         for g in range(G):
-            # a chunk's tail past the table re-reads its last entry; the
-            # mask below drops every position >= nb_max * bs
-            ki = tables_ref[b, jnp.minimum(c * G + g, nb_max - 1)]
+            # a chunk's tail past the table is never fetched; the clamp
+            # keeps the scalar read inside the table
+            ki = tables_ref[row, jnp.minimum(c * G + g, nb_max - 1)]
             if quantized:
                 # int8 tiles are 32 sublanes: a block lands whole at
                 # [slot, g] and is cast into the chunk-shaped stage below
@@ -259,116 +316,147 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
             else:
                 kd = kbuf.at[slot, pl.ds(g * bs, bs)]
                 vd = vbuf.at[slot, pl.ds(g * bs, bs)]
-            out += [pltpu.make_async_copy(k_hbm.at[lay, ki], kd,
-                                          sem.at[slot, 0]),
-                    pltpu.make_async_copy(v_hbm.at[lay, ki], vd,
-                                          sem.at[slot, 1])]
+            out.append((g == 0 or c * G + g < live_blocks,
+                        [pltpu.make_async_copy(k_hbm.at[lay, ki], kd,
+                                               sem.at[slot, 0]),
+                         pltpu.make_async_copy(v_hbm.at[lay, ki], vd,
+                                               sem.at[slot, 1])]))
         if quantized:
             # a whole number of 128-lane tiles: Tc itself, or (a table
             # shorter than one tile) the single padded chunk at 0
             first = pl.multiple_of(c * Tc, 128) if n_chunks > 1 else 0
             cols = pl.ds(first, ksbuf.shape[-1])
-            out += [pltpu.make_async_copy(ks_hbm.at[b, :, cols],
-                                          ksbuf.at[slot], sem.at[slot, 2]),
-                    pltpu.make_async_copy(vs_hbm.at[b, :, cols],
-                                          vsbuf.at[slot], sem.at[slot, 3])]
+            out.append((True,
+                        [pltpu.make_async_copy(ks_hbm.at[row, :, cols],
+                                               ksbuf.at[slot],
+                                               sem.at[slot, 2]),
+                         pltpu.make_async_copy(vs_hbm.at[row, :, cols],
+                                               vsbuf.at[slot],
+                                               sem.at[slot, 3])]))
         return out
 
-    def start(c):
-        for cp in fetches(c, jax.lax.rem(c, _N_BUF)):
-            cp.start()
-
-    # row r = (w, i): window token w, quantization block i of the merged
-    # head dim (16-bit pools: i is the head)
-    row = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 1)
-    diag = col // qb == row % Rw                              # (R, HD)
-    q = q_ref[0].astype(jnp.float32)                          # (W, HD)
-    qbd = jnp.zeros((R, HD), jnp.float32)
-    for w in range(W):
-        mine = jnp.logical_and(diag, row // Rw == w)
-        qbd = jnp.where(mine, jnp.broadcast_to(q[w:w + 1], (R, HD)), qbd)
-    qbd = qbd.astype(compute_dtype)
-    if per_head > 1:
-        same_head = (
-            jax.lax.broadcasted_iota(jnp.int32, (R, R), 0) // per_head
-            == jax.lax.broadcasted_iota(jnp.int32, (R, R), 1) // per_head
-        ).astype(jnp.float32)
-
-    def per_window(x):
-        """(Rw, >= chunk) per-token rows -> (R, chunk): one copy per
-        window token (Rw is a whole number of sublane tiles)."""
-        x = x[:, :Tc]
-        return x if W == 1 else jnp.concatenate([x] * W, axis=0)
-
-    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    start(0)
-    if n_chunks > 1:
-        @pl.when(n_live > 1)
-        def _():
-            start(1)
-
-    def body(c, carry):
-        if n_chunks > 2:
-            @pl.when(c + 2 < n_live)
+    def each_copy(row, c, slot, act):
+        """Start or await (``act``) the wanted copies of chunk c."""
+        for wanted, copies in fetches(row, c, slot):
+            @pl.when(wanted)
             def _():
-                start(c + 2)
-        slot = jax.lax.rem(c, _N_BUF)
-        for cp in fetches(c, slot):
-            cp.wait()
-        if quantized:
-            for g in range(G):
-                rows = pl.ds(g * bs, bs)
-                kst[rows, :] = kbuf[slot, g].astype(jnp.float32).astype(
-                    compute_dtype)
-                vst[rows, :] = vbuf[slot, g].astype(jnp.float32).astype(
-                    compute_dtype)
-            k, v = kst[...], vst[...]
-        else:
-            k = kbuf[slot].astype(compute_dtype)
-            v = vbuf[slot].astype(compute_dtype)
-        s = jax.lax.dot_general(
-            qbd, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (R, Tc)
-        if quantized:
-            s = s * per_window(ksbuf[slot])
+                for cp in copies:
+                    act(cp)
+
+    def start_at(p):
+        """Start the fetch of ring position p, if the call has one: a
+        chunk of this row or of one of the next two live rows (p is at
+        most two past a position of this row, and every live row has a
+        chunk)."""
+        @pl.when(p < total)
+        def _():
+            after = next_ref[b]
+            row = jnp.where(p < first_ref[after], b, after)
+            after = next_ref[after]
+            row = jnp.where(p < first_ref[after], row, after)
+            each_copy(row, p - first_ref[row], jax.lax.rem(p, _N_BUF),
+                      lambda cp: cp.start())
+
+    @pl.when(n_live == 0)
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(n_live > 0)
+    def _():
+        @pl.when(base == 0)                   # the first live row
+        def _():
+            start_at(0)
+            start_at(1)
+
+        length = lengths_ref[b]
+        # row r = (w, i): window token w, quantization block i of the
+        # merged head dim (16-bit pools: i is the head)
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 1)
+        diag = col // qb == row % Rw                          # (R, HD)
+        q = q_ref[0].astype(jnp.float32)                      # (W, HD)
+        qbd = jnp.zeros((R, HD), jnp.float32)
+        for w in range(W):
+            mine = jnp.logical_and(diag, row // Rw == w)
+            qbd = jnp.where(mine, jnp.broadcast_to(q[w:w + 1], (R, HD)), qbd)
+        qbd = qbd.astype(compute_dtype)
         if per_head > 1:
+            same_head = (
+                jax.lax.broadcasted_iota(jnp.int32, (R, R), 0) // per_head
+                == jax.lax.broadcasted_iota(jnp.int32, (R, R), 1) // per_head
+            ).astype(jnp.float32)
+
+        def per_window(x):
+            """(Rw, >= chunk) per-token rows -> (R, chunk): one copy per
+            window token (Rw is a whole number of sublane tiles)."""
+            x = x[:, :Tc]
+            return x if W == 1 else jnp.concatenate([x] * W, axis=0)
+
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        def body(c, carry):
+            p = base + c
+            start_at(p + 2)
+            slot = jax.lax.rem(p, _N_BUF)
+            each_copy(b, c, slot, lambda cp: cp.wait())
+            if quantized:
+                for g in range(G):
+                    rows = pl.ds(g * bs, bs)
+                    kst[rows, :] = kbuf[slot, g].astype(jnp.float32).astype(
+                        compute_dtype)
+                    vst[rows, :] = vbuf[slot, g].astype(jnp.float32).astype(
+                        compute_dtype)
+                k, v = kst[...], vst[...]
+            else:
+                k = kbuf[slot].astype(compute_dtype)
+                v = vbuf[slot].astype(compute_dtype)
+            # a block that was not fetched leaves what the buffer held, and
+            # 0 x NaN is NaN in P @ V: dead positions' values are zeros
+            # (their keys are covered by the where on the scores)
+            v_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (Tc, HD), 0)
+            v = jnp.where(v_pos < length + n_tok, v, jnp.zeros_like(v))
             s = jax.lax.dot_general(
-                same_head, s, (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
+                qbd, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # (R, Tc)
+            if quantized:
+                s = s * per_window(ksbuf[slot])
+            if per_head > 1:
+                s = jax.lax.dot_general(
+                    same_head, s, (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+            s = s * sm_scale
+            k_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 1)
+            w_pos = jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 0) // (
+                Rw * q_per_kv)
+            last = jnp.minimum(length + w_pos, nb_max * bs - 1)
+            s = jnp.where(k_pos <= last, s, NEG_INF)
+            m_prev = m_ref[:]                                 # (R, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                            # (R, Tc) fp32
+            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
+            if quantized:
+                p = p * per_window(vsbuf[slot])
+            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+                p.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-        s = s * sm_scale
-        k_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 1)
-        w_pos = jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 0) // (
-            Rw * q_per_kv)
-        last = jnp.minimum(length + w_pos, nb_max * bs - 1)
-        s = jnp.where(k_pos <= last, s, NEG_INF)
-        m_prev = m_ref[:]                                     # (R, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                                # (R, Tc) fp32
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
-        if quantized:
-            p = p * per_window(vsbuf[slot])
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
-        return carry
+            m_ref[:] = m_new
+            return carry
 
-    jax.lax.fori_loop(0, n_live, body, 0)
+        jax.lax.fori_loop(0, n_live, body, 0)
 
-    l = l_ref[:]
-    l_safe = jnp.where(l == 0.0, 1.0, l)                     # never 0: k_pos
-    heads = jnp.where(diag, acc_ref[:] / l_safe, 0.0)        # 0 always live
-    out_row = jax.lax.broadcasted_iota(jnp.int32, (W, HD), 0)
-    out = jnp.zeros((W, HD), jnp.float32)
-    for w in range(W):
-        tok = jnp.sum(heads[w * Rw:(w + 1) * Rw], axis=0, keepdims=True)
-        out = jnp.where(out_row == w, jnp.broadcast_to(tok, (W, HD)), out)
-    o_ref[0] = out.astype(o_ref.dtype)
+        l = l_ref[:]
+        l_safe = jnp.where(l == 0.0, 1.0, l)                 # never 0: k_pos
+        heads = jnp.where(diag, acc_ref[:] / l_safe, 0.0)    # 0 always live
+        out_row = jax.lax.broadcasted_iota(jnp.int32, (W, HD), 0)
+        out = jnp.zeros((W, HD), jnp.float32)
+        for w in range(W):
+            tok = jnp.sum(heads[w * Rw:(w + 1) * Rw], axis=0, keepdims=True)
+            out = jnp.where(out_row == w, jnp.broadcast_to(tok, (W, HD)), out)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _scale_rows(scale, layer, tables, n_rows, n_cols):
@@ -440,6 +528,8 @@ def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
         pltpu.VMEM((R, 1), jnp.float32),           # l (denominator)
         pltpu.VMEM((R, HD), jnp.float32),          # acc
         pltpu.SemaphoreType.DMA((_N_BUF, 4 if quantized else 2)),
+        pltpu.SMEM((B + 1,), jnp.int32),           # plan: next live row
+        pltpu.SMEM((B + 1,), jnp.int32),           # plan: first ring position
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B,),

@@ -21,7 +21,16 @@ Edge coverage per the serving layer's invariants: partial last blocks,
 SCRATCH-slot inactivity (all-zero tables), per-slot length edges (block
 boundary, single token), multi-token windows (the speculative scoring
 step), and the write_tokens overflow-to-scratch guard.
+
+The online kernel's WALK (PR 35: dead rows do nothing and come last, one
+DMA ring over the whole call, the last chunk fetches its live blocks only)
+has its own cases below, over a table long enough for three chunks and a
+pool whose unreferenced blocks hold NaN: the interpreter fills VMEM scratch
+with NaN too, so a block that was not fetched and still reached the MXU
+fails them.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -60,6 +69,7 @@ TABLES = np.asarray([[1, 2, 3, 4],      # len 31: partial last block
                      [0, 0, 0, 0]],     # inactive slot (scratch)
                     np.int32)
 LENGTHS = np.asarray([31, 23, 8, 0, 0], np.int32)
+LIVE = TABLES[:, 0] != pk.SCRATCH_BLOCK
 
 
 def _oracle(model, q, pool, tables, lengths, layer):
@@ -111,7 +121,8 @@ def test_int8_pool_within_tolerance(mode, devices):
         np.testing.assert_array_equal(out, ref)
     else:
         scale = np.abs(ref).max()
-        assert np.abs(out - ref).max() < 0.02 * scale
+        assert np.abs(out - ref)[LIVE].max() < 0.02 * scale
+        assert not out[~LIVE].any()       # a dead row: zeros, not scratch
 
 
 @pytest.mark.parametrize("n_window", [1, 4])
@@ -132,7 +143,103 @@ def test_online_mode_within_compute_dtype_rounding(n_window, devices):
         lambda q, p: paged_attention(q, p, TABLES, LENGTHS, 1,
                                      mode="online"))(q, pool), np.float32)
     scale = np.abs(ref).max()
-    assert np.abs(out - ref).max() < 1e-2 * scale
+    assert np.abs(out - ref)[LIVE].max() < 1e-2 * scale
+    assert not out[~LIVE].any()
+
+
+# ------------------------------------------------- the online kernel's walk
+# blocks of 16 make chunks of 8 blocks = 128 positions; a table of 20 is
+# three chunks, the third half past the table's end
+WALK_BS, WALK_NB_MAX, WALK_CHUNK = 16, 20, 128
+#          name: (query heads, K/V heads, window, kv_bits)
+WALK_POOLS = {"bf16": (2, 2, 1, 16), "int8": (2, 2, 1, 8),
+              "mqa20": (20, 1, 1, 16), "window3": (2, 2, 3, 16)}
+# a row's length: the position of its first window token; None: a dead row
+# (its whole table names the scratch block, its length 0)
+WALK_ROWS = {
+    # dead rows first, in the middle and last
+    "dead_rows": [None, 40, None, None, 200, 5, None],
+    # one token before, on and after a block edge and a chunk edge, the
+    # second chunk's, and the table's last position
+    "edges": [14, 15, 16, 126, 127, 128, 254, 255, 256, 316],
+    "all_dead": [None, None, None],
+    # every chunk whole and every ring hand-over: one-chunk rows in a run
+    # (the fetch two positions ahead belongs to the row after next)
+    "handover": [127, 0, 3, 255, 127, None, 319 - 2, 1],
+    # every row seated and every chunk whole: the walk before PR 35
+    "full_rows": [127, 255, 127, 255],
+}
+
+
+def _walk_case(rows, heads, kv_heads, window, kv_bits):
+    """Pool, tables, lengths and queries of one case: every live row owns
+    its table's worth of blocks with finite K/V; every block no table
+    names holds NaN (an int8 pool: NaN scales)."""
+    rng = np.random.default_rng(5)
+    B = len(rows)
+    n_blocks = 1 + B * WALK_NB_MAX + 3
+    pool = pk.init_pool(1, n_blocks, WALK_BS, heads, HD, jnp.bfloat16,
+                        kv_bits=kv_bits, quant_block=8, n_kv_head=kv_heads)
+    tables = np.zeros((B, WALK_NB_MAX), np.int32)
+    lengths = np.zeros((B,), np.int32)
+    named = {pk.SCRATCH_BLOCK}
+    for b, length in enumerate(rows):
+        if length is None:
+            continue
+        n = -(-(length + window) // WALK_BS)           # the blocks it holds
+        tables[b, :n] = 1 + b * WALK_NB_MAX + np.arange(n)
+        lengths[b] = length
+        named.update(tables[b, :n].tolist())
+        kv = [jnp.asarray(rng.standard_normal(
+            (1, n * WALK_BS, kv_heads, HD)), jnp.bfloat16) for _ in "kv"]
+        pool = pk.write_prefill(pool, jnp.asarray(tables[b, :n]), *kv)
+    poison = np.asarray([i not in named for i in range(n_blocks)])
+    for name in (("k_scale", "v_scale") if kv_bits == 8 else ("k", "v")):
+        pool[name] = jnp.where(poison[None, :, None, None], jnp.nan,
+                               pool[name])
+    q = jnp.asarray(rng.standard_normal((B, window, heads, HD)),
+                    jnp.bfloat16)
+    return pool, tables, lengths, q
+
+
+def _dense_oracle(q, pool, tables, lengths, kv_heads):
+    """Plain float64 softmax attention over the ``gather_kv`` view."""
+    keys, vals = pk.gather_kv(pool, 0, jnp.asarray(tables), jnp.float32,
+                              kv_heads)
+    keys, vals = np.asarray(keys, np.float64), np.asarray(vals, np.float64)
+    q = np.asarray(q, np.float64)
+    B, W, n_head, hd = q.shape
+    out = np.zeros((B, W, n_head * hd))
+    for b, w, h in np.ndindex(B, W, n_head):
+        n = int(lengths[b]) + w + 1
+        kv = h // (n_head // kv_heads)
+        s = keys[b, :n, kv] @ q[b, w, h] / np.sqrt(hd)
+        p = np.exp(s - s.max())
+        out[b, w, h * hd:(h + 1) * hd] = (p / p.sum()) @ vals[b, :n, kv]
+    return out
+
+
+@pytest.mark.parametrize("pool_kind", list(WALK_POOLS))
+@pytest.mark.parametrize("rows", list(WALK_ROWS))
+def test_online_walk_live_rows_match_dead_rows_are_zero(rows, pool_kind,
+                                                        devices):
+    """Live rows equal the gather oracle within the online mode's
+    tolerance wherever they stand in the batch and wherever their length
+    ends; dead rows are exactly zero; nothing is NaN though every block
+    the walk must not fetch is."""
+    heads, kv_heads, window, kv_bits = WALK_POOLS[pool_kind]
+    pool, tables, lengths, q = _walk_case(WALK_ROWS[rows], heads, kv_heads,
+                                          window, kv_bits)
+    out = np.asarray(jax.jit(
+        lambda q, p: paged_attention(q, p, tables, lengths, 0,
+                                     mode="online"))(q, pool), np.float32)
+    live = tables[:, 0] != pk.SCRATCH_BLOCK
+    assert np.isfinite(out).all()
+    assert not out[~live].any()
+    if live.any():
+        ref = _dense_oracle(q, pool, tables, lengths, kv_heads)
+        tol = (2e-2 if kv_bits == 8 else 1e-2) * np.abs(ref[live]).max()
+        assert np.abs(out - ref)[live].max() < tol
 
 
 def test_decode_step_kernel_vs_gather_impl(devices):
@@ -225,3 +332,56 @@ def test_write_tokens_overflow_lands_in_scratch(devices):
     assert k_np[0, 2, 2:].any() and k_np[0, 2].sum() == 2 * 8  # rows 2,3
     assert k_np[0, 1].sum() == 0          # block 1 (real) untouched
     assert k_np[0, pk.SCRATCH_BLOCK, 0].sum() == 8   # overflow -> scratch
+
+
+# ------------------------------------------ the kernel the TPU compiles
+@pytest.fixture(scope="module")
+def v5e():
+    """A described v5e:2x2 (no chip needed); built here and never at
+    import, so every xdist worker collects the same tests."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.mark.parametrize("slots,heads,kv_heads,head_dim,positions,kv_bits", [
+    (16, 16, 16, 128, 2048, 16),      # cerebras-gpt-1.3b, ouro-2.6b
+    (16, 20, 20, 64, 1024, 16),       # gpt2-large
+    (64, 20, 1, 128, 2048, 16),       # jamba2-3b: 20 query heads, one K/V
+    (16, 16, 16, 128, 2048, 8),       # an int8 pool and its scale rows
+], ids=["cerebras-gpt-1.3b", "gpt2-large", "jamba2-3b", "int8-pool"])
+def test_online_kernel_compiles_for_a_v5e(v5e, slots, heads, kv_heads,
+                                          head_dim, positions, kv_bits):
+    """The online kernel at the served shapes, through Mosaic and XLA:TPU
+    for a described v5e: one custom call, and no copy of the pool (the
+    kernel reads blocks where they lie)."""
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    block, layers = 16, 2
+    nb_max = positions // block
+    width = kv_heads * head_dim
+    rows = (layers, slots * nb_max + 1, block)
+    pool = {n: (rows + (width,), jnp.bfloat16 if kv_bits == 16 else jnp.int8)
+            for n in ("k", "v")}
+    if kv_bits == 8:
+        pool.update({n + "_scale": (rows + (width // 64,), jnp.float32)
+                     for n in ("k", "v")})
+    names = sorted(pool)
+
+    def fn(q, tables, lengths, *leaves):
+        return paged_attention(q, dict(zip(names, leaves)), tables, lengths,
+                               1, mode="online", interpret=False)
+    shapes = [((slots, 1, heads, head_dim), jnp.bfloat16),
+              ((slots, nb_max), jnp.int32), ((slots,), jnp.int32),
+              *[pool[n] for n in names]]
+    text = jax.jit(fn).trace(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    ]).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    payload = "{}[{}]".format("bf16" if kv_bits == 16 else "s8",
+                              ",".join(map(str, pool["k"][0])))
+    assert payload in text                     # the pool is an operand
+    assert not re.search(re.escape(" = " + payload) + r"\S* copy\(", text)
