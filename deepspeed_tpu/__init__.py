@@ -7,6 +7,11 @@ compute path is jitted SPMD over a named device mesh, not a port of the
 reference's torch/CUDA machinery.
 """
 
+import sys as _sys
+import time as _time
+_T_IMPORT = _time.monotonic()           # setup.import starts here
+_JAX_PRELOADED = "jax" in _sys.modules
+
 from .version import __version__
 from .runtime.activation_checkpointing import checkpointing
 from .runtime.engine import DeepSpeedEngine
@@ -15,8 +20,10 @@ from .runtime.health import HealthMonitor, TrainingHealthError
 from .runtime.lr_schedules import get_lr_scheduler
 from .runtime import zero
 from .utils.logging import logger, log_dist
+from .monitor import spans as _spans
 
 
+@_spans.in_setup_span("setup.engine_init", engine="train")
 def initialize(args=None, model=None, optimizer=None, model_parameters=None,
                training_data=None, lr_scheduler=None, mpu=None,
                dist_init_required=None, collate_fn=None, config=None,
@@ -169,3 +176,8 @@ def init_inference(model=None, **kwargs):
     """Build an InferenceEngine. Parity: reference ``deepspeed/__init__.py:221``."""
     from .inference.engine import InferenceEngine
     return InferenceEngine(model, **kwargs)
+
+
+# the package's own import, top to bottom (monitor/startup.py: "import")
+_spans.recorder().setup_record("setup.import", _T_IMPORT, _time.monotonic(),
+                               attrs={"jax_preloaded": _JAX_PRELOADED})

@@ -28,11 +28,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..monitor import spans as monspans
 from ..parallel import mesh as M
 from ..utils.logging import logger, log_dist
 
 
 class InferenceEngine:
+    @monspans.in_setup_span("setup.engine_init", engine="InferenceEngine")
     def __init__(self, model=None, mp_size: int = 1, dtype=None,
                  checkpoint: Optional[str] = None, params: Any = None,
                  replace_with_kernel_inject: bool = False,

@@ -547,6 +547,7 @@ class ServingEngine:
     or None for defaults.
     """
 
+    @monspans.in_setup_span("setup.engine_init", engine="ServingEngine")
     def __init__(self, model=None, params=None, engine=None, config=None,
                  mesh=None, compile_cache=None, monitor=None,
                  **engine_kwargs):
@@ -613,11 +614,13 @@ class ServingEngine:
         self._recurrent = bool(getattr(inner, "has_recurrent_state", False))
         if self._recurrent:
             self._refuse_for_recurrent_state(config)
-        with jax.set_mesh(engine.mesh):
+        with monspans.recorder().setup_span("setup.pool_alloc") as alloc, \
+                jax.set_mesh(engine.mesh):
             self.pool = inner.init_serving_state(
                 config.batch_slots, self.num_blocks, config.block_size,
                 kv_bits=config.kv_bits, quant_block=config.kv_quant_block,
                 dtype=cache_dtype)
+            alloc.attrs = {"bytes": pk.pool_bytes(self.pool)}
         self._recurrent_bytes = (inner.recurrent_state_bytes(self.pool)
                                  if self._recurrent else 0)
         if pk.is_latent_pool(self.pool):
@@ -809,6 +812,7 @@ class ServingEngine:
         # (--audit-step tracing proves jaxpr equality armed vs disarmed).
         # The process-wide span recorder records armed or not.
         self._spans = monspans.recorder()
+        self._startup_line_due = True
         # sampled live requests -> wall-clock submit time (the `trace`
         # event's anchor; everything else it carries is in results[uid])
         self._traces: Dict[int, float] = {}
@@ -2602,6 +2606,16 @@ class ServingEngine:
             raise
         finally:
             self._spans.close(root)      # nothing, after an idle poll
+            if self._startup_line_due and self._steps:
+                self._log_startup()
+
+    def _log_startup(self):
+        """Once, when the first step that did anything has returned: where
+        this process's time went before it could serve
+        (docs/monitoring.md#start-up)."""
+        from ..monitor import startup
+        self._startup_line_due = False
+        log_dist(f"ServingEngine {startup.line()}", ranks=[0])
 
     def _settles_every_step(self, active) -> bool:
         """Must the step dispatched for ``active`` be read before this call

@@ -18,6 +18,20 @@ small dict.  Rows live in a bounded ring, oldest dropped, with a ``dropped``
 count and the end time of the newest dropped row (``dropped_until``) so a
 reader can refuse a range the ring no longer covers.
 
+Start-up is the exception: its rows are few, the oldest, and read after
+everything else has been written.  A span opened with :meth:`setup_span`
+(the layers ``setup.*`` and ``compile.*``) goes into the ring like any other
+AND into a small store of its own that step rows never displace; the root of
+a step in which such a row was recorded (the warm-up's ``serving.step`` /
+``train.step``) is kept with it.  JAX's own ``jax.monitoring`` durations
+(tracing, lowering to MLIR, the backend compile) are rows ``jax.trace`` /
+``jax.lower`` / ``jax.compile`` of that store, which is how a ``jax.jit``
+that no ``CachedStep`` wraps is seen at all.  :meth:`setup_rows` returns the
+store and whether it is whole, ``t_process_start`` is when the process began
+on this clock, and :func:`innermost_seconds` divides an interval among rows
+that nest (``monitor/startup.py`` names the phases).  The hot path pays
+nothing for any of it: ``open`` / ``close`` / ``_append`` are as they were.
+
 Every span is also a ``jax.profiler.TraceAnnotation("ds.<name>")``: a
 profiler capture holds the program's spans on the device trace's own clock,
 beside the device's operations (Perfetto / XProf), with step and uid as
@@ -31,12 +45,28 @@ the recorder takes no lock: a ``deque.append`` is atomic under the GIL.
 """
 
 import collections
+import functools
+import heapq
+import os
 import time
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 ANNOTATION_PREFIX = "ds."        # never "bench.": that is the benchmark's
-DEFAULT_CAPACITY = 65536         # rows; serve_chat's 40 s are about 30 k
+DEFAULT_CAPACITY = 65536         # rows; serve_chat's 40 s are about 46 k
+SETUP_CAPACITY = 4096            # set-up rows; a process writes hundreds
+# a model's trace emits a sub-millisecond duration for every jitted jnp
+# call inside it, thousands an executable; rows that short move no metric
+# read in seconds, and the rows that hold them are kept
+JAX_ROW_MIN_S = 1e-3
+JAX_ROWS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+# fires inside a backend compile that JAX's persistent cache answered
+JAX_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 Span = collections.namedtuple(
     "Span", "name t_start t_end parent step uid attrs")
@@ -58,8 +88,19 @@ class _Open:
         return False
 
 
+class _OpenSetup(_Open):
+    """A span of start-up: closed into the ring and the set-up store."""
+
+    __slots__ = ()
+
+    def __exit__(self, *exc):
+        self._recorder.close_setup(self)
+        return False
+
+
 class SpanRecorder:
-    def __init__(self, capacity=DEFAULT_CAPACITY, clock=time.monotonic):
+    def __init__(self, capacity=DEFAULT_CAPACITY, clock=time.monotonic,
+                 setup_capacity=SETUP_CAPACITY):
         self.capacity = int(capacity)
         self._clock = clock
         self._stack = []
@@ -67,6 +108,15 @@ class SpanRecorder:
         self._appended = 0           # rows ever appended (a span's ``mark``)
         self.dropped = 0             # rows the ring has pushed out
         self.dropped_until = None    # t_end of the newest of them
+        # when the process began, on this clock (a clock of the caller's
+        # own has no such moment: the recorder's creation stands in)
+        self.t_process_start = (_PROCESS_START if clock is time.monotonic
+                                else clock())
+        self.setup_capacity = int(setup_capacity)
+        self._setup = []             # set-up rows, in the order they ended
+        self._setup_roots = []       # step roots a set-up row ended inside
+        self.setup_dropped = 0       # set-up rows refused at the cap
+        self._jax_cache_hit = False  # a retrieval since the last compile
 
     @property
     def depth(self) -> int:
@@ -109,6 +159,69 @@ class SpanRecorder:
         return rec
 
     span = open
+
+    def setup_span(self, name, attrs=None) -> _Open:
+        """Start a span of start-up (``setup.*``, ``compile.*``): one
+        :meth:`open` makes, kept in the set-up store too when it closes.
+        The step rows' own entry points know nothing of that store."""
+        rec = self.open(name, attrs=attrs)
+        rec.__class__ = _OpenSetup
+        return rec
+
+    def close_setup(self, rec: _Open) -> float:
+        """:meth:`close` for a span of :meth:`setup_span`."""
+        was_open = not rec.closed
+        seconds = self.close(rec)
+        if was_open:
+            # the ring's row and this one share ``attrs``: what is added
+            # to the dict after the close is read from both
+            self._keep(Span(rec.name, rec.t0, rec.t1, rec.parent, rec.step,
+                            rec.uid, rec.attrs))
+        return seconds
+
+    def setup_record(self, name, t_start, t_end, attrs=None):
+        """A set-up row whose times the caller holds (the package's import;
+        a duration JAX reports when it is over).  Kept in the store alone:
+        no reader of the ring knows these names."""
+        self._keep(Span(name, t_start, t_end, None, None, None, attrs))
+
+    def _keep(self, row):
+        if len(self._setup) >= self.setup_capacity:
+            self.setup_dropped += 1
+            return
+        self._setup.append(row)
+        # the step that stood still for this row is start-up too: its root
+        # is open now and has no row yet, so the object is kept and read
+        # once it has closed.  Nothing is asked of a step that acquires
+        # nothing.
+        root = self._stack[0] if self._stack else None
+        if root.__class__ is _Open and (
+                not self._setup_roots or self._setup_roots[-1] is not root):
+            self._setup_roots.append(root)
+
+    def _jax_duration(self, event, seconds):
+        """One of JAX's compile durations, as it ends (the listener)."""
+        if event == JAX_CACHE_RETRIEVAL:
+            self._jax_cache_hit = True
+            return
+        name = JAX_ROWS.get(event)
+        if name is None:
+            return
+        attrs = None
+        if name == "jax.compile":
+            if self._jax_cache_hit:
+                attrs = {"cached": True}
+            self._jax_cache_hit = False
+        if seconds < JAX_ROW_MIN_S:
+            return
+        now = self._clock()
+        start = now - seconds
+        # a jit traced inside another reports before it: the row that
+        # holds those of its own name replaces them
+        kept = self._setup
+        while kept and kept[-1].name == name and kept[-1].t_start >= start:
+            kept.pop()
+        self._keep(Span(name, start, now, None, None, None, attrs))
 
     def close(self, rec: _Open) -> float:
         """Close ``rec`` and anything left open inside it (an exception may
@@ -187,8 +300,16 @@ class SpanRecorder:
         """Iterate the ring from its newest row backwards."""
         return reversed(self._rows)
 
+    def setup_rows(self):
+        """``(rows, whole)``: the set-up store in the order its rows ended,
+        with the closed roots of the steps they ended inside, and whether
+        the store has kept every row it was handed."""
+        roots = [Span(r.name, r.t0, r.t1, None, r.step, r.uid, r.attrs)
+                 for r in self._setup_roots if r.t1 is not None]
+        return self._setup + roots, self.setup_dropped == 0
+
     def reset(self):
-        """Forget everything: rows, open spans and the dropped count."""
+        """Forget everything: rows, open spans and the dropped counts."""
         for rec in self._stack:
             self._leave(rec)
         self._stack = []
@@ -196,6 +317,9 @@ class SpanRecorder:
         self._appended = 0
         self.dropped = 0
         self.dropped_until = None
+        self._setup = []
+        self._setup_roots = []
+        self.setup_dropped = 0
 
 
 def leaf(name, root_name):
@@ -209,9 +333,74 @@ def leaf(name, root_name):
     return name
 
 
+def innermost_seconds(rows, t_from, t_to, label):
+    """``[t_from, t_to]`` divided among ``rows``: each instant goes to the
+    innermost row that covers it (the one that started last) and is booked
+    under ``label(row)``; an instant no row covers is booked under ``None``.
+    ``{label: seconds}``; the values add up to ``t_to - t_from``."""
+    out = collections.defaultdict(float)
+    if t_to <= t_from:
+        return dict(out)
+    rows = sorted((r for r in rows if r.t_end > t_from and r.t_start < t_to
+                   and r.t_end > r.t_start),
+                  key=lambda r: (r.t_start, -r.t_end))
+    cuts = sorted({t_from, t_to}.union(
+        t for r in rows for t in (r.t_start, r.t_end) if t_from < t < t_to))
+    open_rows, i = [], 0       # heap of (-t_start, order, row)
+    for lo, hi in zip(cuts, cuts[1:]):
+        while i < len(rows) and rows[i].t_start <= lo:
+            heapq.heappush(open_rows, (-rows[i].t_start, -i, rows[i]))
+            i += 1
+        while open_rows and open_rows[0][2].t_end <= lo:
+            # ended: an outer row that ended before it is still in the
+            # heap below and is dropped when it comes to the top
+            heapq.heappop(open_rows)
+        out[label(open_rows[0][2]) if open_rows else None] += hi - lo
+    return dict(out)
+
+
+def _process_start():
+    """When this process began, on ``time.monotonic()``: its start time in
+    ``/proc/self/stat`` (clock ticks since boot) against ``CLOCK_BOOTTIME``.
+    The program cannot see a harness's own first clock read, and everything
+    before the first import of this module would otherwise have no start.
+    Falls back to now, the module's import."""
+    now = time.monotonic()
+    try:
+        with open("/proc/self/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - int(fields[19]) / os.sysconf("SC_CLK_TCK"))
+    except (OSError, AttributeError, ValueError, IndexError):
+        return now
+    return now - age if age >= 0.0 else now
+
+
+_PROCESS_START = _process_start()
 _RECORDER = SpanRecorder()
+
+
+def _on_jax_duration(event, seconds, **_):
+    _RECORDER._jax_duration(event, seconds)
+
+
+# one listener for the process: it runs when something compiles, so never
+# inside a clean window
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def recorder() -> SpanRecorder:
     """The process-wide recorder both engines write into."""
     return _RECORDER
+
+
+def in_setup_span(name, **attrs):
+    """Decorator: each call of the function is one span of start-up in the
+    process-wide recorder (an engine's constructor)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with _RECORDER.setup_span(name, attrs=dict(attrs)):
+                return fn(*args, **kwargs)
+        return spanned
+    return wrap

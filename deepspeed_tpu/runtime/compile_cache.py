@@ -534,17 +534,19 @@ class CachedStep:
     def _acquire(self, args, kwargs, sig):
         # the compile.* spans nest under whatever step called for the
         # executable, which is how an in-window recompile names its step;
-        # lower_ms / compile_ms / deserialize_ms are their durations
+        # lower_ms / compile_ms / deserialize_ms are their durations.
+        # Down to the ``lower`` call this frame keeps its size (ROADMAP
+        # D13): what is new is a callee's, or comes after it has returned.
         rec = spans.recorder()
-        with rec.span("compile.lower", attrs={"fn": self.name}) as span:
+        with rec.setup_span("compile.lower", attrs={"fn": self.name}) as span:
             lowered = self._jit.lower(*args, **kwargs)
         lower_ms = (span.t1 - span.t0) * 1000
+        _split_lower(rec, span)
         cache = self.cache
         material = None
         if cache is not None:
             cache._count("lower_ms", lower_ms)
-            material = build_key_material(self.name, args, lowered,
-                                          self.key_extra, kwargs=kwargs)
+            material = self._key_material(args, lowered, kwargs)
         if material is not None:
             key = key_from_material(material)
             exe = self._try_deserialize(cache, key,
@@ -555,8 +557,8 @@ class CachedStep:
                 return hit
         else:
             key = "<uncached>"
-        with rec.span("compile.build",
-                      attrs={"fn": self.name, "source": "compile"}) as span:
+        with rec.setup_span("compile.build", attrs={
+                "fn": self.name, "source": "compile"}) as span:
             compiled = lowered.compile()
             if material is not None:
                 self._try_serialize(cache, key, compiled, material)
@@ -570,6 +572,15 @@ class CachedStep:
         self._exes[sig] = hit
         return hit
 
+    def _key_material(self, args, lowered, kwargs):
+        """:func:`build_key_material` under its own span, ``compile.key``
+        (the lowered text rendered and hashed), in a callee so that
+        ``_acquire``'s frame keeps its size (ROADMAP D13)."""
+        with spans.recorder().setup_span("compile.key",
+                                         attrs={"fn": self.name}):
+            return build_key_material(self.name, args, lowered,
+                                      self.key_extra, kwargs=kwargs)
+
     def _note(self, span, exe, args):
         if self._describe is None:
             return
@@ -582,8 +593,8 @@ class CachedStep:
 
     def _try_deserialize(self, cache, key, devices, args=()):
         rec = spans.recorder()
-        with rec.span("compile.load",
-                      attrs={"fn": self.name, "source": "cache"}) as span:
+        with rec.setup_span("compile.load", attrs={
+                "fn": self.name, "source": "cache"}) as span:
             payload = cache.get(key)
             if payload is None:
                 rec.discard(span)    # nothing stored: a lookup, not a load
@@ -627,6 +638,22 @@ class CachedStep:
                            "entry not persisted")
             return
         cache.put(key, payload, meta=material)
+
+
+def _split_lower(rec, span):
+    """``trace_s`` / ``mlir_s`` on a closed ``compile.lower`` span: the
+    seconds of it JAX reported as tracing the function and as lowering the
+    jaxpr to MLIR (the recorder's ``jax.trace`` / ``jax.lower`` rows inside
+    it).  Left out where JAX reported neither."""
+    inside = [r for r in rec.setup_rows()[0]
+              if r.name in ("jax.trace", "jax.lower")
+              and r.t_start >= span.t0 and r.t_end <= span.t1]
+    parts = spans.innermost_seconds(inside, span.t0, span.t1,
+                                    lambda r: r.name)
+    if "jax.trace" in parts:
+        span.attrs["trace_s"] = parts["jax.trace"]
+    if "jax.lower" in parts:
+        span.attrs["mlir_s"] = parts["jax.lower"]
 
 
 def executable_memory_analysis(exe):

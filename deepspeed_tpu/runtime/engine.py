@@ -163,6 +163,7 @@ class DeepSpeedEngine:
             self.monitor = moncore.Monitor(run_dir=None, sinks=())
         from ..monitor import spans as monspans
         self._spans = monspans.recorder()  # records armed or not
+        self._startup_line_due = True
         self._mon_tokens_per_step = None   # lazy: first stacked batch
         self._mon_step_stats = None        # lazy: per-program flops/wire
         self._mon_example = None           # (batch, rng) for one-time pricing
@@ -265,22 +266,24 @@ class DeepSpeedEngine:
                     "offload_param.fast_init requires the model to expose "
                     "init_numpy(seed) (a host-RAM init twin)")
             params = model.init_numpy(rng_seed)
-        self._loss_fn, params0, self._apply_fn, self._tp_specs = _resolve_model(
-            model, loss_fn, params, apply_fn, rng_seed,
-            init_on_host=offload_wanted)
-        # one jitted cast, not one dispatch per leaf; under offload the
-        # cast runs ON THE HOST backend — the default-device jit would
-        # silently haul the tree to the accelerator
-        f32 = lambda t: tree_cast(t, jnp.float32)
-        if all(np.dtype(l.dtype) == np.float32
-               for l in jax.tree_util.tree_leaves(params0)):
-            pass      # already fp32: skip the cast (a copy of the whole
-            # tree — prohibitive transient RAM at beyond-HBM param counts)
-        elif offload_wanted:
-            with jax.default_device(jax.devices("cpu")[0]):
+        with self._spans.setup_span("setup.params_init"):
+            (self._loss_fn, params0, self._apply_fn,
+             self._tp_specs) = _resolve_model(
+                model, loss_fn, params, apply_fn, rng_seed,
+                init_on_host=offload_wanted)
+            # one jitted cast, not one dispatch per leaf; under offload the
+            # cast runs ON THE HOST backend — the default-device jit would
+            # silently haul the tree to the accelerator
+            f32 = lambda t: tree_cast(t, jnp.float32)
+            if all(np.dtype(l.dtype) == np.float32
+                   for l in jax.tree_util.tree_leaves(params0)):
+                pass  # already fp32: skip the cast (a copy of the whole
+                # tree — prohibitive transient RAM at beyond-HBM param counts)
+            elif offload_wanted:
+                with jax.default_device(jax.devices("cpu")[0]):
+                    params0 = jax.jit(f32)(params0)
+            else:
                 params0 = jax.jit(f32)(params0)
-        else:
-            params0 = jax.jit(f32)(params0)
 
         # ---- quantized-collectives router (runtime/comm/) ----------------
         # Per-route wire policy: qwZ int8 param gathers, qgZ error-fed
@@ -433,7 +436,11 @@ class DeepSpeedEngine:
                          "offload d2h path only", ranks=[0])
 
         # ---- initial device state -----------------------------------------
-        self.state = self._init_state(params0)
+        with self._spans.setup_span("setup.state_place") as placed:
+            self.state = self._init_state(params0)
+            placed.attrs = {"bytes": sum(
+                int(getattr(leaf, "nbytes", 0))
+                for leaf in jax.tree_util.tree_leaves(self.state))}
         self._needs_master = self.compute_dtype != jnp.float32
 
         # ---- data ----------------------------------------------------------
@@ -1462,6 +1469,15 @@ class DeepSpeedEngine:
                 yield
         finally:
             self._spans.close(root)
+            if self._startup_line_due:
+                self._log_startup()
+
+    def _log_startup(self):
+        """Once, when the first step has returned: where this process's
+        time went before it could train (docs/monitoring.md#start-up)."""
+        from ..monitor import startup
+        self._startup_line_due = False
+        log_dist(f"DeepSpeedEngine {startup.line()}", ranks=[0])
 
     def _train_batch(self, data_iter):
         from .. import fault

@@ -773,6 +773,21 @@ def test_tracing_armed_step_jaxpr_identical(tiny, devices):
                                             trace_sample_rate=1.0))
     assert jaxpr_text(on) == off_jaxpr
     on.close()
+    # ... and to the recorder's one jax.monitoring listener, which every
+    # process has: the step traced without it is the same step
+    from jax._src import monitoring as jax_monitoring
+    from deepspeed_tpu.monitor import spans as monspans
+    jax_monitoring.unregister_event_duration_listener(
+        monspans._on_jax_duration)
+    try:
+        bare = ServingEngine(model=model, params=params,
+                             config=ServingConfig(batch_slots=2,
+                                                  block_size=8))
+        assert jaxpr_text(bare) == off_jaxpr
+        bare.close()
+    finally:
+        jax.monitoring.register_event_duration_secs_listener(
+            monspans._on_jax_duration)
 
 
 # ------------------------------------------------ speculative decoding
