@@ -13,6 +13,19 @@ carrying the online-softmax state (m, l, acc).  The backward pass recomputes
 probabilities from the saved logsumexp (no (T,T) residuals), with one kernel
 for dK/dV (grid over k blocks) and one for dQ (grid over q blocks).
 
+The causal tile walk (dense causal calls with square blocks): a resident
+``(block, block)`` visit is cut into square 256-tiles under a static plan
+(``_tile_plan``).  Tiles above the diagonal are never touched, only the
+tiles the diagonal crosses pay for a mask, the rest run with none.  The
+kernels run a q tile row's contiguous tiles as ONE product (``_strips``),
+a block wholly under the diagonal as one product over the block, and a
+block above it not at all (the grid's test).  Blocks stay as large as
+``_auto_blocks`` makes them, so a block no longer bounds what the causal
+mask skips.  Where a head's keys are one block (T up to the block) the
+forward keeps no running state either.  Every other call (non-causal, LUT,
+banded, merged, biased, ``block_q != block_k``) runs the trivial plan, one
+tile under the mask it always had, through the same body.
+
 Runs compiled on TPU; ``interpret=True`` under other backends so numerics
 tests run on the CPU mesh (SURVEY.md §4: every kernel is tested against a
 pure-jnp reference).
@@ -34,8 +47,16 @@ NEG_INF = -1e30
 
 def _auto_blocks(seq_len, head_dim, block_q, block_k):
     """Measured on v5e: large square blocks amortize the online-softmax
-    scratch revisits — 1024×1024 hits ~30 TF/s at T=4096 vs ~5 TF/s at
-    128×128.  Cap by head_dim to stay inside VMEM (score block is bq×bk
+    scratch revisits and the grid step — 1024×1024 hits ~30 TF/s vs ~5 TF/s
+    at 128×128, both AT T=4096, where a 1024-block's grid test already
+    skips three quarters of the upper triangle.  At T <= the block it
+    skipped nothing (train_z1's T=1024 ran the whole square three times a
+    layer).  Since PR 37 a block no longer bounds what the causal mask
+    skips: the kernels walk the resident block in 256-tiles
+    (``_causal_tile``: chosen on the chip for hd 64 and hd 128 alike, the
+    three kernels 1.468 -> 1.035 ms a layer at (80, 1024, 64) and 0.919 ->
+    0.873 at (16, 2048, 128)), so these sizes are about the grid step and
+    VMEM alone.  Cap by head_dim to stay inside VMEM (score block is bq×bk
     fp32).
 
     NOTE (round-2 lesson): tall-q/narrow-k blocks (bq=T, bk=512) win a
@@ -128,6 +149,139 @@ def _sparse_luts(layout_bytes, shape, causal, block_q, block_k):
     return kmap, klen, qmap, qlen
 
 
+# ============================================================ causal tile walk
+_CAUSAL_TILE = 256       # edge of the square sub-tiles (see _causal_tile)
+
+
+def _causal_tile(causal, block_q, block_k, head_dim, dense=True):
+    """Edge of the square sub-tiles a resident ``(block_q, block_k)`` block
+    is cut in, or ``None`` for the trivial plan: one tile, the whole block,
+    masked as it always was.  Only the dense causal call with square blocks
+    gets a plan of its own (the LUT, banded, merged, biased and non-causal
+    calls keep their numerics to the bit); the edge may depend on
+    ``head_dim`` and the block, on nothing else.
+
+    Measured on v5e (PERF.md §6, PR 37, call A; the three kernels alone,
+    ms a layer, parent -> 128 / 256 / 512): hd 64, T 1024, 80 heads 1.468
+    -> 1.113 / 1.035 / 1.134; hd 128, T 2048, 16 heads 0.919 -> 0.876 /
+    0.873 / 0.896 (a 512-block is its own tile: what is left is the mask
+    the blocks under the diagonal no longer pay); hd 64, T 4096, 16 heads
+    2.788 -> 2.524 / 2.441 / 2.478.  256 wins at every shape."""
+    del head_dim
+    if not (causal and dense and block_q == block_k):
+        return None
+    tile = min(_CAUSAL_TILE, block_q)
+    return None if block_q % tile else tile
+
+
+def _tile_plan(block_q, block_k, tile, diagonal):
+    """The static plan of one block visit: a tuple of rows ``(lo, hi,
+    masked)``.  Row ``r`` is the ``r``-th q sub-tile; it visits the k tiles
+    ``lo .. hi - 1`` with no mask at all and, unless ``masked`` is None,
+    the tile ``masked`` (the next one) under the mask.  Tiles it names
+    nowhere lie above the diagonal and are never touched.  ``diagonal``:
+    the block the diagonal crosses (``qi == kj``); any other visited block
+    lies wholly under it."""
+    if tile is None:
+        return ((0, 0, 0),)
+    assert block_q == block_k and block_q % tile == 0, (block_q, block_k, tile)
+    n = block_q // tile
+    if not diagonal:
+        return ((0, n, None),) * n
+    return tuple((0, r, r) for r in range(n))
+
+
+def _plan_counts(plan):
+    """(tiles visited, tiles masked, tiles in the block's square)."""
+    masked = sum(m is not None for _, _, m in plan)
+    return sum(hi - lo for lo, hi, _ in plan) + masked, masked, len(plan) ** 2
+
+
+def _strips(plan, tile):
+    """How the kernels run a diagonal block's plan: a row's tiles are
+    contiguous, so each row is ONE product, ``tile`` q rows against the
+    row's k columns, with the mask on its last square.  Yields ``(rows,
+    cols)`` slices of the resident block.  (A loop over single tiles, the
+    plan read literally, lost to the whole square: each tile is a chain of
+    product, row maximum, exp, product whose latency nothing hides, and
+    its time went with the tile's edge, not its area; PERF.md §6, PR 37.)"""
+    for r, (lo, hi, masked) in enumerate(plan):
+        assert masked == hi, plan
+        yield pl.ds(r * tile, tile), pl.ds(lo * tile, (hi + 1 - lo) * tile)
+
+
+def _strip_scores(q, k, sm_scale, tile):
+    """Scores of one strip: only the last square meets the diagonal, and
+    there local positions decide (the strip's last column is its last row).
+    No ``k_pos < seq_len`` guard: under a causal mask a padded key meets
+    padded queries only, whose rows are cut off and whose ``do`` is zero."""
+    s = _scores(q, k, sm_scale)
+    last = s[:, -tile:]
+    row = jax.lax.broadcasted_iota(jnp.int32, last.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, last.shape, 1)
+    last = jnp.where(row >= col, last, NEG_INF)
+    if s.shape[1] == tile:
+        return last
+    return jnp.concatenate([s[:, :-tile], last], axis=1)
+
+
+# What the plans of the dense calls traced since the last
+# ``reset_tile_census`` add up to: ``{call: (visited, masked, square)}``
+# over a call's whole grid.  A call traced again (the custom_vjp's primal
+# and its forward rule, a rematerialised forward) has the same key and
+# counts once.  Trace-time bookkeeping: nothing of it reaches the program.
+_tile_census = {}
+
+
+def reset_tile_census():
+    _tile_census.clear()
+
+
+def tile_census():
+    """``{"visited", "masked", "square"}`` summed over the distinct dense
+    flash calls traced since :func:`reset_tile_census`."""
+    sums = [sum(c[i] for c in _tile_census.values()) for i in range(3)]
+    return dict(zip(("visited", "masked", "square"), sums))
+
+
+def _record_tiles(kind, BH, d, nq, nk, block_q, block_k, causal, tile):
+    """Books one dense call: the plans of the blocks its grid's test lets
+    through, over all ``BH`` heads."""
+    counts = [_plan_counts(_tile_plan(block_q, block_k, tile, i == j))
+              for i in range(nq) for j in range(nk)
+              if not causal or j * block_k <= i * block_q + block_q - 1]
+    visited, masked, _ = (sum(c) for c in zip(*counts))
+    _tile_census[(kind, BH, d, nq, nk, block_q, block_k, causal, tile)] = (
+        BH * visited, BH * masked, BH * nq * nk * counts[0][2])
+
+
+def _online_softmax(s, v, m_prev, l_prev, acc_prev):
+    """One online-softmax update with the scores ``s`` (f32) of a tile."""
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                    # fp32
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc_prev * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+def _scores(q, k, sm_scale):
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * sm_scale
+
+
+def _p_and_ds(s, v, do, lse, delta, sm_scale):
+    """The backward's recomputed probabilities and dS = P * (dP - delta),
+    dP = dO V^T, from masked scores."""
+    p = jnp.exp(s - lse)                      # fp32
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * sm_scale
+
+
 # =============================================================== forward kernel
 def _unpack_in_refs(refs, n_main, use_kbias, use_abias):
     """Unpack input refs in call order ``main... [kb] [ab]``; returns
@@ -145,8 +299,11 @@ def _unpack_in_refs(refs, n_main, use_kbias, use_abias):
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
                 seq_len, n_heads=1, use_kbias=False,
                 use_abias=False, use_lut=False, use_merge=False,
-                use_banded=None, num_k_total=None):
+                use_banded=None, num_k_total=None, tile=None):
     """Grid: (BH, nq, nk) with nk innermost (revisits scratch).
+
+    ``tile``: the dense causal call's sub-tile edge (``_causal_tile``);
+    ``None`` is the trivial plan, the whole block under today's mask.
 
     With ``use_lut`` (the block-sparse path; reference
     ``ops/sparse_attention/matmul.py`` SDD/DSD/DDS Triton kernels + their
@@ -172,11 +329,18 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
-    @pl.when(kj == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    # a head whose keys are ONE resident block under the tile walk (T up to
+    # the block: train_z1's grid is (B*H, 1, 1)) needs no running state:
+    # each q strip meets all its keys at once and is written out from
+    # registers.  The (rows, 1) statistics fill one lane in 128, so their
+    # init, rescale and read-back cost the forward a third of its time.
+    stateless = tile is not None and num_k_blocks == 1
+    if not stateless:
+        @pl.when(kj == 0)
+        def _():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
 
     if use_banded is not None:
         # static band+global slots with kernel blocks DECOUPLED from the
@@ -208,14 +372,11 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
         if causal:
             should_compute = ki * block_k <= qi * block_q + (block_q - 1)
 
-    @pl.when(should_compute)
-    def _():
+    def whole_block():
         q = q_ref[0]          # (block_q, d)
         k = k_ref[0]          # (block_k, d)
         v = v_ref[0]          # (block_k, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
+        s = _scores(q, k, sm_scale)           # (bq, bk)
         if use_kbias:
             s = s + kb_ref[0, 0]              # (1, bk) broadcast over rows
         if use_abias:
@@ -255,17 +416,42 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
                             sub1_ref[h_idx, qi, kj])
             valid = jnp.logical_and(valid, sel > 0)
         s = jnp.where(valid, s, NEG_INF)
+        m_ref[:], l_ref[:], acc_ref[:] = _online_softmax(
+            s, v, m_ref[:], l_ref[:], acc_ref[:])
 
-        m_prev = m_ref[:]                     # (bq, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                # (bq, bk) fp32
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+    def under_diagonal():
+        # every tile unmasked, none skipped: the rows fuse, one product
+        m_ref[:], l_ref[:], acc_ref[:] = _online_softmax(
+            _scores(q_ref[0], k_ref[0], sm_scale), v_ref[0],
+            m_ref[:], l_ref[:], acc_ref[:])
+
+    def diagonal():
+        plan = _tile_plan(block_q, block_k, tile, True)
+        for rows, cols in _strips(plan, tile):
+            s = _strip_scores(q_ref[0, rows], k_ref[0, cols], sm_scale, tile)
+            v = v_ref[0, cols]
+            if not stateless:
+                m_ref[rows], l_ref[rows], acc_ref[rows] = _online_softmax(
+                    s, v, m_ref[rows], l_ref[rows], acc_ref[rows])
+                continue
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)   # > 0: a row sees itself
+            acc = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            o_ref[0, rows] = (acc / l).astype(o_ref.dtype)
+            lse_ref[0, rows] = jnp.broadcast_to(m + jnp.log(l),
+                                                (tile, MIN_LANES))
+
+    if tile is None:
+        pl.when(should_compute)(whole_block)
+    else:
+        if num_k_blocks > 1:
+            pl.when(kj < qi)(under_diagonal)
+        pl.when(kj == qi)(diagonal)
+    if stateless:
+        return
 
     @pl.when(kj == num_k_blocks - 1)
     def _():
@@ -569,6 +755,11 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k,
         attn_bias = _tile_abias(attn_bias, T, Tp, block_q, block_k)
         in_specs.append(pl.BlockSpec((1, 1, block_q, block_k), ab_idx))
         args = args + (attn_bias,)
+    dense = not use_lut and banded is None
+    tile = _causal_tile(causal, block_q, block_k, d,
+                        dense and k_bias is None and attn_bias is None)
+    if dense:
+        _record_tiles("fwd", BH, d, nq, nk, block_q, block_k, causal, tile)
     if use_dma:
         kernel = functools.partial(
             _fwd_kernel_dma, sm_scale=sm_scale, causal=causal,
@@ -581,7 +772,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k,
             seq_len=T, n_heads=H, use_kbias=k_bias is not None,
             use_abias=attn_bias is not None,
             use_lut=use_lut and not use_merge, use_merge=use_merge,
-            use_banded=banded, num_k_total=nk)
+            use_banded=banded, num_k_total=nk, tile=tile)
     out_specs = [
         pl.BlockSpec((1, block_q, d), q_idx),
         pl.BlockSpec((1, block_q, MIN_LANES), q_idx),
@@ -617,10 +808,11 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k,
 # ============================================================== backward kernels
 def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
                      seq_len, n_heads=1, use_kbias=False,
-                     use_abias=False, use_lut=False):
+                     use_abias=False, use_lut=False, tile=None):
     """Grid: (BH, nk, nq) with nq innermost; accumulates dK/dV for one k block.
     ``use_lut``: inner dim is the live q-block count; scalar-prefetch
-    ``(qmap, qlen)`` lead the args and pick the visited q block."""
+    ``(qmap, qlen)`` lead the args and pick the visited q block.
+    ``tile``: as in :func:`_fwd_kernel`."""
     if use_lut:
         qmap_ref, qlen_ref = refs[:2]
         refs = refs[2:]
@@ -646,17 +838,8 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
         if causal:
             should_compute = qi * block_q + (block_q - 1) >= ki * block_k
 
-    @pl.when(should_compute)
-    def _():
-        q = q_ref[0]            # (bq, d)
-        k = k_ref[0]            # (bk, d)
-        v = v_ref[0]
-        do = do_ref[0]          # (bq, d)
-        lse = lse_ref[0][:, :1]          # (bq, 1) — lane-broadcast stat
-        delta = delta_ref[0][:, :1]      # (bq, 1)
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
+    def masked_scores(q, k):
+        s = _scores(q, k, sm_scale)
         if use_kbias:
             s = s + kb_ref[0, 0]
         if use_abias:
@@ -668,20 +851,43 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
         valid = jnp.logical_and(q_pos < seq_len, k_pos < seq_len)
         if causal:
             valid = jnp.logical_and(valid, q_pos >= k_pos)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse)                      # (bq, bk) fp32
-        # dV += P^T dO
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dP = dO V^T ; dS = P * (dP - delta)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        # dK += dS^T Q
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        return jnp.where(valid, s, NEG_INF)
+
+    def accumulate(q, k, v, do, lse, delta, dk, dv, scores):
+        p, ds = _p_and_ds(scores(q, k), v, do, lse, delta, sm_scale)
+        # dK += dS^T Q ; dV += P^T dO
+        contract_rows = (((0,), (0,)), ((), ()))
+        return (dk + jax.lax.dot_general(
+                    ds.astype(q.dtype), q, contract_rows,
+                    preferred_element_type=jnp.float32),
+                dv + jax.lax.dot_general(
+                    p.astype(do.dtype), do, contract_rows,
+                    preferred_element_type=jnp.float32))
+
+    def whole_block(scores):
+        def visit():
+            dk_acc[:], dv_acc[:] = accumulate(
+                q_ref[0], k_ref[0], v_ref[0], do_ref[0],
+                lse_ref[0][:, :1],           # (bq, 1) — lane-broadcast stat
+                delta_ref[0][:, :1], dk_acc[:], dv_acc[:], scores)
+        return visit
+
+    def diagonal():
+        plan = _tile_plan(block_q, block_k, tile, True)
+        for rows, cols in _strips(plan, tile):
+            dk_acc[cols], dv_acc[cols] = accumulate(
+                q_ref[0, rows], k_ref[0, cols], v_ref[0, cols],
+                do_ref[0, rows], lse_ref[0, rows][:, :1],
+                delta_ref[0, rows][:, :1], dk_acc[cols], dv_acc[cols],
+                lambda q, k: _strip_scores(q, k, sm_scale, tile))
+
+    if tile is None:
+        pl.when(should_compute)(whole_block(masked_scores))
+    else:
+        if num_q_blocks > 1:      # under the diagonal: no tile masked
+            pl.when(qj > ki)(whole_block(
+                lambda q, k: _scores(q, k, sm_scale)))
+        pl.when(qj == ki)(diagonal)
 
     @pl.when(qj == num_q_blocks - 1)
     def _():
@@ -691,9 +897,10 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
 
 def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
                    seq_len, n_heads=1, use_kbias=False,
-                   use_abias=False, use_lut=False):
+                   use_abias=False, use_lut=False, tile=None):
     """Grid: (BH, nq, nk) with nk innermost; accumulates dQ for one q block.
-    ``use_lut``: inner dim is the live k-block count (same LUT as forward)."""
+    ``use_lut``: inner dim is the live k-block count (same LUT as forward).
+    ``tile``: as in :func:`_fwd_kernel`."""
     if use_lut:
         kmap_ref, klen_ref = refs[:2]
         refs = refs[2:]
@@ -718,17 +925,8 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
         if causal:
             should_compute = ki * block_k <= qi * block_q + (block_q - 1)
 
-    @pl.when(should_compute)
-    def _():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
+    def masked_scores(q, k):
+        s = _scores(q, k, sm_scale)
         if use_kbias:
             s = s + kb_ref[0, 0]
         if use_abias:
@@ -740,14 +938,37 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
         valid = jnp.logical_and(q_pos < seq_len, k_pos < seq_len)
         if causal:
             valid = jnp.logical_and(valid, q_pos >= k_pos)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_acc[:] += jax.lax.dot_general(
+        return jnp.where(valid, s, NEG_INF)
+
+    def accumulate(q, k, v, do, lse, delta, dq, scores):
+        _, ds = _p_and_ds(scores(q, k), v, do, lse, delta, sm_scale)
+        return dq + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    def whole_block(scores):
+        def visit():
+            dq_acc[:] = accumulate(
+                q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0][:, :1],
+                delta_ref[0][:, :1], dq_acc[:], scores)
+        return visit
+
+    def diagonal():
+        plan = _tile_plan(block_q, block_k, tile, True)
+        for rows, cols in _strips(plan, tile):
+            dq_acc[rows] = accumulate(
+                q_ref[0, rows], k_ref[0, cols], v_ref[0, cols],
+                do_ref[0, rows], lse_ref[0, rows][:, :1],
+                delta_ref[0, rows][:, :1], dq_acc[rows],
+                lambda q, k: _strip_scores(q, k, sm_scale, tile))
+
+    if tile is None:
+        pl.when(should_compute)(whole_block(masked_scores))
+    else:
+        if num_k_blocks > 1:      # under the diagonal: no tile masked
+            pl.when(kj < qi)(whole_block(
+                lambda q, k: _scores(q, k, sm_scale)))
+        pl.when(kj == qi)(diagonal)
 
     @pl.when(kj == num_k_blocks - 1)
     def _():
@@ -789,6 +1010,12 @@ def _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
     lse, delta = bcast(lse), bcast(delta)
 
     H = n_heads or 1
+    tile = _causal_tile(
+        causal, block_q, block_k, d,
+        not use_lut and k_bias is None and attn_bias is None)
+    if not use_lut:
+        for kind in ("dkdv", "dq"):
+            _record_tiles(kind, BH, d, nq, nk, block_q, block_k, causal, tile)
     if use_lut:
         # dK/dV grid: (BH, nk, live-q); the visited q block is qmap[h, j, i]
         qrow_idx = lambda b, j, i, qm, ql: (b, qm[jax.lax.rem(b, H), j, i], 0)
@@ -827,7 +1054,7 @@ def _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
         _bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_q_blocks=n_inner_q,
         seq_len=T, n_heads=H, use_kbias=k_bias is not None,
-        use_abias=attn_bias is not None, use_lut=use_lut)
+        use_abias=attn_bias is not None, use_lut=use_lut, tile=tile)
     dkdv_out_specs = [
         pl.BlockSpec((1, block_k, d), kcol_idx),
         pl.BlockSpec((1, block_k, d), kcol_idx),
@@ -880,7 +1107,7 @@ def _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
         _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_k_blocks=n_inner_k,
         seq_len=T, n_heads=H, use_kbias=k_bias is not None,
-        use_abias=attn_bias is not None, use_lut=use_lut)
+        use_abias=attn_bias is not None, use_lut=use_lut, tile=tile)
     dq_out_spec = pl.BlockSpec((1, block_q, d), q_ij)
     dq_out_shape = jax.ShapeDtypeStruct((BH, Tp, d), q.dtype)
     dq_scratch = [pltpu.VMEM((block_q, d), jnp.float32)]

@@ -119,6 +119,14 @@ def _resolve_model(model, loss_fn, params, apply_fn, rng_seed,
     return loss_fn, params, apply_fn, tp_specs
 
 
+def _reset_flash_tile_census():
+    """A callee, so that ``_grads_and_metrics``, which every layer's trace
+    runs under, keeps its frame's size (ROADMAP D13: how long ``jit.lower``
+    takes depends on where the frames below it lie)."""
+    from ..ops.transformer.flash_attention import reset_tile_census
+    reset_tile_census()
+
+
 class DeepSpeedEngine:
     """Config-driven training engine over a jitted SPMD step."""
 
@@ -748,31 +756,39 @@ class DeepSpeedEngine:
         weights; and ``custom_calls``, the Pallas kernels one call runs
         by the scope they sit in (a flash block under a remat policy that
         saves ``attn_out`` reads 3 x layers under ``attention``, 4 x
-        under any other)."""
+        under any other).  Beside them ``flash_tiles``, read off the trace
+        and not off the HLO: the tile plans of the step's distinct dense
+        flash calls (a scanned layer's three count once), as tiles visited
+        / masked / in the square; visited < square where the causal tile
+        walk engages."""
         from ..analysis.comms import step_collectives
         from ..analysis.jaxpr_audit import census_from_hlo_text, \
             custom_calls_from_hlo_text
+        from ..ops.transformer.flash_attention import tile_census
         state = args[0]
         shapes = {np.shape(leaf) for leaf in jax.tree_util.tree_leaves(
             state.master if state.master is not None else state.params)}
         text = exe.as_text()
         return {**step_collectives(census_from_hlo_text(text), shapes),
-                "custom_calls": custom_calls_from_hlo_text(text)}
+                "custom_calls": custom_calls_from_hlo_text(text),
+                "flash_tiles": tile_census()}
 
     def compile_report(self):
         """Compile-cache status + per-entry hit/miss/compile-ms events
         for this engine's cache (surfaced by ds_report), and of each
-        acquired step executable what it moves (``collectives``) and the
-        kernels it runs (``custom_calls``)."""
+        acquired step executable what it moves (``collectives``), the
+        kernels it runs (``custom_calls``) and the tiles its flash calls
+        visit (``flash_tiles``)."""
         from . import compile_cache as ccache
         report = ccache.report(self.compile_cache)
         steps = [w for w in (self._jit_train_step, self._jit_grad_step)
                  if w.described]
+        beside = ("custom_calls", "flash_tiles")
         report["collectives"] = {
             w.name: {k: v for k, v in w.described.items()
-                     if k != "custom_calls"} for w in steps}
-        report["custom_calls"] = {
-            w.name: w.described["custom_calls"] for w in steps}
+                     if k not in beside} for w in steps}
+        for key in beside:
+            report[key] = {w.name: w.described[key] for w in steps}
         return report
 
     def _install_moe_wire(self):
@@ -1108,6 +1124,9 @@ class DeepSpeedEngine:
         through the router's error-fed int8 wire.  Returns
         ``(grads, overflow, lr, metrics, new_comm_error)`` — the last is
         None on the full-width path."""
+        # a step's trace starts here: what _describe_step reads as
+        # ``flash_tiles`` is this step's calls and nothing traced before
+        _reset_flash_tile_census()
         cur_scale = (state.scale.cur_scale if state.scale is not None
                      else jnp.float32(1.0))
         out = self._grad_fn(base, batch, rng, cur_scale)

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer.flash_attention import (
-    flash_attention, attention_reference)
+    flash_attention, flash_attention_with_lse, attention_reference)
 
 
 def make_qkv(B=2, T=128, H=2, d=32, dtype=jnp.float32, seed=0):
@@ -78,7 +78,14 @@ def test_single_block():
 def test_auto_blocks_heuristic():
     """v5e-measured policy: large SQUARE blocks (end-to-end MFU beats the
     tall-q microbench winner — see _auto_blocks NOTE); halved caps for
-    wide heads (VMEM)."""
+    wide heads (VMEM).  The 1024-block figure was measured at T = 4096.
+    Since PR 37 a block no longer bounds what the causal mask skips: the
+    kernels walk a resident block in 256-tiles (``test_tile_plan``), so
+    these values amortise the grid step and nothing else.  256 was chosen
+    on the chip for both head sizes (the three kernels alone, ms a layer,
+    parent -> 128 / 256 / 512-tiles): hd 64, T 1024, 80 heads 1.468 ->
+    1.113 / 1.035 / 1.134; hd 128, T 2048, 16 heads 0.919 -> 0.876 / 0.873
+    / 0.896 (PERF.md §6, PR 37)."""
     from deepspeed_tpu.ops.transformer.flash_attention import _auto_blocks
     assert _auto_blocks(512, 64, None, None) == (512, 512)
     assert _auto_blocks(1024, 64, None, None) == (1024, 1024)
@@ -103,6 +110,207 @@ def test_dma_slot_walk_unroll_bounded():
     # the bounded unroll must divide into the ring without aliasing a
     # live slot: ring depth itself is the safe group size
     assert _N_KV_BUF >= 2
+
+
+# ------------------------------------------------------ the causal tile walk
+def _plan_tiles(plan):
+    """``(q_tile, k_tile, masked)`` of every tile a plan visits."""
+    for r, (lo, hi, masked) in enumerate(plan):
+        for i in range(lo, hi):
+            yield r, i, False
+        if masked is not None:
+            yield r, masked, True
+
+
+@pytest.mark.parametrize("block,tile,diagonal,counts", [
+    (1024, 256, True, (10, 4, 16)),
+    (1024, 256, False, (16, 0, 16)),
+    (1024, 128, True, (36, 8, 64)),
+    (1024, 512, True, (3, 2, 4)),
+    (512, 256, True, (3, 2, 4)),
+    (512, 256, False, (4, 0, 4)),
+    (256, 256, True, (1, 1, 1)),
+    (256, 256, False, (1, 0, 1)),
+    (384, None, True, (1, 1, 1)),         # the trivial plan
+])
+def test_tile_plan(block, tile, diagonal, counts):
+    """The plan as a pure function: every (q, k) position at or under the
+    diagonal lies in exactly one visited tile, none above it in an unmasked
+    one, and a masked tile is one the diagonal crosses.  On a diagonal
+    block each row's tiles are contiguous and end in the masked one, which
+    is what lets the kernels run a row as one product (``_strips``)."""
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        _tile_plan, _plan_counts, _strips)
+    plan = _tile_plan(block, block, tile, diagonal)
+    assert _plan_counts(plan) == counts
+    edge = tile or block
+    n = block // edge
+    seen = np.zeros((n, n), int)
+    for a, b, masked in _plan_tiles(plan):
+        seen[a, b] += 1
+        if diagonal:
+            assert b <= a                     # nothing above the diagonal
+            assert masked == (a == b)         # the mask where it crosses
+        else:
+            assert not masked or tile is None
+    live = np.tril(np.ones((n, n), int)) if diagonal else np.ones((n, n), int)
+    np.testing.assert_array_equal(seen, live)
+    if diagonal and tile:
+        for r, (rows, cols) in enumerate(_strips(plan, tile)):
+            assert (rows.start, rows.size) == (r * tile, tile)
+            assert (cols.start, cols.size) == (0, (r + 1) * tile)
+
+
+@pytest.mark.parametrize("args,tile", [
+    ((True, 1024, 1024, 64), 256),
+    ((True, 512, 512, 128), 256),
+    ((True, 96, 96, 64), 96),             # T below one sub-tile
+    ((True, 384, 384, 64), None),         # not a multiple: the whole block
+    ((True, 512, 256, 64), None),         # block_q != block_k
+    ((False, 1024, 1024, 64), None),      # non-causal
+    ((True, 1024, 1024, 64, False), None),    # LUT / banded / biased
+])
+def test_causal_tile_choice(args, tile):
+    from deepspeed_tpu.ops.transformer.flash_attention import _causal_tile
+    assert _causal_tile(*args) == tile
+
+
+def _weighted(fn, w):
+    return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+
+@pytest.mark.parametrize("d,T,blocks,dtype,causal", [
+    (64, 256, None, jnp.float32, True),       # one tile: the block itself
+    (64, 1024, None, jnp.float32, True),      # train_z1: one 1024-block
+    (64, 1024, None, jnp.bfloat16, True),
+    (64, 1536, (1024, 1024), jnp.float32, True),   # Tp > T, a block under
+    (128, 1024, None, jnp.float32, True),
+    (128, 2048, (512, 512), jnp.float32, True),    # train_z3_x4
+    (128, 2048, (512, 512), jnp.bfloat16, True),
+    (64, 1024, (512, 256), jnp.float32, True),     # block_q != block_k
+    (64, 1024, (256, 512), jnp.float32, True),
+    (64, 96, None, jnp.float32, True),        # T below one sub-tile
+    (64, 512, None, jnp.float32, False),
+], ids=lambda v: getattr(v, "__name__", None) or str(v).replace(" ", ""))
+def test_walk_forward_and_backward_match_reference(d, T, blocks, dtype,
+                                                   causal):
+    """Forward and gradients against the dense oracle at the shapes the
+    tile walk engages at (and at those it must leave alone)."""
+    bq, bk = blocks or (None, None)
+    q, k, v = make_qkv(B=1, T=T, H=2, d=d, dtype=dtype, seed=T + d)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                            block_q=bq, block_k=bk)
+    f32 = lambda t: t.astype(jnp.float32)
+    ref = lambda q, k, v: attention_reference(f32(q), f32(k), f32(v),
+                                              causal=causal)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(f32(flash(q, k, v))),
+                               np.asarray(ref(q, k, v)), atol=tol, rtol=tol)
+    gf = jax.grad(_weighted(flash, w), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(_weighted(ref, w), argnums=(0, 1, 2))(q, k, v)
+    tol = 1e-4 if dtype == jnp.float32 else 6e-2
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(f32(b)),
+                                   atol=tol, rtol=tol,
+                                   err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("T,blocks", [(1024, None), (1536, (1024, 1024)),
+                                      (1024, (512, 256))],
+                         ids=["one_block", "padded_two_blocks", "bq_ne_bk"])
+def test_walk_with_lse_and_a_nonzero_dlse(T, blocks):
+    """``flash_attention_with_lse`` shares the kernels: both outputs and
+    the gradient of a loss that reads BOTH (so ``dlse`` is not zero)."""
+    bq, bk = blocks or (None, None)
+    q, k, v = make_qkv(B=1, T=T, H=2, d=64, seed=T)
+    w = jax.random.normal(jax.random.PRNGKey(3), q.shape, jnp.float32)
+
+    def ref_pair(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+        return (attention_reference(q, k, v),
+                jax.scipy.special.logsumexp(s, axis=-1))
+
+    def loss(pair):
+        def f(q, k, v):
+            out, lse = pair(q, k, v)
+            return jnp.sum(out * w) + jnp.sum(jnp.sin(lse))
+        return f
+    flash = lambda q, k, v: flash_attention_with_lse(q, k, v, block_q=bq,
+                                                     block_k=bk)
+    for a, b in zip(flash(q, k, v), ref_pair(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=2e-5)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref_pair), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4, err_msg=f"d{name} mismatch")
+
+
+def _trivial_plan_case(name):
+    """Output and q/k/v gradients of one call that runs the trivial plan
+    (one tile, the whole block, masked as before PR 37)."""
+    from deepspeed_tpu.ops.sparse_attention.sparsity_config import (
+        BSLongformerSparsityConfig, BigBirdSparsityConfig)
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        sparse_flash_attention)
+    B, T, H, d = 1, 128, 2, 16
+    q, k, v = make_qkv(B=B, T=T, H=H, d=d, seed=11)
+    band = jnp.asarray(BSLongformerSparsityConfig(
+        num_heads=H, block=16, num_sliding_window_blocks=3,
+        global_block_indices=[0]).make_layout(T), jnp.int32)
+    bird = jnp.asarray(BigBirdSparsityConfig(
+        num_heads=H, block=16, num_random_blocks=1,
+        num_sliding_window_blocks=3, num_global_blocks=1,
+        attention="unidirectional").make_layout(T), jnp.int32)
+    kp = jnp.where(jnp.arange(T)[None, :] < 100, 0.0, -1e9) * \
+        jnp.ones((B, 1), jnp.float32)
+    ab = jnp.where(jnp.arange(T)[:, None] - jnp.arange(T)[None, :] < 40,
+                   0.0, -1e9).astype(jnp.float32)
+    fn = {
+        "non_causal": lambda q, k, v: flash_attention(
+            q, k, v, causal=False, block_q=64, block_k=64),
+        "lut": lambda q, k, v: sparse_flash_attention(q, k, v, bird),
+        "banded": lambda q, k, v: sparse_flash_attention(q, k, v, band),
+        "merged": lambda q, k, v: sparse_flash_attention(
+            q, k, v, band, block_q_merge=2),
+        "biased": lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=64, block_k=64,
+            key_padding_bias=kp, attn_bias=ab),
+    }[name]
+    grads = jax.grad(lambda q, k, v: jnp.sum(jnp.square(fn(q, k, v))),
+                     argnums=(0, 1, 2))(q, k, v)
+    return (fn(q, k, v),) + grads
+
+
+def _digest(arrays):
+    import hashlib
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.ascontiguousarray(np.asarray(a, np.float32)).tobytes())
+    return h.hexdigest()[:16]
+
+
+# taken from the parent tree (commit 2e4dd84, interpreted on the CPU):
+# `python -c "import test_flash_attention as t; ..."` under its package
+PARENT_DIGESTS = {
+    "non_causal": "722b0d20c3e93a04",
+    "lut": "8948410434ff1185",
+    "banded": "79f93339e7437f50",
+    "merged": "7f202d850bcd2674",
+    "biased": "1715fd65178ce166",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_DIGESTS))
+def test_trivial_plan_equals_the_parent_to_the_bit(name):
+    """What gets no tile walk keeps its numerics: output and gradients of
+    one call each equal, bit for bit, what the tree before PR 37 gave."""
+    arrays = _trivial_plan_case(name)
+    assert all(np.isfinite(np.asarray(a)).all() for a in arrays)
+    assert _digest(arrays) == PARENT_DIGESTS[name]
 
 
 # ------------------------------------------------- residuals under remat
@@ -244,6 +452,46 @@ def v5e():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+@pytest.mark.parametrize("kernels,calls", [("forward", 1), ("backward", 2)])
+@pytest.mark.parametrize("BH,T,d", [(80, 1024, 64), (16, 2048, 128)],
+                         ids=["train_z1", "train_z3_x4"])
+def test_causal_kernels_compile_for_a_v5e(v5e, monkeypatch, BH, T, d,
+                                          kernels, calls):
+    """The three kernels at both training cells' shapes (bf16, the blocks
+    ``_auto_blocks`` gives), through Mosaic and XLA:TPU for a described
+    v5e: one custom call each.  The tile walk's static slices, its lane
+    concatenation under the mask and the stateless forward's writes of
+    ``out`` and ``lse`` a strip at a time are what the interpreter cannot
+    refuse and Mosaic can."""
+    import importlib
+    from jax.sharding import SingleDeviceSharding
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    x = jax.ShapeDtypeStruct((BH, T, d), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((BH, T), jnp.float32, sharding=one_chip)
+    scale = 1.0 / np.sqrt(d)
+    if kernels == "forward":
+        fn = lambda q, k, v: fa._fwd(q, k, v, scale, True, None, None)
+        args = (x, x, x)
+    else:
+        fn = lambda q, k, v, out, lse, do: fa._bwd(
+            scale, True, None, None, (q, k, v, out, lse), do)
+        args = (x, x, x, x, lse, x)
+    fa.reset_tile_census()
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert text.count("tpu_custom_call") == calls
+    # 256-tiles: T 1024 is one block of 10 tiles in 16; T 2048 is four
+    # 512-blocks a side, the diagonal ones 3 tiles in 4: 36 in 64
+    visited, square = {1024: (10, 16), 2048: (36, 64)}[T]
+    assert fa.tile_census() == {
+        "visited": calls * BH * visited,
+        "masked": calls * BH * T // 256,
+        "square": calls * BH * square}
+
+
 N_LAYER = 3
 
 
@@ -254,7 +502,8 @@ N_LAYER = 3
 ], ids=["z1_saved", "z1_not_saved", "z3_x4_saved"])
 def test_compile_report_counts_the_flash_calls_of_a_step(
         v5e, monkeypatch, tmp_path, policy, axes, stage, per_layer):
-    """``compile_report()["custom_calls"]`` of a tiny GPT-2 engine whose
+    """``compile_report()["custom_calls"]`` and ``["flash_tiles"]`` of a
+    tiny GPT-2 engine whose
     train step is acquired, through the engine's own wrapper, for the
     described TPU (the CPU interprets kernels and its executable holds no
     custom call): the Mosaic calls the compiler KEPT, times the layer
@@ -270,8 +519,9 @@ def test_compile_report_counts_the_flash_calls_of_a_step(
         "deepspeed_tpu.ops.transformer.flash_attention")
     n_dev = int(np.prod(list(axes.values())))
     mesh = M.make_mesh(axes, devices=jax.devices()[:n_dev])
+    # T 512: the shortest a 64-wide head's block is cut in tiles at
     model = GPT2(config=GPT2Config(
-        vocab_size=256, max_seq=256, n_embd=128, n_layer=N_LAYER, n_head=2,
+        vocab_size=256, max_seq=512, n_embd=128, n_layer=N_LAYER, n_head=2,
         embd_pdrop=0.0, attn_pdrop=0.0, resid_pdrop=0.0, remat=True,
         remat_policy=policy, attention_impl="auto", loss_chunk=128),
         dtype=jnp.bfloat16)
@@ -293,7 +543,7 @@ def test_compile_report_counts_the_flash_calls_of_a_step(
                                     sharding=NamedSharding(tpu_mesh, spec))
     state = jax.tree_util.tree_map(on_tpu, engine.state)
     batch = jax.ShapeDtypeStruct(
-        (1, 2 * M.dp_world_size(mesh), 257), jnp.int32,
+        (1, 2 * M.dp_world_size(mesh), 513), jnp.int32,
         sharding=NamedSharding(tpu_mesh, P(None, M.BATCH_AXES)))
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
                                sharding=NamedSharding(tpu_mesh, P()))
@@ -306,8 +556,12 @@ def test_compile_report_counts_the_flash_calls_of_a_step(
     report = engine.compile_report()
     assert report["custom_calls"] == {
         "DeepSpeedEngine.train_step": {"attention": per_layer * N_LAYER}}
-    assert "custom_calls" not in \
-        report["collectives"]["DeepSpeedEngine.train_step"]
+    # forward, dK/dV and dQ of the scanned layer, each counted once: 2
+    # sequences x 2 heads a device, a 512-block of 3 tiles in 4, 2 masked
+    assert report["flash_tiles"] == {"DeepSpeedEngine.train_step": {
+        "visited": 3 * 4 * 3, "masked": 3 * 4 * 2, "square": 3 * 4 * 4}}
+    assert not {"custom_calls", "flash_tiles"} & set(
+        report["collectives"]["DeepSpeedEngine.train_step"])
     built = [row for row in engine._spans.rows("compile.build")
              if row.attrs.get("fn") == "DeepSpeedEngine.train_step"]
     assert built[-1].attrs["custom_calls"] == {
