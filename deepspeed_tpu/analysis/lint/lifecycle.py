@@ -132,6 +132,9 @@ PROTECTED_ATTRS = {
     # device-resident copy of the slot state stale
     "_tables": ("__init__", "_set_slot"),
     "blocks": ("__init__",),             # per-sequence block list (_Slot)
+    # and its list of the second kind (a window layer's ring; the ring's
+    # entries of `_tables` are written by `_set_slot` like the rest)
+    "wblocks": ("__init__",),
 }
 
 _MUTATING_METHODS = ("append", "extend", "insert", "pop", "popleft",

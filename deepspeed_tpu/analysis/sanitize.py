@@ -102,7 +102,11 @@ class ShadowSanitizer:
     """
 
     def __init__(self, num_blocks: int, *, scratch_block: int = 0,
-                 halt: bool = True):
+                 halt: bool = True, kind: str = ""):
+        # a model with window layers has a second allocator, whose ids are
+        # its own (docs/serving.md#window-layers): one shadow table each,
+        # ``kind`` ("window") named in that table's findings
+        self.kind = kind
         self.num_blocks = int(num_blocks)
         self.scratch_block = int(scratch_block)
         self.halt = bool(halt)
@@ -116,6 +120,9 @@ class ShadowSanitizer:
 
     # ------------------------------------------------------------ emit
     def _emit(self, code, message, **extra):
+        if self.kind:
+            message = f"[{self.kind} blocks] {message}"
+            extra["kind"] = self.kind
         f = Finding(code, "error", message,
                     eqn_path=f"sanitize/{code}", extra=extra)
         self.findings.append(f)

@@ -476,19 +476,23 @@ def _merge_heads(x):
     return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
 
 
-def _window_slots(block_tables, lengths, W, bs):
+def _window_slots(block_tables, lengths, W, bs, ring=False):
     """``(block ids, offsets)``, each (B, W): where window token i of slot b
     (position ``lengths[b] + i``) lands.  A position past the table goes to
-    the scratch block (:func:`write_tokens` says why)."""
+    the scratch block (:func:`write_tokens` says why); in a ``ring`` table
+    (:func:`ring_blocks`) no position is past it."""
     nb_max = block_tables.shape[1]
     pos = lengths[:, None] + jnp.arange(W, dtype=lengths.dtype)[None, :]
     idx = pos // bs                                        # (B, W)
+    if ring:
+        return jnp.take_along_axis(block_tables, idx % nb_max, axis=1), \
+            pos % bs
     blk = jnp.take_along_axis(block_tables,
                               jnp.minimum(idx, nb_max - 1), axis=1)
     return jnp.where(idx < nb_max, blk, SCRATCH_BLOCK), pos % bs
 
 
-def write_tokens(pool, layer, block_tables, lengths, k, v):
+def write_tokens(pool, layer, block_tables, lengths, k, v, ring=False):
     """Scatter a W-token decode window's K/V per slot into the pool.
 
     ``layer``: scalar (traced inside the layer scan); ``block_tables``:
@@ -501,9 +505,11 @@ def write_tokens(pool, layer, block_tables, lengths, k, v):
     slot's allocation) is REDIRECTED to the scratch block instead of
     letting the gather clamp silently overwrite the table's last real
     block — any token whose logits depend on such a position is beyond
-    ``max_new`` and truncated by the scheduler anyway."""
+    ``max_new`` and truncated by the scheduler anyway.  ``ring``: the
+    tables are rings (:func:`ring_blocks`), position ``p`` in entry
+    ``(p // block_size) % nb_max``."""
     blk, off = _window_slots(block_tables, lengths, k.shape[1],
-                             pool["k"].shape[2])
+                             pool["k"].shape[2], ring=ring)
     k, v = _merge_heads(k), _merge_heads(v)
     if not is_quantized_pool(pool):
         dt = pool["k"].dtype
@@ -874,3 +880,77 @@ def gather_latent(pool, layer, block_tables, dtype):
     x = pool[LATENT][layer][block_tables]          # (B, nb, bs, row)
     B, nb, bs, row = x.shape
     return x.reshape(B, nb * bs, row).astype(dtype)
+
+
+# ------------------------------------------------------------- window pool
+# A model with SLIDING-WINDOW layers beside global ones (``models/afmoe.py``)
+# keeps two kinds of block in one serving state: the ``k`` / ``v`` leaves over
+# its global layers, whose tables grow with the stream, and the ``wk`` / ``wv``
+# leaves over its window layers, whose tables are RINGS: a window layer reads
+# the last ``window`` positions and no more, so a stream holds at most
+# :func:`ring_blocks` of them and position ``p`` lives in entry ``(p //
+# block_size) % ring`` of its table, a block reused when the window has slid
+# past what it held.  A stream shorter than the ring holds only the blocks
+# its own tokens fill.  Each kind has its own :class:`BlockAllocator`, its
+# own scratch block 0, and its own count of blocks.
+WINDOW = ("wk", "wv")
+
+
+def ring_blocks(window: int, block_size: int) -> int:
+    """Entries of a window layer's ring: the blocks that ``window``
+    consecutive positions can touch (the window behind the token being
+    written, and that token's own block)."""
+    return -(-(int(window) - 1) // int(block_size)) + 1
+
+
+def init_window_pool(n_layer, num_blocks, block_size, n_kv_head, head_dim,
+                     dtype=jnp.bfloat16):
+    """Zeroed ``wk`` / ``wv`` leaves (16-bit; the layout of ``k`` / ``v``)."""
+    shape = (n_layer, num_blocks, block_size, n_kv_head * head_dim)
+    return {name: jnp.zeros(shape, dtype) for name in WINDOW}
+
+
+def window_view(pool):
+    """The window leaves under the names every ``k`` / ``v`` function and
+    the paged kernel take; :func:`with_window` puts a written view back."""
+    return {"k": pool[WINDOW[0]], "v": pool[WINDOW[1]]}
+
+
+def with_window(pool, view):
+    return dict(pool, **{WINDOW[0]: view["k"], WINDOW[1]: view["v"]})
+
+
+def write_prefill_ring(view, ring_table, k, v, layer, t_real):
+    """Seat a prompt's LAST blocks in a window layer's ring: ``k`` / ``v``
+    (T, H, hd) with ``T`` a block multiple, ``ring_table`` (ring,) the
+    stream's ring (scratch where it holds no block), ``t_real`` the prompt's
+    true length.  The ``min(T // block_size, ring)`` blocks that end at the
+    one holding token ``t_real - 1`` are written, each at its ring entry:
+    everything position ``t_real`` and later can still see."""
+    bs = view["k"].shape[2]
+    ring = ring_table.shape[0]
+    nb = k.shape[0] // bs
+    n = min(nb, ring)
+    first = jnp.clip((t_real - 1) // bs - (n - 1), 0, nb - n)   # first block
+    entries = ring_table[(first + jnp.arange(n)) % ring]
+
+    def put(x, rows):
+        rows = jax.lax.dynamic_slice_in_dim(_merge_heads(rows), first * bs,
+                                            n * bs, axis=0)
+        return x.at[layer, entries].set(
+            rows.reshape(n, bs, rows.shape[-1]).astype(x.dtype))
+    return {"k": put(view["k"], k), "v": put(view["v"], v)}
+
+
+def gather_ring(view, layer, ring_tables, lengths, dtype, n_head):
+    """:func:`gather_kv` over ring tables, and where each gathered row
+    stands: ``(keys, vals (B, ring * block_size, H, hd), positions (B, ring *
+    block_size))``, the position negative where the ring holds nothing yet.
+    ``lengths``: the position of the token just written."""
+    from ..ops.transformer.paged_attention import ring_positions
+    keys, vals = gather_kv(view, layer, ring_tables, dtype, n_head)
+    ring, bs = ring_tables.shape[1], view["k"].shape[2]
+    cell = jnp.arange(ring * bs)
+    pos = ring_positions((cell // bs)[None, :], (cell % bs)[None, :],
+                         lengths[:, None], ring, bs)
+    return keys, vals, pos

@@ -381,6 +381,10 @@ class ServingConfig:
     # pool blocks INCLUDING the reserved scratch block 0; 0 → auto:
     # every slot can hold max_seq tokens (the no-eviction-safe maximum)
     num_blocks: int = 0
+    # for a model with sliding-window layers (docs/serving.md#window-layers):
+    # blocks of the WINDOW kind, its scratch block included; 0 → auto:
+    # every slot can hold a full ring
+    window_num_blocks: int = 0
     kv_bits: int = 16               # 16 | 8 (int8 payloads + block scales)
     kv_quant_block: int = 64        # quantizer block over the head dim
     max_new_tokens: int = 64        # per-request default
@@ -506,9 +510,11 @@ class _Slot:
     """Host-side state of one active decode-batch slot."""
 
     def __init__(self, req: Request, blocks: List[int], prompt_len: int,
-                 max_new: int):
+                 max_new: int, wblocks: Optional[List[int]] = None):
         self.req = req
         self.blocks = blocks
+        # window-kind blocks, the slot's ring (docs/serving.md#window-layers)
+        self.wblocks = wblocks or []
         self.prompt_len = prompt_len
         self.max_new = max_new
         self.out_tokens: List[int] = []
@@ -614,12 +620,27 @@ class ServingEngine:
         self._recurrent = bool(getattr(inner, "has_recurrent_state", False))
         if self._recurrent:
             self._refuse_for_recurrent_state(config)
+        # a model with sliding-window layers keeps TWO kinds of block
+        # (docs/serving.md#window-layers): the growing table's, and a ring
+        # of `ring` entries over its window layers with an allocator of
+        # its own.  The slot's table carries both, the ring last.
+        self.ring = self.window_num_blocks = 0
+        self.window_allocator = None
+        state_kw = {}
+        if getattr(inner, "has_window_layers", False):
+            self._refuse_for_window_pool(config)
+            self.ring = inner.ring_entries(config.block_size)
+            self.window_num_blocks = config.window_num_blocks or (
+                1 + config.batch_slots * self.ring)
+            assert self.window_num_blocks >= 2, "window_num_blocks >= 2"
+            self.window_allocator = pk.BlockAllocator(self.window_num_blocks)
+            state_kw["window_num_blocks"] = self.window_num_blocks
         with monspans.recorder().setup_span("setup.pool_alloc") as alloc, \
                 jax.set_mesh(engine.mesh):
             self.pool = inner.init_serving_state(
                 config.batch_slots, self.num_blocks, config.block_size,
                 kv_bits=config.kv_bits, quant_block=config.kv_quant_block,
-                dtype=cache_dtype)
+                dtype=cache_dtype, **state_kw)
             alloc.attrs = {"bytes": pk.pool_bytes(self.pool)}
         self._recurrent_bytes = (inner.recurrent_state_bytes(self.pool)
                                  if self._recurrent else 0)
@@ -650,10 +671,16 @@ class ServingEngine:
         self._sanitizer = None
         armed = (_sanitize.resolve_enabled(False)
                  if config.sanitize is None else bool(config.sanitize))
+        self._window_sanitizer = None
         if armed:
             self._sanitizer = _sanitize.ShadowSanitizer(
                 self.num_blocks, scratch_block=pk.SCRATCH_BLOCK,
                 halt=config.sanitize_halt)
+            if self.ring:
+                # the second kind's ids are its own: a shadow table each
+                self._window_sanitizer = _sanitize.ShadowSanitizer(
+                    self.window_num_blocks, scratch_block=pk.SCRATCH_BLOCK,
+                    halt=config.sanitize_halt, kind="window")
             logger.warning("serving: shadow sanitizer ARMED "
                            "(DSTPU31x lifecycle checks, halt="
                            f"{config.sanitize_halt})")
@@ -726,7 +753,7 @@ class ServingEngine:
         S = config.batch_slots
         self._slots: List[Optional[_Slot]] = [None] * S
         self._snap_last = np.zeros((S,), np.int32)  # ngen at last snapshot
-        self._tables = np.zeros((S, self.nb_max), np.int32)
+        self._tables = np.zeros((S, self.nb_max + self.ring), np.int32)
         self._lengths = np.zeros((S,), np.int32)
         self._toks = np.zeros((S,), np.int32)
         self._seeds = np.zeros((S,), np.int32)
@@ -740,7 +767,7 @@ class ServingEngine:
         # (docs/serving.md#step-anatomy)
         self._resident = None
         self._state_dirty = True
-        self._unpack = None           # packed (S, nb_max + 6) → the seven
+        self._unpack = None           # packed (S, table + 6) → the seven
         self._reused_steps = 0        # decode steps that sent no state up
         self._state_uploads = 0
         # at most ONE decode step is dispatched and unread
@@ -868,6 +895,26 @@ class ServingEngine:
                     f"{what}: {reason} (docs/serving.md#{anchor})")
 
     @staticmethod
+    def _refuse_for_window_pool(config):
+        """A model with sliding-window layers keeps a second kind of block
+        whose table is a ring: a block there holds whatever positions the
+        window has reached, not a fixed stretch of the stream.  What moves,
+        shares or re-reads a stream BY its blocks has not learned the ring:
+        refused by name (ROADMAP, Queue 2)."""
+        image = ("a block image covers the growing table's blocks alone: a "
+                 "restored stream's window layers would read another "
+                 "stream's ring")
+        why = {"prefix_cache": "a ring block is overwritten as the window "
+                               "slides: a prefix's window-layer blocks "
+                               "cannot be shared read-only",
+               "kv_snapshot": image, "transfer": image, "role": image,
+               "speculative": "the window kernel attends one query token a "
+                              "slot, and a ring has overwritten what a "
+                              "rejected draft's rollback would re-read"}
+        ServingEngine._refuse_armed(config, why, "sliding-window layers",
+                                    "window-layers")
+
+    @staticmethod
     def _refuse_for_latent_pool(config):
         """A latent pool (``paged_kv.init_latent_pool``) has one leaf where
         every other pool has ``k`` and ``v``, and its kernel attends one
@@ -979,6 +1026,15 @@ class ServingEngine:
             "blocks_per_request_at_defaults": ub["total_blocks"],
             "free_blocks": self.allocator.free_blocks,
         }
+        if self.ring:
+            # the second kind (docs/serving.md#window-layers): a stream
+            # reserves what its own tokens fill, at most the ring
+            out.update(
+                window_num_blocks=self.window_num_blocks,
+                window_ring_blocks=self.ring,
+                window_free_blocks=self.window_allocator.free_blocks,
+                window_blocks_per_request_at_defaults=self._window_need(
+                    c.block_size + c.max_new_tokens))
         if self._prefix_index is not None:
             # admission counts UNIQUE blocks when the cache is armed —
             # surface the sharing split next to the classic math
@@ -1003,7 +1059,7 @@ class ServingEngine:
         bucket = self.nb_max * c.block_size
         pf = self._prefill_fn(bucket)
         toks = jnp.zeros((1, min(bucket, self.max_seq)), jnp.int32)
-        blocks = jnp.zeros((bucket // c.block_size,), jnp.int32)
+        blocks = jnp.zeros((bucket // c.block_size + self.ring,), jnp.int32)
         with jax.set_mesh(self.engine.mesh):
             dec_exe = self._decode.executable(*self._decode_args())
             pre_exe = pf.executable(*self._prefill_args(
@@ -1135,6 +1191,11 @@ class ServingEngine:
             raise ValueError(
                 f"request needs {nb} blocks; the pool only has "
                 f"{self.num_blocks - 1} allocatable")
+        need_w = self._window_need(total)
+        if need_w > max(0, self.window_num_blocks - 1):
+            raise ValueError(
+                f"request needs {need_w} window blocks; the window pool "
+                f"only has {self.window_num_blocks - 1} allocatable")
         if req.uid is not None and req.uid in self.results:
             # validated BEFORE the overload gate: an inadmissible
             # (duplicate-uid) submission must not shed legitimate queued
@@ -1286,13 +1347,15 @@ class ServingEngine:
 
     # ------------------------------ slot state: mirrors and the device's copy
     def _set_slot(self, slot: int, blocks=(), length=0, tok=0, seed=0,
-                  ngen=0, temp=1.0, flag=False):
+                  ngen=0, temp=1.0, flag=False, wblocks=()):
         """Seat a stream in ``slot`` — or, with the defaults, clear the
         row — in all seven mirrors.  Every write of slot state other than
         the decode step's plain advance comes through here or marks
         ``_state_dirty`` itself, so the next dispatch re-sends the state."""
         self._tables[slot] = pk.SCRATCH_BLOCK
         self._tables[slot, :len(blocks)] = blocks
+        # the ring's entries stand after the growing table's
+        self._tables[slot, self.nb_max:self.nb_max + len(wblocks)] = wblocks
         self._lengths[slot] = length
         self._toks[slot] = tok
         self._seeds[slot] = seed
@@ -1312,7 +1375,7 @@ class ServingEngine:
             return
         assert self._unread is None, "settle before re-sending slot state"
         self._build_decode()
-        # (S, nb_max + 6) int32, the temperatures as their bits
+        # (S, table + 6) int32, the temperatures as their bits
         buf = np.column_stack((
             self._tables, self._lengths, self._toks, self._seeds,
             self._ngen, self._temps.view(np.int32), self._flags))
@@ -1424,7 +1487,7 @@ class ServingEngine:
             return (_pack_read(out, nonfin, accept_len), pool,
                     lengths + n, ngen + n)
 
-        nb = self.nb_max
+        nb = self._tables.shape[1]
 
         def unpack(buf):
             return (buf[:, :nb], buf[:, nb], buf[:, nb + 1], buf[:, nb + 2],
@@ -1437,7 +1500,7 @@ class ServingEngine:
             f"serving.unpack[{c.batch_slots}x{nb}]", unpack)
         spec_tag = f",spec{self.spec.k}" if self.spec is not None else ""
         self._decode = self.engine._wrap_step(
-            f"serving.decode[{c.batch_slots}x{self.nb_max}"
+            f"serving.decode[{c.batch_slots}x{nb}"
             f"x{c.block_size},kv{c.kv_bits},{c.top_k}{spec_tag}]",
             spec_step if self.spec is not None else step,
             donate_argnums=(1,))
@@ -1534,9 +1597,15 @@ class ServingEngine:
                 block_size=c.block_size,
                 shared_prefix_tokens=ns * c.block_size)
             assert ub["shared_blocks"] == ns   # same clamp by construction
+            # both kinds are reserved here, once, for the stream's whole
+            # life; the head waits on whichever is short
+            need_w = self._window_need(len(req.tokens) + new)
+            if need_w and not self.window_allocator.can_alloc(need_w):
+                return
             fresh = self._alloc_blocks(ub["unique_blocks"], uid=req.uid)
             if fresh is None:
                 return
+            wblocks = self._alloc_window(need_w, uid=req.uid)
             if ns:
                 # borrow the cached prefix read-only: one refcount per
                 # co-tenant on top of the cache's own reference
@@ -1551,7 +1620,8 @@ class ServingEngine:
             self._prefix_requests_total += (
                 1 if self._prefix_index is not None else 0)
             try:
-                self._start(slot, req, blocks, new, share=share)
+                self._start(slot, req, blocks, new, share=share,
+                            wblocks=wblocks)
             except Exception:
                 # a prefill that dies mid-dispatch (device OOM, a
                 # poisoned executable) must not leak the blocks: free
@@ -1571,7 +1641,34 @@ class ServingEngine:
                     released = self.allocator.free(blocks)
                     if self._sanitizer is not None:
                         self._sanitizer.on_free(released, uid=req.uid)
+                    self._free_window(wblocks, uid=req.uid)
                 raise
+
+    def _window_need(self, total_tokens: int) -> int:
+        """Window-kind blocks a stream of ``total_tokens`` reserves: what
+        its own tokens fill, at most the ring; 0 for a model without window
+        layers."""
+        if not self.ring:
+            return 0
+        return min(pk.blocks_needed(total_tokens, self.config.block_size),
+                   self.ring)
+
+    def _alloc_window(self, n: int, uid=None) -> List[int]:
+        """``n`` blocks of the window kind (the caller has asked
+        ``can_alloc``); [] for a model without window layers."""
+        if not n:
+            return []
+        wblocks = self.window_allocator.alloc(n)
+        if self._window_sanitizer is not None:
+            self._window_sanitizer.on_alloc(wblocks, uid=uid)
+        return wblocks
+
+    def _free_window(self, wblocks: List[int], uid=None):
+        if not wblocks:
+            return
+        released = self.window_allocator.free(wblocks)
+        if self._window_sanitizer is not None:
+            self._window_sanitizer.on_free(released, uid=uid)
 
     def _deadline_unmeetable(self, req: Request) -> bool:
         """A queued request whose deadline has passed, or provably cannot
@@ -1599,9 +1696,11 @@ class ServingEngine:
             return False
         if self._prefix_index is not None:
             return True
-        need = pk.blocks_needed(len(req.tokens) + req.max_new_tokens,
-                                self.config.block_size)
-        return need <= self.allocator.free_blocks
+        total = len(req.tokens) + req.max_new_tokens
+        need = pk.blocks_needed(total, self.config.block_size)
+        return need <= self.allocator.free_blocks and (
+            not self.ring or self.window_allocator.can_alloc(
+                self._window_need(total)))
 
     def _prefix_match(self, req: Request) -> Optional[dict]:
         """Clamped radix lookup for one admission.  ``ns`` is capped at
@@ -1672,9 +1771,11 @@ class ServingEngine:
         return est
 
     def _start(self, slot: int, req: Request, blocks: List[int], new: int,
-               share: Optional[dict] = None):
+               share: Optional[dict] = None,
+               wblocks: Optional[List[int]] = None):
         fault.site("serving.prefill")
         c = self.config
+        wblocks = wblocks or []
         T = int(len(req.tokens))
         rec = self.results[req.uid]
         with self._spans.span("serving.prefill", uid=req.uid) as prefill:
@@ -1696,7 +1797,12 @@ class ServingEngine:
             toks = np.zeros((1, min(bucket, self.max_seq)), np.int32)
             toks[0, :T] = req.tokens
             nb_pre = bucket // c.block_size
-            blk = jnp.asarray(np.asarray(blocks[:nb_pre], np.int32))
+            # the prompt's blocks of the growing table, then the ring
+            # (scratch where a short stream holds no block)
+            blk = np.zeros((nb_pre + self.ring,), np.int32)
+            blk[:nb_pre] = blocks[:nb_pre]
+            blk[nb_pre:nb_pre + len(wblocks)] = wblocks
+            blk = jnp.asarray(blk)
             fn = self._prefill_fn(bucket)
             with jax.set_mesh(self.engine.mesh):
                 with self._spans.span("serving.prefill.dispatch"):
@@ -1718,6 +1824,8 @@ class ServingEngine:
             released = self.allocator.free(blocks)
             if self._sanitizer is not None:
                 self._sanitizer.on_free(released, uid=req.uid)
+            self._scrub_window(wblocks, uid=req.uid)
+            self._free_window(wblocks, uid=req.uid)
             logger.warning(
                 f"serving: request {req.uid} QUARANTINED at prefill — "
                 f"non-finite logits; typed '{POISONED}' result "
@@ -1727,14 +1835,17 @@ class ServingEngine:
             self._check_breaker()
             return
 
-        s = _Slot(req, blocks, T, new)
+        s = _Slot(req, blocks, T, new, wblocks=wblocks)
         s.out_tokens.append(first)
         s.hist.append(first)
         self._slots[slot] = s
         self._set_slot(slot, blocks, length=T, tok=first, seed=req.seed,
-                       ngen=1, temp=req.temperature, flag=req.do_sample)
+                       ngen=1, temp=req.temperature, flag=req.do_sample,
+                       wblocks=s.wblocks)
         if self._sanitizer is not None:
             self._sanitizer.on_attach(req.uid, blocks)
+        if self._window_sanitizer is not None:
+            self._window_sanitizer.on_attach(req.uid, s.wblocks)
         rec["t_first"] = time.monotonic()
         rec["t_tokens"] = [rec["t_first"]]
         if new == 1 or first == c.eos_token_id:
@@ -2384,7 +2495,17 @@ class ServingEngine:
             if self.admit_next_transfer() is None:
                 return
 
-    def _set_blocks(self, blocks: List[int], poison: bool):
+    def _scrub_window(self, wblocks, uid=None):
+        """Zero a stream's window-kind blocks before they go back (a
+        poisoned forward's K/V may be non-finite in every layer)."""
+        if not wblocks:
+            return
+        if self._window_sanitizer is not None:
+            self._window_sanitizer.on_scrub(wblocks, uid=uid)
+        self._set_blocks(wblocks, poison=False, window=True)
+
+    def _set_blocks(self, blocks: List[int], poison: bool,
+                    window: bool = False):
         """Pool edit over a block list, outside the decode step:
         ``poison=True`` NaN-fills the payload (int8 pools NaN the fp32
         scales — the int8 lanes cannot hold a NaN), ``poison=False``
@@ -2399,11 +2520,12 @@ class ServingEngine:
         doubling a production pool's bytes).  The block list pads to
         ``nb_max`` by repeating its first id (duplicate scatter indices
         write the same value), so every request shape shares one
-        executable."""
+        executable.  ``window``: the blocks are of the window kind (the
+        ``wk`` / ``wv`` leaves, padded to the ring)."""
         if self._blockset is None:
             quant = pk.is_quantized_pool(self.pool)
 
-            def setter(pool, blk, val):
+            def setter(pool, blk, val, window=False):
                 if quant:
                     return dict(pool,
                                 k_scale=pool["k_scale"].at[:, blk].set(val),
@@ -2411,18 +2533,22 @@ class ServingEngine:
                 return dict(pool, **{
                     name: pool[name].at[:, blk].set(
                         val.astype(pool[name].dtype))
-                    for name in pk.payload_names(pool)})
+                    for name in (pk.WINDOW if window
+                                 else pk.payload_names(pool))})
 
             # cpu backend: donation would only warn (PR-4's copy-on-
             # donate note); device backends get the in-place update
             donate = (0,) if jax.default_backend() != "cpu" else ()
-            self._blockset = jax.jit(setter, donate_argnums=donate)
-        padded = np.full((self.nb_max,), blocks[0], np.int32)
+            self._blockset = jax.jit(setter, donate_argnums=donate,
+                                     static_argnames=("window",))
+        padded = np.full((self.ring if window else self.nb_max,), blocks[0],
+                         np.int32)
         padded[:len(blocks)] = blocks
         val = jnp.float32(jnp.nan if poison else (1.0 if
                           pk.is_quantized_pool(self.pool) else 0.0))
         with jax.set_mesh(self.engine.mesh):
-            self.pool = self._blockset(self.pool, jnp.asarray(padded), val)
+            self.pool = self._blockset(self.pool, jnp.asarray(padded), val,
+                                       window=window)
 
     def _finish(self, slot: int, outcome: str = OK):
         s = self._slots[slot]
@@ -2448,6 +2574,7 @@ class ServingEngine:
                 self._sanitizer.on_scrub(scrub, uid=s.req.uid)
             if scrub:
                 self._set_blocks(scrub, poison=False)
+            self._scrub_window(s.wblocks, uid=s.req.uid)
         elif not s.wire_kv and self._prefix_index is not None:
             # publish this request's fully-WRITTEN prompt+output blocks
             # into the radix cache (the cache takes its own refcount)
@@ -2462,6 +2589,9 @@ class ServingEngine:
         released = self.allocator.free(s.blocks)
         if self._sanitizer is not None:
             self._sanitizer.on_free(released, uid=s.req.uid)
+        if self._window_sanitizer is not None:
+            self._window_sanitizer.on_detach(s.req.uid)
+        self._free_window(s.wblocks, uid=s.req.uid)
         rec = self.results[s.req.uid]
         rec["tokens"] = list(s.out_tokens)
         rec["outcome"] = outcome
@@ -2760,12 +2890,32 @@ class ServingEngine:
         while a step is unread), and whether the queue's head waits for
         BLOCKS: admission has just run or was not due, so a head still
         queued beside a free slot lacks only them."""
-        return {"blocks_in_use": self.allocator.used_blocks,
-                "blocks_free": self.allocator.free_blocks,
-                "kv_tokens": int(self._lengths.sum()),
-                "waits_for_blocks": bool(
-                    self.queue and not self._draining
-                    and len(active) < self.config.batch_slots)}
+        waits = bool(self.queue and not self._draining
+                     and len(active) < self.config.batch_slots)
+        out = {"blocks_in_use": self.allocator.used_blocks,
+               "blocks_free": self.allocator.free_blocks,
+               "kv_tokens": int(self._lengths.sum()),
+               "waits_for_blocks": waits}
+        if self.ring:
+            # the second kind, and WHICH kind the head waits for; the tokens
+            # a window layer can still read (each stream's last
+            # `sliding_window`) beside `kv_tokens`, and the rest, which one
+            # growing table for every layer would still hold
+            window = self.model.config.sliding_window
+            seen = int(np.minimum(self._lengths, window).sum())
+            total = (len(self.queue[0].tokens)
+                     + self.queue[0].max_new_tokens) if waits else 0
+            out.update(
+                window_blocks_in_use=self.window_allocator.used_blocks,
+                window_blocks_free=self.window_allocator.free_blocks,
+                window_kv_tokens=seen,
+                window_capped_tokens=out["kv_tokens"] - seen,
+                waits_for_window_blocks=waits and not
+                self.window_allocator.can_alloc(self._window_need(total)),
+                waits_for_global_blocks=waits and pk.blocks_needed(
+                    total, self.config.block_size)
+                > self.allocator.free_blocks)
+        return out
 
     def _dispatch(self, active, ahead: bool) -> _Unread:
         """Call the decode executable for ``active`` and start its one
@@ -3013,6 +3163,10 @@ class ServingEngine:
             f"{self.allocator.free_blocks} free of "
             f"{self.num_blocks - 1} allocatable "
             f"({self.allocator.used_blocks} leaked or still held)"
+            + (f", and {self._window_need(len(req.tokens) + req.max_new_tokens)}"
+               f" window block(s) of which "
+               f"{self.window_allocator.free_blocks} are free of "
+               f"{self.window_num_blocks - 1}" if self.ring else "")
             + (f"; memory forensics: {path}" if path else ""))
 
     # decode steps between latency-percentile/hist emissions: quantile
@@ -3471,6 +3625,12 @@ class ServingEngine:
                # model's held experts, a latent pool's row)
                **(self.model.serving_stats(self.pool)
                   if hasattr(self.model, "serving_stats") else {}),
+               # the second kind of block, for a model with window layers
+               **({"window_num_blocks": self.window_num_blocks,
+                   "window_ring_blocks": self.ring,
+                   "window_pool_bytes": sum(
+                       int(self.pool[n].nbytes) for n in pk.WINDOW)}
+                  if self.ring else {}),
                "recurrent_state_bytes": self._recurrent_bytes,
                "state_seats": self._state_seats,
                "outcomes": dict(self._outcomes),
@@ -3502,6 +3662,8 @@ class ServingEngine:
                 "p999": round(p["p999"], 2)}
         if self._sanitizer is not None:
             out["sanitizer"] = self._sanitizer.stats()
+            if self._window_sanitizer is not None:
+                out["window_sanitizer"] = self._window_sanitizer.stats()
         if (self.kvs is not None or self._kv_migrated_total
                 or self._kv_fallback_total):
             kv = {"snapshots": self._kv_snapshots_total,
@@ -3573,6 +3735,8 @@ class ServingEngine:
                 # after a clean drain every block must be home —
                 # anything still allocated is a leak (DSTPU312)
                 self._sanitizer.on_close()
+                if self._window_sanitizer is not None:
+                    self._window_sanitizer.on_close()
             # snapshot retention at teardown: finished uids' images go;
             # journaled still-pending uids keep theirs (a restart or a
             # router handoff may restore them)

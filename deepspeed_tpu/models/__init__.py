@@ -1,6 +1,7 @@
 """Model zoo: GPT-2 family (flagship), BERT encoder, MoE GPT, GPT-J/NeoX,
 Jamba (Mamba + attention hybrid), Ouro (one stack of layers looped),
-DeepSeek-V2 (latent attention, routed and shared experts)."""
+DeepSeek-V2 (latent attention, routed and shared experts), AFMoE (window and
+global attention layers, gated grouped-query attention, sigmoid routing)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -31,6 +32,9 @@ def build(name, **overrides):
         if name.startswith("deepseek-v2"):
             from .deepseek_v2 import DeepseekV2
             return DeepseekV2(preset=name, **overrides)
+        if name.startswith("afmoe"):
+            from .afmoe import Afmoe
+            return Afmoe(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

@@ -197,6 +197,11 @@ class DeepseekV2:
             config = DeepseekV2Config(**base)
         c = config
         dropless.check_route(c.topk_method, c.scoring_func)
+        if c.scoring_func != "softmax":
+            # moe/dropless.py scores by sigmoid too (models/afmoe.py); this
+            # family's reference and its cell know softmax alone
+            raise ValueError(f"scoring_func = {c.scoring_func!r}: "
+                             "models/deepseek_v2.py computes 'softmax'")
         scaling = c.rope_scaling
         if scaling is not None and scaling.get("type") != "yarn":
             raise ValueError(f"rope_scaling.type = {scaling.get('type')!r}: "

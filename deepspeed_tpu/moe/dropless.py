@@ -5,12 +5,15 @@ No reference counterpart: the reference's gate (``sharded_moe.py``,
 ``TopKGate``) routes top-1 / top-2 into a capacity-padded ``(E x C, M)``
 buffer and drops what overflows; it stays, for ``gpt2_moe``.  Here:
 
-- :func:`route` scores every token over ALL ``E`` experts in float32 and
+- :func:`route` scores every token over ALL ``E`` experts in float32
+  (``softmax`` over the experts, or an independent ``sigmoid`` each) and
   picks its ``k``: plain ``greedy`` top-k, or ``group_limited_greedy`` (the
   experts in ``n_group`` groups; a token keeps the ``topk_group`` groups whose
   best expert scores highest and picks its ``k`` among those: DeepSeek-V2's
-  device-limited routing, arXiv:2405.04434 section 2.1.2).  Nothing is ever
-  dropped: there is no capacity.
+  device-limited routing, arXiv:2405.04434 section 2.1.2).  A selection
+  ``bias`` (one value an expert, kept by a load balancer and not by the
+  gradient) moves the PICK and never the weight.  Nothing is ever dropped:
+  there is no capacity.
 - :func:`held_experts` computes, for the ``count`` experts ``first ..
   first + count - 1`` that THIS chip holds, their part of every token's
   output: the token-expert pairs are sorted by expert, the pairs of held
@@ -27,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 TOPK_METHODS = ("greedy", "group_limited_greedy")
-SCORING_FUNCS = ("softmax",)
+SCORING_FUNCS = ("softmax", "sigmoid")
 
 
 def check_route(topk_method, scoring_func):
@@ -42,30 +45,40 @@ def check_route(topk_method, scoring_func):
 
 def route(logits, k, *, topk_method="greedy", n_group=1, topk_group=1,
           scoring_func="softmax", norm_topk_prob=False,
-          routed_scaling_factor=1.0):
+          routed_scaling_factor=1.0, bias=None, scale_normed=False):
     """``logits`` (N, E), the router's outputs -> ``(experts (N, k) int32,
     weights (N, k) float32)``.
 
-    Scores are ``softmax`` over all ``E`` in float32.  The weights are the
-    picked scores, renormalised to sum to 1 (``norm_topk_prob``, for k > 1)
-    or else multiplied by ``routed_scaling_factor``: one or the other, as
-    HF ``DeepseekV2MoEGate`` does.  An unknown ``topk_method`` or
+    Scores are ``softmax`` over all ``E``, or ``sigmoid`` of each, in
+    float32.  ``bias`` (E,) is added to the scores for the PICK alone (groups
+    and experts); the weights are the picked scores WITHOUT it, renormalised
+    to sum to 1 (``norm_topk_prob``, for k > 1) or else multiplied by
+    ``routed_scaling_factor``: one or the other, as HF ``DeepseekV2MoEGate``
+    does; ``scale_normed`` multiplies the renormalised weights by the factor
+    too, for a family that says both.  An unknown ``topk_method`` or
     ``scoring_func`` is refused by name."""
     check_route(topk_method, scoring_func)
     N, E = logits.shape
-    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    pick_from = scores
+    logits = logits.astype(jnp.float32)
+    scores = (jax.nn.softmax(logits, axis=-1) if scoring_func == "softmax"
+              else jax.nn.sigmoid(logits))
+    pick_from = scores if bias is None else scores + bias.astype(jnp.float32)
     if topk_method == "group_limited_greedy":
         assert E % n_group == 0 and topk_group <= n_group, (E, n_group)
-        best = scores.reshape(N, n_group, E // n_group).max(axis=-1)
+        best = pick_from.reshape(N, n_group, E // n_group).max(axis=-1)
         _, groups = jax.lax.top_k(best, topk_group)            # (N, topk_group)
         kept = jnp.zeros((N, n_group), bool).at[
             jnp.arange(N)[:, None], groups].set(True)
+        # a biased score may be negative: a dropped group's must lie below
         pick_from = jnp.where(jnp.repeat(kept, E // n_group, axis=1),
-                              scores, 0.0)
+                              pick_from, 0.0 if bias is None else -jnp.inf)
     weights, experts = jax.lax.top_k(pick_from, k)
+    if bias is not None:
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if k > 1 and norm_topk_prob:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        if scale_normed:
+            weights = weights * routed_scaling_factor
     else:
         weights = weights * routed_scaling_factor
     return experts.astype(jnp.int32), weights

@@ -50,6 +50,16 @@ BlockSpec-LUT and manual-DMA variants side by side):
   which is what keeps CPU tier-1 exact when the serving decode routes
   through here.
 
+A **window** layer (``window=w``: key ``s`` is live for the token at
+position ``t`` iff ``0 <= t - s < w``) keeps its table as a RING: the table's
+``nb_max`` entries hold the newest ``nb_max`` blocks of the stream, logical
+block ``j`` in entry ``j % nb_max`` (``paged_kv.ring_blocks`` sizes it so that
+the window and the block being written always fit).  The online walk then
+starts at the chunk that holds the row's first live position, looks each
+block up at its ring entry, fetches no block that lies wholly before the
+window and masks the dead positions of the first live block; dead rows and
+the carried DMA ring are as above.  One query token a slot (W = 1).
+
 ``mode="auto"`` resolves to ``online`` on compiled TPU and ``exact``
 under the interpreter.  Queries are a ``(B, W, H, hd)`` window —
 ``W=1`` is plain decode, ``W=k+1`` is the speculative-decode scoring
@@ -97,7 +107,8 @@ def resolve_mode(mode: str) -> str:
 
 # ============================================================== exact kernel
 def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
-                  scale_attn, compute_dtype, quantized, n_kv_head):
+                  scale_attn, compute_dtype, quantized, n_kv_head,
+                  window=None):
     """Grid (B, nb_max), block walk innermost (revisits scratch).
 
     Scores land in a full (H, W, S) fp32 row; the last block's visit
@@ -148,14 +159,33 @@ def _exact_kernel(*refs, block_size, nb_max, n_head, head_dim, n_window,
         S = nb_max * bs
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (n_head, W, S), 2)
         w_pos = jax.lax.broadcasted_iota(jnp.int32, (n_head, W, S), 1)
-        valid = k_pos <= lengths_ref[b] + w_pos
+        if window is not None:
+            # column (e, off) of the ring holds the newest logical block j
+            # with j % nb_max == e, none (a position below 0) if the stream
+            # has not reached entry e yet
+            k_pos = ring_positions(k_pos // bs, k_pos % bs,
+                                   lengths_ref[b] + W - 1, nb_max, bs)
+            valid = (k_pos <= lengths_ref[b] + w_pos) & (k_pos >= 0) & (
+                k_pos > lengths_ref[b] + w_pos - window)
+        else:
+            valid = k_pos <= lengths_ref[b] + w_pos
         s = jnp.where(valid, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(compute_dtype)
         out = jnp.einsum("hwk,khd->whd", p, vrow_ref[...])
         o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _exact_call(q, pool, tables, lengths, layer_arr, *, scale_attn):
+def ring_positions(entry, offset, newest, ring, block_size):
+    """The position held at ``offset`` of ring entry ``entry`` when the
+    newest position written is ``newest``: logical block ``j`` lives in entry
+    ``j % ring``, so the entry holds the largest ``j <= newest // block_size``
+    of its residue (negative: nothing yet)."""
+    last = newest // block_size
+    return (last - jax.lax.rem(last - entry + ring, ring)) * block_size + offset
+
+
+def _exact_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
+                window=None):
     B, W, H, hd = q.shape
     bs, HD = pool["k"].shape[2:]
     nb_max = tables.shape[1]
@@ -189,7 +219,7 @@ def _exact_call(q, pool, tables, lengths, layer_arr, *, scale_attn):
     kernel = functools.partial(
         _exact_kernel, block_size=bs, nb_max=nb_max, n_head=H, head_dim=hd,
         n_window=W, scale_attn=scale_attn, compute_dtype=q.dtype,
-        quantized=quantized, n_kv_head=HD // hd)
+        quantized=quantized, n_kv_head=HD // hd, window=window)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, H, hd), q.dtype),
@@ -204,7 +234,7 @@ def _round_up(x, m):
 
 def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
                    compute_dtype, quant_block, group, rows_per_token,
-                   q_per_kv=1):
+                   q_per_kv=1, window=None):
     """Grid (B,): ONE program per slot walks the slot's LIVE tokens in
     chunks of ``group`` blocks (``group * block_size`` key positions: a
     whole number of 128-lane score columns) through a triple-buffered
@@ -246,7 +276,11 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     head; the pool's width is the KV heads alone): the ``q_per_kv`` query
     heads of one KV head ride the WINDOW axis — the caller hands in
     ``W * q_per_kv`` query rows of the pool's width — and share window
-    token ``w``'s causal limit."""
+    token ``w``'s causal limit.
+
+    ``window`` (module docstring): the table is a ring of ``nb_max``
+    entries and the walk covers the chunks from the one that holds position
+    ``length - window + 1`` on; one token a slot."""
     quantized = quant_block is not None
     if quantized:
         (tables_ref, lengths_ref, layer_ref, q_ref,
@@ -273,8 +307,22 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
         """Chunks (or blocks) of ``row`` that hold a position <= length +
         n_tok - 1, the window's last row; everything past is masked for
         every row.  0 for a dead row."""
-        n = jnp.minimum((lengths_ref[row] + n_tok + unit - 1) // unit, most)
+        n = (lengths_ref[row] + n_tok + unit - 1) // unit
+        if window is None:            # a ring's positions outrun its table
+            n = jnp.minimum(n, most)
         return jnp.where(tables_ref[row, 0] == SCRATCH_BLOCK, 0, n)
+
+    def first_unit(row, unit):
+        """The chunk (or block) that holds ``row``'s first live position."""
+        return jnp.maximum(lengths_ref[row] - (window - 1), 0) // unit
+
+    def row_chunks(row):
+        """Chunks ``row``'s walk covers: all that hold a live position, or,
+        under a window, those from the first live one on."""
+        n = live_units(row, Tc, n_chunks)
+        if window is None:
+            return n
+        return jnp.maximum(n - first_unit(row, Tc), 0)
 
     @pl.when(b == 0)
     def _():
@@ -288,13 +336,13 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
 
         def forth(r, pos):
             first_ref[r] = pos
-            return pos + live_units(r, Tc, n_chunks)
+            return pos + row_chunks(r)
 
         next_ref[B] = B
         jax.lax.fori_loop(0, B, back, B)
         first_ref[B] = jax.lax.fori_loop(0, B, forth, 0)
 
-    n_live = live_units(b, Tc, n_chunks)      # this row's chunks; 0: dead
+    n_live = row_chunks(b)                    # this row's chunks; 0: dead
     base = first_ref[b]                       # ring position of chunk 0
     total = first_ref[B]
 
@@ -304,11 +352,20 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
         position, which a live chunk's first always does — and one for an
         int8 pool's scale tiles, which go whole with their chunk."""
         live_blocks = live_units(row, bs, nb_max)
+        if window is not None:
+            c = c + first_unit(row, Tc)       # the row's c-th chunk
+            first_block = first_unit(row, bs)
         out = []
         for g in range(G):
-            # a chunk's tail past the table is never fetched; the clamp
-            # keeps the scalar read inside the table
-            ki = tables_ref[row, jnp.minimum(c * G + g, nb_max - 1)]
+            if window is None:
+                # a chunk's tail past the table is never fetched; the clamp
+                # keeps the scalar read inside the table
+                ki = tables_ref[row, jnp.minimum(c * G + g, nb_max - 1)]
+                wanted = g == 0 or c * G + g < live_blocks
+            else:
+                ki = tables_ref[row, jax.lax.rem(c * G + g, nb_max)]
+                wanted = jnp.logical_and(c * G + g >= first_block,
+                                         c * G + g < live_blocks)
             if quantized:
                 # int8 tiles are 32 sublanes: a block lands whole at
                 # [slot, g] and is cast into the chunk-shaped stage below
@@ -316,7 +373,7 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
             else:
                 kd = kbuf.at[slot, pl.ds(g * bs, bs)]
                 vd = vbuf.at[slot, pl.ds(g * bs, bs)]
-            out.append((g == 0 or c * G + g < live_blocks,
+            out.append((wanted,
                         [pltpu.make_async_copy(k_hbm.at[lay, ki], kd,
                                                sem.at[slot, 0]),
                          pltpu.make_async_copy(v_hbm.at[lay, ki], vd,
@@ -401,6 +458,8 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
             start_at(p + 2)
             slot = jax.lax.rem(p, _N_BUF)
             each_copy(b, c, slot, lambda cp: cp.wait())
+            if window is not None:
+                c = c + first_unit(b, Tc)     # positions are the stream's
             if quantized:
                 for g in range(G):
                     rows = pl.ds(g * bs, bs)
@@ -416,7 +475,10 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
             # 0 x NaN is NaN in P @ V: dead positions' values are zeros
             # (their keys are covered by the where on the scores)
             v_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (Tc, HD), 0)
-            v = jnp.where(v_pos < length + n_tok, v, jnp.zeros_like(v))
+            v_live = v_pos < length + n_tok
+            if window is not None:
+                v_live = jnp.logical_and(v_live, v_pos > length - window)
+            v = jnp.where(v_live, v, jnp.zeros_like(v))
             s = jax.lax.dot_general(
                 qbd, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)           # (R, Tc)
@@ -431,8 +493,13 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
             k_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 1)
             w_pos = jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 0) // (
                 Rw * q_per_kv)
-            last = jnp.minimum(length + w_pos, nb_max * bs - 1)
-            s = jnp.where(k_pos <= last, s, NEG_INF)
+            if window is None:
+                last = jnp.minimum(length + w_pos, nb_max * bs - 1)
+                s = jnp.where(k_pos <= last, s, NEG_INF)
+            else:
+                s = jnp.where(jnp.logical_and(k_pos <= length + w_pos,
+                                              k_pos > length + w_pos - window),
+                              s, NEG_INF)
             m_prev = m_ref[:]                                 # (R, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -472,7 +539,7 @@ def _scale_rows(scale, layer, tables, n_rows, n_cols):
 
 
 def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
-                 interpret, q_per_kv=1):
+                 interpret, q_per_kv=1, window=None, name="paged_attention"):
     B, W, H, hd = q.shape
     bs, HD = pool["k"].shape[2:]
     if HD != H * hd:
@@ -483,7 +550,8 @@ def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
         qg = q.reshape(B, W, n_kv, G_q, hd).transpose(0, 1, 3, 2, 4)
         out = _online_call(qg.reshape(B, W * G_q, n_kv, hd), pool, tables,
                            lengths, layer_arr, scale_attn=scale_attn,
-                           interpret=interpret, q_per_kv=G_q)
+                           interpret=interpret, q_per_kv=G_q, window=window,
+                           name=name)
         out = out.reshape(B, W, G_q, n_kv, hd).transpose(0, 1, 3, 2, 4)
         return out.reshape(B, W, H * hd)
     nb_max = tables.shape[1]
@@ -540,18 +608,19 @@ def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
         _online_kernel, block_size=bs, nb_max=nb_max, head_dim=hd,
         scale_attn=scale_attn, compute_dtype=q.dtype,
         quant_block=qb if quantized else None, group=G, rows_per_token=Rw,
-        q_per_kv=q_per_kv)
+        q_per_kv=q_per_kv, window=window)
     cp = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, HD), q.dtype),
-        compiler_params=cp, interpret=interpret, name="paged_attention",
+        compiler_params=cp, interpret=interpret, name=name,
     )(tables, lengths, layer_arr, *args)
 
 
 # ================================================================ public API
 def paged_attention(q, pool, block_tables, lengths, layer, *,
-                    scale_attn=True, mode="auto", interpret=None):
+                    scale_attn=True, mode="auto", interpret=None,
+                    window=None, name="paged_attention"):
     """Masked attention of a ``(B, W)`` query window over the paged pool,
     reading K/V blocks in place (no gathered copy).
 
@@ -564,7 +633,10 @@ def paged_attention(q, pool, block_tables, lengths, layer, *,
       padded); ``lengths``: (B,) int32 — position of the FIRST window
       token (its K/V already written, so ``k_pos <= lengths + w`` is
       the causal mask for window row ``w``);
-    - ``layer``: int or traced scalar (called inside the layer scan).
+    - ``layer``: int or traced scalar (called inside the layer scan);
+    - ``window``: None, or the layer's sliding window: ``block_tables`` is
+      then a ring (module docstring) and W is 1;
+    - ``name``: the compiled call's name in a device trace.
 
     Returns (B, W, H·hd) in ``q.dtype`` — same contract as
     ``gather_kv`` + ``GPT2._masked_attend``, which remains the oracle
@@ -576,13 +648,19 @@ def paged_attention(q, pool, block_tables, lengths, layer, *,
     # attention) a divisor of H, each shared by H // n_kv query heads
     assert HD % hd == 0 and (H * hd) % HD == 0, (pool["k"].shape, q.shape)
     mode = resolve_mode(mode)
+    if window is not None:
+        assert W == 1 and "k_scale" not in pool, \
+            "a window layer attends one token a slot over a 16-bit pool"
+        bs, ring = pool["k"].shape[2], block_tables.shape[1]
+        assert (ring - 1) * bs >= window - 1, \
+            f"a ring of {ring} blocks of {bs} cannot hold a window of {window}"
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     tables = jnp.asarray(block_tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     if mode == "exact":
         return _exact_call(q, pool, tables, lengths, layer_arr,
-                           scale_attn=scale_attn)
+                           scale_attn=scale_attn, window=window)
     return _online_call(q, pool, tables, lengths, layer_arr,
                         scale_attn=scale_attn,
                         interpret=_interpret() if interpret is None
-                        else interpret)
+                        else interpret, window=window, name=name)

@@ -385,3 +385,116 @@ def test_online_kernel_compiles_for_a_v5e(v5e, slots, heads, kv_heads,
                               ",".join(map(str, pool["k"][0])))
     assert payload in text                     # the pool is an operand
     assert not re.search(re.escape(" = " + payload) + r"\S* copy\(", text)
+
+
+# ------------------------------------------------ a window layer's ring walk
+# name: (block, window, rows' lengths; None: a dead row)
+RING_CASES = {
+    # a window that is no multiple of the block: a ring of 8 blocks of 16,
+    # ONE chunk of 128 positions; rows before, on and after the window's
+    # edge, a block's edge, the chunk's edge, and long after the ring wrapped
+    "ring8": (16, 100, [5, None, 14, 99, 100, 101, 127, 128, 250, 1000, None,
+                        317, 0]),
+    # a ring of 14 blocks: two chunks, the second part past the ring
+    "ring14": (16, 200, [3, 199, 200, 201, 255, 256, 257, 4095, None]),
+    # the served shape: blocks of 64, window 4,096, a ring of 65
+    "ring65": (64, 4096, [10, 4095, 4096, 4100, 9000, None, 16383]),
+}
+
+
+def _ring_case(rows, heads, kv_heads, bs, window):
+    """A ring pool as a stream of ``length + 1`` tokens leaves it (logical
+    block ``j`` in entry ``j % ring``; blocks the window has slid past are
+    overwritten), every block no ring names NaN, and the float64 oracle over
+    the last ``window`` positions."""
+    rng = np.random.default_rng(5)
+    ring = pk.ring_blocks(window, bs)
+    B = len(rows)
+    k = np.full((1, 1 + B * ring + 3, bs, kv_heads * HD), np.nan, np.float32)
+    k[:, 0] = 0
+    v = k.copy()
+    tables = np.zeros((B, ring), np.int32)
+    lengths = np.zeros((B,), np.int32)
+    ref = np.zeros((B, 1, heads * HD))
+    q = rng.standard_normal((B, 1, heads, HD)).astype(np.float32)
+    for b, length in enumerate(rows):
+        if length is None:
+            continue
+        last = length // bs
+        n = min(last + 1, ring)
+        tables[b, :n] = 1 + b * ring + np.arange(n)
+        lengths[b] = length
+        K, V = (np.asarray(jnp.asarray(rng.standard_normal(
+            ((last + 1) * bs, kv_heads, HD)), jnp.bfloat16), np.float32)
+            for _ in "kv")
+        for j in range(max(0, last - ring + 1), last + 1):
+            at = tables[b, j % ring]
+            k[0, at] = K[j * bs:(j + 1) * bs].reshape(bs, -1)
+            v[0, at] = V[j * bs:(j + 1) * bs].reshape(bs, -1)
+        lo = max(0, length - window + 1)
+        for h in range(heads):
+            g = h // (heads // kv_heads)
+            s = K[lo:length + 1, g].astype(np.float64) @ q[b, 0, h] \
+                / np.sqrt(HD)
+            p = np.exp(s - s.max())
+            ref[b, 0, h * HD:(h + 1) * HD] = (p / p.sum()) @ V[lo:length + 1,
+                                                               g]
+    pool = {"k": jnp.asarray(k, jnp.bfloat16), "v": jnp.asarray(v,
+                                                                jnp.bfloat16)}
+    return pool, tables, lengths, jnp.asarray(q, jnp.bfloat16), ref
+
+
+@pytest.mark.parametrize("mode", ["online", "exact"])
+@pytest.mark.parametrize("heads, kv_heads", [(2, 2), (6, 1)])
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_window_walk_over_a_ring(case, heads, kv_heads, mode, devices):
+    """A window layer's decode attention over a ring table: the walk starts
+    at the chunk of the first live position, looks blocks up at their ring
+    entries, masks the first block's dead positions; live rows equal the
+    oracle over the last ``window`` positions, dead rows are zero, nothing
+    is NaN though every block no ring names is."""
+    bs, window, rows = RING_CASES[case]
+    pool, tables, lengths, q, ref = _ring_case(rows, heads, kv_heads, bs,
+                                               window)
+    out = np.asarray(jax.jit(lambda q, p: paged_attention(
+        q, p, tables, lengths, 0, mode=mode, window=window))(q, pool),
+        np.float32)
+    live = tables[:, 0] != pk.SCRATCH_BLOCK
+    assert np.isfinite(out).all()
+    if mode == "online":
+        assert not out[~live].any()
+    assert np.abs(out - ref)[live].max() < 1e-2 * np.abs(ref[live]).max()
+
+
+def test_a_ring_too_short_for_its_window_is_refused(devices):
+    pool, tables, lengths, q, _ = _ring_case([40], 2, 2, 16, 100)
+    with pytest.raises(AssertionError, match="cannot hold a window"):
+        paged_attention(q, pool, tables[:, :6], lengths, 0, window=100)
+
+
+def test_window_kernel_compiles_for_a_v5e(v5e):
+    """The window walk at the served shape (96 slots, 48 query heads over 8
+    K/V heads of 128, a ring of 65 blocks of 64) through Mosaic and XLA:TPU
+    for a described v5e: one custom call under its own name, no copy of the
+    pool."""
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    slots, heads, kv_heads, hd, block, layers = 96, 48, 8, 128, 64, 4
+    ring = pk.ring_blocks(4096, block)
+    rows = (layers, 2688, block, kv_heads * hd)
+
+    def fn(q, tables, lengths, k, v):
+        return paged_attention(q, {"k": k, "v": v}, tables, lengths, 1,
+                               mode="online", interpret=False, window=4096,
+                               name="paged_attention_window")
+    shapes = [((slots, 1, heads, hd), jnp.bfloat16),
+              ((slots, ring), jnp.int32), ((slots,), jnp.int32),
+              (rows, jnp.bfloat16), (rows, jnp.bfloat16)]
+    text = jax.jit(fn).trace(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    ]).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_attention_window" in text
+    payload = "bf16[{}]".format(",".join(map(str, rows)))
+    assert payload in text
+    assert not re.search(re.escape(" = " + payload) + r"\S* copy\(", text)
