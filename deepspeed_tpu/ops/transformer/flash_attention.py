@@ -10,8 +10,29 @@ with fp32 running max/denominator and bf16 MXU matmuls.
 Layout: inputs (B, T, H, d) (the model's layout) are processed on a grid
 (B*H, q_blocks, k_blocks); the innermost k dimension revisits VMEM scratch
 carrying the online-softmax state (m, l, acc).  The backward pass recomputes
-probabilities from the saved logsumexp (no (T,T) residuals), with one kernel
-for dK/dV (grid over k blocks) and one for dQ (grid over q blocks).
+probabilities from the saved logsumexp (no (T,T) residuals).
+
+The backward (``_bwd``).  A DENSE call (no LUT, no key or attention bias;
+causal or not) is ONE kernel: grid (B*H, k_blocks, q_blocks), each (q block,
+k block) pair visited once, and from its one ``q·kᵀ``, one exp, one
+``dO·vᵀ`` and one ``dS`` come its shares of dK, dV AND dQ, five products
+where two kernels that each recomputed the scores ran seven.  dK and dV
+accumulate in VMEM over the q sweep as before.  dQ: where a head's keys are
+one block (T up to the block) a visit holds all of a q row's keys and its dQ
+is written straight out; else the shares are summed, k block by k block in
+ascending order, in one more resident, an fp32 accumulator over the head's
+whole padded sequence (1 MB at T 2048, 2 MB at T 4096, a row padded to 128
+lanes), and a q block is cast and written at its visit under the last k
+block.  TPU grid steps run in order on one core, so this needs no atomics;
+both inner grid axes carry state.  ``_fused_backward`` decides from the
+shapes the call sees: a sequence whose accumulator is over
+``_FUSED_BWD_DQ_ACC_BYTES`` (T past 8192) keeps two kernels, dK/dV (grid over
+k blocks) and dQ (grid over q blocks).  So do the LUT, banded, merged and
+biased calls, unchanged to the bit: their visits follow per-row LUTs (a k
+block's q rows and a q row's k blocks are different lists, so one grid does
+not walk both), and a bias tile would be one more resident operand in the
+fused kernel's VMEM.  ``tile_census()`` counts the backward calls by form
+(``bwd_fused`` / ``bwd_split``).
 
 The causal tile walk (dense causal calls with square blocks): a resident
 ``(block, block)`` visit is cut into square 256-tiles under a static plan
@@ -43,6 +64,10 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = None   # None → auto-tuned by head_dim/seq (see _auto_blocks)
 DEFAULT_BLOCK_K = None
 NEG_INF = -1e30
+# Mosaic requires the last (lane) dim of a block to be 128-aligned or span
+# the array; per-row softmax statistics (lse/delta) are stored broadcast
+# across a 128-wide lane dim (same trick as the upstream TPU flash kernel)
+MIN_LANES = 128
 
 
 def _auto_blocks(seq_len, head_dim, block_q, block_k):
@@ -70,10 +95,25 @@ def _auto_blocks(seq_len, head_dim, block_q, block_k):
     if block_k is None:
         block_k = min(cap, max(128, seq_len))
     return block_q, block_k
-# Mosaic requires the last (lane) dim of a block to be 128-aligned or span
-# the array; per-row softmax statistics (lse/delta) are stored broadcast
-# across a 128-wide lane dim (same trick as the upstream TPU flash kernel)
-MIN_LANES = 128
+
+
+# The fused backward's one new resident: dQ summed in fp32 over a head's
+# whole padded sequence, as VMEM lays it out (a row padded to 128 lanes:
+# 1 MB at T 2048, 2 MB at T 4096, for hd 64 and hd 128 alike).  Over this
+# the sequence is too long for one more buffer beside the blocks and their
+# score tiles, and the backward stays two kernels.
+_FUSED_BWD_DQ_ACC_BYTES = 4 * 2 ** 20
+
+
+def _fused_backward(dense, nq, nk, block_q, head_dim):
+    """Whether a backward is ONE kernel (dQ, dK and dV from one pass over
+    the scores) or two (dK/dV, then dQ with the scores computed again).
+    Decided by what the call can see, never by a key: the dense plan (no
+    LUT, no key or attention bias: those keep the kernels and the bits they
+    had) and an accumulator inside its budget.  A head whose keys are one
+    block needs no accumulator and always fuses."""
+    acc_bytes = nq * block_q * max(head_dim, MIN_LANES) * 4 if nk > 1 else 0
+    return dense and acc_bytes <= _FUSED_BWD_DQ_ACC_BYTES
 
 
 def _interpret():
@@ -81,12 +121,14 @@ def _interpret():
 
 
 def _pallas(kernel, *, grid, in_specs, out_specs, out_shape, scratch,
-            num_prefetch=0):
+            num_prefetch=0, carried_axes=1):
     """One pallas_call builder for the dense (plain grid) and LUT
     (scalar-prefetch grid) variants — the operand lists must never
-    diverge between the two paths."""
+    diverge between the two paths.  ``carried_axes``: how many of the
+    grid's innermost axes carry state in scratch from step to step."""
     cp = pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",))
+        dimension_semantics=("parallel",) * (len(grid) - carried_axes)
+        + ("arbitrary",) * carried_axes)
     if num_prefetch:
         return pl.pallas_call(
             kernel,
@@ -229,19 +271,27 @@ def _strip_scores(q, k, sm_scale, tile):
 # ``reset_tile_census`` add up to: ``{call: (visited, masked, square)}``
 # over a call's whole grid.  A call traced again (the custom_vjp's primal
 # and its forward rule, a rematerialised forward) has the same key and
-# counts once.  Trace-time bookkeeping: nothing of it reaches the program.
+# counts once.  Beside it the form each distinct backward took, dense or
+# not: ``{call: "bwd_fused" | "bwd_split"}``.  Trace-time bookkeeping:
+# nothing of it reaches the program.
 _tile_census = {}
+_bwd_forms = {}
 
 
 def reset_tile_census():
     _tile_census.clear()
+    _bwd_forms.clear()
 
 
 def tile_census():
     """``{"visited", "masked", "square"}`` summed over the distinct dense
-    flash calls traced since :func:`reset_tile_census`."""
+    flash calls traced since :func:`reset_tile_census`, and the distinct
+    backward calls by form: ``bwd_fused`` (one kernel for dQ, dK and dV)
+    and ``bwd_split`` (two, each recomputing the scores)."""
     sums = [sum(c[i] for c in _tile_census.values()) for i in range(3)]
-    return dict(zip(("visited", "masked", "square"), sums))
+    forms = list(_bwd_forms.values())
+    return {**dict(zip(("visited", "masked", "square"), sums)),
+            **{form: forms.count(form) for form in ("bwd_fused", "bwd_split")}}
 
 
 def _record_tiles(kind, BH, d, nq, nk, block_q, block_k, causal, tile):
@@ -808,18 +858,34 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k,
 # ============================================================== backward kernels
 def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
                      seq_len, n_heads=1, use_kbias=False,
-                     use_abias=False, use_lut=False, tile=None):
+                     use_abias=False, use_lut=False, tile=None,
+                     num_k_blocks=None):
     """Grid: (BH, nk, nq) with nq innermost; accumulates dK/dV for one k block.
     ``use_lut``: inner dim is the live q-block count; scalar-prefetch
     ``(qmap, qlen)`` lead the args and pick the visited q block.
-    ``tile``: as in :func:`_fwd_kernel`."""
+    ``tile``: as in :func:`_fwd_kernel`.
+
+    ``num_k_blocks``: given, this is the FUSED backward (dense calls only,
+    ``_fused_backward``): the visit's one ``dS`` also yields its share of
+    dQ, ``dS·K``, so no second kernel recomputes the scores.  A third
+    output ``dq`` follows ``dk`` and ``dv``.  Where the head's keys are one
+    block the visit holds all of a q row's keys and dQ is written straight
+    out; else the shares add up in ``dq_acc``, fp32 over the head's whole
+    padded sequence ``(nq, block_q, d)``, k block by k block in ascending
+    order (grid steps run in order on one core: both inner axes carry
+    state), and q block ``i`` is cast and written at its visit under the
+    LAST k block, which is where its ``dq`` block index first leaves 0."""
     if use_lut:
         qmap_ref, qlen_ref = refs[:2]
         refs = refs[2:]
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), \
         kb_ref, ab_ref, idx = \
         _unpack_in_refs(refs, 6, use_kbias, use_abias)
-    dk_ref, dv_ref, dk_acc, dv_acc = refs[idx:idx + 4]
+    fused = num_k_blocks is not None
+    dk_ref, dv_ref = refs[idx:idx + 2]
+    dq_ref = refs[idx + 2] if fused else None
+    dk_acc, dv_acc, *rest = refs[idx + (3 if fused else 2):]
+    dq_acc = rest[0] if rest else None        # only past one k block
     ki = pl.program_id(1)
     qj = pl.program_id(2)
 
@@ -827,6 +893,11 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if dq_acc is not None:
+        @pl.when(ki == 0)
+        def _():
+            dq_acc[qj] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
     if use_lut:
         h_idx = pl.program_id(0) % n_heads
@@ -855,31 +926,43 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
 
     def accumulate(q, k, v, do, lse, delta, dk, dv, scores):
         p, ds = _p_and_ds(scores(q, k), v, do, lse, delta, sm_scale)
-        # dK += dS^T Q ; dV += P^T dO
+        ds = ds.astype(q.dtype)
+        # dK += dS^T Q ; dV += P^T dO ; and the visit's share of dQ, dS K
         contract_rows = (((0,), (0,)), ((), ()))
-        return (dk + jax.lax.dot_general(
-                    ds.astype(q.dtype), q, contract_rows,
-                    preferred_element_type=jnp.float32),
-                dv + jax.lax.dot_general(
-                    p.astype(do.dtype), do, contract_rows,
-                    preferred_element_type=jnp.float32))
+        dk = dk + jax.lax.dot_general(
+            ds, q, contract_rows, preferred_element_type=jnp.float32)
+        dv = dv + jax.lax.dot_general(
+            p.astype(do.dtype), do, contract_rows,
+            preferred_element_type=jnp.float32)
+        dq = jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) if fused else None
+        return dk, dv, dq
+
+    def add_dq(rows, dq):
+        if dq_acc is not None:
+            dq_acc[qj, rows] += dq
+        elif fused:       # the head's one k block: all of these rows' keys
+            dq_ref[0, rows] = dq.astype(dq_ref.dtype)
 
     def whole_block(scores):
         def visit():
-            dk_acc[:], dv_acc[:] = accumulate(
+            dk_acc[:], dv_acc[:], dq = accumulate(
                 q_ref[0], k_ref[0], v_ref[0], do_ref[0],
                 lse_ref[0][:, :1],           # (bq, 1) — lane-broadcast stat
                 delta_ref[0][:, :1], dk_acc[:], dv_acc[:], scores)
+            add_dq(slice(None), dq)
         return visit
 
     def diagonal():
         plan = _tile_plan(block_q, block_k, tile, True)
         for rows, cols in _strips(plan, tile):
-            dk_acc[cols], dv_acc[cols] = accumulate(
+            dk_acc[cols], dv_acc[cols], dq = accumulate(
                 q_ref[0, rows], k_ref[0, cols], v_ref[0, cols],
                 do_ref[0, rows], lse_ref[0, rows][:, :1],
                 delta_ref[0, rows][:, :1], dk_acc[cols], dv_acc[cols],
                 lambda q, k: _strip_scores(q, k, sm_scale, tile))
+            add_dq(rows, dq)
 
     if tile is None:
         pl.when(should_compute)(whole_block(masked_scores))
@@ -893,6 +976,11 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    if dq_acc is not None:
+        @pl.when(ki == num_k_blocks - 1)
+        def _():
+            dq_ref[0] = dq_acc[qj].astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, num_k_blocks,
@@ -1010,12 +1098,15 @@ def _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
     lse, delta = bcast(lse), bcast(delta)
 
     H = n_heads or 1
-    tile = _causal_tile(
-        causal, block_q, block_k, d,
-        not use_lut and k_bias is None and attn_bias is None)
+    dense = not use_lut and k_bias is None and attn_bias is None
+    tile = _causal_tile(causal, block_q, block_k, d, dense)
+    fused = _fused_backward(dense, nq, nk, block_q, d)
+    call_key = (BH, d, nq, nk, block_q, block_k, causal, tile)
+    _bwd_forms[call_key + (use_lut, k_bias is None, attn_bias is None)] = (
+        "bwd_fused" if fused else "bwd_split")
     if not use_lut:
-        for kind in ("dkdv", "dq"):
-            _record_tiles(kind, BH, d, nq, nk, block_q, block_k, causal, tile)
+        for kind in ("bwd",) if fused else ("dkdv", "dq"):
+            _record_tiles(kind, *call_key)
     if use_lut:
         # dK/dV grid: (BH, nk, live-q); the visited q block is qmap[h, j, i]
         qrow_idx = lambda b, j, i, qm, ql: (b, qm[jax.lax.rem(b, H), j, i], 0)
@@ -1054,7 +1145,8 @@ def _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
         _bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_q_blocks=n_inner_q,
         seq_len=T, n_heads=H, use_kbias=k_bias is not None,
-        use_abias=attn_bias is not None, use_lut=use_lut, tile=tile)
+        use_abias=attn_bias is not None, use_lut=use_lut, tile=tile,
+        num_k_blocks=nk if fused else None)
     dkdv_out_specs = [
         pl.BlockSpec((1, block_k, d), kcol_idx),
         pl.BlockSpec((1, block_k, d), kcol_idx),
@@ -1067,10 +1159,24 @@ def _bwd(sm_scale, causal, block_q, block_k, residuals, dout,
         pltpu.VMEM((block_k, d), jnp.float32),
         pltpu.VMEM((block_k, d), jnp.float32),
     ]
+    if fused:
+        # q block i's dq is whole at its visit under the LAST k block and
+        # is written there; until then the block index rests at 0, so no
+        # half-summed block travels to HBM
+        dkdv_out_specs.append(pl.BlockSpec(
+            (1, block_q, d),
+            lambda b, j, i: (b, jnp.where(j == nk - 1, i, 0), 0)))
+        dkdv_out_shape.append(jax.ShapeDtypeStruct((BH, Tp, d), q.dtype))
+        if nk > 1:
+            dkdv_scratch.append(pltpu.VMEM((nq, block_q, d), jnp.float32))
     call = _pallas(dkdv_kernel, grid=(BH, nk, n_inner_q),
                    in_specs=dkdv_specs, out_specs=dkdv_out_specs,
                    out_shape=dkdv_out_shape, scratch=dkdv_scratch,
-                   num_prefetch=2 if use_lut else 0)
+                   num_prefetch=2 if use_lut else 0,
+                   carried_axes=2 if fused else 1)
+    if fused:
+        dk, dv, dq = call(*dkdv_args)
+        return dq[:, :T], dk[:, :T], dv[:, :T]
     dk, dv = (call(qmap, qlen, *dkdv_args) if use_lut
               else call(*dkdv_args))
 
@@ -1180,7 +1286,8 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None,
     to the backward.  Under ``jax.checkpoint`` with a
     ``save_only_these_names`` policy that lists it they are saved
     (``2·H·d + 4·H`` bytes a token in bfloat16) and the rematerialised
-    backward runs two kernels a call, dK/dV and dQ, and no second forward;
+    backward runs one kernel a call (two, dK/dV and dQ, past the fused
+    backward's budget: ``_fused_backward``) and no second forward;
     with any other policy, or ``None`` here, the name changes nothing.
     Not carried by the biased variants.
     """

@@ -755,12 +755,14 @@ class DeepSpeedEngine:
         the partitioner moves activations where ZeRO-3 should move
         weights; and ``custom_calls``, the Pallas kernels one call runs
         by the scope they sit in (a flash block under a remat policy that
-        saves ``attn_out`` reads 3 x layers under ``attention``, 4 x
-        under any other).  Beside them ``flash_tiles``, read off the trace
-        and not off the HLO: the tile plans of the step's distinct dense
-        flash calls (a scanned layer's three count once), as tiles visited
-        / masked / in the square; visited < square where the causal tile
-        walk engages."""
+        saves ``attn_out`` reads 2 x layers under ``attention``, forward
+        and fused backward, 3 x under any other).  Beside them
+        ``flash_tiles``, read off the trace and not off the HLO: the tile
+        plans of the step's distinct dense flash calls (a scanned layer's
+        two count once), as tiles visited / masked / in the square;
+        visited < square where the causal tile walk engages; and the
+        distinct backward calls by form, ``bwd_fused`` / ``bwd_split``
+        (a step that fell back to two backward kernels shows here)."""
         from ..analysis.comms import step_collectives
         from ..analysis.jaxpr_audit import census_from_hlo_text, \
             custom_calls_from_hlo_text

@@ -190,13 +190,23 @@ def _weighted(fn, w):
     (64, 1024, (512, 256), jnp.float32, True),     # block_q != block_k
     (64, 1024, (256, 512), jnp.float32, True),
     (64, 96, None, jnp.float32, True),        # T below one sub-tile
+    (64, 384, None, jnp.float32, True),       # no multiple of the 256-tile
     (64, 512, None, jnp.float32, False),
+    (64, 1024, None, jnp.float32, False),     # one block, no mask to skip
+    (128, 1024, (512, 512), jnp.float32, False),   # several blocks, dQ summed
+    (128, 1280, (512, 512), jnp.float32, False),   # and padded keys masked
 ], ids=lambda v: getattr(v, "__name__", None) or str(v).replace(" ", ""))
 def test_walk_forward_and_backward_match_reference(d, T, blocks, dtype,
                                                    causal):
     """Forward and gradients against the dense oracle at the shapes the
-    tile walk engages at (and at those it must leave alone)."""
+    tile walk engages at (and at those it must leave alone).  Every case is
+    a dense call whose dQ accumulator, where it needs one, is inside its
+    budget: the backward is the FUSED kernel (one block a head: dQ written
+    a strip at a time; several: summed k block by k block)."""
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        reset_tile_census, tile_census)
     bq, bk = blocks or (None, None)
+    reset_tile_census()
     q, k, v = make_qkv(B=1, T=T, H=2, d=d, dtype=dtype, seed=T + d)
     w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
     flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
@@ -214,16 +224,21 @@ def test_walk_forward_and_backward_match_reference(d, T, blocks, dtype,
         np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(f32(b)),
                                    atol=tol, rtol=tol,
                                    err_msg=f"d{name} mismatch")
+    census = tile_census()
+    assert (census["bwd_fused"], census["bwd_split"]) == (1, 0)
 
 
-@pytest.mark.parametrize("T,blocks", [(1024, None), (1536, (1024, 1024)),
-                                      (1024, (512, 256))],
-                         ids=["one_block", "padded_two_blocks", "bq_ne_bk"])
-def test_walk_with_lse_and_a_nonzero_dlse(T, blocks):
+@pytest.mark.parametrize("T,blocks,d", [
+    (1024, None, 64), (1536, (1024, 1024), 64), (1024, (512, 256), 64),
+    (1024, (512, 512), 128), (384, None, 64)],
+    ids=["one_block", "padded_two_blocks", "bq_ne_bk", "hd128_two_blocks",
+         "no_tile_multiple"])
+def test_walk_with_lse_and_a_nonzero_dlse(T, blocks, d):
     """``flash_attention_with_lse`` shares the kernels: both outputs and
-    the gradient of a loss that reads BOTH (so ``dlse`` is not zero)."""
+    the gradient of a loss that reads BOTH (so ``dlse`` is not zero; it
+    enters the fused backward through ``delta``, as it entered the two)."""
     bq, bk = blocks or (None, None)
-    q, k, v = make_qkv(B=1, T=T, H=2, d=64, seed=T)
+    q, k, v = make_qkv(B=1, T=T, H=2, d=d, seed=T)
     w = jax.random.normal(jax.random.PRNGKey(3), q.shape, jnp.float32)
 
     def ref_pair(q, k, v):
@@ -272,6 +287,8 @@ def _trivial_plan_case(name):
     fn = {
         "non_causal": lambda q, k, v: flash_attention(
             q, k, v, causal=False, block_q=64, block_k=64),
+        "causal_blocks": lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=32, block_k=32),
         "lut": lambda q, k, v: sparse_flash_attention(q, k, v, bird),
         "banded": lambda q, k, v: sparse_flash_attention(q, k, v, band),
         "merged": lambda q, k, v: sparse_flash_attention(
@@ -311,6 +328,54 @@ def test_trivial_plan_equals_the_parent_to_the_bit(name):
     arrays = _trivial_plan_case(name)
     assert all(np.isfinite(np.asarray(a)).all() for a in arrays)
     assert _digest(arrays) == PARENT_DIGESTS[name]
+
+
+# dense calls of several k blocks, so dQ is a sum over visits; taken from
+# the tree before PR 41 (commit c34cda0), whose backward was two kernels
+DENSE_PARENT_DIGESTS = {
+    "non_causal": PARENT_DIGESTS["non_causal"],
+    "causal_blocks": "085c7df16b0eefce",
+}
+
+
+@pytest.mark.parametrize("form", ["bwd_fused", "bwd_split"])
+@pytest.mark.parametrize("name", sorted(DENSE_PARENT_DIGESTS))
+def test_dense_backward_equals_the_parent_in_either_form(name, form,
+                                                         monkeypatch):
+    """The fused backward sums dQ k block by k block in ascending order, as
+    the dQ kernel did, so where a diagonal block is one tile it equals the
+    two kernels to the bit.  And a call whose accumulator is over the
+    budget (shrunk here to one byte under what T 128 needs: 128 rows x 128
+    lanes x 4 bytes) takes the two kernels, unchanged."""
+    import importlib
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.flash_attention")
+    if form == "bwd_split":
+        monkeypatch.setattr(fa, "_FUSED_BWD_DQ_ACC_BYTES", 128 * 128 * 4 - 1)
+    fa.reset_tile_census()
+    arrays = _trivial_plan_case(name)
+    census = fa.tile_census()
+    other = {"bwd_fused": "bwd_split", "bwd_split": "bwd_fused"}[form]
+    assert (census[form], census[other]) == (1, 0)
+    assert _digest(arrays) == DENSE_PARENT_DIGESTS[name]
+
+
+@pytest.mark.parametrize("dense,T,block,d,fused", [
+    (True, 1024, 1024, 64, True),         # train_z1: one block, no accumulator
+    (True, 2048, 512, 128, True),         # train_z3_x4: 1 MB
+    (True, 4096, 1024, 64, True),         # 2 MB: a 64-wide row fills 128 lanes
+    (True, 8192, 512, 128, True),         # 4 MB, the budget
+    (True, 8192 + 512, 512, 128, False),  # over it: two kernels
+    (True, 16384, 1024, 64, False),
+    (False, 1024, 1024, 64, False),       # LUT, banded, merged, biased
+    (False, 2048, 512, 128, False),
+])
+def test_fused_backward_choice(dense, T, block, d, fused):
+    """One kernel or two is read off the call's own shapes: the dense plan
+    and an fp32 dQ accumulator over the padded sequence inside its budget."""
+    from deepspeed_tpu.ops.transformer.flash_attention import _fused_backward
+    n = T // block
+    assert _fused_backward(dense, n, n, block, d) is fused
 
 
 # ------------------------------------------------- residuals under remat
@@ -369,16 +434,18 @@ def _pallas_calls(fn, *args):
 @pytest.mark.parametrize("sharded", [False, True],
                          ids=["one_device", "per_device_x4"])
 @pytest.mark.parametrize("policy,residual_name,calls", [
-    ("names:attn_out,mlp_fc", "attn_out", 3),
-    ("names:attn_out,mlp_fc", None, 4),
-    (None, "attn_out", 4),
-    ("dots", "attn_out", 4),
+    ("names:attn_out,mlp_fc", "attn_out", 2),
+    ("names:attn_out,mlp_fc", None, 3),
+    (None, "attn_out", 3),
+    ("dots", "attn_out", 3),
 ], ids=["named_and_saved", "unnamed", "remat_all", "dots"])
 def test_remat_backward_kernel_calls(policy, residual_name, calls, sharded):
-    """Forward, dK/dV, dQ: three kernels where the policy saves the named
-    residuals; a fourth, the forward again, wherever it does not.  The same
-    with the kernel inside a four-device ``shard_map``, as a ZeRO-3 step
-    runs it."""
+    """Forward and ONE backward: two kernels where the policy saves the
+    named residuals; a third, the forward again, wherever it does not.
+    (Three and four until PR 41: the backward was dK/dV and dQ, each
+    computing the scores, the exp and ``dO·Vᵀ`` for itself; a dense call
+    now gets all three gradients from one pass.)  The same with the kernel
+    inside a four-device ``shard_map``, as a ZeRO-3 step runs it."""
     import contextlib
     from deepspeed_tpu.parallel import mesh as M
     ctx = contextlib.nullcontext()
@@ -452,17 +519,25 @@ def v5e():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.mark.parametrize("kernels,calls", [("forward", 1), ("backward", 2)])
-@pytest.mark.parametrize("BH,T,d", [(80, 1024, 64), (16, 2048, 128)],
-                         ids=["train_z1", "train_z3_x4"])
+@pytest.mark.parametrize("kernels", ["forward", "backward"])
+@pytest.mark.parametrize("BH,T,d,bwd_calls", [
+    (80, 1024, 64, 1), (16, 2048, 128, 1), (16, 4096, 64, 1),
+    (4, 8192, 128, 1), (2, 16384, 128, 2)],
+    ids=["train_z1", "train_z3_x4", "T4096_hd64", "T8192_at_the_budget",
+         "T16384_over_it"])
 def test_causal_kernels_compile_for_a_v5e(v5e, monkeypatch, BH, T, d,
-                                          kernels, calls):
-    """The three kernels at both training cells' shapes (bf16, the blocks
+                                          bwd_calls, kernels):
+    """The kernels at both training cells' shapes (bf16, the blocks
     ``_auto_blocks`` gives), through Mosaic and XLA:TPU for a described
-    v5e: one custom call each.  The tile walk's static slices, its lane
-    concatenation under the mask and the stateless forward's writes of
-    ``out`` and ``lse`` a strip at a time are what the interpreter cannot
-    refuse and Mosaic can."""
+    v5e: one custom call each, forward and fused backward.  The tile
+    walk's static slices, its lane concatenation under the mask, the
+    stateless forward's writes of ``out`` and ``lse`` a strip at a time and
+    the fused backward's of ``dq`` are what the interpreter cannot refuse
+    and Mosaic can; so is the fused backward's VMEM (six operands and three
+    outputs double-buffered, the dK and dV accumulators and, past one k
+    block, dQ's over the whole sequence) against a v5e's scoped limit, up
+    to the longest sequence whose accumulator is inside the budget.  Past
+    it the backward compiles as the two kernels it was."""
     import importlib
     from jax.sharding import SingleDeviceSharding
     fa = importlib.import_module(
@@ -479,26 +554,32 @@ def test_causal_kernels_compile_for_a_v5e(v5e, monkeypatch, BH, T, d,
         fn = lambda q, k, v, out, lse, do: fa._bwd(
             scale, True, None, None, (q, k, v, out, lse), do)
         args = (x, x, x, x, lse, x)
+    calls = 1 if kernels == "forward" else bwd_calls
     fa.reset_tile_census()
     text = jax.jit(fn).trace(*args).lower(
         lowering_platforms=("tpu",)).compile().as_text()
     assert text.count("tpu_custom_call") == calls
     # 256-tiles: T 1024 is one block of 10 tiles in 16; T 2048 is four
-    # 512-blocks a side, the diagonal ones 3 tiles in 4: 36 in 64
-    visited, square = {1024: (10, 16), 2048: (36, 64)}[T]
+    # 512-blocks a side, the diagonal ones 3 tiles in 4 and the six under
+    # them whole: 36 in 64
+    block = 512 if d > 64 else 1024
+    edge, n = T // block, block // 256    # blocks a side, tiles a block's side
+    visited = edge * n * (n + 1) // 2 + edge * (edge - 1) // 2 * n * n
     assert fa.tile_census() == {
         "visited": calls * BH * visited,
         "masked": calls * BH * T // 256,
-        "square": calls * BH * square}
+        "square": calls * BH * (T // 256) ** 2,
+        "bwd_fused": int(kernels == "backward" and bwd_calls == 1),
+        "bwd_split": int(kernels == "backward" and bwd_calls == 2)}
 
 
 N_LAYER = 3
 
 
 @pytest.mark.parametrize("policy,axes,stage,per_layer", [
-    ("names:attn_out,mlp_fc", {"data": 1}, 1, 3),
-    ("names:mlp_fc", {"data": 1}, 1, 4),
-    ("names:attn_out,mlp_fc", {"data": 1, "fsdp": 4}, 3, 3),
+    ("names:attn_out,mlp_fc", {"data": 1}, 1, 2),
+    ("names:mlp_fc", {"data": 1}, 1, 3),
+    ("names:attn_out,mlp_fc", {"data": 1, "fsdp": 4}, 3, 2),
 ], ids=["z1_saved", "z1_not_saved", "z3_x4_saved"])
 def test_compile_report_counts_the_flash_calls_of_a_step(
         v5e, monkeypatch, tmp_path, policy, axes, stage, per_layer):
@@ -556,10 +637,12 @@ def test_compile_report_counts_the_flash_calls_of_a_step(
     report = engine.compile_report()
     assert report["custom_calls"] == {
         "DeepSpeedEngine.train_step": {"attention": per_layer * N_LAYER}}
-    # forward, dK/dV and dQ of the scanned layer, each counted once: 2
-    # sequences x 2 heads a device, a 512-block of 3 tiles in 4, 2 masked
+    # the forward and the ONE backward of the scanned layer, each counted
+    # once: 2 sequences x 2 heads a device, a 512-block of 3 tiles in 4, 2
+    # masked; and the backward's form, which no HLO shows on a CPU
     assert report["flash_tiles"] == {"DeepSpeedEngine.train_step": {
-        "visited": 3 * 4 * 3, "masked": 3 * 4 * 2, "square": 3 * 4 * 4}}
+        "visited": 2 * 4 * 3, "masked": 2 * 4 * 2, "square": 2 * 4 * 4,
+        "bwd_fused": 1, "bwd_split": 0}}
     assert not {"custom_calls", "flash_tiles"} & set(
         report["collectives"]["DeepSpeedEngine.train_step"])
     built = [row for row in engine._spans.rows("compile.build")
