@@ -644,6 +644,11 @@ class ServingEngine:
             alloc.attrs = {"bytes": pk.pool_bytes(self.pool)}
         self._recurrent_bytes = (inner.recurrent_state_bytes(self.pool)
                                  if self._recurrent else 0)
+        # what a decode step's state update moves for one live slot, where
+        # the model says (a step's span carries it times the slots seated)
+        self._state_step_bytes = (inner.state_step_bytes()
+                                  if hasattr(inner, "state_step_bytes")
+                                  else None)
         if pk.is_latent_pool(self.pool):
             self._refuse_for_latent_pool(config)
         # what the model's layers count in a dispatch (an expert layer's
@@ -1793,6 +1798,8 @@ class ServingEngine:
                 # what the recurrence walks and what it must not take in;
                 # the dispatch below writes the slot's recurrent rows whole
                 prefill.attrs.update(scan_tokens=T, pad_tokens=bucket - T)
+                if hasattr(self.model, "prefill_attrs"):
+                    prefill.attrs.update(self.model.prefill_attrs(T))
                 self._state_seats += 1
             toks = np.zeros((1, min(bucket, self.max_seq)), np.int32)
             toks[0, :T] = req.tokens
@@ -2896,6 +2903,14 @@ class ServingEngine:
                "blocks_free": self.allocator.free_blocks,
                "kv_tokens": int(self._lengths.sum()),
                "waits_for_blocks": waits}
+        if self._recurrent:
+            # the slots whose recurrent rows this dispatch advances (and
+            # those it leaves), and the bytes of state that takes (read
+            # and written)
+            out["seated_slots"] = len(active)
+            out["free_slots"] = self.config.batch_slots - len(active)
+            if self._state_step_bytes is not None:
+                out["state_bytes"] = len(active) * self._state_step_bytes
         if self.ring:
             # the second kind, and WHICH kind the head waits for; the tokens
             # a window layer can still read (each stream's last

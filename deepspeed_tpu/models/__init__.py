@@ -1,7 +1,9 @@
 """Model zoo: GPT-2 family (flagship), BERT encoder, MoE GPT, GPT-J/NeoX,
 Jamba (Mamba + attention hybrid), Ouro (one stack of layers looped),
 DeepSeek-V2 (latent attention, routed and shared experts), AFMoE (window and
-global attention layers, gated grouped-query attention, sigmoid routing)."""
+global attention layers, gated grouped-query attention, sigmoid routing),
+Nemotron-H (Mamba-2 mixers, attention layers and non-gated expert layers, one
+of them a layer)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -35,6 +37,9 @@ def build(name, **overrides):
         if name.startswith("afmoe"):
             from .afmoe import Afmoe
             return Afmoe(preset=name, **overrides)
+        if name.startswith("nemotron-h"):
+            from .nemotron_h import NemotronH
+            return NemotronH(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

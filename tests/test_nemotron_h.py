@@ -1,0 +1,547 @@
+"""Nemotron-H (models/nemotron_h.py, NVIDIA Nemotron-3-Nano): Mamba-2 mixers
+whose state lives in the serving engine's recurrent rows, attention layers
+with no position over the paged pool, non-gated relu2 experts of which a chip
+holds a share; a layer is ONE of the three.  Every number is held against the
+benchmark's plain reference (``benchmark/reference/nemotron_h.py``), which
+shares no code with the program and knows no cache, no chunk and no kernel:
+its recurrence is a ``lax.scan`` over tokens.
+
+Tiny model at widths that keep the ratios: pattern ``MEM*EM`` (all three
+kinds), hidden 64, 4 Mamba heads of 8 in 2 groups over a state of 16, chunks
+of 8 that a 40-token stream crosses several times, 4 query heads over 2 K/V
+heads of 16, 16 experts (top-4) of width 32 and a shared one of 48; seeded
+weights, float32 (a wrong hand-off of state stands orders above the
+rounding), the projections that feed the attention scores and the recurrence
+enlarged (``sharp``).
+"""
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.inference import Request, ServingEngine
+from deepspeed_tpu.models import build, nemotron_h
+from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.ops import mamba2 as m2
+from benchmark import control_nemotron
+from benchmark.reference import nemotron_h as reference
+
+PRESET = nemotron_h.PRESETS["nemotron-h-tiny"]
+TOL = 1e-3          # of the largest reference logit; float32 reads ~1e-6
+
+
+def tiny(dtype=jnp.float32, **overrides):
+    return build("nemotron-h-tiny", dtype=dtype,
+                 **{"max_position_embeddings": 64, **overrides})
+
+
+def ref_cfg(model, **extra):
+    """The reference's configuration (published key names) of ``model``."""
+    c = model.config
+    keys = ("hybrid_override_pattern", "mamba_num_heads", "mamba_head_dim",
+            "n_groups", "ssm_state_size", "conv_kernel",
+            "layer_norm_epsilon", "num_attention_heads",
+            "num_key_value_heads", "head_dim", "num_experts_per_tok",
+            "norm_topk_prob", "routed_scaling_factor")
+    return {**{k: getattr(c, k) for k in keys}, **extra}
+
+
+def sharp(params):
+    """q and k enlarged: scores of order 1 and a softmax far from uniform (at
+    the initialisation's 0.02 attention is nearly an average and a position
+    that should not be there hardly moves a logit).  ``in_proj`` enlarged to
+    what the published width gives it (0.02 sqrt(2688) is 1; 0.02 sqrt(64)
+    a sixth): x, B and C of order 1, or ``S C`` is a ten-thousandth of ``D
+    x`` and a state left behind hardly moves a logit."""
+    attn, mamba = dict(params["attn"]), dict(params["mamba"])
+    attn.update(q_w=8.0 * attn["q_w"], k_w=8.0 * attn["k_w"])
+    mamba.update(in_w=6.0 * mamba["in_w"])
+    return dict(params, attn=attn, mamba=mamba)
+
+
+@pytest.fixture(scope="module")
+def model_params():
+    m = tiny()
+    return m, sharp(m.init(jax.random.PRNGKey(3)))
+
+
+def tokens(seed, *shape, hi=PRESET["vocab_size"]):
+    return np.asarray(jax.random.randint(jax.random.PRNGKey(seed), shape, 0,
+                                         hi), np.int32)
+
+
+def rel_err(got, ref):
+    got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
+    return float(np.abs(got - ref).max() / np.abs(ref).max())
+
+
+# ------------------------------------------------ (a) forward, loss, refusals
+def test_layer_kinds_and_parameter_count(model_params):
+    m, params = model_params
+    assert m.layers == [("M", 0), ("E", 0), ("M", 1), ("*", 0), ("E", 1),
+                        ("M", 2)]
+    n = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    assert n == m.num_params()
+    big = nemotron_h.NemotronHConfig()          # the published defaults
+    assert (big.count("M"), big.count("E"), big.count("*")) == (23, 23, 6)
+    assert big.d_inner == 4096 and big.conv_dim == 6144
+    assert big.state_bytes_per_layer == 2_097_152
+    assert nemotron_h.NemotronH(big).num_params() == 31_577_940_288
+
+
+@pytest.mark.parametrize("position", [0, 17, 39])
+def test_forward_logits_match_the_reference(model_params, position):
+    m, params = model_params
+    toks = tokens(1, 2, 40)
+    got = m.apply(params, toks)[:, position]
+    ref = reference.logits_at(ref_cfg(m), params, jnp.asarray(toks),
+                              jnp.full((2,), position))
+    assert rel_err(got, ref) < 1e-4
+
+
+def test_loss_matches_the_reference(model_params):
+    m, params = model_params
+    batch = jnp.asarray(tokens(2, 2, 33))
+    got = m.loss(params, batch, None)
+    ref = reference.loss(ref_cfg(m), params, batch)
+    assert abs(float(got) - float(ref)) < 1e-5 * abs(float(ref))
+
+
+def test_cached_decoding_matches_the_full_forward(model_params):
+    m, params = model_params
+    toks = jnp.asarray(tokens(4, 2, 30))
+    full = m.apply(params, toks)
+    cache = m.init_cache(2, 32)
+    got, cache = m.apply_with_cache(params, toks[:, :21], cache)
+    assert rel_err(got, full[:, :21]) < 1e-4
+    for t in range(21, 30):
+        step, cache = m.apply_with_cache(params, toks[:, t:t + 1], cache)
+        assert rel_err(step[:, 0], full[:, t]) < 1e-4
+    eng = ds.init_inference(m, params=params, dtype=jnp.float32)
+    out = eng.generate(np.asarray(toks[:, :10]), max_new_tokens=4)
+    assert out.shape == (2, 14)
+
+
+@pytest.mark.parametrize("overrides, named", [
+    (dict(hybrid_override_pattern="ME-*EM"), "hybrid_override_pattern"),
+    (dict(mlp_hidden_act="gelu"), "gelu"),
+    (dict(n_group=2), "n_group"),
+    (dict(mamba_hidden_act="relu"), "mamba_hidden_act"),
+])
+def test_what_the_file_does_not_compute_is_refused_by_name(overrides, named):
+    with pytest.raises(ValueError, match=named):
+        tiny(**overrides)
+
+
+# --------------------------------------------------- (b) the state-space ops
+def scan_operands(T, Bt=2, H=4, P=8, G=2, N=16, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    f32 = jnp.float32
+    return (jax.random.normal(k[0], (Bt, T, H, P), f32),
+            jax.nn.softplus(jax.random.normal(k[1], (Bt, T, H), f32)) * 0.3,
+            -jnp.exp(jax.random.uniform(k[2], (H,), f32, 0.0, 2.5)),
+            jax.random.normal(k[3], (Bt, T, G, N), f32),
+            jax.random.normal(k[4], (Bt, T, G, N), f32),
+            jax.random.normal(k[5], (H,), f32),
+            jax.random.normal(k[6], (Bt, H, P, N), f32))
+
+
+def recurrence(x, dt, A, B, C, D, h0):
+    """Token by token, with the one-token update."""
+    S, ys = h0, []
+    for t in range(x.shape[1]):
+        y, S = m2.ssm_step_jnp(x[:, t], dt[:, t], A, B[:, t], C[:, t], D, S)
+        ys.append(y)
+    return jnp.stack(ys, 1), S
+
+
+@pytest.mark.parametrize("T, t_real, form", [
+    (16, 16, "jnp"), (32, 32, "kernel"), (37, 37, "jnp"), (37, 37, "kernel"),
+    (5, 5, "kernel"), (48, 41, "jnp"), (48, 41, "kernel"), (24, 1, "kernel"),
+    (40, 40, "initial state")])
+def test_chunked_scan_matches_the_token_by_token_recurrence(T, t_real, form):
+    """Chunks of 16: lengths that are and are not multiples of the chunk, a
+    padded tail frozen by ``dt = 0`` (the state handed back is the state
+    after token ``t_real - 1``), and a scan continued from a state.  The
+    kernel runs interpreted."""
+    x, dt, A, B, C, D, h0 = scan_operands(T)
+    masked = m2.mask_delta(dt, t_real)
+    if form == "initial state":
+        y, S = m2.ssd_scan(x, masked, A, B, C, D, h0=h0, chunk=16)
+    else:
+        h0 = jnp.zeros_like(h0)
+        scan = m2.ssd_scan_jnp if form == "jnp" else (
+            lambda *a, **kw: m2.ssd_scan_kernel(*a, **kw, interpret=True))
+        y, S = scan(x, masked, A, B, C, D, chunk=16)
+    cut = lambda a: a[:, :t_real]
+    y_ref, S_ref = recurrence(cut(x), cut(dt), A, cut(B), cut(C), D, h0)
+    assert float(jnp.abs(y[:, :t_real] - y_ref).max()) < 2e-5
+    assert float(jnp.abs(S - S_ref).max()) < 1e-5
+
+
+def test_scan_dispatch_refuses_a_kernel_with_an_initial_state():
+    x, dt, A, B, C, D, h0 = scan_operands(8)
+    with pytest.raises(AssertionError, match="zero state"):
+        m2.ssd_scan(x, dt, A, B, C, D, h0=h0, impl="kernel")
+
+
+def test_one_token_update_in_place_and_a_dead_slot_untouched():
+    """The kernel (interpreted) against the ``jax.numpy`` form over layer 1
+    of a three-layer state: the other layers and the dead slots' rows keep
+    every bit."""
+    x, dt, A, B, C, D, h0 = scan_operands(1, Bt=5)
+    ssm = jnp.stack([0.5 * h0, h0, 2.0 * h0])
+    live = jnp.asarray([True, False, True, True, False])
+    args = (x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], D)
+    y_j, s_j = m2.ssm_step(ssm, 1, *args, active=live, impl="jnp")
+    y_k, s_k = m2.ssm_step(ssm, 1, *args, active=live, impl="kernel",
+                           interpret=True)
+    assert float(jnp.abs(y_j - y_k)[live].max()) < 1e-5
+    assert float(jnp.abs(s_j - s_k).max()) < 1e-6
+    for s in (s_j, s_k):
+        assert bool((s[1][~live] == ssm[1][~live]).all())
+        assert bool((s[0] == ssm[0]).all()) and bool((s[2] == ssm[2]).all())
+        assert float(jnp.abs(s[1][live] - ssm[1][live]).max()) > 0.0
+
+
+def test_grouped_gated_norm_is_not_one_mean_square():
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    y = jax.random.normal(k[0], (3, 32)) * jnp.repeat(
+        jnp.asarray([0.1, 1.0, 3.0, 9.0]), 8)      # groups of unlike size
+    z, w = jax.random.normal(k[1], (3, 32)), 1.0 + jax.random.normal(
+        k[2], (32,)) * 0.1
+    grouped = m2.gated_group_norm(y, z, w, 4, 1e-5)
+    g = (y * jax.nn.silu(z)).reshape(3, 4, 8)
+    want = (g / jnp.sqrt((g * g).mean(-1, keepdims=True) + 1e-5)
+            ).reshape(3, 32) * w
+    assert float(jnp.abs(grouped - want).max()) < 1e-5
+    whole = m2.gated_group_norm(y, z, w, 1, 1e-5)
+    assert float(jnp.abs(grouped - whole).max()) > 0.5
+
+
+# ------------------------------------------------------- (c) the expert layer
+def parent_held_experts(x, experts, weights, gate_w, up_w, down_w, first,
+                        layer=None):
+    """``moe/dropless.py::held_experts`` as it stood before the non-gated
+    form came (PR 41's tree), operation for operation."""
+    N, k = experts.shape
+    count = gate_w.shape[-3]
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    local = jnp.where(held, local, count)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.zeros((count,), jnp.int32).at[local].add(1, mode="drop")
+    if layer is not None:
+        n = gate_w.shape[0] * count
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n,), jnp.int32), sizes, (layer * count,))
+        gate_w, up_w, down_w = (w.reshape((n,) + w.shape[2:])
+                                for w in (gate_w, up_w, down_w))
+    rows = x[order // k]
+    dt = x.dtype
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, gate_w.astype(dt), sizes)) \
+        * jax.lax.ragged_dot(rows, up_w.astype(dt), sizes)
+    out = jax.lax.ragged_dot(h, down_w.astype(dt), sizes)
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    out = out[back].reshape(N, k, -1).astype(jnp.float32)
+    w = jnp.where(held.reshape(N, k), weights, 0.0)[..., None]
+    return jnp.where(w != 0, out * w, 0.0).sum(axis=1).astype(dt)
+
+
+def expert_operands(dtype, layers=None, N=24, D=16, F=12, E=8, k=3, seed=0):
+    key = jax.random.split(jax.random.PRNGKey(seed), 6)
+    lead = () if layers is None else (layers,)
+    x = jax.random.normal(key[0], (N, D)).astype(dtype)
+    logits = jax.random.normal(key[1], (N, E))
+    w = lambda kk, *shape: jax.random.normal(kk, lead + shape) * 0.3
+    return (x, logits, w(key[2], 4, D, F), w(key[3], 4, D, F),
+            w(key[4], 4, F, D))
+
+
+@pytest.mark.parametrize("family, route_kw, layer, dtype", [
+    ("deepseek_v2", dict(topk_method="group_limited_greedy", n_group=4,
+                         topk_group=2, routed_scaling_factor=16.0), None,
+     jnp.float32),
+    ("deepseek_v2", dict(topk_method="group_limited_greedy", n_group=4,
+                         topk_group=2, routed_scaling_factor=16.0), 1,
+     jnp.bfloat16),
+    ("afmoe", dict(scoring_func="sigmoid", norm_topk_prob=True,
+                   routed_scaling_factor=2.448, scale_normed=True), 2,
+     jnp.bfloat16),
+    ("afmoe", dict(scoring_func="sigmoid", norm_topk_prob=True,
+                   routed_scaling_factor=2.448, scale_normed=True), None,
+     jnp.float32),
+])
+def test_the_gated_path_is_what_it_was_bit_for_bit(family, route_kw, layer,
+                                                   dtype):
+    """The held experts 2..5 of 8, as ``deepseek_v2`` and ``afmoe`` call the
+    layer: the same result as the parent's function, to the bit, and the
+    same operations in the same order (one jaxpr)."""
+    x, logits, gate, up, down = expert_operands(
+        dtype, layers=None if layer is None else 3)
+    experts, weights = dropless.route(logits, 3, **route_kw)
+    args = (x, experts, weights, gate, up, down, 2)
+    now = dropless.held_experts(*args, layer=layer)
+    was = parent_held_experts(*args, layer=layer)
+    assert now.dtype == was.dtype and bool((now == was).all())
+    as_text = lambda fn: str(jax.make_jaxpr(
+        lambda *a: fn(*a, layer=layer))(*args))
+    assert as_text(dropless.held_experts) == as_text(parent_held_experts)
+
+
+@pytest.mark.parametrize("layer", [None, 1])
+def test_non_gated_experts_match_a_loop_over_experts(layer):
+    """Experts 2..5 of 8; ``up_w`` goes in (out, in)."""
+    x, logits, _, up, down = expert_operands(
+        jnp.float32, layers=None if layer is None else 3)
+    experts, weights = dropless.route(
+        logits, 3, scoring_func="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, scale_normed=True,
+        bias=jnp.linspace(-0.2, 0.2, 8))
+    got = dropless.held_experts(x, experts, weights, None,
+                                jnp.swapaxes(up, -1, -2), down, 2,
+                                layer=layer, act="relu2")
+    up_l, down_l = (up, down) if layer is None else (up[layer], down[layer])
+    want = jnp.zeros_like(x)
+    for e in range(4):
+        w = jnp.where(experts == 2 + e, weights, 0.0).sum(-1)
+        want += w[:, None] * (jnp.square(jnp.maximum(x @ up_l[e], 0.0))
+                              @ down_l[e])
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(want).max()) > 0.1
+
+
+@pytest.mark.parametrize("transposed, K, N", [(True, 256, 200),
+                                              (False, 200, 384)])
+def test_the_pallas_grouped_product_matches_ragged_dot(transposed, K, N):
+    """What a TPU runs in ``grouped_product``'s place (``megablox.gmm``,
+    interpreted here) at dims that are and are not multiples of 128, 200
+    rows that are no multiple of a tile, an empty group, a group that
+    crosses a tile's edge, rows in no group, and layer 1's groups of a
+    merged stack of three layers."""
+    key = jax.random.split(jax.random.PRNGKey(0), 2)
+    rows = jax.random.normal(key[0], (200, K))
+    w = jax.random.normal(key[1], (12, K, N)) * 0.1
+    sizes = jnp.zeros((12,), jnp.int32).at[4:8].set(
+        jnp.asarray([90, 0, 70, 15]))
+    want = jax.lax.ragged_dot(rows, w, sizes)
+    stored = jnp.swapaxes(w, 1, 2) if transposed else w
+    got = dropless.grouped_product(rows, stored, sizes, transposed=transposed,
+                                   interpret=True)
+    assert got.shape == want.shape == (200, N)
+    assert float(jnp.abs(got - want)[:175].max()) < 1e-4
+    assert float(jnp.abs(dropless.grouped_product(
+        rows, stored, sizes, transposed=transposed) - want).max()) == 0.0
+
+
+@pytest.mark.parametrize("K, N, pallas", [
+    (2688, 1856, True), (1856, 2688, True),        # Nemotron-3-Nano: up, down
+    (5120, 1536, False), (1536, 5120, False),      # DeepSeek-V2
+    (3072, 3072, False),                           # Trinity
+    (2048, 1400, True)])                           # a gated expert of odd width
+def test_the_grouped_kernel_is_chosen_from_the_dims(K, N, pallas):
+    """A product is Pallas' where a dim is no multiple of 128 and XLA's
+    elsewhere, whatever kind of expert asks."""
+    assert dropless.pallas_grouped(K, N) is pallas
+
+
+def test_a_gated_expert_of_odd_width_takes_the_pallas_product(monkeypatch):
+    """On a TPU all three products of a gated expert whose width is no
+    multiple of 128 are the Pallas call, and none is at widths that are:
+    the selector sees shapes, not the kind of expert."""
+    from deepspeed_tpu.analysis.jaxpr_audit import iter_eqns
+    monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
+
+    def calls(D, F):
+        x, logits, gate, up, down = expert_operands(jnp.float32, D=D, F=F)
+        experts, weights = dropless.route(logits, 3)
+        jaxpr = jax.make_jaxpr(lambda *a: dropless.held_experts(
+            *a, 0))(x, experts, weights, gate, up, down)
+        names = [e.primitive.name for e, _ in iter_eqns(jaxpr.jaxpr)]
+        return names.count("pallas_call"), names.count("ragged_dot_general")
+    assert calls(128, 200) == (3, 0)
+    assert calls(128, 256) == (0, 3)
+
+
+def test_an_unknown_activation_is_refused_by_name():
+    x, logits, _, up, down = expert_operands(jnp.float32)
+    experts, weights = dropless.route(logits, 3)
+    with pytest.raises(ValueError, match="swish7"):
+        dropless.held_experts(x, experts, weights, None,
+                              jnp.swapaxes(up, -1, -2), down, 0, act="swish7")
+
+
+def test_the_shares_add_up_to_the_whole_layer(model_params):
+    """One expert layer over the same normed tokens, held four ways: the four
+    shares' routed parts, with the shared expert (which every chip computes
+    alike) counted once, equal the UNCUT reference's whole layer."""
+    m, params = model_params
+    pm = params["moe"]
+    h = jax.random.normal(jax.random.PRNGKey(7), (1, 24, 64))
+    u = h[0] / jnp.sqrt((h[0] ** 2).mean(-1, keepdims=True) + 1e-5)
+    whole, _ = reference.experts(ref_cfg(m), pm, 1, u)
+    total, shared = 0.0, None
+    for first in (0, 4, 8, 12):
+        share = tiny(experts_held=(first, 4))
+        cut = dict(pm, up_w=pm["up_w"][:, first:first + 4],
+                   down_w=pm["down_w"][:, first:first + 4])
+        out, counts, _ = share._moe(cut, h, 1)
+        only_shared = (jnp.square(jnp.maximum(u @ pm["shared_up_w"][1], 0.0))
+                       @ pm["shared_down_w"][1])
+        total = total + (out[0] - h[0]) - only_shared
+        shared = only_shared
+        # and the reference, given the same share, leaves the same out
+        mine, _ = reference.experts(
+            ref_cfg(m, experts_held=[first, 4]), cut, 1, u)
+        assert rel_err(out[0] - h[0], mine) < 1e-4
+        assert int(counts[0] + counts[1]) == 24 * 4
+    assert rel_err(total + shared, whole) < 1e-4
+
+
+# ------------------------------------------------------------- (d) serving
+PROMPTS = (13, 21, 9, 30, 17, 26)      # none on an 8-token bucket's edge
+NEW = (5, 9, 3, 12, 7, 4)              # so slots free at different steps
+
+
+def live_logit_error(srv, params, ref):
+    """The benchmark's check: the NEXT decode step's logits through the paged
+    path and the recurrent rows, against the reference's full forward over
+    each live slot's history."""
+    p, pool, tables, lengths, toks = srv._decode_args()[:5]
+    if not hasattr(srv, "_next_logits"):        # traced once an engine
+        srv._next_logits = jax.jit(lambda p, t, pl, tb, ln:
+                                   srv.model.decode_step_paged(
+                                       p, t, pl, tb, ln)[0])
+    got = np.asarray(srv._next_logits(p, toks, pool, tables, lengths))
+    live = [i for i, s in enumerate(srv._slots) if s is not None]
+    worst = 0.0
+    for i in live:
+        s = srv._slots[i]
+        hist = np.concatenate([np.asarray(s.req.tokens),
+                               np.asarray(s.out_tokens)]).astype(np.int32)
+        row = np.zeros((1, 64), np.int32)      # one shape, one compile
+        row[0, :len(hist)] = hist
+        want = ref(params, jnp.asarray(row), jnp.asarray([len(hist) - 1]))
+        worst = max(worst, rel_err(got[i], want[0]))
+    return worst, len(live)
+
+
+def serve_and_compare(params, model=None):
+    """Six requests through three slots: every slot is seated, freed and
+    seated again by a second stream (no state may leak).  Returns the worst
+    logit error seen at any step and the engine (drained)."""
+    m = model or tiny()
+    cfg = ref_cfg(m)
+    ref = jax.jit(lambda p, t, pos: reference.logits_at(cfg, p, t, pos))
+    eng = ds.init_inference(m, params=params, dtype=jnp.float32)
+    srv = ServingEngine(engine=eng, config={
+        "batch_slots": 3, "block_size": 8})
+    uids = [srv.submit(Request(tokens=tokens(20 + i, n), max_new_tokens=new))
+            for i, (n, new) in enumerate(zip(PROMPTS, NEW))]
+    worst, seen = 0.0, 0
+    while srv.step():
+        if any(s is not None for s in srv._slots):
+            err, n = live_logit_error(srv, params, ref)
+            worst, seen = max(worst, err), seen + n
+    assert seen > 20
+    return worst, srv, uids
+
+
+def test_serving_matches_the_reference(model_params):
+    _, params = model_params
+    worst, srv, uids = serve_and_compare(params)
+    assert worst < TOL
+    st = srv.stats()
+    assert st["completed"] == 6 and st["state_seats"] == 6   # slots reused
+    assert [len(srv.results[u]["tokens"]) for u in uids] == list(NEW)
+    assert srv.allocator.free_blocks == srv.num_blocks - 1
+    # what the donated pytree holds, by kind
+    c = srv.model.config
+    per_stream = c.count("M") * (c.state_bytes_per_layer
+                                 + (c.conv_kernel - 1) * c.conv_dim * 4)
+    assert st["recurrent_state_bytes"] == 3 * per_stream
+    assert st["state_bytes_per_stream"] == per_stream
+    assert srv.pool["k"].shape == (1, srv.num_blocks, 8, 32)   # 2 x 16 wide
+    assert st["kv_pool_bytes"] == 2 * srv.pool["k"].nbytes
+    assert (st["mamba_layers"], st["attention_layers"],
+            st["expert_layers"]) == (3, 1, 2)
+    # the new attributes of the spans
+    rows = srv._spans.rows()
+    pre = [r for r in rows if r.name == "serving.prefill"][-1].attrs
+    assert pre["ssd_tokens"] == pre["scan_tokens"] == pre["prompt_len"]
+    assert pre["ssd_chunks"] == -(-pre["prompt_len"] // c.chunk_size)
+    assert pre["routed_pairs"] + pre["pairs_elsewhere"] == \
+        4 * 2 * pre["prompt_len"]
+    steps = [r.attrs for r in rows if r.name == "serving.step" and r.attrs]
+    assert steps and max(a["seated_slots"] for a in steps) == 3
+    for a in steps:
+        assert a["seated_slots"] + a["free_slots"] == 3
+        assert a["state_bytes"] == a["seated_slots"] * 3 * 2 \
+            * c.state_bytes_per_layer
+        # (a step that `live_logit_error` settled from outside books none)
+        assert a.get("experts_touched", 0) + a.get("experts_idle", 32) == 32
+
+
+def test_an_inactive_row_keeps_its_recurrent_rows(model_params):
+    m, params = model_params
+    pool = m.init_serving_state(2, 5, 8, dtype=jnp.float32)
+    pool = dict(pool, ssm=pool["ssm"] + 1.0, conv=pool["conv"] + 2.0)
+    tables = jnp.asarray([[1, 2], [0, 0]], jnp.int32)       # row 1: scratch
+    _, new, routes = m.decode_step_paged(
+        params, jnp.asarray([3, 4]), pool, tables,
+        jnp.asarray([5, 0], jnp.int32), with_routes=True)
+    assert routes.shape == (2, 2, 4)
+    assert float(jnp.abs(new["ssm"][:, 1] - 1.0).max()) == 0.0
+    assert float(jnp.abs(new["conv"][:, 1] - 2.0).max()) == 0.0
+    assert float(jnp.abs(new["ssm"][:, 0] - 1.0).max()) > 0.0
+    assert int(new["counters"][0] + new["counters"][1]) == 4 * 2   # 1 live
+
+
+# ---------------------------------------------------------- (e) sensitivity
+def test_state_taken_at_the_buckets_end_fails(model_params, monkeypatch):
+    """The pad after the prompt enters the recurrence: what this family is
+    most likely to get wrong, and the check sees it."""
+    _, params = model_params
+    monkeypatch.setattr(m2, "mask_delta", lambda delta, t_real: delta)
+    monkeypatch.setattr(m2, "conv_tail_at",
+                        lambda padded, t_real, width: padded[:, -width:])
+    worst, _, _ = serve_and_compare(params)
+    assert worst > 10 * TOL
+
+
+def test_a_seat_that_keeps_the_previous_rows_fails(model_params, monkeypatch):
+    _, params = model_params
+    sound = nemotron_h.NemotronH.prefill_paged
+
+    def keeps_rows(self, params, toks, pool, blocks, slot, t_real):
+        row, new = sound(self, params, toks, pool, blocks, slot, t_real)
+        return row, dict(new, conv=pool["conv"], ssm=pool["ssm"])
+    monkeypatch.setattr(nemotron_h.NemotronH, "prefill_paged", keeps_rows)
+    worst, _, _ = serve_and_compare(params)
+    assert worst > 10 * TOL
+
+
+# what each fault must read at the least: a mechanism computed otherwise
+# stands ten times over TOL; the two that move a number by a percent or by
+# bfloat16's rounding (0.002 and 0.0002 here, the sound program 1e-6) stand
+# thirty times over the sound program's own reading
+FAULT_FLOORS = dict.fromkeys(control_nemotron.FAULTS, 10 * TOL)
+FAULT_FLOORS.update(bias_in_weights=TOL, state_bf16=TOL / 10)
+
+
+@pytest.mark.parametrize("fault", control_nemotron.FAULTS)
+def test_each_planted_fault_fails_at_float32(model_params, fault):
+    """``benchmark/control_nemotron.py``'s faults, each against the sound
+    reference at float32, where nothing hides below the precision served."""
+    _, params = model_params
+    unplant = control_nemotron.plant(fault)
+    try:
+        worst, _, _ = serve_and_compare(params)
+    finally:
+        unplant()
+    sound = serve_and_compare(params)[0]               # and it is out again
+    assert sound < TOL / 30
+    assert worst > max(FAULT_FLOORS[fault], 30 * sound), (fault, worst)
