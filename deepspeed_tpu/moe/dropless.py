@@ -18,15 +18,18 @@ buffer and drops what overflows; it stays, for ``gpt2_moe``.  Here:
   first + count - 1`` that THIS chip holds, their part of every token's
   output: the token-expert pairs are sorted by expert, the pairs of held
   experts run through three grouped matrix products
-  (:func:`grouped_product`: on a TPU one Mosaic call each, which reads an
-  expert's matrices only for the rows routed to it, XLA's or Pallas' by the
-  matrices' dims; TWO for a non-gated expert, ``down(act(up(x)))``), and
+  (:func:`grouped_product`: on a TPU one Pallas call each, which reads an
+  expert's matrices only for the rows routed to it, in tiles chosen from
+  the matrices' dims; TWO for a non-gated expert, ``down(act(up(x)))``), and
   each token's pairs are weighted and summed.  What the absent experts would
   add is left out:
   that is the other chips' part of an expert-parallel layer, and nothing here
   stands in for them or for the exchange.  With every expert held
   (``first`` 0, ``count`` ``E``) it is the whole layer.
 """
+
+import collections
+import time
 
 import jax
 import jax.numpy as jnp
@@ -122,27 +125,107 @@ def route_counters(experts, first, count, live=None):
         (live & ~held.any(axis=1)).sum()]).astype(jnp.int32)
 
 
-_GMM_ROWS = 128         # the rows of a Pallas tile: a held expert meets a dozen
-_LANES = 128            # what XLA's own grouped kernel tiles a dim by
+_LANES = 128            # a tile's dim is a multiple of this, or the whole dim
+_GMM_ROWS = 128         # the rows of a tile: a held expert meets 5 to 130
+_TILE_BYTES = 9 << 19   # the most one copy of a tile of the matrices holds:
+#                         4.5 MiB, 3,072 x 768 bfloat16
+_VMEM_BYTES = 15 << 20  # what a plan may hold of the 16 MiB Mosaic scopes a call
+
+# every grouped product traced in this process, oldest first:
+# (time.monotonic(), "gmm 128x5120x384 of 768x5120x1536/120")
+_traced = collections.deque(maxlen=4096)
 
 
 def _on_tpu():
     return jax.default_backend() == "tpu"
 
 
-def _tile(n, cap=1024):
-    """The largest multiple of 128 up to ``cap`` that divides ``n``; ``n``
-    itself where none does (a block may span a whole dim)."""
-    fits = [t for t in range(_LANES, min(n, cap) + 1, _LANES) if n % t == 0]
-    return fits[-1] if fits else n
+def _tile(n, most):
+    """The largest multiple of 128 up to ``most`` that divides ``n`` (128
+    where none does); ``n`` itself where 128 does not divide it: a block may
+    span a whole dim, and one that is no multiple of 128 must."""
+    if n % _LANES:
+        return n
+    fits = [t for t in range(_LANES, min(n, most) + 1, _LANES) if n % t == 0]
+    return fits[-1] if fits else _LANES
 
 
-def pallas_grouped(K, N):
-    """Whether a grouped product over ``(K, N)`` matrices runs as the Pallas
-    grouped matmul on a TPU: where a dim is no multiple of 128.  Decided from
-    the shapes alone, so a gated expert of such a width takes the same path
-    as a non-gated one."""
-    return bool(K % _LANES or N % _LANES)
+def plan_vmem_bytes(plan, itemsize=2):
+    """What the Pallas grouped matmul holds in VMEM under ``plan`` = (rows,
+    tk, tn): two copies each of a tile of the rows, of the matrices and of
+    the result, and the float32 accumulator."""
+    tm, tk, tn = plan
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def tile_plan(K, N, itemsize=2):
+    """``(rows, tk, tn)``: the tiles of the Pallas grouped matmul over
+    matrices (K, N), from the shapes alone.  128 rows; **all of K in one
+    tile** and as much of N beside it as 4.5 MiB hold; where N is no
+    multiple of 128 (or K alone is too long) all of N, and K in tiles of up
+    to 4.5 MiB.
+
+    Why, measured (ms a product on a v5e, bfloat16, median of 5 x 16 calls,
+    the stack merged into ``layers x count`` groups of which one layer's
+    have rows, ``layer`` traced; PERF.md section 6, PR 43; ``ragged`` is
+    XLA's kernel for ``ragged_dot`` in the same form; the first three plans
+    through ``megablox.gmm``, ``here`` the plan through
+    ``ops/grouped_matmul.py``):
+
+    ================================  ======  =========  =========  =========  =====
+    K x N / groups, rows (held pairs) ragged  PR 42's    N whole    plan       here
+    ================================  ======  =========  =========  =========  =====
+    DeepSeek-V2 5120x1536/120                 1024x768   1280x1536  5120x384
+      decode 768 (96 over 20 experts) 0.766   0.567      0.528      0.496      0.476
+      prefill 6,144 (768)             1.149   0.686      0.642      0.572      0.544
+    DeepSeek-V2 1536x5120/120                 768x1024   384x5120   1536x1280
+      decode                          0.784   0.547      0.505      0.499      0.486
+      prefill                         1.159   0.664      0.612      0.565      0.544
+    Trinity 3072x3072/128                     1024x1024  512x3072   3072x768
+      decode 384 (48 over 13 of 32)   0.501   0.442      0.416      0.401      0.390
+      prefill 4,096 (512)             2.062   1.060      0.987      0.926      0.909
+      prefill 32,768 (4,096)          2.555   1.905      1.770      1.388      1.363
+    Nemotron 2688x1856/224 (out, in)          896x1856              the same
+      decode 1,536 (384)              4.140   0.561                            0.522
+      prefill 6,144 (1,536)           4.498   0.712                            0.664
+    Nemotron 1856x2688/224                    1856x896              the same
+      decode                          3.722   0.527                            0.490
+      prefill                         4.085   0.628                            0.583
+    ================================  ======  =========  =========  =========  =====
+
+    With K in one tile a group whose rows cross a tile's edge finds its
+    matrices still resident (the block index does not change between the two
+    visits) and the sums run in ``ragged_dot``'s order: the results were
+    equal to the bit at every such plan.  With K whole, tiles of 2.5 to 4.5
+    MiB read within 3 % of each other and 1.5 MiB costs up to 13 %; K cut in
+    pieces costs 5 to 35 %.  Fewer rows a tile (32, 64) lose 1 to 60 %, 256
+    rows gain 2 % on an 8k document and lose 3 % at 38 rows an expert, 512 do
+    not fit; the count of rows, the count of groups (the merged stack costs
+    3 % over one layer's matrices alone) and a transposed stack move no
+    choice, so they are no argument.  Nemotron's two shapes come out as PR
+    42 measured them."""
+    most = _TILE_BYTES // itemsize
+    if N % _LANES == 0 and K * _LANES <= most:
+        plan = _GMM_ROWS, K, _tile(N, most // K)
+    else:
+        tn = _tile(N, 512)
+        plan = _GMM_ROWS, _tile(K, most // tn), tn
+    if plan_vmem_bytes(plan, itemsize) > _VMEM_BYTES:
+        raise ValueError(
+            f"grouped product over ({K}, {N}) matrices: tiles {plan} hold "
+            f"{plan_vmem_bytes(plan, itemsize)} bytes of VMEM, over "
+            f"{_VMEM_BYTES} (a dim that is no multiple of 128 goes in whole)")
+    return plan
+
+
+def products_traced(t0, t1):
+    """``{"gmm 128x5120x384 of 768x5120x1536/120": calls}``: the grouped
+    products traced between two readings of ``time.monotonic()``, each by
+    its kernel, its tiles (rows x tk x tn) and its shapes (M x K x N /
+    groups).  ``CachedStep`` puts it on the ``compile.lower`` row of the
+    executable that was being traced."""
+    seen = collections.Counter(what for t, what in _traced if t0 <= t <= t1)
+    return dict(seen)
 
 
 def grouped_product(rows, w, sizes, transposed=False, interpret=None):
@@ -152,37 +235,42 @@ def grouped_product(rows, w, sizes, transposed=False, interpret=None):
     unspecified).  Returns (M, N) in ``rows.dtype``, accumulated in float32.
     EVERY grouped product of :func:`held_experts` is this one.
 
-    ``jax.lax.ragged_dot`` (on a TPU XLA's own Mosaic call,
-    ``ragged-dot-none`` in a trace), except on a TPU where
-    :func:`pallas_grouped` says the dims defeat it: XLA tiles its kernel by
-    divisors of the dims, and 2,688 = 21 x 128 beside 1,856 = 14.5 x 128
-    (Nemotron-3-Nano's experts) leave it tiles of 128 x 128: 10,000 grid
-    steps and as many 32 KB copies an expert layer a product, 3.6 ms where
-    the bytes take 0.4 (72 % of the traced window; PERF.md section 6, PR 42).
-    There the Pallas grouped matmul JAX ships runs (``megablox.gmm``: ``gmm``
-    in an executable and in a trace) with tiles of this file's choosing: all
-    of a dim that is no multiple of 128 and up to 1,024 of one that is
-    (3.3 MB a copy at those widths).  Where both dims are multiples of 128
-    (DeepSeek-V2's 5,120 x 1,536, Trinity's 3,072 x 3,072) XLA's kernel is
-    what ran before and what is measured: PERF.md section 6 sets the two
-    side by side at all three widths.  ``transposed`` reads an (out, in)
-    stack as stored: the chip keeps a (D, 1856) stack with D minor whichever
-    way it is declared, and a product that wants the 1,856 minor re-lays ALL
-    of it first (2.2 GB a layer a step, found by compiling for a v5e)."""
+    ONE kernel a backend, and no selector on widths.  On a TPU the Pallas
+    grouped matmul of ``ops/grouped_matmul.py`` (``gmm`` in an executable
+    and in a device trace: ``megablox.gmm``'s algorithm under a table of
+    visits that traces in a sixth of the time) under :func:`tile_plan`'s
+    tiles: it reads a group's matrices only where rows were routed to it,
+    and with K in one tile reads them once.  XLA's own kernel for
+    ``jax.lax.ragged_dot`` (``ragged-dot-none``) was 1.3 to 2.3 x slower at
+    widths that are multiples of 128 (DeepSeek-V2's 5,120 x 1,536, Trinity's
+    3,072 x 3,072) and 7 to 8 x at Nemotron-3-Nano's 2,688 x 1,856, which
+    leave it tiles of 128 x 128 (:func:`tile_plan`'s table; PERF.md section
+    6, PRs 42 and 43).  Off
+    the chip (the CPU's tests, the plain references) ``jax.lax.ragged_dot``;
+    ``interpret=True`` / ``False`` forces the Pallas call anywhere.  Each
+    trace notes kernel, tiles and shapes for :func:`products_traced`.
+
+    ``transposed`` reads an (out, in) stack as stored: the chip keeps a
+    (D, 1856) stack with D minor whichever way it is declared, and a product
+    that wants the 1,856 minor re-lays ALL of it first (2.2 GB a layer a
+    step, found by compiling for a v5e)."""
     M, K = rows.shape
+    G = w.shape[0]
     N = w.shape[1 if transposed else 2]
+    shapes = f"{M}x{K}x{N}/{G}"
     if interpret is None:
-        if not (_on_tpu() and pallas_grouped(K, N)):
+        if not _on_tpu():
+            _traced.append((time.monotonic(), f"ragged_dot of {shapes}"))
             if transposed:
                 w = jnp.swapaxes(w, -1, -2)
             return jax.lax.ragged_dot(rows, w, sizes)
         interpret = False
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-    rows = jnp.pad(rows, ((0, -M % _GMM_ROWS), (0, 0)))
-    out = gmm(rows, w, sizes, preferred_element_type=rows.dtype,
-              tiling=(_GMM_ROWS, _tile(K), _tile(N)),
-              transpose_rhs=transposed, interpret=interpret)
-    return out[:M]
+    from ..ops.grouped_matmul import grouped_matmul
+    plan = tile_plan(K, N, rows.dtype.itemsize)
+    _traced.append((time.monotonic(),
+                    "gmm {}x{}x{} of {}".format(*plan, shapes)))
+    rows = jnp.pad(rows, ((0, -M % plan[0]), (0, 0)))
+    return grouped_matmul(rows, w, sizes, plan, transposed, interpret)[:M]
 
 
 def held_experts(x, experts, weights, gate_w, up_w, down_w, first,

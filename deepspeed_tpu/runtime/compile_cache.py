@@ -51,6 +51,7 @@ import json
 import os
 import pickle
 import shutil
+import sys
 import time
 
 import numpy as np
@@ -542,6 +543,7 @@ class CachedStep:
             lowered = self._jit.lower(*args, **kwargs)
         lower_ms = (span.t1 - span.t0) * 1000
         _split_lower(rec, span)
+        _note_grouped_products(span)
         cache = self.cache
         material = None
         if cache is not None:
@@ -654,6 +656,19 @@ def _split_lower(rec, span):
         span.attrs["trace_s"] = parts["jax.trace"]
     if "jax.lower" in parts:
         span.attrs["mlir_s"] = parts["jax.lower"]
+
+
+def _note_grouped_products(span):
+    """``grouped_products`` on a closed ``compile.lower`` span: the routed
+    experts' grouped products that were traced inside it, each by kernel,
+    tiles and shapes (``moe/dropless.products_traced``), so that a run says
+    which kernel and plan an executable holds without a device trace.  Left
+    out where none was traced (a program that never imported the expert
+    layer imports nothing here)."""
+    dropless = sys.modules.get("deepspeed_tpu.moe.dropless")
+    products = dropless and dropless.products_traced(span.t0, span.t1)
+    if products:
+        span.attrs["grouped_products"] = products
 
 
 def executable_memory_analysis(exe):

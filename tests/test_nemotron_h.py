@@ -315,10 +315,11 @@ def test_non_gated_experts_match_a_loop_over_experts(layer):
 
 
 @pytest.mark.parametrize("transposed, K, N", [(True, 256, 200),
-                                              (False, 200, 384)])
+                                              (False, 200, 384),
+                                              (False, 256, 384)])
 def test_the_pallas_grouped_product_matches_ragged_dot(transposed, K, N):
-    """What a TPU runs in ``grouped_product``'s place (``megablox.gmm``,
-    interpreted here) at dims that are and are not multiples of 128, 200
+    """What a TPU runs in ``grouped_product``'s place
+    (``ops/grouped_matmul.py``, interpreted here) at dims that are and are not multiples of 128, 200
     rows that are no multiple of a tile, an empty group, a group that
     crosses a tile's edge, rows in no group, and layer 1's groups of a
     merged stack of three layers."""
@@ -337,33 +338,149 @@ def test_the_pallas_grouped_product_matches_ragged_dot(transposed, K, N):
         rows, stored, sizes, transposed=transposed) - want).max()) == 0.0
 
 
-@pytest.mark.parametrize("K, N, pallas", [
-    (2688, 1856, True), (1856, 2688, True),        # Nemotron-3-Nano: up, down
-    (5120, 1536, False), (1536, 5120, False),      # DeepSeek-V2
-    (3072, 3072, False),                           # Trinity
-    (2048, 1400, True)])                           # a gated expert of odd width
-def test_the_grouped_kernel_is_chosen_from_the_dims(K, N, pallas):
-    """A product is Pallas' where a dim is no multiple of 128 and XLA's
-    elsewhere, whatever kind of expert asks."""
-    assert dropless.pallas_grouped(K, N) is pallas
+@pytest.mark.parametrize("transposed", [False, True])
+def test_the_kernel_sums_over_tiles_of_k_and_of_n(transposed):
+    """``ops/grouped_matmul.py`` alone under tiles smaller than every dim
+    (three tiles of K into the accumulator, two of N, 64 rows a tile): a
+    group inside one tile, one over three, an empty one between, two that
+    share a tile, rows in no group."""
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+    key = jax.random.split(jax.random.PRNGKey(1), 2)
+    rows = jax.random.normal(key[0], (256, 384))
+    w = jax.random.normal(key[1], (6, 384, 256)) * 0.1
+    sizes = jnp.asarray([0, 30, 140, 0, 20, 9], jnp.int32)
+    want = jax.lax.ragged_dot(rows, w, sizes)
+    got = grouped_matmul(rows, jnp.swapaxes(w, 1, 2) if transposed else w,
+                         sizes, (64, 128, 128), transposed, interpret=True)
+    assert got.shape == (256, 256)
+    assert float(jnp.abs(got - want)[:199].max()) < 1e-4
 
 
-def test_a_gated_expert_of_odd_width_takes_the_pallas_product(monkeypatch):
-    """On a TPU all three products of a gated expert whose width is no
-    multiple of 128 are the Pallas call, and none is at widths that are:
-    the selector sees shapes, not the kind of expert."""
+def test_the_table_of_visits_is_every_group_tile_pair_in_order():
+    """Against a loop over groups and tiles, for sizes with empty groups,
+    groups that end on a tile's edge and a group that spans several tiles;
+    the steps past the count are never run and only have to be in range."""
+    from deepspeed_tpu.ops.grouped_matmul import visits
+    rng = np.random.default_rng(0)
+    for trial in range(20):
+        G, tm, tiles_m = 9, 8, 12
+        sizes = rng.integers(0, 30, G) * (rng.random(G) < 0.6)
+        sizes[rng.integers(G)] = 16            # one that ends on an edge
+        sizes = (sizes * (tiles_m * tm - 5) // max(sizes.sum(), 1)
+                 if sizes.sum() > tiles_m * tm else sizes).astype(np.int32)
+        group, tile, start, end, count = visits(jnp.asarray(sizes), tiles_m,
+                                                tm)
+        want, at = [], 0
+        for g, n in enumerate(sizes):
+            want += [(g, t) for t in range(at // tm, (at + n - 1) // tm + 1)
+                     if n]
+            assert (int(start[g]), int(end[g])) == (at, at + n)
+            at += n
+        assert int(count) == len(want) <= tiles_m + G - 1 == group.shape[0]
+        assert list(zip(group[:len(want)].tolist(),
+                        tile[:len(want)].tolist())) == want
+        assert 0 <= int(tile.min()) and int(tile.max()) < tiles_m
+        assert 0 <= int(group.min()) and int(group.max()) < G
+
+
+def test_a_gated_stacked_layer_through_the_pallas_product(monkeypatch):
+    """A gated expert layer as ``deepseek_v2`` and ``afmoe`` call it (the
+    stack of three layers whole, ``layer`` 1, experts 2..5 of 8 held), all
+    three products the interpreted Pallas call at widths that are multiples
+    of 128: what ``ragged_dot`` gives, to rounding, with an idle expert and
+    absent experts' pairs in no group."""
+    x, logits, gate, up, down = expert_operands(jnp.float32, layers=3,
+                                                N=40, D=128, F=256)
+    experts, weights = dropless.route(logits.at[:, 3].set(-1e9), 3)
+    args = (x, experts, weights, gate, up, down, 2)
+    want = dropless.held_experts(*args, layer=1)
+    product = dropless.grouped_product
+    monkeypatch.setattr(dropless, "grouped_product",
+                        lambda *a, **kw: product(*a, interpret=True, **kw))
+    got = dropless.held_experts(*args, layer=1)
+    assert float(jnp.abs(want).max()) > 0.1
+    assert float(jnp.abs(got - want).max()) < 1e-4 * float(
+        jnp.abs(want).max())
+
+
+# (layers x count groups, D, F) of the three cells' expert layers, and the
+# rows of a decode step and of a prompt (slots or tokens x picks a token)
+CELL_WIDTHS = {"deepseek-v2": (120, 5120, 1536), "trinity": (128, 3072, 3072),
+               "nemotron-3-nano": (224, 2688, 1856)}
+
+
+@pytest.mark.parametrize("cell, rows, product, plan", [
+    ("deepseek-v2", 768, "up", (128, 5120, 384)),
+    ("deepseek-v2", 768, "down", (128, 1536, 1280)),
+    ("deepseek-v2", 6144, "up", (128, 5120, 384)),
+    ("deepseek-v2", 6144, "down", (128, 1536, 1280)),
+    ("trinity", 384, "up", (128, 3072, 768)),
+    ("trinity", 4096, "down", (128, 3072, 768)),
+    ("trinity", 32768, "up", (128, 3072, 768)),
+    # PR 42's, measured there: this cell's executables do not change
+    ("nemotron-3-nano", 1536, "up", (128, 896, 1856)),
+    ("nemotron-3-nano", 1536, "down", (128, 1856, 896)),
+    ("nemotron-3-nano", 6144, "up", (128, 896, 1856)),
+    ("nemotron-3-nano", 6144, "down", (128, 1856, 896)),
+])
+def test_the_tile_plan_comes_from_the_shapes(monkeypatch, cell, rows, product,
+                                             plan):
+    """At the three cells' widths, a decode step's rows and a prompt's over
+    the merged stack: the product takes the measured plan (``tile_plan``'s
+    table) and says so; a tile divides its dim in multiples of 128 or spans
+    it, and two copies of every tile with the accumulator fit the VMEM the
+    module states."""
+    import time
+    monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
+    groups, D, F = CELL_WIDTHS[cell]
+    K, N = (D, F) if product == "up" else (F, D)
+    transposed = cell == "nemotron-3-nano" and product == "up"
+    tm, tk, tn = dropless.tile_plan(K, N)
+    assert (tm, tk, tn) == plan
+    for tile, dim in ((tk, K), (tn, N)):
+        assert tile == dim or (tile % 128 == 0 and dim % tile == 0)
+    assert dropless.plan_vmem_bytes(plan) <= dropless._VMEM_BYTES < 16 << 20
+    shapes = [((rows, K), jnp.bfloat16),
+              ((groups, N, K) if transposed else (groups, K, N), jnp.bfloat16),
+              ((groups,), jnp.int32)]
+    t0 = time.monotonic()
+    out = jax.eval_shape(
+        lambda *a: dropless.grouped_product(*a, transposed=transposed),
+        *[jax.ShapeDtypeStruct(*s) for s in shapes])
+    assert out.shape == (rows, N) and out.dtype == jnp.bfloat16
+    assert dropless.products_traced(t0, time.monotonic()) == {
+        f"gmm {tm}x{tk}x{tn} of {rows}x{K}x{N}/{groups}": 1}
+
+
+def test_a_plan_that_cannot_fit_is_refused_by_name():
+    """Two dims that are no multiples of 128 go in whole; where that is
+    more VMEM than a call may hold, the refusal names dims and tiles."""
+    with pytest.raises(ValueError, match=r"\(2600, 3000\).*VMEM"):
+        dropless.tile_plan(2600, 3000)
+
+
+@pytest.mark.parametrize("F", [256, 200])
+def test_on_a_tpu_every_product_is_the_pallas_call(monkeypatch, F):
+    """With the backend a TPU all three products of a gated expert are the
+    Pallas call and none is ``ragged_dot``, at a width that is a multiple
+    of 128 and at one that is not: the backend chooses, not the widths; and
+    the trace leaves each product's kernel, tiles and shapes behind for
+    the ``compile.lower`` row."""
+    import time
     from deepspeed_tpu.analysis.jaxpr_audit import iter_eqns
     monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
-
-    def calls(D, F):
-        x, logits, gate, up, down = expert_operands(jnp.float32, D=D, F=F)
-        experts, weights = dropless.route(logits, 3)
-        jaxpr = jax.make_jaxpr(lambda *a: dropless.held_experts(
-            *a, 0))(x, experts, weights, gate, up, down)
-        names = [e.primitive.name for e, _ in iter_eqns(jaxpr.jaxpr)]
-        return names.count("pallas_call"), names.count("ragged_dot_general")
-    assert calls(128, 200) == (3, 0)
-    assert calls(128, 256) == (0, 3)
+    x, logits, gate, up, down = expert_operands(jnp.float32, D=128, F=F)
+    experts, weights = dropless.route(logits, 3)
+    t0 = time.monotonic()
+    jaxpr = jax.make_jaxpr(lambda *a: dropless.held_experts(
+        *a, 0))(x, experts, weights, gate, up, down)
+    names = [e.primitive.name for e, _ in iter_eqns(jaxpr.jaxpr)]
+    assert names.count("pallas_call") == 3
+    assert names.count("ragged_dot_general") == 0
+    traced = dropless.products_traced(t0, time.monotonic())
+    assert sum(traced.values()) == 3 and all(
+        what.startswith("gmm 128x") for what in traced)
+    assert f"gmm 128x128x{F} of 72x128x{F}/4" in traced
 
 
 def test_an_unknown_activation_is_refused_by_name():

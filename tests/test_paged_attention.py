@@ -498,3 +498,41 @@ def test_window_kernel_compiles_for_a_v5e(v5e):
     payload = "bf16[{}]".format(",".join(map(str, rows)))
     assert payload in text
     assert not re.search(re.escape(" = " + payload) + r"\S* copy\(", text)
+
+
+# ------------------------------- the routed experts' grouped products, for
+# the same described chip (kept in THIS file: one worker describes the
+# topology once, and a third file doing so could land on another worker)
+@pytest.mark.parametrize("layers, count, D, F, slots, k", [
+    (6, 20, 5120, 1536, 128, 6),      # DeepSeek-V2: 120 groups, 768 rows
+    (4, 32, 3072, 3072, 96, 4),       # Trinity: 128 groups, 384 rows
+], ids=["deepseek-v2", "trinity"])
+def test_a_stacked_expert_layer_compiles_to_three_gmm_calls(
+        v5e, monkeypatch, layers, count, D, F, slots, k):
+    """``held_experts`` alone over a stacked expert layer at the published
+    widths, a decode step's rows, ``layer`` traced, compiled for a described
+    v5e as the chip's backend would choose: three ``gmm`` Mosaic calls, no
+    ``ragged-dot``, and no copy of a whole expert stack in front of a
+    product (PR 42's 2.2 GB re-layout was found this way)."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.moe import dropless
+    monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    stack = lambda *dims: ((layers, count) + dims, jnp.bfloat16)
+    shapes = [((slots, D), jnp.bfloat16), ((slots, k), jnp.int32),
+              ((slots, k), jnp.float32), stack(D, F), stack(D, F),
+              stack(F, D), ((), jnp.int32)]
+
+    def fn(x, experts, weights, gate_w, up_w, down_w, layer):
+        return dropless.held_experts(x, experts, weights, gate_w, up_w,
+                                     down_w, 2 * count, layer=layer)
+    exe = jax.jit(fn).trace(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    ]).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3
+    assert text.count("tpu_custom_call") == 3
+    assert "ragged-dot" not in text
+    assert not re.search(r"bf16\[(\d+,)+\d{4,},\d{4,}\]\S* copy\(", text)
+    # the stacks are operands, and nothing of their size is a transient
+    assert exe.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20

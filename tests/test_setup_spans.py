@@ -209,6 +209,30 @@ def test_a_jit_outside_cachedstep_is_seen_and_a_lowering_is_split(tmp_path):
     assert lower.t_end <= key.t_start
 
 
+def test_a_lowering_says_which_grouped_products_it_traced(tmp_path):
+    """An executable that holds an expert layer: its ``compile.lower`` row
+    names each grouped product by kernel, tiles and shapes
+    (``moe/dropless.products_traced``); one that holds none has no such
+    attribute."""
+    from deepspeed_tpu.moe import dropless
+    rec = fresh()
+    t0 = rec.now()
+    key = jax.random.split(jax.random.PRNGKey(0), 5)
+    x = jax.random.normal(key[0], (10, 16))
+    experts, weights = dropless.route(jax.random.normal(key[1], (10, 4)), 2)
+    gate, up = (jax.random.normal(k, (4, 16, 24)) for k in key[2:4])
+    down = jax.random.normal(key[4], (4, 24, 16))
+    cache = cc.CompileCache(str(tmp_path))
+    cc.wrap_step("layer", lambda x, e, w: dropless.held_experts(
+        x, e, w, gate, up, down, 0), cache=cache)(x, experts, weights)
+    cc.wrap_step("plain", lambda x: x * 2.0, cache=cache)(x)
+    layer, plain = [r for r in rec.setup_rows()[0]
+                    if r.t_start >= t0 and r.name == "compile.lower"]
+    assert layer.attrs["grouped_products"] == {
+        "ragged_dot of 20x16x24/4": 2, "ragged_dot of 20x24x16/4": 1}
+    assert "grouped_products" not in plain.attrs
+
+
 # -------------------------------------------------------------- the partition
 def parts_of(rows, t_start, t_until):
     parts = startup.partition(rows, t_start, t_until)
