@@ -58,10 +58,15 @@ re-lays all 2.2 GB of the stack in every layer of every step
 
 Serving state (``init_serving_state``; docs/serving.md#recurrent-state): the
 paged ``{k, v}`` pool over the ATTENTION layers only, and per slot ``conv (Lm,
-slots, K - 1, Dc)`` in the model dtype and ``ssm (Lm, slots, H, P, N)``
+slots, K - 1, Dc)`` in the model dtype and ``ssm (Lm, slots, G, N, (H / G) P)``
 float32: 2,097,152 + 36,864 bytes a Mamba layer a stream at the published
-widths.  A prefill writes a slot's rows whole; a decode step advances the
-live rows in place (``mamba2_state_update``).
+widths.  ``ssm`` lies as the one-token update reads it
+(``ops/mamba2.py::step_layout``: a group, then ``N`` on the sublanes, then the
+group's heads and channels on the lanes), not as the scan hands a state back.
+A prefill writes a slot's rows whole, laying the prompt's final ``(H, P, N)``
+state out once (``mamba2.to_step_layout``); a decode step advances the live
+rows in place (``mamba2_state_update``).  The contiguous cache
+(``init_cache``) keeps ``(Lm, B, H, P, N)``: the scan starts from it.
 """
 
 import dataclasses
@@ -492,14 +497,21 @@ class NemotronH:
         return jnp.mean(lse - picked[..., 0])
 
     # ---------------------------------------------------- contiguous decoding
-    def _recurrent_rows(self, rows, dtype):
+    def _recurrent_rows(self, rows, dtype, state):
+        """Per row and Mamba layer the convolution's tail and the recurrent
+        state, ``state`` one row's shape of it: ``(H, P, N)`` as the scan
+        hands it back (``init_cache``), or the one-token update's own
+        (``init_serving_state``)."""
         c = self.config
         Lm = c.count(MAMBA)
         return {"conv": jnp.zeros((Lm, rows, c.conv_kernel - 1, c.conv_dim),
                                   dtype),
-                "ssm": jnp.zeros((Lm, rows, c.mamba_num_heads,
-                                  c.mamba_head_dim, c.ssm_state_size),
-                                 jnp.float32)}
+                "ssm": jnp.zeros((Lm, rows) + tuple(state), jnp.float32)}
+
+    @property
+    def _state_dims(self):
+        c = self.config
+        return (c.mamba_num_heads, c.mamba_head_dim, c.ssm_state_size)
 
     def init_cache(self, batch_size: int, max_len: Optional[int] = None,
                    dtype=None):
@@ -511,7 +523,7 @@ class NemotronH:
         kv = (c.count(ATTENTION), batch_size, max_len or c.max_seq,
               c.n_kv_head, c.head_dim)
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
-                **self._recurrent_rows(batch_size, dtype),
+                **self._recurrent_rows(batch_size, dtype, self._state_dims),
                 "index": jnp.zeros((), jnp.int32)}
 
     def apply_with_cache(self, params, tokens, cache):
@@ -560,14 +572,18 @@ class NemotronH:
                            kv_bits=16, quant_block=64, dtype=None):
         """The one pytree the serving engine donates through its steps: the
         paged ``{k, v}`` pool over the attention layers, per slot the Mamba
-        layers' convolution tails and recurrent states, and ``counters``."""
+        layers' convolution tails and recurrent states, and ``counters``.  The
+        states lie in the one-token update's layout, ``ssm (Lm, slots, G, N,
+        (H / G) P)`` float32 (module docstring): index it by ``[layer]`` and
+        ``[:, slot]``, read a row back with ``mamba2.from_step_layout``."""
         from ..inference import paged_kv as pk
         c = self.config
         dtype = dtype or self.dtype
         pool = pk.init_pool(c.count(ATTENTION), num_blocks, block_size,
                             c.n_head, c.head_dim, dtype, kv_bits=kv_bits,
                             quant_block=quant_block, n_kv_head=c.n_kv_head)
-        return dict(pool, **self._recurrent_rows(batch_slots, dtype),
+        state = m2.step_layout(*self._state_dims, c.n_groups)
+        return dict(pool, **self._recurrent_rows(batch_slots, dtype, state),
                     counters=jnp.zeros((len(self.step_counters),), jnp.int32))
 
     @staticmethod
@@ -600,8 +616,9 @@ class NemotronH:
         layers' K/V into ``blocks``, and slot ``slot``'s recurrent rows written
         WHOLE with the state after token ``t_real - 1`` (the pad after it must
         not enter a recurrence; it is routed like any token and left out of
-        the counters).  ``toks``: (1, T); returns ``(logits (1, Vh) at token
-        t_real - 1, pool)``."""
+        the counters), the scan's ``(H, P, N)`` state re-laid once into the
+        update's layout (``ssm.seat``; 2 MB a layer).  ``toks``: (1, T);
+        returns ``(logits (1, Vh) at token t_real - 1, pool)``."""
         from ..inference import paged_kv as pk
         T = toks.shape[1]
         bucket = blocks.shape[0] * pool["k"].shape[2]
@@ -614,7 +631,8 @@ class NemotronH:
                     pool,
                     conv=pool["conv"].at[m, slot].set(
                         tail[0].astype(pool["conv"].dtype)),
-                    ssm=pool["ssm"].at[m, slot].set(state[0]))
+                    ssm=pool["ssm"].at[m, slot].set(
+                        m2.to_step_layout(state[0], self.config.n_groups)))
             return h, (pool, ks, vs)
 
         def attn_fn(p, h, a, carry):

@@ -18,12 +18,24 @@ decay               one a channel and column    one a HEAD (64 heads of 64)
                                                 of 8 heads (8 groups)
 state a layer       ``(16, 5120)`` float32      ``(64, 64, 128)`` float32
                     327,680 bytes a stream      2,097,152 bytes a stream
+  as it is served   the same                    ``(8, 128, 512)``: a group,
+                                                ``N``, the group's (head,
+                                                channel)
 conv carry          ``(3, 5120)``               ``(3, 6144)`` (x, B and C)
 prompt              a recurrence on the VPU     chunks of 128 on the MXU
 ==================  ==========================  ============================
 
-Layout: ``N`` = 128 is the minor dim, exactly one lane tile; ``P`` rides the
-sublanes.  The published ``(H, P, N)`` orientation is kept as it is.
+Layout.  The scan works and hands its state back in the published ``(H, P,
+N)`` orientation: ``N`` = 128 is the minor dim, exactly one lane tile, ``P``
+rides the sublanes.  The SERVING state, which only the one-token update reads,
+is kept as ``(G, N, (H / G) P)`` (:func:`step_layout`, :func:`to_step_layout`):
+``N`` rides the sublanes and a group's 8 heads x 64 channels = 512 the lanes.
+``y[h, p] = sum_n S[h, p, n] C[g, n]`` contracts over ``N``: with ``N`` on the
+lanes every vreg of the state pays a lane broadcast of ``dt x`` and a lane
+reduction for ``y`` (the XLU's work; it held the update at 69 % of its copy,
+PERF.md section 6, PR 44); with ``N`` on the sublanes ``dt x`` and the decay
+are rows, ``B`` and ``C`` columns, and the sum over ``N`` is vreg-wise
+addition.
 
 Three pieces, each in two forms of one signature:
 
@@ -37,11 +49,14 @@ Three pieces, each in two forms of one signature:
   sublanes, the chunk's tokens on the lanes) so that each head's slice is a
   whole number of sublane tiles and every per-token factor is a row.
 - :func:`ssm_step` — one token for every slot over ONE layer's rows of the
-  serving state ``(layers, slots, H, P, N)``, IN PLACE: the TPU form
-  (``pallas_call`` ``mamba2_state_update``, the leaf aliased to its output, the
-  layer a prefetched scalar, a slot's 2 MB a grid step) reads and writes the
-  state once; a dead slot is handed ``decay`` 1 and ``dt x`` 0, which leave its
-  rows what they were bit for bit.
+  serving state ``(layers, slots, G, N, (H / G) P)``, IN PLACE: the TPU form
+  (``pallas_call`` ``mamba2_state_update``, the leaf left in HBM and aliased
+  to its output, the layer a prefetched scalar, 8 slots' 16 MB a grid step
+  through three VMEM buffers, reads and writes taking turns) reads and writes
+  the state once; a dead slot is handed ``decay`` 1 and ``dt x`` 0, which
+  leave its rows what they were bit for bit.  The ``jax.numpy`` form goes
+  through :func:`ssm_step_jnp` over the published orientation and lays the
+  result out again: one leaf for the CPU, the tests and the chip.
 - :func:`gated_group_norm` — ``RMSNorm(y * silu(z))`` with the mean square
   taken over each GROUP's channels.
 
@@ -64,7 +79,8 @@ from .selective_scan import causal_conv, conv_tail_at, mask_delta  # noqa: F401
 
 SCAN_KERNEL = "mamba2_ssd_scan"
 STEP_KERNEL = "mamba2_state_update"
-_STEP_VMEM = 48 << 20     # a slot's state in and out, double-buffered: 8 MB
+_STEP_BUF = 48 << 20      # the update's three buffers of the state in VMEM
+_STEP_VMEM = 64 << 20     # those, and the per-slot operands beside them
 
 
 def _interpret():
@@ -266,74 +282,183 @@ def ssm_step_jnp(x, dt, A, B, C, D, S):
     return y.astype(x.dtype), S
 
 
-def _step_kernel(layer_ref, da_ref, dtxT_ref, b_ref, c_ref, s_ref, yT_ref,
-                 s_out_ref, *, per):
-    """Grid (slots,): one slot's rows of one layer.  ``da`` (H,) float32 in
-    SMEM, each head's decay; ``dtxT`` (P, H) float32, ``dt x`` with the heads
-    on the lanes; ``b``, ``c`` (G, N) float32; the state (H, P, N) float32 in
-    and (aliased) out; ``yT`` (P, H) float32."""
-    del layer_ref
-    H = s_ref.shape[0]
-    dtxT = dtxT_ref[...]
-    lane = jax.lax.broadcasted_iota(jnp.int32, dtxT.shape, 1)
-    yT = jnp.zeros(dtxT.shape, jnp.float32)
-    for h in range(H):
-        g = h // per
-        S = s_ref[h] * da_ref[h] + dtxT[:, h:h + 1] * b_ref[g:g + 1, :]
-        s_out_ref[h] = S
-        col = jnp.sum(S * c_ref[g:g + 1, :], axis=-1, keepdims=True)
-        yT = jnp.where(lane == h, col, yT)
-    yT_ref[...] = yT
+def step_layout(H, P, N, G):
+    """One slot's rows of the serving state, per layer: ``(G, N, (H / G)
+    P)``, the ``(H, P, N)`` state with each group's heads and channels on the
+    lanes and ``N`` on the sublanes (:func:`to_step_layout`)."""
+    return (G, N, H // G * P)
+
+
+def to_step_layout(S, G):
+    """``(..., H, P, N)`` -> ``(..., G, N, (H / G) P)``: head ``h``'s channel
+    ``p`` is lane ``(h % (H / G)) P + p`` of group ``h // (H / G)``."""
+    lead, (H, P, N) = S.shape[:-3], S.shape[-3:]
+    return jnp.moveaxis(S.reshape(lead + (G, H // G * P, N)), -1, -2)
+
+
+def from_step_layout(L, H):
+    """The inverse of :func:`to_step_layout`: ``(..., G, N, (H / G) P)`` ->
+    ``(..., H, P, N)``."""
+    lead, (G, N, W) = L.shape[:-3], L.shape[-3:]
+    return jnp.moveaxis(L, -1, -2).reshape(lead + (H, G * W // H, N))
+
+
+def _step_kernel(layer_ref, da_ref, dtx_ref, cols_ref, s_hbm, y_ref, s_out_hbm,
+                 buf, sem, *, H):
+    """Grid (slots / bs,): ``bs`` slots' rows of one layer a step.  ``da``
+    (bs H,) float32 in SMEM, each slot's decay a head; ``dtx`` (bs, G, W)
+    float32, ``dt x`` with a group's ``W = (H / G) P`` (head, channel) on the
+    lanes; ``cols`` (N, bs 2 G) float32, ``N`` on the sublanes and per slot
+    ``B`` and then ``C`` on the lanes; ``y`` (bs, G, W) float32; the state
+    (layers, slots G, N, W) float32 stays in HBM, in and (aliased) out, and a
+    step's ``bs G`` groups pass through one of ``buf``'s three (bs G, N, W).
+
+    A group's state is multiplied and added to vreg for vreg (a row broadcast
+    over the sublanes, a column over the lanes), and the sum over ``N`` is
+    vreg-wise addition with one fold of 8 sublanes a lane tile at its end.
+
+    READS AND WRITES TAKE TURNS.  This chip reads at 92 % of its bandwidth
+    while nothing is written and moves 80 % of it, reads and writes together,
+    as soon as a write is in flight (PERF.md section 6, PR 44).  So step ``i``
+    updates its rows under the read of step ``i + 1``'s, which began in step
+    ``i - 1``, writes its own back when that read has landed, and starts the
+    read of step ``i + 2``'s when they are written.  Each turn goes as two
+    copies, all but the last group and the last group, and the next turn
+    starts between them: the pipe does not drain where the turn changes."""
+    i, n = pl.program_id(0), pl.num_programs(0)
+    layer, (bs, G, W), groups = layer_ref[0], dtx_ref.shape, buf.shape[1]
+    per = H // G
+    # which of its group's heads a lane belongs to
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1) // (W // per)
+    cuts = ((0, groups - 1), (groups - 1, 1)) if groups > 1 else ((0, 1),)
+
+    def turn(j, out):
+        for part, (at, size) in enumerate(cuts):
+            vmem = buf.at[j % 3, pl.ds(at, size)]
+            rows = pl.ds(j * groups + at, size)
+            if out:
+                yield pltpu.make_async_copy(vmem, s_out_hbm.at[layer, rows],
+                                            sem.at[2 + part])
+            else:
+                yield pltpu.make_async_copy(s_hbm.at[layer, rows], vmem,
+                                            sem.at[part])
+
+    @pl.when(i == 0)
+    def _():
+        for c in turn(0, False):
+            c.start()
+        for c in turn(0, False):
+            c.wait()
+
+        @pl.when(n > 1)
+        def _():
+            for c in turn(1, False):
+                c.start()
+
+    mine = buf.at[i % 3]
+
+    for r in range(groups):               # unrolled: a column is a STATIC lane
+        j, g = divmod(r, G)
+        decay = jnp.zeros((1, W), jnp.float32)
+        for k in range(per):
+            decay = jnp.where(head == k, da_ref[j * H + g * per + k], decay)
+        at = j * 2 * G + g
+        b, c = cols_ref[:, at:at + 1], cols_ref[:, at + G:at + G + 1]
+        S = mine[r] * decay + b * dtx_ref[j, g:g + 1, :]
+        mine[r] = S
+        y_ref[j, g:g + 1, :] = jnp.sum(S * c, axis=0, keepdims=True)
+
+    *read_head, read_last = turn(i + 1, False)
+    *write_head, write_last = turn(i, True)
+
+    @pl.when(i + 1 < n)
+    def _():
+        for c in read_head:
+            c.wait()
+    for c in (*write_head, write_last):
+        c.start()
+
+    @pl.when(i + 1 < n)
+    def _():
+        read_last.wait()
+    for c in write_head:
+        c.wait()
+
+    @pl.when(i + 2 < n)
+    def _():
+        for c in turn(i + 2, False):
+            c.start()
+    write_last.wait()
+
+
+def _step_slots(slots, state_bytes):
+    """Slots a grid step: the most of 8, 4, 2, 1 that divide ``slots`` and
+    whose state, three times (the kernel's ``buf``), fits ``_STEP_BUF``."""
+    for bs in (8, 4, 2):
+        if slots % bs == 0 and 3 * bs * state_bytes <= _STEP_BUF:
+            return bs
+    return 1
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _step_call(ssm, layer, decay, dtx, B, C, *, interpret):
     f32 = jnp.float32
-    _, slots, H, P, N = ssm.shape
-    G = B.shape[1]
-    n_da = _smem_block(H)
-    state = pl.BlockSpec((None, None, H, P, N),
-                         lambda i, layer: (layer[0], i, 0, 0, 0))
-    group = pl.BlockSpec((None, G, N), lambda i, layer: (i, 0, 0))
-    cols = pl.BlockSpec((None, P, H), lambda i, layer: (i, 0, 0))
-    yT, ssm = pl.pallas_call(
-        functools.partial(_step_kernel, per=H // G),
+    _, slots, G, N, W = ssm.shape
+    H = decay.shape[1]
+    bs = _step_slots(slots, 4 * G * N * W)
+    n_da = _smem_block(bs * H)
+    da = jnp.pad(decay.astype(f32).reshape(slots // bs, bs * H),
+                 ((0, 0), (0, n_da - bs * H))).reshape(-1)
+    # a step's columns in one tile: N on the sublanes, (slot, B | C, group)
+    # on the lanes (128 of them at 8 slots of 8 groups: nothing padded)
+    cols = jnp.concatenate([B.astype(f32), C.astype(f32)], axis=1).reshape(
+        slots // bs, bs * 2 * G, N).swapaxes(1, 2)
+    per_slot = lambda *dims: pl.BlockSpec((bs,) + dims,
+                                          lambda i, layer: (i, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    y, groups = pl.pallas_call(
+        functools.partial(_step_kernel, H=H),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(slots,),
+            num_scalar_prefetch=1, grid=(slots // bs,),
             in_specs=[pl.BlockSpec((n_da,), lambda i, layer: (i,),
                                    memory_space=pltpu.SMEM),
-                      cols, group, group, state],
-            out_specs=[cols, state]),
-        out_shape=[jax.ShapeDtypeStruct((slots, P, H), f32),
-                   jax.ShapeDtypeStruct(ssm.shape, f32)],
-        input_output_aliases={5: 1},
+                      per_slot(G, W),
+                      pl.BlockSpec((None, N, bs * 2 * G),
+                                   lambda i, layer: (i, 0, 0)), in_hbm],
+            out_specs=[per_slot(G, W), in_hbm],
+            scratch_shapes=[pltpu.VMEM((3, bs * G, N, W), f32),
+                            pltpu.SemaphoreType.DMA((4,))]),
+        out_shape=[jax.ShapeDtypeStruct((slots, G, W), f32),
+                   jax.ShapeDtypeStruct((ssm.shape[0], slots * G, N, W), f32)],
+        input_output_aliases={4: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_STEP_VMEM),
         interpret=interpret, name=STEP_KERNEL,
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.pad(decay.astype(f32), ((0, 0), (0, n_da - H))).reshape(-1),
-      dtx.astype(f32).swapaxes(1, 2),
-      B.astype(f32), C.astype(f32), ssm)
-    return yT.swapaxes(1, 2), ssm
+    )(jnp.asarray(layer, jnp.int32).reshape(1), da,
+      dtx.astype(f32).reshape(slots, G, W), cols,
+      ssm.reshape(ssm.shape[0], slots * G, N, W))
+    return y.reshape(dtx.shape), groups.reshape(ssm.shape)
 
 
 def ssm_step(ssm, layer, x, dt, A, B, C, D, active=None, impl="auto",
              interpret=None):
     """One token for every slot over layer ``layer``'s rows of the serving
-    state ``ssm`` (layers, slots, H, P, N) float32, in place.  ``x`` (slots,
-    H, P); ``dt`` (slots, H) float32; ``B``, ``C`` (slots, G, N); ``active``
-    (slots,) bool or None: a dead slot's rows stay as they are.  Returns ``(y
-    (slots, H, P) in x.dtype, ssm)``; ``impl`` as :func:`ssd_scan`."""
+    state ``ssm`` (layers, slots, G, N, (H / G) P) float32
+    (:func:`step_layout`), in place.  ``x`` (slots, H, P); ``dt`` (slots, H)
+    float32; ``B``, ``C`` (slots, G, N); ``active`` (slots,) bool or None: a
+    dead slot's rows stay as they are.  Returns ``(y (slots, H, P) in
+    x.dtype, ssm)``; ``impl`` as :func:`ssd_scan`.  float32 throughout in
+    either form; the kernel's ``y`` sums its 128 terms in another order."""
     f32 = jnp.float32
     if impl == "auto":
         impl = "jnp" if _interpret() else "kernel"
     if impl == "jnp":
-        S = ssm[layer]
+        S = from_step_layout(ssm[layer], x.shape[1])
         y, S2 = ssm_step_jnp(x, dt, A, B, C, D, S)
         if active is not None:
             S2 = jnp.where(active[:, None, None, None], S2, S)
-        return y, ssm.at[layer].set(S2)
+        return y, ssm.at[layer].set(to_step_layout(S2, B.shape[1]))
     dt, xf = dt.astype(f32), x.astype(f32)
     decay, dtx = jnp.exp(dt * A.astype(f32)), dt[..., None] * xf
     if active is not None:

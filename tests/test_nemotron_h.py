@@ -187,13 +187,29 @@ def test_scan_dispatch_refuses_a_kernel_with_an_initial_state():
         m2.ssd_scan(x, dt, A, B, C, D, h0=h0, impl="kernel")
 
 
-def test_one_token_update_in_place_and_a_dead_slot_untouched():
+def low_bits_share(S):
+    """The share of ``S``'s elements whose 16 low mantissa bits are not all
+    zero: about 1 for float32 arithmetic, 0 after a pass through bfloat16."""
+    bits = jax.lax.bitcast_convert_type(S, jnp.uint32) & 0xFFFF
+    return float((bits != 0).mean())
+
+
+@pytest.mark.parametrize("dims, slots", [
+    (dict(H=4, P=8, G=2, N=16), [True, False, True, True, False]),
+    # slots in pairs, so the kernel's reads and writes take three turns
+    (dict(H=4, P=8, G=2, N=16), [True, False, True, True, False, True]),
+    # ONE slot and layer at the published shape: 3 x 3 x 2 MB
+    (dict(H=64, P=64, G=8, N=128), [False, True, False]),
+])
+def test_one_token_update_in_place_and_a_dead_slot_untouched(dims, slots):
     """The kernel (interpreted) against the ``jax.numpy`` form over layer 1
-    of a three-layer state: the other layers and the dead slots' rows keep
-    every bit."""
-    x, dt, A, B, C, D, h0 = scan_operands(1, Bt=5)
+    of a three-layer state in the update's own layout: the other layers and
+    the dead slots' rows keep every bit, and what is written is float32."""
+    live = jnp.asarray(slots)
+    x, dt, A, B, C, D, h0 = scan_operands(1, Bt=len(slots), **dims)
+    h0 = m2.to_step_layout(0.1 * h0, dims["G"])
+    assert h0.shape[1:] == m2.step_layout(**dims)
     ssm = jnp.stack([0.5 * h0, h0, 2.0 * h0])
-    live = jnp.asarray([True, False, True, True, False])
     args = (x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], D)
     y_j, s_j = m2.ssm_step(ssm, 1, *args, active=live, impl="jnp")
     y_k, s_k = m2.ssm_step(ssm, 1, *args, active=live, impl="kernel",
@@ -204,6 +220,26 @@ def test_one_token_update_in_place_and_a_dead_slot_untouched():
         assert bool((s[1][~live] == ssm[1][~live]).all())
         assert bool((s[0] == ssm[0]).all()) and bool((s[2] == ssm[2]).all())
         assert float(jnp.abs(s[1][live] - ssm[1][live]).max()) > 0.0
+        assert low_bits_share(s[1][live]) > 0.99
+
+
+def test_the_updates_layout_is_the_states_with_n_on_the_sublanes():
+    """``to_step_layout``: head ``h``'s channel ``p`` and column ``n`` lie at
+    group ``h // (H / G)``, sublane ``n``, lane ``(h % (H / G)) P + p``; the
+    way back is its inverse, and one update through the leaf is
+    ``ssm_step_jnp`` over the published orientation."""
+    x, dt, A, B, C, D, h0 = scan_operands(1, Bt=3, H=6, P=4, G=3, N=8)
+    leaf = m2.to_step_layout(h0, 3)
+    assert leaf.shape == (3,) + m2.step_layout(6, 4, 8, 3) == (3, 3, 8, 8)
+    for h, p, n in [(0, 0, 0), (1, 3, 7), (4, 2, 5), (5, 1, 6)]:
+        assert float(leaf[2, h // 2, n, (h % 2) * 4 + p]) == \
+            float(h0[2, h, p, n])
+    assert bool((m2.from_step_layout(leaf, 6) == h0).all())
+    args = (x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], D)
+    y, new = m2.ssm_step(leaf[None], 0, *args, impl="jnp")
+    y_ref, s_ref = m2.ssm_step_jnp(*args, h0)
+    assert bool((y == y_ref).all())
+    assert bool((m2.from_step_layout(new[0], 6) == s_ref).all())
 
 
 def test_grouped_gated_norm_is_not_one_mean_square():
@@ -615,6 +651,49 @@ def test_an_inactive_row_keeps_its_recurrent_rows(model_params):
     assert float(jnp.abs(new["conv"][:, 1] - 2.0).max()) == 0.0
     assert float(jnp.abs(new["ssm"][:, 0] - 1.0).max()) > 0.0
     assert int(new["counters"][0] + new["counters"][1]) == 4 * 2   # 1 live
+
+
+def test_the_seat_and_the_step_agree_on_the_states_layout(model_params):
+    """A prompt through ``prefill_paged`` (its length no multiple of the
+    chunk), then three ``decode_step_paged`` tokens: the first layer's rows of
+    the slot are the token-by-token ``ssm_step_jnp`` recurrence from zero over
+    the same operands.  Every head has a decay of its own and every group a
+    ``B`` and a ``C`` of its own, so a leaf that the seat lays out otherwise
+    than the step reads it (transposed, or its heads in other groups) stands
+    orders above the rounding; and what the step writes is float32."""
+    m, params = model_params
+    c = m.config
+    toks = tokens(11, 1, 14)
+    prompt, slot = 11, 1
+    pool = m.init_serving_state(3, 6, 8, dtype=jnp.float32)
+    assert pool["ssm"].shape == (3, 3) + m2.step_layout(
+        c.mamba_num_heads, c.mamba_head_dim, c.ssm_state_size, c.n_groups)
+    padded = np.zeros((1, 16), np.int32)
+    padded[0, :prompt] = toks[0, :prompt]
+    _, pool = m.prefill_paged(params, jnp.asarray(padded), pool,
+                              jnp.asarray([1, 2], jnp.int32), slot, prompt)
+    tables = jnp.asarray([[0, 0], [1, 2], [0, 0]], jnp.int32)
+    for t in range(prompt, 14):
+        step_toks = jnp.asarray([0, toks[0, t], 0], jnp.int32)
+        _, pool = m.decode_step_paged(params, step_toks, pool, tables,
+                                      jnp.asarray([0, t, 0], jnp.int32))
+    # the first layer is a mixer: its input is the embedding
+    p0 = jax.tree_util.tree_map(lambda a: a[0], params["mamba"])
+    x, _, dt, B, C, _ = m._scan_inputs(p0, m._embed(params, jnp.asarray(toks)),
+                                       None)
+    A = m._A(p0)
+    assert len(set(np.asarray(A).round(4))) == c.mamba_num_heads
+    _, S = recurrence(x, dt, A, B, C, p0["D"],
+                      jnp.zeros((1, c.mamba_num_heads, c.mamba_head_dim,
+                                 c.ssm_state_size)))
+    got = m2.from_step_layout(pool["ssm"][0, slot], c.mamba_num_heads)
+    assert rel_err(got, S[0]) < 1e-5
+    # the leaf read as if it lay in the published orientation does not pass
+    assert rel_err(pool["ssm"][0, slot].reshape(S[0].shape), S[0]) > 1e-2
+    assert low_bits_share(pool["ssm"][:, slot]) > 0.99
+    # the other slots were never seated
+    assert float(jnp.abs(pool["ssm"][:, 0]).max()) == 0.0
+    assert float(jnp.abs(pool["ssm"][:, 2]).max()) == 0.0
 
 
 # ---------------------------------------------------------- (e) sensitivity

@@ -387,6 +387,91 @@ def test_online_kernel_compiles_for_a_v5e(v5e, slots, heads, kv_heads,
     assert not re.search(re.escape(" = " + payload) + r"\S* copy\(", text)
 
 
+# ---- the other kernel of a decode step that writes in place: Mamba-2's update
+def test_state_update_kernel_compiles_for_a_v5e(v5e):
+    """``ops/mamba2.py``'s one-token update at the served shape (7 Mamba
+    layers x 256 slots x 2 MB, Nemotron-3-Nano), through Mosaic and XLA:TPU
+    for a described v5e: one custom call, the 3.67 GB leaf aliased to its
+    output (a copy of it is a quarter of the chip), and the kernel's three
+    buffers of 8 slots inside its VMEM limit."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.ops import mamba2
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    L, slots, H, P, N, G = 7, 256, 64, 64, 128, 8
+    leaf = (L, slots) + mamba2.step_layout(H, P, N, G)
+    assert leaf == (7, 256, 8, 128, 512)
+    assert mamba2._step_slots(slots, 4 * H * P * N) == 8
+    f32 = jnp.float32
+    shapes = [(leaf, f32), ((), jnp.int32), ((slots, H), f32),
+              ((slots, H, P), f32), ((slots, G, N), f32), ((slots, G, N), f32)]
+    exe = jax.jit(lambda *a: mamba2._step_call(*a, interpret=False),
+                  donate_argnums=(0,)).trace(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    ]).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line)
+    assert mamba2.STEP_KERNEL in call
+    assert "output_to_operand_aliasing={{1}: (4, {})}" in call
+    state = 4 * int(np.prod(leaf))
+    m = exe.memory_analysis()
+    assert m.alias_size_in_bytes == state
+    assert m.temp_size_in_bytes < 64 * 2 ** 20
+    assert not re.search(r"f32\[7,(256,8|2048),128,512\]\S* copy\(", text)
+
+
+def test_a_decode_step_updates_the_recurrent_state_where_it_lies(v5e,
+                                                                 monkeypatch):
+    """``NemotronH.decode_step_paged`` at the published widths, 256 slots,
+    cut to two Mamba-2 layers round an attention layer: both updates are the
+    named kernel over the leaf as it lies (aliased through the step, never
+    copied, never transposed), ``dt x`` and ``y`` cross the call as ``(slots,
+    H, P)`` in ``x``'s own order, and what XLA re-lays round a call is the
+    three ``(256, 64, 64)``-sized operands at most (``dt x``, the decay rows
+    and ``y``: the step's activations lie with the SLOTS minor, XLA's choice
+    for a one-token stream, and the kernel takes rows)."""
+    import importlib
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.models import nemotron_h
+    from deepspeed_tpu.ops import mamba2
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    monkeypatch.setattr(importlib.import_module(
+        "deepspeed_tpu.ops.transformer.paged_attention"), "_interpret",
+        lambda: False)
+    monkeypatch.setattr(mamba2, "_interpret", lambda: False)
+    slots, blocks = 256, 1024
+    model = nemotron_h.NemotronH(nemotron_h.NemotronHConfig(
+        num_hidden_layers=3, hybrid_override_pattern="M*M",
+        vocab_held=(0, 2048)), dtype=jnp.bfloat16)
+    on = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(on, jax.eval_shape(
+        lambda: model.init_serving_state(slots, blocks, 64)))
+    assert pool["ssm"].shape == (2, 256, 8, 128, 512)
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                               sharding=one_chip)
+    exe = jax.jit(model.decode_step_paged, donate_argnums=(2,)).trace(
+        params, ints(slots), pool, ints(slots, 4096 // 64), ints(slots)
+    ).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and mamba2.STEP_KERNEL in line]
+    assert len(calls) == 2
+    for call in calls:
+        assert "output_to_operand_aliasing={{1}: (4, {})}" in call
+    state = 4 * int(np.prod(pool["ssm"].shape))
+    assert exe.memory_analysis().alias_size_in_bytes >= state
+    assert not re.search(
+        r"f32\[2,(256,8|2048),128,512\]\S* (copy|transpose)\(", text)
+    relaid = re.findall(
+        r" = f32\[256,(?:64,64|8,512|1,4096)\]\S* (?:copy|transpose)\(", text)
+    assert len(relaid) <= 3 * len(calls)
+
+
 # ------------------------------------------------ a window layer's ring walk
 # name: (block, window, rows' lengths; None: a dead row)
 RING_CASES = {
