@@ -619,11 +619,7 @@ def _audit_paged_attention():
     clean executable — zero host callbacks (DSTPU201), pool donation
     honored (DSTPU204) — with **no gathered K/V materialization in the
     jaxpr** (the census above; the gather-fallback twin must trip the
-    same census, proving the detector sees what the kernel deleted).
-    Speculative decoding armed must (a) keep the armed scoring step
-    just as clean and (b) produce TOKEN-IDENTICAL outputs to the
-    disarmed engine (greedy and sampled) — the determinism contract's
-    acceptance-semantics half."""
+    same census, proving the detector sees what the kernel deleted)."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -636,7 +632,7 @@ def _audit_paged_attention():
     bs, H = 8, 4
     params_cache = {}
 
-    def build(paged_impl, speculative=None, kv_bits=16):
+    def build(paged_impl, kv_bits=16):
         cfg = GPT2Config(vocab_size=64, max_seq=32, n_embd=32, n_layer=2,
                          n_head=H, embd_pdrop=0.0, attn_pdrop=0.0,
                          resid_pdrop=0.0, attention_impl="jnp",
@@ -648,13 +644,12 @@ def _audit_paged_attention():
             model=model, params=params_cache["p"],
             config=ServingConfig(batch_slots=2, block_size=bs,
                                  kv_bits=kv_bits, max_new_tokens=6,
-                                 preflight=False,
-                                 speculative=speculative))
+                                 preflight=False))
 
     findings = []
     hd = 32 // H
 
-    # (1) kernel decode step, 16-bit and int8 pools: clean audit + the
+    # kernel decode step, 16-bit and int8 pools: clean audit + the
     # zero-gather census
     for kv_bits in (16, 8):
         srv = build("kernel", kv_bits=kv_bits)
@@ -689,44 +684,6 @@ def _audit_paged_attention():
             "blind and the kernel's zero-gather verdict above proves "
             "nothing", eqn_path="paged-attn/census-sanity"))
     srv_g.close()
-
-    # (2) speculative decode: armed engine == disarmed engine, token
-    # for token (greedy AND sampled), and the armed step audits clean
-    def traffic():
-        return [Request(tokens=np.tile(np.arange(4), 3),
-                        max_new_tokens=6, uid=1),
-                Request(tokens=np.arange(5) % 3, max_new_tokens=5,
-                        uid=2, do_sample=True, temperature=0.8, seed=7)]
-
-    plain_srv = build("kernel")
-    plain = plain_srv.run(traffic())
-    plain_srv.close()
-    spec_srv = build("kernel", speculative={"k": 3})
-    spec = spec_srv.run(traffic())
-    for uid in (1, 2):
-        if plain[uid]["tokens"] != spec[uid]["tokens"]:
-            findings.append(Finding(
-                "DSTPU200", "error",
-                f"--audit-step paged-attn: speculative decode diverged "
-                f"from the autoregressive path on uid {uid} "
-                f"(plain={plain[uid]['tokens']}, "
-                f"spec={spec[uid]['tokens']}) — acceptance must be "
-                f"'the token the model would have sampled anyway'",
-                eqn_path="paged-attn/spec-equivalence"))
-    report = audit_fn(spec_srv._decode, *spec_srv._decode_args(),
-                      donate_argnums=(1,), mesh=spec_srv.engine.mesh)
-    for f in report.findings:
-        f.extra = dict(f.extra, audit="paged-attn-spec")
-    findings.extend(report.findings)
-    jaxpr_s = jax.make_jaxpr(spec_srv._decode)(*spec_srv._decode_args())
-    if _kv_gather_eqns(jaxpr_s, bs, H, hd):
-        findings.append(Finding(
-            "DSTPU206", "error",
-            "--audit-step paged-attn: the speculative scoring step "
-            "materializes gathered K/V — the kernel path must cover "
-            "multi-token windows too",
-            eqn_path="paged-attn/spec-zero-gather"))
-    spec_srv.close()
     return findings
 
 
@@ -1348,9 +1305,8 @@ def main(argv=None):
                          "attention kernel decode step (zero host "
                          "callbacks, pool donation honored, NO gathered "
                          "K/V materialization in the jaxpr — census "
-                         "sanity-checked against the gather fallback) "
-                         "and speculative-decode armed-vs-disarmed "
-                         "token equivalence (docs/serving.md); "
+                         "sanity-checked against the gather fallback; "
+                         "docs/serving.md); "
                          "'elastic' audits the first resharded step after "
                          "an elastic resume on half the devices "
                          "(docs/elasticity.md); 'moe' audits the quantized "

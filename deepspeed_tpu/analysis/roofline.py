@@ -48,8 +48,7 @@ DEFAULT_WARMUP_STEPS = 2
 
 def gather_materialization_bytes(*, n_layer, batch_slots, nb_max,
                                  block_size, n_head, head_dim,
-                                 itemsize, paged_impl="gather",
-                                 n_window=1) -> int:
+                                 itemsize, paged_impl="gather") -> int:
     """HBM traffic of the paged decode's gather materialization, per
     decode step — FOR THE LIVE IMPLEMENTATION.
 
@@ -61,13 +60,10 @@ def gather_materialization_bytes(*, n_layer, batch_slots, nb_max,
     ``ops/transformer/paged_attention.py``) DMAs blocks straight from
     the pool: the term is **0**, and ``ds_explain`` proves the bytes
     are gone rather than keeping a modeled cost the implementation no
-    longer pays.  ``n_window`` scales the window width (speculative
-    scoring steps gather once per step regardless of window, so the
-    term is window-independent; kept explicit for clarity)."""
+    longer pays."""
     if paged_impl == "kernel":
         return 0
     assert paged_impl == "gather", f"unknown paged_impl {paged_impl!r}"
-    del n_window                             # gather is per step, not per row
     copy = 2 * n_layer * batch_slots * nb_max * block_size \
         * n_head * head_dim * itemsize       # K + V materialized copies
     return 2 * copy                          # written, then read

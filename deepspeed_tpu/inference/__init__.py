@@ -5,8 +5,8 @@ shedding, quarantine, crash-recoverable journal — ``journal.py``) is
 this repo's production-traffic addition (docs/serving.md)."""
 
 from .engine import InferenceEngine
-from .serving import (ServingConfig, ServingEngine, SpeculativeConfig,
-                      PrefixCacheConfig, describe_prefix_cache,
+from .serving import (ServingConfig, ServingEngine, PrefixCacheConfig,
+                      describe_prefix_cache,
                       Request, ServingError, QueueFullError,
                       ServingStalledError, CircuitOpenError,
                       OK, SHED, DEADLINE, POISONED, OUTCOMES)
@@ -15,7 +15,7 @@ from .router import (ReplicaRouter, RouterConfig, ReplicaHandle,
                      HEALTHY, SUSPECT, DRAINING, DEAD)
 
 __all__ = ["InferenceEngine", "ServingEngine", "ServingConfig",
-           "SpeculativeConfig", "PrefixCacheConfig",
+           "PrefixCacheConfig",
            "describe_prefix_cache", "Request",
            "ServingError", "QueueFullError", "ServingStalledError",
            "CircuitOpenError", "OK", "SHED", "DEADLINE", "POISONED",

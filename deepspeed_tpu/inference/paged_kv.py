@@ -499,10 +499,11 @@ def write_tokens(pool, layer, block_tables, lengths, k, v, ring=False):
     (B, nb_max) int32; ``lengths``: (B,) int32 — the FIRST window
     token's position (window token i lands at ``lengths + i``);
     ``k``/``v``: (B, W, H, hd) in compute dtype, ``H`` the pool's K/V
-    heads (W=1 is plain decode; W=k+1 is the speculative scoring window).  Slots whose tables are
+    heads (W=1 is the serving decode step; no caller in the product passes
+    more, ROADMAP D17).  Slots whose tables are
     all-scratch write into block 0 (discarded), and a window position
-    that overflows the table (a speculative draft running past the
-    slot's allocation) is REDIRECTED to the scratch block instead of
+    that overflows the table (a window running past the slot's
+    allocation) is REDIRECTED to the scratch block instead of
     letting the gather clamp silently overwrite the table's last real
     block — any token whose logits depend on such a position is beyond
     ``max_new`` and truncated by the scheduler anyway.  ``ring``: the

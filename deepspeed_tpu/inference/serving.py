@@ -27,7 +27,7 @@ MII/externals.  This module is that missing layer, built TPU-first:
   decode step N+1 on the device's own advanced state BEFORE it reads
   step N, so the chip computes while the host wakes up, reads and books;
   whatever changes a slot or needs the host's view of a token (an
-  admission, a finish, speculation, a snapshot, a drain, any outside
+  admission, a finish, a snapshot, a drain, any outside
   reader of slot state) settles the unread step first
   (docs/serving.md#one-step-in-flight);
 - **admission control** — capacity math (blocks needed vs free) gates
@@ -149,92 +149,6 @@ class CircuitOpenError(ServingError):
     """The poison circuit breaker tripped: new submissions are rejected
     until the operator investigates (the forensic dump path is in the
     message and on the monitor bus)."""
-
-
-@dataclasses.dataclass
-class SpeculativeConfig:
-    """The ``serving.speculative`` block (docs/serving.md#speculative-
-    decoding): self-drafting n-gram speculation over the paged decode.
-
-    Per scheduler step the drafter proposes ``k`` tokens per live slot
-    (``draft: "ngram"`` — the most recent previous occurrence of the
-    slot's tail ``ngram``-gram, falling back to shorter grams then to
-    last-token repeat), the fused scan scores current + k drafts in ONE
-    decode dispatch, and the per-slot accept length is computed
-    in-graph.  Accept/reject is a pure function of the request
-    (seed + committed tokens), so outputs are TOKEN-IDENTICAL to plain
-    autoregressive decode under any arrival order/co-batching — a
-    drafted token is accepted iff it equals the token the model would
-    have sampled anyway."""
-    k: int = 4                      # drafted tokens per slot per step
-    draft: str = "ngram"            # the only drafter (self-drafting)
-    ngram: int = 3                  # longest tail gram the drafter matches
-
-    def __post_init__(self):
-        assert self.k >= 1, f"speculative.k must be >= 1, got {self.k}"
-        assert self.draft == "ngram", \
-            f"speculative.draft must be 'ngram', got {self.draft!r}"
-        assert self.ngram >= 1, \
-            f"speculative.ngram must be >= 1, got {self.ngram}"
-
-    @classmethod
-    def from_value(cls, v):
-        """None/False → off; True → defaults; dict → the JSON block."""
-        if not v:
-            return None
-        if v is True:
-            return cls()
-        if isinstance(v, cls):
-            return v
-        known = {f.name for f in dataclasses.fields(cls)}
-        unknown = set(v) - known
-        if unknown:
-            raise ValueError(
-                f"unknown serving.speculative keys: {sorted(unknown)} "
-                f"(known: {sorted(known)})")
-        return cls(**v)
-
-
-# the drafter's search window over each slot's committed history: a
-# fixed rule (the LAST `DRAFT_WINDOW` tokens), so drafting stays a pure
-# function of the history (replay/replica-deterministic) while the
-# per-step host cost stays O(window), not O(generated-so-far)
-DRAFT_WINDOW = 1024
-
-
-def ngram_draft(history, k: int, ngram: int):
-    """Self-drafting proposal: the ``k`` tokens that followed the most
-    recent PREVIOUS occurrence of the history's tail n-gram (longest
-    gram first, shorter grams as fallback; last-token repeat when
-    nothing matches).  A pure function of the slot's committed token
-    history — the determinism contract's drafter half: replicas,
-    journal replays and permuted arrivals all draft identically.
-
-    Greedy decode of a fixed model frequently falls into repeating
-    loops, which is exactly this drafter's best case (the classic
-    prompt-lookup/self-speculation observation)."""
-    h = np.asarray(history, np.int64)
-    L = h.size
-    out = np.full((k,), int(h[-1]) if L else 0, np.int32)
-    if L < 2:
-        return out
-    for order in range(min(ngram, L - 1), 0, -1):
-        tail = h[L - order:]
-        # all previous windows of length `order` (the last one, ending
-        # at L, IS the tail — excluded)
-        n_win = L - order
-        win = np.lib.stride_tricks.sliding_window_view(h, order)[:n_win]
-        hits = np.nonzero((win == tail).all(axis=1))[0]
-        if hits.size == 0:
-            continue
-        start = int(hits[-1]) + order       # continuation of the match
-        cont = h[start:start + k]
-        if cont.size == 0:
-            continue
-        out[:cont.size] = cont
-        out[cont.size:] = int(cont[-1])
-        return out
-    return out
 
 
 # ------------------------------------------- KV snapshot/migration config
@@ -412,13 +326,6 @@ class ServingConfig:
     # Sampling is a pure function of the uid, so replicas/restarts
     # sample the same requests.  0.0 = off; needs an armed monitor.
     trace_sample_rate: float = 0.0
-    # ---- speculative decoding (docs/serving.md#speculative-decoding) ----
-    # None/false = off; true = defaults; or the JSON block
-    # {"k": 4, "draft": "ngram", "ngram": 3}.  Token-identical to plain
-    # autoregressive decode (acceptance == "the model would have
-    # sampled this token anyway"); per-request acceptance stats ride
-    # the monitor bus.
-    speculative: Any = None
     # ---- shadow sanitizer (docs/static-analysis.md#sanitizer) ----
     # None → resolve from env DSTPU_SANITIZE / `deepspeed --sanitize`
     # (OFF by default); True/False pin it.  Pure host-side shadow
@@ -487,7 +394,7 @@ class Request:
 
 def _pack_read(*parts):
     """In-graph: everything the host reads after a decode dispatch — (B,)
-    or (B, W) parts — as ONE (B, columns) int32 array: one device-to-host
+    or (B, n) parts — as ONE (B, columns) int32 array: one device-to-host
     copy, not one per value.  The poison flag rides beside the token: the
     sentinel token is a valid id."""
     return jnp.concatenate(
@@ -518,14 +425,9 @@ class _Slot:
         self.prompt_len = prompt_len
         self.max_new = max_new
         self.out_tokens: List[int] = []
-        # committed token history (prompt + emitted), maintained
-        # incrementally for the speculative drafter — rebuilding
-        # prompt+outputs with np.concatenate every scheduler step is
-        # O(history) host work per live slot in the hot loop
+        # committed token history (prompt + emitted), kept as the tokens
+        # arrive: what the prefix cache keys a finished stream's blocks by
         self.hist: List[int] = [int(t) for t in np.asarray(req.tokens)]
-        # speculative-decode acceptance accounting (per request)
-        self.spec_proposed = 0
-        self.spec_accepted = 0
         # ---- prefix sharing (docs/serving.md#prefix-sharing) ----
         # pending is None on the plain path; a prefix-hit slot seats
         # with the not-yet-ingested prompt tail here and replays it
@@ -541,6 +443,59 @@ class _Slot:
         # refused (backpressure / publish failure) decodes LOCALLY —
         # the per-stream degrade-to-mixed latch
         self.no_transfer = False
+
+
+# ------------------------------------------- what needs a stream to be its blocks
+# `prefix_cache`, `kv_snapshot`, `transfer` and a `role` each move or share a
+# stream BY its K/V blocks: they need a stream to be nothing but the `k` / `v`
+# blocks of one growing table.  Three kinds of serving state are more than
+# that, and serving one with such a feature armed would serve a silently wrong
+# stream: refused at construction, by name, until the feature learns the kind
+# (ROADMAP, Queue 2).  kind -> (what the error calls it, {feature: reason});
+# the kind is also its anchor in docs/serving.md.
+_SHIPS_IMAGES = ("the transfer queue ships block images: the decode side would "
+                 "seat K/V without the recurrent rows")
+_RING_IMAGE = ("a block image covers the growing table's blocks alone: a "
+               "restored stream's window layers would read another stream's "
+               "ring")
+_KV_IMAGE = "a block image is int8 K and V with scales a head"
+_NEEDS_BLOCKS_ALONE = {
+    "recurrent-state": ("recurrent state", {
+        "prefix_cache": "a shared prefix's blocks carry no recurrent state: "
+                        "the borrower's scan would start from zeros, not from "
+                        "the prefix's end",
+        "kv_snapshot": "a block image holds K/V only: a restored stream would "
+                       "resume with another stream's recurrent rows",
+        "transfer": _SHIPS_IMAGES, "role": _SHIPS_IMAGES}),
+    "window-layers": ("sliding-window layers", {
+        "prefix_cache": "a ring block is overwritten as the window slides: a "
+                        "prefix's window-layer blocks cannot be shared "
+                        "read-only",
+        "kv_snapshot": _RING_IMAGE, "transfer": _RING_IMAGE,
+        "role": _RING_IMAGE}),
+    "latent-pool": ("a latent KV pool", {
+        "prefix_cache": "the radix cache's copy-on-write is written for k and "
+                        "v leaves",
+        "kv_snapshot": _KV_IMAGE, "transfer": _KV_IMAGE, "role": _KV_IMAGE}),
+}
+
+
+def _refuse_what_needs_blocks_alone(config, model, pool):
+    """Raise, by name, for the first feature of ``_NEEDS_BLOCKS_ALONE`` that
+    ``config`` arms over the first kind of state that ``model`` and its
+    ``pool`` show."""
+    shown = {"recurrent-state": getattr(model, "has_recurrent_state", False),
+             "window-layers": getattr(model, "has_window_layers", False),
+             "latent-pool": pk.is_latent_pool(pool)}
+    for kind, (what, why) in _NEEDS_BLOCKS_ALONE.items():
+        if not shown[kind]:
+            continue
+        for name, reason in why.items():
+            value = getattr(config, name)
+            if value not in (None, False, "mixed"):
+                raise ValueError(
+                    f"serving.{name}={value!r} cannot serve a model with "
+                    f"{what}: {reason} (docs/serving.md#{kind})")
 
 
 class ServingEngine:
@@ -588,9 +543,6 @@ class ServingEngine:
         assert 0.0 <= config.trace_sample_rate <= 1.0, \
             f"serving.trace_sample_rate must be in [0, 1], " \
             f"got {config.trace_sample_rate!r}"
-        # speculative decoding (docs/serving.md#speculative-decoding):
-        # None = plain one-token autoregressive decode
-        self.spec = SpeculativeConfig.from_value(config.speculative)
 
         # quantized-weight routing: the SAME helper InferenceEngine
         # .generate uses (models whose decode consumes int8 leaves
@@ -618,8 +570,6 @@ class ServingEngine:
         # (docs/serving.md#recurrent-state) — ONE pytree, donated whole
         # through every prefill and decode dispatch
         self._recurrent = bool(getattr(inner, "has_recurrent_state", False))
-        if self._recurrent:
-            self._refuse_for_recurrent_state(config)
         # a model with sliding-window layers keeps TWO kinds of block
         # (docs/serving.md#window-layers): the growing table's, and a ring
         # of `ring` entries over its window layers with an allocator of
@@ -628,7 +578,6 @@ class ServingEngine:
         self.window_allocator = None
         state_kw = {}
         if getattr(inner, "has_window_layers", False):
-            self._refuse_for_window_pool(config)
             self.ring = inner.ring_entries(config.block_size)
             self.window_num_blocks = config.window_num_blocks or (
                 1 + config.batch_slots * self.ring)
@@ -649,8 +598,7 @@ class ServingEngine:
         self._state_step_bytes = (inner.state_step_bytes()
                                   if hasattr(inner, "state_step_bytes")
                                   else None)
-        if pk.is_latent_pool(self.pool):
-            self._refuse_for_latent_pool(config)
+        _refuse_what_needs_blocks_alone(config, inner, self.pool)
         # what the model's layers count in a dispatch (an expert layer's
         # routed pairs): a small leaf of its serving state, read back with
         # the step's tokens and written into the step's span
@@ -817,11 +765,6 @@ class ServingEngine:
         # (terminal, bad) totals at the last error_rate emission — the
         # SLO engine's windowed error-rate series (monitor/slo.py)
         self._err_window_last = (0, 0)
-        # speculative-decode acceptance accounting (drafted vs accepted
-        # draft tokens; the bonus token after a fully-accepted window is
-        # free and not counted on either side)
-        self._spec_proposed_total = 0
-        self._spec_accepted_total = 0
         # prefix-sharing accounting (counted once per SEATED request)
         self._prefix_requests_total = 0
         self._prefix_hits_total = 0
@@ -835,7 +778,6 @@ class ServingEngine:
         self._step_ema_s = None   # measured decode-step wall EMA (the
         self._step_last_s = None  # predictive-deadline denominator; see
         #                           _step_estimate_s for the fast-bias)
-        self._spec_rate_ema = None  # emitted tokens/slot/step EMA (spec)
         # bounded ring of recent terminal outcomes: the poison-rate
         # window AND the breaker's forensic payload (PR-9 RingBuffer)
         self._recent = RingBuffer(max(1, int(config.poison_window)))
@@ -862,79 +804,6 @@ class ServingEngine:
             f"blocks={self.num_blocks} (nb_max={self.nb_max}) "
             f"kv_bits={config.kv_bits} "
             f"pool={pk.pool_bytes(self.pool) / 1e6:.1f} MB", ranks=[0])
-
-    @staticmethod
-    def _refuse_for_recurrent_state(config):
-        """A model with recurrent layers keeps, per stream, state that is
-        not in its K/V blocks.  Every feature below moves, shares or rolls
-        back a stream BY its blocks, so each would serve a silently wrong
-        stream: refused here, by name, until it learns the recurrent rows
-        (ROADMAP, Queue 2)."""
-        ships_images = ("the transfer queue ships block images: the decode "
-                        "side would seat K/V without the recurrent rows")
-        why = {
-            "prefix_cache": "a shared prefix's blocks carry no recurrent "
-                            "state: the borrower's scan would start from "
-                            "zeros, not from the prefix's end",
-            "kv_snapshot": "a block image holds K/V only: a restored "
-                           "stream would resume with another stream's "
-                           "recurrent rows",
-            "speculative": "its rollback is not advancing `lengths`; a "
-                           "recurrence has consumed the rejected drafts "
-                           "and cannot un-consume them",
-            "transfer": ships_images,
-            "role": ships_images,
-        }
-        ServingEngine._refuse_armed(config, why, "recurrent state",
-                                    "recurrent-state")
-
-    @staticmethod
-    def _refuse_armed(config, why, what, anchor):
-        """Raise, by name, for the first feature of ``why`` (name ->
-        reason) that ``config`` arms."""
-        for name, reason in why.items():
-            value = getattr(config, name)
-            if value not in (None, False, "mixed"):
-                raise ValueError(
-                    f"serving.{name}={value!r} cannot serve a model with "
-                    f"{what}: {reason} (docs/serving.md#{anchor})")
-
-    @staticmethod
-    def _refuse_for_window_pool(config):
-        """A model with sliding-window layers keeps a second kind of block
-        whose table is a ring: a block there holds whatever positions the
-        window has reached, not a fixed stretch of the stream.  What moves,
-        shares or re-reads a stream BY its blocks has not learned the ring:
-        refused by name (ROADMAP, Queue 2)."""
-        image = ("a block image covers the growing table's blocks alone: a "
-                 "restored stream's window layers would read another "
-                 "stream's ring")
-        why = {"prefix_cache": "a ring block is overwritten as the window "
-                               "slides: a prefix's window-layer blocks "
-                               "cannot be shared read-only",
-               "kv_snapshot": image, "transfer": image, "role": image,
-               "speculative": "the window kernel attends one query token a "
-                              "slot, and a ring has overwritten what a "
-                              "rejected draft's rollback would re-read"}
-        ServingEngine._refuse_armed(config, why, "sliding-window layers",
-                                    "window-layers")
-
-    @staticmethod
-    def _refuse_for_latent_pool(config):
-        """A latent pool (``paged_kv.init_latent_pool``) has one leaf where
-        every other pool has ``k`` and ``v``, and its kernel attends one
-        token a slot.  What moves, shares or re-reads a stream BY its K/V
-        leaves has not learned the row yet: refused by name (ROADMAP, Queue
-        2)."""
-        image = "a block image is int8 K and V with scales a head"
-        why = {"prefix_cache": "the radix cache's copy-on-write and the "
-                               "prompt tail's window steps are written for "
-                               "k and v leaves",
-               "kv_snapshot": image, "transfer": image, "role": image,
-               "speculative": "the latent kernel attends one query token a "
-                              "slot, not a draft window"}
-        ServingEngine._refuse_armed(config, why, "a latent KV pool",
-                                    "latent-pool")
 
     # ------------------------------------------------------------- recovery
     def _recover(self, state):
@@ -1389,23 +1258,12 @@ class ServingEngine:
         self._state_dirty = False
         self._state_uploads += 1
 
-    def _operands(self, toks=None):
+    def _operands(self):
         """The decode executable's nine operands as they stand: params,
-        pool and the resident slot state.  With speculation armed the
-        token operand is the host-made (B, k+1) window
-        [current, draft_1..draft_k], sent up every step; ``toks=None``
-        (preflight/audit/pricing callers) sends a window whose draft
-        columns repeat the current token — same shapes, same program."""
-        tables, lengths, cur, seeds, ngen, temps, flags = self._resident
-        if self.spec is not None:
-            if toks is None:
-                toks = np.repeat(self._toks[:, None], self.spec.k + 1,
-                                 axis=1)
-            cur = jnp.asarray(toks)
-        return (self.engine.params, self.pool, tables, lengths, cur, seeds,
-                ngen, temps, flags)
+        pool and the resident slot state."""
+        return (self.engine.params, self.pool, *self._resident)
 
-    def _decode_args(self, toks=None):
+    def _decode_args(self):
         """The nine live operands of the NEXT decode step, for a reader
         outside :meth:`step`: the unread step, if there is one, is settled
         first, so the operands equal the mirrors and the slots' token
@@ -1413,7 +1271,7 @@ class ServingEngine:
         steps consumes nothing and moves no later token)."""
         self._settle()
         self._sync_state()
-        return self._operands(toks)
+        return self._operands()
 
     # ---------------------------------------------------------- jitted steps
     def _sample_tokens(self, logits, seeds, ngen, temps, flags):
@@ -1461,37 +1319,6 @@ class ServingEngine:
             return (_pack_read(*read), pool, lengths + n,
                     jnp.where(n > 0, nxt, toks), ngen + n)
 
-        def spec_step(params, pool, tables, lengths, toks_win, seeds, ngen,
-                      temps, flags):
-            """Speculative scoring step: ONE fused dispatch scores the
-            (B, k+1) window [current, drafts...] — window position i's
-            logits are what plain decode would see at generation index
-            ``ngen + i``, so sampling each position with its own
-            ``fold_in(seed, ngen + i)`` key reproduces the plain
-            stream EXACTLY.  A draft is accepted iff it equals the
-            token position i-1 sampled anyway; the per-slot accept
-            length (1 committed token + accepted-draft run + the free
-            bonus token) is computed in-graph.  Rejected tails never
-            advance ``lengths`` — that host-side non-advance IS the
-            rollback (stale K/V above the committed length is masked
-            and overwritten when decode reaches those positions)."""
-            logits, pool = self.model.decode_step_paged(
-                deq(params), toks_win, pool, tables, lengths)  # (B, W, V)
-            nonfin = rows_nonfinite(logits)                    # (B, W)
-            outs = []
-            for i in range(toks_win.shape[1]):
-                nxt = self._sample_tokens(logits[:, i], seeds, ngen + i,
-                                          temps, flags)
-                outs.append(jnp.where(nonfin[:, i],
-                                      jnp.int32(POISON_SENTINEL_TOKEN),
-                                      nxt))
-            out = jnp.stack(outs, axis=1)                      # (B, W)
-            match = (toks_win[:, 1:] == out[:, :-1]).astype(jnp.int32)
-            accept_len = 1 + jnp.sum(jnp.cumprod(match, axis=1), axis=1)
-            n = jnp.where(tables[:, 0] != pk.SCRATCH_BLOCK, accept_len, 0)
-            return (_pack_read(out, nonfin, accept_len), pool,
-                    lengths + n, ngen + n)
-
         nb = self._tables.shape[1]
 
         def unpack(buf):
@@ -1503,12 +1330,10 @@ class ServingEngine:
         c = self.config
         self._unpack = self.engine._wrap_step(
             f"serving.unpack[{c.batch_slots}x{nb}]", unpack)
-        spec_tag = f",spec{self.spec.k}" if self.spec is not None else ""
         self._decode = self.engine._wrap_step(
             f"serving.decode[{c.batch_slots}x{nb}"
-            f"x{c.block_size},kv{c.kv_bits},{c.top_k}{spec_tag}]",
-            spec_step if self.spec is not None else step,
-            donate_argnums=(1,))
+            f"x{c.block_size},kv{c.kv_bits},{c.top_k}]",
+            step, donate_argnums=(1,))
 
     def _prefill_fn(self, bucket: int):
         """Jitted prefill for prompts padded to ``bucket`` tokens: runs
@@ -1758,9 +1583,7 @@ class ServingEngine:
     def _step_estimate_s(self) -> Optional[float]:
         """PER-TOKEN wall estimate for predictive deadline shedding:
         the step EMA, clamped to the LAST measured step when that was
-        faster, divided by the measured tokens-per-step rate when
-        speculation is armed (a spec step emits up to k+1 tokens — the
-        per-step wall alone would over-shed).  Fast-biased on purpose —
+        faster (a step emits one token a row).  Fast-biased on purpose —
         a compile/deserialize-laden first step must not convince the
         gate that every deadline is hopeless; an underestimate only
         admits a request the per-step deadline check will still evict
@@ -1771,8 +1594,6 @@ class ServingEngine:
         est = self._step_ema_s
         if self._step_last_s is not None:
             est = min(est, self._step_last_s)
-        if self._spec_rate_ema is not None:
-            est = est / max(1.0, self._spec_rate_ema)
         return est
 
     def _start(self, slot: int, req: Request, blocks: List[int], new: int,
@@ -2603,11 +2424,6 @@ class ServingEngine:
         rec["tokens"] = list(s.out_tokens)
         rec["outcome"] = outcome
         rec["t_done"] = time.monotonic()
-        if self.spec is not None:
-            # per-request acceptance stats (docs/serving.md#speculative-
-            # decoding); the run totals ride the monitor bus as counters
-            rec["spec"] = {"proposed": s.spec_proposed,
-                           "accepted": s.spec_accepted}
         self._outcomes[outcome] += 1
         self._recent.append({"uid": s.req.uid, "outcome": outcome,
                              "generated": len(s.out_tokens),
@@ -2756,12 +2572,12 @@ class ServingEngine:
 
     def _settles_every_step(self, active) -> bool:
         """Must the step dispatched for ``active`` be read before this call
-        returns?  Where the next step is built from the tokens just read
-        (speculation's window, a prompt tail being ingested), where
-        another party reads slot state between calls (a snapshot cadence,
-        a transfer queue, a role) and while draining, the order stays
-        dispatch, read, book: nothing is ever left in flight."""
-        return (self.spec is not None or self.kvs is not None
+        returns?  Where the next step is built from the host's own tokens
+        (a prompt tail being ingested), where another party reads slot
+        state between calls (a snapshot cadence, a transfer queue, a role)
+        and while draining, the order stays dispatch, read, book: nothing
+        is ever left in flight."""
+        return (self.kvs is not None
                 or self._txq is not None or self.role != "mixed"
                 or self._draining
                 or any(self._slots[i].pending is not None for i in active))
@@ -2939,31 +2755,6 @@ class ServingEngine:
         them (JAX's asynchronous dispatch takes them as they are)."""
         spans = self._spans
         self._build_decode()
-        spec = self.spec
-        toks_win = None
-        if spec is not None:
-            # draft k tokens per live slot from its committed history —
-            # a pure host-side function of the request (module
-            # docstring: determinism survives), proposed as runtime
-            # operands so the compiled step never re-specializes
-            with spans.span("serving.draft"):
-                toks_win = np.repeat(self._toks[:, None], spec.k + 1,
-                                     axis=1)
-                for i in active:
-                    s = self._slots[i]
-                    if s.pending:
-                        # prompt ingestion (prefix sharing): draft
-                        # columns carry the next prompt tokens, teacher-
-                        # forced, so one window step writes up to k+1
-                        # prompt positions' K/V.  Any remaining columns
-                        # keep the repeated current token — they write
-                        # junk past the prompt, masked and rewritten
-                        # when decode reaches those positions.
-                        fill = s.pending[:spec.k]
-                        toks_win[i, 1:1 + len(fill)] = fill
-                    else:
-                        toks_win[i, 1:] = ngram_draft(
-                            s.hist[-DRAFT_WINDOW:], spec.k, spec.ngram)
         with jax.set_mesh(self.engine.mesh):
             # the slot state goes up (only if a slot changed) on its own
             # bracket, so that "dispatch" is the call into the executable
@@ -2971,18 +2762,14 @@ class ServingEngine:
             with spans.span("serving.upload") as upload:
                 upload.attrs = {"uploaded": self._state_dirty}
                 self._sync_state()
-                args = self._operands(toks_win)
+                args = self._operands()
             self._reused_steps += not upload.attrs["uploaded"]
             self._ahead_steps += ahead
             res = self._resident    # operand order: [1] lengths, [2] toks,
             #                         [4] ngen come back advanced
             with spans.span("serving.dispatch") as dispatch:
                 dispatch.attrs = {"ahead": ahead}
-                if spec is not None:
-                    read, self.pool, res[1], res[4] = self._decode(*args)
-                else:
-                    read, self.pool, res[1], res[2], res[4] = \
-                        self._decode(*args)
+                read, self.pool, res[1], res[2], res[4] = self._decode(*args)
                 # the one buffer the host needs starts down at once
                 read.copy_to_host_async()
         if self._kv_warm_pending:
@@ -2996,7 +2783,6 @@ class ServingEngine:
         deadlines); it is unread no longer.  Returns ``(rows it was
         dispatched for, tokens emitted, their stamp)``."""
         spans = self._spans
-        spec = self.spec
         active = step.active
         self._unread = None
         # the host's wait for the device, alone on its bracket
@@ -3009,12 +2795,8 @@ class ServingEngine:
                 self._route_attrs = dict(zip(
                     self._counter_names, (int(x) for x in read[0, n_cols:])))
                 read = read[:, :n_cols]
-            # (B, W) tokens | (B, W) non-finite flags | accept length;
-            # plain decode is the W=1 window: one token, always "accepted"
-            W = read.shape[1] // 2
-            out, nonfin = read[:, :W], read[:, W:2 * W] != 0
-            accept_len = (read[:, 2 * W] if spec is not None
-                          else np.ones((read.shape[0],), np.int64))
+            # a row's token | its non-finite flag
+            out, nonfin = read[:, 0], read[:, 1] != 0
         # the value read above synced the dispatch: from the upload's start
         # to the read's end is a true decode-step cost, the predictive-
         # deadline EMA's input (the spans' own clock reads, no others).  A
@@ -3047,93 +2829,51 @@ class ServingEngine:
                     # this step was already dispatched: its sample is
                     # discarded (docs/serving.md#one-step-in-flight)
                     continue
+                if nonfin[i]:
+                    # the sentinel token is NOT appended: the record keeps
+                    # its pre-poison tokens
+                    self._evict_poisoned(i)
+                    continue
                 if s.pending:
-                    # prompt ingestion (prefix sharing): the committed
-                    # columns wrote prompt K/V — their samples are
-                    # DISCARDED.  Advance stops one token short of the
-                    # prompt end: the step where pending is empty has
-                    # the final prompt token as its operand, and its
-                    # column-0 sample (key fold_in(seed, 0), ngen still
-                    # 0) IS the first generated token — the same index
-                    # the prefill path samples, so outputs stay token-
-                    # identical to the unshared path.
-                    W = out.shape[1]
-                    rem = len(s.pending)
-                    adv = W if rem >= W else rem
-                    if nonfin[i, :adv].any():
-                        self._evict_poisoned(i)
-                        continue
-                    # not the in-graph advance (no sample is kept, ngen
-                    # stands): the next dispatch re-sends the state
-                    self._lengths[i] += adv
-                    self._toks[i] = s.pending[adv - 1]
+                    # prompt ingestion (prefix sharing): the step wrote one
+                    # prompt position's K/V and its sample is DISCARDED.
+                    # Advance stops one token short of the prompt end: the
+                    # step where pending is empty has the final prompt
+                    # token as its operand, and its sample (key
+                    # fold_in(seed, 0), ngen still 0) IS the first
+                    # generated token — the same index the prefill path
+                    # samples, so outputs stay token-identical to the
+                    # unshared path.  Not the in-graph advance (no sample
+                    # is kept, ngen stands): the next dispatch re-sends the
+                    # state
+                    self._lengths[i] += 1
+                    self._toks[i] = s.pending.pop(0)
                     self._state_dirty = True
-                    del s.pending[:adv]
                     dl = self.results[s.req.uid]["deadline"]
                     if dl is not None and now >= dl:
                         self._finish(i, outcome=DEADLINE)
                     continue
-                was_ingest = s.pending is not None   # [] = final step
-                if was_ingest:
-                    s.pending = None
-                a = int(accept_len[i])
-                # emission plan: walk the accepted window until poison /
-                # eos / max_new truncates it (side-effect-free, so the
-                # acceptance booking below lands BEFORE _finish writes
-                # the terminal record)
-                plan = []
-                poisoned_here = False
-                finished_here = False
-                for j in range(a):
-                    if nonfin[i, j]:
-                        # poison at this position: the sentinel token is
-                        # NOT appended — the record keeps only its
-                        # pre-poison tokens, exactly as plain decode
-                        # would have at this generation index
-                        poisoned_here = True
-                        break
-                    tok = int(out[i, j])
-                    plan.append(tok)
-                    if len(s.out_tokens) + len(plan) >= s.max_new \
-                            or tok == c.eos_token_id:
-                        # finish mid-window: accepted tokens past this
-                        # one are discarded (plain decode would have
-                        # stopped here; the slot frees either way)
-                        finished_here = True
-                        break
-                emitted = len(plan)
-                emitted_step += emitted
-                if spec is not None:
-                    # acceptance books only drafts that CONTRIBUTED an
-                    # emitted token (emitted = 1 committed + used
-                    # drafts): a draft the model agreed with but whose
-                    # token was truncated at eos/max_new/poison must not
-                    # inflate the accept rate the bus/alerting reads
-                    used = max(0, emitted - 1)
-                    s.spec_proposed += spec.k
-                    s.spec_accepted += used
-                    self._spec_proposed_total += spec.k
-                    self._spec_accepted_total += used
-                s.out_tokens.extend(plan)
-                s.hist.extend(plan)
+                tok = int(out[i])
+                emitted_step += 1
+                s.out_tokens.append(tok)
+                s.hist.append(tok)
                 rec = self.results[s.req.uid]
-                rec["t_tokens"].extend([now] * emitted)
-                if was_ingest and plan:
-                    # first token of a prefix-HIT request: TTFT stamps
-                    # here (the plain path stamps it at prefill) — by
-                    # construction one decode step after the suffix
-                    # finished ingesting, i.e. the new-suffix cost
+                rec["t_tokens"].append(now)
+                if s.pending is not None:
+                    # [] = the prompt tail's final step.  First token of a
+                    # prefix-HIT request: TTFT stamps here (the plain path
+                    # stamps it at prefill) — by construction one decode
+                    # step after the suffix finished ingesting, i.e. the
+                    # new-suffix cost
+                    s.pending = None
                     if rec["t_first"] is None:
                         rec["t_first"] = now
-                if poisoned_here:
-                    self._evict_poisoned(i)
-                    continue
-                if finished_here:
+                if len(s.out_tokens) >= s.max_new or tok == c.eos_token_id:
                     self._finish(i)
                     continue
-                self._lengths[i] += emitted
-                self._ngen[i] += emitted
-                self._toks[i] = s.out_tokens[-1]
+                self._lengths[i] += 1
+                self._ngen[i] += 1
+                self._toks[i] = tok
                 dl = rec["deadline"]
                 if dl is not None and now >= dl:
                     # mid-decode deadline: evict with the partial tokens
@@ -3150,13 +2890,6 @@ class ServingEngine:
                     # never changes
                     with spans.span("serving.kv_snapshot"):
                         self._snapshot_slot_safe(i)
-            if spec is not None and active:
-                # tokens-per-step EMA: the predictive deadline gate's
-                # per-token denominator under speculation
-                rate = max(1.0, emitted_step / len(active))
-                self._spec_rate_ema = (
-                    rate if self._spec_rate_ema is None
-                    else 0.7 * self._spec_rate_ema + 0.3 * rate)
         return len(active), emitted_step, now
 
     def _raise_stalled(self):
@@ -3188,13 +2921,13 @@ class ServingEngine:
     # walks are cheap (O(buckets)) but need not run per generated token
     _PERCENTILES_EVERY = 16
 
-    def _monitor_finish(self, active_slots, tokens=None):
+    def _monitor_finish(self, active_slots, tokens):
         """Per-decode-step telemetry: the serving stats (previously an
         export-only dict) re-routed through the bus in the one schema.
         Cheap counters ride every emitted step; the percentile gauges
         (a sort over the completion windows) ride a coarser cadence.
-        ``tokens``: tokens emitted this step (== active_slots for plain
-        decode; up to (k+1)·active under speculation)."""
+        ``tokens``: tokens emitted this step (a token a row that neither
+        ended before the step was read nor ingests a prompt tail)."""
         mon = self.monitor
         # memory-ledger cadence: the monitor's `memory_interval` when it
         # carries one (config-built monitors; 0 = the documented off
@@ -3293,16 +3026,6 @@ class ServingEngine:
             return
         if d_term > 0:
             self._err_window_last = (term, bad)
-        if self.spec is not None:
-            # speculative acceptance on the bus: drafted vs accepted
-            # draft tokens (counters merge across replicas/restarts),
-            # plus the run accept-rate as a gauge for ds_top/alerting
-            counters["spec_proposed_total"] = self._spec_proposed_total
-            counters["spec_accepted_total"] = self._spec_accepted_total
-            if self._spec_proposed_total:
-                gauges["spec_accept_rate"] = round(
-                    self._spec_accepted_total / self._spec_proposed_total,
-                    4)
         if self._steps % self._PERCENTILES_EVERY == 0:
             st = self.stats()
             if "latency_ms" in st:
@@ -3320,8 +3043,7 @@ class ServingEngine:
                 if h:
                     mon.hist(hname, h, step=self._steps, unit="ms")
         self._emit_exe_cost(mon)
-        mon.set_rates(tokens_per_step=(
-            active_slots if tokens is None else tokens))
+        mon.set_rates(tokens_per_step=tokens)
         mon.end_step(self._steps, scalars=scalars, gauges=gauges,
                      counters=counters, name="serving_step")
 
@@ -3371,21 +3093,12 @@ class ServingEngine:
             paged_impl=impl)
         if not (flops or hbm):
             return None
-        # with speculation armed a step emits up to (k+1)·batch_slots
-        # tokens: report the MEASURED rate (the ds_explain verdict's
-        # per-token view must not understate spec throughput by k+1x)
-        tokens_per_step = c.batch_slots
-        if self.spec is not None and self._spec_rate_ema is not None:
-            tokens_per_step = round(c.batch_slots * self._spec_rate_ema, 1)
-        out = {"exe": "serving_step", "flops": flops, "hbm_bytes": hbm,
-               "wire_bytes": wire.get("wire_bytes_per_step", 0),
-               "gather_bytes": gather, "paged_impl": impl,
-               "tokens_per_step": tokens_per_step,
-               "device_kind": _jax.devices()[0].device_kind,
-               "n_chips": len(_jax.devices())}
-        if self.spec is not None:
-            out["speculative_k"] = self.spec.k
-        return out
+        return {"exe": "serving_step", "flops": flops, "hbm_bytes": hbm,
+                "wire_bytes": wire.get("wire_bytes_per_step", 0),
+                "gather_bytes": gather, "paged_impl": impl,
+                "tokens_per_step": c.batch_slots,
+                "device_kind": _jax.devices()[0].device_kind,
+                "n_chips": len(_jax.devices())}
 
     def _emit_exe_cost(self, mon):
         """One `exe_cost` gauge per serving configuration — the
@@ -3587,8 +3300,6 @@ class ServingEngine:
         self._outcomes = {k: 0 for k in OUTCOMES}
         self._requeued_total = 0
         self._err_window_last = (0, 0)
-        self._spec_proposed_total = 0
-        self._spec_accepted_total = 0
         self._kv_snapshots_total = 0
         self._kv_migrated_total = 0
         self._kv_fallback_total = 0
@@ -3655,16 +3366,6 @@ class ServingEngine:
                # None = the startup memory gate had nothing to compare
                # (disabled, no budget, or no executable analysis)
                "preflight": self._preflight}
-        if self.spec is not None:
-            out["speculative"] = {
-                "k": self.spec.k,
-                "proposed": self._spec_proposed_total,
-                "accepted": self._spec_accepted_total,
-                "accept_rate": round(
-                    self._spec_accepted_total
-                    / max(1, self._spec_proposed_total), 4),
-                "tokens_per_step": round(
-                    self._generated_total / max(1, self._steps), 2)}
         if self._lat_hist:
             p = self._lat_hist.percentiles()
             out["latency_ms"] = {

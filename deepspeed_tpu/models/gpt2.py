@@ -759,9 +759,10 @@ class GPT2:
         """One decode window for B slots over a paged/block KV pool.
 
         ``toks``: (B,) int32 current input token per slot — or (B, W)
-        for a multi-token window (speculative decoding scores the
-        current token + k drafts in ONE step; window token i sits at
-        position ``lengths + i`` with in-window causal masking);
+        for a multi-token window (window token i sits at position
+        ``lengths + i`` with in-window causal masking; the serving loop
+        passes (B,), and chunked prefill, ROADMAP S3, is the window's
+        next user);
         ``lengths``: (B,) int32 tokens already cached per slot (== the
         first window token's position); ``block_tables``: (B, nb_max)
         int32 pool block ids (unused entries point at the reserved

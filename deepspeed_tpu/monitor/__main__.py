@@ -259,13 +259,6 @@ def render(agg: Aggregate, source: str, clock=time.time) -> str:
             f"poisoned {_fmt(srv['poisoned_total'] or 0)}  "
             f"requeued {_fmt(srv['requeued_total'] or 0)}  "
             f"breaker {'OPEN' if srv['breaker_open'] else 'closed'}"]
-        # speculative decoding armed: acceptance rides the same line
-        # (docs/serving.md#speculative-decoding)
-        if c("spec_proposed_total") is not None:
-            rate = g("spec_accept_rate")
-            lines[-1] += (f"  spec {int(c('spec_accepted_total') or 0)}/"
-                          f"{int(c('spec_proposed_total'))}"
-                          + (f" ({rate:.0%})" if rate is not None else ""))
     if agg.hists:
         # whole-run latency percentiles from the mergeable histograms
         # (docs/monitoring.md#histograms) — not a truncated window

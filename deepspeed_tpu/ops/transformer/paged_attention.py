@@ -62,8 +62,9 @@ the carried DMA ring are as above.  One query token a slot (W = 1).
 
 ``mode="auto"`` resolves to ``online`` on compiled TPU and ``exact``
 under the interpreter.  Queries are a ``(B, W, H, hd)`` window —
-``W=1`` is plain decode, ``W=k+1`` is the speculative-decode scoring
-step (``inference/serving.py``) — masked causally inside the window:
+``W=1`` is the serving decode step (``inference/serving.py``), a wider
+window is chunked prefill's to take (ROADMAP S3, D17) — masked causally
+inside the window:
 key position ``s`` is live for window row ``w`` iff
 ``s <= lengths[b] + w``.
 """
@@ -624,8 +625,8 @@ def paged_attention(q, pool, block_tables, lengths, layer, *,
     """Masked attention of a ``(B, W)`` query window over the paged pool,
     reading K/V blocks in place (no gathered copy).
 
-    - ``q``: (B, W, H, hd) in the attention compute dtype (W=1: plain
-      decode; W=k+1: the speculative scoring window);
+    - ``q``: (B, W, H, hd) in the attention compute dtype (W=1: the
+      serving decode step);
     - ``pool``: the ``paged_kv`` pool pytree (16-bit or int8+scales); its
       width is ``n_kv_head * hd``, with ``n_kv_head`` a divisor of H
       (H itself: multi-head; 1: multi-query);

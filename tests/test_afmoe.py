@@ -460,21 +460,7 @@ def test_the_sanitizer_and_the_lint_know_the_second_table():
 
 
 # ------------------------------------------------------ (f) refused by name
-@pytest.mark.parametrize("name, value", [
-    ("prefix_cache", True), ("speculative", {"k": 2}),
-    ("kv_snapshot", {"every_n_tokens": 4}), ("transfer", True),
-    ("role", "prefill")])
-def test_what_has_not_learned_the_ring_is_refused_by_name(model_params,
-                                                          name, value,
-                                                          tmp_path):
-    m, params = model_params
-    eng = ds.init_inference(m, params=params, dtype=jnp.float32)
-    with pytest.raises(ValueError, match=f"serving.{name}.*sliding-window"):
-        ServingEngine(engine=eng, config={
-            "batch_slots": 2, "block_size": BLOCK,
-            "journal_dir": str(tmp_path), name: value})
-
-
+# (what is refused over a ring of window blocks: tests/test_serving_refusals.py)
 @pytest.mark.parametrize("key, value", [
     ("rope_scaling", {"type": "linear", "factor": 4.0}),
     ("n_group", 2), ("hidden_act", "gelu"), ("score_func", "softmax_v3"),

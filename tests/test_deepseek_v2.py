@@ -548,15 +548,10 @@ def test_the_pool_row_is_the_latent_and_a_token_costs_seven_of_them():
     assert pk.latent_row_bytes(big) == 1280
 
 
-@pytest.mark.parametrize("name, value", [
-    ("prefix_cache", True), ("speculative", {"k": 2}),
-    ("kv_snapshot", {"interval_steps": 4})])
-def test_what_has_not_learned_the_latent_row_is_refused_by_name(name, value):
+def test_an_int8_latent_pool_is_refused():
+    """(What moves a stream by its K/V blocks is refused over a latent pool
+    in tests/test_serving_refusals.py.)"""
     eng = ds.init_inference(tiny(), dtype=jnp.float32)
-    with pytest.raises(ValueError, match=f"serving.{name}.*latent"):
-        ServingEngine(engine=eng, config={"batch_slots": 2, "block_size": 8,
-                                          "journal_dir": "/nonexistent",
-                                          name: value})
     with pytest.raises(ValueError, match="kv_bits"):
         ServingEngine(engine=eng, config={"batch_slots": 2, "kv_bits": 8})
 

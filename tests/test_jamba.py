@@ -391,22 +391,8 @@ def test_decode_step_kernel_and_gather_paths_agree(model_params):
     assert rel_err(outs[0], outs[1]) < 1e-5
 
 
-# ------------------------------------------------------ (f) refused features
-@pytest.mark.parametrize("block, word", [
-    ({"prefix_cache": True}, "prefix_cache"),
-    ({"kv_snapshot": True, "journal_dir": "/nonexistent"}, "kv_snapshot"),
-    ({"speculative": {"k": 2}}, "speculative"),
-    ({"transfer": {"dir": "/nonexistent"}}, "transfer"),
-    ({"role": "prefill"}, "role"),
-    ({"role": "decode"}, "role")])
-def test_what_assumes_a_stream_is_its_blocks_is_refused(model_params, block,
-                                                        word):
-    m, params = model_params
-    eng = ds.init_inference(m, params=params, dtype=jnp.float32)
-    with pytest.raises(ValueError, match=f"{word}.*recurrent state"):
-        ServingEngine(engine=eng, config={"batch_slots": 2, **block})
-
-
+# ------------------------------------- (f) the served context limit
+# (what is refused over recurrent state: tests/test_serving_refusals.py)
 def test_the_models_positions_are_the_served_context_limit():
     """No positional parameter: ``max_position_embeddings`` sizes a slot's
     block table and the pool, and nothing else."""

@@ -718,21 +718,6 @@ def test_ds_top_renders_serving_resilience_line(tmp_path, capsys):
     assert "breaker OPEN" in out
 
 
-def test_ds_top_renders_spec_acceptance(tmp_path, capsys):
-    """With speculation armed the serving line carries accepted/proposed
-    + the accept rate (docs/serving.md#speculative-decoding)."""
-    from deepspeed_tpu.monitor.__main__ import main as ds_top
-    bus = MonitorBus([JSONLSink(str(tmp_path / EVENTS_FILE))])
-    bus.step("serving_step", 9, active_slots=3, queued=0)
-    bus.counter("spec_proposed_total", 40, step=9)
-    bus.counter("spec_accepted_total", 30, step=9)
-    bus.gauge("spec_accept_rate", 0.75, step=9)
-    bus.flush()
-    assert ds_top([str(tmp_path), "--once"]) == 0
-    out = capsys.readouterr().out
-    assert "spec 30/40" in out and "(75%)" in out
-
-
 def test_ds_top_renders_hist_and_trace_lines(tmp_path, capsys):
     """Schema-v2 hist events render whole-run p50/p99/p999; trace events
     render the request-trace summary with the export pointer."""

@@ -225,8 +225,8 @@ def test_serving_matches_the_reference(model_params, impl, n, slots, model):
 
 
 def test_a_decode_window_is_its_tokens_one_after_another(model_params):
-    """A (B, W) window (what speculation scores): window token i sits at
-    position ``lengths + i``."""
+    """A (B, W) window (chunked prefill's to take, ROADMAP D17): window
+    token i sits at position ``lengths + i``."""
     _, params = model_params
     m = tiny(paged_attention_impl="gather")
     pool = m.init_serving_state(2, 9, 8, dtype=jnp.float32)

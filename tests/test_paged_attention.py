@@ -19,8 +19,8 @@ exported exactly so the kernel has something to be tested against:
 
 Edge coverage per the serving layer's invariants: partial last blocks,
 SCRATCH-slot inactivity (all-zero tables), per-slot length edges (block
-boundary, single token), multi-token windows (the speculative scoring
-step), and the write_tokens overflow-to-scratch guard.
+boundary, single token), multi-token windows (no caller in the product
+since PR 45, kept honest here for chunked prefill: ROADMAP D17), and the write_tokens overflow-to-scratch guard.
 
 The online kernel's WALK (PR 35: dead rows do nothing and come last, one
 DMA ring over the whole call, the last chunk fetches its live blocks only)
@@ -276,8 +276,8 @@ def test_decode_step_kernel_vs_gather_impl(devices):
 def test_multi_token_window_matches_sequential_steps(devices):
     """A (B, W) window through decode_step_paged must produce, at each
     window position, the same logits as W sequential single-token steps
-    committing the same tokens — the property speculative scoring
-    relies on (window position i == what plain decode would see).
+    committing the same tokens (window position i == what plain decode
+    would see): what a chunk of a prompt taken as a window relies on.
 
     Mathematically identical, not bitwise: the window matmuls carry
     (B, W, D) operands where sequential carries (B, 1, D), so XLA's
@@ -317,7 +317,7 @@ def test_multi_token_window_matches_sequential_steps(devices):
 
 
 def test_write_tokens_overflow_lands_in_scratch(devices):
-    """A window position past the slot's table (a speculative draft
+    """A window position past the slot's table (a window
     running beyond the allocation) must be REDIRECTED to the scratch
     block — the take-along-axis clamp would otherwise silently
     overwrite the table's LAST REAL block."""

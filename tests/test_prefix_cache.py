@@ -493,25 +493,6 @@ def test_prefix_cache_decode_jaxpr_identical(tiny96, devices):
     assert jaxpr_text(None) == jaxpr_text(True)
 
 
-def test_speculative_decode_with_prefix_sharing(tiny96, devices):
-    """Prompt ingestion through the SPECULATIVE step (window > 1): the
-    pending prompt rides the draft window, rollback semantics hold,
-    and outputs still match the unshared spec oracle."""
-    model, params = tiny96
-    spec = {"k": 3, "ngram": 2}
-    oracle_srv = _mk(model, params, prefix=None, speculative=spec)
-    oracle = {u: r["tokens"]
-              for u, r in oracle_srv.run(_reqs(3)).items()}
-    oracle_srv.close()
-    srv = _mk(model, params, prefix=True, speculative=spec)
-    got = {u: r["tokens"] for u, r in srv.run(_reqs(3)).items()}
-    st = srv.stats()["prefix_cache"]
-    srv.close()
-    assert got == oracle
-    assert st["requests_hit"] >= 1
-    assert srv.allocator.free_blocks == srv.num_blocks - 1
-
-
 # ===================================================================
 # migration under sharing
 # ===================================================================

@@ -790,12 +790,11 @@ def test_tracing_armed_step_jaxpr_identical(tiny, devices):
             monspans._on_jax_duration)
 
 
-# ------------------------------------------------ speculative decoding
-def _spec_reqs():
-    """Mixed traffic for the spec-identity tests: loopy prompts the
-    n-gram drafter can hit, random prompts it mostly cannot, greedy AND
-    sampled decoding, lengths that finish mid-window, 4 requests over 2
-    slots (slot churn)."""
+# ------------------------------------------------- one token a row a step
+def _mixed_reqs():
+    """Mixed traffic for the identity test: prompts that repeat and
+    prompts that do not, greedy AND sampled decoding, lengths that end
+    at different steps, 4 requests over 2 slots (slot churn)."""
     rng = np.random.default_rng(9)
     reqs = []
     for i in range(4):
@@ -809,138 +808,42 @@ def _spec_reqs():
     return reqs
 
 
-def test_speculative_token_identity_permuted_arrivals(tiny, devices):
-    """Speculative decode must be TOKEN-IDENTICAL to plain
-    autoregressive decode — a draft is accepted only when it equals the
-    token the model would have sampled anyway — and the determinism
-    contract must survive speculation: permuted arrival orders change
-    nothing (drafting is a pure function of each request's own
-    history)."""
+def test_token_identity_permuted_arrivals(tiny, devices):
+    """Each uid's stream is a function of the request alone: permuted
+    arrival orders (so other slots, other neighbours, other steps at
+    which a slot is re-seated) give every uid the tokens it gets when it
+    is served with nothing beside it, greedy and sampled, and every
+    stream has the length it asked for."""
     model, params = tiny
 
-    def run(speculative, order):
+    def run(order, slots=2):
         srv = ServingEngine(
             model=model, params=params,
-            config=ServingConfig(batch_slots=2, block_size=8,
-                                 max_new_tokens=8, top_k=8,
-                                 speculative=speculative))
-        reqs = _spec_reqs()
+            config=ServingConfig(batch_slots=slots, block_size=8,
+                                 max_new_tokens=8, top_k=8))
+        reqs = _mixed_reqs()
         out = srv.run([reqs[j] for j in order])
-        st = srv.stats()
+        assert srv.allocator.free_blocks == srv.num_blocks - 1
         srv.close()
-        return {u: r["tokens"] for u, r in out.items()}, st, out
+        return {u: r["tokens"] for u, r in out.items()}
 
-    plain, _, _ = run(None, [0, 1, 2, 3])
-    spec_a, st, recs = run({"k": 3, "ngram": 3}, [0, 1, 2, 3])
-    spec_b, _, _ = run({"k": 3, "ngram": 3}, [2, 0, 3, 1])
-    assert spec_a == plain, "speculative decode diverged from plain"
-    assert spec_b == plain, "spec + permuted arrivals diverged"
-    # acceptance accounting: stats() block + per-request records
-    assert st["speculative"]["k"] == 3
-    assert st["speculative"]["proposed"] > 0
-    assert 0.0 <= st["speculative"]["accept_rate"] <= 1.0
-    for u, rec in recs.items():
-        assert rec["spec"]["proposed"] >= rec["spec"]["accepted"] >= 0
-    # the loopy prompts must actually exercise acceptance, else this
-    # test would pass with a drafter that proposes garbage
-    assert st["speculative"]["accepted"] > 0
+    alone = {}
+    for j in range(4):
+        alone.update(run([j], slots=1))
+    assert [len(alone[u]) for u in range(4)] == [3, 4, 5, 6]
+    for order in ([0, 1, 2, 3], [2, 0, 3, 1], [3, 2, 1, 0]):
+        assert run(order) == alone, f"arrival order {order} moved a stream"
 
 
-def test_speculative_eos_and_short_requests_mid_window(tiny, devices):
-    """Mid-stream evictions under speculation: an eos landing anywhere
-    in the accepted window truncates exactly where plain decode would
-    stop (accepted tokens past it are discarded), max_new_tokens=1
-    finishes at prefill without ever drafting, and freed slots/blocks
-    churn to queued work."""
-    model, params = tiny
-    r = Request(tokens=np.tile(np.arange(4), 3), max_new_tokens=8, seed=0)
-    ref_srv = ServingEngine(model=model, params=params,
-                            config=ServingConfig(batch_slots=1,
-                                                 block_size=8,
-                                                 max_new_tokens=8))
-    ref = ref_srv.run([r])[r.uid]["tokens"]
-    ref_srv.close()
-    eos = int(ref[2])          # an eos mid-stream (and mid-window at k=3)
-
-    def run(speculative):
-        srv = ServingEngine(
-            model=model, params=params,
-            config=ServingConfig(batch_slots=1, block_size=8,
-                                 max_new_tokens=8, eos_token_id=eos,
-                                 speculative=speculative))
-        reqs = [Request(tokens=np.tile(np.arange(4), 3), max_new_tokens=8,
-                        seed=0, uid=0),
-                Request(tokens=np.arange(5), max_new_tokens=1, seed=1,
-                        uid=1),
-                Request(tokens=np.arange(6), max_new_tokens=5, seed=2,
-                        uid=2)]
-        out = srv.run(reqs)
-        free = srv.allocator.free_blocks == srv.num_blocks - 1
-        srv.close()
-        return {u: rec["tokens"] for u, rec in out.items()}, free
-
-    plain, free_p = run(None)
-    spec, free_s = run({"k": 3})
-    assert spec == plain
-    assert plain[0] == ref[:ref.index(eos) + 1]   # stopped AT eos
-    assert len(plain[1]) == 1                     # finished at prefill
-    assert free_p and free_s                      # every block returned
-
-
-def test_speculative_counters_ride_the_monitor_bus(tiny, devices):
-    """Per-request acceptance stats ride the bus: the serving step
-    events carry spec_proposed/accepted_total counters and the
-    accept-rate gauge (ISSUE 14 acceptance)."""
-    from deepspeed_tpu.monitor import Monitor
-    model, params = tiny
-    mon = Monitor(run_dir=None, sinks=("ring",))
-    srv = ServingEngine(model=model, params=params, monitor=mon,
-                        config=ServingConfig(batch_slots=2, block_size=8,
-                                             max_new_tokens=8,
-                                             speculative={"k": 2}))
-    srv.run([Request(tokens=np.tile(np.arange(4), 3), max_new_tokens=8,
-                     seed=0)])
-    ring = list(mon.ring)
-    counters = {e.name: e.value for e in ring
-                if getattr(e, "kind", None) == "counter"}
-    gauges = {e.name: e.value for e in ring
-              if getattr(e, "kind", None) == "gauge"}
-    assert counters.get("spec_proposed_total", 0) > 0
-    assert "spec_accepted_total" in counters
-    assert "spec_accept_rate" in gauges
-    assert 0.0 <= gauges["spec_accept_rate"] <= 1.0
-    srv.close()
-
-
-def test_speculative_config_validation(tiny, devices):
-    from deepspeed_tpu.inference import SpeculativeConfig
-    assert SpeculativeConfig.from_value(None) is None
-    assert SpeculativeConfig.from_value(False) is None
-    assert SpeculativeConfig.from_value(True).k == 4
-    assert SpeculativeConfig.from_value({"k": 2, "ngram": 1}).k == 2
-    with pytest.raises(AssertionError, match="speculative.k"):
-        SpeculativeConfig.from_value({"k": 0})
-    with pytest.raises(ValueError, match="unknown serving.speculative"):
-        SpeculativeConfig.from_value({"tokens": 3})
-
-
-def test_ngram_draft_is_pure_and_matches_continuations(devices):
-    """The self-drafter: longest-tail-gram match proposes the tokens
-    that followed its most recent previous occurrence; no match falls
-    back to last-token repeat; pure function (same history -> same
-    drafts)."""
-    from deepspeed_tpu.inference.serving import ngram_draft
-    h = [5, 6, 7, 9, 5, 6, 7]          # tail (6,7) last seen at 1..2 -> 9, 5
-    np.testing.assert_array_equal(ngram_draft(h, 3, 3), [9, 5, 6])
-    np.testing.assert_array_equal(ngram_draft(h, 3, 3),
-                                  ngram_draft(list(h), 3, 3))
-    # no repetition anywhere: last-token repeat
-    np.testing.assert_array_equal(ngram_draft([1, 2, 3], 2, 3), [3, 3])
-    # single-token history
-    np.testing.assert_array_equal(ngram_draft([4], 2, 3), [4, 4])
-    # continuation runs off the end: pads with ITS last token
-    np.testing.assert_array_equal(ngram_draft([8, 1, 8], 3, 1), [1, 8, 8])
-    np.testing.assert_array_equal(ngram_draft([5, 5], 3, 1), [5, 5, 5])
+def test_a_retired_key_is_refused_by_name(devices):
+    """``serving.speculative`` went with PR 45: a configuration that still
+    carries it fails at construction and the error names the key, as for
+    any key the block does not have."""
+    for value in ({"k": 2}, True):
+        with pytest.raises(ValueError, match="speculative"):
+            ServingConfig.from_dict({"batch_slots": 2, "speculative": value})
+    with pytest.raises(TypeError, match="speculative"):
+        ServingConfig(speculative={"k": 2})
 
 
 # ------------------------------------- a serving cell's checks, tiny size

@@ -208,20 +208,49 @@ def _shared_prefix_requests():
                 [(3, 12), (9, 5), (6, 9), (12, 7), (5, 3), (7, 10)])]
 
 
-def test_speculation_never_runs_ahead(families, devices):
+@pytest.mark.parametrize("case", ["kv_snapshot", "transfer_queue_mixed_role",
+                                  "draining"])
+def test_what_settles_every_step(families, devices, tmp_path, case):
+    """``_settles_every_step`` by what the engine holds, not by a knob of its
+    own: a snapshot cadence, a transfer queue (on a ``mixed`` role too) and a
+    drain each read every step in the call that dispatched it; an engine with
+    none of them runs ahead.  The streams are the plain engine's."""
     plain = _server(families["gpt2"])
+    assert not plain._settles_every_step(())
     want = {u: r["tokens"] for u, r in plain.run(
-        _shared_prefix_requests()).items()}
+        _shared_prefix_requests()[:3]).items()}
     assert plain.stats()["steps_ahead"] > 0
     plain.close()
-    srv = _server(families["gpt2"], speculative={"k": 2})
-    for r in _shared_prefix_requests():
+    armed = {"kv_snapshot": dict(kv_snapshot={"every_tokens": 4},
+                                 journal_dir=str(tmp_path / "journal"),
+                                 kv_bits=8),
+             "transfer_queue_mixed_role": dict(
+                 transfer={"dir": str(tmp_path / "queue")}),
+             "draining": {}}[case]
+    srv = _server(families["gpt2"], preflight=False, **armed)
+    assert srv.role == "mixed"
+    for r in _shared_prefix_requests()[:3]:
         srv.submit(r)
+    ahead = 0
+    if case == "draining":
+        assert not srv._settles_every_step(())
+        for _ in range(3):
+            assert srv.step()
+        ahead = srv.stats()["steps_ahead"]
+        assert ahead > 0 and srv._unread is not None
+        assert srv.drain() == {"clean": True, "active": 0, "queued": 0}
+    assert srv._settles_every_step(())
     while srv.step():
         assert srv._unread is None       # read in the call that dispatched
     st = srv.stats()
-    assert {u: r["tokens"] for u, r in srv.results.items()} == want
-    assert st["steps_ahead"] == 0 and st["decode_steps"] > 0
+    assert st["steps_ahead"] == ahead and st["decode_steps"] > 0
+    if case == "kv_snapshot":
+        # a lossy pool's streams are its own: held to completion
+        assert all(len(srv.results[u]["tokens"]) == len(want[u])
+                   for u in want)
+        assert st["kv_snapshot"]["snapshots"] > 0
+    else:
+        assert {u: r["tokens"] for u, r in srv.results.items()} == want
     srv.close()
 
 

@@ -311,35 +311,3 @@ def test_armed_faults_leave_decode_jaxpr_identical(tiny_sp, fault_harness,
     # and the sentinel itself is in-graph: the step's jaxpr carries the
     # is_finite reduction (no host round-trip decides quarantine)
     assert "is_finite" in disarmed
-
-
-def test_speculative_quarantine_mid_stream(tiny_sp, fault_harness, devices):
-    """Quarantine under SPECULATION: a logit_nan request is evicted with
-    a typed POISONED result at exactly the generation index plain decode
-    would have caught it (the in-graph sentinel covers every window
-    position), neighbors stay token-identical to the plain-decode run,
-    and its blocks return scrubbed."""
-    model, params = tiny_sp
-    spec = {"k": 3, "ngram": 2}
-    clean_srv = _mk(model, params)
-    clean = {u: r["tokens"] for u, r in clean_srv.run(_reqs(4)).items()}
-    clean_srv.close()
-
-    bad_uid = 2                              # max_new 3: it decodes
-    fault_harness.configure(logit_nan=bad_uid)
-    srv = _mk(model, params, speculative=spec)
-    res = srv.run(_reqs(4))
-    rec = res[bad_uid]
-    assert rec["outcome"] == POISONED
-    # its pool blocks were NaN'd after prefill: the FIRST spec window is
-    # already poisoned at position 0, so only the prefill token survives
-    # (identical to the plain-decode quarantine point)
-    assert len(rec["tokens"]) == 1
-    for u, toks in clean.items():
-        if u != bad_uid:
-            assert res[u]["tokens"] == toks, \
-                f"neighbor {u} perturbed under speculative quarantine"
-    assert srv.allocator.free_blocks == srv.num_blocks - 1
-    assert srv.stats()["outcomes"][POISONED] == 1
-    fault_harness.reset()
-    srv.close()
