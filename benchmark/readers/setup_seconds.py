@@ -4,8 +4,11 @@
 divided among the rows of the program's set-up store, which step rows never
 displace.  The division is the program's own
 (``deepspeed_tpu/monitor/startup.py::partition``), so the eight phases add up
-to that interval.  Nothing is returned for a program without the store, or
-once the store has refused a row."""
+to that interval.  Like ``setup_s`` it leaves out the one call in which the
+TPU runtime started (``view["chip_reach_s"]``, taken by ``run.py``): the
+clock starts that much later, and ``before_program`` is that much shorter.
+Nothing is returned for a program without the store, or once the store has
+refused a row."""
 
 
 def store(view):
@@ -33,4 +36,5 @@ def read(view, phase):
         return None
     from deepspeed_tpu.monitor import startup
     t0, _ = view["facts"]["window"]
+    t_process_start += view.get("chip_reach_s", 0.0)
     return startup.partition(rows, t_process_start, t0)[phase]

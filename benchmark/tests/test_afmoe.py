@@ -17,6 +17,7 @@ import json
 import pytest
 
 from benchmark import harness, run
+from benchmark.tests.cell_metrics import own_and_shared
 from benchmark.tests.test_runners_cpu import SEED
 
 BENCH = harness.load_benchmark()
@@ -201,22 +202,24 @@ def test_the_traffic_is_issue_39s(config):
 
 
 def test_the_cell_reports_its_metrics_and_the_accepted_ones_it_must():
-    per_layer = {m["name"]: m for m in harness.cell_metrics(
-        BENCH, "per_layer", CELL["name"])}
-    ours = {n for n in per_layer if n.endswith(".trinity")
-            or n.startswith("kernels.trinity.")}
-    assert len(ours) >= 17
-    # every accepted metric WITHOUT a list is reported here too, whatever
-    # later PRs add to them
-    for m in BENCH["per_layer"]:
-        assert ("workloads" in m) or m["name"] in per_layer
-    assert {n for n in per_layer if n not in ours} == {
-        m["name"] for m in BENCH["per_layer"] if "workloads" not in m}
-    for n in ours:
-        assert per_layer[n]["moves"] == "serve_tokens_per_s"
-        assert per_layer[n]["workloads"] == [CELL["name"]]
-        spec = harness.read_json("layer_metrics", f"{n}.json")
-        assert callable(harness.load_plugin("readers", spec["reader"]).read)
+    # its kernels' and its decode step's costs and the second pool are this
+    # cell's own; the rest it shares with the other throughput cells
+    own, shared = own_and_shared(BENCH, CELL["name"], "serve_tokens_per_s")
+    assert own == {
+        "kernels.trinity.window_paged_attention_roofline",
+        "kernels.trinity.global_paged_attention_roofline",
+        "kernels.trinity.prefill_attention_roofline",
+        "engine.decode_bandwidth_share.trinity",
+        "serving.global_pool_fill_share.trinity",
+        "serving.window_pool_fill_share.trinity",
+        "serving.window_capped_share.trinity"}
+    assert shared == {
+        "engine.expert_share", "engine.prefill_share.tput",
+        "moe.local_pair_share", "moe.experts_touched_share",
+        "serving.step_ms_p50.tput", "serving.host_ms_per_step_p50.tput",
+        "serving.tokens_per_step", "serving.prefill_ms_p50.tput",
+        "serving.queue_wait_ms_p50", "serving.pool_bound_share",
+        "device.idle_share.tput"}
     assert {m["name"] for m in harness.cell_metrics(
         BENCH, "end_to_end", CELL["name"])} == {"serve_tokens_per_s",
                                                 "setup_s"}
@@ -314,7 +317,7 @@ def test_every_new_metric_reads_a_recorded_fixture(family):
                                    "jit_prefill": (0.9, 0.05)},
                   "kernel_s": {"paged_attention_window": 0.02,
                                "paged_attention_global": 0.008,
-                               "ragged-dot-none": 0.5,
+                               "gmm": 0.5,
                                "paged_attention": 9.0}}
     _, wbytes = family.costs["trinity_window_paged_attention"](v)
     _, gbytes = family.costs["trinity_global_paged_attention"](v)
@@ -322,30 +325,30 @@ def test_every_new_metric_reads_a_recorded_fixture(family):
         pytest.approx(100 * wbytes / 819e9 / 0.02)
     assert metric(v, "kernels.trinity.global_paged_attention_roofline") == \
         pytest.approx(100 * gbytes / 819e9 / 0.008)
-    assert metric(v, "engine.expert_share.trinity") == pytest.approx(25.0)
-    assert metric(v, "engine.prefill_share.trinity") == pytest.approx(45.0)
+    assert metric(v, "engine.expert_share") == pytest.approx(25.0)
+    assert metric(v, "engine.prefill_share.tput") == pytest.approx(45.0)
     _, need = family.costs["trinity_decode_step"](v, module_match="jit_step")
     assert metric(v, "engine.decode_bandwidth_share.trinity") == \
         pytest.approx(100 * need / 819e9 / 0.08)
-    assert metric(v, "moe.local_pair_share.trinity") == pytest.approx(
+    assert metric(v, "moe.local_pair_share") == pytest.approx(
         100 * (120 + 100 + 130 + 110) / (4 * 960))
-    assert metric(v, "moe.experts_touched_share.trinity") == pytest.approx(
+    assert metric(v, "moe.experts_touched_share") == pytest.approx(
         100 * (80 + 70 + 90 + 98) / (4 * 128))
     assert metric(v, "serving.global_pool_fill_share.trinity") == \
         pytest.approx(100 * 4000 / 4927)
     assert metric(v, "serving.window_pool_fill_share.trinity") == \
         pytest.approx(100 * 2000 / 2687)
-    assert metric(v, "serving.pool_bound_share.trinity") == pytest.approx(25)
+    assert metric(v, "serving.pool_bound_share") == pytest.approx(25)
     assert metric(v, "serving.window_capped_share.trinity") == pytest.approx(
         100 * (0.2 + 0.4 + 0.25 + 70 / 240) / 4)
     v["counters"] = {"generated_tokens": 24_000, "decode_steps": 400}
-    assert metric(v, "serving.tokens_per_step.trinity") == 60.0
+    assert metric(v, "serving.tokens_per_step") == 60.0
     # a program whose spans carry none of it (the parent): nothing, never 0
     old = view_with(family, [r for r in step_rows() if len(r[3]) < 3])
     old["trace"] = {"window_s": 2.0, "module_s": {}, "module_calls": {},
                     "kernel_s": {"paged_attention": 9.0}}
     for name in (m["name"] for m in BENCH["per_layer"]
-                 if m.get("workloads") == [CELL["name"]]
+                 if CELL["name"] in m.get("workloads", ())
                  and m["source"] != "host_clock"
                  and not m["name"].startswith(("serving.tokens_per_step",
                                                "serving.prefill_ms",
@@ -441,10 +444,13 @@ def published(config, family, one_chip):
 def compiled(one_chip, monkeypatch, fn, args, donate=()):
     import jax
     import deepspeed_tpu.ops as ops
+    from deepspeed_tpu.moe import dropless
     for name in ("paged_attention", "flash_attention"):
         monkeypatch.setattr(importlib.import_module(
             f"deepspeed_tpu.ops.transformer.{name}"), "_interpret",
             lambda: False)
+    # the chip's branch of the grouped products, not the CPU's ragged_dot
+    monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
     monkeypatch.setattr(ops, "flash_attention_available", lambda: True)
     args = [a if hasattr(a, "sharding") or not isinstance(a, tuple)
             else jax.ShapeDtypeStruct(*a, sharding=one_chip) for a in args]
@@ -479,7 +485,11 @@ def test_the_decode_step_fits_a_v5e_and_walks_both_kinds_in_place(
                           r"paged_attention_window", text)) == 4 or \
         text.count("paged_attention_window") >= 4
     assert text.count("paged_attention_global") >= 1
-    assert "ragged-dot" in text
+    # the experts' three products a layer (the four expert layers may be
+    # one loop's body) are the Pallas grouped matmul (PR 43), not XLA's
+    # ragged-dot, which the CPU alone still runs
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) >= 3
+    assert "ragged-dot" not in text
     assert m.alias_size_in_bytes >= POOL_BYTES
     assert m.temp_size_in_bytes < 128 * 2 ** 20
     assert not re.search(r"bf16\[\d+,\d{4,}\]\S* copy\(", text)

@@ -1,6 +1,7 @@
 """``BENCHMARK.json`` against the letter of its contract, and the data files
 every name in it must lead to."""
 
+import json
 import os
 import re
 
@@ -159,14 +160,110 @@ def test_file_names_use_name_characters():
             assert ok.match(rel), rel
 
 
+# ------------------------------------------------------- the per-layer table
+def reading(entry):
+    """What decides a per-layer value and where it is shown: the data
+    file's reader and parameters, and the entry's own keys but its name and
+    its cells."""
+    spec = harness.read_json("layer_metrics", f"{entry['name']}.json")
+    return json.dumps([spec["reader"], spec.get("params"), entry["unit"],
+                       entry["better"], entry["source"], entry["layer"],
+                       entry["moves"]], sort_keys=True)
+
+
+# the one pair of equal readings that stands (PR 46): which of Trinity's TWO
+# pools, beside the other cells' one
+SAME_READING = {("serving.global_pool_fill_share.trinity",
+                 "serving.pool_fill_share")}
+
+
+@pytest.fixture(scope="module")
+def frozen():
+    """The parent's 128 entries as PR 46 found them, each with the name it
+    has now."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "data", "per_layer_renames.json")) as f:
+        return json.load(f)
+
+
+def test_the_table_holds_one_entry_a_reading(bench, frozen):
+    """A cell that reads what an entry already reads is appended to its
+    ``workloads`` and brings no entry of its own (128 is all there are):
+    no two of the entries PR 46 left agree in reader, parameters, unit,
+    ``better``, source, layer and ``moves`` but the named pair, and no
+    cell, then or later, reports one reading under two names."""
+    assert 1 <= len(bench["per_layer"]) <= 128
+    merged = {old["now"] for old in frozen["parent"].values()}
+    cells = [w["name"] for w in bench["workloads"]]
+    by_reading = {}
+    for m in bench["per_layer"]:
+        by_reading.setdefault(reading(m), []).append(m)
+    for same in by_reading.values():
+        names = sorted(m["name"] for m in same)
+        listed = [w for m in same for w in m.get("workloads", cells)]
+        assert len(listed) == len(set(listed)), names
+        of_pr46 = tuple(n for n in names if n in merged)
+        assert len(of_pr46) <= 1 or of_pr46 in SAME_READING, names
+    # every entry a data file, every data file an entry
+    files = {n[:-len(".json")] for n in os.listdir(
+        os.path.join(harness.BENCH_DIR, "layer_metrics"))}
+    assert files == {m["name"] for m in bench["per_layer"]}
+    assert all(m.get("workloads", True) for m in bench["per_layer"])
+
+
+def test_no_reading_changed_when_the_entries_were_merged(bench, frozen):
+    """PR 46 made one entry of the entries that read the same thing and
+    retired two.  ``data/per_layer_renames.json`` freezes the parent's 128:
+    each one's cells are listed under the name it has now, whose file gives
+    the reader and the parameters the old file gave, so every cell reads
+    what it read, under another name."""
+    parent, widened = frozen["parent"], frozen["widened"]
+    assert len(parent) == 128 and set(widened) <= set(parent)
+    now = {m["name"]: m for m in bench["per_layer"]}
+    retired = {n for n, old in parent.items() if old["now"] is None}
+    assert retired == {"train.host_ms_per_step_p50", "cache.acquire_s"}
+    assert not retired & set(now)
+    assert len({old["now"] for old in parent.values()} - {None}) <= 76
+    for name, old in parent.items():
+        if name in retired:
+            continue
+        entry = now[old["now"]]
+        if old["workloads"] is None:
+            assert "workloads" not in entry, name
+        else:
+            assert set(old["workloads"]) <= set(entry["workloads"]), name
+        spec = harness.read_json("layer_metrics", f"{old['now']}.json")
+        assert spec["reader"] == old["reader"], name
+        if name in widened:
+            # the two that read nothing: the new names hold the old ones
+            assert set(old["params"]["kernels"]) < set(
+                spec["params"]["kernels"]), name
+            assert {k: v for k, v in old["params"].items()
+                    if k != "kernels"} == {
+                k: v for k, v in spec["params"].items() if k != "kernels"}
+        else:
+            assert spec.get("params") == old["params"], name
+    # of the parent's cells, none gained a reading but ISSUE 42's two that
+    # had found no room
+    had = {(old["now"], w) for old in parent.values()
+           for w in old["workloads"] or ()}
+    cells = {w for _, w in had}
+    gained = {(m["name"], w) for m in bench["per_layer"]
+              for w in m.get("workloads", ()) if w in cells} - had
+    assert gained == {(n, w) for n, listed in frozen["joined"].items()
+                      for w in listed}
+
+
 def test_parameter_counts_match_the_published_sizes(bench):
-    def count(c):
+    def gpt2(c):
         d, L = c["n_embd"], c["n_layer"]
         return (c["vocab_size"] * d + c["n_positions"] * d
                 + L * (12 * d * d + 13 * d) + 2 * d)
     for c in bench["configs"]:
         cfg = harness.load_config(bench, c["name"])
-        assert count(cfg) == cfg["parameters"]
+        # a family's own closed form where it has one; GPT-2's otherwise
+        count = getattr(harness.family(cfg), "parameters", gpt2)
+        assert count(cfg) == cfg["parameters"], c["name"]
     assert harness.load_config(bench, "gpt2-large")["parameters"] \
         == 774_030_080
     assert harness.load_config(bench, "cerebras-gpt-1.3b")["parameters"] \

@@ -18,6 +18,7 @@ import json
 import pytest
 
 from benchmark import harness, run
+from benchmark.tests.cell_metrics import own_and_shared
 from benchmark.tests.test_runners_cpu import SEED
 
 BENCH = harness.load_benchmark()
@@ -245,51 +246,56 @@ def test_every_new_metric_reads_a_recorded_fixture(family, config):
                   "module_calls": {"jit_step": (0.04, 0.02),
                                    "jit_prefill": (0.9, 0.05)},
                   "kernel_s": {"mla_paged_attention": 0.008,
-                               "ragged-dot-none": 0.5,
-                               "paged_attention": 9.0}}
+                               "gmm": 0.5, "paged_attention": 9.0}}
     _, nbytes = family.costs["dsv2_mla_paged_attention"](v)
     assert metric(v, "kernels.dsv2.mla_paged_attention_roofline") == \
         pytest.approx(100 * max(240_000 * 7 * 278_528 / 197e12,
                                 nbytes / 819e9) / 0.008)
-    assert metric(v, "engine.expert_share.dsv2") == pytest.approx(25.0)
+    assert metric(v, "engine.expert_share") == pytest.approx(25.0)
+    # the same work under the name it ran by until PR 43 (XLA's ragged dot)
+    was = {**v, "trace": {**v["trace"], "kernel_s": {"ragged-dot-none": 0.5}}}
+    assert metric(was, "engine.expert_share") == pytest.approx(25.0)
     _, need = family.costs["dsv2_decode_step"](v, module_match="jit_step")
     assert metric(v, "engine.decode_bandwidth_share.dsv2") == pytest.approx(
         100 * need / 819e9 / 0.04)
-    assert metric(v, "engine.prefill_share.dsv2") == pytest.approx(45.0)
+    assert metric(v, "engine.prefill_share.tput") == pytest.approx(45.0)
     pairs = 6 * 128 * 6
-    assert metric(v, "moe.local_pair_share.dsv2") == pytest.approx(
+    assert metric(v, "moe.local_pair_share") == pytest.approx(
         100 * (576 + 461 + 576 + 692) / (4 * pairs))
-    assert metric(v, "moe.experts_touched_share.dsv2") == pytest.approx(
+    assert metric(v, "moe.experts_touched_share") == pytest.approx(
         100 * (120 + 90 + 114 + 118) / (4 * 120))
-    assert metric(v, "serving.pool_fill_share.dsv2") == pytest.approx(
+    assert metric(v, "serving.pool_fill_share") == pytest.approx(
         100 * 2700 / 4095)
-    assert metric(v, "serving.ahead_share.dsv2") == 100.0
+    assert metric(v, "serving.ahead_share.tput") == 100.0
     v["counters"] = {"generated_tokens": 51_200, "decode_steps": 400}
-    assert metric(v, "serving.tokens_per_step.dsv2") == 128.0
+    assert metric(v, "serving.tokens_per_step") == 128.0
     # a program whose spans carry none of it (the parent): nothing, never 0
     old = view_with(family, [r for r in step_rows() if len(r[3]) < 3])
     old["trace"] = {"window_s": 2.0, "module_s": {}, "module_calls": {},
                     "kernel_s": {"paged_attention": 9.0}}
     for name in ("kernels.dsv2.mla_paged_attention_roofline",
-                 "engine.expert_share.dsv2",
+                 "engine.expert_share",
                  "engine.decode_bandwidth_share.dsv2",
-                 "moe.local_pair_share.dsv2",
-                 "moe.experts_touched_share.dsv2",
-                 "serving.pool_fill_share.dsv2"):
+                 "moe.local_pair_share",
+                 "moe.experts_touched_share",
+                 "serving.pool_fill_share"):
         assert metric(old, name) is None, name
 
 
 def test_the_cell_reports_its_metrics_and_the_accepted_ones_it_must():
-    per_layer = {m["name"]: m for m in harness.cell_metrics(
-        BENCH, "per_layer", CELL["name"])}
-    ours = {n for n in per_layer if n.endswith(".dsv2")
-            or n.startswith("kernels.dsv2.")}
-    assert len(ours) == 16
-    assert set(per_layer) - ours == {"cache.hit_share", "cache.acquire_s"}
-    for n in ours:
-        assert per_layer[n]["moves"] == "serve_tokens_per_s"
-        assert per_layer[n]["workloads"] == [CELL["name"]]
-        harness.read_json("layer_metrics", f"{n}.json")
+    # its kernel's and its decode step's costs are this cell's own; the
+    # rest it shares with the other throughput cells
+    own, shared = own_and_shared(BENCH, CELL["name"], "serve_tokens_per_s")
+    assert own == {"kernels.dsv2.mla_paged_attention_roofline",
+                   "engine.decode_bandwidth_share.dsv2"}
+    assert shared == {
+        "engine.expert_share", "engine.prefill_share.tput",
+        "moe.local_pair_share", "moe.experts_touched_share",
+        "serving.step_ms_p50.tput", "serving.host_ms_per_step_p50.tput",
+        "serving.tokens_per_step", "serving.prefill_ms_p50.tput",
+        "serving.queue_wait_ms_p50", "serving.state_reuse_share.tput",
+        "serving.ahead_share.tput", "serving.pool_fill_share",
+        "serving.pool_bound_share", "device.idle_share.tput"}
     assert [m["name"] for m in harness.cell_metrics(
         BENCH, "end_to_end", CELL["name"])] == ["serve_tokens_per_s",
                                                 "setup_s"]
@@ -468,9 +474,12 @@ def published(config, family, one_chip):
 
 def compiled(one_chip, monkeypatch, fn, args, donate=()):
     import jax
+    from deepspeed_tpu.moe import dropless
     pla = importlib.import_module(
         "deepspeed_tpu.ops.transformer.paged_latent_attention")
     monkeypatch.setattr(pla, "_interpret", lambda: False)
+    # the chip's branch of the grouped products, not the CPU's ragged_dot
+    monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
     args = [a if hasattr(a, "sharding") or not isinstance(a, tuple)
             else jax.ShapeDtypeStruct(*a, sharding=one_chip) for a in args]
     return jax.jit(fn, donate_argnums=donate).trace(*args).lower(
@@ -488,6 +497,7 @@ def test_the_decode_step_fits_a_v5e_and_reads_the_pool_in_place(
     temporary of its size), no expert matrix and no up-projection is copied
     (a slice of the stacked experts in front of a grouped product would be
     315 MB a matrix), and weights plus pool, 11.3 GB, fit."""
+    import re
     import jax.numpy as jnp
     model, params, pool = published
     pool_bytes = BLOCKS * 64 * TOKEN_BYTES
@@ -497,7 +507,12 @@ def test_the_decode_step_fits_a_v5e_and_reads_the_pool_in_place(
     exe = compiled(one_chip, monkeypatch, step, args, donate=(2,))
     m = exe.memory_analysis()
     text = exe.as_text()
-    assert "mla_paged_attention" in text and "ragged-dot" in text
+    assert "mla_paged_attention" in text
+    # the experts' three products a layer (once in the text: the expert
+    # layers are one loop's body) are the Pallas grouped matmul (PR 43), not
+    # XLA's ragged-dot, which the CPU alone still runs
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) >= 3
+    assert "ragged-dot" not in text
     assert text.count("tpu_custom_call") >= 2
     assert m.alias_size_in_bytes >= pool_bytes == 2_348_810_240
     assert m.temp_size_in_bytes < 64 * 2 ** 20

@@ -18,6 +18,7 @@ import json
 import pytest
 
 from benchmark import harness, run
+from benchmark.tests.cell_metrics import own_and_shared
 from benchmark.tests.test_runners_cpu import SEED
 
 TINY = {"model_type": "jamba", "vocab_size": 512, "hidden_size": 128,
@@ -189,13 +190,13 @@ def test_the_cells_traffic_is_serve_chats_mix_in_bursts(bench):
     assert 0 < check["logit_rms_tol"] <= check["logit_tol"] < 0.15
     bound = [m for m in bench["end_to_end"] if m["name"] == "tpot_ms_p95"][0]
     assert CELL in bound["workloads"] and bound["bound"] == 0.05
-    mine_metrics = [m for m in bench["per_layer"]
-                    if m.get("workloads") == [CELL]]
-    assert len(mine_metrics) == 17
-    for m in mine_metrics:
-        assert m["moves"] == "tpot_ms_p95"
-        spec = harness.read_json("layer_metrics", f"{m['name']}.json")
-        harness.load_plugin("readers", spec["reader"])
+    # three are the cell's own (its kernels' costs); fourteen it shares
+    # with serve_chat, one entry a reading (PR 46)
+    own, shared = own_and_shared(bench, CELL, "tpot_ms_p95")
+    assert own == {
+        "kernels.jamba.selective_scan_roofline",
+        "kernels.jamba.paged_attention_roofline", "engine.scan_share.jamba"}
+    assert len(shared) == 14
 
 
 def test_the_cell_through_the_open_loop_runner_on_the_cpu(bench):

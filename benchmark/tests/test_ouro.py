@@ -1,7 +1,7 @@
 """The Ouro family's files (``configs/ouro-2.6b.json``, ``families/ouro.py``,
 ``reference/ouro.py``) and its cell (``traffic/serve_batch_ouro.json``,
 ``runners/serve_backlog.py``, the ``*.ouro`` metric files and the two readers
-they brought): the parameter count against its closed form and against the
+they brought, shared with later cells since PR 46): the parameter count against its closed form and against the
 program's own shapes, the family's costs against numbers worked by hand, the
 cell through its runner at a tiny size on the CPU, the new readers on rows
 made by hand, and the decode step and the longest prefill compiled for a
@@ -18,6 +18,7 @@ import json
 import pytest
 
 from benchmark import harness, run
+from benchmark.tests.cell_metrics import own_and_shared
 from benchmark.tests.test_runners_cpu import SEED
 
 TINY = {"model_type": "ouro", "vocab_size": 512, "hidden_size": 128,
@@ -185,17 +186,17 @@ def metric(view, name):
 
 def test_pool_fill_and_pool_bound_read_the_step_rows(family):
     v = view_with(family, pool_rows())
-    assert metric(v, "serving.pool_fill_share.ouro") == pytest.approx(
+    assert metric(v, "serving.pool_fill_share") == pytest.approx(
         100 * (319 + 300 + 290 + 0) / (4 * 319))
-    assert metric(v, "serving.pool_bound_share.ouro") == pytest.approx(50.0)
-    assert metric(v, "serving.ahead_share.ouro") == 100.0
+    assert metric(v, "serving.pool_bound_share") == pytest.approx(50.0)
+    assert metric(v, "serving.ahead_share.tput") == 100.0
     # a program whose step span has no such attributes: nothing, never zero
     old = view_with(family, [r for r in pool_rows() if len(r[3]) < 2])
-    assert metric(old, "serving.pool_fill_share.ouro") is None
-    assert metric(old, "serving.pool_bound_share.ouro") is None
+    assert metric(old, "serving.pool_fill_share") is None
+    assert metric(old, "serving.pool_bound_share") is None
     # a ring that dropped rows of the window: nothing
     v["program_spans"]["dropped_until"] = 0.5
-    assert metric(v, "serving.pool_fill_share.ouro") is None
+    assert metric(v, "serving.pool_fill_share") is None
 
 
 def test_the_decode_steps_share_of_the_bytes_it_must_move(family, config):
@@ -218,16 +219,21 @@ def test_the_decode_steps_share_of_the_bytes_it_must_move(family, config):
 
 
 def test_the_cell_reports_its_metrics_and_the_accepted_ones_it_must():
-    per_layer = {m["name"]: m for m in harness.cell_metrics(
-        BENCH, "per_layer", CELL["name"])}
-    ours = {n for n in per_layer if n.endswith(".ouro")
-            or n.startswith("kernels.ouro.")}
-    assert len(ours) == 13
-    assert set(per_layer) - ours == {"cache.hit_share", "cache.acquire_s"}
-    for n in ours:
-        assert per_layer[n]["moves"] == "serve_tokens_per_s"
-        assert per_layer[n]["workloads"] == [CELL["name"]]
-    assert per_layer["serving.pool_bound_share.ouro"]["better"] == "lower"
+    # its kernel's and its decode step's costs are this cell's own; the
+    # rest it shares with the other throughput cells
+    own, shared = own_and_shared(BENCH, CELL["name"], "serve_tokens_per_s")
+    assert own == {"kernels.ouro.paged_attention_roofline",
+                   "engine.decode_bandwidth_share.ouro"}
+    assert shared == {
+        "engine.prefill_share.tput", "serving.step_ms_p50.tput",
+        "serving.host_ms_per_step_p50.tput", "serving.tokens_per_step",
+        "serving.prefill_ms_p50.tput", "serving.queue_wait_ms_p50",
+        "serving.state_reuse_share.tput", "serving.ahead_share.tput",
+        "serving.pool_fill_share", "serving.pool_bound_share",
+        "device.idle_share.tput"}
+    pool_bound = [m for m in BENCH["per_layer"]
+                  if m["name"] == "serving.pool_bound_share"]
+    assert pool_bound[0]["better"] == "lower"
     assert [m["name"] for m in harness.cell_metrics(
         BENCH, "end_to_end", CELL["name"])] == ["serve_tokens_per_s",
                                                 "setup_s"]

@@ -100,27 +100,12 @@ def test_step_host_ms_takes_the_waits_for_the_device_out():
                 minus=minus) == pytest.approx(15.0)
     assert read(view(step_rows()), root="serving.step", q=0,
                 minus=minus) == pytest.approx(6.0)
-    # nothing taken out: the spans' durations (train.step is read so)
+    # nothing taken out: the spans' durations
     assert read(view(step_rows()), root="serving.step",
                 q=50) == pytest.approx(20.0)
     assert read(view(step_rows()), root="train.step", q=50) is None
     assert read(view(step_rows(), dropped_until=10.2), root="serving.step",
                 q=50) is None
-
-
-def test_span_seconds_before_window_sums_the_acquisitions_of_set_up():
-    read = reader("span_seconds_before_window")
-    rows = [Span("compile.lower", 1.0, 1.5, None, None, None, {"fn": "a"}),
-            Span("compile.load", 1.5, 1.75, None, None, None, {"fn": "a"}),
-            Span("serving.step", 2.0, 3.0, None, 1, None, None),
-            Span("compile.lower", 2.1, 2.2, "serving.step", 1, None, None),
-            Span("compile.build", 2.2, 4.2, "serving.step", 1, None, None),
-            # a recompile inside the window is not set-up
-            Span("compile.build", 12.0, 13.0, "serving.step", 9, None, None)]
-    assert read(view(rows), prefix="compile.") == pytest.approx(2.85)
-    # the oldest rows are what it reads: any dropped row may have been one
-    assert read(view(rows, dropped_until=0.5), prefix="compile.") is None
-    assert read(view(rows[2:3]), prefix="compile.") is None
 
 
 def test_readers_return_nothing_for_a_program_without_a_recorder(
@@ -132,7 +117,6 @@ def test_readers_return_nothing_for_a_program_without_a_recorder(
     v = {"facts": {"window": (0.0, 1.0)}}
     assert reader("request_stamps")(v, what="queue_wait", q=95) is None
     assert reader("step_host_ms")(v, root="serving.step", q=50) is None
-    assert reader("span_seconds_before_window")(v, prefix="compile.") is None
     assert reader("idle_in_span")(
         v, span="serving.admit", root="serving.step",
         trace_root="benchmark/tests/data/no_such_dir") is None

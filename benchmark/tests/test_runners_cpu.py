@@ -148,6 +148,25 @@ def test_a_failed_check_is_not_correct():
     assert r["correct"] is False
 
 
+def test_setup_s_leaves_out_the_call_that_started_the_tpu_runtime():
+    """``main`` times its ``jax.devices()`` and hands the seconds in: a
+    process that began 100 s ago and spent 90 of them there reports the
+    other 10 and what the run itself adds, and says so in ``details``."""
+    import time
+    bench = harness.load_benchmark()
+    cell = harness.cell_by_name(bench, "train_z1")
+    kw = dict(seed=3, seconds=0.5, trace=False, config=TINY,
+              traffic=tiny_traffic("train_z1"), log=lambda msg: None)
+    began = time.monotonic() - 100.0
+    whole = run.run_cell(bench, cell, t_process_start=began, **kw)
+    less = run.run_cell(bench, cell, t_process_start=began,
+                        chip_reach_s=90.0, **kw)
+    assert whole["metrics"]["setup_s"]["value"] > 100.0
+    assert 10.0 < less["metrics"]["setup_s"]["value"] < 100.0
+    assert less["details"]["chip_reach_s"] == 90.0
+    assert whole["details"]["chip_reach_s"] == 0.0
+
+
 def test_main_refuses_the_cpu(capsys):
     rc = run.main(["--workload", "train_z1", "--seed", "1", "--seconds",
                    "1", "--trace", "0"])

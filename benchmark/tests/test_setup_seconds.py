@@ -74,6 +74,17 @@ def test_the_eight_phases_add_up_to_the_window_start_less_process_start():
 
 
 @pytest.mark.parametrize("phase", PHASES)
+def test_the_call_that_started_the_tpu_runtime_is_left_out(phase):
+    """``run.py`` hands the seconds of its ``jax.devices()`` in: the clock
+    starts that much later, so ``before_program`` alone is shorter and the
+    eight still add up to what ``setup_s`` counts."""
+    view = dict(hand_view(), chip_reach_s=9.0)
+    want = dict(WANT, before_program=15.0 - 9.0)
+    assert read(view, phase) == pytest.approx(want[phase])
+    assert sum(read(view, p) for p in PHASES) == pytest.approx(40.0 - 9.0)
+
+
+@pytest.mark.parametrize("phase", PHASES)
 def test_nothing_once_the_store_has_refused_a_row(phase):
     assert read(hand_view(whole=False), phase) is None
 
@@ -114,7 +125,7 @@ def test_every_setup_metric_names_a_phase_and_none_is_missing():
         spec = harness.read_json("layer_metrics", f"{m['name']}.json")
         assert spec["reader"] == "setup_seconds"
         assert m["name"] == f"setup.{spec['params']['phase']}_s"
-        # every cell reports setup_s: no list, as cache.acquire_s has none
+        # every cell reports setup_s: no list, as cache.hit_share has none
         assert "workloads" not in m and m["moves"] == "setup_s"
         assert (m["unit"], m["better"], m["source"]) == (
             "s", "lower", "program_span")
