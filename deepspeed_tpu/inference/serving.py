@@ -449,10 +449,11 @@ class _Slot:
 # `prefix_cache`, `kv_snapshot`, `transfer` and a `role` each move or share a
 # stream BY its K/V blocks: they need a stream to be nothing but the `k` / `v`
 # blocks of one growing table.  Three kinds of serving state are more than
-# that, and serving one with such a feature armed would serve a silently wrong
-# stream: refused at construction, by name, until the feature learns the kind
-# (ROADMAP, Queue 2).  kind -> (what the error calls it, {feature: reason});
-# the kind is also its anchor in docs/serving.md.
+# that (a model may show more than one), and serving one with such a feature
+# armed would serve a silently wrong stream: refused at construction, by name,
+# until the feature learns the kind (ROADMAP, Queue 2).  kind -> (what the
+# error calls it, {feature: reason}); the kind is also its anchor in
+# docs/serving.md.
 _SHIPS_IMAGES = ("the transfer queue ships block images: the decode side would "
                  "seat K/V without the recurrent rows")
 _RING_IMAGE = ("a block image covers the growing table's blocks alone: a "
@@ -482,20 +483,25 @@ _NEEDS_BLOCKS_ALONE = {
 
 def _refuse_what_needs_blocks_alone(config, model, pool):
     """Raise, by name, for the first feature of ``_NEEDS_BLOCKS_ALONE`` that
-    ``config`` arms over the first kind of state that ``model`` and its
-    ``pool`` show."""
+    ``config`` arms over a kind of state that ``model`` and its ``pool``
+    show: EVERY kind they show is named, each with its reason and its anchor
+    (a model may show two: recurrent rows beside a ring)."""
     shown = {"recurrent-state": getattr(model, "has_recurrent_state", False),
              "window-layers": getattr(model, "has_window_layers", False),
              "latent-pool": pk.is_latent_pool(pool)}
-    for kind, (what, why) in _NEEDS_BLOCKS_ALONE.items():
-        if not shown[kind]:
+    kinds = [kind for kind in _NEEDS_BLOCKS_ALONE if shown[kind]]
+    # the table may be ragged: a feature is refused for the kinds that list it
+    for name in dict.fromkeys(n for k in kinds
+                              for n in _NEEDS_BLOCKS_ALONE[k][1]):
+        value = getattr(config, name)
+        if value in (None, False, "mixed"):
             continue
-        for name, reason in why.items():
-            value = getattr(config, name)
-            if value not in (None, False, "mixed"):
-                raise ValueError(
-                    f"serving.{name}={value!r} cannot serve a model with "
-                    f"{what}: {reason} (docs/serving.md#{kind})")
+        hit = [k for k in kinds if name in _NEEDS_BLOCKS_ALONE[k][1]]
+        whats = " and ".join(_NEEDS_BLOCKS_ALONE[k][0] for k in hit)
+        reasons = "; ".join(_NEEDS_BLOCKS_ALONE[k][1][name] for k in hit)
+        anchors = ", ".join(f"docs/serving.md#{k}" for k in hit)
+        raise ValueError(f"serving.{name}={value!r} cannot serve a model "
+                         f"with {whats}: {reasons} ({anchors})")
 
 
 class ServingEngine:

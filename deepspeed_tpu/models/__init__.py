@@ -3,7 +3,9 @@ Jamba (Mamba + attention hybrid), Ouro (one stack of layers looped),
 DeepSeek-V2 (latent attention, routed and shared experts), AFMoE (window and
 global attention layers, gated grouped-query attention, sigmoid routing),
 Nemotron-H (Mamba-2 mixers, attention layers and non-gated expert layers, one
-of them a layer)."""
+of them a layer), Phi4Flash (a decoder-hybrid-decoder: Mamba-1 and window
+layers, one full layer whose K/V the cross-attention layers read again, Gated
+Memory Units, differential attention)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -40,6 +42,9 @@ def build(name, **overrides):
         if name.startswith("nemotron-h"):
             from .nemotron_h import NemotronH
             return NemotronH(preset=name, **overrides)
+        if name.startswith("phi4flash"):
+            from .phi4flash import Phi4Flash
+            return Phi4Flash(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

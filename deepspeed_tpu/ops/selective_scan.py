@@ -9,6 +9,10 @@ The recurrence, per channel ``d`` and state column ``n``::
     y_t[d]    = sum_n S_t[n, d] * C_t[n] + D[d] * x_t[d]
     out_t[d]  = y_t[d] * silu(z_t[d])
 
+With ``z = None`` every entry point hands back ``y`` itself, ungated (the
+upstream ``selective_scan_fn(z=None)``): a caller that needs ``y`` before the
+gate (a layer whose scan output other layers read) gates it outside.
+
 Layout: the state is ``(N, Di)`` — the channel dim ``Di`` on the 128 lanes,
 the 16 state columns outside it.  The published ``(Di, N)`` orientation
 would pad a 16-wide minor dim to 128 lanes on a TPU, eight times the bytes.
@@ -64,8 +68,8 @@ def mask_delta(delta, t_real):
 def selective_scan_jnp(x, delta, A, B, C, D, z, h0=None):
     """``x``, ``z``: (Bt, T, Di); ``delta``: (Bt, T, Di) float32, after
     softplus; ``A``: (N, Di) float32 (negative); ``B``, ``C``: (Bt, T, N);
-    ``D``: (Di,); ``h0``: (Bt, N, Di) float32 or None.  Returns ``(out (Bt,
-    T, Di) in x.dtype, state (Bt, N, Di) float32)``."""
+    ``D``: (Di,); ``h0``: (Bt, N, Di) float32 or None; ``z`` None: no gate.
+    Returns ``(out (Bt, T, Di) in x.dtype, state (Bt, N, Di) float32)``."""
     f32 = jnp.float32
     Bt, T, Di = x.shape
     N = A.shape[0]
@@ -83,31 +87,38 @@ def selective_scan_jnp(x, delta, A, B, C, D, z, h0=None):
           B.astype(f32).swapaxes(0, 1), C.astype(f32).swapaxes(0, 1))
     S, y = jax.lax.scan(step, h0.astype(f32), xs)
     y = y.swapaxes(0, 1) + D.astype(f32) * x.astype(f32)
+    if z is None:
+        return y.astype(x.dtype), S
     zf = z.astype(f32)
     return (y * zf * jax.nn.sigmoid(zf)).astype(x.dtype), S
 
 
 def selective_step(x, delta, A, B, C, D, z, S):
     """One token for every row: ``x``, ``z``, ``delta``: (Bt, Di); ``B``,
-    ``C``: (Bt, N); ``S``: (Bt, N, Di) float32.  Returns ``(out (Bt, Di)
-    in x.dtype, new S)``.  Plain ``jax.numpy``: XLA fuses it into one pass
+    ``C``: (Bt, N); ``S``: (Bt, N, Di) float32; ``z`` None: no gate.  Returns
+    ``(out (Bt, Di) in x.dtype, new S)``.  Plain ``jax.numpy``: XLA fuses it into one pass
     over the state (decode's update; PERF.md says how far from its bytes)."""
     f32 = jnp.float32
     xf, d = x.astype(f32), delta.astype(f32)
     dA = jnp.exp(d[:, None, :] * A.astype(f32)[None])
     S = dA * S + (d * xf)[:, None, :] * B.astype(f32)[:, :, None]
     y = jnp.einsum("bnd,bn->bd", S, C.astype(f32)) + D.astype(f32) * xf
+    if z is None:
+        return y.astype(x.dtype), S
     zf = z.astype(f32)
     return (y * zf * jax.nn.sigmoid(zf)).astype(x.dtype), S
 
 
 # ---------------------------------------------------------------- the kernel
-def _scan_kernel(bc_ref, x_ref, dl_ref, z_ref, a_ref, d_ref, y_ref, s_out_ref,
-                 s_ref, *, n_state, chunk, seq_len):
+def _scan_kernel(bc_ref, x_ref, dl_ref, *refs, n_state, chunk, seq_len,
+                 gated=True):
     """Grid (batch, Di tiles, chunks), chunks innermost.  Blocks: ``x``,
-    ``delta``, ``z``, ``y`` (1, chunk, 8, 128); ``A`` (N, 8, 128); ``D``
-    (8, 128); ``bc`` (chunk * 2N,) float32 in SMEM, token-major ``[B_t,
-    C_t]``; state out (1, N, 8, 128); scratch ``s_ref`` (N, 8, 128)."""
+    ``delta``, ``z`` (``gated`` alone), ``y`` (1, chunk, 8, 128); ``A`` (N, 8,
+    128); ``D`` (8, 128); ``bc`` (chunk * 2N,) float32 in SMEM, token-major
+    ``[B_t, C_t]``; state out (1, N, 8, 128); scratch ``s_ref`` (N, 8,
+    128)."""
+    z_ref = refs[0] if gated else None
+    a_ref, d_ref, y_ref, s_out_ref, s_ref = refs[1:] if gated else refs
     c = pl.program_id(2)
     N = n_state
 
@@ -133,8 +144,10 @@ def _scan_kernel(bc_ref, x_ref, dl_ref, z_ref, a_ref, d_ref, y_ref, s_out_ref,
             for n in range(N):
                 S[n] = jnp.exp(dl * A[n]) * S[n] + dx * bc_ref[base + n]
                 acc = acc + S[n] * bc_ref[base + N + n]
-            zv = z_ref[0, t].astype(jnp.float32)
-            y_ref[0, t] = (acc * zv * jax.nn.sigmoid(zv)).astype(y_ref.dtype)
+            if gated:
+                zv = z_ref[0, t].astype(jnp.float32)
+                acc = acc * zv * jax.nn.sigmoid(zv)
+            y_ref[0, t] = acc.astype(y_ref.dtype)
         return tuple(S)
 
     S = jax.lax.fori_loop(0, n_groups, group,
@@ -179,15 +192,17 @@ def _kernel_call(x, delta, A, B, C, D, z, *, interpret):
     per = chunk * 2 * N
 
     tok = pl.BlockSpec((1, chunk, _SUB, _LANE), lambda b, d, c: (b, c, 0, d))
+    gated = z is not None
     kernel = functools.partial(_scan_kernel, n_state=N, chunk=chunk,
-                               seq_len=T)
+                               seq_len=T, gated=gated)
+    toks = [x, delta] + ([z] if gated else [])
     y, S = pl.pallas_call(
         kernel,
         grid=(Bt, W // _LANE, n_chunks),
         in_specs=[
             pl.BlockSpec((per,), lambda b, d, c: (b * n_chunks + c,),
                          memory_space=pltpu.SMEM),
-            tok, tok, tok,
+            *[tok] * len(toks),
             pl.BlockSpec((N, _SUB, _LANE), lambda b, d, c: (0, 0, d)),
             pl.BlockSpec((_SUB, _LANE), lambda b, d, c: (0, d)),
         ],
@@ -201,8 +216,8 @@ def _kernel_call(x, delta, A, B, C, D, z, *, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="selective_scan",
-    )(bc, fold(x.astype(f32)), fold(delta.astype(f32)), fold(z.astype(f32)),
-      fold(A.astype(f32)), fold(D.astype(f32)))
+    )(bc, *[fold(t.astype(f32)) for t in toks], fold(A.astype(f32)),
+      fold(D.astype(f32)))
     return y.reshape(Bt, T, Di).astype(x.dtype), S.reshape(Bt, N, Di)
 
 
