@@ -294,6 +294,80 @@ def test_the_latent_kernel_is_the_gathered_arithmetic():
     assert rel_err(out, ref) < 1e-5
 
 
+
+# The walk (PR 48): blocks of 64 rows of 128 (a latent of 32 + 16) under 4
+# heads.  A case is (chunk, tail tile, table, rows): the file's constants
+# shrunk to chunks of 256 tokens and tail tiles of 128, so that a table of 10
+# blocks is two whole chunks and one of 2 blocks and a last chunk's products
+# are 128 or 256 wide, or (None) left as served, where a table of 20 blocks
+# is one chunk of 1,024 tokens and one of 4 blocks, at widths of 256 to 1,024.
+# A row is the query token's position, or None for an empty slot.
+WALK_BS = 64
+WALK_ROWS = {
+    # one before, on and after a block's edge, a tail tile's, a chunk's,
+    # and the table's last position
+    "edges": (256, 128, 10, [62, 63, 64, 126, 127, 128, 254, 255, 256, 383,
+                             384, 510, 511, 512, 639]),
+    # a table filled to nb_max, and a position past it (clamped)
+    "full_table": (256, 128, 10, [639, 639, 650, 639]),
+    # a table shorter than one chunk: one chunk of 3 blocks
+    "short_table": (None, None, 3, [0, 191, None, 40, 128]),
+    # dead rows first, between live rows and last
+    "dead_rows": (256, 128, 10, [None, 40, None, None, 600, 5, None]),
+    "all_dead": (256, 128, 10, [None, None, None]),
+    # every ring hand-over: one-chunk rows in a run (the fetch two positions
+    # ahead belongs to the row after next) between three-chunk rows
+    "handover": (256, 128, 10, [511, 0, 3, 639, 127, None, 513, 1, 255, 256]),
+    "as_served": (None, None, 20, [1023, 1024, 1279, 300, None, 767, 768, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_ROWS))
+def test_the_latent_walk_does_live_work_only(case, monkeypatch):
+    """Live rows equal the gathered arithmetic wherever they stand in the
+    batch and wherever their length ends; dead rows are exactly zero;
+    nothing is NaN though the scratch block, every block no table names and
+    every position past a row's length is (the interpreter fills the VMEM
+    ring with NaN too, so a block that was not fetched fails in P @ V)."""
+    import importlib
+    pla = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.paged_latent_attention")
+    chunk, tile, nb_max, rows = WALK_ROWS[case]
+    if chunk is not None:
+        monkeypatch.setattr(pla, "_CHUNK_TOKENS", chunk)
+        monkeypatch.setattr(pla, "_TAIL_TOKENS", tile)
+    rng = np.random.default_rng(7)
+    B = len(rows)
+    lat = np.full((2, 1 + B * nb_max + 2, WALK_BS, 128), np.nan, np.float32)
+    tables = np.zeros((B, nb_max), np.int32)
+    lengths = np.zeros((B,), np.int32)
+    for b, length in enumerate(rows):
+        if length is None:
+            continue
+        n = min(length // WALK_BS + 1, nb_max)          # the blocks it holds
+        tables[b, :n] = 1 + b * nb_max + np.arange(n)
+        lengths[b] = length
+        mine = lat[1, tables[b, :n]].reshape(n * WALK_BS, 128)
+        mine[:length + 1] = rng.standard_normal(mine[:length + 1].shape)
+        lat[1, tables[b, :n]] = mine.reshape(n, WALK_BS, 128)
+    pool = {"latent": jnp.asarray(lat)}
+    q = jnp.asarray(rng.standard_normal((B, 4, 128)), jnp.float32)
+    out = np.asarray(jax.jit(lambda q, pool: pla.paged_latent_attention(
+        q, pool, tables, lengths, 1, value_width=32, sm_scale=0.3))(q, pool))
+    live = tables[:, 0] != pk.SCRATCH_BLOCK
+    assert np.isfinite(out).all()
+    assert not out[~live].any()
+    if live.any():
+        stored = np.nan_to_num(np.asarray(pk.gather_latent(
+            pool, 1, jnp.asarray(tables), jnp.float32)))
+        s = np.einsum("bhr,btr->bht", np.asarray(q), stored) * 0.3
+        valid = np.arange(stored.shape[1])[None, None, :] <= lengths[
+            :, None, None]
+        ref = np.einsum("bht,btc->bhc", np.asarray(jax.nn.softmax(
+            jnp.where(valid, s, -jnp.inf), -1)), stored[..., :32])
+        assert rel_err(out[live], ref[live]) < 1e-5
+
+
 # ------------------------------------------------------ (d) negative controls
 def decode_error(model, params):
     """A prompt through ``prefill_paged`` and four tokens through

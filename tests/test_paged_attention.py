@@ -585,6 +585,39 @@ def test_window_kernel_compiles_for_a_v5e(v5e):
     assert not re.search(re.escape(" = " + payload) + r"\S* copy\(", text)
 
 
+
+def test_latent_kernel_compiles_for_a_v5e(v5e):
+    """The latent kernel's walk at ``serve_batch_deepseek_v2``'s shape (128
+    slots, tables of 64 blocks of 64 rows of 640 bfloat16, 128 heads, a
+    value 512 wide) through Mosaic and XLA:TPU for a described v5e: the plan
+    in SMEM scratch and the semaphores carried across the grid pass, one
+    custom call under the name the benchmark reads, no copy of the pool."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.ops.transformer.paged_latent_attention import (
+        paged_latent_attention)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    slots, heads, row, block, nb_max = 128, 128, 640, 64, 64
+    rows = (7, 4096, block, row)
+
+    def fn(q, tables, lengths, latent):
+        return paged_latent_attention(q, {pk.LATENT: latent}, tables,
+                                      lengths, 1, value_width=512,
+                                      sm_scale=0.1, interpret=False)
+    shapes = [((slots, heads, row), jnp.bfloat16),
+              ((slots, nb_max), jnp.int32), ((slots,), jnp.int32),
+              (rows, jnp.bfloat16)]
+    exe = jax.jit(fn).trace(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    ]).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "mla_paged_attention" in text
+    payload = "bf16[{}]".format(",".join(map(str, rows)))
+    assert payload in text
+    assert not re.search(re.escape(" = " + payload) + r"\S* copy\(", text)
+    assert exe.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 # ------------------------------- the routed experts' grouped products, for
 # the same described chip (kept in THIS file: one worker describes the
 # topology once, and a third file doing so could land on another worker)
