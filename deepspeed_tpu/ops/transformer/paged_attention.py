@@ -22,10 +22,17 @@ BlockSpec-LUT and manual-DMA variants side by side):
 
 - ``online`` — the compiled TPU path: grid ``(B,)``, one program per
   slot, the slot's **live** tokens (``length + W`` — short slots skip
-  their tail entirely) fetched in chunks of whole 128-lane score columns
-  through a triple-buffered VMEM ring with explicit ``make_async_copy``
-  from the HBM-resident pool, masked **online-softmax** (fp32 running
-  max/denominator) accumulation per chunk.  The walk does live work
+  their tail entirely) fetched in chunks through a triple-buffered VMEM
+  ring with explicit ``make_async_copy`` from the HBM-resident pool,
+  masked **online-softmax** (fp32 running max/denominator), one update a
+  chunk.  The ``(R, Tc)`` **score tile is chosen from the input**
+  (:func:`score_tile`, a plain function of the pool's width in bytes, the
+  block size, the table's length and the score rows): a chunk costs about
+  the same whatever it carries, so ``Tc`` is the fewest whole blocks and
+  whole 128-lane score columns whose K rows carry ``_CHUNK_BYTES`` — 128
+  tokens of a bfloat16 pool 2,048 lanes wide, 256 at 1,024 or 1,280 lanes,
+  1,024 at 256, 2,048 at 128 — and ``R`` is the real (window row, head)
+  pairs, packed and rounded up once to a sublane tile.  The walk does live work
   only: a **dead row** (an empty slot: ``tables[b, 0]`` is the scratch
   block) issues no DMA, runs no matmul and writes zeros; the **ring is
   carried** across programs (the grid is sequential and scratch
@@ -34,9 +41,12 @@ BlockSpec-LUT and manual-DMA variants side by side):
   fetch issues before position p - 2 computes, whichever row owns it,
   the ``_fwd_kernel_dma`` discipline stretched over the whole call);
   and the **tail is trimmed**: a chunk fetches only the blocks that
-  hold a live position, the value tile's dead rows zeroed before
-  ``P @ V``.  Every tile keeps the pool's ``(rows, H·hd)`` shape: Mosaic
-  DMAs and slices in whole 128-lane tiles, so a 64-lane head is never
+  hold a live position (in a loop over them), a row's last chunk runs
+  its products over the fewest tiles of ``_TAIL_TOKENS`` that cover its
+  live positions, the value tile's dead rows zeroed before ``P @ V``;
+  every chunk before the last is live whole, lands under one wait a leaf
+  and runs with no mask.  Every tile keeps the pool's ``(rows, H·hd)``
+  shape: Mosaic DMAs and slices in whole 128-lane tiles, so a 64-lane head is never
   cut out — heads are separated by a block-diagonal query operand on
   the MXU (``_online_kernel``);
 - ``exact`` — the interpreter-only fallback (non-TPU backends / tests):
@@ -91,6 +101,15 @@ _N_BUF = 3    # DMA ring depth (flash_attention._N_KV_BUF): slot (j+2)%3
 # models, which import this package): the block a table is padded with and
 # the one an empty slot's whole table names
 SCRATCH_BLOCK = 0
+
+# the online walk's tile (score_tile; PERF.md section 6, PR 49)
+_CHUNK_BYTES = 512 * 1024   # K rows a chunk: 128 bf16 tokens of a pool 2,048
+#                             lanes wide (what its chunk always carried), 256
+#                             at 1,024 or 1,280 lanes, 1,024 at 256, 2,048 at 128
+_TAIL_TOKENS = 128          # a last chunk's products run over whole multiples
+#                             of this many tokens (whole score columns)
+_SCORE_BYTES = 512 * 1024   # the (R, Tc) fp32 scores of one softmax update:
+#                             (128, 1024) held, (128, 4096) spilled (PR 48)
 
 
 def _interpret():
@@ -233,16 +252,33 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
+def score_tile(token_bytes, block_size, nb_max, rows):
+    """The ``(Tc, R)`` score tile of the online walk, from what the call reads
+    of its input: ``token_bytes`` the pool's width in bytes (one token's K
+    row), the block size, the table's length and the real score rows (window
+    rows x K/V heads, or quantization blocks).
+
+    ``Tc``, a chunk's tokens: the fewest whole blocks and whole 128-lane score
+    columns whose K rows carry ``_CHUNK_BYTES`` (a chunk costs about the same
+    whatever it carries, so a narrow pool takes more tokens a chunk), no wider
+    than ``_SCORE_BYTES`` of float32 scores and no longer than the table.
+    ``R``: the real rows rounded up ONCE to a float32 sublane tile."""
+    R = _round_up(rows, 8)
+    unit = int(np.lcm(block_size, 128))
+    want = _round_up(-(-_CHUNK_BYTES // token_bytes), unit)
+    fit = max(_SCORE_BYTES // (4 * R) // unit, 1) * unit
+    return min(want, fit, nb_max * block_size), R
+
+
 def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
                    compute_dtype, quant_block, group, rows_per_token,
-                   q_per_kv=1, window=None):
+                   tail_widths, q_per_kv=1, window=None):
     """Grid (B,): ONE program per slot walks the slot's LIVE tokens in
-    chunks of ``group`` blocks (``group * block_size`` key positions: a
-    whole number of 128-lane score columns) through a triple-buffered
+    chunks of ``group`` blocks (:func:`score_tile`) through a triple-buffered
     make_async_copy ring from the HBM pool, carrying fp32 online-softmax
-    state (m, l, acc) per row.  A dead row (its table names the scratch
-    block: the fact ``inference/serving.py``'s own step reads) fetches
-    nothing, computes nothing and writes zeros.
+    state (m, l, acc) per row: one update a chunk.  A dead row (its table
+    names the scratch block: the fact ``inference/serving.py``'s own step
+    reads) fetches nothing, computes nothing and writes zeros.
 
     The ring is ONE ring over the whole call.  Program 0 writes the plan
     into SMEM scratch, which like the VMEM buffers and the DMA semaphores
@@ -252,7 +288,15 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     chunk at ring position p lands in buffer ``p % 3`` and its fetch
     starts before position p - 2 computes, whichever row that is — so a
     row's last chunks compute while the next live row's first two are in
-    flight.  A chunk fetches only the blocks that hold a live position.
+    flight.  A chunk fetches only the blocks that hold a live position, in
+    a LOOP over them (128 unrolled descriptors would be seconds of every
+    start-up's lowering).  Every chunk before a row's last (under a
+    window: between its first and its last) is live whole: it lands under
+    one wait a leaf and runs with no mask; the last chunk's products run
+    over the fewest tiles of ``_TAIL_TOKENS`` that cover its live positions
+    (``tail_widths``: a branch of straight-line code each), dead positions
+    masked in the scores and zeroed in the value tile (a block that was not
+    fetched leaves what the buffer held).
 
     Every tile keeps the pool's (rows, H*hd) shape — no per-head slice,
     reshape or transpose of a sub-128-lane head (Mosaic keeps none of
@@ -262,13 +306,16 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     elsewhere, so ``Qbd @ K^T`` is every head's scores at once and the
     diagonal blocks of ``P @ V`` are every head's outputs.  That is
     H times the FLOPs the math needs, on a kernel whose time is the KV
-    DMA.
+    DMA.  Rows are PACKED: row ``w * rows_per_token + h``, the whole
+    rounded up once (:func:`score_tile`'s R); a window row reaches its rows, and the
+    rows' diagonal blocks their window row, through a 0/1 matmul (W > 1).
 
     int8 pools: a row is one QUANTIZATION block (``quant_block``
-    columns; ``hd // quant_block`` rows per head).  Payloads cast to
-    the compute dtype (exact: |q| <= 127) and the fp32 scales multiply
-    the (R, chunk) score/probability tiles, never a (chunk, H*hd) one:
-    they arrive already transposed to (rows, positions)
+    columns; ``hd // quant_block`` rows per head; ``rows_per_token`` is
+    padded to whole sublane tiles and heads, as the scale tiles are).
+    Payloads cast to the compute dtype (exact: |q| <= 127) and the fp32
+    scales multiply the (R, chunk) score/probability tiles, never a
+    (chunk, H*hd) one: they arrive already transposed to (rows, positions)
     (:func:`_scale_rows`), one tile a chunk, started and awaited with the
     chunk's payload.  Rows of one head sum their partial scores
     through a 0/1 matmul before the softmax.
@@ -294,15 +341,16 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     b = pl.program_id(0)
     B = tables_ref.shape[0]
     lay = layer_ref[0]
-    bs, G, Rw = block_size, group, rows_per_token
+    bs, G, Rw, R = block_size, group, rows_per_token, m_ref.shape[0]
     W, HD = q_ref.shape[1:]
     n_tok = W // q_per_kv                     # window tokens
     qb = quant_block if quantized else head_dim
     per_head = head_dim // qb                 # rows per head
-    R = W * Rw
     Tc = G * bs
     sm_scale = (1.0 / np.sqrt(head_dim)) if scale_attn else 1.0
     n_chunks = -(-nb_max // G)
+    exact = (jax.lax.Precision.HIGHEST if compute_dtype == jnp.float32
+             else None)
 
     def live_units(row, unit, most):
         """Chunks (or blocks) of ``row`` that hold a position <= length +
@@ -315,15 +363,15 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
 
     def first_unit(row, unit):
         """The chunk (or block) that holds ``row``'s first live position."""
+        if window is None:
+            return 0
         return jnp.maximum(lengths_ref[row] - (window - 1), 0) // unit
 
     def row_chunks(row):
         """Chunks ``row``'s walk covers: all that hold a live position, or,
         under a window, those from the first live one on."""
-        n = live_units(row, Tc, n_chunks)
-        if window is None:
-            return n
-        return jnp.maximum(n - first_unit(row, Tc), 0)
+        return jnp.maximum(live_units(row, Tc, n_chunks)
+                           - first_unit(row, Tc), 0)
 
     @pl.when(b == 0)
     def _():
@@ -347,59 +395,40 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
     base = first_ref[b]                       # ring position of chunk 0
     total = first_ref[B]
 
-    def fetches(row, c, slot):
-        """Chunk c of ``row`` into buffer ``slot``: (wanted, copies) pairs,
-        one per block of the chunk — wanted if the block holds a live
-        position, which a live chunk's first always does — and one for an
-        int8 pool's scale tiles, which go whole with their chunk."""
-        live_blocks = live_units(row, bs, nb_max)
-        if window is not None:
-            c = c + first_unit(row, Tc)       # the row's c-th chunk
-            first_block = first_unit(row, bs)
-        out = []
-        for g in range(G):
-            if window is None:
-                # a chunk's tail past the table is never fetched; the clamp
-                # keeps the scalar read inside the table
-                ki = tables_ref[row, jnp.minimum(c * G + g, nb_max - 1)]
-                wanted = g == 0 or c * G + g < live_blocks
-            else:
-                ki = tables_ref[row, jax.lax.rem(c * G + g, nb_max)]
-                wanted = jnp.logical_and(c * G + g >= first_block,
-                                         c * G + g < live_blocks)
-            if quantized:
-                # int8 tiles are 32 sublanes: a block lands whole at
-                # [slot, g] and is cast into the chunk-shaped stage below
-                kd, vd = kbuf.at[slot, g], vbuf.at[slot, g]
-            else:
-                kd = kbuf.at[slot, pl.ds(g * bs, bs)]
-                vd = vbuf.at[slot, pl.ds(g * bs, bs)]
-            out.append((wanted,
-                        [pltpu.make_async_copy(k_hbm.at[lay, ki], kd,
-                                               sem.at[slot, 0]),
-                         pltpu.make_async_copy(v_hbm.at[lay, ki], vd,
-                                               sem.at[slot, 1])]))
-        if quantized:
-            # a whole number of 128-lane tiles: Tc itself, or (a table
-            # shorter than one tile) the single padded chunk at 0
-            first = pl.multiple_of(c * Tc, 128) if n_chunks > 1 else 0
-            cols = pl.ds(first, ksbuf.shape[-1])
-            out.append((True,
-                        [pltpu.make_async_copy(ks_hbm.at[row, :, cols],
-                                               ksbuf.at[slot],
-                                               sem.at[slot, 2]),
-                         pltpu.make_async_copy(vs_hbm.at[row, :, cols],
-                                               vsbuf.at[slot],
-                                               sem.at[slot, 3])]))
-        return out
+    def scale_copies(row, c, slot):
+        """An int8 pool's scale tiles go whole with their chunk: a whole
+        number of 128-lane tiles, Tc itself, or (a table shorter than one
+        tile) the single padded chunk at 0."""
+        first = pl.multiple_of(c * Tc, 128) if n_chunks > 1 else 0
+        cols = pl.ds(first, ksbuf.shape[-1])
+        return [pltpu.make_async_copy(ks_hbm.at[row, :, cols], ksbuf.at[slot],
+                                      sem.at[slot, 2]),
+                pltpu.make_async_copy(vs_hbm.at[row, :, cols], vsbuf.at[slot],
+                                      sem.at[slot, 3])]
 
     def each_copy(row, c, slot, act):
-        """Start or await (``act``) the wanted copies of chunk c."""
-        for wanted, copies in fetches(row, c, slot):
-            @pl.when(wanted)
-            def _():
-                for cp in copies:
-                    act(cp)
+        """Start or await (``act``) the ``row``'s c-th chunk in buffer
+        ``slot``: one copy a leaf per block that holds a live position (a
+        chunk of the walk always has one), in a loop."""
+        c = c + first_unit(row, Tc)
+        lo = 0 if window is None else jnp.clip(
+            first_unit(row, bs) - c * G, 0, G - 1)
+        hi = jnp.clip(live_units(row, bs, nb_max) - c * G, lo + 1, G)
+
+        def one(g, carry):
+            at = c * G + g
+            ki = tables_ref[row, at if window is None
+                            else jax.lax.rem(at, nb_max)]
+            act(pltpu.make_async_copy(k_hbm.at[lay, ki], kbuf.at[slot, g],
+                                      sem.at[slot, 0]))
+            act(pltpu.make_async_copy(v_hbm.at[lay, ki], vbuf.at[slot, g],
+                                      sem.at[slot, 1]))
+            return carry
+
+        jax.lax.fori_loop(lo, hi, one, 0)
+        if quantized:
+            for cp in scale_copies(row, c, slot):
+                act(cp)
 
     def start_at(p):
         """Start the fetch of ring position p, if the call has one: a
@@ -427,103 +456,167 @@ def _online_kernel(*refs, block_size, nb_max, head_dim, scale_attn,
             start_at(1)
 
         length = lengths_ref[b]
-        # row r = (w, i): window token w, quantization block i of the
-        # merged head dim (16-bit pools: i is the head)
+        # row r = (w, i): window row w, quantization block i of the merged
+        # head dim (16-bit pools: i is the head); rows past W * Rw pad R
         row = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (R, HD), 1)
-        diag = col // qb == row % Rw                          # (R, HD)
-        q = q_ref[0].astype(jnp.float32)                      # (W, HD)
-        qbd = jnp.zeros((R, HD), jnp.float32)
-        for w in range(W):
-            mine = jnp.logical_and(diag, row // Rw == w)
-            qbd = jnp.where(mine, jnp.broadcast_to(q[w:w + 1], (R, HD)), qbd)
-        qbd = qbd.astype(compute_dtype)
+        diag = jnp.logical_and(col // qb == row % Rw, row < W * Rw)
+        if W == 1:
+            q_rows = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (R, HD))
+        else:
+            # rows <- their window row, and back: 0/1 matmuls (exact)
+            mine = (jax.lax.broadcasted_iota(jnp.int32, (R, W), 0) // Rw
+                    == jax.lax.broadcasted_iota(jnp.int32, (R, W), 1))
+            mine_t = (jax.lax.broadcasted_iota(jnp.int32, (W, R), 1) // Rw
+                      == jax.lax.broadcasted_iota(jnp.int32, (W, R), 0))
+            q_rows = jax.lax.dot_general(
+                mine.astype(compute_dtype), q_ref[0],
+                (((1,), (0,)), ((), ())), precision=exact,
+                preferred_element_type=jnp.float32)
+        qbd = jnp.where(diag, q_rows, 0.0).astype(compute_dtype)  # (R, HD)
         if per_head > 1:
             same_head = (
                 jax.lax.broadcasted_iota(jnp.int32, (R, R), 0) // per_head
                 == jax.lax.broadcasted_iota(jnp.int32, (R, R), 1) // per_head
             ).astype(jnp.float32)
-
-        def per_window(x):
-            """(Rw, >= chunk) per-token rows -> (R, chunk): one copy per
-            window token (Rw is a whole number of sublane tiles)."""
-            x = x[:, :Tc]
-            return x if W == 1 else jnp.concatenate([x] * W, axis=0)
+        # the window TOKEN of a row (a pad row: the last, so that it masks
+        # what the real rows mask)
+        w_tok = jnp.minimum(
+            jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+            // (Rw * q_per_kv), n_tok - 1)
 
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        def body(c, carry):
+        def land(c, whole):
+            """Chunk c's buffer, once the fetch two ring positions ahead
+            has started and its own, at position ``base + c``, has landed:
+            a whole chunk's G copies a leaf in ONE wait for their bytes
+            together."""
             p = base + c
             start_at(p + 2)
             slot = jax.lax.rem(p, _N_BUF)
-            each_copy(b, c, slot, lambda cp: cp.wait())
-            if window is not None:
-                c = c + first_unit(b, Tc)     # positions are the stream's
+            if whole:
+                pltpu.make_async_copy(k_hbm.at[lay, pl.ds(0, G)],
+                                      kbuf.at[slot], sem.at[slot, 0]).wait()
+                pltpu.make_async_copy(v_hbm.at[lay, pl.ds(0, G)],
+                                      vbuf.at[slot], sem.at[slot, 1]).wait()
+                if quantized:
+                    for cp in scale_copies(b, c, slot):
+                        cp.wait()
+            else:
+                each_copy(b, c, slot, lambda cp: cp.wait())
+            return slot
+
+        def per_window(x, width):
+            """(Rw, >= width) per-token rows -> (R, width): one copy per
+            window row (int8: Rw is a whole number of sublane tiles)."""
+            x = x[:, :width]
+            return x if W == 1 else jnp.concatenate([x] * W, axis=0)
+
+        def attend(c, slot, width, whole):
+            """The first ``width`` positions of the row's c-th chunk into
+            the running softmax, in one update.  ``whole``: every block was
+            fetched and every position is live for the window's first
+            token."""
+            c = c + first_unit(b, Tc)         # positions are the stream's
             if quantized:
-                for g in range(G):
-                    rows = pl.ds(g * bs, bs)
+                def stage(g, carry):
+                    rows = pl.ds(pl.multiple_of(g * bs, bs), bs)
                     kst[rows, :] = kbuf[slot, g].astype(jnp.float32).astype(
                         compute_dtype)
                     vst[rows, :] = vbuf[slot, g].astype(jnp.float32).astype(
                         compute_dtype)
-                k, v = kst[...], vst[...]
+                    return carry
+
+                jax.lax.fori_loop(0, width // bs, stage, 0)
+                k, v = kst[:width], vst[:width]
             else:
-                k = kbuf[slot].astype(compute_dtype)
-                v = vbuf[slot].astype(compute_dtype)
-            # a block that was not fetched leaves what the buffer held, and
-            # 0 x NaN is NaN in P @ V: dead positions' values are zeros
-            # (their keys are covered by the where on the scores)
-            v_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (Tc, HD), 0)
-            v_live = v_pos < length + n_tok
-            if window is not None:
-                v_live = jnp.logical_and(v_live, v_pos > length - window)
-            v = jnp.where(v_live, v, jnp.zeros_like(v))
+                k = kbuf[slot, :width // bs].reshape(width, HD).astype(
+                    compute_dtype)
+                v = vbuf[slot, :width // bs].reshape(width, HD).astype(
+                    compute_dtype)
+            if not whole:
+                # a block that was not fetched leaves what the buffer held,
+                # and 0 x NaN is NaN in P @ V: dead positions' values are
+                # zeros (their keys are covered by the where on the scores)
+                v_pos = c * Tc + jax.lax.broadcasted_iota(
+                    jnp.int32, (width, HD), 0)
+                v_live = v_pos < length + n_tok
+                if window is not None:
+                    v_live = jnp.logical_and(v_live, v_pos > length - window)
+                v = jnp.where(v_live, v, jnp.zeros_like(v))
             s = jax.lax.dot_general(
                 qbd, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)           # (R, Tc)
+                preferred_element_type=jnp.float32)           # (R, width)
             if quantized:
-                s = s * per_window(ksbuf[slot])
+                s = s * per_window(ksbuf[slot], width)
             if per_head > 1:
                 s = jax.lax.dot_general(
                     same_head, s, (((1,), (0,)), ((), ())),
                     precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)
             s = s * sm_scale
-            k_pos = c * Tc + jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 1)
-            w_pos = jax.lax.broadcasted_iota(jnp.int32, (R, Tc), 0) // (
-                Rw * q_per_kv)
-            if window is None:
-                last = jnp.minimum(length + w_pos, nb_max * bs - 1)
-                s = jnp.where(k_pos <= last, s, NEG_INF)
-            else:
-                s = jnp.where(jnp.logical_and(k_pos <= length + w_pos,
-                                              k_pos > length + w_pos - window),
-                              s, NEG_INF)
+            if not whole or n_tok > 1:
+                k_pos = c * Tc + jax.lax.broadcasted_iota(
+                    jnp.int32, (R, width), 1)
+                if window is None:
+                    last = jnp.minimum(length + w_tok, nb_max * bs - 1)
+                    s = jnp.where(k_pos <= last, s, NEG_INF)
+                else:
+                    s = jnp.where(jnp.logical_and(
+                        k_pos <= length + w_tok,
+                        k_pos > length + w_tok - window), s, NEG_INF)
             m_prev = m_ref[:]                                 # (R, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)                            # (R, Tc) fp32
+            p = jnp.exp(s - m_new)                            # (R, width) fp32
             l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
             if quantized:
-                p = p * per_window(vsbuf[slot])
+                p = p * per_window(vsbuf[slot], width)
             acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
                 p.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_ref[:] = m_new
+
+        def whole_chunk(c, carry):
+            attend(c, land(c, True), Tc, True)
             return carry
 
-        jax.lax.fori_loop(0, n_live, body, 0)
+        if window is None:
+            jax.lax.fori_loop(0, n_live - 1, whole_chunk, 0)
+        else:
+            # the first chunk holds the positions the window has slid past
+            pl.when(n_live > 1)(
+                lambda: attend(0, land(0, False), Tc, False))
+            jax.lax.fori_loop(1, n_live - 1, whole_chunk, 0)
+        # the last chunk, at the narrowest width that covers its live tokens
+        c = n_live - 1
+        slot = land(c, False)
+        live_end = length + n_tok
+        if window is None:
+            live_end = jnp.minimum(live_end, nb_max * bs)
+        tail = live_end - (c + first_unit(b, Tc)) * Tc        # 1 .. Tc
+        if len(tail_widths) == 1:
+            attend(c, slot, Tc, False)
+        else:
+            for lo, width in zip((0,) + tail_widths, tail_widths):
+                pl.when(jnp.logical_and(tail > lo, tail <= width))(
+                    functools.partial(attend, c, slot, width, False))
 
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)                 # never 0: k_pos
         heads = jnp.where(diag, acc_ref[:] / l_safe, 0.0)    # 0 always live
-        out_row = jax.lax.broadcasted_iota(jnp.int32, (W, HD), 0)
-        out = jnp.zeros((W, HD), jnp.float32)
-        for w in range(W):
-            tok = jnp.sum(heads[w * Rw:(w + 1) * Rw], axis=0, keepdims=True)
-            out = jnp.where(out_row == w, jnp.broadcast_to(tok, (W, HD)), out)
+        if W == 1:
+            out = jnp.sum(heads, axis=0, keepdims=True)
+        else:
+            # one row has a value in any column: the sum is a selection,
+            # exact in the output's own precision
+            out = jax.lax.dot_general(
+                mine_t.astype(o_ref.dtype), heads.astype(o_ref.dtype),
+                (((1,), (0,)), ((), ())), precision=exact,
+                preferred_element_type=jnp.float32)           # (W, HD)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -542,7 +635,7 @@ def _scale_rows(scale, layer, tables, n_rows, n_cols):
 def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
                  interpret, q_per_kv=1, window=None, name="paged_attention"):
     B, W, H, hd = q.shape
-    bs, HD = pool["k"].shape[2:]
+    n_blocks, bs, HD = pool["k"].shape[1:]
     if HD != H * hd:
         # grouped / multi-query: (B, W, Hkv, G, hd) -> (B, W * G, Hkv * hd),
         # the G query heads of a KV head as extra window rows (the kernel
@@ -557,15 +650,18 @@ def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
         return out.reshape(B, W, H * hd)
     nb_max = tables.shape[1]
     quantized = "k_scale" in pool
-    # chunk: the fewest blocks that make whole 128-lane score columns —
-    # or the whole table, when it is shorter than that
-    G = min(int(np.lcm(bs, 128)) // bs, nb_max)
-    Tc = G * bs
     qb = HD // pool["k_scale"].shape[-1] if quantized else hd
-    # row stride of one window token in the kernel's (R, ·) tiles: one row
-    # per quantization block, padded to whole fp32 sublane tiles and heads
-    Rw = _round_up(HD // qb, 8 * (hd // qb))
-    R = W * Rw
+    # row stride of one window row in the kernel's (R, .) tiles: one row per
+    # head, packed; an int8 pool's one per quantization block, padded to
+    # whole fp32 sublane tiles and heads as its scale tiles are
+    Rw = _round_up(HD // qb, 8 * (hd // qb)) if quantized else H
+    Tc, R = score_tile(HD * pool["k"].dtype.itemsize, bs, nb_max, W * Rw)
+    G = min(Tc // bs, n_blocks)
+    Tc = G * bs
+    # the widths a last chunk's products may take: whole blocks AND whole
+    # tail tiles, and the chunk itself
+    step = int(np.lcm(bs, _TAIL_TOKENS))
+    tail_widths = tuple(range(step, Tc, step)) + (Tc,)
 
     in_specs = [
         pl.BlockSpec((1, W, HD), lambda b, *s: (b, 0, 0)),
@@ -573,24 +669,21 @@ def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
         pl.BlockSpec(memory_space=pl.ANY),         # v pool stays in HBM
     ]
     args = [q.reshape(B, W, HD), pool["k"], pool["v"]]
+    scratch = [
+        pltpu.VMEM((_N_BUF, G, bs, HD), pool["k"].dtype),
+        pltpu.VMEM((_N_BUF, G, bs, HD), pool["v"].dtype),
+    ]
     if quantized:
         Tcs = _round_up(Tc, 128)                   # scale columns per DMA
         n_cols = (-(-nb_max // G) - 1) * Tc + Tcs
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         args += [_scale_rows(pool[n], layer_arr[0], tables, Rw, n_cols)
                  for n in ("k_scale", "v_scale")]
-        scratch = [
-            pltpu.VMEM((_N_BUF, G, bs, HD), jnp.int8),
-            pltpu.VMEM((_N_BUF, G, bs, HD), jnp.int8),
+        scratch += [
             pltpu.VMEM((_N_BUF, Rw, Tcs), jnp.float32),
             pltpu.VMEM((_N_BUF, Rw, Tcs), jnp.float32),
             pltpu.VMEM((Tc, HD), q.dtype),         # k chunk, compute dtype
             pltpu.VMEM((Tc, HD), q.dtype),         # v chunk, compute dtype
-        ]
-    else:
-        scratch = [
-            pltpu.VMEM((_N_BUF, Tc, HD), pool["k"].dtype),
-            pltpu.VMEM((_N_BUF, Tc, HD), pool["v"].dtype),
         ]
     scratch += [
         pltpu.VMEM((R, 1), jnp.float32),           # m (running max)
@@ -609,7 +702,7 @@ def _online_call(q, pool, tables, lengths, layer_arr, *, scale_attn,
         _online_kernel, block_size=bs, nb_max=nb_max, head_dim=hd,
         scale_attn=scale_attn, compute_dtype=q.dtype,
         quant_block=qb if quantized else None, group=G, rows_per_token=Rw,
-        q_per_kv=q_per_kv, window=window)
+        tail_widths=tail_widths, q_per_kv=q_per_kv, window=window)
     cp = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,

@@ -23,13 +23,17 @@ boundary, single token), multi-token windows (no caller in the product
 since PR 45, kept honest here for chunked prefill: ROADMAP D17), and the write_tokens overflow-to-scratch guard.
 
 The online kernel's WALK (PR 35: dead rows do nothing and come last, one
-DMA ring over the whole call, the last chunk fetches its live blocks only)
-has its own cases below, over a table long enough for three chunks and a
+DMA ring over the whole call, the last chunk fetches its live blocks only;
+PR 49: a chunk sized by the pool's bytes, its last products over the live
+tail tiles, grouped-query rows packed) has its own cases below, over a table
+of 320 positions (one chunk with tail tiles of 128 at the file's own
+constants, two or three chunks where a test shrinks ``_CHUNK_BYTES``) and a
 pool whose unreferenced blocks hold NaN: the interpreter fills VMEM scratch
 with NaN too, so a block that was not fetched and still reached the MXU
 fails them.
 """
 
+import importlib
 import re
 
 import numpy as np
@@ -40,6 +44,10 @@ import jax.numpy as jnp
 from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
 from deepspeed_tpu.inference import paged_kv as pk
 from deepspeed_tpu.ops.transformer.paged_attention import paged_attention
+
+# the package re-exports the function under the module's name
+pa_module = importlib.import_module(
+    "deepspeed_tpu.ops.transformer.paged_attention")
 
 BS, NB_MAX, NB, L, H, HD = 8, 4, 16, 2, 4, 16
 
@@ -148,12 +156,18 @@ def test_online_mode_within_compute_dtype_rounding(n_window, devices):
 
 
 # ------------------------------------------------- the online kernel's walk
-# blocks of 16 make chunks of 8 blocks = 128 positions; a table of 20 is
-# three chunks, the third half past the table's end
-WALK_BS, WALK_NB_MAX, WALK_CHUNK = 16, 20, 128
+# blocks of 16 and a table of 20: 320 positions.  These pools are 16 to 64
+# bytes a token wide, so the file's own ``_CHUNK_BYTES`` makes the table ONE
+# chunk whose products run over tail tiles of 128, 256 or all 320 positions;
+# ``_chunks_of`` shrinks it to chunks of 128 (three, the third half past the
+# table's end: the walk before PR 49) or 256 (two, a tail tile inside each)
+WALK_BS, WALK_NB_MAX = 16, 20
 #          name: (query heads, K/V heads, window, kv_bits)
 WALK_POOLS = {"bf16": (2, 2, 1, 16), "int8": (2, 2, 1, 8),
-              "mqa20": (20, 1, 1, 16), "window3": (2, 2, 3, 16)}
+              "mqa20": (20, 1, 1, 16), "window3": (2, 2, 3, 16),
+              # Nemotron-3's grouping: 16 query heads a K/V head, 32 packed
+              # score rows (128 of which 32 were real before PR 49)
+              "gqa32": (32, 2, 1, 16)}
 # a row's length: the position of its first window token; None: a dead row
 # (its whole table names the scratch block, its length 0)
 WALK_ROWS = {
@@ -168,7 +182,16 @@ WALK_ROWS = {
     "handover": [127, 0, 3, 255, 127, None, 319 - 2, 1],
     # every row seated and every chunk whole: the walk before PR 35
     "full_rows": [127, 255, 127, 255],
+    # one before, on and after a tail tile's edge (128) and a 256-token
+    # chunk's, and the table's end, live and dead rows mixed
+    "chunk_edges": [127, None, 128, 129, 255, None, 256, 257, None, 317],
 }
+
+
+def _chunks_of(monkeypatch, tokens, kv_heads, kv_bits):
+    """Make the online walk's chunk ``tokens`` positions of this pool."""
+    monkeypatch.setattr(pa_module, "_CHUNK_BYTES",
+                        tokens * kv_heads * HD * kv_bits // 8)
 
 
 def _walk_case(rows, heads, kv_heads, window, kv_bits):
@@ -219,17 +242,12 @@ def _dense_oracle(q, pool, tables, lengths, kv_heads):
     return out
 
 
-@pytest.mark.parametrize("pool_kind", list(WALK_POOLS))
-@pytest.mark.parametrize("rows", list(WALK_ROWS))
-def test_online_walk_live_rows_match_dead_rows_are_zero(rows, pool_kind,
-                                                        devices):
-    """Live rows equal the gather oracle within the online mode's
-    tolerance wherever they stand in the batch and wherever their length
-    ends; dead rows are exactly zero; nothing is NaN though every block
-    the walk must not fetch is."""
+def _check_walk(rows, pool_kind):
+    """Live rows equal the float64 oracle within the online mode's
+    tolerance, dead rows are exactly zero, nothing is NaN."""
     heads, kv_heads, window, kv_bits = WALK_POOLS[pool_kind]
-    pool, tables, lengths, q = _walk_case(WALK_ROWS[rows], heads, kv_heads,
-                                          window, kv_bits)
+    pool, tables, lengths, q = _walk_case(rows, heads, kv_heads, window,
+                                          kv_bits)
     out = np.asarray(jax.jit(
         lambda q, p: paged_attention(q, p, tables, lengths, 0,
                                      mode="online"))(q, pool), np.float32)
@@ -240,6 +258,76 @@ def test_online_walk_live_rows_match_dead_rows_are_zero(rows, pool_kind,
         ref = _dense_oracle(q, pool, tables, lengths, kv_heads)
         tol = (2e-2 if kv_bits == 8 else 1e-2) * np.abs(ref[live]).max()
         assert np.abs(out - ref)[live].max() < tol
+
+
+@pytest.mark.parametrize("pool_kind", list(WALK_POOLS))
+@pytest.mark.parametrize("rows", list(WALK_ROWS))
+def test_online_walk_live_rows_match_dead_rows_are_zero(rows, pool_kind,
+                                                        devices):
+    """Live rows equal the gather oracle within the online mode's
+    tolerance wherever they stand in the batch and wherever their length
+    ends; dead rows are exactly zero; nothing is NaN though every block
+    the walk must not fetch is."""
+    _check_walk(WALK_ROWS[rows], pool_kind)
+
+
+@pytest.mark.parametrize("pool_kind", list(WALK_POOLS))
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_online_walk_in_several_chunks(chunk, pool_kind, devices,
+                                       monkeypatch):
+    """The same walk with the table cut into three chunks of 128 positions
+    (one tail width: the walk before PR 49) and into two of 256 (a tail tile
+    of 128 inside each): whole chunks land under one wait and run unmasked,
+    the last chunk's products cover its live tail tiles only, a row hands the
+    ring over wherever it ends."""
+    _, kv_heads, _, kv_bits = WALK_POOLS[pool_kind]
+    _chunks_of(monkeypatch, chunk, kv_heads, kv_bits)
+    assert pa_module.score_tile(kv_heads * HD * kv_bits // 8, WALK_BS,
+                                WALK_NB_MAX, 8)[0] == chunk
+    _check_walk(WALK_ROWS["chunk_edges"] + WALK_ROWS["handover"], pool_kind)
+
+
+# what `_online_call` reads of its input -> the (Tc, R) score tile.
+# id: (K/V heads x head dim, bytes an element, block, nb_max, query heads,
+#      Tc, R); "as before PR 49" where the tile is the one every pool had
+SCORE_TILES = {
+    # 2,048 lanes, multi-head: 128 tokens are 512 KB of K; as before PR 49
+    "cerebras-gpt-1.3b": (16 * 128, 2, 16, 128, 16, 128, 16),
+    "ouro-2.6b": (16 * 128, 2, 64, 320, 16, 128, 16),
+    # 1,024 and 1,280 lanes: 256 tokens (128 before PR 49); Trinity's 6
+    # query heads a K/V head are 48 rows as before, Phi-4's 4 over 10 K/V
+    # heads 40 packed rows where 4 window rows padded to 16 made 64
+    "trinity-global": (8 * 128, 2, 64, 272, 48, 256, 48),
+    "trinity-window": (8 * 128, 2, 64, 65, 48, 256, 48),
+    "phi4-shared": (10 * 128, 2, 64, 128, 40, 256, 40),
+    "phi4-window": (10 * 128, 2, 64, 9, 40, 256, 40),
+    "gpt2-large": (20 * 64, 2, 16, 64, 20, 256, 24),
+    # 256 lanes: 1,024 tokens are 512 KB of K; 32 rows, all real (128 of
+    # which 32 were)
+    "nemotron-3-nano": (2 * 128, 2, 64, 64, 32, 1024, 32),
+    # 128 lanes: the whole table of 2,048; 20 rows in 24 (160 of which 20
+    # were)
+    "jamba2-3b": (1 * 128, 2, 16, 128, 20, 2048, 24),
+    # never more than the table
+    "short-table": (1 * 128, 2, 16, 20, 20, 320, 24),
+    # an int8 pool: half the bytes a lane, so twice the tokens
+    "int8-2048": (16 * 128, 1, 16, 128, 32, 256, 32),
+    "int8-256": (2 * 128, 1, 64, 64, 8, 2048, 8),
+    # never so wide that (R, Tc) float32 scores pass 512 KB
+    "many-rows": (1 * 128, 2, 16, 512, 256, 512, 256),
+}
+
+
+@pytest.mark.parametrize("case", list(SCORE_TILES))
+def test_score_tile_follows_the_pool(case):
+    """``score_tile`` is the one place the walk's tile is chosen: a chunk by
+    its bytes (``_CHUNK_BYTES`` of K rows, in whole blocks and whole
+    128-lane score columns), the rows packed and rounded up once."""
+    lanes, itemsize, block, nb_max, rows, chunk, padded = SCORE_TILES[case]
+    assert pa_module.score_tile(lanes * itemsize, block, nb_max,
+                                rows) == (chunk, padded)
+    assert chunk % block == 0 and (chunk % 128 == 0
+                                   or chunk == nb_max * block)
 
 
 def test_decode_step_kernel_vs_gather_impl(devices):
@@ -347,23 +435,29 @@ def v5e():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.mark.parametrize("slots,heads,kv_heads,head_dim,positions,kv_bits", [
-    (16, 16, 16, 128, 2048, 16),      # cerebras-gpt-1.3b, ouro-2.6b
-    (16, 20, 20, 64, 1024, 16),       # gpt2-large
-    (64, 20, 1, 128, 2048, 16),       # jamba2-3b: 20 query heads, one K/V
-    (16, 16, 16, 128, 2048, 8),       # an int8 pool and its scale rows
-], ids=["cerebras-gpt-1.3b", "gpt2-large", "jamba2-3b", "int8-pool"])
+@pytest.mark.parametrize(
+    "slots,heads,kv_heads,head_dim,positions,kv_bits,block", [
+        (16, 16, 16, 128, 2048, 16, 16),  # cerebras-gpt-1.3b, ouro-2.6b
+        (16, 20, 20, 64, 1024, 16, 16),   # gpt2-large
+        (64, 20, 1, 128, 2048, 16, 16),   # jamba2-3b: 20 query heads, one
+        #                                   K/V: chunks of 1,024 tokens
+        (16, 16, 16, 128, 2048, 8, 16),   # an int8 pool and its scale rows
+        (256, 32, 2, 128, 4096, 16, 64),  # nemotron-3-nano: chunks of 512
+        (96, 48, 8, 128, 17408, 16, 64),  # trinity's global layers
+    ], ids=["cerebras-gpt-1.3b", "gpt2-large", "jamba2-3b", "int8-pool",
+            "nemotron-3-nano", "trinity-global"])
 def test_online_kernel_compiles_for_a_v5e(v5e, slots, heads, kv_heads,
-                                          head_dim, positions, kv_bits):
+                                          head_dim, positions, kv_bits,
+                                          block):
     """The online kernel at the served shapes, through Mosaic and XLA:TPU
     for a described v5e: one custom call, and no copy of the pool (the
     kernel reads blocks where they lie)."""
     from jax.sharding import SingleDeviceSharding
     one_chip = SingleDeviceSharding(v5e.devices[0])
-    block, layers = 16, 2
+    layers = 2
     nb_max = positions // block
     width = kv_heads * head_dim
-    rows = (layers, slots * nb_max + 1, block)
+    rows = (layers, min(slots * nb_max, 8192) + 1, block)
     pool = {n: (rows + (width,), jnp.bfloat16 if kv_bits == 16 else jnp.int8)
             for n in ("k", "v")}
     if kv_bits == 8:
@@ -529,15 +623,9 @@ def _ring_case(rows, heads, kv_heads, bs, window):
     return pool, tables, lengths, jnp.asarray(q, jnp.bfloat16), ref
 
 
-@pytest.mark.parametrize("mode", ["online", "exact"])
-@pytest.mark.parametrize("heads, kv_heads", [(2, 2), (6, 1)])
-@pytest.mark.parametrize("case", list(RING_CASES))
-def test_window_walk_over_a_ring(case, heads, kv_heads, mode, devices):
-    """A window layer's decode attention over a ring table: the walk starts
-    at the chunk of the first live position, looks blocks up at their ring
-    entries, masks the first block's dead positions; live rows equal the
-    oracle over the last ``window`` positions, dead rows are zero, nothing
-    is NaN though every block no ring names is."""
+def _check_ring(case, heads, kv_heads, mode):
+    """Live rows equal the oracle over the last ``window`` positions, dead
+    rows are zero (online), nothing is NaN."""
     bs, window, rows = RING_CASES[case]
     pool, tables, lengths, q, ref = _ring_case(rows, heads, kv_heads, bs,
                                                window)
@@ -549,6 +637,30 @@ def test_window_walk_over_a_ring(case, heads, kv_heads, mode, devices):
     if mode == "online":
         assert not out[~live].any()
     assert np.abs(out - ref)[live].max() < 1e-2 * np.abs(ref[live]).max()
+
+
+@pytest.mark.parametrize("mode", ["online", "exact"])
+@pytest.mark.parametrize("heads, kv_heads", [(2, 2), (6, 1)])
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_window_walk_over_a_ring(case, heads, kv_heads, mode, devices):
+    """A window layer's decode attention over a ring table: the walk starts
+    at the chunk of the first live position, looks blocks up at their ring
+    entries, masks the first block's dead positions; live rows equal the
+    oracle over the last ``window`` positions, dead rows are zero, nothing
+    is NaN though every block no ring names is."""
+    _check_ring(case, heads, kv_heads, mode)
+
+
+@pytest.mark.parametrize("heads, kv_heads", [(2, 2), (6, 1)])
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_window_walk_in_chunks_of_128(case, heads, kv_heads, devices,
+                                      monkeypatch):
+    """The ring walk with the chunk a wide pool takes (128 positions: ring14
+    is two chunks, the second part past the ring, ring65 thirty-three, the
+    walk starting at the chunk of the first live position); at the file's
+    own constants these narrow rings are one chunk (two for ring65)."""
+    _chunks_of(monkeypatch, 128, kv_heads, 16)
+    _check_ring(case, heads, kv_heads, "online")
 
 
 def test_a_ring_too_short_for_its_window_is_refused(devices):
