@@ -5,7 +5,10 @@ global attention layers, gated grouped-query attention, sigmoid routing),
 Nemotron-H (Mamba-2 mixers, attention layers and non-gated expert layers, one
 of them a layer), Phi4Flash (a decoder-hybrid-decoder: Mamba-1 and window
 layers, one full layer whose K/V the cross-attention layers read again, Gated
-Memory Units, differential attention)."""
+Memory Units, differential attention), LongCat-Flash (a shortcut-connected
+double block: two latent-attention sub-layers, two dense FFNs and one expert
+layer that joins late; a router wider than its experts, the rest identity
+experts)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -45,6 +48,9 @@ def build(name, **overrides):
         if name.startswith("phi4flash"):
             from .phi4flash import Phi4Flash
             return Phi4Flash(preset=name, **overrides)
+        if name.startswith("longcat-flash"):
+            from .longcat_flash import LongcatFlash
+            return LongcatFlash(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

@@ -24,7 +24,12 @@ from ``c_kv`` at widths ``n + r`` and ``v_head_dim``, blocked over heads so
 that no (H, T, T) score tensor stands whole; one decoded token runs
 ABSORBED, ``q_lat[h] = q_nope[h] W_UK[h]`` meeting the cached rows directly
 (``ops/transformer/paged_latent_attention.py``) and ``W_UV[h]`` applied to the
-(C,)-wide result.
+(C,)-wide result.  The attention's arithmetic (the projection, both forms,
+the seat into a dense cache or the paged pool) is ``models/mla.py``'s
+``LatentAttention``, the ONE copy, which ``models/longcat_flash.py`` calls
+too; what is this family's is an argument to it: YaRN's rope table, the
+softmax scale with ``m^2`` (``_sm_scale``), and no scale on the normed
+latents.
 
 ONE CHIP'S SHARE of an expert-parallel deployment: ``experts_held = (first,
 count)`` says which routed experts this chip holds.  The router keeps its
@@ -69,13 +74,10 @@ import jax
 import jax.numpy as jnp
 
 from ..moe import dropless
+from . import mla
 from .gpt2 import GPT2, layer_slice as _take
 from .jamba import _mm, _rms, swiglu
-from .ouro import _mmt
-from .rotary import (apply_rotary_pos_emb, rotary_freqs, yarn_inv_freq,
-                     yarn_mscale)
-
-_HEAD_BLOCK = 16      # heads whose (T, T) scores stand at once in a prompt
+from .rotary import rotary_freqs, yarn_inv_freq, yarn_mscale
 
 
 @dataclasses.dataclass
@@ -229,7 +231,11 @@ class DeepseekV2:
             table = yarn_mscale(scaling["factor"],
                                 scaling.get("mscale", 1)) / m
         cos, sin = rotary_freqs(r, c.max_seq, base=c.rope_theta, inv_freq=inv)
-        self._rope = (cos * table, sin * table)
+        # the attention itself is models/mla.py's, the one copy; YaRN's
+        # table and the softmax scale (with m squared) are this family's
+        self._mla = mla.LatentAttention(
+            n_head=c.n_head, kv_lora_rank=c.kv_lora_rank, eps=c.rms_norm_eps,
+            rope=(cos * table, sin * table))
         self._sm_scale = float(c.head_dim ** -0.5 * m * m)
 
     # ------------------------------------------------------------------ init
@@ -294,80 +300,6 @@ class DeepseekV2:
                 + c.n_moe_layer * moe + 2 * c.vocab_rows[1] * D + D)
 
     # ---------------------------------------------------------------- pieces
-    def _project(self, p, a, positions):
-        """The normed input ``a`` (B, T, D) -> ``(q_nope (B, T, H, n), q_pe
-        (B, T, H, r) rotated, c_kv (B, T, C) normed, k_pe (B, T, r)
-        rotated)`` in the model dtype: everything either form of the
-        attention needs, and the last two are what a token caches."""
-        c = self.config
-        eps = c.rms_norm_eps
-        cos, sin = self._rope
-        c_q = _rms(_mm(a, p["q_a_w"]), p["q_norm"], eps)
-        heads = lambda x: x.reshape(a.shape[:-1] + (c.n_head, -1))
-        kv = _mm(a, p["kv_a_w"])
-        c_kv = _rms(kv[..., :c.kv_lora_rank], p["kv_norm"], eps)
-        q_pe = apply_rotary_pos_emb(heads(_mmt(c_q, p["q_pe_w"])), cos, sin,
-                                    positions)
-        k_pe = apply_rotary_pos_emb(kv[..., None, c.kv_lora_rank:], cos, sin,
-                                    positions)[..., 0, :]
-        return heads(_mmt(c_q, p["q_nope_w"])), q_pe, c_kv, k_pe
-
-    def _attend_expanded(self, p, q_nope, q_pe, c_kv, k_pe, valid):
-        """Queries (B, T, H, n | r) over the rows ``c_kv`` (B, S, C) and
-        ``k_pe`` (B, S, r), keys and values rebuilt from the rows;
-        ``valid`` broadcasts to (B, heads, T, S).  ``_HEAD_BLOCK`` heads at a
-        time: 128 heads x 2,560^2 float32 scores are 3.4 GB.  Returns (B, T,
-        H v)."""
-        with jax.named_scope("mla.attend"):
-            B, T, H, n = q_nope.shape
-            hb = min(H, _HEAD_BLOCK)
-            dt = q_nope.dtype
-            groups = lambda x: jnp.moveaxis(
-                x.reshape(x.shape[:2] + (H // hb, hb, x.shape[-1])), 2, 0)
-
-            def block(xs):
-                qn, qp, k_up, v_up = xs
-                k = jnp.einsum("bsc,hcn->bshn", c_kv, k_up.astype(dt))
-                v = jnp.einsum("bsc,hvc->bshv", c_kv, v_up.astype(dt))
-                s = (jnp.einsum("bthn,bshn->bhts", qn, k)
-                     + jnp.einsum("bthr,bsr->bhts", qp, k_pe)
-                     ).astype(jnp.float32) * self._sm_scale
-                s = jnp.where(valid, s, jnp.finfo(jnp.float32).min)
-                w = jax.nn.softmax(s, axis=-1).astype(dt)
-                return jnp.einsum("bhts,bshv->bthv", w, v)
-
-            split = lambda w: w.reshape((H // hb, hb) + w.shape[1:])
-            out = jax.lax.map(block, (groups(q_nope), groups(q_pe),
-                                      split(p["k_up_w"]), split(p["v_up_w"])))
-            return jnp.moveaxis(out, 0, 2).reshape(B, T, -1)
-
-    def _absorb(self, p, q_nope, q_pe, width):
-        """One token's queries (B, H, n | r) as rows of the cache's layout:
-        ``[q_nope W_UK | q_pe | 0]`` (B, H, width)."""
-        with jax.named_scope("mla.absorb"):
-            from ..inference import paged_kv as pk
-            q_lat = jnp.einsum("bhn,hcn->bhc", q_nope,
-                               p["k_up_w"].astype(q_nope.dtype))
-            return pk.latent_rows(q_lat, q_pe, width)
-
-    def _attend_absorbed(self, p, q_rows, rows, valid):
-        """``jax.numpy``'s absorbed attention of ``q_rows`` (B, H, width)
-        over gathered or dense ``rows`` (B, S, width), ``valid`` (B, S); what
-        the latent kernel computes in place.  Returns ``o_lat`` (B, H, C)."""
-        C = self.config.kv_lora_rank
-        s = jnp.einsum("bhw,bsw->bhs", q_rows, rows).astype(jnp.float32)
-        s = jnp.where(valid[:, None, :], s * self._sm_scale,
-                      jnp.finfo(jnp.float32).min)
-        w = jax.nn.softmax(s, axis=-1).astype(q_rows.dtype)
-        return jnp.einsum("bhs,bsc->bhc", w, rows[..., :C])
-
-    def _unabsorb(self, p, o_lat):
-        """``o_lat`` (B, H, C) -> (B, H v): ``W_UV`` by head."""
-        with jax.named_scope("mla.absorb"):
-            o = jnp.einsum("bhc,hvc->bhv", o_lat,
-                           p["v_up_w"].astype(o_lat.dtype))
-            return o.reshape(o.shape[0], -1)
-
     def _moe(self, pm, u, layer=None, live=None):
         """The expert layer's output for ``u`` (B, T, D), the normed stream
         in the model dtype: the held experts' part and the shared experts.
@@ -421,7 +353,8 @@ class DeepseekV2:
 
         def attention(p, h, l, carry):
             a = _rms(h, p["ln_in"], eps).astype(self.dtype)
-            out, carry = attn_fn(p, *self._project(p, a, positions), l, carry)
+            out, carry = attn_fn(p, *self._mla.project(p, a, positions), l,
+                                 carry)
             h = h + _mm(out, p["o_w"]).astype(f32)
             return h, _rms(h, p["ln_ff"], eps).astype(self.dtype), carry
 
@@ -475,7 +408,8 @@ class DeepseekV2:
         h, _, _, _ = self._layers(
             params, self._embed(params, tokens), (), jnp.arange(T),
             lambda p, qn, qp, ckv, kpe, l, carry: (
-                self._attend_expanded(p, qn, qp, ckv, kpe, causal), carry),
+                self._mla.attend_expanded(p, qn, qp, ckv, kpe, causal,
+                                          self._sm_scale), carry),
             sliced=True)
         if return_hidden:
             return _rms(h, params["lnf"], self.config.rms_norm_eps)
@@ -507,25 +441,12 @@ class DeepseekV2:
         """Forward ``tokens`` (B, T) from ``cache['index']``; returns
         ``(logits (B, T, Vh), new_cache)``.  A prompt (T > 1) attends
         expanded over the cached rows, one token absorbed."""
-        c = self.config
         T = tokens.shape[1]
         index = cache["index"]
-        S = cache["latent"].shape[2]
-        C = c.kv_lora_rank
-        valid = jnp.arange(S)[None, :] <= index + jnp.arange(T)[:, None]
 
         def attn_fn(p, qn, qp, ckv, kpe, l, lat):
-            new = jnp.concatenate([ckv, kpe], axis=-1).astype(lat.dtype)
-            lat = jax.lax.dynamic_update_slice(lat, new[None],
-                                               (l, 0, index, 0))
-            rows = lat[l].astype(self.dtype)
-            if T > 1:
-                return self._attend_expanded(p, qn, qp, rows[..., :C],
-                                             rows[..., C:], valid), lat
-            q_rows = self._absorb(p, qn[:, 0], qp[:, 0], rows.shape[-1])
-            o_lat = self._attend_absorbed(
-                p, q_rows, rows, jnp.broadcast_to(valid, (rows.shape[0], S)))
-            return self._unabsorb(p, o_lat)[:, None], lat
+            return self._mla.attend_cached(p, qn, qp, ckv, kpe, lat, l, index,
+                                           self._sm_scale, self.dtype)
 
         h, lat, _, _ = self._layers(params, self._embed(params, tokens),
                                  cache["latent"], index + jnp.arange(T),
@@ -571,18 +492,12 @@ class DeepseekV2:
         after token ``t_real - 1`` is routed like any token (nothing is
         dropped) and left out of the counters.  Returns ``(logits (1, Vh) at
         token t_real - 1, pool)``."""
-        from ..inference import paged_kv as pk
         T = toks.shape[1]
-        width = pool[pk.LATENT].shape[-1]
-        bucket = blocks.shape[0] * pool[pk.LATENT].shape[2]
         causal = jnp.tril(jnp.ones((T, T), bool))
 
         def attn_fn(p, qn, qp, ckv, kpe, l, pool):
-            with jax.named_scope("kv.seat"):
-                rows = jnp.pad(pk.latent_rows(ckv[0], kpe[0], width),
-                               ((0, bucket - T), (0, 0)))
-                pool = pk.write_latent_prefill(pool, blocks, rows, l)
-            return self._attend_expanded(p, qn, qp, ckv, kpe, causal), pool
+            return self._mla.attend_prefill(p, qn, qp, ckv, kpe, pool, blocks,
+                                            l, causal, self._sm_scale)
 
         h, pool, counts, _ = self._layers(
             params, self._embed(params, toks), pool, jnp.arange(T), attn_fn,
@@ -603,29 +518,15 @@ class DeepseekV2:
         same step's, so that a comparison with another precision can tell a
         token whose scores tied from one that was computed wrong."""
         from ..inference import paged_kv as pk
-        from ..ops.transformer.paged_latent_attention import (
-            paged_latent_attention)
         c = self.config
         assert toks.ndim == 1, "the latent kernel attends one token a slot"
         impl = self.paged_attention_impl()
-        width = pool[pk.LATENT].shape[-1]
         positions = jnp.minimum(lengths, c.max_seq - 1)[:, None]
 
         def attn_fn(p, qn, qp, ckv, kpe, l, pool):
-            pool = pk.write_latent_tokens(
-                pool, l, block_tables, lengths,
-                pk.latent_rows(ckv, kpe, width))
-            q_rows = self._absorb(p, qn[:, 0], qp[:, 0], width)
-            if impl == "kernel":
-                with jax.named_scope("mla.attend"):
-                    o_lat = paged_latent_attention(
-                        q_rows, pool, block_tables, lengths, l,
-                        value_width=c.kv_lora_rank, sm_scale=self._sm_scale)
-            else:
-                rows = pk.gather_latent(pool, l, block_tables, self.dtype)
-                valid = jnp.arange(rows.shape[1])[None, :] <= lengths[:, None]
-                o_lat = self._attend_absorbed(p, q_rows, rows, valid)
-            return self._unabsorb(p, o_lat)[:, None], pool
+            return self._mla.attend_decode(
+                p, qn, qp, ckv, kpe, pool, block_tables, lengths, l,
+                self._sm_scale, impl, self.dtype)
 
         h, pool, counts, routes = self._layers(
             params, self._embed(params, toks)[:, None], pool, positions,

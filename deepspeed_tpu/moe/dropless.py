@@ -26,6 +26,15 @@ buffer and drops what overflows; it stays, for ``gpt2_moe``.  Here:
   that is the other chips' part of an expert-parallel layer, and nothing here
   stands in for them or for the exchange.  With every expert held
   (``first`` 0, ``count`` ``E``) it is the whole layer.
+- :func:`zero_experts` is the part of ZERO-COMPUTE experts: a router may be
+  wider than its real experts (``models/longcat_flash.py``: 768 outputs, 512
+  experts with matrices and 256 IDENTITY experts, ids 512..767), and a pair
+  that falls to an identity expert returns the token's own input times the
+  routing weight.  That part is the token's own: it needs no matrices and no
+  exchange, every chip computes it whole, and :func:`held_experts` never
+  sees it (an id past the real experts is in no group, like an absent
+  one's).  :func:`zero_pairs` counts such pairs apart from the pairs that
+  fell to another chip.
 """
 
 import collections
@@ -123,6 +132,29 @@ def route_counters(experts, first, count, live=None):
     return jnp.stack([
         n_held, k * live.sum() - n_held, touched, count - touched,
         (live & ~held.any(axis=1)).sum()]).astype(jnp.int32)
+
+
+def zero_experts(x, experts, weights, n_real):
+    """The identity experts' part of the routed output: ``x[n] * sum_i
+    weights[n, i]`` over the pairs whose id is ``n_real`` or more (the ids
+    past the experts that have matrices).  ``x`` (N, D) is what the REAL
+    experts read too (the normed tokens), not the residual stream.  Returns
+    (N, D) float32: it joins a float32 stream, and a weight of up to 6 on
+    the token's own input is no place to round."""
+    w = jnp.where(experts >= n_real, weights, 0.0).sum(axis=-1, keepdims=True)
+    return x.astype(jnp.float32) * w
+
+
+def zero_pairs(experts, n_real, live=None):
+    """How many token-expert pairs fell to a zero-compute expert (an id of
+    ``n_real`` or more): int32 scalar.  :func:`route_counters` counts them
+    among ``pairs_elsewhere`` (they are not held); a family with such
+    experts takes them out of it and reports them beside it.  ``live`` as
+    there."""
+    zero = experts >= n_real
+    if live is not None:
+        zero = zero & live[:, None]
+    return zero.sum().astype(jnp.int32)
 
 
 _LANES = 128            # a tile's dim is a multiple of this, or the whole dim

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import deepspeed_tpu as ds
 from deepspeed_tpu.analysis import capacity
 from deepspeed_tpu.inference import Request, ServingEngine, paged_kv as pk
-from deepspeed_tpu.models import build, deepseek_v2 as dsv2
+from deepspeed_tpu.models import build, deepseek_v2 as dsv2, mla
 from deepspeed_tpu.moe import dropless
 from benchmark.reference import deepseek_v2 as reference
 
@@ -437,9 +437,9 @@ def test_a_decode_step_reports_the_experts_it_routed_to(model_params):
 
 def test_k_pe_cached_before_rope_fails(model_params, monkeypatch):
     _, params = model_params
-    sound = dsv2.apply_rotary_pos_emb
+    sound = mla.apply_rotary_pos_emb
     monkeypatch.setattr(
-        dsv2, "apply_rotary_pos_emb",
+        mla, "apply_rotary_pos_emb",
         lambda x, *a, **k: x if x.shape[-2] == 1 else sound(x, *a, **k))
     assert decode_error(tiny(impl="gather"),
                         params) > 10 * TOL
@@ -447,9 +447,9 @@ def test_k_pe_cached_before_rope_fails(model_params, monkeypatch):
 
 def test_c_kv_cached_before_its_norm_fails(model_params, monkeypatch):
     _, params = model_params
-    sound = dsv2._rms
+    sound = mla._rms
     monkeypatch.setattr(
-        dsv2, "_rms", lambda x, w, eps: x if w.shape[-1] == 32 and
+        mla, "_rms", lambda x, w, eps: x if w.shape[-1] == 32 and
         x.shape[-1] == 32 else sound(x, w, eps))
     assert decode_error(tiny(impl="gather"),
                         params) > 10 * TOL

@@ -818,3 +818,76 @@ def test_the_capacity_gate_still_refuses_other_k_and_still_drops():
                                 jnp.swapaxes(w, 1, 2), 0)
     assert (np.abs(np.asarray(out)).max(axis=1) > 0).all()
     assert dropless.route_counters(experts, 0, 8).tolist() == [24, 0, 1, 7, 0]
+
+
+# ------------------------- a router wider than its experts (PR 50: LongCat)
+# ids past the real experts are ZERO-COMPUTE (identity) experts: in no group
+# of ``held_experts``, their part the token's own input times the weight.
+@pytest.mark.parametrize("case", ["ids_past_the_real_fall_in_no_group",
+                                  "the_identity_part",
+                                  "top12_of_768_with_a_bias"])
+def test_dropless_zero_compute_experts(case):
+    rng = jax.random.split(jax.random.PRNGKey(11), 6)
+    if case == "top12_of_768_with_a_bias":
+        # 512 real and 256 identity outputs, logits of spread 2, a bias of
+        # the configuration's width: the pick is top-12 of scores + bias,
+        # the weight the plain score times the factor
+        N, W, k = 300, 768, 12
+        logits = jax.random.normal(rng[0], (N, W)) * 2
+        bias = jax.random.normal(rng[1], (W,)) * 3e-4
+        experts, weights = dropless.route(
+            logits, k, routed_scaling_factor=6.0, bias=bias)
+        scores = np.asarray(jax.nn.softmax(logits, -1), np.float64)
+        want = np.argsort(-(scores + np.asarray(bias, np.float64)),
+                          axis=1, kind="stable")[:, :k]
+        np.testing.assert_array_equal(np.sort(np.asarray(experts), 1),
+                                      np.sort(want, 1))
+        np.testing.assert_allclose(
+            np.asarray(weights),
+            6.0 * np.take_along_axis(scores, np.asarray(experts), 1),
+            rtol=1e-5)
+        plain, _ = dropless.route(logits, k, routed_scaling_factor=6.0)
+        moved = np.mean([set(a) != set(b) for a, b in
+                         zip(np.asarray(experts), np.asarray(plain))])
+        assert 0.08 < moved < 0.35, moved      # the file says 18 %
+        share = float((np.asarray(experts) >= 512).mean())
+        assert 0.28 < share < 0.39             # a third of the outputs
+        assert int(dropless.zero_pairs(experts, 512)) == \
+            int((np.asarray(experts) >= 512).sum())
+        return
+    N, D, F, first, count, real, k = 20, 16, 24, 2, 3, 8, 4
+    x = jax.random.normal(rng[0], (N, D))
+    w = jax.random.normal(rng[1], (count, D, F)) * 0.3
+    down = jax.random.normal(rng[2], (count, F, D)) * 0.3
+    weights = jax.random.uniform(rng[3], (N, k)) + 0.5
+    # every token: one held expert (3), one absent (6), two identity (9, 11)
+    experts = jnp.tile(jnp.asarray([3, 6, 9, 11]), (N, 1))
+    if case == "ids_past_the_real_fall_in_no_group":
+        out = dropless.held_experts(x, experts, weights, w, w, down, first)
+        alone = dropless.held_experts(x, experts[:, :1], weights[:, :1], w,
+                                      w, down, first)
+        np.testing.assert_allclose(out, alone, rtol=1e-5, atol=1e-5)
+        assert (np.abs(np.asarray(out)).max(axis=1) > 0).all()
+        # the counters tell the absent real expert's pair from the
+        # identity experts' (which ``route_counters`` counts as not held)
+        n = dropless.route_counters(experts, first, count).tolist()
+        z = int(dropless.zero_pairs(experts, real))
+        assert (n[0], n[1] - z, z) == (N, N, 2 * N)
+        # all four ids past the held ones: nothing, exactly
+        none = dropless.held_experts(x, experts + 8, weights, w, w, down,
+                                     first)
+        assert float(jnp.abs(none).max()) == 0.0
+    else:
+        zero = dropless.zero_experts(x, experts, weights, real)
+        assert zero.dtype == jnp.float32 and zero.shape == (N, D)
+        np.testing.assert_allclose(
+            zero, np.asarray(x) * np.asarray(weights)[:, 2:].sum(
+                1, keepdims=True), rtol=1e-6)
+        # bfloat16 tokens: the product is still made in float32
+        z16 = dropless.zero_experts(x.astype(jnp.bfloat16), experts, weights,
+                                    real)
+        assert z16.dtype == jnp.float32
+        assert float(jnp.abs(dropless.zero_experts(
+            x, experts, weights, 12)).max()) == 0.0      # no id that high
+        live = jnp.arange(N) < 5
+        assert int(dropless.zero_pairs(experts, real, live)) == 10
