@@ -295,10 +295,14 @@ def replay_maxparams(doc, *, tolerance=REPLAY_TOLERANCE) -> dict:
 
 def request_unique_blocks(*, prompt_tokens, max_new_tokens, block_size,
                           max_seq=None, shared_prefix_tokens=0) -> dict:
-    """THE per-request block math — the one function serving admission
-    (``ServingEngine._admit``), ``ds_mem --max-streams`` and the memory
-    ledger's shared/unique split all call, so the three can never
-    disagree (regression-pinned in tests/test_serving.py).
+    """THE per-request block math: a stream's cost AT ITS END, as
+    ``ServingEngine.capacity``, ``ds_mem --max-streams`` and the memory
+    ledger's shared/unique split all call it, so the three can never
+    disagree (regression-pinned in tests/test_prefix_cache.py).  Serving
+    admission charges the same total over a stream's life
+    (``ServingEngine._plan``: ``blocks_needed`` of prompt + generation less
+    the shared blocks) and takes it at once only where a seat is the whole
+    life; elsewhere a stream holds what it has written.
 
     ``total_blocks`` is the classic cost (``paged_kv.blocks_needed`` of
     prompt+generation).  ``shared_blocks`` is how many leading blocks a
@@ -382,8 +386,14 @@ def max_streams(plan: dict, budget_bytes, *, safety=0.92,
                 workspace_bytes=0) -> dict:
     """Concurrent-stream bound for an HBM budget: blocks the budget can
     hold after weights + workspace, divided by the per-request block
-    cost — ``ServingEngine`` admission, answerable before anything
-    allocates (the serving twin of :func:`max_params_b`)."""
+    cost, answerable before anything allocates (the serving twin of
+    :func:`max_params_b`).  A LOWER bound on what ``ServingEngine``
+    seats: the cost is a stream's at its END, and admission by the pool's
+    timeline (docs/serving.md#capacity-math--admission-control) charges a
+    stream the blocks it has written, so streams at different points of
+    their answers fit where as many at their ends would not.  It is the
+    number where a seat is still the whole life (the prefix cache, a
+    snapshot cadence or a transfer queue armed)."""
     usable = budget_bytes * safety - plan["weight_bytes"] - workspace_bytes
     blocks = max(0, int(usable // plan["per_block_bytes"]) - 1)  # scratch
     # prefix sharing amortizes the shared head ONCE across every stream;

@@ -129,9 +129,13 @@ PROTECTED_ATTRS = {
                  "_seat_transfer"),
     # slot block tables: every seat (_start, _start_shared, the restore)
     # and the clear in _finish go through _set_slot, which also marks the
-    # device-resident copy of the slot state stale
-    "_tables": ("__init__", "_set_slot"),
-    "blocks": ("__init__",),             # per-sequence block list (_Slot)
+    # device-resident copy of the slot state stale; a block granted to a
+    # seated row goes through _grant_blocks, which tells the allocator, the
+    # sanitizer and the timeline's mirrors, and whose caller sends the grant
+    # to the device's copy
+    "_tables": ("__init__", "_set_slot", "_grant_blocks"),
+    # per-sequence block list (_Slot)
+    "blocks": ("__init__", "_grant_blocks"),
     # and its list of the second kind (a window layer's ring; the ring's
     # entries of `_tables` are written by `_set_slot` like the rest)
     "wblocks": ("__init__",),

@@ -59,6 +59,32 @@ def blocks_needed(total_tokens: int, block_size: int) -> int:
     return max(1, -(-int(total_tokens) // int(block_size)))
 
 
+def timeline_peak(written, ends, held, block_size: int) -> int:
+    """The most blocks a set of streams will ever hold at one decode step,
+    if each runs to its end: the admission rule's one sum
+    (docs/serving.md#capacity-math--admission-control).
+
+    Stream ``i`` has written ``written[i]`` tokens, writes one a step, and
+    leaves after ``ends[i]`` at the latest (prompt + ``max_new_tokens``),
+    when all its blocks come home; it never holds fewer than the ``held[i]``
+    it holds now.  At step ``t`` from now it holds::
+
+        max(held[i], blocks_needed(min(written[i] + t + 1, ends[i])))
+
+    while ``t < ends[i] - written[i]``, and nothing after.  The sum only
+    rises between finishes, so its peak stands at the last step of some
+    stream: one row of sums a stream, no walk over the steps."""
+    L = np.asarray(written, np.int64)
+    E = np.asarray(ends, np.int64)
+    left = E - L                           # <= 0: no stream (an empty slot)
+    t = left[left > 0, None] - 1           # the last step of each stream
+    if not t.size:
+        return 0
+    blocks = np.maximum(-(-np.minimum(L + t + 1, E) // int(block_size)),
+                        np.asarray(held, np.int64))
+    return int((blocks * (left > t)).sum(axis=1).max())
+
+
 class BlockAllocator:
     """Host-side free-list over pool block ids ``[1, num_blocks)``.
 

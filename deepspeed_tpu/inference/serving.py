@@ -709,9 +709,24 @@ class ServingEngine:
         self._transfer_pub_ms: List[float] = []
         self._transfer_outbox: Dict[int, dict] = {}
 
+        # Whoever else holds or reads a stream's blocks (the radix cache, a
+        # snapshot image, the transfer queue) takes them all at the seat, as
+        # every engine did before the pool's timeline: there a seat IS the
+        # stream's whole life (docs/serving.md#capacity-math--admission-control)
+        self._whole_life = (self.prefix is not None or self.kvs is not None
+                            or self._txq is not None or self.role != "mixed")
+
         S = config.batch_slots
         self._slots: List[Optional[_Slot]] = [None] * S
         self._snap_last = np.zeros((S,), np.int32)  # ngen at last snapshot
+        # what the pool's timeline sums, a column a slot (zeros where none
+        # is seated) and one more for the stream that asks: tokens written
+        # (filled in from `_lengths` when the rule is asked), the tokens
+        # after which the stream has left at the latest, the blocks it holds
+        self._timeline = np.zeros((3, S + 1), np.int64)
+        _, self._ends, self._held = self._timeline[:, :S]
+        self._promised = 0            # the timeline's peak at the last seat
+        self._grown_total = 0         # blocks granted to seated rows
         self._tables = np.zeros((S, self.nb_max + self.ring), np.int32)
         self._lengths = np.zeros((S,), np.int32)
         self._toks = np.zeros((S,), np.int32)
@@ -727,6 +742,7 @@ class ServingEngine:
         self._resident = None
         self._state_dirty = True
         self._unpack = None           # packed (S, table + 6) → the seven
+        self._grow = None             # a granted block into the device's tables
         self._reused_steps = 0        # decode steps that sent no state up
         self._state_uploads = 0
         # at most ONE decode step is dispatched and unread
@@ -1227,11 +1243,15 @@ class ServingEngine:
 
     # ------------------------------ slot state: mirrors and the device's copy
     def _set_slot(self, slot: int, blocks=(), length=0, tok=0, seed=0,
-                  ngen=0, temp=1.0, flag=False, wblocks=()):
+                  ngen=0, temp=1.0, flag=False, wblocks=(), end=0):
         """Seat a stream in ``slot`` — or, with the defaults, clear the
-        row — in all seven mirrors.  Every write of slot state other than
-        the decode step's plain advance comes through here or marks
-        ``_state_dirty`` itself, so the next dispatch re-sends the state."""
+        row — in all seven mirrors and in the timeline's two (``end``:
+        prompt + ``max_new_tokens``).
+        Every write of slot state other than the decode step's plain advance
+        and a granted block comes through here or marks ``_state_dirty``
+        itself, so the next dispatch re-sends the state."""
+        self._ends[slot] = end
+        self._held[slot] = len(blocks)
         self._tables[slot] = pk.SCRATCH_BLOCK
         self._tables[slot, :len(blocks)] = blocks
         # the ring's entries stand after the growing table's
@@ -1260,7 +1280,15 @@ class ServingEngine:
             self._tables, self._lengths, self._toks, self._seeds,
             self._ngen, self._temps.view(np.int32), self._flags))
         with jax.set_mesh(self.engine.mesh):
-            self._resident = list(self._unpack(jnp.asarray(buf)))
+            first = self._resident is None
+            self._resident = res = list(self._unpack(jnp.asarray(buf)))
+            if first:
+                # the growth program is acquired with the first upload and
+                # not at the first block edge some stream crosses, which a
+                # warm-up of short answers never reaches: this call grants
+                # nothing
+                res[0] = self._grow(res[0], res[1], np.full(
+                    self._lengths.shape, -1, np.int32))
         self._state_dirty = False
         self._state_uploads += 1
 
@@ -1274,10 +1302,68 @@ class ServingEngine:
         outside :meth:`step`: the unread step, if there is one, is settled
         first, so the operands equal the mirrors and the slots' token
         histories (then synced, if a slot changed; calling this between
-        steps consumes nothing and moves no later token)."""
+        steps consumes nothing and moves no later token).  The tables cover
+        the position each seated row writes next: a block that the next
+        dispatch would grant is granted here."""
         self._settle()
-        self._sync_state()
+        with jax.set_mesh(self.engine.mesh):
+            self._ready_state(False)
         return self._operands()
+
+    # ----------------------------------------- growth: a block at its first write
+    def _grant_blocks(self, ahead: bool):
+        """Give every seated row whose next write opens a block its table
+        lacks that block: from the allocator into the slot's list and the
+        mirrors.  Returns the grants a row (-1: none), or None where no row
+        grows.  ``ahead``: a step is unread, so the rows write one position
+        past the mirrors' lengths (the host counts it, reading nothing).
+
+        The admission rule planned for these blocks when it seated the
+        streams (:meth:`_plan`), so the allocator has them: a seated stream
+        never waits for a block."""
+        bs = self.config.block_size
+        col = (self._lengths + ahead) // bs
+        rows = np.flatnonzero((col >= self._held) & (self._ends > 0))
+        if not rows.size:
+            return None
+        fresh = self.allocator.alloc(len(rows))
+        assert fresh is not None, (
+            f"the pool's timeline broke its promise: {len(rows)} seated "
+            f"row(s) open a block and the allocator has "
+            f"{self.allocator.free_blocks} free of {self.num_blocks - 1} "
+            f"allocatable ({int(self._held.sum())} held by "
+            f"{int((self._ends > 0).sum())} stream(s), "
+            f"{self._promised} promised at the last seat)")
+        grant = np.full(self._lengths.shape, -1, np.int32)
+        for i, b in zip(rows, fresh):
+            s = self._slots[i]
+            s.blocks.append(b)
+            self._tables[i, col[i]] = grant[i] = b
+            if self._sanitizer is not None:
+                self._sanitizer.on_alloc([b], uid=s.req.uid)
+                self._sanitizer.on_attach(s.req.uid, s.blocks)
+        self._held[rows] += 1
+        self._grown_total += len(rows)
+        return grant
+
+    def _ready_state(self, ahead: bool) -> int:
+        """Before a dispatch (or a reader of the operands): grant the blocks
+        the next write opens, then make the device's copy of the slot state
+        equal the mirrors.  An upload carries the grants with everything
+        else; where nothing else changed, the grants alone go up (a few
+        bytes a row) and one small program writes them into the resident
+        tables, in device order behind the step that may still be running:
+        growth settles nothing.  Returns the blocks granted."""
+        before = self._grown_total
+        grant = self._grant_blocks(ahead)
+        if self._state_dirty:
+            self._sync_state()
+        elif grant is not None:
+            res = self._resident
+            # the host array as it is: the executable's own transfer is a
+            # fifth of `jnp.asarray`'s way there
+            res[0] = self._grow(res[0], res[1], grant)
+        return self._grown_total - before
 
     # ---------------------------------------------------------- jitted steps
     def _sample_tokens(self, logits, seeds, ngen, temps, flags):
@@ -1334,8 +1420,19 @@ class ServingEngine:
                     buf[:, nb + 5] != 0)
 
         c = self.config
+
+        def grow(tables, lengths, grant):
+            # a granted block goes where the row's next write lands: one
+            # select over the table, no scatter (which a TPU runs a row at
+            # a time)
+            here = jnp.arange(nb)[None, :] == (lengths // c.block_size)[:, None]
+            return jnp.where(here & (grant >= 0)[:, None], grant[:, None],
+                             tables)
+
         self._unpack = self.engine._wrap_step(
             f"serving.unpack[{c.batch_slots}x{nb}]", unpack)
+        self._grow = self.engine._wrap_step(
+            f"serving.grow[{c.batch_slots}x{nb}]", grow)
         self._decode = self.engine._wrap_step(
             f"serving.decode[{c.batch_slots}x{nb}"
             f"x{c.block_size},kv{c.kv_bits},{c.top_k}]",
@@ -1400,17 +1497,16 @@ class ServingEngine:
 
     # ------------------------------------------------------------- scheduler
     def _admit(self):
-        """Move queue-head requests into free slots while capacity lasts
-        (strict FIFO: a blocked head waits for blocks rather than being
-        overtaken — no starvation).  Deadline enforcement's admit half
-        lives here: a head whose deadline already passed, or provably
-        cannot be met (remaining budget < max_new · measured step EMA),
-        is shed with a typed ``DEADLINE`` result instead of occupying a
-        slot it cannot use."""
+        """Move queue-head requests into free slots while the pool's
+        timeline has room (strict FIFO: a blocked head waits for blocks
+        rather than being overtaken — no starvation).  Deadline
+        enforcement's admit half lives here: a head whose deadline already
+        passed, or provably cannot be met (remaining budget < max_new ·
+        measured step EMA), is shed with a typed ``DEADLINE`` result instead
+        of occupying a slot it cannot use."""
         if self._draining:
             return
         fault.site("serving.admit")
-        c = self.config
         while self.queue:
             req: Request = self.queue[0]
             if self._deadline_unmeetable(req):
@@ -1424,23 +1520,18 @@ class ServingEngine:
             new = req.max_new_tokens       # resolved >= 1 by submit()
             share = self._prefix_match(req)
             ns = share["ns"] if share is not None else 0
-            # the unified capacity math (analysis/capacity.py): the SAME
-            # function ds_mem's serving_plan/--max-streams and the
-            # memory ledger use — admission charges UNIQUE blocks only
-            from ..analysis.capacity import request_unique_blocks
-            ub = request_unique_blocks(
-                prompt_tokens=len(req.tokens), max_new_tokens=new,
-                block_size=c.block_size,
-                shared_prefix_tokens=ns * c.block_size)
-            assert ub["shared_blocks"] == ns   # same clamp by construction
-            # both kinds are reserved here, once, for the stream's whole
-            # life; the head waits on whichever is short
-            need_w = self._window_need(len(req.tokens) + new)
-            if need_w and not self.window_allocator.can_alloc(need_w):
+            # the one rule (`_plan`): the seat's blocks of the growing table
+            # now, the rest promised over the stream's life; the ring is
+            # reserved here, once; the head waits on whichever is short
+            plan = self._head_plan(req, ns)
+            if any(plan[1]):
                 return
-            fresh = self._alloc_blocks(ub["unique_blocks"], uid=req.uid)
+            seat, need_w, peak = plan[0]
+            fresh = self._alloc_blocks(seat, uid=req.uid)
             if fresh is None:
+                # the radix cache held more than an eviction could free
                 return
+            self._promised = peak
             wblocks = self._alloc_window(need_w, uid=req.uid)
             if ns:
                 # borrow the cached prefix read-only: one refcount per
@@ -1479,6 +1570,77 @@ class ServingEngine:
                         self._sanitizer.on_free(released, uid=req.uid)
                     self._free_window(wblocks, uid=req.uid)
                 raise
+
+    # --------------------------------------- admission by the pool's timeline
+    # INVARIANT: a seated stream never waits for a block and is never
+    # preempted; the queue's head is never overtaken.  A seat takes the blocks
+    # the prompt and the first decode write touch; every later block is
+    # granted at the dispatch that first writes into it (`_grant_blocks`).
+    # The head is seated only if, with it, the blocks all seated streams hold
+    # never exceed the pool at any coming step, each running to its
+    # `max_new_tokens` (`paged_kv.timeline_peak`).  By induction every seat
+    # leaves a plan that fits, and what happens is never above the plan: every
+    # seated row writes one token a dispatch, `max_new_tokens` is a hard bound,
+    # and an eos, a deadline, a poisoned row or a drain only brings blocks
+    # home earlier.  Nothing is preempted, recomputed or reordered: a request
+    # gets the tokens it gets alone (tests/test_serving_timeline.py).
+
+    def _seat_blocks(self, prompt_len: int, total: int) -> int:
+        """Blocks of the growing table a stream is seated with: what the
+        prompt and the first decode write touch; where a seat is the
+        stream's whole life (``_whole_life``), all ``total`` tokens' blocks."""
+        return pk.blocks_needed(
+            total if self._whole_life else min(prompt_len + 1, total),
+            self.config.block_size)
+
+    def _plan(self, written: int, total: int, seat: int):
+        """THE admission rule for the growing table's blocks: may a stream
+        that has written ``written`` of at most ``total`` tokens be seated
+        with ``seat`` blocks of its own?
+        Returns a bound on the blocks the seated streams and this one will
+        hold at any step — the peak of the pool's timeline, or the looser sum
+        of their whole lives where even that fits — or None if it has to
+        wait.  Pure host arithmetic over the mirrors."""
+        free = self.allocator.free_blocks
+        if self._whole_life:
+            # what is checked out is the plan; with the radix cache armed an
+            # eviction may still make room, which only the allocator knows
+            if seat > free and self._prefix_index is None:
+                return None
+            return self.allocator.used_blocks + seat
+        if seat > free:
+            return None
+        rows = self._timeline
+        # a row in the unread step has written one token more than its mirror
+        rows[0, :-1] = self._lengths
+        rows[0, :-1] += self._unread is not None
+        rows[:, -1] = written, total, seat
+        # a block checked out by no seated stream is held for ever
+        room = (self.num_blocks - 1 - self.allocator.used_blocks
+                + int(self._held.sum()))
+        bs = self.config.block_size
+        # were every stream at its end at once, as a reservation for life
+        # has it: where that fits (the slots bind, not the pool) every step
+        # fits, and the rule stops at that sum, a row of it and not a square
+        lives = int(np.maximum(-(-rows[1] // bs), rows[2]).sum())
+        if lives <= room:
+            return lives
+        peak = pk.timeline_peak(*rows, bs)
+        return peak if peak <= room else None
+
+    def _head_plan(self, req: Request, shared: int = 0):
+        """What seating ``req`` now takes — ``(seat blocks, window blocks,
+        timeline peak)`` behind ``shared`` borrowed blocks — and the kinds
+        of block it would have to wait for, ``(global, window)``: it may be
+        seated iff neither."""
+        T = len(req.tokens)
+        total = T + req.max_new_tokens
+        seat = self._seat_blocks(T, total) - shared
+        peak = self._plan(T, total, seat)
+        need_w = self._window_need(total)
+        window_short = bool(need_w) and not self.window_allocator.can_alloc(
+            need_w)
+        return (seat, need_w, peak), (peak is None, window_short)
 
     def _window_need(self, total_tokens: int) -> int:
         """Window-kind blocks a stream of ``total_tokens`` reserves: what
@@ -1532,11 +1694,7 @@ class ServingEngine:
             return False
         if self._prefix_index is not None:
             return True
-        total = len(req.tokens) + req.max_new_tokens
-        need = pk.blocks_needed(total, self.config.block_size)
-        return need <= self.allocator.free_blocks and (
-            not self.ring or self.window_allocator.can_alloc(
-                self._window_need(total)))
+        return not any(self._head_plan(req)[1])
 
     def _prefix_match(self, req: Request) -> Optional[dict]:
         """Clamped radix lookup for one admission.  ``ns`` is capped at
@@ -1675,7 +1833,7 @@ class ServingEngine:
         self._slots[slot] = s
         self._set_slot(slot, blocks, length=T, tok=first, seed=req.seed,
                        ngen=1, temp=req.temperature, flag=req.do_sample,
-                       wblocks=s.wblocks)
+                       wblocks=s.wblocks, end=T + new)
         if self._sanitizer is not None:
             self._sanitizer.on_attach(req.uid, blocks)
         if self._window_sanitizer is not None:
@@ -1752,7 +1910,7 @@ class ServingEngine:
         # ngen 0: no token emitted yet
         self._set_slot(slot, blocks, length=pos0, tok=prompt[pos0],
                        seed=req.seed, ngen=0, temp=req.temperature,
-                       flag=req.do_sample)
+                       flag=req.do_sample, end=T + new)
         if self._sanitizer is not None:
             self._sanitizer.on_attach(req.uid, blocks)
         self.results[req.uid]["t_tokens"] = []
@@ -2054,11 +2212,18 @@ class ServingEngine:
                     f"serving: restore of uid {req.uid} found no local "
                     f"prefix match — degrading to a full private import "
                     f"({nb} block(s) duplicated)")
-        fresh = self._alloc_blocks(nb - ns, uid=req.uid)
+        # an image covers the stream's whole life, so the seat takes it all:
+        # the same rule as the queue's head, or the seated streams' growth
+        # would be short of what the import took
+        written = int(prompt.size) + len(out_tokens) - 1
+        peak = self._plan(written, int(prompt.size) + new, nb - ns)
+        fresh = (self._alloc_blocks(nb - ns, uid=req.uid)
+                 if peak is not None else None)
         if fresh is None:
             raise KVRestoreError(
-                f"allocator cannot serve {nb - ns} block(s) "
+                f"the pool's timeline has no room for {nb - ns} block(s) "
                 f"({self.allocator.free_blocks} free)")
+        self._promised = peak
         if ns:
             self.allocator.incref(shared)
             self._prefix_shared_blocks_total += ns
@@ -2098,7 +2263,8 @@ class ServingEngine:
                 slot, blocks,
                 length=int(prompt.size) + len(out_tokens) - 1,
                 tok=out_tokens[-1], seed=req.seed, ngen=len(out_tokens),
-                temp=req.temperature, flag=req.do_sample)
+                temp=req.temperature, flag=req.do_sample,
+                end=int(prompt.size) + new)
             if self._sanitizer is not None:
                 self._sanitizer.on_attach(req.uid, blocks)
                 # DSTPU317 (satellite fix): a restore that imports a
@@ -2718,13 +2884,22 @@ class ServingEngine:
         cached for the seated streams (the mirrors' count: one step behind
         while a step is unread), and whether the queue's head waits for
         BLOCKS: admission has just run or was not due, so a head still
-        queued beside a free slot lacks only them."""
+        queued beside a free slot lacks only them (of which kind, the
+        admission rule says: ``_head_plan``)."""
         waits = bool(self.queue and not self._draining
                      and len(active) < self.config.batch_slots)
         out = {"blocks_in_use": self.allocator.used_blocks,
                "blocks_free": self.allocator.free_blocks,
                "kv_tokens": int(self._lengths.sum()),
-               "waits_for_blocks": waits}
+               "waits_for_blocks": waits,
+               # the bound the admission rule held the seated streams to at
+               # the last seat (`_plan`), the blocks this call's dispatch
+               # grants to seated rows (`_dispatch` counts them) and the
+               # tokens the allocatable blocks hold
+               "blocks_promised": self._promised,
+               "blocks_grown": 0,
+               "kv_token_room": (self.num_blocks - 1)
+               * self.config.block_size}
         if self._recurrent:
             # the slots whose recurrent rows this dispatch advances (and
             # those it leaves), and the bytes of state that takes (read
@@ -2740,18 +2915,20 @@ class ServingEngine:
             # growing table for every layer would still hold
             window = self.model.config.sliding_window
             seen = int(np.minimum(self._lengths, window).sum())
-            total = (len(self.queue[0].tokens)
-                     + self.queue[0].max_new_tokens) if waits else 0
+            window_short = waits and not self.window_allocator.can_alloc(
+                self._window_need(len(self.queue[0].tokens)
+                                  + self.queue[0].max_new_tokens))
             out.update(
                 window_blocks_in_use=self.window_allocator.used_blocks,
                 window_blocks_free=self.window_allocator.free_blocks,
                 window_kv_tokens=seen,
                 window_capped_tokens=out["kv_tokens"] - seen,
-                waits_for_window_blocks=waits and not
-                self.window_allocator.can_alloc(self._window_need(total)),
-                waits_for_global_blocks=waits and pk.blocks_needed(
-                    total, self.config.block_size)
-                > self.allocator.free_blocks)
+                waits_for_window_blocks=window_short,
+                # a head that waits lacks one kind or both: the rule's sum
+                # is asked again only where the ring is short as well
+                waits_for_global_blocks=waits and (
+                    not window_short
+                    or self._head_plan(self.queue[0])[1][0]))
         return out
 
     def _dispatch(self, active, ahead: bool) -> _Unread:
@@ -2767,7 +2944,7 @@ class ServingEngine:
             # alone
             with spans.span("serving.upload") as upload:
                 upload.attrs = {"uploaded": self._state_dirty}
-                self._sync_state()
+                self._pool_attrs["blocks_grown"] = self._ready_state(ahead)
                 args = self._operands()
             self._reused_steps += not upload.attrs["uploaded"]
             self._ahead_steps += ahead
@@ -2901,19 +3078,22 @@ class ServingEngine:
     def _raise_stalled(self):
         c = self.config
         req: Request = self.queue[0]
-        nb = pk.blocks_needed(len(req.tokens) + req.max_new_tokens,
-                              c.block_size)
+        total = len(req.tokens) + req.max_new_tokens
+        nb = pk.blocks_needed(total, c.block_size)
+        seat = self._seat_blocks(len(req.tokens), total)
         # admission failure: the ledger dump makes the block math a
         # forensic artifact, not just an exception message
         path = self._memory_forensics(
             f"serving admission stalled: head uid {req.uid} needs {nb} "
-            f"block(s), allocator has {self.allocator.free_blocks} free")
+            f"block(s) ({seat} at its seat), allocator has "
+            f"{self.allocator.free_blocks} free")
         raise ServingStalledError(
             f"serving stalled: {len(self.queue)} request(s) queued, zero "
             f"slots active, and admission made no progress — head uid "
-            f"{req.uid} needs {nb} block(s) "
+            f"{req.uid} needs {nb} block(s) over its life "
             f"(= ceil(({len(req.tokens)} prompt + {req.max_new_tokens} "
-            f"new) / block_size {c.block_size})) but the allocator has "
+            f"new) / block_size {c.block_size}), {seat} of them at its "
+            f"seat) but the allocator has "
             f"{self.allocator.free_blocks} free of "
             f"{self.num_blocks - 1} allocatable "
             f"({self.allocator.used_blocks} leaked or still held)"
@@ -3302,6 +3482,7 @@ class ServingEngine:
         self._reused_steps = 0
         self._state_uploads = 0
         self._ahead_steps = 0
+        self._grown_total = 0
         self._state_seats = 0
         self._outcomes = {k: 0 for k in OUTCOMES}
         self._requeued_total = 0
@@ -3343,6 +3524,9 @@ class ServingEngine:
                # and the steps dispatched while the one before was still
                # unread (docs/serving.md#one-step-in-flight)
                "steps_ahead": self._ahead_steps,
+               # blocks granted to seated rows at the dispatch that first
+               # wrote into them (docs/serving.md#capacity-math--admission-control)
+               "blocks_grown_total": self._grown_total,
                "generated_tokens": self._generated_total,
                # what the donated pytree holds: K/V blocks, and for a
                # model with recurrent layers its per-slot rows and how
@@ -3470,11 +3654,11 @@ class ServingEngine:
             except OSError as e:
                 logger.warning(f"serving: journal close failed ({e}); "
                                "continuing teardown")
-            for fn in [self._decode, self._unpack] + list(
+            for fn in [self._decode, self._unpack, self._grow] + list(
                     self._prefills.values()):
                 if fn is not None and hasattr(fn, "clear"):
                     fn.clear()
-            self._decode = self._unpack = self._resident = None
+            self._decode = self._unpack = self._grow = self._resident = None
             self._unread = None
             self._prefills.clear()
             self._blockset = None

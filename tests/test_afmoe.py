@@ -330,9 +330,12 @@ def test_a_seat_reserves_both_kinds_and_a_short_one_only_what_it_fills(
     short = srv.submit(Request(tokens=tokens(1, 3), max_new_tokens=3))
     long = srv.submit(Request(tokens=tokens(2, 30), max_new_tokens=20))
     srv.step()
-    # 6 tokens: 2 blocks of each kind; 50 tokens: 13 global, the ring's 3
-    assert blocks_of(srv, short) == (2, 2)
-    assert blocks_of(srv, long) == (13, RING)
+    # 6 tokens: 2 ring blocks for its life, and of the growing table what
+    # the prompt and the first write touch (4 tokens: 1 block); 50 tokens:
+    # the ring's 3, and 8 global blocks for 31 tokens, 13 at its end
+    assert blocks_of(srv, short) == (1, 2)
+    assert blocks_of(srv, long) == (8, RING)
+    assert srv._promised == 2 + 13  # both lives fit at once: the rule's sum
     assert RING * BLOCK <= WINDOW + BLOCK            # the cap
     row = srv._tables[1]
     assert (row[srv.nb_max:] != pk.SCRATCH_BLOCK).sum() == RING

@@ -394,7 +394,9 @@ def test_stalled_scheduler_raises_with_block_math(tiny, devices):
     leaked = srv.allocator.alloc(3)     # simulate a block leak
     assert leaked is not None
     srv.submit(Request(tokens=np.arange(4), max_new_tokens=2))
-    with pytest.raises(ServingStalledError, match=r"needs 1 block.*0 free"):
+    with pytest.raises(ServingStalledError, match=(
+            r"needs 1 block\(s\) over its life.*1 of them at its seat"
+            r".*0 free")):
         srv.run(max_steps=10)
     srv.close()
 

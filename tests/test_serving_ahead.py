@@ -169,7 +169,8 @@ def test_dead_row_at_the_context_limit_writes_only_its_own_blocks(families,
         while srv.results[a]["outcome"] is None:
             assert _step(srv, settle)
             if blocks_a is None:
-                blocks_a = list(srv._slots[0].blocks)
+                # the slots' own lists: each grows a block at a time
+                blocks_a, blocks_b = srv._slots[0].blocks, srv._slots[1].blocks
         if settle:
             _step(srv, settle)           # the neighbour's step N+1, too
         else:
@@ -180,21 +181,53 @@ def test_dead_row_at_the_context_limit_writes_only_its_own_blocks(families,
         c = srv.submit(request_c())
         while srv.step():
             if srv._slots[0] is not None and srv._slots[0].req.uid == c:
-                blocks_c = list(srv._slots[0].blocks)
+                blocks_c = srv._slots[0].blocks
         res = {u: r["tokens"] for u, r in srv.results.items()}
         free = srv.allocator.free_blocks
         srv.close()
-        return res, pool, free, (a, b, c), blocks_a, blocks_c
+        return res, pool, free, (a, b, c), blocks_a, blocks_c, blocks_b
 
-    got, pool, free, (a, b, c), blocks_a, blocks_c = serve(settle=False)
-    want, pool_settled, free_settled, _, _, _ = serve(settle=True)
+    got, pool, free, (a, b, c), blocks_a, blocks_c, blocks_b = serve(
+        settle=False)
+    want, pool_settled, free_settled, _, _, _, b_settled = serve(settle=True)
     assert len(blocks_a) * 8 == 64 == families["gpt2"][0].config.max_seq
     assert got[a] == alone[:j + 1] and (40 + j) // 8 == 7    # the last block
-    touched = _touched_blocks(pool, pool_settled)
+    # the neighbour's own newest block may be another in the two runs: one
+    # granted before the dead row's blocks came home, the other after
+    touched = _touched_blocks(pool, pool_settled) - (
+        set(blocks_b) ^ set(b_settled))
     assert touched and touched <= set(blocks_a) | {pk.SCRATCH_BLOCK}
     assert set(blocks_c) & set(blocks_a)         # seated into freed blocks
     assert got == want and got[c] == c_alone
     assert free == free_settled
+
+
+# ------------------------------------------- (2b) a granted block settles nothing
+@pytest.mark.parametrize("family", ["gpt2", "jamba"])
+def test_growth_settles_no_step(families, devices, family):
+    """A row gets its next block at the dispatch that first writes into it
+    (docs/serving.md#capacity-math--admission-control), through a program of
+    its own behind the step in flight: the same requests run as many steps
+    ahead, with as many uploads, as over an engine that takes every block at
+    the seat (what every engine did before: the parent's count), and the
+    streams are the same."""
+    def serve(whole_life):
+        srv = _server(families[family], block_size=4)
+        srv._whole_life = whole_life
+        res = srv.run(_mixed_requests(sampled=True))
+        st = srv.stats()
+        free = srv.allocator.free_blocks, srv.num_blocks - 1
+        srv.close()
+        return {u: (r["outcome"], r["tokens"]) for u, r in res.items()}, st, free
+
+    got, st, free = serve(False)
+    want, st_life, free_life = serve(True)
+    assert got == want and free == free_life and free[0] == free[1]
+    assert st["blocks_grown_total"] >= 5 and st_life["blocks_grown_total"] == 0
+    for name in ("decode_steps", "steps_ahead", "state_uploads",
+                 "state_reused_steps"):
+        assert st[name] == st_life[name], name
+    assert st["steps_ahead"] > 0
 
 
 # ------------------------------------- (3) what settles every step stays as it was

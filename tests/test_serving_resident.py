@@ -24,7 +24,10 @@ from deepspeed_tpu.inference import (InferenceEngine, ServingEngine,
 
 MIRRORS = ("_tables", "_lengths", "_toks", "_seeds", "_ngen", "_temps",
            "_flags")
-VARIANTS = {"plain": {}, "prefix_sharing": {"prefix_cache": True}}
+# "growth": blocks of 4 tokens over a pool that binds, so rows are granted a
+# block every fourth step, some with a step in flight, and the head waits
+VARIANTS = {"plain": {}, "prefix_sharing": {"prefix_cache": True},
+            "growth": {"block_size": 4, "num_blocks": 25}}
 
 
 @pytest.fixture(scope="module")
@@ -115,6 +118,9 @@ def test_resident_state_equals_mirrors_after_every_step(
             rec["deadline"] = time.monotonic() - 1.0     # force expiry
     assert untouched >= 5               # the in-graph advance was compared
     assert srv.stats()["steps_ahead"] > 0     # ...with a step in flight too
+    # ...and, without the radix cache, tables that grew on the device
+    assert (srv.stats()["blocks_grown_total"] > 0) == (
+        variant != "prefix_sharing")
     out = {u: srv.results[u]["outcome"] for u in uids + [late]}
     assert out[2] == POISONED and out[late] == DEADLINE
     assert all(out[u] == OK for u in uids if u != 2)
