@@ -33,6 +33,8 @@ import numpy as np
 from .comms import CensusEntry, CommsBudget, canonical_kind, check_budget, \
     summarize
 from .findings import Finding, counts_by_severity
+from .hlo_scopes import COMPUTATION_RE as _HLO_COMPUTATION_RE, \
+    OP_NAME_RE as _HLO_OP_NAME_RE, kernel_scope as _kernel_scope
 
 # primitives that round-trip through the host (serialize the step on the
 # dispatch path); anything name-matching *callback is caught too.
@@ -268,8 +270,6 @@ _HLO_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 _HLO_COLLECTIVE_RE = re.compile(
     r"\s(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(")
-_HLO_COMPUTATION_RE = re.compile(
-    r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 _HLO_CALLEE_RE = re.compile(
     r"(body|condition|calls|to_apply)=(%?[\w.\-]+)"
     r"|(branch_computations)=\{([^}]*)\}")
@@ -438,25 +438,6 @@ def census_from_hlo_text(hlo_text):
 
 
 _HLO_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
-_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
-# op_name components that say how a kernel was reached, not what it is
-_HLO_WRAPPER_SCOPES = frozenset({
-    "pallas_call", "shard_map", "checkpoint", "rematted_computation",
-    "while", "body", "cond", "custom_vjp_call", "custom_jvp_call",
-    "closed_call"})
-
-
-def _kernel_scope(op_name: str) -> str:
-    """The innermost user scope of a kernel's ``op_name``: a Mosaic
-    custom call carries no kernel name in its HLO text, so it is named
-    by the ``jax.named_scope`` it sits in (``attention`` for the flash
-    kernels of a GPT-2 block), passing over the components JAX's own
-    transformations add (``jit(f)``, ``transpose(jvp(...))``,
-    ``shard_map``, ``checkpoint``, loop bodies)."""
-    parts = [c for c in op_name.split("/")
-             if c and "(" not in c and c not in _HLO_WRAPPER_SCOPES
-             and not c.startswith("branch_")]
-    return parts[-1] if parts else "pallas_call"
 
 
 def custom_calls_from_hlo_text(hlo_text) -> dict:

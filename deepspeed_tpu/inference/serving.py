@@ -1369,16 +1369,17 @@ class ServingEngine:
     def _sample_tokens(self, logits, seeds, ngen, temps, flags):
         """(B, V) fp32 → (B,) int32: per-slot greedy/sampled select with
         the request-deterministic key stream (module docstring)."""
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        lg = logits / jnp.maximum(temps, 1e-6)[:, None]
-        if self.config.top_k is not None:
-            kth = jax.lax.top_k(lg, self.config.top_k)[0][:, -1:]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        keys = jax.vmap(lambda s, n: jax.random.fold_in(
-            jax.random.PRNGKey(s), n))(seeds, ngen)
-        sampled = jax.vmap(
-            lambda k, row: jax.random.categorical(k, row))(keys, lg)
-        return jnp.where(flags, sampled.astype(jnp.int32), greedy)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            lg = logits / jnp.maximum(temps, 1e-6)[:, None]
+            if self.config.top_k is not None:
+                kth = jax.lax.top_k(lg, self.config.top_k)[0][:, -1:]
+                lg = jnp.where(lg < kth, -jnp.inf, lg)
+            keys = jax.vmap(lambda s, n: jax.random.fold_in(
+                jax.random.PRNGKey(s), n))(seeds, ngen)
+            sampled = jax.vmap(
+                lambda k, row: jax.random.categorical(k, row))(keys, lg)
+            return jnp.where(flags, sampled.astype(jnp.int32), greedy)
 
     def _build_decode(self):
         if self._decode is not None:
@@ -1480,6 +1481,10 @@ class ServingEngine:
                 read = jnp.concatenate([read, pool["counters"]])
             return read, pool
 
+        # every bucket its own XLA module (``jit_prefill_<bucket>``): a
+        # device trace tells the executables apart by module name alone,
+        # and ``monitor.device_scopes()`` books an instruction by it
+        prefill.__name__ = f"prefill_{bucket}"
         fn = self.engine._wrap_step(
             f"serving.prefill[{bucket},kv{self.config.kv_bits}]", prefill,
             donate_argnums=(2,))
@@ -2871,8 +2876,7 @@ class ServingEngine:
                           "t_tokens": None, **self._pool_attrs}
             return True
         n_active, emitted_step, now = booked
-        with spans.span("serving.telemetry"):
-            self._monitor_finish(n_active, tokens=emitted_step)
+        self._monitor_finish(n_active, tokens=emitted_step)
         root.attrs = {"n_active": n_active, "emitted": emitted_step,
                       "t_tokens": now, **self._pool_attrs,
                       **self._route_attrs}

@@ -306,17 +306,18 @@ class Afmoe:
     def _qkv(self, p, h, positions, sliding):
         """The stream ``h`` (B, T, D) -> ``(q (B, T, H, hd), k, v (B, T, Hkv,
         hd))``: q and k normed a head and, on a sliding layer, rotated."""
-        c = self.config
-        a = _rms(h, p["ln_in"], c.rms_norm_eps).astype(self.dtype)
-        heads = lambda x: x.reshape(a.shape[:-1] + (-1, c.head_dim))
-        by_rows = lambda w: jnp.einsum("...d,od->...o", a, w.astype(a.dtype))
-        q = _rms(heads(by_rows(p["q_w"])), p["q_norm"], c.rms_norm_eps)
-        k = _rms(heads(by_rows(p["k_w"])), p["k_norm"], c.rms_norm_eps)
-        if sliding:
-            cos, sin = self._rope
-            q = apply_rotary_pos_emb(q, cos, sin, positions)
-            k = apply_rotary_pos_emb(k, cos, sin, positions)
-        return q, k, heads(_mm(a, p["v_w"]))
+        with jax.named_scope("attention"):
+            c = self.config
+            a = _rms(h, p["ln_in"], c.rms_norm_eps).astype(self.dtype)
+            heads = lambda x: x.reshape(a.shape[:-1] + (-1, c.head_dim))
+            by_rows = lambda w: jnp.einsum("...d,od->...o", a, w.astype(a.dtype))
+            q = _rms(heads(by_rows(p["q_w"])), p["q_norm"], c.rms_norm_eps)
+            k = _rms(heads(by_rows(p["k_w"])), p["k_norm"], c.rms_norm_eps)
+            if sliding:
+                cos, sin = self._rope
+                q = apply_rotary_pos_emb(q, cos, sin, positions)
+                k = apply_rotary_pos_emb(k, cos, sin, positions)
+            return q, k, heads(_mm(a, p["v_w"]))
 
     def _moe(self, pm, u, layer, live=None):
         """Expert layer ``layer`` (of the stacked ``pm``) over ``u`` (B, T,
@@ -358,12 +359,15 @@ class Afmoe:
         with jax.named_scope("attn.gate"):
             a = _rms(h, p["ln_in"], eps).astype(self.dtype)
             out = out * jax.nn.sigmoid(_mm(a, p["gate_w"]))
-        h = h + _rms(_mm(out, p["o_w"]).astype(f32), p["ln_post_attn"], eps)
+        with jax.named_scope("attention"):
+            h = h + _rms(_mm(out, p["o_w"]).astype(f32), p["ln_post_attn"],
+                         eps)
         u = _rms(h, p["ln_pre_mlp"], eps).astype(self.dtype)
         counts = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
         experts = None
         if l < Ld:
-            y = swiglu(_take(params["dense"], l), u)
+            with jax.named_scope("mlp"):
+                y = swiglu(_take(params["dense"], l), u)
         else:
             y, counts, experts = self._moe(params["moe"], u, l - Ld,
                                            live=live)
@@ -456,16 +460,18 @@ class Afmoe:
             return jnp.moveaxis(out, 0, 2).reshape(B, T, H * hd)
 
     def _embed(self, params, tokens):
-        h = params["wte"][tokens - self.config.vocab_rows[0]].astype(
-            jnp.float32)
-        c = self.config
-        return h * np.sqrt(c.hidden_size) if c.mup_enabled else h
+        with jax.named_scope("embed"):
+            h = params["wte"][tokens - self.config.vocab_rows[0]].astype(
+                jnp.float32)
+            c = self.config
+            return h * np.sqrt(c.hidden_size) if c.mup_enabled else h
 
     def _head(self, params, h):
-        h = _rms(h, params["lnf"], self.config.rms_norm_eps)
-        return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
-                          params["head"].astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            h = _rms(h, params["lnf"], self.config.rms_norm_eps)
+            return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
+                              params["head"].astype(self.dtype),
+                              preferred_element_type=jnp.float32)
 
     # --------------------------------------------------------------- forward
     def apply(self, params, tokens, rng=None, deterministic=True,
@@ -656,12 +662,14 @@ class Afmoe:
         def attn_fn(q, k, v, l, pool):
             if l in self.global_layers:
                 i = self.global_layers.index(l)
-                pool = pk.write_tokens(pool, i, table, lengths, k, v)
+                with jax.named_scope("kv.seat"):
+                    pool = pk.write_tokens(pool, i, table, lengths, k, v)
                 with jax.named_scope("attn.global"):
                     return attend(q, pool, table, i, None), pool
             i = self.window_layers.index(l)
-            view = pk.write_tokens(pk.window_view(pool), i, ring_table,
-                                   lengths, k, v, ring=True)
+            with jax.named_scope("kv.seat"):
+                view = pk.write_tokens(pk.window_view(pool), i, ring_table,
+                                       lengths, k, v, ring=True)
             with jax.named_scope("attn.window"):
                 out = attend(q, view, ring_table, i, c.sliding_window)
             return out, pk.with_window(pool, view)

@@ -352,15 +352,17 @@ class DeepseekV2:
         pa = params["attn"]
 
         def attention(p, h, l, carry):
-            a = _rms(h, p["ln_in"], eps).astype(self.dtype)
-            out, carry = attn_fn(p, *self._mla.project(p, a, positions), l,
-                                 carry)
-            h = h + _mm(out, p["o_w"]).astype(f32)
-            return h, _rms(h, p["ln_ff"], eps).astype(self.dtype), carry
+            with jax.named_scope("attention"):
+                a = _rms(h, p["ln_in"], eps).astype(self.dtype)
+                out, carry = attn_fn(p, *self._mla.project(p, a, positions), l,
+                                     carry)
+                h = h + _mm(out, p["o_w"]).astype(f32)
+                return h, _rms(h, p["ln_ff"], eps).astype(self.dtype), carry
 
         for l in range(c.n_dense_layer):
             h, u, carry = attention(_take(pa, l), h, l, carry)
-            h = h + swiglu(_take(params["dense"], l), u).astype(f32)
+            with jax.named_scope("mlp"):
+                h = h + swiglu(_take(params["dense"], l), u).astype(f32)
 
         Ld, pm = c.n_dense_layer, params["moe"]
 
@@ -389,14 +391,16 @@ class DeepseekV2:
         return state
 
     def _embed(self, params, tokens):
-        return params["wte"][tokens - self.config.vocab_rows[0]].astype(
-            jnp.float32)
+        with jax.named_scope("embed"):
+            return params["wte"][tokens - self.config.vocab_rows[0]].astype(
+                jnp.float32)
 
     def _head(self, params, h):
-        h = _rms(h, params["lnf"], self.config.rms_norm_eps)
-        return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
-                          params["head"].astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            h = _rms(h, params["lnf"], self.config.rms_norm_eps)
+            return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
+                              params["head"].astype(self.dtype),
+                              preferred_element_type=jnp.float32)
 
     # --------------------------------------------------------------- forward
     def apply(self, params, tokens, rng=None, deterministic=True,

@@ -278,10 +278,11 @@ def _chunked_head_nll(c, wte, x, labels):
         lab = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
         return jnp.sum((lse - lab) * vc)
 
-    total = jax.lax.map(lambda args: chunk_nll(*args),
-                        (chunks(x), chunks(labels.astype(jnp.int32)),
-                         chunks(jnp.ones((B, T), jnp.float32))))
-    return jnp.sum(total) / (B * T)
+    with jax.named_scope("lm_head"):
+        total = jax.lax.map(lambda args: chunk_nll(*args),
+                            (chunks(x), chunks(labels.astype(jnp.int32)),
+                             chunks(jnp.ones((B, T), jnp.float32))))
+        return jnp.sum(total) / (B * T)
 
 
 class GPT2:
@@ -448,7 +449,7 @@ class GPT2:
 
         from ..runtime.zero.partition import shard_stream
         wte, wpe = self._whole(params, "wte", "wpe")
-        with jax.named_scope("embedding"):
+        with jax.named_scope("embed"):
             pos = jnp.arange(T)
             x = wte.astype(dtype)[tokens] + wpe.astype(dtype)[pos]
             x = shard_stream(_dropout(x, c.embd_pdrop,
@@ -580,11 +581,12 @@ class GPT2:
         """The decode MLP half-block (LN2 → fc → gelu → fc_proj +
         residual), shared by every decode path — int8-aware via
         ``_mm``."""
-        c = self.config
-        h = _layer_norm(x, p["ln2_scale"], p["ln2_bias"], c.layer_norm_eps)
-        h = self._mm(h, p["fc_w"], p["fc_b"])
-        h = jax.nn.gelu(h, approximate=True)
-        return x + self._mm(h, p["fc_proj_w"], p["fc_proj_b"])
+        with jax.named_scope("mlp"):
+            c = self.config
+            h = _layer_norm(x, p["ln2_scale"], p["ln2_bias"], c.layer_norm_eps)
+            h = self._mm(h, p["fc_w"], p["fc_b"])
+            h = jax.nn.gelu(h, approximate=True)
+            return x + self._mm(h, p["fc_proj_w"], p["fc_proj_b"])
 
     def _cached_attention(self, p, h, cache_k, cache_v, index, is_local=None):
         """Per-layer batch-major cache variant (GPT2MoE's decode).
@@ -612,19 +614,21 @@ class GPT2:
         traffic on top of the B-independent weight streaming)."""
         c = self.config
         p = layer_params
-        h = _layer_norm(x, p["ln1_scale"], p["ln1_bias"], c.layer_norm_eps)
-        q, k, v = self._qkv(p, h)
-        # seq-major (L, S, B, H, hd): one CONTIGUOUS (T, B, H, hd) write
-        # per layer per token (see init_cache)
-        ck_all = jax.lax.dynamic_update_slice(
-            ck_all, k.swapaxes(0, 1)[None].astype(ck_all.dtype),
-            (layer, index, 0, 0, 0))
-        cv_all = jax.lax.dynamic_update_slice(
-            cv_all, v.swapaxes(0, 1)[None].astype(cv_all.dtype),
-            (layer, index, 0, 0, 0))
-        attn = self._attend_cached(q, ck_all[layer], cv_all[layer], index,
-                                   is_local, seq_major=True)
-        attn = self._mm(attn, p["proj_w"], p["proj_b"])
+        with jax.named_scope("attention"):
+            h = _layer_norm(x, p["ln1_scale"], p["ln1_bias"],
+                            c.layer_norm_eps)
+            q, k, v = self._qkv(p, h)
+            # seq-major (L, S, B, H, hd): one CONTIGUOUS (T, B, H, hd) write
+            # per layer per token (see init_cache)
+            ck_all = jax.lax.dynamic_update_slice(
+                ck_all, k.swapaxes(0, 1)[None].astype(ck_all.dtype),
+                (layer, index, 0, 0, 0))
+            cv_all = jax.lax.dynamic_update_slice(
+                cv_all, v.swapaxes(0, 1)[None].astype(cv_all.dtype),
+                (layer, index, 0, 0, 0))
+            attn = self._attend_cached(q, ck_all[layer], cv_all[layer],
+                                       index, is_local, seq_major=True)
+            attn = self._mm(attn, p["proj_w"], p["proj_b"])
         return self._ffn(p, x + attn), ck_all, cv_all
 
     def apply_with_cache(self, params, tokens, cache):
@@ -641,8 +645,9 @@ class GPT2:
 
         pos = index + jnp.arange(T)
         from ..module_inject.module_quantize import q_gather
-        x = q_gather(params["wte"], tokens, dtype) + \
-            q_gather(params["wpe"], pos, dtype)
+        with jax.named_scope("embed"):
+            x = q_gather(params["wte"], tokens, dtype) + \
+                q_gather(params["wpe"], pos, dtype)
 
         local_flags = jnp.arange(c.n_layer) % 2 == 1
 
@@ -667,15 +672,17 @@ class GPT2:
             (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
             (params["blocks"], local_flags))
 
-        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"], c.layer_norm_eps)
         # bf16 operands + fp32 accumulation: a pure-fp32 head matmul runs
         # at a fraction of MXU rate and is the only B-proportional flop
         # term in decode — it was the b=8 throughput ceiling.  Tied head:
         # wte used transposed (and possibly int8 — the vocab matmul is
         # ~31% of 125M weight bytes, the single biggest decode stream).
         from ..module_inject.module_quantize import q_matmul
-        logits = q_matmul(x, params["wte"], w_transposed=True,
-                          out_dtype=jnp.float32)
+        with jax.named_scope("lm_head"):
+            x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"],
+                            c.layer_norm_eps)
+            logits = q_matmul(x, params["wte"], w_transposed=True,
+                              out_dtype=jnp.float32)
         new_cache = {"k": new_k, "v": new_v, "index": index + T}
         return logits, new_cache
 
@@ -736,7 +743,8 @@ class GPT2:
         if fwd_len < bucket:
             pad = ((0, 0), (0, bucket - fwd_len), (0, 0), (0, 0))
             k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-        pool = pk.write_prefill(pool, blocks, k, v)
+        with jax.named_scope("kv.seat"):
+            pool = pk.write_prefill(pool, blocks, k, v)
         return logits[0, t_real - 1][None], pool
 
     def _attend_paged(self, q, keys, vals, lengths):
@@ -794,33 +802,38 @@ class GPT2:
         pos = jnp.minimum(
             lengths[:, None] + jnp.arange(W, dtype=lengths.dtype)[None, :],
             c.max_seq - 1)
-        x = q_gather(params["wte"], toks, self.dtype) + \
-            q_gather(params["wpe"], pos, self.dtype)    # (B, W, D)
+        with jax.named_scope("embed"):
+            x = q_gather(params["wte"], toks, self.dtype) + \
+                q_gather(params["wpe"], pos, self.dtype)    # (B, W, D)
 
         def body(carry, lp):
             h, pool, layer = carry
-            hn = _layer_norm(h, lp["ln1_scale"], lp["ln1_bias"],
-                             c.layer_norm_eps)
-            q, k, v = self._qkv(lp, hn)                 # (B, W, H, hd)
-            pool = pk.write_tokens(pool, layer, block_tables, lengths, k, v)
-            if impl == "kernel":
-                attn = paged_attention(q, pool, block_tables, lengths,
-                                       layer, scale_attn=c.scale_attn)
-            else:
-                keys, vals = pk.gather_kv(pool, layer, block_tables,
-                                          self.dtype, c.n_head)
-                attn = self._attend_paged(q, keys, vals, lengths)
-            attn = self._mm(attn, lp["proj_w"], lp["proj_b"])
+            with jax.named_scope("attention"):
+                hn = _layer_norm(h, lp["ln1_scale"], lp["ln1_bias"],
+                                 c.layer_norm_eps)
+                q, k, v = self._qkv(lp, hn)             # (B, W, H, hd)
+                with jax.named_scope("kv.seat"):
+                    pool = pk.write_tokens(pool, layer, block_tables,
+                                           lengths, k, v)
+                if impl == "kernel":
+                    attn = paged_attention(q, pool, block_tables, lengths,
+                                           layer, scale_attn=c.scale_attn)
+                else:
+                    keys, vals = pk.gather_kv(pool, layer, block_tables,
+                                              self.dtype, c.n_head)
+                    attn = self._attend_paged(q, keys, vals, lengths)
+                attn = self._mm(attn, lp["proj_w"], lp["proj_b"])
             return (self._ffn(lp, h + attn), pool, layer + 1), None
 
         (x, pool, _), _ = jax.lax.scan(
             body, (x, pool, jnp.zeros((), jnp.int32)), params["blocks"])
-        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"],
-                        c.layer_norm_eps)
-        if squeeze:
-            x = x[:, 0]
-        logits = q_matmul(x, params["wte"], w_transposed=True,
-                          out_dtype=jnp.float32)
+        with jax.named_scope("lm_head"):
+            x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"],
+                            c.layer_norm_eps)
+            if squeeze:
+                x = x[:, 0]
+            logits = q_matmul(x, params["wte"], w_transposed=True,
+                              out_dtype=jnp.float32)
         return logits, pool
 
     # ------------------------------------------------------------------ loss

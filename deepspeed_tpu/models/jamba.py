@@ -270,17 +270,19 @@ class Jamba:
 
     # ---------------------------------------------------------------- pieces
     def _mlp(self, p, h):
-        c = self.config
-        u = _rms(h, p["ln_ff"], c.rms_norm_eps)
-        return h + swiglu(p, u)
+        with jax.named_scope("mlp"):
+            c = self.config
+            u = _rms(h, p["ln_ff"], c.rms_norm_eps)
+            return h + swiglu(p, u)
 
     def _qkv(self, p, h):
-        c = self.config
-        u = _rms(h, p["ln_in"], c.rms_norm_eps)
-        lead = u.shape[:-1]
-        return (_mm(u, p["q_w"]).reshape(lead + (c.n_head, c.head_dim)),
-                _mm(u, p["k_w"]).reshape(lead + (c.n_kv_head, c.head_dim)),
-                _mm(u, p["v_w"]).reshape(lead + (c.n_kv_head, c.head_dim)))
+        with jax.named_scope("attention"):
+            c = self.config
+            u = _rms(h, p["ln_in"], c.rms_norm_eps)
+            lead = u.shape[:-1]
+            return (_mm(u, p["q_w"]).reshape(lead + (c.n_head, c.head_dim)),
+                    _mm(u, p["k_w"]).reshape(lead + (c.n_kv_head, c.head_dim)),
+                    _mm(u, p["v_w"]).reshape(lead + (c.n_kv_head, c.head_dim)))
 
     def _scan_inputs(self, p, h, tail):
         """A Mamba mixer up to the recurrence, for ``h`` (B, T, D) and the
@@ -288,20 +290,21 @@ class Jamba:
         z, delta, B, C, padded)``: the scan's operands, and the
         convolution's input with its tail in front (the next tail is cut
         out of it)."""
-        c = self.config
-        N, R = c.mamba_d_state, c.mamba_dt_rank
-        u = _rms(h, p["ln_in"], c.rms_norm_eps)
-        x, z = jnp.split(_mm(u, p["in_w"]), 2, axis=-1)
-        with jax.named_scope("ssm.conv"):
-            x, padded = ss.causal_conv(x, p["conv_w"], p["conv_b"], tail)
-            x = jax.nn.silu(x)
-        dt, Bm, Cm = jnp.split(_mm(x, p["x_w"]), [R, R + N], axis=-1)
-        dt = _rms(dt, p["dt_norm"], c.rms_norm_eps)
-        Bm = _rms(Bm.astype(jnp.float32), p["b_norm"], c.rms_norm_eps)
-        Cm = _rms(Cm.astype(jnp.float32), p["c_norm"], c.rms_norm_eps)
-        delta = jax.nn.softplus(_mm(dt, p["dt_w"]).astype(jnp.float32)
-                                + p["dt_b"].astype(jnp.float32))
-        return x, z, delta, Bm, Cm, padded
+        with jax.named_scope("ssm.proj"):
+            c = self.config
+            N, R = c.mamba_d_state, c.mamba_dt_rank
+            u = _rms(h, p["ln_in"], c.rms_norm_eps)
+            x, z = jnp.split(_mm(u, p["in_w"]), 2, axis=-1)
+            with jax.named_scope("ssm.conv"):
+                x, padded = ss.causal_conv(x, p["conv_w"], p["conv_b"], tail)
+                x = jax.nn.silu(x)
+            dt, Bm, Cm = jnp.split(_mm(x, p["x_w"]), [R, R + N], axis=-1)
+            dt = _rms(dt, p["dt_norm"], c.rms_norm_eps)
+            Bm = _rms(Bm.astype(jnp.float32), p["b_norm"], c.rms_norm_eps)
+            Cm = _rms(Cm.astype(jnp.float32), p["c_norm"], c.rms_norm_eps)
+            delta = jax.nn.softplus(_mm(dt, p["dt_w"]).astype(jnp.float32)
+                                    + p["dt_b"].astype(jnp.float32))
+            return x, z, delta, Bm, Cm, padded
 
     @staticmethod
     def _A(p):
@@ -322,7 +325,8 @@ class Jamba:
                                      h0=h0, impl=scan_impl)
         new_tail = ss.conv_tail_at(padded, T if t_real is None else t_real,
                                    c.mamba_d_conv - 1)
-        return h + _mm(y, p["out_w"]), new_tail, S
+        with jax.named_scope("ssm.proj"):
+            return h + _mm(y, p["out_w"]), new_tail, S
 
     def _layers(self, params, h, carry, mamba_fn, attn_fn, sliced=False):
         """``h`` through every layer.  ``mamba_fn(p, h, m, carry)`` and
@@ -356,10 +360,15 @@ class Jamba:
                 h, carry = jax.lax.fori_loop(0, l1 - l0, body, (h, carry))
         return h, carry
 
+    def _embed(self, params, tokens):
+        with jax.named_scope("embed"):
+            return params["wte"].astype(self.dtype)[tokens]
+
     def _head(self, params, h):
-        h = _rms(h, params["lnf"], self.config.rms_norm_eps)
-        return jnp.einsum("...d,vd->...v", h, params["wte"].astype(h.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            h = _rms(h, params["lnf"], self.config.rms_norm_eps)
+            return jnp.einsum("...d,vd->...v", h, params["wte"].astype(h.dtype),
+                              preferred_element_type=jnp.float32)
 
     # --------------------------------------------------------------- forward
     def apply(self, params, tokens, rng=None, deterministic=True,
@@ -369,14 +378,16 @@ class Jamba:
         passes: the kernel has no backward)."""
         T = tokens.shape[1]
         causal = jnp.tril(jnp.ones((T, T), bool))
-        h = params["wte"].astype(self.dtype)[tokens]
+        h = self._embed(params, tokens)
 
         def mamba_fn(p, h, m, carry):
             return self._mamba(p, h, scan_impl=scan_impl)[0], carry
 
         def attn_fn(p, h, a, carry):
             q, k, v = self._qkv(p, h)
-            return h + _mm(grouped_attention(q, k, v, causal), p["o_w"]), carry
+            with jax.named_scope("attention"):
+                return h + _mm(grouped_attention(q, k, v, causal),
+                               p["o_w"]), carry
 
         h, _ = self._layers(params, h, (), mamba_fn, attn_fn, sliced=True)
         if return_hidden:
@@ -417,7 +428,7 @@ class Jamba:
         index = cache["index"]
         S = cache["k"].shape[2]
         valid = (jnp.arange(S)[None, :] <= index + jnp.arange(T)[:, None])
-        h = params["wte"].astype(self.dtype)[tokens]
+        h = self._embed(params, tokens)
 
         def mamba_fn(p, h, m, carry):
             k, v, conv, ssm = carry
@@ -433,8 +444,9 @@ class Jamba:
                 k, kn[None].astype(k.dtype), (a, 0, index, 0, 0))
             v = jax.lax.dynamic_update_slice(
                 v, vn[None].astype(v.dtype), (a, 0, index, 0, 0))
-            out = grouped_attention(q, k[a], v[a], valid)
-            return h + _mm(out, p["o_w"]), (k, v, conv, ssm)
+            with jax.named_scope("attention"):
+                out = grouped_attention(q, k[a], v[a], valid)
+                return h + _mm(out, p["o_w"]), (k, v, conv, ssm)
 
         h, (k, v, conv, ssm) = self._layers(
             params, h, (cache["k"], cache["v"], cache["conv"], cache["ssm"]),
@@ -482,7 +494,7 @@ class Jamba:
         T = toks.shape[1]
         bucket = blocks.shape[0] * pool["k"].shape[2]
         causal = jnp.tril(jnp.ones((T, T), bool))
-        h = params["wte"].astype(self.dtype)[toks]
+        h = self._embed(params, toks)
 
         def mamba_fn(p, h, m, carry):
             pool, ks, vs = carry
@@ -498,16 +510,19 @@ class Jamba:
         def attn_fn(p, h, a, carry):
             pool, ks, vs = carry
             q, k, v = self._qkv(p, h)
-            out = grouped_attention(q, k, v, causal)
-            return h + _mm(out, p["o_w"]), (pool, ks + (k[0],), vs + (v[0],))
+            with jax.named_scope("attention"):
+                out = grouped_attention(q, k, v, causal)
+                return (h + _mm(out, p["o_w"]),
+                        (pool, ks + (k[0],), vs + (v[0],)))
 
         h, (pool, ks, vs) = self._layers(params, h, (pool, (), ()),
                                          mamba_fn, attn_fn)
-        k, v = jnp.stack(ks), jnp.stack(vs)            # (La, T, Hkv, hd)
-        if T < bucket:       # a bucket rounded past max_seq (GPT2 likewise)
-            pad = ((0, 0), (0, bucket - T), (0, 0), (0, 0))
-            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-        pool = pk.write_prefill(pool, blocks, k, v)
+        with jax.named_scope("kv.seat"):
+            k, v = jnp.stack(ks), jnp.stack(vs)        # (La, T, Hkv, hd)
+            if T < bucket:   # a bucket rounded past max_seq (GPT2 likewise)
+                pad = ((0, 0), (0, bucket - T), (0, 0), (0, 0))
+                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+            pool = pk.write_prefill(pool, blocks, k, v)
         row = jax.lax.dynamic_slice_in_dim(h[0], t_real - 1, 1, axis=0)
         return self._head(params, row), pool
 
@@ -524,7 +539,7 @@ class Jamba:
             "a recurrent state has no multi-token window to roll back"
         impl = self.paged_attention_impl()
         active = block_tables[:, 0] != pk.SCRATCH_BLOCK
-        h = params["wte"].astype(self.dtype)[toks][:, None]     # (B, 1, D)
+        h = self._embed(params, toks)[:, None]                  # (B, 1, D)
 
         def mamba_fn(p, h, m, pool):
             tail, S = pool["conv"][m], pool["ssm"][m]
@@ -537,20 +552,23 @@ class Jamba:
                 tail2 = jnp.where(active[:, None, None], padded[:, 1:], tail)
                 pool = dict(pool, conv=pool["conv"].at[m].set(tail2),
                             ssm=pool["ssm"].at[m].set(S2))
-            return h + _mm(y[:, None], p["out_w"]), pool
+            with jax.named_scope("ssm.proj"):
+                return h + _mm(y[:, None], p["out_w"]), pool
 
         def attn_fn(p, h, a, pool):
             q, k, v = self._qkv(p, h)                  # (B, 1, H | Hkv, hd)
-            pool = pk.write_tokens(pool, a, block_tables, lengths, k, v)
-            if impl == "kernel":
-                out = paged_attention(q, pool, block_tables, lengths, a)
-            else:
-                keys, vals = pk.gather_kv(pool, a, block_tables, self.dtype,
-                                          c.n_kv_head)
-                valid = (jnp.arange(keys.shape[1])[None, :]
-                         <= lengths[:, None])[:, None, None, None, :]
-                out = grouped_attention(q, keys, vals, valid)
-            return h + _mm(out, p["o_w"]), pool
+            with jax.named_scope("kv.seat"):
+                pool = pk.write_tokens(pool, a, block_tables, lengths, k, v)
+            with jax.named_scope("attention"):
+                if impl == "kernel":
+                    out = paged_attention(q, pool, block_tables, lengths, a)
+                else:
+                    keys, vals = pk.gather_kv(pool, a, block_tables,
+                                              self.dtype, c.n_kv_head)
+                    valid = (jnp.arange(keys.shape[1])[None, :]
+                             <= lengths[:, None])[:, None, None, None, :]
+                    out = grouped_attention(q, keys, vals, valid)
+                return h + _mm(out, p["o_w"]), pool
 
         h, pool = self._layers(params, h, pool, mamba_fn, attn_fn)
         return self._head(params, h[:, 0]), pool

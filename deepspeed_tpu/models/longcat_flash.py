@@ -339,14 +339,15 @@ class LongcatFlash:
             for s in (0, 1):
                 i = 2 * l + s
                 p = _take(pa, i)
-                a = _rms(h, p["ln_in"], eps).astype(self.dtype)
-                out, carry = attn_fn(p, *self._mla.project(p, a, positions),
-                                     i, carry)
-                h = h + _mm(out, p["o_w"]).astype(f32)
-                u = _rms(h, p["ln_ff"], eps)
+                with jax.named_scope("attention"):
+                    a = _rms(h, p["ln_in"], eps).astype(self.dtype)
+                    out, carry = attn_fn(
+                        p, *self._mla.project(p, a, positions), i, carry)
+                    h = h + _mm(out, p["o_w"]).astype(f32)
+                    u = _rms(h, p["ln_ff"], eps)
                 if s == 0:
                     m, n, experts = self._moe(pm, u, layer=l, live=live)
-                with jax.named_scope("dense.ffn"):
+                with jax.named_scope("mlp"):
                     h = h + swiglu(_take(pd, i),
                                    u.astype(self.dtype)).astype(f32)
                 if s == 1:          # the shortcut: held back until here
@@ -364,14 +365,16 @@ class LongcatFlash:
              routes if with_routes else None))
 
     def _embed(self, params, tokens):
-        return params["wte"][tokens - self.config.vocab_rows[0]].astype(
-            jnp.float32)
+        with jax.named_scope("embed"):
+            return params["wte"][tokens - self.config.vocab_rows[0]].astype(
+                jnp.float32)
 
     def _head(self, params, h):
-        h = _rms(h, params["lnf"], self.config.rms_norm_eps)
-        return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
-                          params["head"].astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            h = _rms(h, params["lnf"], self.config.rms_norm_eps)
+            return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
+                              params["head"].astype(self.dtype),
+                              preferred_element_type=jnp.float32)
 
     # --------------------------------------------------------------- forward
     def apply(self, params, tokens, rng=None, deterministic=True,

@@ -183,8 +183,9 @@ class LatentAttention:
         from ..ops.transformer.paged_latent_attention import (
             paged_latent_attention)
         width = pool[pk.LATENT].shape[-1]
-        pool = pk.write_latent_tokens(pool, layer, block_tables, lengths,
-                                      pk.latent_rows(c_kv, k_pe, width))
+        with jax.named_scope("kv.seat"):
+            pool = pk.write_latent_tokens(pool, layer, block_tables, lengths,
+                                          pk.latent_rows(c_kv, k_pe, width))
         q_rows = self.absorb(p, q_nope[:, 0], q_pe[:, 0], width)
         if impl == "kernel":
             with jax.named_scope("mla.attend"):

@@ -354,28 +354,30 @@ class NemotronH:
         Returns ``(x (B, T, H, P), z (B, T, Di), dt (B, T, H) float32, B, C
         (B, T, G, N), padded)``: the recurrence's operands, and the
         convolution's input with its tail in front."""
-        c = self.config
-        Di, G, N = c.d_inner, c.n_groups, c.ssm_state_size
-        z, xBC, dt = jnp.split(_mm(self._norm(p, h), p["in_w"]),
-                               [Di, Di + c.conv_dim], axis=-1)
-        with jax.named_scope("ssm.conv"):
-            xBC, padded = m2.causal_conv(xBC, p["conv_w"], p["conv_b"], tail)
-            xBC = jax.nn.silu(xBC)
-        x, Bm, Cm = jnp.split(xBC, [Di, Di + G * N], axis=-1)
-        lead = x.shape[:-1]
-        dt = jax.nn.softplus(dt.astype(jnp.float32)
-                             + p["dt_bias"].astype(jnp.float32))
-        return (x.reshape(lead + (c.mamba_num_heads, c.mamba_head_dim)), z,
-                dt, Bm.reshape(lead + (G, N)), Cm.reshape(lead + (G, N)),
-                padded)
+        with jax.named_scope("ssm.proj"):
+            c = self.config
+            Di, G, N = c.d_inner, c.n_groups, c.ssm_state_size
+            z, xBC, dt = jnp.split(_mm(self._norm(p, h), p["in_w"]),
+                                   [Di, Di + c.conv_dim], axis=-1)
+            with jax.named_scope("ssm.conv"):
+                xBC, padded = m2.causal_conv(xBC, p["conv_w"], p["conv_b"], tail)
+                xBC = jax.nn.silu(xBC)
+            x, Bm, Cm = jnp.split(xBC, [Di, Di + G * N], axis=-1)
+            lead = x.shape[:-1]
+            dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                 + p["dt_bias"].astype(jnp.float32))
+            return (x.reshape(lead + (c.mamba_num_heads, c.mamba_head_dim)), z,
+                    dt, Bm.reshape(lead + (G, N)), Cm.reshape(lead + (G, N)),
+                    padded)
 
     def _scan_output(self, p, y, z):
         """From the recurrence's ``y`` (..., H, P) on: the grouped gated norm
         and ``out_proj``."""
-        c = self.config
-        y = m2.gated_group_norm(y.reshape(z.shape), z, p["norm_w"],
-                                c.n_groups, c.layer_norm_epsilon)
-        return _mm(y, p["out_w"]).astype(jnp.float32)
+        with jax.named_scope("ssm.proj"):
+            c = self.config
+            y = m2.gated_group_norm(y.reshape(z.shape), z, p["norm_w"],
+                                    c.n_groups, c.layer_norm_epsilon)
+            return _mm(y, p["out_w"]).astype(jnp.float32)
 
     @staticmethod
     def _A(p):
@@ -457,14 +459,16 @@ class NemotronH:
         return h, carry, counts, (jnp.stack(routes) if routes else None)
 
     def _embed(self, params, tokens):
-        return params["wte"][tokens - self.config.vocab_rows[0]].astype(
-            jnp.float32)
+        with jax.named_scope("embed"):
+            return params["wte"][tokens - self.config.vocab_rows[0]].astype(
+                jnp.float32)
 
     def _head(self, params, h):
-        h = _rms(h, params["lnf"], self.config.layer_norm_epsilon)
-        return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
-                          params["head"].astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            h = _rms(h, params["lnf"], self.config.layer_norm_epsilon)
+            return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
+                              params["head"].astype(self.dtype),
+                              preferred_element_type=jnp.float32)
 
     # --------------------------------------------------------------- forward
     def apply(self, params, tokens, rng=None, deterministic=True,
@@ -476,9 +480,10 @@ class NemotronH:
             return self._mamba(p, h, impl=scan_impl)[0], carry
 
         def attn_fn(p, h, a, carry):
-            q, k, v = self._qkv(p, h)
-            out = causal_prompt_attention(q, k, v)
-            return h + _mm(out, p["o_w"]).astype(jnp.float32), carry
+            with jax.named_scope("attention"):
+                q, k, v = self._qkv(p, h)
+                out = causal_prompt_attention(q, k, v)
+                return h + _mm(out, p["o_w"]).astype(jnp.float32), carry
 
         h, _, _, _ = self._layers(params, self._embed(params, tokens), (),
                                   mamba_fn, attn_fn)
@@ -543,15 +548,16 @@ class NemotronH:
                        ssm.at[m].set(state))
 
         def attn_fn(p, h, a, carry):
-            k, v, conv, ssm = carry
-            q, kn, vn = self._qkv(p, h)
-            k = jax.lax.dynamic_update_slice(
-                k, kn[None].astype(k.dtype), (a, 0, index, 0, 0))
-            v = jax.lax.dynamic_update_slice(
-                v, vn[None].astype(v.dtype), (a, 0, index, 0, 0))
-            out = grouped_attention(q, k[a], v[a], valid)
-            return h + _mm(out, p["o_w"]).astype(jnp.float32), (k, v, conv,
-                                                                ssm)
+            with jax.named_scope("attention"):
+                k, v, conv, ssm = carry
+                q, kn, vn = self._qkv(p, h)
+                k = jax.lax.dynamic_update_slice(
+                    k, kn[None].astype(k.dtype), (a, 0, index, 0, 0))
+                v = jax.lax.dynamic_update_slice(
+                    v, vn[None].astype(v.dtype), (a, 0, index, 0, 0))
+                out = grouped_attention(q, k[a], v[a], valid)
+                return h + _mm(out, p["o_w"]).astype(jnp.float32), (k, v, conv,
+                                                                    ssm)
 
         h, (k, v, conv, ssm), _, _ = self._layers(
             params, self._embed(params, tokens),
@@ -636,20 +642,22 @@ class NemotronH:
             return h, (pool, ks, vs)
 
         def attn_fn(p, h, a, carry):
-            pool, ks, vs = carry
-            q, k, v = self._qkv(p, h)
-            out = causal_prompt_attention(q, k, v)
-            return (h + _mm(out, p["o_w"]).astype(jnp.float32),
-                    (pool, ks + (k[0],), vs + (v[0],)))
+            with jax.named_scope("attention"):
+                pool, ks, vs = carry
+                q, k, v = self._qkv(p, h)
+                out = causal_prompt_attention(q, k, v)
+                return (h + _mm(out, p["o_w"]).astype(jnp.float32),
+                        (pool, ks + (k[0],), vs + (v[0],)))
 
         h, (pool, ks, vs), counts, _ = self._layers(
             params, self._embed(params, toks), (pool, (), ()), mamba_fn,
             attn_fn, live=(jnp.arange(T) < t_real)[None])
-        k, v = jnp.stack(ks), jnp.stack(vs)            # (La, T, Hkv, hd)
-        if T < bucket:       # a bucket rounded past max_seq (GPT2 likewise)
-            pad = ((0, 0), (0, bucket - T), (0, 0), (0, 0))
-            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-        pool = pk.write_prefill(pool, blocks, k, v)
+        with jax.named_scope("kv.seat"):
+            k, v = jnp.stack(ks), jnp.stack(vs)        # (La, T, Hkv, hd)
+            if T < bucket:   # a bucket rounded past max_seq (GPT2 likewise)
+                pad = ((0, 0), (0, bucket - T), (0, 0), (0, 0))
+                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+            pool = pk.write_prefill(pool, blocks, k, v)
         row = jax.lax.dynamic_slice_in_dim(h[0], t_real - 1, 1, axis=0)
         return self._head(params, row), dict(pool, counters=counts)
 
@@ -681,17 +689,20 @@ class NemotronH:
             return h + self._scan_output(p, y[:, None], z), pool
 
         def attn_fn(p, h, a, pool):
-            q, k, v = self._qkv(p, h)                  # (B, 1, H | Hkv, hd)
-            pool = pk.write_tokens(pool, a, block_tables, lengths, k, v)
-            if impl == "kernel":
-                out = paged_attention(q, pool, block_tables, lengths, a)
-            else:
-                keys, vals = pk.gather_kv(pool, a, block_tables, self.dtype,
-                                          c.n_kv_head)
-                valid = (jnp.arange(keys.shape[1])[None, :]
-                         <= lengths[:, None])[:, None, None, None, :]
-                out = grouped_attention(q, keys, vals, valid)
-            return h + _mm(out, p["o_w"]).astype(jnp.float32), pool
+            with jax.named_scope("attention"):
+                q, k, v = self._qkv(p, h)                  # (B, 1, H | Hkv, hd)
+                with jax.named_scope("kv.seat"):
+                    pool = pk.write_tokens(pool, a, block_tables, lengths, k,
+                                           v)
+                if impl == "kernel":
+                    out = paged_attention(q, pool, block_tables, lengths, a)
+                else:
+                    keys, vals = pk.gather_kv(pool, a, block_tables, self.dtype,
+                                              c.n_kv_head)
+                    valid = (jnp.arange(keys.shape[1])[None, :]
+                             <= lengths[:, None])[:, None, None, None, :]
+                    out = grouped_attention(q, keys, vals, valid)
+                return h + _mm(out, p["o_w"]).astype(jnp.float32), pool
 
         h, pool, counts, routes = self._layers(
             params, self._embed(params, toks)[:, None], pool, mamba_fn,

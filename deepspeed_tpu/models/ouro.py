@@ -209,20 +209,23 @@ class Ouro:
         c = self.config
         eps = c.rms_norm_eps
         f32 = jnp.float32
-        u = _rms(h, p["ln_in"], eps).astype(self.dtype)
-        lead = u.shape[:-1]
-        cos, sin = self._rope
-        q = apply_rotary_pos_emb(
-            _mmt(u, p["q_w"]).reshape(lead + (c.n_head, c.head_dim)),
-            cos, sin, positions)
-        k = apply_rotary_pos_emb(
-            _mmt(u, p["k_w"]).reshape(lead + (c.n_kv_head, c.head_dim)),
-            cos, sin, positions)
-        v = _mm(u, p["v_w"]).reshape(lead + (c.n_kv_head, c.head_dim))
-        out, carry = attend(q, k, v)
-        h = h + _rms(_mm(out, p["o_w"]).astype(f32), p["ln_attn_out"], eps)
-        u = _rms(h, p["ln_ff"], eps).astype(self.dtype)
-        h = h + _rms(swiglu(p, u).astype(f32), p["ln_ff_out"], eps)
+        with jax.named_scope("attention"):
+            u = _rms(h, p["ln_in"], eps).astype(self.dtype)
+            lead = u.shape[:-1]
+            cos, sin = self._rope
+            q = apply_rotary_pos_emb(
+                _mmt(u, p["q_w"]).reshape(lead + (c.n_head, c.head_dim)),
+                cos, sin, positions)
+            k = apply_rotary_pos_emb(
+                _mmt(u, p["k_w"]).reshape(lead + (c.n_kv_head, c.head_dim)),
+                cos, sin, positions)
+            v = _mm(u, p["v_w"]).reshape(lead + (c.n_kv_head, c.head_dim))
+            out, carry = attend(q, k, v)
+            h = h + _rms(_mm(out, p["o_w"]).astype(f32), p["ln_attn_out"],
+                         eps)
+        with jax.named_scope("mlp"):
+            u = _rms(h, p["ln_ff"], eps).astype(self.dtype)
+            h = h + _rms(swiglu(p, u).astype(f32), p["ln_ff_out"], eps)
         return h, carry
 
     def _loops(self, params, h, carry, positions, attn_fn, sliced=False,
@@ -267,12 +270,14 @@ class Ouro:
 
     @staticmethod
     def _embed(params, tokens):
-        return params["wte"][tokens].astype(jnp.float32)
+        with jax.named_scope("embed"):
+            return params["wte"][tokens].astype(jnp.float32)
 
     def _head(self, params, h):
-        return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
-                          params["head"].astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            return jnp.einsum("...d,vd->...v", h.astype(self.dtype),
+                              params["head"].astype(self.dtype),
+                              preferred_element_type=jnp.float32)
 
     # --------------------------------------------------------------- forward
     def apply(self, params, tokens, rng=None, deterministic=True,
@@ -398,7 +403,8 @@ class Ouro:
         h = self._embed(params, toks)                           # (B, W, D)
 
         def attn_fn(q, k, v, i, pool):
-            pool = pk.write_tokens(pool, i, block_tables, lengths, k, v)
+            with jax.named_scope("kv.seat"):
+                pool = pk.write_tokens(pool, i, block_tables, lengths, k, v)
             if impl == "kernel":
                 return paged_attention(q, pool, block_tables, lengths, i), pool
             keys, vals = pk.gather_kv(pool, i, block_tables, self.dtype,

@@ -379,24 +379,26 @@ class Phi4Flash:
                    self.config.layer_norm_eps).astype(self.dtype)
 
     def _mlp(self, p, h):
-        g, y = jnp.split(_mm(self._norm(p, h), p["fc1_w"]), 2, axis=-1)
-        return h + _mm(jax.nn.silu(g) * y, p["fc2_w"]).astype(jnp.float32)
+        with jax.named_scope("mlp"):
+            g, y = jnp.split(_mm(self._norm(p, h), p["fc1_w"]), 2, axis=-1)
+            return h + _mm(jax.nn.silu(g) * y, p["fc2_w"]).astype(jnp.float32)
 
     def _scan_inputs(self, p, h, tail):
         """A Mamba mixer up to the recurrence, for ``h`` (B, T, D) and the
         convolution's incoming ``tail`` (B, K-1, Di) or None.  Returns ``(x,
         z, delta, B, C, padded)`` as ``Jamba._scan_inputs`` (whose three inner
         norms are absent here)."""
-        c = self.config
-        N, R = c.mamba_d_state, c.mamba_dt_rank
-        x, z = jnp.split(_mm(self._norm(p, h), p["in_w"]), 2, axis=-1)
-        with jax.named_scope("ssm.conv"):
-            x, padded = ss.causal_conv(x, p["conv_w"], p["conv_b"], tail)
-            x = jax.nn.silu(x)
-        dt, Bm, Cm = jnp.split(_mm(x, p["x_w"]), [R, R + N], axis=-1)
-        delta = jax.nn.softplus(_mm(dt, p["dt_w"]).astype(jnp.float32)
-                                + p["dt_b"].astype(jnp.float32))
-        return x, z, delta, Bm, Cm, padded
+        with jax.named_scope("ssm.proj"):
+            c = self.config
+            N, R = c.mamba_d_state, c.mamba_dt_rank
+            x, z = jnp.split(_mm(self._norm(p, h), p["in_w"]), 2, axis=-1)
+            with jax.named_scope("ssm.conv"):
+                x, padded = ss.causal_conv(x, p["conv_w"], p["conv_b"], tail)
+                x = jax.nn.silu(x)
+            dt, Bm, Cm = jnp.split(_mm(x, p["x_w"]), [R, R + N], axis=-1)
+            delta = jax.nn.softplus(_mm(dt, p["dt_w"]).astype(jnp.float32)
+                                    + p["dt_b"].astype(jnp.float32))
+            return x, z, delta, Bm, Cm, padded
 
     @staticmethod
     def _A(p):
@@ -406,9 +408,10 @@ class Phi4Flash:
         """The mixer's gate, projection and residual.  ``y`` is gated when
         the scan was not asked to ``keep`` it: then it went in without ``z``
         and comes back as ``m``, gated here."""
-        out = y * jax.nn.silu(z) if keep else y
-        return h + _mm(out, p["out_w"]).astype(jnp.float32), \
-            (y if keep else None)
+        with jax.named_scope("ssm.proj"):
+            out = y * jax.nn.silu(z) if keep else y
+            return h + _mm(out, p["out_w"]).astype(jnp.float32), \
+                (y if keep else None)
 
     def _mamba(self, p, h, tail=None, h0=None, t_real=None,
                scan_impl="auto", keep=False):
@@ -491,15 +494,16 @@ class Phi4Flash:
         mlp = lambda l, h: self._mlp(_take(params["mlp"], l), h)
 
         def attn(a, l, window, h, carry, row=None):
-            p = _take(params["attn"], a)
-            u = self._norm(p, h)
-            if row is None:
-                q, k, v = self._qkv(p, u)
-            else:
-                q, k, v = self._qkv(p, u, row(u))
-                h = row(h)
-            out, carry = attn_fn(q, k, v, a, window, carry)
-            return h + self._combine(p, out, l), carry
+            with jax.named_scope("attention"):
+                p = _take(params["attn"], a)
+                u = self._norm(p, h)
+                if row is None:
+                    q, k, v = self._qkv(p, u)
+                else:
+                    q, k, v = self._qkv(p, u, row(u))
+                    h = row(h)
+                out, carry = attn_fn(q, k, v, a, window, carry)
+                return h + self._combine(p, out, l), carry
 
         def pair(i, hc):
             h, carry = hc
@@ -533,20 +537,23 @@ class Phi4Flash:
                 h = h + _mm(m * gate, p["out_w"]).astype(jnp.float32)
             h = mlp(l0 + 2 * j, h)
             p = _take(params["cross"], j)
-            out = cross_fn(self._cross_q(p, self._norm(p, h)))
-            h = h + self._combine(p, out, l0 + 2 * j + 1)
+            with jax.named_scope("attention"):
+                out = cross_fn(self._cross_q(p, self._norm(p, h)))
+                h = h + self._combine(p, out, l0 + 2 * j + 1)
             return mlp(l0 + 2 * j + 1, h)
         return jax.lax.fori_loop(0, c.n_cross_layer, pair, h)
 
     def _embed(self, params, tokens):
-        return params["wte"][tokens].astype(jnp.float32)
+        with jax.named_scope("embed"):
+            return params["wte"][tokens].astype(jnp.float32)
 
     def _head(self, params, h):
-        c = self.config
-        h = _ln(h, params["lnf_w"], params["lnf_b"],
-                c.layer_norm_eps).astype(self.dtype)
-        return jnp.einsum("...d,vd->...v", h, params["wte"].astype(h.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            c = self.config
+            h = _ln(h, params["lnf_w"], params["lnf_b"],
+                    c.layer_norm_eps).astype(self.dtype)
+            return jnp.einsum("...d,vd->...v", h, params["wte"].astype(h.dtype),
+                              preferred_element_type=jnp.float32)
 
     def _counts(self, n_self, n_cross):
         c = self.config
@@ -817,11 +824,13 @@ class Phi4Flash:
 
         def attn_fn(q, k, v, a, window, pool):
             if window is None:
-                pool = pk.write_tokens(pool, 0, table, lengths, k, v)
+                with jax.named_scope("kv.seat"):
+                    pool = pk.write_tokens(pool, 0, table, lengths, k, v)
                 with jax.named_scope("attn.shared"):
                     return attend(q, pool, table, 0, None), pool
-            view = pk.write_tokens(pk.window_view(pool), a, ring_table,
-                                   lengths, k, v, ring=True)
+            with jax.named_scope("kv.seat"):
+                view = pk.write_tokens(pk.window_view(pool), a, ring_table,
+                                       lengths, k, v, ring=True)
             with jax.named_scope("attn.window"):
                 out = attend(q, view, ring_table, a, window)
             return out, pk.with_window(pool, view)

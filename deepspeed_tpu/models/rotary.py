@@ -13,6 +13,7 @@ Two layouts exist in the wild:
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 
@@ -63,18 +64,19 @@ def apply_rotary_pos_emb(x, cos, sin, positions, neox_style=True):
 
     x: (B, T, H, d); positions: (T,) or (B, T) absolute positions.
     """
-    r2 = cos.shape[-1]          # rotary_dim / 2
-    rot, rest = x[..., :2 * r2], x[..., 2 * r2:]
-    c = cos[positions][..., None, :].astype(x.dtype)   # (.., T, 1, r2)
-    s = sin[positions][..., None, :].astype(x.dtype)
-    if c.ndim == 3:             # positions was (T,): add batch axis
-        c, s = c[None], s[None]
-    if neox_style:
-        x1, x2 = rot[..., :r2], rot[..., r2:]
-        out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    else:
-        x1, x2 = rot[..., 0::2], rot[..., 1::2]
-        o1 = x1 * c - x2 * s
-        o2 = x2 * c + x1 * s
-        out = jnp.stack([o1, o2], axis=-1).reshape(rot.shape)
-    return jnp.concatenate([out, rest], axis=-1) if rest.shape[-1] else out
+    with jax.named_scope("rope"):
+        r2 = cos.shape[-1]          # rotary_dim / 2
+        rot, rest = x[..., :2 * r2], x[..., 2 * r2:]
+        c = cos[positions][..., None, :].astype(x.dtype)   # (.., T, 1, r2)
+        s = sin[positions][..., None, :].astype(x.dtype)
+        if c.ndim == 3:             # positions was (T,): add batch axis
+            c, s = c[None], s[None]
+        if neox_style:
+            x1, x2 = rot[..., :r2], rot[..., r2:]
+            out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+        else:
+            x1, x2 = rot[..., 0::2], rot[..., 1::2]
+            o1 = x1 * c - x2 * s
+            o2 = x2 * c + x1 * s
+            out = jnp.stack([o1, o2], axis=-1).reshape(rot.shape)
+        return jnp.concatenate([out, rest], axis=-1) if rest.shape[-1] else out

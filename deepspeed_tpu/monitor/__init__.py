@@ -50,6 +50,7 @@ from .sinks import (Sink, JSONLSink, CSVSink, RingBufferSink,
                     TensorboardSink, SinkUnavailable, EVENTS_FILE,
                     stream_segments)
 from .core import Monitor, NullMonitor, from_config
+from .scope_maps import device_scopes
 from .slo import (Objective, SentinelConfig, SLOConfig, SLOEvaluator,
                   RegressionSentinel)
 
@@ -60,5 +61,5 @@ __all__ = [
     "SinkUnavailable", "EVENTS_FILE", "stream_segments",
     "Monitor", "NullMonitor", "from_config",
     "Objective", "SentinelConfig", "SLOConfig", "SLOEvaluator",
-    "RegressionSentinel",
+    "RegressionSentinel", "device_scopes",
 ]

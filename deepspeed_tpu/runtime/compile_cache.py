@@ -59,11 +59,12 @@ import jax
 import jax.numpy as jnp
 
 from ..checkpoint import atomic
-from ..monitor import spans
+from ..monitor import scope_maps, spans
 from ..utils.logging import logger, log_dist
 
 PAYLOAD_FILE = "payload.bin"
 KEY_FILE = "key_anatomy.json"
+SCOPES_FILE = "device_scopes.json"
 STATS_FILE = "last_run_stats.json"
 FORMAT_VERSION = 1
 ENV_DIR = "DSTPU_COMPILE_CACHE"
@@ -209,6 +210,11 @@ def build_key_material(name, args, lowered, key_extra=None, kwargs=None):
         "dstpu205_weak_scalars": weak_scalars,
         "config": key_extra or {},
         "lowering_sha256": hashlib.sha256(low_text.encode()).hexdigest(),
+        # the entry holds the executable's map of device scopes, and scopes
+        # are ``op_name`` metadata the lowering's hash does not see: an
+        # entry written under other rules (or by a commit that kept no
+        # map) is not this program's
+        "device_scopes": scope_maps.VERSION,
     }
     return material
 
@@ -231,7 +237,7 @@ class CompileCache:
     """Content-addressed on-disk store of serialized compiled executables.
 
     Entry layout: ``<dir>/<key>/{payload.bin, key_anatomy.json,
-    manifest.json}``, committed via the atomic stage/manifest/rename
+    device_scopes.json, manifest.json}``, committed via the atomic stage/manifest/rename
     protocol and validated (SHA-256) on every read.  ``readonly=True``
     serves a shared CI cache: reads verify and deserialize, but nothing
     is written, touched, or evicted.
@@ -283,9 +289,22 @@ class CompileCache:
         self._touch(path)
         return payload
 
-    def put(self, key, payload, meta=None):
+    def scopes(self, key):
+        """``(module, {instruction: scope})`` as stored beside entry
+        ``key``'s payload when it was built (``SCOPES_FILE``), or None for
+        an entry that holds none (an older store's)."""
+        try:
+            with open(os.path.join(self._entry_dir(key), SCOPES_FILE)) as f:
+                got = json.load(f)
+            return got["module"], got["instructions"]
+        except (OSError, ValueError, KeyError):
+            return None
+
+    def put(self, key, payload, meta=None, scopes=None):
         """Atomically commit an entry; returns True on success.  Failures
-        (disk full, permissions, races) degrade to not-cached.
+        (disk full, permissions, races) degrade to not-cached.  ``scopes``:
+        the executable's ``(module, {instruction: scope})``, kept as
+        ``SCOPES_FILE`` so that a warm start reads it and renders no HLO.
 
         Staging is PER-PROCESS (``<key>.<pid>.tmp``): the cache is shared
         by design, and two workers compiling the same program must not
@@ -305,6 +324,11 @@ class CompileCache:
                 # key anatomy beside the payload, not a metric stream
                 json.dump(meta or {}, f, indent=2,  # dstpu: disable=DSTPU104
                           sort_keys=True, default=str)
+            if scopes is not None:
+                with open(os.path.join(staged, SCOPES_FILE), "w") as f:
+                    json.dump(  # dstpu: disable=DSTPU104
+                        {"module": scopes[0], "instructions": scopes[1]}, f,
+                        separators=(",", ":"))
             atomic.write_manifest(staged, meta={
                 "key": key, "format_version": FORMAT_VERSION,
                 "payload_bytes": len(payload)})
@@ -617,6 +641,8 @@ class CachedStep:
                                f"{key[:16]} ({type(e).__name__}: {e}); "
                                "falling back to a fresh compile")
                 return None
+            _note_device_scopes(self.name, exe, cache.scopes(key),
+                                loaded=True)
             self._note(span, exe, args)
         ms = (span.t1 - span.t0) * 1000              # read + deserialise
         cache._count("hits")
@@ -628,6 +654,8 @@ class CachedStep:
 
     def _try_serialize(self, cache, key, compiled, material):
         from jax.experimental import serialize_executable as se
+        # here and not in ``_acquire``, whose frame keeps its size (D13)
+        scopes = _note_device_scopes(self.name, compiled)
         try:
             ser, in_tree, out_tree = se.serialize(compiled)
             payload = pickle.dumps((ser, in_tree, out_tree))
@@ -639,7 +667,33 @@ class CachedStep:
                            f"{self.name} ({type(e).__name__}: {e}); "
                            "entry not persisted")
             return
-        cache.put(key, payload, meta=material)
+        cache.put(key, payload, meta=material, scopes=scopes)
+
+
+def _note_device_scopes(name, exe, stored=None, loaded=False):
+    """Hand ``monitor.device_scopes()`` the executable's map from device
+    instruction to ``jax.named_scope``.  Read off the HLO text where the
+    executable was BUILT; a load (``loaded``) hands on what its entry
+    ``stored`` and renders nothing, and where the entry holds none (an
+    older store's) the text is read when the maps are first asked for.  Returns
+    the map, or None where it could not be read: a reading, never a reason
+    to fail."""
+    def read():
+        from ..analysis.hlo_scopes import scope_map
+        try:
+            return scope_map(exe.as_text(), scope_maps.VOCABULARY,
+                             scope_maps.CONTAINERS)
+        except Exception as e:
+            logger.warning(f"{name}: device scopes not read ({e})")
+            return None
+
+    if loaded and stored is None:
+        scope_maps.note_later(read)
+        return None
+    got = stored or read()
+    if got is not None:
+        scope_maps.note(*got)
+    return got
 
 
 def _split_lower(rec, span):

@@ -1163,7 +1163,9 @@ class DeepSpeedEngine:
                         wire_nf, jnp.full(g.shape, jnp.nan, g.dtype), g),
                     grads)
         if self.config.gradient_clipping > 0:
-            grads, gnorm = clip_by_global_norm(grads, self.config.gradient_clipping)
+            with jax.named_scope("optimizer"):
+                grads, gnorm = clip_by_global_norm(
+                    grads, self.config.gradient_clipping)
         else:
             gnorm = global_norm(grads)
         # ZeRO-2: constrain grads to fsdp sharding → reduce-scatter
@@ -1224,10 +1226,13 @@ class DeepSpeedEngine:
             metrics.update(sm)
         else:
             skip, new_health = overflow, state.health
-        new_base, new_opt = self.optimizer.update(
-            grads, state.opt_state, base, step=state.optimizer_steps + 1, lr=lr)
-        new_base = zpart.constrain(new_base, self._master_specs if needs_master
-                                   else self._param_specs, self.mesh)
+        with jax.named_scope("optimizer"):
+            new_base, new_opt = self.optimizer.update(
+                grads, state.opt_state, base, step=state.optimizer_steps + 1,
+                lr=lr)
+            new_base = zpart.constrain(
+                new_base, self._master_specs if needs_master
+                else self._param_specs, self.mesh)
 
         if self._health_enabled and self._health_cfg.skip_nonfinite:
             # optimizer-minted non-finites (e.g. an Inf moment) are caught
@@ -1245,8 +1250,9 @@ class DeepSpeedEngine:
             # path, engine.py:1819-1871 — extended beyond fp16)
             sel = lambda new, old: jax.tree_util.tree_map(
                 lambda n, o: jnp.where(skip, o, n), new, old)
-            new_base = sel(new_base, base)
-            new_opt = sel(new_opt, state.opt_state)
+            with jax.named_scope("optimizer"):
+                new_base = sel(new_base, base)
+                new_opt = sel(new_opt, state.opt_state)
             if new_ef is not None:
                 # error feedback computed from a skipped step's garbage
                 # gradients must not poison future compensation
@@ -1265,8 +1271,9 @@ class DeepSpeedEngine:
             new_scale = state.scale
 
         if needs_master:
-            new_params = zpart.constrain(tree_cast(new_base, dtype),
-                                         self._param_specs, self.mesh)
+            with jax.named_scope("optimizer"):
+                new_params = zpart.constrain(tree_cast(new_base, dtype),
+                                             self._param_specs, self.mesh)
             new_master = new_base
         else:
             new_params = new_base

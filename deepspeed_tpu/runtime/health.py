@@ -82,14 +82,15 @@ def tree_nonfinite(tree) -> jnp.ndarray:
     scan into the step for free under SPMD (sharded leaves reduce
     cross-device automatically).
     """
-    leaves = [l for l in jax.tree_util.tree_leaves(tree)
-              if jnp.issubdtype(jnp.result_type(l), jnp.inexact)]
-    if not leaves:
-        return jnp.asarray(False)
-    out = jnp.asarray(False)
-    for l in leaves:
-        out = jnp.logical_or(out, jnp.logical_not(jnp.all(jnp.isfinite(l))))
-    return out
+    with jax.named_scope("sentinel"):
+        leaves = [l for l in jax.tree_util.tree_leaves(tree)
+                  if jnp.issubdtype(jnp.result_type(l), jnp.inexact)]
+        if not leaves:
+            return jnp.asarray(False)
+        out = jnp.asarray(False)
+        for l in leaves:
+            out = jnp.logical_or(out, jnp.logical_not(jnp.all(jnp.isfinite(l))))
+        return out
 
 
 def rows_nonfinite(x, axis=-1) -> jnp.ndarray:
@@ -101,7 +102,8 @@ def rows_nonfinite(x, axis=-1) -> jnp.ndarray:
     callback), so a poisoned request is detected in-graph and its
     sampling branchlessly forced to a sentinel while neighbors' rows are
     untouched (docs/serving.md#resilience)."""
-    return jnp.logical_not(jnp.all(jnp.isfinite(x), axis=axis))
+    with jax.named_scope("sentinel"):
+        return jnp.logical_not(jnp.all(jnp.isfinite(x), axis=axis))
 
 
 def update_ema(state: HealthState, loss, *, window: int,

@@ -222,17 +222,6 @@ def test_weight_quantization_class():
     assert err < np.abs(np.asarray(params["w"])).max() / 50
 
 
-def test_instrument_w_nvtx_passthrough():
-    from deepspeed_tpu.utils.nvtx import instrument_w_nvtx
-    import jax.numpy as jnp
-
-    @instrument_w_nvtx
-    def f(x):
-        return x * 2
-
-    assert float(f(jnp.float32(3.0))) == 6.0
-
-
 def test_debug_name_maps():
     import jax.numpy as jnp
     from deepspeed_tpu.utils import debug

@@ -73,7 +73,7 @@ def test_module_profile_tree_gpt2():
 
     # depth 1: the model's named_scope sections
     kids = tree["children"]
-    assert {"embedding", "blocks", "lm_head"} <= set(kids), kids.keys()
+    assert {"embed", "blocks", "lm_head"} <= set(kids), kids.keys()
     # depth 2: block internals, through the scanned layer stack
     blocks = kids["blocks"]["children"]
     assert {"attention", "mlp"} <= set(blocks), blocks.keys()

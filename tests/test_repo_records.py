@@ -26,6 +26,7 @@ BASES = ("", "deepspeed_tpu", "deepspeed_tpu/runtime", "tests", "docs")
 PRODUCED_BY_A_RUN = {
     "heartbeat.json",       # a router worker's liveness file (serving.md)
     "key_anatomy.json",     # the compile cache's key dump (compile-cache.md)
+    "device_scopes.json",   # an AOT entry's map of device scopes (the same)
 }
 # a backticked span that is one path, with an optional :line, ::test or
 # #anchor behind it

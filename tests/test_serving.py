@@ -624,13 +624,12 @@ def test_serving_step_anatomy(tiny, devices):
             assert names == ["serving.admit"] + dispatching
             assert ahead == [False] and st.attrs["emitted"] == 0
         elif ahead == [True]:
-            assert names == dispatching + settling + ["serving.telemetry"]
+            assert names == dispatching + settling
         else:
             # a row's last token was in flight: read first, then whatever
             # the freed slot allows, then the next step if a row is left
             assert names[:3] == settling + ["serving.admit"]
-            assert names[3:] in (dispatching + ["serving.telemetry"],
-                                 ["serving.telemetry"])
+            assert names[3:] in (dispatching, [])
             assert ahead in ([False], [])
         shapes.add((st in booking, tuple(ahead)))
         for a, b in zip(kids, kids[1:]):
