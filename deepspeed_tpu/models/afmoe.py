@@ -31,7 +31,8 @@ the full layers with tables that grow, ``wk`` / ``wv`` over the sliding layers
 with tables used as RINGS.  ``block_tables`` (and a prefill's ``blocks``)
 carry both: the growing table first, the ring's ``ring_entries`` last.  A
 prompt's attention runs in blocks (no ``T x T`` scores): a band on the sliding
-layers, the causal triangle on the full ones (the flash kernel on a TPU).
+layers, the causal triangle on the full ones (on a TPU both through the flash
+forward, ``_attend_prompt``).
 
 ONE CHIP'S SHARE, as ``models/deepseek_v2.py``: ``experts_held`` /
 ``vocab_held`` say which routed experts and vocabulary rows this chip holds;
@@ -429,15 +430,26 @@ class Afmoe:
         return h, carry, counts, (jnp.stack(routes) if routes else None)
 
     def _attend_prompt(self, q, k, v, l):
-        """A prompt's attention for layer ``l``, in blocks: the band of a
-        sliding layer, the causal triangle of a full one (the flash kernel on
-        a TPU, K/V repeated to the query heads)."""
+        """A prompt's attention for layer ``l``, in blocks.  On a TPU both
+        kinds go through the flash forward: a sliding layer the windowed
+        entry, which walks the band alone (``prefill_band_attention`` in a
+        capture; a prompt no longer than the window is the causal triangle,
+        walked the same way), a full layer the dense causal call, K/V
+        repeated to the query heads.  Elsewhere ``banded_attention``."""
         c = self.config
+        from ..ops import flash_attention_available
         if c.types[l] == SLIDING:
             with jax.named_scope("attn.window"):
-                return banded_attention(q, k, v, window=c.sliding_window)
+                if not flash_attention_available():
+                    return banded_attention(q, k, v, window=c.sliding_window)
+                from ..ops.transformer.flash_attention import (
+                    flash_attention_window)
+                # the kernel's OWN name, and no scope around it: what is
+                # fused around the call stays ``attn.window`` work
+                return flash_attention_window(
+                    q, k, v, window=c.sliding_window,
+                    name="prefill_band_attention").reshape(q.shape[:2] + (-1,))
         with jax.named_scope("attn.global"):
-            from ..ops import flash_attention_available
             if not flash_attention_available():
                 return banded_attention(q, k, v)
             from ..ops.transformer.flash_attention import flash_attention

@@ -47,6 +47,13 @@ forward keeps no running state either.  Every other call (non-causal, LUT,
 banded, merged, biased, ``block_q != block_k``) runs the trivial plan, one
 tile under the mask it always had, through the same body.
 
+The windowed forward (``flash_attention_window``; a serving prefill's
+sliding-window layers, ``models/afmoe.py``) is a path of its own beside all
+of these: a causal window ``0 <= t - s < window`` walked as a static band of
+square blocks (``_band_slots``), the grid's inner axis as long as the band,
+masks on the two blocks an edge crosses only, grouped K/V heads read through
+the index map.  Forward only.
+
 Runs compiled on TPU; ``interpret=True`` under other backends so numerics
 tests run on the CPU mesh (SURVEY.md §4: every kernel is tested against a
 pure-jnp reference).
@@ -121,11 +128,13 @@ def _interpret():
 
 
 def _pallas(kernel, *, grid, in_specs, out_specs, out_shape, scratch,
-            num_prefetch=0, carried_axes=1):
+            num_prefetch=0, carried_axes=1, name=None):
     """One pallas_call builder for the dense (plain grid) and LUT
     (scalar-prefetch grid) variants — the operand lists must never
     diverge between the two paths.  ``carried_axes``: how many of the
-    grid's innermost axes carry state in scratch from step to step."""
+    grid's innermost axes carry state in scratch from step to step.
+    ``name``: the kernel's own name in the HLO text and a device trace
+    (without one it is named after the scope it sits in)."""
     cp = pltpu.CompilerParams(
         dimension_semantics=("parallel",) * (len(grid) - carried_axes)
         + ("arbitrary",) * carried_axes)
@@ -140,7 +149,7 @@ def _pallas(kernel, *, grid, in_specs, out_specs, out_shape, scratch,
     return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
                           scratch_shapes=scratch, compiler_params=cp,
-                          interpret=_interpret())
+                          interpret=_interpret(), name=name)
 
 
 # ======================================================== sparse-layout LUTs
@@ -855,6 +864,126 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k,
     return out[:, :T], lse[:, :T, 0]
 
 
+# =================================================== windowed forward (a band)
+# Edge of the windowed forward's square blocks.  Measured on v5e (PERF.md
+# section 6, PR 53; 48 query heads over 8 K/V heads of 128, a window of 4,096,
+# ms a layer at 8,192 tokens): 256 -> 23.2, 512 -> 11.1, 1024 -> 6.47 (6.27
+# as handed in; the ``jax.numpy`` band 10.9).  A grid step is one chain of
+# product, row maximum, exp, product that nothing overlaps with the next
+# step's, and only inside a block this large does the compiler hide the
+# vector work under the MXU's.  Cutting the two blocks an edge crosses into
+# strips that skip their unseen tiles (the dense call's tile plan) LOST to
+# masking them whole (6.88 against 6.47): a strip is a shorter chain, not a
+# cheaper one.
+_WINDOW_BLOCK = 1024
+
+
+def _band_slots(window, block):
+    """The static band walk of a causal window over square blocks: a q
+    block meets ``slots`` key blocks, the one ``back`` blocks behind it at
+    slot ``slots - 1 - back``, its own (the diagonal) last.  Returns
+    ``(slots, edges)``; ``edges``: ``{back: limit}`` of the blocks the
+    LOWER edge crosses, where a key is seen iff ``row - col < limit`` in
+    the block's own coordinates.  Every block between those and the
+    diagonal is seen whole and runs with no mask."""
+    slots = -(-(window - 1) // block) + 1
+    edges = {back: window - back * block for back in range(1, slots)
+             if back * block + block - 1 >= window}
+    return slots, edges
+
+
+def _window_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                       sm_scale, window, block, num_slots):
+    """Grid ``(B H, q blocks, num_slots)``: query ``t`` sees key ``s`` iff
+    ``0 <= t - s < window``, and the inner axis is as long as the BAND
+    (``_band_slots``), not as the sequence.  Slot ``kj`` holds key block
+    ``qi - (num_slots - 1 - kj)`` (the index map's, clipped at 0); one
+    wholly before key 0 costs its grid step and no product.  Which slots
+    the band's two edges cross is static: those pay for a mask, at the
+    token; the slots between run with none.  Forward only: no log-sum-exp
+    leaves it."""
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+    _, edges = _band_slots(window, block)
+    slot_of = lambda back: num_slots - 1 - back
+    # rows before the window's first block: their slot lies before key 0
+    on_keys = kj >= slot_of(qi)
+
+    @pl.when(kj == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def visit(causal=False, limit=None):
+        """The slot's block; ``causal``: it is the diagonal's (``row >=
+        col`` is seen); ``limit``: the lower edge crosses it (``row - col <
+        limit`` is seen)."""
+        def run():
+            s = _scores(q_ref[0], k_ref[0], sm_scale)
+            if causal or limit is not None:
+                ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                         - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+                seen = ahead >= 0 if causal else ahead < limit
+                if causal and limit is not None:
+                    seen = jnp.logical_and(seen, ahead < limit)
+                s = jnp.where(seen, s, NEG_INF)
+            m_ref[:], l_ref[:], acc_ref[:] = _online_softmax(
+                s, v_ref[0], m_ref[:], l_ref[:], acc_ref[:])
+        return run
+
+    edge_slots = {slot_of(back): limit for back, limit in edges.items()
+                  if slot_of(back) >= 0}
+    for slot, limit in edge_slots.items():
+        pl.when(jnp.logical_and(kj == slot, on_keys))(visit(limit=limit))
+    first_whole = max(edge_slots, default=-1) + 1
+    if first_whole < num_slots - 1:
+        pl.when(jnp.logical_and(
+            jnp.logical_and(kj >= first_whole, kj < num_slots - 1),
+            on_keys))(visit())
+    pl.when(kj == num_slots - 1)(
+        visit(causal=True, limit=window if window < block else None))
+
+    @pl.when(kj == num_slots - 1)
+    def _():
+        # a row sees itself: l > 0, and what a slot with no seen key left
+        # behind (exp(NEG_INF - NEG_INF) = 1) the first seen key wiped
+        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _window_fwd(q, k, v, window, sm_scale, block, name):
+    """``q`` (B, T, H, d) over ``k`` / ``v`` (B, T, Hkv, d), each stacked to
+    ``(B heads, T, d)`` as the dense call's; query head ``h`` reads K/V
+    head ``h // (H // Hkv)`` through the index map alone: no repeated K/V.
+    Returns (B, T, H, d).  Jitted so that a program's layers of one shape
+    trace and lower the kernel ONCE (four window layers in each of a
+    server's prefill buckets: the kernel's body is what a start-up's
+    ``trace+lower`` would pay four times a bucket)."""
+    B, T, H, d = q.shape
+    G = H // k.shape[2]
+    block = min(block, T)
+    Tp = -(-T // block) * block
+    nq = Tp // block
+    num_slots = min(_band_slots(window, block)[0], nq)
+    stack = lambda x: _pad_t(x.transpose(0, 2, 1, 3).reshape(-1, T, d), Tp)
+    q_spec = pl.BlockSpec((1, block, d), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, block, d),
+        lambda b, i, j: (b // G, jnp.maximum(i - (num_slots - 1) + j, 0), 0))
+    call = _pallas(
+        functools.partial(_window_fwd_kernel, sm_scale=sm_scale,
+                          window=window, block=block, num_slots=num_slots),
+        grid=(B * H, nq, num_slots),
+        in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B * H, Tp, d), q.dtype),
+        scratch=[pltpu.VMEM((block, d), jnp.float32),
+                 pltpu.VMEM((block, 1), jnp.float32),
+                 pltpu.VMEM((block, 1), jnp.float32)], name=name)
+    out = call(stack(q), stack(k), stack(v))
+    return out[:, :T].reshape(B, H, T, d).transpose(0, 2, 1, 3)
+
+
 # ============================================================== backward kernels
 def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, num_q_blocks,
                      seq_len, n_heads=1, use_kbias=False,
@@ -1347,6 +1476,48 @@ def flash_attention_with_lse(q, k, v, *, causal=True, sm_scale=None,
                                residual_name and (residual_name, H))
     return (out.reshape(B, H, T, d).transpose(0, 2, 1, 3),
             lse.reshape(B, H, T))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _window_call(q, k, v, window, sm_scale, name):
+    return _window_fwd(q, k, v, window, sm_scale, _WINDOW_BLOCK, name)
+
+
+def _window_no_grad(*_):
+    raise NotImplementedError(
+        "flash_attention_window is forward-only: no windowed backward kernel "
+        "exists (flash_attention's causal backward is another function's)")
+
+
+_window_call.defvjp(_window_no_grad, _window_no_grad)
+
+
+def flash_attention_window(q, k, v, *, window, sm_scale=None, name=None):
+    """The flash FORWARD under a causal window: ``q`` (B, T, H, d) over
+    ``k`` / ``v`` (B, T, Hkv, d) with ``Hkv`` dividing ``H`` (query head
+    ``h`` reads K/V head ``h // (H // Hkv)``); query ``t`` sees key ``s``
+    iff ``0 <= t - s < window``, the edge at the TOKEN whatever the block.
+    Returns (B, T, H, d).  A block of queries walks only the key blocks
+    between the band's lower edge and the diagonal (``_band_slots``:
+    ``ceil((window - 1) / block) + 1`` grid steps a block of
+    ``_WINDOW_BLOCK`` queries, 5 at a window of 4,096, of which the 2 an edge
+    crosses are masked); the scores never leave VMEM; fp32 statistics, input-dtype products, as
+    :func:`flash_attention`.  ``T <= window`` is the causal triangle, walked
+    the same way.
+
+    FORWARD ONLY: under ``jax.grad`` it raises ``NotImplementedError`` by
+    name.  ``name``: the kernel's name in a device trace."""
+    window = int(window)
+    H, Hkv = q.shape[2], k.shape[2]
+    if window < 1 or H % Hkv or k.shape != v.shape \
+            or k.shape != q.shape[:2] + (Hkv, q.shape[3]):
+        raise ValueError(
+            f"flash_attention_window: window {window}, q {q.shape}, k "
+            f"{k.shape}, v {v.shape}: a window of at least 1 and K/V (B, T, "
+            "Hkv, d) with Hkv dividing q's heads")
+    if sm_scale is None:
+        sm_scale = 1.0 / np.sqrt(q.shape[-1])
+    return _window_call(q, k, v, window, float(sm_scale), name)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))

@@ -88,6 +88,36 @@ def test_logits_match_the_reference_past_the_window(model_params):
     assert rel_err(got, ref) < TOL
 
 
+@pytest.mark.parametrize("T", [6, 40], ids=["under_the_window", "5_windows"])
+def test_logits_match_the_reference_through_the_flash_forward(
+        model_params, monkeypatch, T):
+    """What a TPU runs (``flash_attention_available`` forced true, the
+    kernels interpreted, the band's blocks 16 so that 40 tokens are three):
+    every sliding layer's prompt goes through the windowed forward, one shorter
+    than the window (the causal triangle) as one five windows long, every
+    full layer's through the dense causal call, and the logits match the
+    reference as the ``jax.numpy`` band's do."""
+    import importlib
+    import deepspeed_tpu.ops as ops
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.flash_attention")
+    monkeypatch.setattr(ops, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(fa, "_WINDOW_BLOCK", 16)
+    calls = {"band": 0, "causal": 0}
+    for name, key in (("_window_fwd", "band"), ("_fwd", "causal")):
+        def counted(*a, _inner=getattr(fa, name), _key=key, **kw):
+            calls[_key] += 1
+            return _inner(*a, **kw)
+        monkeypatch.setattr(fa, name, counted)
+    m, params = model_params
+    toks = jnp.asarray(tokens(1, 2, T))
+    got = jax.jit(m.apply)(params, toks)
+    ref = jax.jit(lambda p: reference.logits(ref_cfg(m), p, toks))(params)
+    assert rel_err(got, ref) < TOL
+    assert calls == {"band": len(m.window_layers),
+                     "causal": len(m.global_layers)}
+
+
 def test_loss_matches_the_reference(model_params):
     m, params = model_params
     batch = jnp.asarray(tokens(2, 2, 25))

@@ -506,6 +506,99 @@ def test_custom_call_census_counts_loop_trips():
     assert custom_calls_from_hlo_text(text) == {"attention": 10, "head": 1}
 
 
+# ------------------------------------------------- the windowed forward
+def _window_reference(q, k, v, window):
+    """Masked dense oracle: key ``s`` seen by query ``t`` iff ``0 <= t - s
+    < window``; query head ``h`` reads K/V head ``h // (H // Hkv)``."""
+    T, d, G = q.shape[1], q.shape[-1], q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, G, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / np.sqrt(d)
+    ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    s = jnp.where((ahead >= 0) & (ahead < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _window_qkv(T, H, Hkv, d, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    mk = lambda key, h: jax.random.normal(key, (1, T, h, d), jnp.float32)
+    return mk(ks[0], H), mk(ks[1], Hkv), mk(ks[2], Hkv)
+
+
+@pytest.mark.parametrize("T,window,block,H,Hkv,d", [
+    (96, 128, None, 2, 2, 64),       # T below the window: the triangle
+    (128, 128, 64, 2, 2, 64),        # T at the window
+    (320, 128, 128, 6, 1, 128),      # above; a bucket of 64s, not of 128s
+    (320, 200, 128, 2, 1, 64),       # the window no multiple of the block:
+    #                                  two slots under the lower edge
+    (300, 100, 128, 4, 2, 128),      # the window inside one block
+    (448, 129, 64, 6, 1, 64),        # window - 1 a multiple of the block
+    (1088, 600, 512, 2, 1, 128),     # the edge in two slots of 512
+    (1088, 1024, 512, 6, 1, 64),     # the served ratio: 4 blocks a window
+], ids=["below", "at", "bucket_of_64s", "edge_in_two_slots", "inside_a_block",
+        "aligned_edge", "blocks_of_512_hd128", "blocks_of_512_grouped_hd64"])
+def test_window_forward_matches_the_masked_reference(monkeypatch, T, window,
+                                                     block, H, Hkv, d):
+    """``flash_attention_window`` (interpreted) against a masked dense
+    reference AND ``models/afmoe.banded_attention``, what runs off the
+    chip; then the band's edge BY VALUE: a key moved far away moves the
+    rows that see it, up to ``t - s = window - 1``, and leaves row ``t - s
+    = window`` (and the row before the key) to the bit."""
+    import importlib
+    from deepspeed_tpu.models.afmoe import banded_attention
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.flash_attention")
+    if block:
+        monkeypatch.setattr(fa, "_WINDOW_BLOCK", block)
+    q, k, v = _window_qkv(T, H, Hkv, d, seed=T + window)
+    fn = lambda v: fa.flash_attention_window(q, k, v, window=window)
+    out = fn(v)
+    assert out.shape == q.shape
+    ref = _window_reference(q, k, v, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    band = banded_attention(q, k, v, window=window, block=64)
+    np.testing.assert_allclose(np.asarray(out.reshape(band.shape)),
+                               np.asarray(band), atol=2e-5, rtol=2e-5)
+    s = max(T - window - 3, 1)                # a key with rows past its band
+    moved = np.abs(np.asarray(fn(v.at[:, s].add(100.0)) - out)).max(
+        axis=(0, 2, 3))                       # (T,): how far each row moved
+    last = min(s + window - 1, T - 1)
+    assert (moved[s:last + 1] > 1e-3).all()   # t - s = 0 .. window - 1: seen
+    assert moved[s - 1] == 0.0
+    if s + window < T:
+        assert moved[s + window] == 0.0       # t - s = window: not seen
+        assert (moved[s + window:] == 0.0).all()
+
+
+def test_window_forward_refuses_a_gradient_by_name():
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        flash_attention_window)
+    q, k, v = _window_qkv(128, 2, 1, 64)
+    loss = lambda q: flash_attention_window(q, k, v, window=64).sum()
+    with pytest.raises(NotImplementedError,
+                       match="flash_attention_window is forward-only"):
+        jax.grad(loss)(q)
+
+
+def test_window_call_leaves_the_dense_census_as_the_parent_had_it():
+    """A dense causal call's ``tile_census()`` entry is the parent's (T
+    1024, hd 64, 4 heads: one 1024-block of 10 tiles in 16 a head, 4
+    masked; the fused backward), with a windowed call traced beside it."""
+    import importlib
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.flash_attention")
+    x = jax.ShapeDtypeStruct((2, 1024, 2, 64), jnp.bfloat16)
+    fa.reset_tile_census()
+    jax.eval_shape(jax.grad(lambda q, k, v: fa.flash_attention(
+        q, k, v).astype(jnp.float32).sum()), x, x, x)
+    dense = fa.tile_census()
+    assert dense == {"visited": 2 * 4 * 10, "masked": 2 * 4 * 4,
+                     "square": 2 * 4 * 16, "bwd_fused": 1, "bwd_split": 0}
+    jax.eval_shape(lambda q, k, v: fa.flash_attention_window(
+        q, k, v, window=256), x, x, x)
+    assert fa.tile_census() == dense
+
+
 # -------------------------------------------- the step the TPU compiles
 @pytest.fixture(scope="module")
 def v5e():
@@ -571,6 +664,31 @@ def test_causal_kernels_compile_for_a_v5e(v5e, monkeypatch, BH, T, d,
         "square": calls * BH * (T // 256) ** 2,
         "bwd_fused": int(kernels == "backward" and bwd_calls == 1),
         "bwd_split": int(kernels == "backward" and bwd_calls == 2)}
+
+
+@pytest.mark.parametrize("T", [5120, 16384, 4160, 512])
+def test_window_kernel_compiles_for_a_v5e(v5e, monkeypatch, T):
+    """The windowed forward at Trinity's served shape (48 query heads over
+    8 K/V heads of 128, a window of 4,096, bf16, blocks of 1,024: a 4 MB
+    score tile beside the double-buffered operands) through Mosaic and
+    XLA:TPU for a described v5e: ONE custom call, named as the model names
+    it, and no repeated K/V (the index map reads K/V head ``h // 6``); a
+    bucket that is no multiple of the block is padded, one under a block
+    is one block."""
+    import importlib
+    from jax.sharding import SingleDeviceSharding
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.transformer.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    q = jax.ShapeDtypeStruct((1, T, 48, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, T, 8, 128), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda q, k, v: fa.flash_attention_window(
+        q, k, v, window=4096, name="prefill_band_attention")).trace(
+            q, kv, kv).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "%prefill_band_attention" in text
+    assert "broadcast" not in text
 
 
 N_LAYER = 3
