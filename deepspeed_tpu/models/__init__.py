@@ -8,7 +8,9 @@ layers, one full layer whose K/V the cross-attention layers read again, Gated
 Memory Units, differential attention), LongCat-Flash (a shortcut-connected
 double block: two latent-attention sub-layers, two dense FFNs and one expert
 layer that joins late; a router wider than its experts, the rest identity
-experts)."""
+experts), Qwen3-Next (Gated DeltaNet layers, whose delta-rule state is read
+before it is written, and gated attention layers at a partial rotary, every
+layer followed by many small experts and a gated shared one)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -51,6 +53,9 @@ def build(name, **overrides):
         if name.startswith("longcat-flash"):
             from .longcat_flash import LongcatFlash
             return LongcatFlash(preset=name, **overrides)
+        if name.startswith("qwen3-next"):
+            from .qwen3_next import Qwen3Next
+            return Qwen3Next(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

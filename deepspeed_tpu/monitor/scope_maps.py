@@ -17,10 +17,13 @@ AMBIGUOUS = "ambiguous"
 VERSION = 2
 
 SERVED = ("gpt2", "jamba", "ouro", "deepseek_v2", "afmoe", "nemotron_h",
-          "phi4flash", "longcat_flash")
+          "phi4flash", "longcat_flash", "qwen3_next")
 TRAIN = "train"
 _MIXERS = ("jamba", "nemotron_h", "phi4flash")
-_ROUTED = ("deepseek_v2", "afmoe", "nemotron_h", "longcat_flash")
+_RECURRENT = _MIXERS + ("qwen3_next",)
+_ROUTED = ("deepseek_v2", "afmoe", "nemotron_h", "longcat_flash",
+           "qwen3_next")
+_DELTA = ("qwen3_next",)
 _LATENT = ("deepseek_v2", "longcat_flash")
 # The one vocabulary of device scopes: ``{scope: (who opens it, what it
 # covers)}``, "who" the served families (``models/<family>.py``'s decode
@@ -52,7 +55,7 @@ VOCABULARY = {
                     "a sliding-window layer's attention core, prefill band "
                     "and decode ring alike"),
     "attn.global": (("afmoe",), "a full-attention layer's core"),
-    "attn.gate": (("afmoe",), "the attention output gate"),
+    "attn.gate": (("afmoe", "qwen3_next"), "the attention output gate"),
     "attn.shared": (("phi4flash",),
                     "attention over the one shared K/V: the full layer and "
                     "every cross layer"),
@@ -67,19 +70,25 @@ VOCABULARY = {
                  "in/x/dt/out projections, gate"),
     "ssm.conv": (_MIXERS, "the causal convolution and its activation"),
     "ssm.scan": (_MIXERS, "a prompt's recurrence (the scan kernel)"),
-    "ssm.step": (_MIXERS, "one token's state update and the write of "
+    "ssm.step": (_RECURRENT, "one token's state update and the write of "
                  "every slot's recurrent rows"),
-    "ssm.seat": (_MIXERS, "a prefill's state written into its slot"),
+    "ssm.seat": (_RECURRENT, "a prefill's state written into its slot"),
+    "gdn.project": (_DELTA, "a Gated DeltaNet mixer up to its recurrence: "
+                    "norm, the two input products, the convolution, the "
+                    "l2 norms, beta and the decay"),
+    "gdn.chunk": (_DELTA, "a prompt's chunked delta rule"),
+    "gdn.gate_norm": (_DELTA, "the gated norm a head and the output "
+                      "projection"),
     "moe.route": (_ROUTED, "router scores, top-k, the step's counters"),
     "moe.experts": (_ROUTED, "the held experts' grouped products with "
                     "their gather and combine"),
-    "moe.shared": (("deepseek_v2", "afmoe", "nemotron_h"),
+    "moe.shared": (("deepseek_v2", "afmoe", "nemotron_h", "qwen3_next"),
                    "the shared experts"),
     "moe.zero": (("longcat_flash",), "the identity experts' part"),
     "ut.loop": (("ouro",), "one pass of the looped layers outside their "
                 "sub-layers: the stacked weights' slices"),
     "prefill_flash_attention": (
-        ("afmoe", "nemotron_h"),
+        ("afmoe", "nemotron_h", "qwen3_next"),
         "names the prompt's flash kernel in a capture (TPU only)"),
 }
 TPU_ONLY = ("prefill_flash_attention",)

@@ -238,6 +238,8 @@ SERVED = {   # family -> (preset, overrides, block size)
     "nemotron_h": ("nemotron-h-tiny", {"max_position_embeddings": 64}, 8),
     "phi4flash": ("phi4flash-tiny", {"max_position_embeddings": 64}, 8),
     "longcat_flash": ("longcat-flash-tiny", {}, 8),
+    "qwen3_next": ("qwen3-next-tiny", {"max_position_embeddings": 64,
+                                       "num_hidden_layers": 4}, 8),
 }
 
 

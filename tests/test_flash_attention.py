@@ -195,6 +195,10 @@ def _weighted(fn, w):
     (64, 1024, None, jnp.float32, False),     # one block, no mask to skip
     (128, 1024, (512, 512), jnp.float32, False),   # several blocks, dQ summed
     (128, 1280, (512, 512), jnp.float32, False),   # and padded keys masked
+    # heads of 256 (PR 54: Qwen3-Next's attention layers): blocks of 512
+    (256, 1024, None, jnp.float32, True),
+    (256, 1024, None, jnp.bfloat16, True),
+    (256, 640, (512, 512), jnp.float32, True),     # Tp > T at hd 256
 ], ids=lambda v: getattr(v, "__name__", None) or str(v).replace(" ", ""))
 def test_walk_forward_and_backward_match_reference(d, T, blocks, dtype,
                                                    causal):

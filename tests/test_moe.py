@@ -820,6 +820,51 @@ def test_the_capacity_gate_still_refuses_other_k_and_still_drops():
     assert dropless.route_counters(experts, 0, 8).tolist() == [24, 0, 1, 7, 0]
 
 
+# ----------------- many small experts (PR 54: Qwen3-Next, top-10 of 512)
+@pytest.mark.parametrize("held", [(0, 64), (448, 64), (100, 8), (0, 512)],
+                         ids=lambda h: f"experts_{h[0]}_to_{h[0] + h[1] - 1}")
+def test_dropless_top10_of_512_renormalised_over_every_pick(held):
+    """Softmax over 512 outputs, the ten largest, their weights over the sum
+    of ALL ten whether the expert is held here or not; the held experts' part
+    is the sum over the held picks under those weights, and the counters
+    split the 10 pairs a token into held and elsewhere."""
+    first, count = held
+    N, E, k, D, F = 96, 512, 10, 16, 8
+    rng = jax.random.split(jax.random.PRNGKey(54), 5)
+    logits = jax.random.normal(rng[0], (N, E)) * 2
+    experts, weights = dropless.route(logits, k, scoring_func="softmax",
+                                      norm_topk_prob=True)
+    p = np.asarray(jax.nn.softmax(logits, -1), np.float64)
+    want = np.argsort(-p, axis=1, kind="stable")[:, :k]
+    np.testing.assert_array_equal(np.sort(np.asarray(experts), 1),
+                                  np.sort(want, 1))
+    picked = np.take_along_axis(p, np.asarray(experts), 1)
+    np.testing.assert_allclose(np.asarray(weights),
+                               picked / picked.sum(1, keepdims=True),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(weights).sum(1), 1.0, rtol=1e-5)
+    x = jax.random.normal(rng[1], (N, D))
+    gate = jax.random.normal(rng[2], (count, D, F)) * 0.3
+    up = jax.random.normal(rng[3], (count, D, F)) * 0.3
+    down = jax.random.normal(rng[4], (count, F, D)) * 0.3
+    out = dropless.held_experts(x, experts, weights, gate, up, down, first)
+    ref = np.zeros((N, D))
+    for n in range(N):
+        for e, w in zip(np.asarray(experts)[n], np.asarray(weights)[n]):
+            if first <= e < first + count:
+                i = int(e) - first
+                ref[n] += float(w) * np.asarray(
+                    (jax.nn.silu(x[n] @ gate[i]) * (x[n] @ up[i])) @ down[i])
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
+    here = int(((np.asarray(experts) >= first)
+                & (np.asarray(experts) < first + count)).sum())
+    n = dropless.route_counters(experts, first, count).tolist()
+    assert (n[0], n[1]) == (here, k * N - here)
+    assert n[2] + n[3] == count and n[4] == int(
+        (~((np.asarray(experts) >= first)
+           & (np.asarray(experts) < first + count)).any(1)).sum())
+
+
 # ------------------------- a router wider than its experts (PR 50: LongCat)
 # ids past the real experts are ZERO-COMPUTE (identity) experts: in no group
 # of ``held_experts``, their part the token's own input times the weight.

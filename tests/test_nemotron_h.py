@@ -444,7 +444,9 @@ def test_a_gated_stacked_layer_through_the_pallas_product(monkeypatch):
 # (layers x count groups, D, F) of the three cells' expert layers, and the
 # rows of a decode step and of a prompt (slots or tokens x picks a token)
 CELL_WIDTHS = {"deepseek-v2": (120, 5120, 1536), "trinity": (128, 3072, 3072),
-               "nemotron-3-nano": (224, 2688, 1856)}
+               "nemotron-3-nano": (224, 2688, 1856),
+               # 12 layers x 64 held experts of width 512 (PR 54)
+               "qwen3-next": (768, 2048, 512)}
 
 
 @pytest.mark.parametrize("cell, rows, product, plan", [
@@ -460,6 +462,12 @@ CELL_WIDTHS = {"deepseek-v2": (120, 5120, 1536), "trinity": (128, 3072, 3072),
     ("nemotron-3-nano", 1536, "down", (128, 1856, 896)),
     ("nemotron-3-nano", 6144, "up", (128, 896, 1856)),
     ("nemotron-3-nano", 6144, "down", (128, 1856, 896)),
+    # many small experts: a matrix is ONE tile (2 MiB) either way; a decode
+    # step's 640 pairs (64 slots x 10) and a 4,096-token prompt's
+    ("qwen3-next", 640, "up", (128, 2048, 512)),
+    ("qwen3-next", 640, "down", (128, 512, 2048)),
+    ("qwen3-next", 40960, "up", (128, 2048, 512)),
+    ("qwen3-next", 40960, "down", (128, 512, 2048)),
 ])
 def test_the_tile_plan_comes_from_the_shapes(monkeypatch, cell, rows, product,
                                              plan):

@@ -162,12 +162,20 @@ def test_online_mode_within_compute_dtype_rounding(n_window, devices):
 # ``_chunks_of`` shrinks it to chunks of 128 (three, the third half past the
 # table's end: the walk before PR 49) or 256 (two, a tail tile inside each)
 WALK_BS, WALK_NB_MAX = 16, 20
-#          name: (query heads, K/V heads, window, kv_bits)
+#          name: (query heads, K/V heads, window, kv_bits[, head size])
 WALK_POOLS = {"bf16": (2, 2, 1, 16), "int8": (2, 2, 1, 8),
               "mqa20": (20, 1, 1, 16), "window3": (2, 2, 3, 16),
               # Nemotron-3's grouping: 16 query heads a K/V head, 32 packed
               # score rows (128 of which 32 were real before PR 49)
-              "gqa32": (32, 2, 1, 16)}
+              "gqa32": (32, 2, 1, 16),
+              # Qwen3-Next's attention layers (PR 54): heads of 256, 8 query
+              # heads a K/V head, a pool 512 lanes wide
+              "gqa8_hd256": (16, 2, 1, 16, 256)}
+
+
+def _walk_pool(kind):
+    """``(query heads, K/V heads, window, kv_bits, head size)`` of a kind."""
+    return (WALK_POOLS[kind] + (HD,))[:5]
 # a row's length: the position of its first window token; None: a dead row
 # (its whole table names the scratch block, its length 0)
 WALK_ROWS = {
@@ -188,13 +196,13 @@ WALK_ROWS = {
 }
 
 
-def _chunks_of(monkeypatch, tokens, kv_heads, kv_bits):
+def _chunks_of(monkeypatch, tokens, kv_heads, kv_bits, hd=HD):
     """Make the online walk's chunk ``tokens`` positions of this pool."""
     monkeypatch.setattr(pa_module, "_CHUNK_BYTES",
-                        tokens * kv_heads * HD * kv_bits // 8)
+                        tokens * kv_heads * hd * kv_bits // 8)
 
 
-def _walk_case(rows, heads, kv_heads, window, kv_bits):
+def _walk_case(rows, heads, kv_heads, window, kv_bits, HD=HD):
     """Pool, tables, lengths and queries of one case: every live row owns
     its table's worth of blocks with finite K/V; every block no table
     names holds NaN (an int8 pool: NaN scales)."""
@@ -245,9 +253,9 @@ def _dense_oracle(q, pool, tables, lengths, kv_heads):
 def _check_walk(rows, pool_kind):
     """Live rows equal the float64 oracle within the online mode's
     tolerance, dead rows are exactly zero, nothing is NaN."""
-    heads, kv_heads, window, kv_bits = WALK_POOLS[pool_kind]
+    heads, kv_heads, window, kv_bits, hd = _walk_pool(pool_kind)
     pool, tables, lengths, q = _walk_case(rows, heads, kv_heads, window,
-                                          kv_bits)
+                                          kv_bits, hd)
     out = np.asarray(jax.jit(
         lambda q, p: paged_attention(q, p, tables, lengths, 0,
                                      mode="online"))(q, pool), np.float32)
@@ -280,9 +288,9 @@ def test_online_walk_in_several_chunks(chunk, pool_kind, devices,
     of 128 inside each): whole chunks land under one wait and run unmasked,
     the last chunk's products cover its live tail tiles only, a row hands the
     ring over wherever it ends."""
-    _, kv_heads, _, kv_bits = WALK_POOLS[pool_kind]
-    _chunks_of(monkeypatch, chunk, kv_heads, kv_bits)
-    assert pa_module.score_tile(kv_heads * HD * kv_bits // 8, WALK_BS,
+    _, kv_heads, _, kv_bits, hd = _walk_pool(pool_kind)
+    _chunks_of(monkeypatch, chunk, kv_heads, kv_bits, hd)
+    assert pa_module.score_tile(kv_heads * hd * kv_bits // 8, WALK_BS,
                                 WALK_NB_MAX, 8)[0] == chunk
     _check_walk(WALK_ROWS["chunk_edges"] + WALK_ROWS["handover"], pool_kind)
 
@@ -305,6 +313,9 @@ SCORE_TILES = {
     # 256 lanes: 1,024 tokens are 512 KB of K; 32 rows, all real (128 of
     # which 32 were)
     "nemotron-3-nano": (2 * 128, 2, 64, 64, 32, 1024, 32),
+    # 512 lanes (2 K/V heads of 256, PR 54): 512 tokens are 512 KB of K; 8
+    # query heads a K/V head are 16 rows, all real
+    "qwen3-next": (2 * 256, 2, 64, 104, 16, 512, 16),
     # 128 lanes: the whole table of 2,048; 20 rows in 24 (160 of which 20
     # were)
     "jamba2-3b": (1 * 128, 2, 16, 128, 20, 2048, 24),
@@ -444,8 +455,9 @@ def v5e():
         (16, 16, 16, 128, 2048, 8, 16),   # an int8 pool and its scale rows
         (256, 32, 2, 128, 4096, 16, 64),  # nemotron-3-nano: chunks of 512
         (96, 48, 8, 128, 17408, 16, 64),  # trinity's global layers
+        (64, 16, 2, 256, 6656, 16, 64),   # qwen3-next: heads of 256
     ], ids=["cerebras-gpt-1.3b", "gpt2-large", "jamba2-3b", "int8-pool",
-            "nemotron-3-nano", "trinity-global"])
+            "nemotron-3-nano", "trinity-global", "qwen3-next"])
 def test_online_kernel_compiles_for_a_v5e(v5e, slots, heads, kv_heads,
                                           head_dim, positions, kv_bits,
                                           block):
@@ -513,6 +525,40 @@ def test_state_update_kernel_compiles_for_a_v5e(v5e):
     assert m.alias_size_in_bytes == state
     assert m.temp_size_in_bytes < 64 * 2 ** 20
     assert not re.search(r"f32\[7,(256,8|2048),128,512\]\S* copy\(", text)
+
+
+def test_delta_state_update_kernel_compiles_for_a_v5e(v5e):
+    """``ops/gated_delta.py``'s one-token update at the served shape (9
+    DeltaNet layers x 64 slots x 2 MB, Qwen3-Next, PR 54), through Mosaic and
+    XLA:TPU for a described v5e: one custom call, the 1.2 GB leaf aliased to
+    its output, the kernel's three buffers of 8 slots inside its VMEM limit,
+    and no copy of the leaf."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.ops import gated_delta as gd
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    L, slots, H, dk, dv = 9, 64, 32, 128, 128
+    assert gd._step_slots(slots, 4 * H * dk * dv) == 8
+    assert 3 * 8 * 4 * H * dk * dv <= gd._STEP_BUF < gd._STEP_VMEM
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    leaf = (L, slots, H, dk, dv)
+    shapes = [(leaf, f32), ((), jnp.int32), ((slots, H, dk), bf16),
+              ((slots, H, dk), bf16), ((slots, H, dv), bf16),
+              ((slots, H), f32), ((slots, H), f32), ((slots,), jnp.bool_)]
+    exe = jax.jit(lambda st, l, q, k, v, g, b, a: gd.delta_step(
+        st, l, q, k, v, g, b, active=a, impl="kernel", interpret=False),
+        donate_argnums=(0,)).trace(*[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+        ]).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line)
+    assert gd.STEP_KERNEL in call
+    assert "output_to_operand_aliasing={{1}: (4, {})}" in call
+    m = exe.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * int(np.prod(leaf))
+    assert m.temp_size_in_bytes < 64 * 2 ** 20
+    assert not re.search(r"f32\[9,(64,32|2048),128,128\]\S* copy\(", text)
 
 
 def test_a_decode_step_updates_the_recurrent_state_where_it_lies(v5e,

@@ -24,6 +24,7 @@ DOCS = os.path.join(os.path.dirname(__file__), os.pardir, "docs", "serving.md")
 FAMILIES = {
     "jamba": ("jamba-tiny", "recurrent state", "recurrent-state"),
     "nemotron_h": ("nemotron-h-tiny", "recurrent state", "recurrent-state"),
+    "qwen3_next": ("qwen3-next-tiny", "recurrent state", "recurrent-state"),
     "trinity": ("afmoe-tiny", "sliding-window layers", "window-layers"),
     "deepseek_v2": ("deepseek-v2-tiny", "a latent KV pool", "latent-pool")}
 FEATURES = {"prefix_cache": ("prefix_cache", True),
