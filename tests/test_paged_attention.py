@@ -561,6 +561,46 @@ def test_delta_state_update_kernel_compiles_for_a_v5e(v5e):
     assert not re.search(r"f32\[9,(64,32|2048),128,128\]\S* copy\(", text)
 
 
+@pytest.mark.parametrize("T", [2048, 3072, 3414, 3584, 4096])
+def test_delta_chunk_scan_kernel_compiles_for_a_v5e(v5e, T):
+    """``ops/gated_delta.py``'s chunked rule at the published widths (32
+    value heads of 128 x 128, chunks of 64, bfloat16 operands, a float32
+    state handed in) at the five segment lengths ``serve_longctx_qwen3next``'s
+    prompts are cut into, through Mosaic and XLA:TPU for a described v5e: ONE
+    custom call of the kernel's name (the number of chunks is a grid extent:
+    3,414 is padded to 54 of them), inside the VMEM it asks for (Mosaic
+    refuses a kernel over its ``vmem_limit_bytes``), and no transpose of an
+    operand round it: q, k, v and o cross the call as the projection leaves
+    them."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.ops import gated_delta as gd
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    H, dk, dv = 32, 128, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert gd._chunk_kernel_lowers(H, 64, dk, dv)
+    assert gd._CHUNK_VMEM <= 100 << 20          # a v5e has 128 MiB
+    shapes = [((1, T, H, dk), bf16), ((1, T, H, dk), bf16),
+              ((1, T, H, dv), bf16), ((1, T, H), f32), ((1, T, H), f32),
+              ((1, H, dk, dv), f32), ((), jnp.int32)]
+    exe = jax.jit(lambda q, k, v, g, b, S, n: gd.delta_chunk(
+        q, k, v, g, b, S, chunk=64, t_real=n, impl="kernel",
+        interpret=False)).trace(*[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+        ]).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line)
+    assert gd.CHUNK_KERNEL in call
+    assert not re.search(r"bf16\[[0-9,]*\]\S* transpose\(", text)
+    # beside the call XLA keeps G and beta (two small fusions) and, HERE,
+    # where q, k, v arrive as parameters in the default 4-D tiling, one
+    # re-tiling copy each and the output's: nothing the size of the parent's
+    # U, T, W and score matrices (0.4 GB a layer at 4,096 tokens)
+    tokens = T + -T % 64
+    assert exe.memory_analysis().temp_size_in_bytes < 5 * 2 * tokens * H * dv
+
+
 def test_a_decode_step_updates_the_recurrent_state_where_it_lies(v5e,
                                                                  monkeypatch):
     """``NemotronH.decode_step_paged`` at the published widths, 256 slots,

@@ -200,6 +200,14 @@ def rule_operands(T, B=2, H=4, dk=8, dv=8, seed=0, alike=0.5):
     return q, kk, v, g, beta, S0
 
 
+def chunked(impl, **kw):
+    """``gd.delta_chunk`` in one form, jitted: ``"kernel"`` is the Pallas
+    kernel ``gated_delta_chunk_scan`` interpreted (no chip here)."""
+    return jax.jit(functools.partial(gd.delta_chunk, impl=impl,
+                                     interpret=True, **kw))
+
+
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
 @pytest.mark.parametrize("T, chunk, t_real, with_state", [
     (45, 16, None, True),         # not a multiple of the chunk, from a state
     (45, 64, None, False),        # one chunk, cut to 64
@@ -208,17 +216,116 @@ def rule_operands(T, B=2, H=4, dk=8, dv=8, seed=0, alike=0.5):
     (40, 16, 40, False), (5, 16, None, True), (1, 16, None, True),
 ])
 def test_the_chunked_rule_matches_the_token_recurrence(T, chunk, t_real,
-                                                       with_state):
+                                                       with_state, impl):
     q, k, v, g, beta, S0 = rule_operands(T)
     S0 = S0 if with_state else None
     n = T if t_real is None else t_real
     want_o, want_S = gd.delta_scan_jnp(q[:, :n], k[:, :n], v[:, :n],
                                        g[:, :n], beta[:, :n], S0)
-    got_o, got_S = jax.jit(functools.partial(gd.delta_chunk, chunk=chunk))(
-        q, k, v, g, beta, S0, t_real=t_real)
+    got_o, got_S = chunked(impl, chunk=chunk)(q, k, v, g, beta, S0,
+                                              t_real=t_real)
     assert got_o.shape == v.shape and got_S.dtype == jnp.float32
     assert rel_err(got_o[:, :n], want_o) < 1e-5
     assert rel_err(got_S, want_S) < 1e-5
+
+
+@pytest.mark.parametrize("shape, T, chunk, t_real", [
+    # the served chunk and head size, a length that is no multiple of 64,
+    # from a state: three grid steps of one pair, the last one padded
+    (dict(B=1, H=2, dk=128, dv=128), 150, 64, None),
+    # four pairs a grid step and two groups of heads, each head its own decay
+    (dict(B=2, H=16, dk=8, dv=16), 48, 16, None),
+    # the bucket's real tokens end inside the FIRST chunk
+    (dict(B=1, H=4, dk=8, dv=8), 48, 16, 5),
+    (dict(B=1, H=2, dk=128, dv=128), 130, 64, 3),
+], ids=["150_tokens_at_128", "sixteen_heads", "t_real_5", "t_real_3_at_128"])
+def test_the_chunk_kernel_where_only_a_kernel_can_go_wrong(shape, T, chunk,
+                                                           t_real):
+    q, k, v, g, beta, S0 = rule_operands(T, seed=3, **shape)
+    H = shape["H"]
+    g = g * jnp.linspace(0.05, 2.0, H)       # a head's decay is its own
+    n = T if t_real is None else t_real
+    want_o, want_S = gd.delta_scan_jnp(q[:, :n], k[:, :n], v[:, :n],
+                                       g[:, :n], beta[:, :n], S0)
+    got_o, got_S = chunked("kernel", chunk=chunk)(q, k, v, g, beta, S0,
+                                                  t_real=t_real)
+    assert got_o.shape == v.shape
+    assert rel_err(got_o[:, :n], want_o) < 1e-5
+    assert rel_err(got_S, want_S) < 1e-5
+    # head by head: a pair's two halves and a step's two pairs kept apart
+    for h in range(H):
+        assert rel_err(got_S[:, h], want_S[:, h]) < 2e-5, h
+
+
+def _rms(a, b):
+    return float(jnp.sqrt(jnp.mean(jnp.square(a.astype(jnp.float32) - b))
+                          / jnp.mean(jnp.square(b))))
+
+
+@pytest.mark.parametrize("alike, sure, slow, state_tol", [
+    (0.5, 0.0, 1.0, 5e-3), (16.0, 2.5, 0.05, 2e-2),
+], ids=["keys_alike", "a_token_repeated"])
+def test_the_chunk_kernel_at_bfloat16(alike, sure, slow, state_tol):
+    """bfloat16 operands as served, against the float32 recurrence over the
+    same rounded operands; and the kernel against ``"jnp"``, which rounds
+    at the same places and inverts at the same precision, closer than either
+    is to the recurrence.  The second case is the one the module docstring
+    warns of: keys all but equal (a cosine of 0.996: a token repeated),
+    ``beta`` near 1 and hardly any decay, where the powers inside the
+    16 x 16 blocks are far larger than ``T``; bfloat16 products leave the
+    state 1.4e-2 off there in either form, and in float32 the kernel stays
+    at 1e-5."""
+    q, k, v, g, beta, S0 = rule_operands(150, B=1, H=2, dk=128, dv=128,
+                                         seed=4, alike=alike)
+    g, beta = slow * g, jax.nn.sigmoid(jax.scipy.special.logit(beta) + sure)
+    want_o, want_S = gd.delta_scan_jnp(q, k, v, g, beta, S0)
+    o, S = chunked("kernel")(q, k, v, g, beta, S0)
+    assert _rms(o, want_o) < 2e-5 and _rms(S, want_S) < 2e-5
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    want_o, want_S = gd.delta_scan_jnp(q, k, v.astype(jnp.float32), g, beta,
+                                       S0)
+    got = {impl: chunked(impl)(q, k, v, g, beta, S0)
+           for impl in ("jnp", "kernel")}
+    for o, S in got.values():
+        assert o.dtype == jnp.bfloat16 and S.dtype == jnp.float32
+        assert _rms(o, want_o) < 5e-3 and _rms(S, want_S) < state_tol
+    between = (_rms(got["kernel"][0], got["jnp"][0].astype(jnp.float32)),
+               _rms(got["kernel"][1], got["jnp"][1]))
+    assert between[0] < 0.5 * _rms(got["jnp"][0], want_o), between
+    assert between[1] < 0.5 * _rms(got["jnp"][1], want_S), between
+
+
+def test_the_chunk_kernel_is_named_and_differentiated_and_the_chips():
+    """The kernel's name (``breakdown.device_ops`` and the scope map read
+    it); ``jax.grad`` through it, which has no backward kernel and takes the
+    ``jax.numpy`` form's (the model's ``loss`` calls the entry with no
+    ``impl``); and ``impl="auto"``: off the chip today's ``jax.numpy`` body to
+    the bit, on it the kernel at widths Mosaic tiles and the ``jax.numpy``
+    body at a tiny model's."""
+    q, k, v, g, beta, S0 = rule_operands(40, B=1, H=2)
+    text = jax.jit(functools.partial(gd.delta_chunk, chunk=16, impl="kernel",
+                                     interpret=True)).trace(
+        q, k, v, g, beta, S0).jaxpr.pretty_print(name_stack=True)
+    assert gd.CHUNK_KERNEL == "gated_delta_chunk_scan" and gd.CHUNK_KERNEL in text
+
+    def loss(impl, *operands):
+        o, S = chunked(impl, chunk=16)(*operands, t_real=30)
+        return (o * o).sum() + (S * jnp.cos(S)).sum()
+    every = tuple(range(6))
+    want = jax.grad(functools.partial(loss, "jnp"), every)(q, k, v, g, beta, S0)
+    got = jax.grad(functools.partial(loss, "kernel"), every)(q, k, v, g, beta, S0)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and rel_err(a, b) < 1e-5
+    assert float(jnp.abs(got[3][:, 30:]).max()) == 0.0      # a pad's g
+    with pytest.raises(ValueError, match="pairs"):
+        gd.delta_chunk(q[:, :, :1], k[:, :, :1], v[:, :, :1], g[:, :, :1],
+                       beta[:, :, :1], impl="kernel", interpret=True)
+    auto = gd.delta_chunk(q, k, v, g, beta, S0, chunk=16, t_real=20)
+    body = gd.delta_chunk_jnp(q, k, v, g, beta, S0, chunk=16, t_real=20)
+    assert all(bool((a == b).all()) for a, b in zip(auto, body))
+    assert gd._chunk_kernel_lowers(32, 64, 128, 128)
+    assert not gd._chunk_kernel_lowers(4, 16, 8, 8)
+    assert not gd._chunk_kernel_lowers(3, 64, 128, 128)
 
 
 @pytest.mark.parametrize("C, scale", [(64, 0.2), (64, 0.6), (16, 0.6),
@@ -236,9 +343,10 @@ def test_the_triangular_inverse_by_blocks(C, scale):
     assert float(jnp.abs(jnp.triu(got, 1)).max()) == 0.0
 
 
-def test_a_pad_enters_neither_the_state_nor_the_decay():
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
+def test_a_pad_enters_neither_the_state_nor_the_decay(impl):
     q, k, v, g, beta, S0 = rule_operands(32)
-    _, S = gd.delta_chunk(q, k, v, g, beta, S0, chunk=16, t_real=0)
+    _, S = chunked(impl, chunk=16)(q, k, v, g, beta, S0, t_real=0)
     assert float(jnp.abs(S - S0).max()) == 0.0
 
 
