@@ -132,10 +132,14 @@ PROTECTED_ATTRS = {
     # device-resident copy of the slot state stale; a block granted to a
     # seated row goes through _grant_blocks, which tells the allocator, the
     # sanitizer and the timeline's mirrors, and whose caller sends the grant
-    # to the device's copy
-    "_tables": ("__init__", "_set_slot", "_grant_blocks"),
+    # to the device's copy; where the cache folds itself a row's table is
+    # rewritten in mid-life by _fold_ended_windows (a window's blocks out,
+    # its summary blocks in), which tells the same three and marks the
+    # device's copy stale
+    "_tables": ("__init__", "_set_slot", "_grant_blocks",
+                "_fold_ended_windows"),
     # per-sequence block list (_Slot)
-    "blocks": ("__init__", "_grant_blocks"),
+    "blocks": ("__init__", "_grant_blocks", "_fold_ended_windows"),
     # and its list of the second kind (a window layer's ring; the ring's
     # entries of `_tables` are written by `_set_slot` like the rest)
     "wblocks": ("__init__",),

@@ -59,10 +59,90 @@ def blocks_needed(total_tokens: int, block_size: int) -> int:
     return max(1, -(-int(total_tokens) // int(block_size)))
 
 
-def timeline_peak(written, ends, held, block_size: int) -> int:
+class WindowFold:
+    """The holding of a cache that FOLDS itself (docs/serving.md#folded-cache):
+    a stream keeps the exact K/V rows of its current ``window`` tokens, and at
+    each window's end those rows are folded ``chunk`` to 1 into summary rows
+    of the same shape and given back.  A slot's table is its summary blocks
+    (``summary_blocks`` a folded window) followed by its current window's
+    blocks (at most ``window_blocks``); stream position ``p`` is table row
+    :meth:`row`.  Plain integer arithmetic: every method takes Python ints,
+    numpy arrays and traced ``jax.numpy`` arrays alike, so the host's
+    admission sum and the compiled step read one rule."""
+
+    def __init__(self, window: int, chunk: int, block_size: int):
+        window, chunk, bs = int(window), int(chunk), int(block_size)
+        assert window % chunk == 0, (window, chunk)
+        self.window, self.chunk, self.block_size = window, chunk, bs
+        self.summaries = window // chunk        # rows a folded window keeps
+        if self.summaries % bs or window % bs:
+            raise ValueError(
+                f"block_size {bs}: a window of {window} tokens and its "
+                f"{self.summaries} summary rows must both be whole blocks")
+        self.summary_blocks = self.summaries // bs
+        self.window_blocks = window // bs
+
+    def row(self, p):
+        """Table row of stream position ``p``: behind the summaries of the
+        windows before its own."""
+        return self.summaries * (p // self.window) + p % self.window
+
+    def column(self, p):
+        """Table column (block) that position ``p`` is written into."""
+        return self.row(p) // self.block_size
+
+    def held(self, n):
+        """Blocks a stream of ``n >= 1`` tokens holds when its newest token
+        is written, before the fold that token may bring."""
+        return self.column(n - 1) + 1
+
+    def charge(self, n):
+        """The most blocks the stream holds in the step that brings it to
+        ``n`` tokens: a window's end needs the fold's new summary blocks
+        BEFORE the window's blocks come home."""
+        return self.held(n) + self.summary_blocks * (n % self.window == 0)
+
+    def life_peak(self, total: int) -> int:
+        """The most blocks a stream of ``total`` tokens ever holds: the
+        fold of its last whole window, or its end if it never folds."""
+        ends = int(total) // self.window
+        return int(self.charge(ends * self.window) if ends
+                   else self.held(total))
+
+    def table_blocks(self, max_seq: int) -> int:
+        """Columns a table needs for any stream of up to ``max_seq``
+        tokens: the last window full behind every earlier one's summaries."""
+        if max_seq <= self.window:
+            return blocks_needed(max_seq, self.block_size)
+        return int(self.held((int(max_seq) // self.window) * self.window))
+
+
+def _folded_peak(L, E, fold: WindowFold) -> int:
+    """:func:`timeline_peak` for holdings that FALL: a stream holds
+    ``fold.charge(n)`` blocks in the step that brings it to ``n`` tokens,
+    which drops after a window's end and at its finish alone, so the sum is
+    read at every stream's window ends and last step."""
+    live = E > L
+    L, E = L[live], E[live]
+    if not L.size:
+        return 0
+    W = fold.window
+    first = L // W + 1                      # window ends k W in (L, E]
+    ks = first[:, None] + np.arange(int((E // W - first).max()) + 1)[None, :]
+    ends = ks[ks <= (E // W)[:, None]] * W - np.repeat(
+        L, np.maximum(E // W - first + 1, 0)) - 1
+    t = np.unique(np.concatenate([E - L - 1, ends]))[:, None]
+    blocks = fold.charge(np.minimum(L + t + 1, E))
+    return int((blocks * (E - L > t)).sum(axis=1).max())
+
+
+def timeline_peak(written, ends, held, block_size: int, fold=None) -> int:
     """The most blocks a set of streams will ever hold at one decode step,
     if each runs to its end: the admission rule's one sum
-    (docs/serving.md#capacity-math--admission-control).
+    (docs/serving.md#capacity-math--admission-control).  ``fold``: the
+    streams' cache folds itself (:class:`WindowFold`): a stream then holds
+    exactly what its length says (``held`` is not read) and the sum is
+    :func:`_folded_peak`'s.
 
     Stream ``i`` has written ``written[i]`` tokens, writes one a step, and
     leaves after ``ends[i]`` at the latest (prompt + ``max_new_tokens``),
@@ -76,6 +156,8 @@ def timeline_peak(written, ends, held, block_size: int) -> int:
     stream: one row of sums a stream, no walk over the steps."""
     L = np.asarray(written, np.int64)
     E = np.asarray(ends, np.int64)
+    if fold is not None:
+        return _folded_peak(L, E, fold)
     left = E - L                           # <= 0: no stream (an empty slot)
     t = left[left > 0, None] - 1           # the last step of each stream
     if not t.size:

@@ -81,6 +81,7 @@ slot assignment, or what else shares the batch (tested:
 
 import collections
 import dataclasses
+import functools
 import os
 import shutil
 import time
@@ -443,12 +444,15 @@ class _Slot:
         # refused (backpressure / publish failure) decodes LOCALLY —
         # the per-stream degrade-to-mixed latch
         self.no_transfer = False
+        # where the cache folds (docs/serving.md#folded-cache): the windows
+        # whose rows are summary blocks by now, at the head of ``blocks``
+        self.folded = 0
 
 
 # ------------------------------------------- what needs a stream to be its blocks
 # `prefix_cache`, `kv_snapshot`, `transfer` and a `role` each move or share a
 # stream BY its K/V blocks: they need a stream to be nothing but the `k` / `v`
-# blocks of one growing table.  Three kinds of serving state are more than
+# blocks of one growing table.  Four kinds of serving state are more than
 # that (a model may show more than one), and serving one with such a feature
 # armed would serve a silently wrong stream: refused at construction, by name,
 # until the feature learns the kind (ROADMAP, Queue 2).  kind -> (what the
@@ -460,6 +464,9 @@ _RING_IMAGE = ("a block image covers the growing table's blocks alone: a "
                "restored stream's window layers would read another stream's "
                "ring")
 _KV_IMAGE = "a block image is int8 K and V with scales a head"
+_FOLDED_IMAGE = ("a block image is the blocks of a growing table, one a "
+                 "block of tokens: a folded stream's table is summary blocks "
+                 "and a window, and the image does not say which is which")
 _NEEDS_BLOCKS_ALONE = {
     "recurrent-state": ("recurrent state", {
         "prefix_cache": "a shared prefix's blocks carry no recurrent state: "
@@ -478,6 +485,13 @@ _NEEDS_BLOCKS_ALONE = {
         "prefix_cache": "the radix cache's copy-on-write is written for k and "
                         "v leaves",
         "kv_snapshot": _KV_IMAGE, "transfer": _KV_IMAGE, "role": _KV_IMAGE}),
+    "compacted-window": ("a cache that folds its windows", {
+        "prefix_cache": "the radix cache keys a block by the block of token "
+                        "ids it holds: a summary block is no function of one "
+                        "block of ids, and a window's blocks go back to the "
+                        "pool at its fold",
+        "kv_snapshot": _FOLDED_IMAGE, "transfer": _FOLDED_IMAGE,
+        "role": _FOLDED_IMAGE}),
 }
 
 
@@ -488,7 +502,8 @@ def _refuse_what_needs_blocks_alone(config, model, pool):
     (a model may show two: recurrent rows beside a ring)."""
     shown = {"recurrent-state": getattr(model, "has_recurrent_state", False),
              "window-layers": getattr(model, "has_window_layers", False),
-             "latent-pool": pk.is_latent_pool(pool)}
+             "latent-pool": pk.is_latent_pool(pool),
+             "compacted-window": getattr(model, "has_folded_cache", False)}
     kinds = [kind for kind in _NEEDS_BLOCKS_ALONE if shown[kind]]
     # the table may be ragged: a feature is refused for the kinds that list it
     for name in dict.fromkeys(n for k in kinds
@@ -561,9 +576,24 @@ class ServingEngine:
         self.model = inner
         mc = inner.config
         self.max_seq = mc.max_seq
-        self.nb_max = pk.blocks_needed(mc.max_seq, config.block_size)
+        # a model whose cache folds itself (docs/serving.md#folded-cache)
+        # says what a stream holds at each length: the table's columns, the
+        # admission sum and the granted block all read it
+        self._fold = (inner.cache_fold(config.block_size)
+                      if getattr(inner, "has_folded_cache", False) else None)
+        if self._fold is None:
+            self.nb_max = pk.blocks_needed(mc.max_seq, config.block_size)
+        else:
+            self.nb_max = self._fold.table_blocks(mc.max_seq)
+            if config.kv_bits != 16:
+                raise ValueError(
+                    f"serving.kv_bits={config.kv_bits} cannot serve a model "
+                    "with a cache that folds its windows: a summary row is "
+                    "kept at 16 bits (docs/serving.md#compacted-window)")
         self.num_blocks = config.num_blocks or (
-            1 + config.batch_slots * self.nb_max)
+            1 + config.batch_slots * (
+                self.nb_max if self._fold is None
+                else self._fold.life_peak(mc.max_seq)))
         assert self.num_blocks >= 2, "num_blocks must be >= 2"
 
         cache_dtype = getattr(inner, "dtype", jnp.bfloat16)
@@ -727,6 +757,9 @@ class ServingEngine:
         _, self._ends, self._held = self._timeline[:, :S]
         self._promised = 0            # the timeline's peak at the last seat
         self._grown_total = 0         # blocks granted to seated rows
+        self._folded_total = 0        # windows folded in decoding
+        self._folded_reported = 0     # of them, by the last step's row
+        self._foldfn = None           # a window's rows into summary rows
         self._tables = np.zeros((S, self.nb_max + self.ring), np.int32)
         self._lengths = np.zeros((S,), np.int32)
         self._toks = np.zeros((S,), np.int32)
@@ -919,7 +952,10 @@ class ServingEngine:
             "capacity_tokens": pk.capacity_tokens(self.pool),
             "pool_bytes": pk.pool_bytes(self.pool),
             "kv_bits": c.kv_bits,
-            "blocks_per_request_at_defaults": ub["total_blocks"],
+            # a folded cache's request peaks at its last window's fold
+            "blocks_per_request_at_defaults": (
+                ub["total_blocks"] if self._fold is None
+                else self._life_blocks(c.block_size + c.max_new_tokens)),
             "free_blocks": self.allocator.free_blocks,
         }
         if self.ring:
@@ -953,9 +989,18 @@ class ServingEngine:
         self._build_decode()
         c = self.config
         bucket = self.nb_max * c.block_size
-        pf = self._prefill_fn(bucket)
+        if self._fold is None:
+            pf = self._prefill_fn(bucket)
+            blocks = jnp.zeros((bucket // c.block_size + self.ring,),
+                               jnp.int32)
+        else:
+            # the largest segment of a folded prompt: a whole window
+            bucket = self._fold.window
+            pf = self._prefill_fn(bucket, window=True)
+            blocks = jnp.zeros(
+                (self.model.summary_table_blocks(c.block_size)
+                 + self._fold.summary_blocks,), jnp.int32)
         toks = jnp.zeros((1, min(bucket, self.max_seq)), jnp.int32)
-        blocks = jnp.zeros((bucket // c.block_size + self.ring,), jnp.int32)
         with jax.set_mesh(self.engine.mesh):
             dec_exe = self._decode.executable(*self._decode_args())
             pre_exe = pf.executable(*self._prefill_args(
@@ -1082,7 +1127,7 @@ class ServingEngine:
             raise ValueError(
                 f"prompt {toks.size} + max_new_tokens {new} = {total} "
                 f"exceeds max_seq {self.max_seq}")
-        nb = pk.blocks_needed(total, self.config.block_size)
+        nb = self._life_blocks(total)
         if nb > self.num_blocks - 1:
             raise ValueError(
                 f"request needs {nb} blocks; the pool only has "
@@ -1289,6 +1334,12 @@ class ServingEngine:
                 # nothing
                 res[0] = self._grow(res[0], res[1], np.full(
                     self._lengths.shape, -1, np.int32))
+                if self._fold is not None:
+                    # the fold's program likewise: scratch into scratch
+                    self.pool = self._foldfn(
+                        self.engine.params, self.pool,
+                        np.zeros((self._fold.window_blocks,), np.int32),
+                        np.zeros((self._fold.summary_blocks,), np.int32))
         self._state_dirty = False
         self._state_uploads += 1
 
@@ -1310,6 +1361,21 @@ class ServingEngine:
             self._ready_state(False)
         return self._operands()
 
+    # ------------------------------------- what a stream holds at a length
+    def _column(self, position):
+        """The table column that stream position ``position`` is written
+        into (host mirrors and the device's lengths alike): the position's
+        block, or where the cache folds, its block behind the summaries."""
+        if self._fold is None:
+            return position // self.config.block_size
+        return self._fold.column(position)
+
+    def _life_blocks(self, total: int) -> int:
+        """The most blocks a stream of ``total`` tokens ever holds."""
+        if self._fold is None:
+            return pk.blocks_needed(total, self.config.block_size)
+        return self._fold.life_peak(total)
+
     # ----------------------------------------- growth: a block at its first write
     def _grant_blocks(self, ahead: bool):
         """Give every seated row whose next write opens a block its table
@@ -1321,8 +1387,7 @@ class ServingEngine:
         The admission rule planned for these blocks when it seated the
         streams (:meth:`_plan`), so the allocator has them: a seated stream
         never waits for a block."""
-        bs = self.config.block_size
-        col = (self._lengths + ahead) // bs
+        col = self._column(self._lengths + ahead)
         rows = np.flatnonzero((col >= self._held) & (self._ends > 0))
         if not rows.size:
             return None
@@ -1426,7 +1491,7 @@ class ServingEngine:
             # a granted block goes where the row's next write lands: one
             # select over the table, no scatter (which a TPU runs a row at
             # a time)
-            here = jnp.arange(nb)[None, :] == (lengths // c.block_size)[:, None]
+            here = jnp.arange(nb)[None, :] == self._column(lengths)[:, None]
             return jnp.where(here & (grant >= 0)[:, None], grant[:, None],
                              tables)
 
@@ -1434,12 +1499,19 @@ class ServingEngine:
             f"serving.unpack[{c.batch_slots}x{nb}]", unpack)
         self._grow = self.engine._wrap_step(
             f"serving.grow[{c.batch_slots}x{nb}]", grow)
+        if self._fold is not None:
+            def fold(params, pool, src, dst):
+                return self.model.fold_paged(deq(params), pool, src, dst)
+
+            self._foldfn = self.engine._wrap_step(
+                f"serving.fold[{self._fold.window}/{self._fold.chunk},"
+                f"{c.block_size}]", fold, donate_argnums=(1,))
         self._decode = self.engine._wrap_step(
             f"serving.decode[{c.batch_slots}x{nb}"
             f"x{c.block_size},kv{c.kv_bits},{c.top_k}]",
             step, donate_argnums=(1,))
 
-    def _prefill_fn(self, bucket: int):
+    def _prefill_fn(self, bucket: int, window: bool = False):
         """Jitted prefill for prompts padded to ``bucket`` tokens: runs
         the model's contiguous cached forward on ONE sequence, scatters
         its K/V into the slot's first blocks, and returns the real last
@@ -1455,16 +1527,20 @@ class ServingEngine:
         executable (same ``_sample_tokens`` stream as the decode step)
         — an eager per-request sampling tail would sit directly on the
         time-to-first-token metric."""
-        fn = self._prefills.get(bucket)
+        key = (bucket, window) if window else bucket
+        fn = self._prefills.get(key)
         if fn is not None:
             return fn
         deq = self._deq
-        model = self.model
+        run = self.model.prefill_paged
+        if window:
+            # a whole window of a folded cache leaves its summaries alone
+            run = functools.partial(run, fold=True)
 
         def prefill(params, toks, pool, blocks, t_real, seed, temp, flag,
-                    slot=None):
-            row, pool = model.prefill_paged(deq(params), toks, pool, blocks,
-                                            slot, t_real)
+                    extra=None):
+            # `extra`: the family's own operand (`_prefill_args`)
+            row, pool = run(deq(params), toks, pool, blocks, extra, t_real)
             # prefill half of the quarantine sentinel: without it, a
             # request whose PREFILL logits are already non-finite would
             # sample a garbage first token — and at max_new_tokens == 1
@@ -1484,21 +1560,25 @@ class ServingEngine:
         # every bucket its own XLA module (``jit_prefill_<bucket>``): a
         # device trace tells the executables apart by module name alone,
         # and ``monitor.device_scopes()`` books an instruction by it
-        prefill.__name__ = f"prefill_{bucket}"
+        prefill.__name__ = "prefill_window" if window else f"prefill_{bucket}"
         fn = self.engine._wrap_step(
-            f"serving.prefill[{bucket},kv{self.config.kv_bits}]", prefill,
-            donate_argnums=(2,))
-        self._prefills[bucket] = fn
+            f"serving.prefill[{'window,' if window else ''}{bucket},"
+            f"kv{self.config.kv_bits}]", prefill, donate_argnums=(2,))
+        self._prefills[key] = fn
         return fn
 
-    def _prefill_args(self, toks, blocks, slot, t_real, seed, temp, flag):
-        """The prefill executable's operands.  The slot rides along only
-        for a model that keeps state per slot: every other family's
-        prefill keeps the eight operands it always had."""
+    def _prefill_args(self, toks, blocks, extra, t_real, seed, temp, flag):
+        """The prefill executable's operands.  ``extra`` is the one operand
+        a family may ask for beside them, handed to its ``prefill_paged``
+        in the fifth place: the SLOT for a model that keeps state per slot,
+        the segment's FIRST POSITION where the cache folds.  Every other
+        family's prefill keeps the eight operands it always had."""
         args = (self.engine.params, jnp.asarray(toks), self.pool,
                 jnp.asarray(blocks), jnp.int32(t_real), jnp.int32(seed),
                 jnp.float32(temp), jnp.asarray(flag))
-        return args + (jnp.int32(slot),) if self._recurrent else args
+        if self._recurrent or self._fold is not None:
+            return args + (jnp.int32(extra),)
+        return args
 
     # ------------------------------------------------------------- scheduler
     def _admit(self):
@@ -1594,6 +1674,8 @@ class ServingEngine:
         """Blocks of the growing table a stream is seated with: what the
         prompt and the first decode write touch; where a seat is the
         stream's whole life (``_whole_life``), all ``total`` tokens' blocks."""
+        if self._fold is not None:
+            return int(self._fold.held(min(prompt_len + 1, total)))
         return pk.blocks_needed(
             total if self._whole_life else min(prompt_len + 1, total),
             self.config.block_size)
@@ -1624,13 +1706,17 @@ class ServingEngine:
         room = (self.num_blocks - 1 - self.allocator.used_blocks
                 + int(self._held.sum()))
         bs = self.config.block_size
-        # were every stream at its end at once, as a reservation for life
-        # has it: where that fits (the slots bind, not the pool) every step
-        # fits, and the rule stops at that sum, a row of it and not a square
-        lives = int(np.maximum(-(-rows[1] // bs), rows[2]).sum())
-        if lives <= room:
-            return lives
-        peak = pk.timeline_peak(*rows, bs)
+        if self._fold is None:
+            # were every stream at its end at once, as a reservation for
+            # life has it: where that fits (the slots bind, not the pool)
+            # every step fits, and the rule stops at that sum, a row of it
+            # and not a square
+            lives = int(np.maximum(-(-rows[1] // bs), rows[2]).sum())
+            if lives <= room:
+                return lives
+        # a folded stream's holding falls at every window's end: the sum
+        # over the steps, read at the window ends and the finishes
+        peak = pk.timeline_peak(*rows, bs, fold=self._fold)
         return peak if peak <= room else None
 
     def _head_plan(self, req: Request, shared: int = 0):
@@ -1769,6 +1855,9 @@ class ServingEngine:
                share: Optional[dict] = None,
                wblocks: Optional[List[int]] = None):
         fault.site("serving.prefill")
+        # frame ballast, as `_step`'s: the two names that moved to `_seat`
+        # (every frame from `step` to a prefill's trace the size it had)
+        _k0 = _k1 = None
         c = self.config
         wblocks = wblocks or []
         T = int(len(req.tokens))
@@ -1784,6 +1873,12 @@ class ServingEngine:
             bucket = pk.blocks_needed(T, c.block_size) * c.block_size
             prefill.attrs = {"prompt_len": T, "bucket": bucket,
                              **self._loop_attrs}
+            if self._fold is not None:
+                # a window at a time: the bucket is the tail's
+                read = self._prefill_folded(req, blocks, slot, prefill)
+                first, bad = self._read_prefill(read, prefill)
+                return self._seat(slot, req, blocks, wblocks, new, first,
+                                  bad, rec)
             if self._recurrent:
                 # what the recurrence walks and what it must not take in;
                 # the dispatch below writes the slot's recurrent rows whole
@@ -1810,6 +1905,15 @@ class ServingEngine:
             # the read syncs the prefill dispatch: the host waits here
             with self._spans.span("serving.prefill.readback"):
                 first, bad = self._read_prefill(read, prefill)
+        self._seat(slot, req, blocks, wblocks, new, first, bad, rec)
+
+    def _seat(self, slot: int, req: Request, blocks: List[int],
+              wblocks: List[int], new: int, first: int, bad: int, rec: dict):
+        """What follows a prefill's read: the stream seated with its first
+        token, or, where the prefill's logits were not finite, quarantined
+        before it ever holds the slot."""
+        c = self.config
+        T = int(len(req.tokens))
         if bad:
             # quarantined AT prefill: the slot is never seated, the
             # sentinel token is never surfaced, and the blocks go back
@@ -1833,6 +1937,8 @@ class ServingEngine:
             return
 
         s = _Slot(req, blocks, T, new, wblocks=wblocks)
+        if self._fold is not None:
+            s.folded = T // self._fold.window
         s.out_tokens.append(first)
         s.hist.append(first)
         self._slots[slot] = s
@@ -1874,6 +1980,88 @@ class ServingEngine:
         prefill.attrs.update(zip(self._counter_names,
                                  (int(x) for x in read[2:])))
         return int(read[0]), int(read[1])
+
+    # ------------------------------------------- a cache that folds itself
+    def _prefill_folded(self, req: Request, blocks: List[int], slot: int,
+                        prefill):
+        """A prompt into a folded cache, a WINDOW AT A TIME
+        (docs/serving.md#folded-cache): every whole window through one
+        executable, which reads the summaries of the windows before it from
+        the pool and leaves its own in the stream's next summary blocks; the
+        tail, padded to its bucket, through the bucket's.  The only state a
+        segment hands the next is the summary blocks it wrote.  Returns the
+        last segment's read (the first token is sampled there)."""
+        c, fold = self.config, self._fold
+        W, sb = fold.window, fold.summary_blocks
+        full, tail = divmod(int(len(req.tokens)), W)
+        bucket = pk.blocks_needed(tail, c.block_size) * c.block_size \
+            if tail else 0
+        prefill.attrs.update(bucket=bucket, windows=full)
+        # the summary table every segment reads: scratch past what is folded
+        table = np.zeros((self.model.summary_table_blocks(c.block_size),),
+                         np.int32)
+        with jax.set_mesh(self.engine.mesh):
+            with self._spans.span("serving.prefill.dispatch"):
+                for j in range(full + bool(tail)):
+                    whole = j < full
+                    own = blocks[j * sb:(j + 1) * sb] if whole else \
+                        blocks[full * sb:full * sb + bucket // c.block_size]
+                    piece = req.tokens[j * W:(j + 1) * W]
+                    toks = np.zeros((1, W if whole else bucket), np.int32)
+                    toks[0, :len(piece)] = piece
+                    read, self.pool = self._prefill_fn(
+                        toks.shape[1], window=whole)(*self._prefill_args(
+                            toks, np.concatenate([table, own]).astype(
+                                np.int32), j * W, len(piece), req.seed,
+                            req.temperature, req.do_sample))
+                    if whole:
+                        table[j * sb:(j + 1) * sb] = own
+                read.copy_to_host_async()
+        with self._spans.span("serving.prefill.readback"):
+            return np.asarray(read)
+
+    def _fold_ended_windows(self, active):
+        """Fold the window of every row of ``active`` whose length has just
+        reached a window's end: the window's exact rows into summary rows in
+        fresh blocks (one small executable a row, the pool donated), its
+        blocks back to the allocator, the row's table rewritten.  The new
+        blocks are taken BEFORE the old come home: the step the admission
+        sum charges ``summary_blocks`` more (``WindowFold.charge``)."""
+        fold = self._fold
+        rows = [i for i in active if self._slots[i] is not None
+                and self._lengths[i] // fold.window > self._slots[i].folded]
+        if not rows:
+            return
+        with self._spans.span("serving.fold") as span:
+            for i in rows:
+                s = self._slots[i]
+                fresh = self.allocator.alloc(fold.summary_blocks)
+                assert fresh is not None, (
+                    f"the pool's timeline broke its promise: a fold needs "
+                    f"{fold.summary_blocks} block(s) and the allocator has "
+                    f"{self.allocator.free_blocks} free")
+                src = s.blocks[-fold.window_blocks:]
+                with jax.set_mesh(self.engine.mesh):
+                    self.pool = self._foldfn(
+                        self.engine.params, self.pool,
+                        np.asarray(src, np.int32),
+                        np.asarray(fresh, np.int32))
+                # in place: the list is the slot's identity elsewhere
+                s.blocks[-fold.window_blocks:] = fresh
+                s.folded += 1
+                if self._sanitizer is not None:
+                    self._sanitizer.on_alloc(fresh, uid=s.req.uid)
+                    self._sanitizer.on_detach(s.req.uid)
+                    self._sanitizer.on_attach(s.req.uid, s.blocks)
+                released = self.allocator.free(src)
+                if self._sanitizer is not None:
+                    self._sanitizer.on_free(released, uid=s.req.uid)
+                self._held[i] = len(s.blocks)
+                self._tables[i] = pk.SCRATCH_BLOCK
+                self._tables[i, :len(s.blocks)] = s.blocks
+            self._folded_total += len(rows)
+            self._state_dirty = True
+            span.attrs = {"windows": len(rows)}
 
     def _start_shared(self, slot: int, req: Request, blocks: List[int],
                       new: int, share: dict):
@@ -2771,6 +2959,11 @@ class ServingEngine:
         if (self._state_dirty or self._settles_every_step(unread.active)
                 or self._admission_due()):
             return False
+        if self._fold is not None and not (
+                (self._lengths[unread.active] + 1) % self._fold.window).all():
+            # the unread step ends a row's window: it is folded, and the
+            # row's table rewritten, before the next step may read it
+            return False
         return not any(len(self._slots[i].out_tokens) + 1
                        >= self._slots[i].max_new for i in unread.active)
 
@@ -2904,6 +3097,23 @@ class ServingEngine:
                "blocks_grown": 0,
                "kv_token_room": (self.num_blocks - 1)
                * self.config.block_size}
+        if self._fold is not None:
+            # the rows the tables hold, not the streams' lengths; how many
+            # blocks are folded history and how many the current windows';
+            # the windows folded, and the blocks their folds gave back,
+            # since the last step's row
+            fold = self._fold
+            summary = fold.summary_blocks * sum(
+                s.folded for s in self._slots if s is not None)
+            folded = self._folded_total - self._folded_reported
+            self._folded_reported = self._folded_total
+            out.update(
+                kv_tokens=int(fold.row(self._lengths).sum()),
+                summary_blocks=summary,
+                window_blocks=int(self._held.sum()) - summary,
+                windows_folded=folded,
+                # a window's blocks are the stream's alone: all come home
+                blocks_released_by_fold=folded * fold.window_blocks)
         if self._recurrent:
             # the slots whose recurrent rows this dispatch advances (and
             # those it leaves), and the bytes of state that takes (read
@@ -3077,13 +3287,15 @@ class ServingEngine:
                     # never changes
                     with spans.span("serving.kv_snapshot"):
                         self._snapshot_slot_safe(i)
+        if self._fold is not None:
+            self._fold_ended_windows(active)
         return len(active), emitted_step, now
 
     def _raise_stalled(self):
         c = self.config
         req: Request = self.queue[0]
         total = len(req.tokens) + req.max_new_tokens
-        nb = pk.blocks_needed(total, c.block_size)
+        nb = self._life_blocks(total)
         seat = self._seat_blocks(len(req.tokens), total)
         # admission failure: the ledger dump makes the block math a
         # forensic artifact, not just an exception message
@@ -3487,6 +3699,7 @@ class ServingEngine:
         self._state_uploads = 0
         self._ahead_steps = 0
         self._grown_total = 0
+        self._folded_total = self._folded_reported = 0
         self._state_seats = 0
         self._outcomes = {k: 0 for k in OUTCOMES}
         self._requeued_total = 0
@@ -3531,6 +3744,12 @@ class ServingEngine:
                # blocks granted to seated rows at the dispatch that first
                # wrote into them (docs/serving.md#capacity-math--admission-control)
                "blocks_grown_total": self._grown_total,
+               # where the cache folds: windows folded in decoding, and the
+               # blocks their folds gave back (docs/serving.md#folded-cache)
+               **({"windows_folded_total": self._folded_total,
+                   "blocks_released_by_fold_total":
+                   self._folded_total * self._fold.window_blocks}
+                  if self._fold is not None else {}),
                "generated_tokens": self._generated_total,
                # what the donated pytree holds: K/V blocks, and for a
                # model with recurrent layers its per-slot rows and how

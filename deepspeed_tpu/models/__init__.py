@@ -10,7 +10,10 @@ double block: two latent-attention sub-layers, two dense FFNs and one expert
 layer that joins late; a router wider than its experts, the rest identity
 experts), Qwen3-Next (Gated DeltaNet layers, whose delta-rule state is read
 before it is written, and gated attention layers at a partial rotary, every
-layer followed by many small experts and a gated shared one)."""
+layer followed by many small experts and a gated shared one), EvaByte (a
+byte-level decoder with EVA attention: a window of exact K/V rows that is
+folded, a chunk to a summary row, at every window's end; eight prediction
+heads)."""
 
 from .gpt2 import GPT2, GPT2Config, PRESETS as GPT2_PRESETS
 
@@ -56,6 +59,9 @@ def build(name, **overrides):
         if name.startswith("qwen3-next"):
             from .qwen3_next import Qwen3Next
             return Qwen3Next(preset=name, **overrides)
+        if name.startswith("evabyte"):
+            from .evabyte import EvaByte
+            return EvaByte(preset=name, **overrides)
         if name.startswith("cifar"):
             from .cifar import CifarCNN
             return CifarCNN(preset=name, **overrides)

@@ -17,13 +17,14 @@ AMBIGUOUS = "ambiguous"
 VERSION = 2
 
 SERVED = ("gpt2", "jamba", "ouro", "deepseek_v2", "afmoe", "nemotron_h",
-          "phi4flash", "longcat_flash", "qwen3_next")
+          "phi4flash", "longcat_flash", "qwen3_next", "evabyte")
 TRAIN = "train"
 _MIXERS = ("jamba", "nemotron_h", "phi4flash")
 _RECURRENT = _MIXERS + ("qwen3_next",)
 _ROUTED = ("deepseek_v2", "afmoe", "nemotron_h", "longcat_flash",
            "qwen3_next")
 _DELTA = ("qwen3_next",)
+_FOLDED = ("evabyte",)
 _LATENT = ("deepseek_v2", "longcat_flash")
 # The one vocabulary of device scopes: ``{scope: (who opens it, what it
 # covers)}``, "who" the served families (``models/<family>.py``'s decode
@@ -37,7 +38,7 @@ VOCABULARY = {
                   "an attention sub-layer outside its core: norm, q/k/v and "
                   "output projections, residual (GPT-2: the whole sub-layer)"),
     "mlp": (("gpt2", "jamba", "ouro", "deepseek_v2", "afmoe", "phi4flash",
-             "longcat_flash", TRAIN),
+             "longcat_flash", "evabyte", TRAIN),
             "a dense feed-forward sub-layer with its norm and residual"),
     "blocks": ((TRAIN,), "the layer loop of the GPT-2 training forward"),
     "lm_head": (SERVED + (TRAIN,), "the final norm and the vocabulary "
@@ -49,8 +50,8 @@ VOCABULARY = {
     "optimizer": ((TRAIN,), "gradient clipping, the optimizer's update, the "
                   "skip-step's select and the cast of the new parameters"),
     "kv.seat": (SERVED, "the K/V (or latent row) pool writes"),
-    "rope": (("ouro", "afmoe") + _LATENT, "the rotary table lookup and "
-             "rotation of queries and keys"),
+    "rope": (("ouro", "afmoe") + _LATENT + _FOLDED, "the rotary table "
+             "lookup and rotation of queries and keys"),
     "attn.window": (("afmoe", "phi4flash"),
                     "a sliding-window layer's attention core, prefill band "
                     "and decode ring alike"),
@@ -79,6 +80,15 @@ VOCABULARY = {
     "gdn.chunk": (_DELTA, "a prompt's chunked delta rule"),
     "gdn.gate_norm": (_DELTA, "the gated norm a head and the output "
                       "projection"),
+    "eva.summarise": (_FOLDED, "the fold: a window's exact K/V rows into "
+                      "one summary row a chunk, in a prompt's whole windows "
+                      "and for a window that ends in decoding"),
+    "eva.prompt_attention": (_FOLDED, "a prompt window's (or tail's) "
+                             "queries over the summaries before it and its "
+                             "own keys, one softmax"),
+    "eva.decode_attention": (_FOLDED, "a decode step's attention over a "
+                             "slot's table of summary rows and window rows "
+                             "(round the paged kernel)"),
     "moe.route": (_ROUTED, "router scores, top-k, the step's counters"),
     "moe.experts": (_ROUTED, "the held experts' grouped products with "
                     "their gather and combine"),

@@ -229,7 +229,7 @@ def test_every_named_scope_of_the_models_is_in_the_vocabulary():
     assert opened == set(scope_maps.VOCABULARY)
 
 
-SERVED = {   # family -> (preset, overrides, block size)
+SERVED = {   # family -> (preset, overrides, block size[, prompt tokens])
     "gpt2": ("gpt2-tiny", {"n_layer": 2, "max_seq": 64}, 8),
     "jamba": ("jamba-tiny", {"max_position_embeddings": 64}, 8),
     "ouro": ("ouro-tiny", {"max_position_embeddings": 64}, 8),
@@ -240,6 +240,8 @@ SERVED = {   # family -> (preset, overrides, block size)
     "longcat_flash": ("longcat-flash-tiny", {}, 8),
     "qwen3_next": ("qwen3-next-tiny", {"max_position_embeddings": 64,
                                        "num_hidden_layers": 4}, 8),
+    # a prompt past one window of 16: the whole-window prefill folds
+    "evabyte": ("evabyte-tiny", {"max_position_embeddings": 64}, 4, 21),
 }
 
 
@@ -286,13 +288,13 @@ def test_every_scope_the_table_lists_is_in_the_executables(family, devices):
         engine.close()
         assert want <= got, sorted(want - got)
         return
-    preset, overrides, block = SERVED[family]
+    preset, overrides, block, *prompt = SERVED[family]
     eng = ds.init_inference(build(preset, dtype=jnp.float32, **overrides),
                             dtype=jnp.float32)
     srv = ServingEngine(engine=eng, config={"batch_slots": 2,
                                             "block_size": block})
-    srv.run([Request(tokens=np.arange(1, 12, dtype=np.int32),
-                     max_new_tokens=3)])
+    srv.run([Request(tokens=np.arange(1, 1 + (prompt or [11])[0],
+                                      dtype=np.int32), max_new_tokens=3)])
     maps = device_scopes()
     assert any(m.startswith("jit_prefill_") for m in maps), sorted(maps)
     got = _scopes_in(maps, "jit_step", "jit_prefill_")

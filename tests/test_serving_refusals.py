@@ -1,9 +1,9 @@
 """What needs a stream to be nothing but its K/V blocks, and who is refused it.
 
 ``prefix_cache``, ``kv_snapshot``, ``transfer`` and a ``role`` move or share a
-stream BY the ``k`` / ``v`` blocks of one growing table.  Three kinds of
+stream BY the ``k`` / ``v`` blocks of one growing table.  Four kinds of
 serving state are more than that (recurrent rows, a ring of window blocks, a
-latent row), and ``inference/serving.py`` keeps ONE table of them
+latent row, a table that folds its windows into summary blocks), and ``inference/serving.py`` keeps ONE table of them
 (``_NEEDS_BLOCKS_ALONE``): every family that shows a kind is refused every
 such feature at construction, by the feature's name, with the kind's phrase
 and an anchor of ``docs/serving.md`` that exists."""
@@ -26,7 +26,9 @@ FAMILIES = {
     "nemotron_h": ("nemotron-h-tiny", "recurrent state", "recurrent-state"),
     "qwen3_next": ("qwen3-next-tiny", "recurrent state", "recurrent-state"),
     "trinity": ("afmoe-tiny", "sliding-window layers", "window-layers"),
-    "deepseek_v2": ("deepseek-v2-tiny", "a latent KV pool", "latent-pool")}
+    "deepseek_v2": ("deepseek-v2-tiny", "a latent KV pool", "latent-pool"),
+    "evabyte": ("evabyte-tiny", "a cache that folds its windows",
+                "compacted-window")}
 FEATURES = {"prefix_cache": ("prefix_cache", True),
             "kv_snapshot": ("kv_snapshot", {"every_tokens": 4}),
             "transfer": ("transfer", {"dir": "/nonexistent"}),
@@ -66,7 +68,9 @@ def test_what_needs_a_stream_to_be_its_blocks_is_refused_by_name(
             rf"\(docs/serving\.md#{anchor}\)$")
     with pytest.raises(ValueError, match=said):
         ServingEngine(engine=engines(family), config={
-            "batch_slots": 2, "block_size": 8, "journal_dir": "/nonexistent",
+            "batch_slots": 2, "journal_dir": "/nonexistent",
+            # the tiny fold's 4 summary rows a window are whole blocks
+            "block_size": 4 if family == "evabyte" else 8,
             name: value})
     assert anchor in headings
 

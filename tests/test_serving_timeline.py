@@ -82,6 +82,75 @@ def test_timeline_peak_equals_a_walk_over_the_steps(bs, whole):
     assert pk.timeline_peak([5], [5 * bs], [7], bs) == 7
 
 
+# --------------------------- (1b) a cache that folds: holdings that fall
+def _walk_folded(written, ends, fold):
+    """The peak of a folded cache's sum, by walking every step: a stream
+    holds ``fold.charge(n)`` blocks in the step that brings it to ``n``."""
+    peak = 0
+    for t in range(int(max(max(e - w for w, e in zip(written, ends)), 0))):
+        peak = max(peak, sum(int(fold.charge(min(w + t + 1, e)))
+                             for w, e in zip(written, ends) if t < e - w))
+    return peak
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("window, chunk, bs", [
+    (16, 4, 4), (16, 4, 2), (64, 8, 8), (2048, 16, 64)])
+def test_the_folded_sum_equals_a_walk_over_every_step(window, chunk, bs,
+                                                      seed):
+    """Random sawtooth streams (a stream gives ``window / bs`` blocks back
+    and takes ``window / chunk / bs`` at every window's end, having held both
+    for a step): the sum read at the window ends and the finishes is the
+    walk's peak, and stands at a window's end, not at a finish, in a good
+    part of the draws."""
+    fold = pk.WindowFold(window, chunk, bs)
+    rng = np.random.default_rng([seed, window, bs])
+    at_a_fold = 0
+    for _ in range(60 if window < 2048 else 6):
+        n = int(rng.integers(1, 9))
+        written = rng.integers(0, 4 * window, n)
+        ends = written + rng.integers(-2, 3 * window, n)   # some not live
+        got = pk.timeline_peak(written, ends, np.zeros(n), bs, fold=fold)
+        assert got == _walk_folded(written, ends, fold)
+        finishes = [sum(int(fold.charge(min(w + t + 1, e)))
+                        for w, e in zip(written, ends) if t < e - w)
+                    for t in {int(e - w - 1) for w, e in zip(written, ends)
+                              if e > w}]
+        at_a_fold += got > max(finishes, default=0)
+    assert at_a_fold > 0
+    assert pk.timeline_peak([], [], [], bs, fold=fold) == 0
+    # one stream alone: the fold of its last whole window
+    assert pk.timeline_peak([0], [3 * window + 5], [0], bs, fold=fold) \
+        == fold.life_peak(3 * window + 5) \
+        == 3 * fold.summary_blocks + fold.window_blocks
+
+
+@pytest.mark.parametrize("whole", [False, True], ids=["grown", "some_whole"])
+@pytest.mark.parametrize("bs", [16, 64])
+def test_a_window_no_stream_reaches_is_the_growing_table(bs, whole):
+    """Monotone holdings, case by case: a fold whose window no stream's end
+    reaches holds ``blocks_needed`` at every length, and its sum is the
+    growing table's, which stays the default and reads what it read."""
+    fold = pk.WindowFold(1 << 20, bs, bs)
+    rng = np.random.default_rng(bs + whole)
+    for _ in range(100):
+        n = int(rng.integers(1, 20))
+        prompts = rng.integers(1, 40 * bs // 8, n)
+        ends = prompts + rng.integers(1, 30 * bs // 8, n)
+        written = np.minimum(prompts + rng.integers(0, 400, n), ends - 1)
+        held = np.array([pk.blocks_needed(w + 1, bs) for w in written])
+        grown = pk.timeline_peak(written, ends, held, bs)
+        assert grown == _walk(written, ends, held, bs)
+        assert grown == pk.timeline_peak(written, ends, held, bs, fold=fold)
+        assert all(int(fold.held(m)) == pk.blocks_needed(m, bs)
+                   for m in (1, bs, bs + 1, 7 * bs))
+        if whole:
+            # what a seat holds beyond its length is the default rule's alone
+            more = held + 3
+            assert pk.timeline_peak(written, ends, more, bs) == _walk(
+                written, ends, more, bs)
+
+
 # ------------------------------------- (2) random backlogs over a pool that binds
 def _backlog(seed, n=14):
     rng = np.random.default_rng(seed)
