@@ -322,7 +322,7 @@ class Afmoe:
 
     def _moe(self, pm, u, layer, live=None):
         """Expert layer ``layer`` (of the stacked ``pm``) over ``u`` (B, T,
-        D), the normed stream in the model dtype: ``(output, counters (5,),
+        D), the normed stream in the model dtype: ``(output, counters (7,),
         experts (B T, k))``; ``live`` (B, T) bool leaves pad rows and empty
         slots out of the counts."""
         c = self.config
@@ -338,12 +338,12 @@ class Afmoe:
                 bias=pm["expert_bias"][layer], norm_topk_prob=c.route_norm,
                 routed_scaling_factor=c.route_scale, scale_normed=True)
             counts = dropless.route_counters(
-                experts, *c.held,
+                experts, *c.held, width=c.num_experts,
                 live=None if live is None else live.reshape(-1))
         with jax.named_scope("moe.experts"):
             routed = dropless.held_experts(
                 x, experts, weights, pm["gate_w"], pm["up_w"], pm["down_w"],
-                c.held[0], layer=layer)
+                c.held[0], layer=layer, width=c.num_experts)
         with jax.named_scope("moe.shared"):
             shared = swiglu({"gate_w": pm["shared_gate_w"][layer],
                              "up_w": pm["shared_up_w"][layer],
@@ -354,7 +354,7 @@ class Afmoe:
         """Layer ``l`` from its attention's output ``out`` (B, T, H hd) on:
         the output gate (from the layer's normed input, worked out again
         here), ``o_proj``, the residual, the MLP or the expert layer, the
-        residual.  ``(h, counters (5,), experts (B T, k) or None)``."""
+        residual.  ``(h, counters (7,), experts (B T, k) or None)``."""
         c = self.config
         eps, f32, Ld = c.rms_norm_eps, jnp.float32, c.n_dense_layer
         with jax.named_scope("attn.gate"):
@@ -402,7 +402,7 @@ class Afmoe:
         (a layer's kind of attention and of MLP is static).  ``positions``
         (B, T); ``attn_fn(q, k, v, l, carry)`` attends for layer ``l`` and
         returns ``((B, T, H hd), carry)``.  Returns ``(h, carry, counters
-        (5,) summed over the expert layers, routes (expert layers, B T, k)
+        (7,) summed over the expert layers, routes (expert layers, B T, k)
         or None)``; ``with_routes`` is for a stream that is not cut into
         chunks (a decode step)."""
         c = self.config

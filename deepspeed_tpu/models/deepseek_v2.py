@@ -304,7 +304,7 @@ class DeepseekV2:
         """The expert layer's output for ``u`` (B, T, D), the normed stream
         in the model dtype: the held experts' part and the shared experts.
         ``pm``: one layer's leaves, or (``layer`` given) every layer's,
-        stacked.  Returns ``(output (B, T, D), counters (5,), experts (B T,
+        stacked.  Returns ``(output (B, T, D), counters (7,), experts (B T,
         k))``; ``live`` (B, T) bool leaves pad rows and empty slots out of
         the counts."""
         c = self.config
@@ -323,12 +323,12 @@ class DeepseekV2:
                 norm_topk_prob=c.norm_topk_prob,
                 routed_scaling_factor=c.routed_scaling_factor)
             counts = dropless.route_counters(
-                experts, *c.held,
+                experts, *c.held, width=c.n_routed_experts,
                 live=None if live is None else live.reshape(-1))
         with jax.named_scope("moe.experts"):
             routed = dropless.held_experts(
                 x, experts, weights, pm["gate_w"], pm["up_w"], pm["down_w"],
-                c.held[0], layer=layer)
+                c.held[0], layer=layer, width=c.n_routed_experts)
         with jax.named_scope("moe.shared"):
             shared = swiglu({"gate_w": at(pm["shared_gate_w"]),
                              "up_w": at(pm["shared_up_w"]),
@@ -343,7 +343,7 @@ class DeepseekV2:
         ONE loop over the stacked weights, indexed in place (serving: a
         slice of a stack would copy it every call), or, ``sliced``, scanned
         over (training: gradients flow into the slices).  Returns ``(h,
-        carry, counters (5,) summed over the expert layers, routes)``:
+        carry, counters (7,) summed over the expert layers, routes)``:
         ``routes`` (expert layers, B T, k), the experts every token was
         routed to, where ``with_routes`` asks for them, else None."""
         c = self.config

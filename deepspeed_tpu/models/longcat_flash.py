@@ -174,7 +174,8 @@ class LongcatFlash:
     # told apart from those that fell to another chip (the three kinds of
     # pair sum to ``moe_topk`` x live tokens x layers)
     step_counters = ("routed_pairs", "pairs_elsewhere", "zero_pairs",
-                     "experts_touched", "experts_idle", "tokens_unrouted")
+                     "experts_touched", "experts_idle", "tokens_unrouted",
+                     "calls_compacted", "calls_whole")
 
     def __init__(self, config: Optional[LongcatFlashConfig] = None,
                  preset: str = None, dtype=jnp.bfloat16, **overrides):
@@ -264,7 +265,7 @@ class LongcatFlash:
         the model dtype): the held real experts' part and the identity
         experts'.  ``pm``: one layer's leaves, or (``layer`` given) every
         layer's, stacked.  Returns ``(output (B, T, D) float32, counters
-        (6,) in ``step_counters``' order, experts (B T, k))``; ``live`` (B,
+        (8,) in ``step_counters``' order, experts (B T, k))``; ``live`` (B,
         T) bool leaves pad rows and empty slots out of the counts."""
         c = self.config
         x32 = u.reshape(-1, u.shape[-1]).astype(jnp.float32)
@@ -280,17 +281,26 @@ class LongcatFlash:
                 logits, c.moe_topk, scoring_func="softmax",
                 routed_scaling_factor=c.routed_scaling_factor,
                 bias=at(pm["router_bias"]))
-            held, elsewhere, touched, idle, unrouted = \
-                dropless.route_counters(experts, *c.held, live=live)
+            held, elsewhere, touched, idle, unrouted, *calls = \
+                dropless.route_counters(
+                    experts, *c.held, live=live, width=c.router_width,
+                    rows=self._moe_chunks(x.shape[0])[1])
             zero = dropless.zero_pairs(experts, c.n_routed_experts, live)
             counts = jnp.stack([held, elsewhere - zero, zero, touched, idle,
-                                unrouted])
+                                unrouted, *calls])
         with jax.named_scope("moe.experts"):
             routed = self._held_experts(pm, x, experts, weights, layer)
         with jax.named_scope("moe.zero"):
             out = routed.astype(jnp.float32) + dropless.zero_experts(
                 x32, experts, weights, c.n_routed_experts)
         return out.reshape(u.shape), counts, experts
+
+    @staticmethod
+    def _moe_chunks(N):
+        """``(chunks, tokens a chunk)`` of :meth:`_held_experts` over ``N``
+        tokens."""
+        n = -(-N // _MOE_CHUNK)
+        return n, -(-N // n)
 
     def _held_experts(self, pm, x, experts, weights, layer):
         """``dropless.held_experts`` over ``x`` (N, D), a long prompt in
@@ -309,11 +319,10 @@ class LongcatFlash:
         N = x.shape[0]
         held = lambda xs: dropless.held_experts(
             *xs, pm["gate_w"], pm["up_w"], pm["down_w"], c.held[0],
-            layer=layer)
-        if N <= _MOE_CHUNK:
+            layer=layer, width=c.router_width)
+        n, size = self._moe_chunks(N)
+        if n == 1:
             return held((x, experts, weights))
-        n = -(-N // _MOE_CHUNK)
-        size = -(-N // n)
         cut = lambda a, fill=0: jnp.pad(
             a, ((0, n * size - N),) + ((0, 0),) * (a.ndim - 1),
             constant_values=fill).reshape((n, size) + a.shape[1:])
@@ -326,7 +335,7 @@ class LongcatFlash:
         ``attn_fn(p, q_nope, q_pe, c_kv, k_pe, i, carry)`` attends for
         sub-layer ``i = 2 l + s`` and returns ``((B, T, H v), carry)``.  ONE
         loop over the stacked weights, indexed in place.  Returns ``(h,
-        carry, counters (6,) summed over the layers, routes)``: ``routes``
+        carry, counters (8,) summed over the layers, routes)``: ``routes``
         (layers, B T, k), the experts every token was routed to, where
         ``with_routes`` asks for them, else None."""
         c = self.config

@@ -408,7 +408,7 @@ class NemotronH:
 
     def _moe(self, pm, h, layer, live=None):
         """Expert layer ``layer`` (of the stacked ``pm``) over the stream ``h``
-        (B, T, D): ``(h, counters (5,), experts (B T, k))``; ``live`` (B, T)
+        (B, T, D): ``(h, counters (7,), experts (B T, k))``; ``live`` (B, T)
         bool leaves pad rows and empty slots out of the counts."""
         c = self.config
         eps = c.layer_norm_epsilon
@@ -428,12 +428,13 @@ class NemotronH:
                 routed_scaling_factor=c.routed_scaling_factor,
                 scale_normed=True)
             counts = dropless.route_counters(
-                experts, *c.held,
+                experts, *c.held, width=c.n_routed_experts,
                 live=None if live is None else live.reshape(-1))
         with jax.named_scope("moe.experts"):
             routed = dropless.held_experts(
                 x, experts, weights, None, pm["up_w"], pm["down_w"],
-                c.held[0], layer=layer, act=c.mlp_hidden_act)
+                c.held[0], layer=layer, act=c.mlp_hidden_act,
+                width=c.n_routed_experts)
         with jax.named_scope("moe.shared"):
             shared = _mm(act(_mm(x, pm["shared_up_w"][layer])),
                          pm["shared_down_w"][layer])
@@ -444,7 +445,7 @@ class NemotronH:
         """The float32 stream ``h`` (B, T, D) through every layer, unrolled (a
         layer's kind is static).  ``mamba_fn(p, h, m, carry)`` and
         ``attn_fn(p, h, a, carry)`` run a mixer with its residual and return
-        ``(h, carry)``.  Returns ``(h, carry, counters (5,) summed over the
+        ``(h, carry)``.  Returns ``(h, carry, counters (7,) summed over the
         expert layers, routes (expert layers, B T, k))``."""
         counts = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
         routes = []

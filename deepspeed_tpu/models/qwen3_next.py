@@ -454,7 +454,7 @@ class Qwen3Next:
 
     def _moe(self, pm, h, layer, live=None):
         """Expert layer ``layer`` (of the stacked ``pm``) over the stream ``h``
-        (B, T, D): ``(h, counters (5,), experts (B T, k))``; ``live`` (B, T)
+        (B, T, D): ``(h, counters (7,), experts (B T, k))``; ``live`` (B, T)
         bool leaves pad rows and empty slots out of the counts."""
         c = self.config
         u = _rms0(h, pm["ln2"][layer], c.rms_norm_eps)
@@ -470,12 +470,13 @@ class Qwen3Next:
                 logits, c.num_experts_per_tok, scoring_func="softmax",
                 norm_topk_prob=c.norm_topk_prob)
             counts = dropless.route_counters(
-                experts, *c.held,
+                experts, *c.held, width=c.num_experts,
                 live=None if live is None else live.reshape(-1))
         with jax.named_scope("moe.experts"):
             routed = dropless.held_experts(
                 x, experts, weights, pm["gate_w"], pm["up_w"], pm["down_w"],
-                c.held[0], layer=layer, act=c.hidden_act)
+                c.held[0], layer=layer, act=c.hidden_act,
+                width=c.num_experts)
         with jax.named_scope("moe.shared"):
             shared = _mm(act(_mm(x, pm["shared_gate_w"][layer]))
                          * _mm(x, pm["shared_up_w"][layer]),
@@ -492,7 +493,7 @@ class Qwen3Next:
         ``attn_fn(p, h, a, carry)`` run a mixer with its residual and return
         ``(h, carry)``; the expert layer of a long prompt runs in chunks of
         tokens (``Afmoe._over_tokens``: it lays out ``num_experts_per_tok``
-        rows a token).  Returns ``(h, carry, counters (5,) summed over the
+        rows a token).  Returns ``(h, carry, counters (7,) summed over the
         layers, routes (layers, B T, k) or None)``; ``with_routes`` is for a
         stream that is not cut into chunks (a decode step)."""
         B, T = h.shape[:2]

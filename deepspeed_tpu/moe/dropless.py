@@ -38,6 +38,8 @@ buffer and drops what overflows; it stays, for ``gpt2_moe``.  Here:
 """
 
 import collections
+import functools
+import math
 import time
 
 import jax
@@ -111,27 +113,46 @@ def route(logits, k, *, topk_method="greedy", n_group=1, topk_group=1,
 
 # the counters an expert layer reports, in this order (int32 each)
 COUNTERS = ("routed_pairs", "pairs_elsewhere", "experts_touched",
-            "experts_idle", "tokens_unrouted")
+            "experts_idle", "tokens_unrouted", "calls_compacted",
+            "calls_whole")
 
 
-def route_counters(experts, first, count, live=None):
-    """What fell where, for one layer: ``(5,)`` int32 in :data:`COUNTERS`'
+def route_counters(experts, first, count, live=None, width=None, rows=None):
+    """What fell where, for one layer: ``(7,)`` int32 in :data:`COUNTERS`'
     order.  ``routed_pairs``: token-expert pairs of held experts;
     ``pairs_elsewhere``: the others (together ``k`` x the live tokens);
     ``experts_touched`` / ``experts_idle``: held experts with a token and
     with none; ``tokens_unrouted``: live tokens none of whose experts is
-    held.  ``live`` (N,) bool leaves rows out (pad rows, empty slots)."""
+    held.  ``live`` (N,) bool leaves rows out (pad rows, empty slots).
+
+    ``calls_compacted`` / ``calls_whole``: the calls of :func:`held_experts`
+    over these ``experts`` (``width`` as given to it; one call, or one every
+    ``rows`` tokens where the family cuts a long prompt) whose held pairs
+    took ONE slab of the narrow width and more than one, by the same
+    :func:`compact_width` and the same count: every row's pairs, live or
+    not, for the call gathers those too.  Both 0 where the call keeps the
+    whole-width path."""
     N, k = experts.shape
     live = jnp.ones((N,), bool) if live is None else live
     local = experts - first
-    held = (local >= 0) & (local < count) & live[:, None]
+    seen = (local >= 0) & (local < count)
+    held = seen & live[:, None]
     per_expert = jnp.zeros((count,), jnp.int32).at[
         jnp.where(held, local, count)].add(1, mode="drop")
     touched = (per_expert > 0).sum()
     n_held = held.sum()
+    rows = N if rows is None else rows
+    C = compact_width(rows, k, count, width)
+    compacted = whole = 0
+    if C is not None:
+        calls = -(-N // rows)
+        in_call = jnp.pad(seen, ((0, calls * rows - N), (0, 0))).reshape(
+            calls, rows * k).sum(axis=1)
+        compacted = (in_call <= C).sum()
+        whole = calls - compacted
     return jnp.stack([
         n_held, k * live.sum() - n_held, touched, count - touched,
-        (live & ~held.any(axis=1)).sum()]).astype(jnp.int32)
+        (live & ~held.any(axis=1)).sum(), compacted, whole]).astype(jnp.int32)
 
 
 def zero_experts(x, experts, weights, n_real):
@@ -162,6 +183,49 @@ _GMM_ROWS = 128         # the rows of a tile: a held expert meets 5 to 130
 _TILE_BYTES = 9 << 19   # the most one copy of a tile of the matrices holds:
 #                         4.5 MiB, 3,072 x 768 bfloat16
 _VMEM_BYTES = 15 << 20  # what a plan may hold of the 16 MiB Mosaic scopes a call
+# :func:`compact_width`: a call of up to this many token-expert pairs keeps
+# the whole-width path, and the narrow width is this many times the held
+# experts' even share of the pairs.  Measured (ms a call of ``held_experts``
+# alone on a v5e, bfloat16, the merged stack, even routing, whole width ->
+# one slab of the narrow width; PERF.md section 6, PR 57):
+#   Qwen3-Next 4,096 tokens x 10 (64 of 512 held)  5.03 -> 2.22;
+#     2,048 tokens                                  2.25 -> 1.21
+#   LongCat 2,048 x 12 (16 of 768)  8.23 -> 3.16;  256 x 12     2.13 -> 1.81
+#   DeepSeek-V2 1,024 x 6 (20 of 160)  2.51 -> 1.86;  384 x 6   1.59 -> 1.51
+#   Trinity 4,096 x 4 (32 of 256)  4.92 -> 4.42
+#   Nemotron 1,024 x 6 (32 of 128: half the width)  1.58 -> 1.45;
+#     384 x 6 (2,304 pairs, the smallest call over the threshold) 1.138 -> 1.108
+# A factor of 1.5 read 1 to 10 % under 2 and 1.25 another 1 to 3 %; 2 keeps
+# a prompt whose routing is twice as skewed as even in one pass.  No shape
+# over 2,048 pairs lost, and every decode step of the five served cells
+# (1,920 pairs at most) lies under it.
+_COMPACT_MIN_PAIRS = 2048
+_COMPACT_FACTOR = 2
+
+
+def compact_width(N, k, count, width):
+    """The narrow width ``C`` of :func:`held_experts` over ``N`` tokens of
+    ``k`` picks each, ``count`` experts held of the ``width`` the router
+    picks from; ``None`` where the call keeps the whole-width path.
+
+    From what the call can see and nothing else: ``count / width`` of the
+    ``N k`` pairs fall to the held experts if the routing is even, and ``C``
+    is :data:`_COMPACT_FACTOR` times that, rounded up to the grouped
+    product's tile of rows.  No narrow width where the router's width is
+    not given, where the whole width is :data:`_COMPACT_MIN_PAIRS` pairs or
+    fewer (every decode step of the served cells lowers to the operations
+    it had), where ``C`` is over half the whole width (the way back by token
+    for under half the rows: Nemotron's quarter held stands at the edge and
+    reads a tie), or
+    where a token picks more experts than a tile has rows
+    (:func:`_sum_by_token` finds a token's rows in two tiles at most)."""
+    pairs = N * k
+    if width is None or pairs <= _COMPACT_MIN_PAIRS or k > _GMM_ROWS:
+        return None
+    C = math.ceil(_COMPACT_FACTOR * pairs * count / width)
+    C = -(-C // _GMM_ROWS) * _GMM_ROWS
+    return C if 2 * C <= pairs else None
+
 
 # every grouped product traced in this process, oldest first:
 # (time.monotonic(), "gmm 128x5120x384 of 768x5120x1536/120")
@@ -306,7 +370,7 @@ def grouped_product(rows, w, sizes, transposed=False, interpret=None):
 
 
 def held_experts(x, experts, weights, gate_w, up_w, down_w, first,
-                 layer=None, act="silu"):
+                 layer=None, act="silu", width=None):
     """The held experts' part of the routed output.
 
     - ``x`` (N, D): the normed tokens, in the compute dtype;
@@ -321,12 +385,49 @@ def held_experts(x, experts, weights, gate_w, up_w, down_w, first,
       front of a grouped product (315 MB a matrix at DeepSeek-V2's widths:
       found by compiling for a v5e), so the stack goes in whole, its two
       leading dims merged, as ``layers x count`` groups of which only
-      ``layer``'s have rows.
+      ``layer``'s have rows;
+    - ``width``: how many experts the router picks from (its outputs), from
+      which :func:`compact_width` reckons how many of the ``N k`` pairs the
+      held experts meet if the routing is even.
 
     Returns (N, D): ``sum_i weights[n, i] * Expert^{experts[n, i]}(x[n])``
     over the pairs whose expert is held.  The pairs are sorted by expert
     (the absent experts' pairs last, in no group), so each held expert's
-    matrices meet only the rows routed to it."""
+    matrices meet only the rows routed to it.
+
+    Where :func:`compact_width` gives a narrow width ``C`` the held pairs,
+    which the sort puts first, are worked a SLAB of ``C`` of the sorted list
+    at a time, as many slabs as hold a held pair (``ceil(held / C)``: a loop
+    whose count is the call's own; one pass wherever the held pairs are
+    within twice their even share, none where no pair is held): a slab's
+    ``C`` rows of D are gathered, multiplied out (``C`` rows a product, each
+    group's rows those of it that lie in the slab) and summed, weighted, by
+    token (:func:`_sum_by_token`) into a float32 (N, D).  Nothing of ``N k``
+    rows by D or F is written or read on any path, only int32 vectors of that
+    length; nothing is dropped and there is no capacity.  One set of grouped
+    products serves every count (a second branch at another width would
+    trace and lower three more Mosaic calls an executable: 30 to 50 % of a
+    prefill executable's trace on DeepSeek-V2 and LongCat, whose layers are
+    one loop body; PERF.md section 6, PR 57).  A token's pairs are summed in
+    float32 either way, in the whole form in pair order and here a tile of
+    the token-sorted rows at a time and slab after slab: the same sum of at
+    most ``k`` terms within float32 rounding, and the same from run to run.
+
+    The body is a ``jax.jit`` of its own (inlined by XLA, so a caller's
+    executable holds the same operations): a model that unrolls its layers
+    traces and lowers the layer's body once an executable and not once a
+    layer."""
+    N, k = experts.shape
+    return _held_experts(
+        x, experts, weights, gate_w, up_w, down_w, first, layer, act=act,
+        C=compact_width(N, k, up_w.shape[-3], width))
+
+
+@functools.partial(jax.jit, static_argnames=("act", "C"))
+def _held_experts(x, experts, weights, gate_w, up_w, down_w, first, layer, *,
+                  act, C):
+    """:func:`held_experts` with its narrow width ``C`` (or None) worked
+    out."""
     N, k = experts.shape
     act = activation(act)
     count = up_w.shape[-3]
@@ -342,19 +443,114 @@ def held_experts(x, experts, weights, gate_w, up_w, down_w, first,
         gate_w, up_w, down_w = (
             None if w is None else w.reshape((n,) + w.shape[2:])
             for w in (gate_w, up_w, down_w))
-    rows = x[order // k]                                         # (N k, D)
     dt = x.dtype
-    if gate_w is None:
-        h = act(grouped_product(rows, up_w.astype(dt), sizes, transposed=True))
-    else:
-        h = act(grouped_product(rows, gate_w.astype(dt), sizes)) \
-            * grouped_product(rows, up_w.astype(dt), sizes)
-    out = grouped_product(h, down_w.astype(dt), sizes)           # (N k, D)
-    # back to token order: pair j of the sorted list is pair order[j]
-    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
-        jnp.arange(N * k, dtype=jnp.int32))
-    out = out[back].reshape(N, k, -1).astype(jnp.float32)
-    # an absent expert's pair took part in no group: whatever its row holds
-    # is not read
-    w = jnp.where(held.reshape(N, k), weights, 0.0)[..., None]
-    return jnp.where(w != 0, out * w, 0.0).sum(axis=1).astype(dt)
+
+    def products(rows, sizes):
+        if gate_w is None:
+            h = act(grouped_product(rows, up_w.astype(dt), sizes,
+                                    transposed=True))
+        else:
+            h = act(grouped_product(rows, gate_w.astype(dt), sizes)) \
+                * grouped_product(rows, up_w.astype(dt), sizes)
+        return grouped_product(h, down_w.astype(dt), sizes)
+
+    if C is None:
+        with jax.named_scope("moe.gather"):
+            rows = x[order // k]                                 # (N k, D)
+        out = products(rows, sizes)                              # (N k, D)
+        with jax.named_scope("moe.gather"):
+            # back to token order: pair j of the sorted list is pair order[j]
+            back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+                jnp.arange(N * k, dtype=jnp.int32))
+            out = out[back].reshape(N, k, -1).astype(jnp.float32)
+            # an absent expert's pair took part in no group: whatever its
+            # row holds is not read
+            w = jnp.where(held.reshape(N, k), weights, 0.0)[..., None]
+            return jnp.where(w != 0, out * w, 0.0).sum(axis=1).astype(dt)
+
+    n_held = sizes.sum()
+    end = jnp.cumsum(sizes)          # a group's rows of the sorted list are
+    start = end - sizes              # [start, end)
+    order = jnp.pad(order, (0, -(N * k) % C))
+    flat = weights.reshape(-1)
+
+    def slab(s, total):
+        lo = s * C
+        with jax.named_scope("moe.gather"):
+            pairs = jax.lax.dynamic_slice(order, (lo,), (C,))
+            token = pairs // k
+            rows = _rows(x, token)                               # (C, D)
+        out = products(rows, jnp.clip(end, lo, lo + C)
+                       - jnp.clip(start, lo, lo + C))            # (C, D)
+        with jax.named_scope("moe.gather"):
+            # a row past the held pairs took part in no group: not read
+            real = lo + jnp.arange(C) < n_held
+            w = jnp.where(real, _rows(flat, pairs), 0.0)
+            return total + _sum_by_token(out, w, jnp.where(real, token, N), N)
+
+    return jax.lax.fori_loop(
+        0, (n_held + C - 1) // C, slab,
+        jnp.zeros((N, x.shape[1]), jnp.float32)).astype(dt)
+
+
+def _sum_by_token(out, w, token, N):
+    """``sum_j w[j] * out[j]`` over the rows ``j`` of each token: ``out``
+    (C, D) the result rows of the held pairs in the sorted list's order,
+    ``w`` (C,) float32 their routing weights, ``token`` (C,) int32 whose row
+    each is (``N``, with weight 0, for a row that is no pair's: whatever it
+    holds is not read), ``C`` a multiple of the tile of rows.  Returns
+    (N, D) float32.
+
+    No scatter: XLA:TPU runs a scatter-add of rows one row after another,
+    0.09 us a row of 2,048 float32 and 1.3 us a row of 5,120 (0.92 ms a
+    layer at Qwen3-Next's 10,240 rows, 2.0 ms at DeepSeek-V2's 1,536, where
+    the whole-width form's way back took 0.7; a sort by token in front of
+    ``segment_sum`` is the same scatter: PERF.md section 6, PR 57).  The rows
+    are sorted by token instead, each tile of 128 of them is summed by token
+    on the MXU (a 128 x 128 matrix that holds row ``r``'s weight at (the
+    rank of its token within the tile, ``r``), times the tile, in float32:
+    0.14 to 0.66 ms at the same shapes), and a token, whose at most ``k <=
+    128`` rows lie in one tile or in two neighbours, reads its one or two
+    partial sums."""
+    C, D = out.shape
+    R, lax = _GMM_ROWS, jax.lax
+    # (in ``lax``, few operations: this is traced for every prefill bucket,
+    # and a ``jax.numpy`` index costs 5 ms of it)
+    token, w, by_token = lax.sort(
+        (token, w, lax.iota(jnp.int32, C)), num_keys=1)
+    out = jnp.where((w != 0).reshape(C, 1), _rows(out, by_token), 0)
+    # the rank of a row's token among the tokens of its tile
+    before = lax.concatenate([lax.full((1,), -1, jnp.int32),
+                              lax.slice(token, (0,), (C - 1,))], 0)
+    rank = lax.cumsum((token != before).astype(jnp.int32)).reshape(C // R, R)
+    slot = rank - lax.slice(rank, (0, 0), (C // R, 1))           # (tiles, R)
+    weigh = jnp.where(
+        slot.reshape(C // R, 1, R) == lax.iota(jnp.int32, R).reshape(R, 1),
+        w.reshape(C // R, 1, R), 0.0)                            # slot x row
+    part = lax.dot_general(
+        weigh, out.astype(jnp.float32).reshape(C // R, R, D),
+        (((2,), (1,)), ((0,), (0,))), precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).reshape(C, D)
+    # token n's rows are [below[n], below[n + 1]) of the sorted list
+    below = (token.reshape(1, C)
+             < lax.iota(jnp.int32, N + 1).reshape(N + 1, 1)).sum(
+        axis=1, dtype=jnp.int32)
+    start, end = lax.slice(below, (0,), (N,)), lax.slice(below, (1,), (N + 1,))
+    ends = lax.concatenate([start, lax.max(end - 1, start)], 0)  # (2 N,)
+    ends = lax.min(ends, jnp.int32(C - 1))
+    tile = ends // R
+    both = _rows(part, tile * R + _rows(slot.reshape(C), ends))  # (2 N, D)
+    half = lambda a, i: lax.slice_in_dim(a, i * N, (i + 1) * N)
+    two = (half(tile, 0) != half(tile, 1)).reshape(N, 1)
+    return jnp.where((end > start).reshape(N, 1),
+                     half(both, 0) + jnp.where(two, half(both, 1), 0.0), 0.0)
+
+
+def _rows(a, index):
+    """``a[index]`` for an int32 vector ``index`` of rows in bounds, as one
+    ``lax.gather`` (``jax.numpy`` indexing traces 5 ms a time)."""
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=tuple(range(1, a.ndim)), collapsed_slice_dims=(0,),
+        start_index_map=(0,))
+    return jax.lax.gather(a, index.reshape(-1, 1), dnums,
+                          (1,) + a.shape[1:], mode="promise_in_bounds")

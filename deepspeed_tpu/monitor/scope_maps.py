@@ -90,8 +90,11 @@ VOCABULARY = {
                              "slot's table of summary rows and window rows "
                              "(round the paged kernel)"),
     "moe.route": (_ROUTED, "router scores, top-k, the step's counters"),
-    "moe.experts": (_ROUTED, "the held experts' grouped products with "
-                    "their gather and combine"),
+    "moe.experts": (_ROUTED, "the held experts' grouped products and the "
+                    "sort of the pairs by expert"),
+    "moe.gather": (_ROUTED, "inside moe.experts: the pairs' rows gathered "
+                   "in, the result rows brought back to their tokens and "
+                   "the weighted sum"),
     "moe.shared": (("deepseek_v2", "afmoe", "nemotron_h", "qwen3_next"),
                    "the shared experts"),
     "moe.zero": (("longcat_flash",), "the identity experts' part"),

@@ -149,7 +149,7 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_whole():
         jax.random.PRNGKey(5))["moe"])
     u = jax.random.normal(jax.random.PRNGKey(6), (1, 50, 64))
     whole, counts, _ = m._moe(pm, u)
-    assert counts.tolist() == [50 * K, 0, E, 0, 0]
+    assert counts.tolist() == [50 * K, 0, E, 0, 0, 0, 0]
     stacked = jax.tree_util.tree_map(lambda w: w[None], pm)
     ref_whole = reference._experts(CFG, stacked, 0, u[0])
     assert rel_err(whole[0], ref_whole) < 1e-5
@@ -516,7 +516,8 @@ def test_no_token_is_dropped_whatever_the_imbalance(case):
         1.0, np.abs(ref).max())
     if case == "none_held":
         assert float(jnp.abs(out).max()) == 0.0
-    assert dropless.route_counters(experts, first, count).tolist() == want
+    assert dropless.route_counters(experts, first, count).tolist() == [
+        *want, 0, 0]                    # one path: no call counted
     # rows left out of the count (pad, empty slots) are still computed
     live = jnp.arange(N) < 10
     n = dropless.route_counters(experts, first, count, live).tolist()
@@ -579,6 +580,34 @@ def test_the_whole_model_routes_every_pair_here(model_params):
     # a prefill counts its prompt's tokens, not its bucket's pad
     assert [a["routed_pairs"] for a in prefills] == [
         K * (L - 1) * t for t in PROMPTS[:3]]
+
+
+def test_a_prompt_says_which_branch_its_expert_layers_took(monkeypatch):
+    """PR 57: a prompt's expert layers run over the held pairs compacted to
+    the narrow width or fall back, and the ``serving.prefill`` row says how
+    many calls did which; a decode step's calls are under the threshold and
+    count 0 and 0.  Tiny sizes: the tile of rows and the threshold shrunk so
+    that a 16-token bucket's 96 pairs have a narrow width (24 of them, twice
+    the even share 2 / 16); the layer's output is the whole-width one."""
+    monkeypatch.setattr(dropless, "_GMM_ROWS", 8)
+    monkeypatch.setattr(dropless, "_COMPACT_MIN_PAIRS", 64)
+    m = tiny(experts_held=(2, 2), impl="gather")
+    params = sharp(m.init(jax.random.PRNGKey(3)))
+    srv, steps, prefills = plain_run(m, params)
+    assert all(a["calls_compacted"] == a["calls_whole"] == 0 for a in steps)
+    assert all(a["calls_compacted"] + a["calls_whole"] == L - 1
+               for a in prefills)
+    assert sum(a["calls_compacted"] for a in prefills) > 0
+    pm = jax.tree_util.tree_map(lambda w: w[0], params["moe"])
+    u = jax.random.normal(jax.random.PRNGKey(6), (1, 64, 64))
+    narrow, counts, _ = m._moe(pm, u)
+    assert counts.tolist()[5:] == [1, 0]
+    monkeypatch.setattr(dropless, "_COMPACT_MIN_PAIRS", 10 ** 6)
+    whole, counts, _ = m._moe(pm, u)
+    assert counts.tolist()[5:] == [0, 0]
+    assert float(jnp.abs(narrow - whole).max()) < 1e-5 * max(
+        1.0, float(jnp.abs(whole).max()))
+    assert float(jnp.abs(whole).max()) > 0
 
 
 def test_a_share_counts_what_falls_elsewhere():

@@ -123,23 +123,39 @@ def test_logits_match_the_reference(model_params):
     assert apply_error(m, params) < 1e-4
 
 
-@pytest.mark.parametrize("length, chunk, size", [
-    (40, 16, 16), (40, 30, 27), (40, 64, 40), (37, 16, 15)])
+@pytest.mark.parametrize("length, chunk, size, narrow", [
+    (40, 16, 16, False), (40, 30, 27, False), (40, 64, 40, False),
+    (37, 16, 15, False), (40, 30, 27, True), (40, 64, 40, True)])
 def test_a_long_prompts_expert_layer_in_chunks_is_the_same(
-        model_params, monkeypatch, length, chunk, size):
+        monkeypatch, length, chunk, size, narrow):
     """The expert layer gathers at most ``_MOE_CHUNK`` tokens' pairs at once
     (2 rows of 40 tokens here: 5 chunks of 16, 3 of 27 and 2 of 40; 2 rows of
     37 in 5 chunks of 15): where the count does not divide the tokens the
     last chunk is filled up with rows that no held expert takes, and the
-    logits are the whole prompt's."""
-    m, params = model_params
+    logits are the whole prompt's.  ``narrow``: a share of the experts (4 of
+    16 real, 24 outputs) with the tile of rows and the threshold shrunk, so
+    that a chunk's call has a narrow width and its ``cond`` sits inside the
+    ``lax.map`` over chunks; the counters count a call a chunk."""
+    m = tiny(experts_held=(4, 4)) if narrow else tiny()
+    params = sharp(m.init(jax.random.PRNGKey(3)))
+    if narrow:
+        monkeypatch.setattr(dropless, "_GMM_ROWS", 8)
+        monkeypatch.setattr(dropless, "_COMPACT_MIN_PAIRS", 64)
     seen = []
     sound = dropless.held_experts
     monkeypatch.setattr(lcf, "_MOE_CHUNK", chunk)
     monkeypatch.setattr(dropless, "held_experts", lambda x, *a, **kw: (
         seen.append(x.shape[0]), sound(x, *a, **kw))[1])
-    assert apply_error(tiny(), params, toks=TOKS[:, :length]) < 1e-4
+    cfg = dict(CFG, experts_held=[4, 4]) if narrow else CFG
+    assert apply_error(m, params, cfg=cfg, toks=TOKS[:, :length]) < 1e-4
     assert set(seen) == {size}
+    if narrow:
+        u = jax.random.normal(jax.random.PRNGKey(8), (2, length, 64))
+        pm = jax.tree_util.tree_map(lambda w: w[0], params["moe"])
+        _, counts, _ = m._moe(pm, u)
+        n = dict(zip(m.step_counters, counts.tolist()))
+        assert n["calls_compacted"] + n["calls_whole"] == -(-2 * length // size)
+        assert n["calls_compacted"] > 0
 
 
 def test_the_bias_moves_the_pick_and_never_the_weight(model_params):

@@ -817,7 +817,8 @@ def test_the_capacity_gate_still_refuses_other_k_and_still_drops():
     out = dropless.held_experts(x, experts, weights, w, w,
                                 jnp.swapaxes(w, 1, 2), 0)
     assert (np.abs(np.asarray(out)).max(axis=1) > 0).all()
-    assert dropless.route_counters(experts, 0, 8).tolist() == [24, 0, 1, 7, 0]
+    assert dropless.route_counters(experts, 0, 8).tolist() == [
+        24, 0, 1, 7, 0, 0, 0]
 
 
 # ----------------- many small experts (PR 54: Qwen3-Next, top-10 of 512)
@@ -936,3 +937,220 @@ def test_dropless_zero_compute_experts(case):
             x, experts, weights, 12)).max()) == 0.0      # no id that high
         live = jnp.arange(N) < 5
         assert int(dropless.zero_pairs(experts, real, live)) == 10
+
+
+# ------------- the held pairs compacted to a static width (PR 57)
+def uncompacted_held_experts(x, experts, weights, gate_w, up_w, down_w, first,
+                             layer=None, act="silu"):
+    """``moe/dropless.py::held_experts`` as it stood before the narrow branch
+    came (PR 56's tree), operation for operation: a row for EVERY pair."""
+    N, k = experts.shape
+    act = dropless.activation(act)
+    count = up_w.shape[-3]
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    local = jnp.where(held, local, count)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.zeros((count,), jnp.int32).at[local].add(1, mode="drop")
+    if layer is not None:
+        n = up_w.shape[0] * count
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n,), jnp.int32), sizes, (layer * count,))
+        gate_w, up_w, down_w = (
+            None if w is None else w.reshape((n,) + w.shape[2:])
+            for w in (gate_w, up_w, down_w))
+    rows = x[order // k]
+    dt = x.dtype
+    if gate_w is None:
+        h = act(dropless.grouped_product(rows, up_w.astype(dt), sizes,
+                                         transposed=True))
+    else:
+        h = act(dropless.grouped_product(rows, gate_w.astype(dt), sizes)) \
+            * dropless.grouped_product(rows, up_w.astype(dt), sizes)
+    out = dropless.grouped_product(h, down_w.astype(dt), sizes)
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    out = out[back].reshape(N, k, -1).astype(jnp.float32)
+    w = jnp.where(held.reshape(N, k), weights, 0.0)[..., None]
+    return jnp.where(w != 0, out * w, 0.0).sum(axis=1).astype(dt)
+
+
+def _loops_and_branches(fn, *args):
+    from deepspeed_tpu.analysis.jaxpr_audit import iter_eqns
+    names = [e.primitive.name
+             for e, _ in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)]
+    return names.count("while"), names.count("cond")
+
+
+# 96 tokens of 4 picks over a router of 32 outputs, experts 8..11 held: 384
+# pairs, 48 of them held if the routing is even, the narrow width twice that
+# in whole tiles of rows = 128; the cases plant the count of held pairs
+_PLANTED = {"none_held": 0, "even_share": 48, "exactly_C": 128,
+            "C_plus_1": 129, "every_pair_held": 384}       # 0, 1, 1, 2, 3 slabs
+
+
+def _planted_experts(n_held, N, k, first, count, width, seed):
+    """(N, k) ids of which exactly ``n_held`` pairs (spread over the tokens,
+    a token's picks distinct where its held picks number ``count`` or
+    fewer) fall to ``first .. first + count - 1``."""
+    rng = np.random.RandomState(seed)
+    absent = [e for e in range(width) if not first <= e < first + count]
+    flat = rng.choice(absent, size=N * k)
+    at = rng.permutation(N * k)[:n_held]
+    flat[at] = first + rng.randint(0, count, size=n_held)
+    return jnp.asarray(flat.reshape(N, k), jnp.int32)
+
+
+@pytest.mark.parametrize("case", [
+    *_PLANTED,
+    "non_gated_transposed", "non_gated_two_slabs", "merged_stack",
+    "merged_stack_three_slabs", "one_pick_a_token", "ids_past_the_real_experts",
+    "bfloat16", "under_the_threshold", "no_width_given", "over_half_the_width"])
+def test_held_pairs_compacted_to_a_static_width(case, monkeypatch):
+    """``held_experts`` with a narrow width against the form that lays out a
+    row for every pair: equal within float32 rounding of a ``k``-term sum
+    whether the held pairs fill one slab of the width or several (a token's
+    pairs are summed a tile of token-sorted rows at a time and slab after
+    slab, not in pair order), ONE loop over the slabs and no ``cond``; no
+    loop at all, the same operations to the letter and the same bits, where
+    the call keeps its whole-width path; ``route_counters`` says by the same
+    rule which calls took one pass."""
+    monkeypatch.setattr(dropless, "_COMPACT_MIN_PAIRS", 256)
+    N, k, D, F, first, count, width = 96, 4, 16, 24, 8, 4, 32
+    dtype, layer, gated, tol = jnp.float32, None, True, 2e-6
+    n_held = _PLANTED.get(case, 48)
+    if case == "non_gated_two_slabs":
+        n_held = 200
+    if case == "merged_stack_three_slabs":
+        n_held = 384
+    if case == "one_pick_a_token":
+        N, k, n_held = 512, 1, 70               # 512 pairs, C = 128
+    if case == "under_the_threshold":
+        N = 64                                  # 256 pairs: one path
+    if case == "over_half_the_width":
+        width = 12                              # C = 256 of 384 pairs
+    experts = _planted_experts(n_held, N, k, first, count, width, seed=57)
+    if case == "ids_past_the_real_experts":     # LongCat's pad fill: width
+        experts = experts.at[N // 2:].set(width)
+    if case == "bfloat16":
+        dtype, tol = jnp.bfloat16, 1e-2
+    gated = not case.startswith("non_gated")
+    if case.startswith("merged_stack"):
+        layer = jnp.int32(1)
+    key = jax.random.split(jax.random.PRNGKey(57), 5)
+    lead = () if layer is None else (3,)
+    x = jax.random.normal(key[0], (N, D)).astype(dtype)
+    weights = jax.random.uniform(key[1], (N, k)) + 0.5
+    w = lambda kk, *shape: jax.random.normal(kk, lead + shape) * 0.3
+    gate = w(key[2], count, D, F) if gated else None
+    up = w(key[3], count, D, F) if gated else w(key[3], count, F, D)
+    down = w(key[4], count, F, D)
+    kw = dict(layer=layer, act="silu" if gated else "relu2")
+    given = None if case == "no_width_given" else width
+    now = lambda *a: dropless.held_experts(*a, first, width=given, **kw)
+    was = lambda *a: uncompacted_held_experts(*a, first, **kw)
+    args = (x, experts, weights, gate, up, down)
+    got, ref = jax.jit(now)(*args), jax.jit(was)(*args)
+    assert got.dtype == ref.dtype == dtype
+    C = dropless.compact_width(N, k, count, given)
+    held_here = int(((experts >= first) & (experts < first + count)).sum())
+    calls = dropless.route_counters(experts, first, count,
+                                    width=given).tolist()[5:]
+    if case in ("under_the_threshold", "no_width_given",
+                "over_half_the_width"):
+        assert C is None and calls == [0, 0]
+        assert _loops_and_branches(now, *args) == (0, 0)
+        bare = lambda *a: dropless._held_experts.__wrapped__(
+            *a, first, C=None, **kw)            # under its own ``jax.jit``
+        assert str(jax.make_jaxpr(bare)(*args)) == str(
+            jax.make_jaxpr(was)(*args))
+        assert bool((got == ref).all())
+        return
+    assert C == 128 and _loops_and_branches(now, *args) == (1, 0)
+    if case != "ids_past_the_real_experts":
+        assert held_here == n_held
+    assert calls == ([0, 1] if held_here > C else [1, 0])
+    err = float(jnp.abs(got.astype(jnp.float32)
+                        - ref.astype(jnp.float32)).max())
+    assert err <= tol * max(1.0, float(jnp.abs(ref).max())), err
+    assert (float(jnp.abs(ref).max()) > 0.1) == (held_here > 0)
+
+
+def test_call_counters_follow_a_family_that_cuts_a_long_prompt(monkeypatch):
+    """``route_counters(rows=...)``: one call of ``held_experts`` every
+    ``rows`` tokens (LongCat's ``_MOE_CHUNK``), the last chunk's pad rows in
+    no group; each chunk is judged by its own count (``calls_whole``: its
+    held pairs took more than one slab of the narrow width)."""
+    monkeypatch.setattr(dropless, "_COMPACT_MIN_PAIRS", 256)
+    first, count, width, k = 8, 4, 32, 4
+    crowded = _planted_experts(300, 96, k, first, count, width, seed=1)
+    sparse = _planted_experts(20, 96, k, first, count, width, seed=2)
+    tail = _planted_experts(10, 40, k, first, count, width, seed=3)
+    experts = jnp.concatenate([crowded, sparse, tail])
+    n = dropless.route_counters(experts, first, count, width=width, rows=96)
+    assert n.tolist()[5:] == [2, 1] and int(n[0]) == 330
+    whole = dropless.route_counters(experts, first, count, width=width)
+    assert whole.tolist()[5:] == [0, 1]         # 330 of 928 pairs, C = 256
+
+
+@pytest.mark.parametrize("case", ["three_rows_a_token", "random", "one_token",
+                                  "no_row_is_a_pair"])
+def test_the_narrow_way_back_sums_each_tokens_rows(case):
+    """``_sum_by_token`` over three tiles of 128 rows against a loop: tokens
+    whose rows straddle a tile's edge (three rows a token: rows 126..128 are
+    token 42's), tokens with no row, rows that are no pair's (token ``N``,
+    weight 0, holding NaN: never read), every row one token's."""
+    C, D, N = 384, 16, 200
+    rng = np.random.RandomState(57)
+    out = rng.randn(C, D).astype(np.float32)
+    w = (rng.rand(C) + 0.5).astype(np.float32)
+    if case == "three_rows_a_token":
+        token = np.arange(C) // 3
+        n_real = 300
+    elif case == "random":
+        token, n_real = rng.randint(0, N, size=C), 333
+    elif case == "one_token":
+        token, n_real = np.full((C,), 7), 128     # k <= 128 rows a token
+    else:
+        token, n_real = rng.randint(0, N, size=C), 0
+    token = rng.permutation(token[:n_real])
+    token = np.concatenate([token, np.full((C - n_real,), N)]).astype(np.int32)
+    w[n_real:] = 0.0
+    out[n_real:] = np.nan
+    want = np.zeros((N, D))
+    for j in range(n_real):
+        want[token[j]] += float(w[j]) * out[j].astype(np.float64)
+    got = jax.jit(dropless._sum_by_token, static_argnums=3)(
+        jnp.asarray(out), jnp.asarray(w), jnp.asarray(token), N)
+    assert got.shape == (N, D) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("cell, config, picks, held, width", [
+    ("serve_batch_deepseek_v2", "deepseek-v2", "num_experts_per_tok", 20, 160),
+    ("serve_longmix_trinity", "trinity-large-preview", "num_experts_per_tok",
+     32, 256),
+    ("serve_longanswer_nemotron3", "nemotron-3-nano-30b-a3b",
+     "num_experts_per_tok", 32, 128),
+    ("serve_agent_longcat", "longcat-flash-chat", "moe_topk", 16, 768),
+    ("serve_longctx_qwen3next", "qwen3-next-80b-a3b", "num_experts_per_tok",
+     64, 512)])
+def test_a_decode_step_of_a_served_cell_keeps_one_path(cell, config, picks,
+                                                       held, width):
+    """Every slot's token through an expert layer is a call under the
+    threshold: no narrow width, so no ``cond`` in a decode step and both
+    call counters 0; the cell's longest prompt chunk has one."""
+    import json
+    import os
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "traffic", cell + ".json")) as f:
+        slots = json.load(f)["serving"]["batch_slots"]
+    with open(os.path.join(root, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    k = cfg[picks]
+    assert cfg["experts_held"][1] == held
+    assert slots * k <= dropless._COMPACT_MIN_PAIRS
+    assert dropless.compact_width(slots, k, held, width) is None
+    C = dropless.compact_width(2048, k, held, width)
+    assert C is not None and C % 128 == 0 and 2 * C <= 2048 * k
+    assert C >= 2 * 2048 * k * held / width

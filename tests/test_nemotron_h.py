@@ -325,9 +325,11 @@ def test_the_gated_path_is_what_it_was_bit_for_bit(family, route_kw, layer,
     now = dropless.held_experts(*args, layer=layer)
     was = parent_held_experts(*args, layer=layer)
     assert now.dtype == was.dtype and bool((now == was).all())
-    as_text = lambda fn: str(jax.make_jaxpr(
-        lambda *a: fn(*a, layer=layer))(*args))
-    assert as_text(dropless.held_experts) == as_text(parent_held_experts)
+    as_text = lambda fn, **kw: str(jax.make_jaxpr(
+        lambda *a: fn(*a, layer=layer, **kw))(*args))
+    # (the function's body, under a ``jax.jit`` of its own since PR 57)
+    assert as_text(dropless._held_experts.__wrapped__, act="silu",
+                   C=None) == as_text(parent_held_experts)
 
 
 @pytest.mark.parametrize("layer", [None, 1])
@@ -527,6 +529,21 @@ def test_on_a_tpu_every_product_is_the_pallas_call(monkeypatch, F):
     assert sum(traced.values()) == 3 and all(
         what.startswith("gmm 128x") for what in traced)
     assert f"gmm 128x128x{F} of 72x128x{F}/4" in traced
+    # a call with a narrow width (PR 57) has the same three, a slab of rows
+    # wide: 1,536 pairs, 4 experts held of 64, twice the even share = 256
+    monkeypatch.setattr(dropless, "_COMPACT_MIN_PAIRS", 1024)
+    x, logits, gate, up, down = expert_operands(jnp.float32, D=128, F=F,
+                                                N=512)
+    experts, weights = dropless.route(logits, 3)
+    t0 = time.monotonic()
+    jaxpr = jax.make_jaxpr(lambda *a: dropless.held_experts(
+        *a, 0, width=64))(x, experts, weights, gate, up, down)
+    names = [e.primitive.name for e, _ in iter_eqns(jaxpr.jaxpr)]
+    assert names.count("pallas_call") == 3 and names.count("while") == 1
+    assert names.count("cond") == 0
+    traced = dropless.products_traced(t0, time.monotonic())
+    assert traced == {f"gmm 128x128x{F} of 256x128x{F}/4": 2,
+                      f"gmm 128x{F}x128 of 256x{F}x128/4": 1}
 
 
 def test_an_unknown_activation_is_refused_by_name():
