@@ -733,13 +733,8 @@ class GPT2:
         bucket = blocks.shape[0] * pool["k"].shape[2]
         cache = self.init_cache(1, fwd_len)
         logits, cache = self.apply_with_cache(params, toks, cache)
-        # (L, T, H, hd) at B=1.  init_cache is seq-major only since the
-        # batch-major decode went; the first branch is kept unchanged
-        # until a PR that may move this function's frame (ROADMAP D13)
-        if cache["k"].shape[1] == 1:          # batch-major, or T == 1
-            k, v = cache["k"][:, 0], cache["v"][:, 0]
-        else:                                  # seq-major (L, S, B, ...)
-            k, v = cache["k"][:, :, 0], cache["v"][:, :, 0]
+        # seq-major (L, S, B, H, hd): (L, T, H, hd) at B=1
+        k, v = cache["k"][:, :, 0], cache["v"][:, :, 0]
         if fwd_len < bucket:
             pad = ((0, 0), (0, bucket - fwd_len), (0, 0), (0, 0))
             k, v = jnp.pad(k, pad), jnp.pad(v, pad)

@@ -443,6 +443,39 @@ class CompileCache:
         }
 
 
+# ``jit_fn.lower`` called beneath one interpreter frame of 2^17 slots (1 MiB):
+# every executable of a ``CachedStep`` is lowered through it.  CPython 3.12
+# keeps interpreter frames in chunks of 16 KiB and maps a chunk, and unmaps
+# it again, each time the call depth crosses a chunk's edge.  A JAX trace
+# goes tens of frames up and down for every operation it stages, so it
+# crosses edges all the time, and in a process with many threads every
+# unmapping is dear: on the chip's host each of Jamba's 23 executables took
+# 2.6 s to lower in 16 KiB chunks and 0.8 s beneath this frame
+# (``setup.trace_lower_s`` 54.9 s against 16.0: PERF.md section 6, PR 58).
+# Where a trace's innermost calls lay ON an edge, which the sizes of all the
+# frames above them decided, it was worse again (PR 29: 5.4 s an executable
+# from one caller's frame 248 bytes smaller), and five modules kept their
+# frames the size they had.  A frame that does not fit in what is left of a
+# chunk gets a new one, the smallest 16 KiB x 2^k that holds it and 1,000
+# slots more (``push_chunk``, Python/pystate.c): for 2^17 slots ONE chunk of
+# 2 MiB, of which this frame takes the first and everything under ``lower``
+# lies in the second, with no edge to cross whatever the callers above or the
+# model below look like.  Pages it does not touch are never faulted in, and
+# the chunk is unmapped when the frame returns.  The size is a constant of
+# the interpreter's, not an option: where chunks are sized otherwise the
+# frame is merely large, and a trace deeper than that 1 MiB goes on in 16 KiB
+# chunks as all did before.  A step with no cache attached (``__call__``'s
+# passthroughs) is traced by ``jax.jit`` where it is called: those calls are
+# the steady state's too, which a chunk mapped a call would tax.  Held by
+# ``tests/test_compile_cache.py::test_lowering_takes_as_long_from_any_depth``.
+def _lower_at_own_depth(jit_fn, args, kwargs):
+    return jit_fn.lower(*args, **kwargs)
+
+
+_lower_at_own_depth.__code__ = _lower_at_own_depth.__code__.replace(
+    co_stacksize=1 << 17)
+
+
 # ----------------------------------------------------------- the AOT wrapper
 class CachedStep:
     """Dispatch wrapper for one jitted entry point.
@@ -472,7 +505,7 @@ class CachedStep:
 
     # jax.jit API surface used elsewhere in the repo
     def lower(self, *args, **kwargs):
-        return self._jit.lower(*args, **kwargs)
+        return _lower_at_own_depth(self._jit, args, kwargs)
 
     def clear(self):
         """Drop live executables (frees their device programs)."""
@@ -560,11 +593,9 @@ class CachedStep:
         # the compile.* spans nest under whatever step called for the
         # executable, which is how an in-window recompile names its step;
         # lower_ms / compile_ms / deserialize_ms are their durations.
-        # Down to the ``lower`` call this frame keeps its size (ROADMAP
-        # D13): what is new is a callee's, or comes after it has returned.
         rec = spans.recorder()
         with rec.setup_span("compile.lower", attrs={"fn": self.name}) as span:
-            lowered = self._jit.lower(*args, **kwargs)
+            lowered = _lower_at_own_depth(self._jit, args, kwargs)
         lower_ms = (span.t1 - span.t0) * 1000
         _split_lower(rec, span)
         _note_grouped_products(span)
@@ -600,8 +631,7 @@ class CachedStep:
 
     def _key_material(self, args, lowered, kwargs):
         """:func:`build_key_material` under its own span, ``compile.key``
-        (the lowered text rendered and hashed), in a callee so that
-        ``_acquire``'s frame keeps its size (ROADMAP D13)."""
+        (the lowered text rendered and hashed)."""
         with spans.recorder().setup_span("compile.key",
                                          attrs={"fn": self.name}):
             return build_key_material(self.name, args, lowered,
@@ -654,7 +684,6 @@ class CachedStep:
 
     def _try_serialize(self, cache, key, compiled, material):
         from jax.experimental import serialize_executable as se
-        # here and not in ``_acquire``, whose frame keeps its size (D13)
         scopes = _note_device_scopes(self.name, compiled)
         try:
             ser, in_tree, out_tree = se.serialize(compiled)

@@ -119,14 +119,6 @@ def _resolve_model(model, loss_fn, params, apply_fn, rng_seed,
     return loss_fn, params, apply_fn, tp_specs
 
 
-def _reset_flash_tile_census():
-    """A callee, so that ``_grads_and_metrics``, which every layer's trace
-    runs under, keeps its frame's size (ROADMAP D13: how long ``jit.lower``
-    takes depends on where the frames below it lie)."""
-    from ..ops.transformer.flash_attention import reset_tile_census
-    reset_tile_census()
-
-
 class DeepSpeedEngine:
     """Config-driven training engine over a jitted SPMD step."""
 
@@ -1128,7 +1120,8 @@ class DeepSpeedEngine:
         None on the full-width path."""
         # a step's trace starts here: what _describe_step reads as
         # ``flash_tiles`` is this step's calls and nothing traced before
-        _reset_flash_tile_census()
+        from ..ops.transformer.flash_attention import reset_tile_census
+        reset_tile_census()
         cur_scale = (state.scale.cur_scale if state.scale is not None
                      else jnp.float32(1.0))
         out = self._grad_fn(base, batch, rng, cur_scale)

@@ -102,6 +102,75 @@ def test_cache_root_is_placed_from_outside_or_fixed(monkeypatch, tmp_path):
         jax.config.update("jax_compilation_cache_dir", saved)
 
 
+def test_lowering_takes_as_long_from_any_depth():
+    """How long a ``CachedStep`` takes to lower a function does not depend
+    on how deep the interpreter's stack is where the executable is asked
+    for.  The control first: the same function through a bare
+    ``jax.jit(...).lower`` from each depth finds one that is several times
+    slower than the rest (CPython 3.12's 16 KiB chunks of interpreter
+    frames: the trace's calls of ``leaf`` cross a chunk's edge there, and
+    every crossing maps or unmaps a chunk), or else the test is skipped.
+    ``leaf``'s frame is 200 slots so that it is the frame that meets the
+    edge, from some 13 depths in every 127: every fourth depth finds them.
+    A slowest depth is read twice more and its best time is what counts:
+    where the frames lie is the same each time, what else the machine does
+    is not."""
+    import statistics
+    import sys
+    import time
+    from deepspeed_tpu.monitor import spans as monspans
+    if sys.implementation.name != "cpython" or sys.version_info[:2] != (3, 12):
+        pytest.skip("the frame chunks are CPython 3.12's")
+    depths = range(0, 160, 4)
+
+    def leaf(x):
+        return x
+
+    leaf.__code__ = leaf.__code__.replace(co_stacksize=200)
+
+    def body(x):
+        for _ in range(30_000):
+            leaf(x)
+        return x + 1
+
+    def at_depth(n, f):
+        return f() if n == 0 else at_depth(n - 1, f)
+
+    x = jnp.zeros((2,))
+    rec = monspans.recorder()
+
+    def bare():
+        t0 = time.perf_counter()
+        jax.jit(lambda x: body(x)).lower(x)       # a new function: a new trace
+        return time.perf_counter() - t0
+
+    def acquired():
+        mark = rec.open("test")
+        cc.CachedStep("probe", jax.jit(lambda x: body(x))).executable(x)
+        (row,) = [r for r in rec.since(mark) if r.name == "compile.lower"]
+        rec.discard(mark)
+        return row.t_end - row.t_start
+
+    def slowest_against_the_rest(lower):
+        lower()                                   # JAX's own first-call work
+        took = {d: at_depth(d, lower) for d in depths}
+        d = max(took, key=took.get)
+        again = min(took[d], at_depth(d, lower), at_depth(d, lower))
+        return again / statistics.median(took.values()), d
+
+    ratio, depth = slowest_against_the_rest(bare)
+    if ratio < 5:
+        pytest.skip(f"no depth slows a bare lower here (the slowest, {depth}, "
+                    f"{ratio:.1f} x the median): nothing to hold")
+    rec.reset()
+    try:
+        ratio, depth = slowest_against_the_rest(acquired)
+    finally:
+        rec.reset()       # these acquisitions' rows are no later test's
+    assert ratio < 3, (f"compile.lower from depth {depth} took {ratio:.1f} x "
+                       f"the median of {len(depths)} depths")
+
+
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_store_round_trips_a_sub_mesh_executable(tmp_path, devices, n_dev):
     """An executable compiled for jax.devices()[:n] must come back bound

@@ -257,3 +257,35 @@ def test_gptj_unrolled_matches_scanned():
     for a, b in zip(jax.tree_util.tree_leaves(c1),
                     jax.tree_util.tree_leaves(c2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+@pytest.mark.parametrize("fwd_len,t_real", [(1, 1), (8, 5), (6, 6)])
+def test_gpt2_prefill_paged_matches_apply(fwd_len, t_real):
+    """``GPT2.prefill_paged`` on one prompt against ``apply``: the logits at
+    the prompt's last token, and the pool's rows against the K/V the cached
+    forward computed (its cache is seq-major, ``(L, S, B, H, hd)``).  The
+    forward is 1 token long (one row of K/V padded to the block), a whole
+    bucket, and shorter than its bucket."""
+    from deepspeed_tpu.inference import paged_kv as pk
+    m = build("gpt2-tiny", dtype=jnp.float32, attention_impl="jnp")
+    c = m.config
+    params = m.init(jax.random.PRNGKey(0))
+    bs, nb = 4, 2
+    toks = np.random.RandomState(3).randint(
+        0, c.vocab_size, (1, fwd_len)).astype(np.int32)
+    pool = m.init_serving_state(1, 1 + nb, bs)
+    blocks = jnp.asarray([2, 1], jnp.int32)
+    row, pool = m.prefill_paged(params, jnp.asarray(toks), pool, blocks, 0,
+                                t_real)
+    want = m.apply(params, jnp.asarray(toks))
+    np.testing.assert_allclose(np.asarray(row), np.asarray(want)[:, t_real - 1],
+                               atol=1e-5, rtol=1e-5)
+    _, cache = m.apply_with_cache(params, jnp.asarray(toks),
+                                  m.init_cache(1, fwd_len))
+    for layer in range(c.n_layer):
+        rows = pk.gather_kv(pool, layer, blocks[None], jnp.float32, c.n_head)
+        for name, got in zip(("k", "v"), rows):
+            np.testing.assert_allclose(
+                np.asarray(got)[0, :fwd_len],
+                np.asarray(cache[name])[layer, :, 0], atol=1e-6)
+            assert not np.asarray(got)[0, fwd_len:].any()   # the pad rows

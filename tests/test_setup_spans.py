@@ -73,8 +73,9 @@ def test_setup_store_survives_a_ring_that_drops_step_rows():
 
 
 def test_setup_rows_ride_the_ring_too_until_it_drops_them():
-    """``rows()`` keeps its meaning: the readers of the ring that exist
-    (``cache.acquire_s``) find the ``compile.*`` rows where they were."""
+    """``rows()`` keeps its meaning: a reader of the ring (a recompile
+    inside the window, named by the step it fell in) finds the
+    ``compile.*`` rows where they were."""
     rec = SpanRecorder(clock=ticking())
     root = rec.open("serving.step", step=1)
     with rec.setup_span("compile.lower", attrs={"fn": "f"}):
