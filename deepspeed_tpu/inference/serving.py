@@ -783,6 +783,7 @@ class ServingEngine:
         # step behind the device's copy until `_settle` books it
         self._unread: Optional[_Unread] = None
         self._ahead_steps = 0         # dispatched while the last was unread
+        self._admits_under = 0        # prefills dispatched under such a step
         self._t_read = float("-inf")  # when the newest step was read
 
         self.queue: deque = deque()
@@ -1588,10 +1589,14 @@ class ServingEngine:
         enforcement's admit half lives here: a head whose deadline already
         passed, or provably cannot be met (remaining budget < max_new ·
         measured step EMA), is shed with a typed ``DEADLINE`` result instead
-        of occupying a slot it cannot use."""
+        of occupying a slot it cannot use.
+
+        A decode step may be unread (:meth:`_behind_unread`): the first seat
+        then books it, and what it booked is returned (else None)."""
         if self._draining:
-            return
+            return None
         fault.site("serving.admit")
+        booked = None
         while self.queue:
             req: Request = self.queue[0]
             if self._deadline_unmeetable(req):
@@ -1601,7 +1606,7 @@ class ServingEngine:
                 continue
             free = [i for i, s in enumerate(self._slots) if s is None]
             if not free:
-                return
+                break
             new = req.max_new_tokens       # resolved >= 1 by submit()
             share = self._prefix_match(req)
             ns = share["ns"] if share is not None else 0
@@ -1610,12 +1615,12 @@ class ServingEngine:
             # reserved here, once; the head waits on whichever is short
             plan = self._head_plan(req, ns)
             if any(plan[1]):
-                return
+                break
             seat, need_w, peak = plan[0]
             fresh = self._alloc_blocks(seat, uid=req.uid)
             if fresh is None:
                 # the radix cache held more than an eviction could free
-                return
+                break
             self._promised = peak
             wblocks = self._alloc_window(need_w, uid=req.uid)
             if ns:
@@ -1632,8 +1637,8 @@ class ServingEngine:
             self._prefix_requests_total += (
                 1 if self._prefix_index is not None else 0)
             try:
-                self._start(slot, req, blocks, new, share=share,
-                            wblocks=wblocks)
+                booked = self._start(slot, req, blocks, new, share=share,
+                                     wblocks=wblocks) or booked
             except Exception:
                 # a prefill that dies mid-dispatch (device OOM, a
                 # poisoned executable) must not leak the blocks: free
@@ -1655,6 +1660,7 @@ class ServingEngine:
                         self._sanitizer.on_free(released, uid=req.uid)
                     self._free_window(wblocks, uid=req.uid)
                 raise
+        return booked
 
     # --------------------------------------- admission by the pool's timeline
     # INVARIANT: a seated stream never waits for a block and is never
@@ -1667,8 +1673,13 @@ class ServingEngine:
     # leaves a plan that fits, and what happens is never above the plan: every
     # seated row writes one token a dispatch, `max_new_tokens` is a hard bound,
     # and an eos, a deadline, a poisoned row or a drain only brings blocks
-    # home earlier.  Nothing is preempted, recomputed or reordered: a request
-    # gets the tokens it gets alone (tests/test_serving_timeline.py).
+    # home earlier.  The one write past a stream's last token, the dead row-step
+    # of a finish seen one dispatch late (docs/serving.md#one-step-in-flight),
+    # lands at `prompt + max_new_tokens - 1` at the latest: inside the blocks
+    # `_life_blocks(total)` planned, in the last step the timeline holds the
+    # stream for, so `_grant_blocks(ahead=True)` may grant it its last block.
+    # Nothing is preempted, recomputed or reordered: a request gets the tokens
+    # it gets alone (tests/test_serving_timeline.py).
 
     def _seat_blocks(self, prompt_len: int, total: int) -> int:
         """Blocks of the growing table a stream is seated with: what the
@@ -1772,8 +1783,8 @@ class ServingEngine:
 
     def _admission_due(self) -> bool:
         """Would :meth:`_admit` shed or seat the queue's head now?  Asked
-        while a step is unread, without touching anything: a seat changes
-        a slot, so the unread step is settled before it.  With the prefix
+        while a step is unread, without touching anything (the next step
+        then does not run ahead: :meth:`_behind_unread`).  With the prefix
         cache armed the head's need depends on what it shares and on what
         an eviction frees, so a free slot alone answers yes."""
         if not self.queue or self._draining:
@@ -1859,50 +1870,68 @@ class ServingEngine:
         wblocks = wblocks or []
         T = int(len(req.tokens))
         rec = self.results[req.uid]
+        # a decode step is unread: the prefill goes to the device BEHIND it
+        # (docs/serving.md#one-step-in-flight)
+        under = self._unread is not None
+        self._admits_under += under
         with self._spans.span("serving.prefill", uid=req.uid) as prefill:
             # queue wait ends the instant this request is seated
             rec["t_admit"] = prefill.t0
             if share is not None:
-                prefill.attrs = {"prompt_len": T,
+                prefill.attrs = {"prompt_len": T, "under_step": under,
                                  "shared_blocks": share["ns"]}
                 self._start_shared(slot, req, blocks, new, share)
                 return
             bucket = pk.blocks_needed(T, c.block_size) * c.block_size
             prefill.attrs = {"prompt_len": T, "bucket": bucket,
-                             **self._loop_attrs}
-            if self._fold is not None:
-                # a window at a time: the bucket is the tail's
-                read = self._prefill_folded(req, blocks, slot, prefill)
-                first, bad = self._read_prefill(read, prefill)
-                return self._seat(slot, req, blocks, wblocks, new, first,
-                                  bad, rec)
-            if self._recurrent:
-                # what the recurrence walks and what it must not take in;
-                # the dispatch below writes the slot's recurrent rows whole
-                prefill.attrs.update(scan_tokens=T, pad_tokens=bucket - T)
-                if hasattr(self.model, "prefill_attrs"):
-                    prefill.attrs.update(self.model.prefill_attrs(T))
-                self._state_seats += 1
-            toks = np.zeros((1, min(bucket, self.max_seq)), np.int32)
-            toks[0, :T] = req.tokens
-            nb_pre = bucket // c.block_size
-            # the prompt's blocks of the growing table, then the ring
-            # (scratch where a short stream holds no block)
-            blk = np.zeros((nb_pre + self.ring,), np.int32)
-            blk[:nb_pre] = blocks[:nb_pre]
-            blk[nb_pre:nb_pre + len(wblocks)] = wblocks
-            blk = jnp.asarray(blk)
-            fn = self._prefill_fn(bucket)
+                             "under_step": under, **self._loop_attrs}
             with jax.set_mesh(self.engine.mesh):
                 with self._spans.span("serving.prefill.dispatch"):
-                    read, self.pool = fn(*self._prefill_args(
-                        toks, blk, slot, T, req.seed, req.temperature,
-                        req.do_sample))
+                    if self._fold is not None:
+                        # a window at a time: the bucket is the tail's
+                        read = self._prefill_folded(req, blocks, slot,
+                                                    prefill)
+                    else:
+                        read = self._prefill_whole(req, blocks, wblocks,
+                                                   slot, bucket, prefill)
                     read.copy_to_host_async()
+            # the step in flight ends on the device before the prefill
+            # begins, so reading it first costs no wait of its own; and it
+            # is booked while the slot is still empty: a row of it that died
+            # in this slot has its sample discarded, and the mirrors the
+            # seat below writes are never advanced by it
+            booked = self._book(self._unread) if under else None
             # the read syncs the prefill dispatch: the host waits here
             with self._spans.span("serving.prefill.readback"):
                 first, bad = self._read_prefill(read, prefill)
         self._seat(slot, req, blocks, wblocks, new, first, bad, rec)
+        return booked
+
+    def _prefill_whole(self, req: Request, blocks: List[int],
+                       wblocks: List[int], slot: int, bucket: int, prefill):
+        """A prompt through its bucket's one executable; returns its read,
+        still on the device."""
+        c = self.config
+        T = int(len(req.tokens))
+        if self._recurrent:
+            # what the recurrence walks and what it must not take in;
+            # the dispatch below writes the slot's recurrent rows whole
+            prefill.attrs.update(scan_tokens=T, pad_tokens=bucket - T)
+            if hasattr(self.model, "prefill_attrs"):
+                prefill.attrs.update(self.model.prefill_attrs(T))
+            self._state_seats += 1
+        toks = np.zeros((1, min(bucket, self.max_seq)), np.int32)
+        toks[0, :T] = req.tokens
+        nb_pre = bucket // c.block_size
+        # the prompt's blocks of the growing table, then the ring
+        # (scratch where a short stream holds no block)
+        blk = np.zeros((nb_pre + self.ring,), np.int32)
+        blk[:nb_pre] = blocks[:nb_pre]
+        blk[nb_pre:nb_pre + len(wblocks)] = wblocks
+        read, self.pool = self._prefill_fn(bucket)(*self._prefill_args(
+            toks, jnp.asarray(blk), slot, T, req.seed, req.temperature,
+            req.do_sample))
+        return read
 
     def _seat(self, slot: int, req: Request, blocks: List[int],
               wblocks: List[int], new: int, first: int, bad: int, rec: dict):
@@ -1987,7 +2016,8 @@ class ServingEngine:
         the pool and leaves its own in the stream's next summary blocks; the
         tail, padded to its bucket, through the bucket's.  The only state a
         segment hands the next is the summary blocks it wrote.  Returns the
-        last segment's read (the first token is sampled there)."""
+        last segment's read, still on the device (the first token is sampled
+        there)."""
         c, fold = self.config, self._fold
         W, sb = fold.window, fold.summary_blocks
         full, tail = divmod(int(len(req.tokens)), W)
@@ -1997,25 +2027,21 @@ class ServingEngine:
         # the summary table every segment reads: scratch past what is folded
         table = np.zeros((self.model.summary_table_blocks(c.block_size),),
                          np.int32)
-        with jax.set_mesh(self.engine.mesh):
-            with self._spans.span("serving.prefill.dispatch"):
-                for j in range(full + bool(tail)):
-                    whole = j < full
-                    own = blocks[j * sb:(j + 1) * sb] if whole else \
-                        blocks[full * sb:full * sb + bucket // c.block_size]
-                    piece = req.tokens[j * W:(j + 1) * W]
-                    toks = np.zeros((1, W if whole else bucket), np.int32)
-                    toks[0, :len(piece)] = piece
-                    read, self.pool = self._prefill_fn(
-                        toks.shape[1], window=whole)(*self._prefill_args(
-                            toks, np.concatenate([table, own]).astype(
-                                np.int32), j * W, len(piece), req.seed,
-                            req.temperature, req.do_sample))
-                    if whole:
-                        table[j * sb:(j + 1) * sb] = own
-                read.copy_to_host_async()
-        with self._spans.span("serving.prefill.readback"):
-            return np.asarray(read)
+        for j in range(full + bool(tail)):
+            whole = j < full
+            own = blocks[j * sb:(j + 1) * sb] if whole else \
+                blocks[full * sb:full * sb + bucket // c.block_size]
+            piece = req.tokens[j * W:(j + 1) * W]
+            toks = np.zeros((1, W if whole else bucket), np.int32)
+            toks[0, :len(piece)] = piece
+            read, self.pool = self._prefill_fn(
+                toks.shape[1], window=whole)(*self._prefill_args(
+                    toks, np.concatenate([table, own]).astype(np.int32),
+                    j * W, len(piece), req.seed, req.temperature,
+                    req.do_sample))
+            if whole:
+                table[j * sb:(j + 1) * sb] = own
+        return read
 
     def _fold_ended_windows(self, active):
         """Fold the window of every row of ``active`` whose length has just
@@ -2942,27 +2968,44 @@ class ServingEngine:
         return (self.kvs is not None
                 or self._txq is not None or self.role != "mixed"
                 or self._draining
-                or any(self._slots[i].pending is not None for i in active))
+                or any(self._slots[i] is not None
+                       and self._slots[i].pending is not None for i in active))
 
-    def _runs_ahead(self, unread: _Unread) -> bool:
-        """May the next decode step be dispatched before ``unread`` is
-        read?  Only if it would run on exactly the operands a read would
-        leave: the resident state is clean, nothing is to be seated or
-        shed, and no row is known to end with the unread step (nearly
-        every finish is by ``max_new_tokens``, which the host can count:
-        reading first re-seats the slot at once and computes no dead row).
-        An eos, a poisoned row or a passed deadline is seen one dispatch
-        late instead."""
-        if (self._state_dirty or self._settles_every_step(unread.active)
-                or self._admission_due()):
-            return False
+    def _behind_unread(self, unread: _Unread) -> Optional[str]:
+        """What this call may send to the device BEHIND ``unread``, before
+        reading it: the next decode ``"step"``, the queue head's
+        ``"prefill"``, or nothing (``None``: settle, then dispatch).
+
+        The next step runs ahead only on exactly the operands a read would
+        leave: the resident state is clean and nothing is to be seated or
+        shed.  A row that ends with the unread step (an eos, a poisoned row,
+        a passed deadline, and the ``max_new_tokens`` the host could count)
+        is seen one dispatch late: it computes one dead row-step inside its
+        own blocks, and its slot is re-seated behind that step instead of
+        after a drained device.  A step that no row would live through is
+        not dispatched.
+
+        An admission that is due runs with the step in flight
+        (:meth:`_start` books it after the prefill's dispatch and before the
+        seat).  What is armed for other readers settles first, as every
+        admission did: whatever settles every step, and a prefix cache (a
+        finish publishes blocks and an eviction frees them, which only the
+        allocator knows).  So does a step that ends a row's window of a
+        folded cache: it is folded, and the row's table rewritten, before
+        anything else is dispatched or planned."""
+        if self._settles_every_step(unread.active):
+            return None
         if self._fold is not None and not (
                 (self._lengths[unread.active] + 1) % self._fold.window).all():
-            # the unread step ends a row's window: it is folded, and the
-            # row's table rewritten, before the next step may read it
-            return False
-        return not any(len(self._slots[i].out_tokens) + 1
-                       >= self._slots[i].max_new for i in unread.active)
+            return None
+        if self._admission_due():
+            return "prefill" if self._prefix_index is None else None
+        if self._state_dirty:
+            return None
+        slots = self._slots
+        live = any(slots[i] is not None and len(slots[i].out_tokens) + 1
+                   < slots[i].max_new for i in unread.active)
+        return "step" if live else None
 
     def _settle(self):
         """Read and book the unread step, if there is one, outside
@@ -2985,9 +3028,10 @@ class ServingEngine:
         mon = self.monitor
         mon.begin_step(root)
         unread = self._unread
-        ahead = unread is not None and self._runs_ahead(unread)
+        behind = self._behind_unread(unread) if unread is not None else None
+        ahead = behind == "step"
         booked = None                    # (active, tokens emitted, stamp)
-        if unread is not None and not ahead:
+        if unread is not None and behind is None:
             # settle, then dispatch: a slot may change below
             booked = self._book(unread)
         active = ()
@@ -3003,7 +3047,12 @@ class ServingEngine:
                 with spans.span("serving.kv_transfer"):
                     self._admit_transfers()
             with spans.span("serving.admit"):
-                self._admit()
+                # with `unread` in flight, the first seat books it
+                booked = self._admit() or booked
+            if self._unread is not None:
+                # ...and nothing was seated after all (the head was shed):
+                # the order every step had before
+                booked = self._book(unread)
             if self._txq is not None and self.role == "prefill":
                 # AFTER _admit: slots seated by this step's prefill publish
                 # immediately — the handoff adds zero decode-step latency
@@ -3679,6 +3728,7 @@ class ServingEngine:
         self._reused_steps = 0
         self._state_uploads = 0
         self._ahead_steps = 0
+        self._admits_under = 0
         self._grown_total = 0
         self._folded_total = self._folded_reported = 0
         self._state_seats = 0
@@ -3722,6 +3772,8 @@ class ServingEngine:
                # and the steps dispatched while the one before was still
                # unread (docs/serving.md#one-step-in-flight)
                "steps_ahead": self._ahead_steps,
+               # and the prefills dispatched behind such a step
+               "admits_under_step": self._admits_under,
                # blocks granted to seated rows at the dispatch that first
                # wrote into them (docs/serving.md#capacity-math--admission-control)
                "blocks_grown_total": self._grown_total,

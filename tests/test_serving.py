@@ -598,10 +598,13 @@ def test_serving_step_anatomy(tiny, devices):
     cover it to within its self time; a prefill hangs under ``admit`` with
     its request's uid and its dispatch and read-back as children.  A call
     carries the number of the step it books: it dispatches the next step
-    first while no slot changes, reads first before one does, and the first
+    first while a row lives on, sends an admission's prefill to the device
+    first and books the step in flight inside the prefill's bracket, reads
+    first where no row is left to live through another step, and the first
     call only seats and dispatches (docs/serving.md#one-step-in-flight)."""
     reqs = [Request(tokens=np.arange(5), max_new_tokens=4, seed=0),
-            Request(tokens=np.arange(9), max_new_tokens=3, seed=1)]
+            Request(tokens=np.arange(9), max_new_tokens=3, seed=1),
+            Request(tokens=np.arange(7), max_new_tokens=5, seed=2)]
     rows, res = _served_rows(tiny, reqs)
     steps = [r for r in rows if r.name == "serving.step"]
     booking = [st for st in steps if st.attrs["t_tokens"] is not None]
@@ -625,12 +628,20 @@ def test_serving_step_anatomy(tiny, devices):
             assert ahead == [False] and st.attrs["emitted"] == 0
         elif ahead == [True]:
             assert names == dispatching + settling
+        elif ahead == [False]:
+            # the third request goes in under the step in flight (which
+            # carries the second's dead row): its prefill is dispatched,
+            # the step read and booked inside the prefill's bracket, then
+            # the state goes up and the next step follows
+            assert names == ["serving.admit"] + dispatching
+            inside = [r.name for r in rows if r.parent == "serving.prefill"
+                      and r.step == st.step and st.t_start <= r.t_start]
+            assert inside == ["serving.prefill.dispatch"] + settling \
+                + ["serving.prefill.readback"]
         else:
-            # a row's last token was in flight: read first, then whatever
-            # the freed slot allows, then the next step if a row is left
-            assert names[:3] == settling + ["serving.admit"]
-            assert names[3:] in (dispatching, [])
-            assert ahead in ([False], [])
+            # the last row's last token was in flight: no row would live
+            # through another step, so it is read first and nothing follows
+            assert names == settling + ["serving.admit"]
         shapes.add((st in booking, tuple(ahead)))
         for a, b in zip(kids, kids[1:]):
             assert a.t_end <= b.t_start                   # no overlap
@@ -643,14 +654,16 @@ def test_serving_step_anatomy(tiny, devices):
         len(r["tokens"]) - 1 for r in res.values())
     prefills = [r for r in rows if r.name == "serving.prefill"]
     assert sorted(r.uid for r in prefills) == sorted(q.uid for q in reqs)
+    assert [pf.attrs["under_step"] for pf in prefills] == [False, False, True]
     for pf in prefills:
-        assert pf.parent == "serving.admit" and pf.step == 1
+        assert pf.parent == "serving.admit"
+        assert pf.step == (3 if pf.attrs["under_step"] else 1)
         assert pf.attrs["prompt_len"] == len(
             next(q for q in reqs if q.uid == pf.uid).tokens)
         assert pf.attrs["bucket"] % 8 == 0
         assert res[pf.uid]["t_admit"] == pf.t_start
         kids = [r for r in rows if r.parent == "serving.prefill"
-                and r.uid == pf.uid]
+                and r.uid == pf.uid and r.name.startswith("serving.prefill.")]
         assert [k.name for k in kids] == ["serving.prefill.dispatch",
                                           "serving.prefill.readback"]
         assert pf.t_start <= kids[0].t_start

@@ -1,12 +1,15 @@
 """One decode step always in flight (docs/serving.md#one-step-in-flight).
 
 While no slot changes, ``ServingEngine.step()`` dispatches decode step N+1 on
-the device's own advanced state before it reads step N.  These tests hold the
-token streams, the outcomes and the block accounting to a server that is made
-to settle every step (the order every step had before), on both served
-architectures; the one row a late-seen finish computes in vain to its own
-blocks; the configurations that must never run ahead to ``steps_ahead == 0``;
-and every way of ending a run to "nothing unread, no token missing"."""
+the device's own advanced state before it reads step N; a finish of any kind
+is seen one dispatch late, and an admission's prefill goes to the device
+behind the step in flight.  These tests hold the token streams, the outcomes
+and the block accounting to a server that is made to settle every step (the
+order every step had before), on GPT-2, Jamba and a folded cache; the one row
+a late-seen finish computes in vain to its own blocks; a slot re-seated
+behind the step that carried its old row to the new stream's own state; the
+configurations that must never run ahead to ``steps_ahead == 0``; and every
+way of ending a run to "nothing unread, no token missing"."""
 
 import time
 
@@ -36,13 +39,19 @@ def families():
                      resid_pdrop=0.0, attention_impl="jnp")
     gpt2 = GPT2(cfg, dtype=jnp.float32)
     jamba = build("jamba-tiny", dtype=jnp.float32, **JAMBA)
+    # a cache that folds: windows of 16 tokens, 4 summary rows a window
+    folded = build("evabyte-tiny", dtype=jnp.float32)
     return {"gpt2": (gpt2, gpt2.init(jax.random.PRNGKey(0))),
-            "jamba": (jamba, jamba.init(jax.random.PRNGKey(3)))}
+            "jamba": (jamba, jamba.init(jax.random.PRNGKey(3))),
+            "folded": (folded, jax.jit(folded.init)(jax.random.PRNGKey(7)))}
 
 
 def _server(model_params, **cfg):
     model, params = model_params
-    return ServingEngine(model=model, params=params, config=ServingConfig(
+    if hasattr(model, "cache_fold"):
+        cfg = {"block_size": 2, **cfg}   # whole blocks of summary rows
+    return ServingEngine(model=model, params=params, dtype=jnp.float32,
+                         config=ServingConfig(
         **{"batch_slots": 3, "block_size": 8, **cfg}))
 
 
@@ -118,6 +127,137 @@ def test_streams_identical_to_settling_every_step(families, fault_harness,
     assert got[0][1][-1] == eos and len(got[0][1]) < len(clean[0]["tokens"])
     assert [len(got[u][1]) for u in uids[3:]] == [7, 1, 10]     # max_new
     assert 3 <= len(got[victim][1]) < 30
+
+
+# ------------------- (1b) admissions behind the step in flight move no stream
+def _backlog(family):
+    """Three times as many requests as slots, every one ending at its
+    ``max_new_tokens`` and no two of a wave at the same step: every admission
+    after the first wave follows a finish the host could have counted."""
+    rng = np.random.default_rng(11)
+    shapes = [(19, 12), (25, 5), (18, 9), (28, 7), (9, 3), (23, 10),
+              (12, 6), (30, 4), (7, 11)]
+    if family == "folded":               # prompts and answers that cross
+        shapes = [(5, 30), (15, 9), (33, 21), (18, 6), (47, 12), (16, 20),
+                  (7, 14), (40, 9), (31, 18)]       # windows of 16 tokens
+    return [Request(tokens=rng.integers(0, 128, n), max_new_tokens=new,
+                    seed=i, do_sample=family != "folded", temperature=0.8)
+            for i, (n, new) in enumerate(shapes)]
+
+
+@pytest.mark.parametrize("family", ["gpt2", "jamba", "folded"])
+def test_admissions_behind_a_counted_finish_move_no_stream(families, devices,
+                                                           family):
+    """The order that took the place of "a foreseen finish settles first" and
+    "an admission settles first": the step after a row's ``max_new_tokens``-th
+    token is dispatched with the row still seated, and the head's prefill goes
+    to the device behind it.  Streams, outcomes and free blocks are those of
+    a server made to settle every step; Jamba's prefill writes the slot's
+    recurrent rows whole behind the dead row's advance of them; a step that
+    ends a row's window of the folded cache is settled and folded first."""
+    from deepspeed_tpu.monitor import spans as monspans
+
+    def serve(settle):
+        srv = _server(families[family], sanitize=True)
+        mark = monspans.recorder().open("test")
+        for r in _backlog(family):
+            srv.submit(r)
+        while _step(srv, settle):
+            pass
+        rows = monspans.recorder().since(mark)
+        monspans.recorder().discard(mark)
+        assert srv._unread is None
+        st = srv.stats()
+        res = {u: (r["outcome"], r["tokens"]) for u, r in srv.results.items()}
+        free = srv.allocator.free_blocks, srv.num_blocks - 1
+        srv.close()                      # the sanitizer's leak check
+        under = [r.attrs["under_step"] for r in rows
+                 if r.name == "serving.prefill"]
+        return res, st, free, under
+
+    got, st, free, under = serve(settle=False)
+    want, st_settled, free_settled, under_settled = serve(settle=True)
+    assert got == want and all(o == OK for o, _ in got.values())
+    assert [len(t) for _, t in got.values()] == [
+        r.max_new_tokens for r in _backlog(family)]
+    assert free == free_settled and free[0] == free[1]
+    # the admissions after the first wave went in under a step, but for a
+    # step that had to be settled for its own sake (a window's end, a step no
+    # row was left to live through) and a second seat of the same call (its
+    # slot was freed by the very step the first prefill went under)
+    assert sum(under) == st["admits_under_step"] >= 3
+    assert len(under) == 9 and not any(under[:3])
+    assert st_settled["admits_under_step"] == st_settled["steps_ahead"] == 0
+    assert not any(under_settled)
+    # the dead row-steps: more steps ran ahead than a server that settles a
+    # counted finish first could have run, and no token came of them
+    assert st["steps_ahead"] > 0
+    assert st["generated_tokens"] == st_settled["generated_tokens"]
+    if family == "folded":
+        assert st["windows_folded_total"] \
+            == st_settled["windows_folded_total"] > 0
+
+
+def test_a_slot_reseated_behind_its_old_rows_step_keeps_its_own_state(
+        families, devices):
+    """A slot is re-seated while the step that carried its old row is still
+    in flight: the prefill is dispatched behind that step, the step is booked
+    before the seat, and the new stream never receives the old row's sample,
+    its length or the block the dead row-step was granted."""
+    def requests():
+        return [Request(tokens=np.arange(9) % 7, max_new_tokens=8, seed=1,
+                        do_sample=True),             # the old row: 9 + 8 = 17
+                Request(tokens=np.arange(21) % 11, max_new_tokens=30, seed=2),
+                Request(tokens=np.arange(13) % 5 + 1, max_new_tokens=6,
+                        seed=3, do_sample=True)]     # the new tenant
+
+    solo = _server(families["gpt2"], batch_slots=1)
+    alone = [solo.run([r])[i]["tokens"] for i, r in enumerate(requests())]
+    solo.close()
+    srv = _server(families["gpt2"], batch_slots=2, sanitize=True)
+    old, other, new = (srv.submit(r) for r in requests())
+    seen = {}
+    book, seat = srv._book, srv._seat
+
+    def watch_book(step):
+        if srv._slots[0] is None and 0 in step.active and "dead" not in seen:
+            # the step that carried the old row's dead row-step: booked
+            # while the slot is empty, inside the new tenant's `_start`
+            seen["dead"] = (srv.queue[0].uid if srv.queue else None,
+                            srv.results[new]["t_admit"] is not None)
+        return book(step)
+
+    def watch_seat(slot, req, blocks, *a, **kw):
+        if req.uid == new:
+            seen["seat"] = (slot, srv._unread is None, "dead" in seen,
+                            list(blocks))
+        return seat(slot, req, blocks, *a, **kw)
+    srv._book, srv._seat = watch_book, watch_seat
+    old_blocks = None
+    while srv.results[new]["t_first"] is None:
+        if srv._slots[0] is not None and srv._slots[0].req.uid == old:
+            old_blocks = srv._slots[0].blocks        # the list: it grows
+        assert srv.step()
+    # the old row's last step opened its third block for the dead write at
+    # position 16, which went home with the others before the new seat
+    assert len(old_blocks) == 3 == pk.blocks_needed(9 + 8, 8)
+    head, prefilling = seen["dead"]
+    assert head is None and prefilling   # booked inside the new tenant's start
+    slot, settled, after_dead, seat_blocks = seen["seat"]
+    assert (slot, settled, after_dead) == (0, True, True)
+    s = srv._slots[0]
+    assert s.req.uid == new and s.out_tokens == alone[2][:1]
+    assert s.blocks == seat_blocks and len(seat_blocks) \
+        == pk.blocks_needed(13 + 1, 8) == int(srv._held[0])
+    # the mirrors are the seat's own: one prompt, one token, its own first
+    # sample (the dead row's is discarded), however the old row stood
+    assert (int(srv._lengths[0]), int(srv._ngen[0]), int(srv._toks[0])) \
+        == (13, 1, alone[2][0])
+    assert srv.stats()["admits_under_step"] == 1
+    srv.run()
+    assert [srv.results[u]["tokens"] for u in (old, other, new)] == alone
+    assert srv.allocator.free_blocks == srv.num_blocks - 1
+    srv.close()
 
 
 # ------------------------------------- (2) the row computed in vain is harmless
@@ -202,6 +342,72 @@ def test_dead_row_at_the_context_limit_writes_only_its_own_blocks(families,
     assert free == free_settled
 
 
+@pytest.mark.parametrize("past_the_edge", [0, 1],
+                         ids=["fills_its_last_block", "opens_its_last_block"])
+def test_dead_row_of_a_counted_finish_writes_inside_its_planned_blocks(
+        families, devices, past_the_edge):
+    """A row's step after its ``max_new_tokens``-th token is dispatched with
+    the row still seated and writes position ``prompt + max_new - 1``: the
+    last position of its last block where ``prompt + max_new`` is a multiple
+    of the block, and the FIRST of a block no live token ever reaches where
+    it is one more, which that dispatch is granted (`_grant_blocks`' assertion
+    and the sanitizer armed, the pool as small as the admission rule lets
+    both streams be seated: the timeline planned for that block).  What the
+    pool holds differs from a settled server's only inside the row's own
+    blocks, the neighbour's stream does not move, and every block comes
+    home."""
+    new = 14 + past_the_edge             # 10 + 14 = 24 = three blocks of 8
+
+    def request_a():
+        return Request(tokens=np.arange(10) % 7, max_new_tokens=new, seed=5,
+                       do_sample=True, temperature=1.2)
+
+    def request_b():
+        return Request(tokens=np.arange(13) + 3, max_new_tokens=30, seed=6)
+
+    life = pk.blocks_needed(10 + new, 8)
+    assert life == 3 + past_the_edge
+
+    def serve(settle):
+        # the fewest blocks both lives fit in: a grant outside the plan
+        # would find the allocator empty
+        srv = _server(families["gpt2"], batch_slots=2, sanitize=True,
+                      num_blocks=1 + life + pk.blocks_needed(13 + 30, 8))
+        a, b = srv.submit(request_a()), srv.submit(request_b())
+        blocks_a = None
+        while srv.results[a]["outcome"] is None:
+            assert _step(srv, settle)
+            if blocks_a is None:
+                blocks_a, blocks_b = srv._slots[0].blocks, srv._slots[1].blocks
+        if settle:
+            _step(srv, settle)           # the neighbour's step N+1, too
+        else:
+            assert srv._unread is not None       # N+1 carries the dead row
+            assert srv._slots[0] is None and 0 in srv._unread.active
+            srv._settle()
+        assert len(srv.results[b]["t_tokens"]) == new + 1
+        pool = {k: np.asarray(v) for k, v in srv.pool.items()}
+        grown = srv.stats()["blocks_grown_total"]
+        srv.run()
+        res = {u: (r["outcome"], r["tokens"]) for u, r in srv.results.items()}
+        free = srv.allocator.free_blocks, srv.num_blocks - 1
+        srv.close()
+        return res, pool, free, grown, list(blocks_a), list(blocks_b)
+
+    got, pool, free, grown, blocks_a, blocks_b = serve(settle=False)
+    want, pool_settled, free_settled, grown_settled, a_settled, b_settled \
+        = serve(settle=True)
+    assert got == want and all(o == OK for o, _ in got.values())
+    assert free == free_settled and free[0] == free[1]
+    # the dead row-step's own block: granted by its dispatch, never by a
+    # server that settles first (no live token writes position 24)
+    assert len(blocks_a) == life == len(a_settled) + past_the_edge
+    assert grown == grown_settled + past_the_edge
+    touched = _touched_blocks(pool, pool_settled) - (
+        set(blocks_b) ^ set(b_settled))
+    assert touched and touched <= {blocks_a[-1], pk.SCRATCH_BLOCK}
+
+
 # ------------------------------------------- (2b) a granted block settles nothing
 @pytest.mark.parametrize("family", ["gpt2", "jamba"])
 def test_growth_settles_no_step(families, devices, family):
@@ -242,18 +448,44 @@ def _shared_prefix_requests():
 
 
 @pytest.mark.parametrize("case", ["kv_snapshot", "transfer_queue_mixed_role",
-                                  "draining"])
+                                  "draining", "prefix_cache"])
 def test_what_settles_every_step(families, devices, tmp_path, case):
     """``_settles_every_step`` by what the engine holds, not by a knob of its
     own: a snapshot cadence, a transfer queue (on a ``mixed`` role too) and a
     drain each read every step in the call that dispatched it; an engine with
-    none of them runs ahead.  The streams are the plain engine's."""
+    none of them runs ahead and admits under the step in flight.  An armed
+    prefix cache runs ahead between admissions and settles before each, as
+    every admission did (a finish publishes blocks and an eviction frees
+    them, which only the allocator knows).  The streams are the plain
+    engine's."""
     plain = _server(families["gpt2"])
     assert not plain._settles_every_step(())
+    n = 6 if case == "prefix_cache" else 3           # more than the slots
     want = {u: r["tokens"] for u, r in plain.run(
-        _shared_prefix_requests()[:3]).items()}
+        _shared_prefix_requests()[:n]).items()}
     assert plain.stats()["steps_ahead"] > 0
+    assert (plain.stats()["admits_under_step"] > 0) == (n > 3)
     plain.close()
+    if case == "prefix_cache":
+        srv = _server(families["gpt2"], prefix_cache=True)
+        assert not srv._settles_every_step(())
+        for r in _shared_prefix_requests():
+            srv.submit(r)
+        start, unread_at_a_seat = srv._start, []
+
+        def watch(*a, **kw):
+            unread_at_a_seat.append(srv._unread is not None)
+            return start(*a, **kw)
+        srv._start = watch
+        while srv.step():
+            pass
+        st = srv.stats()
+        assert len(unread_at_a_seat) == 6 and not any(unread_at_a_seat)
+        assert st["admits_under_step"] == 0 and st["steps_ahead"] > 0
+        assert st["prefix_cache"]["requests_hit"] >= 1
+        assert {u: r["tokens"] for u, r in srv.results.items()} == want
+        srv.close()
+        return
     armed = {"kv_snapshot": dict(kv_snapshot={"every_tokens": 4},
                                  journal_dir=str(tmp_path / "journal"),
                                  kv_bits=8),

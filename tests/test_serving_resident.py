@@ -167,9 +167,10 @@ def test_streams_identical_to_an_upload_on_every_step(tiny, devices, variant):
 
 def test_counters_reused_steps_and_uploads(tiny, devices):
     """(c) N steps in which no slot changes record N reused steps, N steps
-    ahead and no upload; a seat and a finish are each settled first and
-    record exactly one upload; the ``serving.upload`` and
-    ``serving.dispatch`` spans say which it was."""
+    ahead and no upload; a seat goes in under the step in flight and records
+    exactly one upload; a finish is seen one dispatch late (the step after it
+    ran ahead with the dead row) and the upload follows in the next call; the
+    ``serving.upload`` and ``serving.dispatch`` spans say which it was."""
     from deepspeed_tpu.monitor import spans as monspans
     srv = _server(tiny)
     srv.submit(Request(tokens=np.arange(5), max_new_tokens=12, seed=0))
@@ -185,22 +186,25 @@ def test_counters_reused_steps_and_uploads(tiny, devices):
     assert (st["decode_steps"], st["state_reused_steps"], st["steps_ahead"],
             st["state_uploads"]) == (6, 6, 6, 0)
     srv.submit(Request(tokens=np.arange(7), max_new_tokens=2, seed=1))
-    assert srv.step()                    # a seat: settle, one upload, dispatch
-    assert srv.stats()["state_uploads"] == 1
-    assert srv.step()                    # its last token is in flight: read
-    assert srv.results[1]["outcome"] == OK           # first; the finish
-    st = srv.stats()                                 # uploads once more
+    assert srv.step()                    # a seat under the step in flight:
+    st = srv.stats()                     # prefill, book, one upload, dispatch
+    assert (st["state_uploads"], st["admits_under_step"]) == (1, 1)
+    assert srv.step()                    # its last token is in flight: the
+    assert srv.results[1]["outcome"] == OK           # next step runs ahead
+    st = srv.stats()                                 # with the dead row
     assert (st["state_uploads"], st["state_reused_steps"],
-            st["steps_ahead"]) == (2, 6, 6)
-    assert srv.step()
+            st["steps_ahead"]) == (1, 7, 7)
+    assert srv.step()                    # the cleared row goes up
     st = srv.stats()
     assert (st["state_uploads"], st["steps_ahead"]) == (2, 7)
     rows = rec.since(mark)
     rec.discard(mark)
     assert [r.attrs["uploaded"] for r in rows if r.name == "serving.upload"] \
-        == [False] * 6 + [True, True, False]
+        == [False] * 6 + [True, False, True]
     assert [r.attrs["ahead"] for r in rows if r.name == "serving.dispatch"] \
-        == [True] * 6 + [False, False, True]
+        == [True] * 6 + [False, True, False]
+    assert [r.attrs["under_step"] for r in rows
+            if r.name == "serving.prefill"] == [True]
     srv.run()
     assert srv._unread is None
     assert srv.stats()["decode_steps"] == 11         # 12 tokens, one prefilled

@@ -9,7 +9,8 @@ overtaken.  The sum alone against a walk over the steps; random backlogs
 through a tiny engine whose pool binds, every stream against the same request
 served alone; more streams seated than a reservation for life would seat;
 every way of leaving early brings every granted block home; a block granted
-out of a dead row's hands; and the operands an outside reader gets."""
+out of a dead row's hands; a head planned under the step in flight beside
+blocks that just came home; and the operands an outside reader gets."""
 
 import time
 
@@ -321,6 +322,13 @@ def test_early_exits_return_every_grown_block(tiny, fault_harness, devices,
 
 
 # -------------------- (5) a block granted out of a dead row's hands
+def _garbage(srv, blocks):
+    """Fill ``blocks`` of the pool with finite garbage (1e4 in K and V)."""
+    ids = jnp.asarray(blocks)
+    srv.pool = {k: v.at[:, ids].set(jnp.asarray(1e4, v.dtype))
+                for k, v in srv.pool.items()}
+
+
 def test_block_granted_beside_a_dead_row_in_flight(tiny, devices):
     """A row ends at an eos the host sees one dispatch late: its blocks go
     home while a step dispatched for it is still in flight, and the very next
@@ -332,11 +340,6 @@ def test_block_granted_beside_a_dead_row_in_flight(tiny, devices):
     its tokens.  (Finite garbage, as every block that comes home holds: NaN
     would pass through a masked position as 0 x NaN, which is why a quarantine
     scrubs and why `_set_blocks(poison=True)` is no use here.)"""
-    def garbage(srv, blocks):
-        ids = jnp.asarray(blocks)
-        srv.pool = {k: v.at[:, ids].set(jnp.asarray(1e4, v.dtype))
-                    for k, v in srv.pool.items()}
-
     def request_a():
         return Request(tokens=np.arange(20) % 17, max_new_tokens=30, seed=5,
                        uid=0, do_sample=True, temperature=1.2)
@@ -355,14 +358,14 @@ def test_block_granted_beside_a_dead_row_in_flight(tiny, devices):
 
     b_alone = _alone(tiny, [request_b()], eos_token_id=eos)[1]
     srv = _server(tiny, batch_slots=2, num_blocks=14, eos_token_id=eos)
-    garbage(srv, list(srv.allocator._free))
+    _garbage(srv, list(srv.allocator._free))
     orig, homes, grants = srv._finish, [], []
 
     def finish(slot, outcome=OK):
         blocks = list(srv._slots[slot].blocks)
         in_flight = dispatched[0] > srv._steps      # booked: `_steps`
         orig(slot, outcome)
-        garbage(srv, blocks)             # behind the step that is in flight
+        _garbage(srv, blocks)             # behind the step that is in flight
         homes.append((blocks, in_flight))
     srv._finish = finish
     grant = srv._grant_blocks
@@ -394,6 +397,83 @@ def test_block_granted_beside_a_dead_row_in_flight(tiny, devices):
     assert srv.results[b]["tokens"] == b_alone
     assert srv.allocator.free_blocks == srv.num_blocks - 1
     srv.close()
+
+
+# ----- (6) a head planned with a step unread and a dead row's blocks just home
+def test_head_planned_under_a_step_beside_blocks_just_returned(tiny, devices):
+    """The pool binds (two slots, and the three lives fill it to the last
+    block only once the first has gone): a row ends
+    at its ``max_new_tokens`` with the next step already dispatched for it,
+    its blocks go home, and the head is planned at once, with that step still
+    unread (every live row one token past its mirror), seated into the blocks
+    that just came home, its prefill dispatched behind the dead row's write
+    into one of them.  The timeline keeps its promise (`_grant_blocks`'
+    assertion never fires, what is checked out never passes what the rule
+    promised), every stream yields the tokens it yields alone, and every
+    block comes home.  Held over a pool of GARBAGE, as the test above."""
+    def requests():
+        return [Request(tokens=np.arange(20) % 17, max_new_tokens=13, seed=5,
+                        uid=0, do_sample=True, temperature=1.2),  # 33: 5 blocks
+                Request(tokens=np.arange(11) + 3, max_new_tokens=36, seed=6,
+                        uid=1),                                   # 47: 6
+                Request(tokens=(np.arange(26) * 5) % 23, max_new_tokens=9,
+                        seed=7, uid=2, do_sample=True)]           # 35: 5
+
+    want = _alone(tiny, requests())
+    # 5 + 6 blocks seat the first two; the third's 5 are the first's
+    srv = _server(tiny, batch_slots=2, num_blocks=12)
+    _garbage(srv, list(srv.allocator._free))
+    orig, homes = srv._finish, []
+
+    def finish(slot, outcome=OK):
+        blocks = list(srv._slots[slot].blocks)
+        in_flight = dispatched[0] > srv._steps      # booked: `_steps`
+        orig(slot, outcome)
+        _garbage(srv, blocks)             # behind the step that is in flight
+        homes.append((blocks, in_flight))
+    srv._finish = finish
+    dispatch, dispatched = srv._dispatch, [0]
+
+    def count_dispatch(active, ahead):
+        dispatched[0] += 1
+        return dispatch(active, ahead)
+    srv._dispatch = count_dispatch
+    head_plan, plans = srv._head_plan, []
+
+    def watch_plan(req, shared=0):
+        out = head_plan(req, shared)
+        plans.append((req.uid, srv._unread is not None,
+                      srv.allocator.free_blocks, out[0][2]))
+        return out
+    srv._head_plan = watch_plan
+    start, seats = srv._start, []
+
+    def watch_start(slot, req, blocks, *a, **kw):
+        seats.append((req.uid, srv._unread is not None, list(blocks),
+                      [int(x) for x in srv._lengths]))
+        return start(slot, req, blocks, *a, **kw)
+    srv._start = watch_start
+    for r in requests():
+        srv.submit(r)
+    while srv.step():
+        assert srv.allocator.used_blocks == int(srv._held.sum()) \
+            <= srv._promised <= 11
+    blocks_a, in_flight = homes[0]
+    assert in_flight and len(blocks_a) == 5      # the dead row's own block too
+    # the head's plan that passed was made with that step unread, over
+    # exactly the blocks that had just come home, and the seat took them
+    uid, unread, blocks_c, lengths = seats[2]
+    assert (uid, unread) == (2, True) and set(blocks_c) <= set(blocks_a)
+    # (free: all but the neighbour's three; the plan's peak: the whole pool,
+    # which the dead row's five blocks had to come home for)
+    assert [p[1:] for p in plans if p[0] == 2][-1] == (True, 11 - 3, 11)
+    assert lengths[1] == 11 + 12         # the neighbour's mirror: twelve steps
+    #                                      booked, the thirteenth in flight
+    assert srv.stats()["admits_under_step"] == 1
+    assert {u: r["tokens"] for u, r in srv.results.items()} == want
+    assert all(r["outcome"] == OK for r in srv.results.values())
+    assert srv.allocator.free_blocks == srv.num_blocks - 1
+    srv.close()                          # the sanitizer's leak check
 
 
 # ---------------- (7) the operands an outside reader gets cover the next write
